@@ -14,7 +14,7 @@ import urllib.request
 
 # Hard-set (not setdefault): this demo serves a tiny random-weight model
 # — it must not grab (or fail to share) a real TPU chip another process
-# holds. Real-chip serving runs through `python bench.py --serve`.
+# holds. The engine on the chip runs as `python chip_smoke.py`.
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import ray_tpu as ray

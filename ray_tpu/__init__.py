@@ -14,10 +14,6 @@ import inspect as _inspect
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 from ._version import __version__
-from .util import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from .core import runtime as _runtime
 from .core.actor import (ActorClass, ActorHandle, exit_actor,
                          get_actor, method)

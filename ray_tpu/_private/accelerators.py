@@ -23,6 +23,33 @@ TPU_NAME_ENV = "TPU_NAME"                         # pod/slice name
 CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"  # e.g. "2,2,1"
 
 
+# Published per-chip peaks, keyed by jax's `device_kind`: the one table
+# every utilisation or roofline number in this repo divides by. A chip
+# that is not here is an error, not a default — add its row with its
+# source.
+CHIP_PEAKS: Dict[str, Dict[str, object]] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "Google Cloud documentation, \"TPU v5e\" (per chip)",
+    },
+}
+
+
+def chip_peaks(device) -> Dict[str, object]:
+    """The CHIP_PEAKS row of a jax device; raises for an unknown kind."""
+    kind = device.device_kind
+    if kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {kind!r} (platform "
+            f"{device.platform!r}); known: {sorted(CHIP_PEAKS)} — add "
+            "its row to ray_tpu/_private/accelerators.py CHIP_PEAKS")
+    return CHIP_PEAKS[kind]
+
+
 def get_visible_chips() -> Optional[List[str]]:
     """Chip ids this process may use; None = unrestricted. An EMPTY env
     value means ZERO chips (the CUDA_VISIBLE_DEVICES contract — '' is a
@@ -41,8 +68,9 @@ def set_visible_chips(chip_ids: List[str]) -> None:
 
 
 def num_chips_per_host() -> int:
-    """Chips THIS PROCESS may use: the visibility list wins (the
-    CUDA_VISIBLE_DEVICES analog — a restricted process must not
+    """Chips on this host, for a process that must NOT touch jax (a
+    node daemon: its workers own the chips): the visibility list wins
+    (the CUDA_VISIBLE_DEVICES analog — a restricted process must not
     advertise the whole host), then the host bounds env (e.g.
     "2,2,1" → 4), then probing jax; 0 if undiscoverable."""
     visible = get_visible_chips()
@@ -57,6 +85,22 @@ def num_chips_per_host() -> int:
             return n
         except ValueError:
             pass
+    return _jax_chip_count()
+
+
+def num_chips_driven() -> int:
+    """Chips THIS process computes on, for the one process that owns
+    them (the local runtime's driver): the visibility list, which
+    libtpu honours, else what the jax client sees. The host bounds env
+    is not consulted: it describes the host TYPE, and a one-chip
+    container on a four-chip host type still reads "2,2,1"."""
+    visible = get_visible_chips()
+    if visible is not None:
+        return len(visible)
+    return _jax_chip_count()
+
+
+def _jax_chip_count() -> int:
     try:
         import jax
 

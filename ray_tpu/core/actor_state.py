@@ -27,6 +27,7 @@ from .exceptions import (
 )
 from .ids import ActorID, ObjectID, TaskID
 from .object_ref import ObjectRef
+from .resources import TPU
 from .runtime_env import applied as _renv_applied
 from .runtime_support import _ctx
 from .task import TaskSpec
@@ -505,6 +506,8 @@ class ProcActorState(ActorState):
             }
             if self.runtime_env:
                 create_msg["runtime_env"] = self.runtime_env
+            if self.resources.get(TPU):
+                create_msg["num_tpus"] = self.resources.get(TPU)
             reply = w.run_task(create_msg)
             for ev in reply.get("spans") or ():
                 self.rt.events.record_raw(ev)

@@ -1,4 +1,4 @@
-"""Exception taxonomy.
+"""Exception hierarchy.
 
 Capability-equivalent to the reference's exception surface
 (reference: python/ray/exceptions.py): user-code failures wrapped with the

@@ -34,6 +34,25 @@ def _runtime_env(renv: Optional[Dict[str, Any]]):
     return applied(renv)
 
 
+def _refuse_tpu_on_cpu_pin(msg) -> None:
+    """One process owns a chip. Spawned workers default to
+    JAX_PLATFORMS=cpu (worker_proc.py, cluster_utils.py) so they never
+    take it from the driver — which means a task or actor that asked for
+    `num_tpus` and was placed here would compute on the CPU without a
+    word. Fail it instead. The native hand-off plane forwards the
+    driver's frame whole ("resources"); the Python planes send
+    "num_tpus"."""
+    tpus = msg.get("num_tpus") or (msg.get("resources") or {}).get("TPU")
+    if tpus and os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+        raise RuntimeError(
+            f"this task/actor asked for num_tpus={tpus:g} but was placed "
+            f"in worker process {os.getpid()}, which is pinned to the CPU "
+            "(JAX_PLATFORMS=cpu): it would compute on the CPU. Run it in "
+            "the process that owns the chip (the driver: default local "
+            "runtime, num_worker_procs=0), or start that node with "
+            "JAX_PLATFORMS=tpu so that one of its workers owns it.")
+
+
 def _setup(args):
     sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
     sock.connect(args.socket)
@@ -269,6 +288,8 @@ def main() -> None:
                     if traced else contextlib.nullcontext())
 
         try:
+            if mtype in ("task", "actor_create"):
+                _refuse_tpu_on_cpu_pin(msg)
             if mtype == "task":
                 fn = get_fn(msg)
                 call_args, call_kwargs = _unpack_args(

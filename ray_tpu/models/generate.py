@@ -114,8 +114,7 @@ def _prefill_layer(cfg: TransformerConfig, carry, lp):
     x, sin, cos = carry
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _qkv(cfg, lp, h, sin, cos)
-    force_ref = jax.default_backend() != "tpu"
-    out = flash_attention(q, k, v, causal=True, force_reference=force_ref)
+    out = flash_attention(q, k, v, causal=True)
     B, S, _, _ = q.shape
     x = x + (out.reshape(B, S, -1) @ lp["wo"].astype(x.dtype))
     x = _ffn(cfg, lp, x)
@@ -213,9 +212,8 @@ def prefill_sample(cfg: TransformerConfig, params, cache: KVCache,
                    tokens: jax.Array, length: jax.Array, slot: jax.Array,
                    top_k: int, temperature: jax.Array, key: jax.Array
                    ) -> Tuple[KVCache, jax.Array]:
-    """prefill + first-token sampling in ONE dispatch (halves the
-    admission round trips — TTFT is round-trip-bound on remote chips).
-    Returns (cache', token ())."""
+    """prefill + first-token sampling in ONE dispatch (one host sync
+    per admission instead of two). Returns (cache', token ())."""
     cache, last = _prefill_core(cfg, params, cache, tokens, length, slot)
     tok = sample(last[None], key, temperature=temperature[None],
                  top_k=top_k)[0]
@@ -313,9 +311,7 @@ def _suffix_layer(cfg: TransformerConfig, q_offset: int, sin, cos,
                             (W,) + pv.shape)
     kk = jnp.concatenate([pk_b, k_s], axis=1)     # (W, Sp+Sq, KVH, Dh)
     vv = jnp.concatenate([pv_b, v_s], axis=1)
-    force_ref = jax.default_backend() != "tpu"
-    out = flash_attention(q, kk, vv, causal=True, q_offset=q_offset,
-                          force_reference=force_ref)
+    out = flash_attention(q, kk, vv, causal=True, q_offset=q_offset)
     x = x + (out.reshape(W, Sq, -1) @ lp["wo"].astype(x.dtype))
     x = _ffn(cfg, lp, x)
     return (x,), (k_s, v_s)
@@ -559,9 +555,10 @@ def decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
     temperature. Returns (cache', toks (num_steps, B)). The host engine
     truncates per-slot output at eos/max_new_tokens — slots that finish
-    mid-block burn at most num_steps-1 wasted ticks, the price of
-    amortizing the host↔device round trip (which dominates decode on
-    tunneled/remote chips) over num_steps tokens.
+    mid-block burn at most num_steps-1 wasted ticks, the price of one
+    dispatch and one host fetch per num_steps tokens. What a block
+    should cost on a local chip is not measured (ROADMAP Queue 1
+    item 2).
     """
 
     def body(carry, sub):

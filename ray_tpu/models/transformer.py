@@ -53,7 +53,8 @@ class TransformerConfig:
     # size — the f32 (B, S, V) logits tensor is never materialized
     # (chunked_cross_entropy). 0 = classic full-logits loss.
     ce_chunk: int = 0
-    # attention: "auto" = pallas flash on TPU / XLA-fused reference on CPU;
+    # attention: "auto" = ops/flash_attention.py's dispatch (kernels on a
+    # TPU at or above the kv crossover, XLA-fused reference otherwise);
     # "reference" forces the einsum path. seq_parallel picks the sequence-
     # parallel strategy when the mesh has an sp axis > 1 (ops/ kernels).
     attn_impl: str = "auto"
@@ -183,6 +184,20 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def init_params_sharded(cfg: TransformerConfig, key: jax.Array, mesh
+                        ) -> Dict[str, Any]:
+    """init_params with every weight created in its mesh sharding: each
+    device materializes only its shard, nothing is placed whole on the
+    default device first. Same values as init_params for the same key
+    (threefry is partitionable)."""
+    from ..parallel.sharding import tree_shardings
+
+    shardings = tree_shardings(param_logical_axes(cfg), mesh)
+    with jax.sharding.set_mesh(mesh):
+        return jax.jit(partial(init_params, cfg),
+                       out_shardings=shardings)(key)
+
+
 # ---------------------------------------------------------------------------
 # Model pieces
 # ---------------------------------------------------------------------------
@@ -219,7 +234,7 @@ def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     """Dispatch causal attention to the right kernel for the ambient mesh.
 
     No mesh (or all relevant axes size 1): plain fused flash attention
-    (pallas on TPU, XLA-fused reference elsewhere). Sharded mesh: a
+    (ops/flash_attention.py decides kernel vs reference). Sharded mesh: a
     shard_map manual region — pallas kernels are opaque to the auto
     partitioner, so sharded attention MUST be manual. With an `sp` axis
     > 1 the sequence stays sharded end-to-end: ring attention rotates kv
@@ -229,7 +244,6 @@ def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     from ..ops import flash_attention, ring_attention, ulysses_attention
     from ..parallel.sharding import logical_to_mesh_axes
 
-    force_ref = jax.default_backend() != "tpu"
     if cfg.attn_impl == "reference":
         return flash_attention(q, k, v, causal=True, force_reference=True)
 
@@ -238,8 +252,7 @@ def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     used = {a for a, n in sizes.items() if n > 1} & {
         "dcn", "dp", "fsdp", "ep", "tp", "sp"}
     if not used:
-        return flash_attention(q, k, v, causal=True,
-                               force_reference=force_ref)
+        return flash_attention(q, k, v, causal=True)
 
     q_axes = ("batch", "seq", "act_heads", None)
     kv_axes = ("batch", "seq", "act_kv_heads", None)
@@ -253,8 +266,7 @@ def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
                 return ulysses_attention(q, k, v, axis_name="sp",
                                          causal=True)
             return ring_attention(q, k, v, axis_name="sp", causal=True)
-        return flash_attention(q, k, v, causal=True,
-                               force_reference=force_ref)
+        return flash_attention(q, k, v, causal=True)
 
     return jax.shard_map(
         local_attn, mesh=mesh, in_specs=(qspec, kvspec, kvspec),

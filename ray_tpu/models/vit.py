@@ -152,8 +152,7 @@ def _encoder_layer(cfg: ViTConfig, carry, lp):
     q = wsc(q, ("batch", "seq", "act_heads", None))
     k = wsc(k, ("batch", "seq", "act_heads", None))
     v = wsc(v, ("batch", "seq", "act_heads", None))
-    force_ref = jax.default_backend() != "tpu"
-    a = flash_attention(q, k, v, causal=False, force_reference=force_ref)
+    a = flash_attention(q, k, v, causal=False)
     x = x + (a.reshape(B, N, H * Dh) @ lp["wo"].astype(x.dtype))
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)

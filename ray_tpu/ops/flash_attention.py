@@ -14,13 +14,17 @@ mask so the same kernels serve ring attention (ops/ring_attention.py),
 where each ring step attends to a rotated kv shard with a different
 global offset.
 
-Runs in pallas interpret mode off-TPU (CPU tests), and falls back to a
-pure-jnp reference for shapes that don't tile (tiny head counts, ragged
-sequence lengths).
+`on_tpu()` is the one place that decides kernel vs reference for every
+caller (the models, ring, ulysses): the kernels are compiled only for a
+TPU backend; anywhere else the pure-jnp reference runs unless a test
+asks for the interpreter (`interpret=True`). Every dispatch decision is
+counted in `DISPATCH_COUNTS` at trace time, so a caller can see which
+path a compiled program took.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional, Tuple
@@ -33,6 +37,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+_SUBLANES = 8
+
+# path -> times chosen, counted when a call is TRACED (once per compile,
+# not per execution). Paths: "pallas", "pallas_interpret", and
+# "reference_<why>" with why in forced / untileable / no_tpu / short_kv;
+# ring_attention counts "ring_pallas" and "ring_reference_<why>".
+DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
 
 
 def _sds(shape, dtype, *like):
@@ -44,8 +55,10 @@ def _sds(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def on_tpu() -> bool:
+    """Whether Pallas kernels compile for the device this process
+    computes on. The models never ask the backend themselves."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -411,10 +424,27 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _pick_block(s: int, target: int) -> int:
-    b = min(target, s)
-    while s % b:
-        b -= 1
+    """Largest block <= target the TPU lowering accepts for a dimension
+    of s rows: all of s, or a multiple of 8 rows that divides s (bf16
+    compiles at 8 as well; checked by tests/test_tpu_aot_compile.py).
+    0 when there is none."""
+    if s <= target:
+        return s
+    b = target - target % _SUBLANES
+    while b and s % b:
+        b -= _SUBLANES
     return b
+
+
+def tileable(sq: int, skv: int, d: int, block_q: int, block_k: int
+             ) -> Tuple[int, int]:
+    """(block_q, block_k) for the kernels, or (0, 0) when the shape
+    cannot be tiled (tiny or ragged sequences, odd head dims) and must
+    take the reference."""
+    bq, bk = _pick_block(sq, block_q), _pick_block(skv, block_k)
+    if bq >= _SUBLANES and bk >= _SUBLANES and d % _SUBLANES == 0:
+        return bq, bk
+    return 0, 0
 
 
 def _expand_kv(x: jax.Array, n_heads: int) -> jax.Array:
@@ -440,41 +470,44 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Offsets are *global token positions* of element 0 of the q / kv
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     Returns (B, Sq, H, D).
+
+    `interpret=None` compiles the kernels on a TPU and takes the
+    reference anywhere else; `True` runs them in the Pallas interpreter
+    (kernel tests on the CPU); `False` compiles them whatever the
+    backend (ahead-of-time compiles for a described chip).
     """
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = _interpret_default()
 
     qt = jnp.swapaxes(q, 1, 2)
     kt = _expand_kv(jnp.swapaxes(k, 1, 2), H)
     vt = _expand_kv(jnp.swapaxes(v, 1, 2), H)
 
-    bq = _pick_block(Sq, block_q)
-    bk = _pick_block(Skv, block_k)
-    # Tiling floor: tiny/ragged shapes route to the fused-by-XLA reference.
-    use_pallas = (not force_reference and bq >= 8 and bk >= 8
-                  and D % 8 == 0)
-    # Crossover dispatch (measured on v5e): below ~2k kv positions XLA's
-    # own attention fusion beats the pallas kernels (the O(S^2) buffer is
-    # still cheap and XLA overlaps the surrounding matmuls better); the
-    # pallas path wins once the score matrix dominates HBM. interpret
-    # mode (CPU tests) always runs the kernels — that's its purpose.
-    if (use_pallas and not interpret and not force_pallas
-            and Skv < _XLA_CROSSOVER_SKV):
-        use_pallas = False
-    # pallas interpret mode (CPU tests) can't run under shard_map's
-    # varying-axes checks — those tests exercise the jnp reference.
-    # (jax.typeof is newer-jax only; without it there are no vma checks
-    # to trip, so the guard is moot.)
-    _typeof = getattr(jax, "typeof", None)
-    if interpret and _typeof is not None and _typeof(qt).vma:
-        use_pallas = False
+    bq, bk = tileable(Sq, Skv, D, block_q, block_k)
+    compiled = on_tpu() if interpret is None else not interpret
+    if force_reference:
+        path = "reference_forced"
+    elif not bq:
+        path = "reference_untileable"
+    elif force_pallas or interpret:
+        path = "pallas" if compiled else "pallas_interpret"
+    elif not compiled:
+        # Off the TPU the interpreter is a test tool, never a default.
+        path = "reference_no_tpu"
+    elif Skv < _XLA_CROSSOVER_SKV:
+        # Below the crossover the O(S^2) score buffer is still cheap and
+        # XLA fuses attention with the surrounding matmuls. The value
+        # dates from the earlier remote installation and has not been
+        # re-measured on the local chip (ROADMAP Queue 1 item 4).
+        path = "reference_short_kv"
+    else:
+        path = "pallas"
+    DISPATCH_COUNTS[path] += 1
     offs = jnp.asarray([[q_offset, kv_offset]], jnp.float32)
-    out = _flash(qt, kt, vt, offs, causal, sm_scale, bq, bk, use_pallas,
-                 interpret)
+    out = _flash(qt, kt, vt, offs, causal, sm_scale, bq, bk,
+                 path.startswith("pallas"), not compiled)
     return jnp.swapaxes(out, 1, 2)
 
 

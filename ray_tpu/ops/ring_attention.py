@@ -31,12 +31,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from .flash_attention import (
+    DISPATCH_COUNTS,
     NEG_INF,
     _bwd_impl,
     _fwd_impl,
-    _interpret_default,
-    _pick_block,
     _reference,
+    on_tpu,
+    tileable,
 )
 
 
@@ -72,7 +73,7 @@ def _one_step(q, k, v, offs, *, causal, sm_scale, block_q, block_k,
     if use_pallas:
         return _fwd_impl(q, k, v, offs, sm_scale=sm_scale,
                          block_q=block_q, block_k=block_k, causal=causal,
-                         interpret=_interpret_default())
+                         interpret=False)
     return _reference(q, k, v, offs, sm_scale=sm_scale, causal=causal)
 
 
@@ -105,8 +106,10 @@ def _ring_fwd(q, k, v, axis_name, causal, sm_scale, block_q, block_k,
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
         return (k_nxt, v_nxt, out_new, lse_new), None
 
-    out0 = lax.pvary(jnp.zeros((B, H, S, D), jnp.float32), axis_name)
-    lse0 = lax.pvary(jnp.full((B, H, S), NEG_INF, jnp.float32), axis_name)
+    out0 = lax.pcast(jnp.zeros((B, H, S, D), jnp.float32), axis_name,
+                     to="varying")
+    lse0 = lax.pcast(jnp.full((B, H, S), NEG_INF, jnp.float32), axis_name,
+                     to="varying")
     (k_back, v_back, out, lse), _ = lax.scan(
         body, (k, v, out0, lse0), jnp.arange(n))
     # n rotations = full circle: k_back/v_back are the original shards.
@@ -127,7 +130,7 @@ def _ring_bwd(axis_name, causal, sm_scale, block_q, block_k, use_pallas,
             return _bwd_impl(q, k_cur, v_cur, g, out, lse, offs,
                              sm_scale=sm_scale, block_q=block_q,
                              block_k=block_k, causal=causal,
-                             interpret=_interpret_default())
+                             interpret=False)
         # jnp fallback: unnormalized-softmax gradient against global lse.
         s = (jnp.einsum("bhqd,bhkd->bhqk", q, k_cur)
              .astype(jnp.float32) * sm_scale)
@@ -174,9 +177,9 @@ def _ring_bwd(axis_name, causal, sm_scale, block_q, block_k, use_pallas,
         dv_nxt = lax.ppermute(dv_new, axis_name, perm)
         return (k_nxt, v_nxt, dk_nxt, dv_nxt, dq_new), None
 
-    dq0 = lax.pvary(jnp.zeros(q.shape, jnp.float32), axis_name)
-    dk0 = lax.pvary(jnp.zeros(k.shape, jnp.float32), axis_name)
-    dv0 = lax.pvary(jnp.zeros(v.shape, jnp.float32), axis_name)
+    dq0, dk0, dv0 = (
+        lax.pcast(jnp.zeros(x.shape, jnp.float32), axis_name,
+                  to="varying") for x in (q, k, v))
     (k_b, v_b, dk, dv, dq), _ = lax.scan(
         body, (k, v, dk0, dv0, dq0), jnp.arange(n))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -207,10 +210,14 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
         rep = H // kt.shape[1]
         kt = jnp.repeat(kt, rep, axis=1)
         vt = jnp.repeat(vt, rep, axis=1)
-    bq = _pick_block(S, block_q)
-    bk = _pick_block(S, block_k)
-    use_pallas = (bq >= 8 and bk >= 8 and D % 8 == 0
-                  and not _interpret_default())
+    # Same blocks and the same decision as flash_attention: the kernels
+    # on a TPU, the reference (never the interpreter) anywhere else.
+    bq, bk = tileable(S, S, D, block_q, block_k)
+    tpu = on_tpu()
+    use_pallas = bool(bq) and tpu
+    DISPATCH_COUNTS["ring_pallas" if use_pallas
+                    else "ring_reference_untileable" if tpu
+                    else "ring_reference_no_tpu"] += 1
     out = _ring(qt, kt, vt, axis_name, causal, sm_scale, bq, bk,
                 use_pallas)
     return jnp.swapaxes(out, 1, 2)

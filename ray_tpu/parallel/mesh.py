@@ -49,8 +49,9 @@ def make_mesh(plan: ParallelPlan, *, devices: Optional[Sequence] = None):
     """Build a jax.sharding.Mesh shaped by the plan.
 
     On TPU, uses mesh_utils.create_device_mesh for ICI-aware placement
-    (innermost axes ↔ nearest-neighbor links). On CPU (tests), a plain
-    reshape of the device list.
+    (innermost axes ↔ nearest-neighbor links) and raises what it raises:
+    a silent reshape would hide a wrong ICI layout on a real host. On
+    CPU (tests), a plain reshape of the device list.
     """
     import jax
     from jax.sharding import Mesh
@@ -65,12 +66,9 @@ def make_mesh(plan: ParallelPlan, *, devices: Optional[Sequence] = None):
 
     shape = plan.mesh_shape
     if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
-            arr = mesh_utils.create_device_mesh(
-                shape, devices=devices, allow_split_physical_axes=True)
-        except Exception:  # noqa: BLE001 — odd topologies: fall back
-            arr = np.asarray(devices).reshape(shape)
+        from jax.experimental import mesh_utils
+        arr = mesh_utils.create_device_mesh(
+            shape, devices=devices, allow_split_physical_axes=True)
     else:
         arr = np.asarray(devices).reshape(shape)
     return Mesh(arr, plan.mesh_axis_names)

@@ -19,6 +19,7 @@ import optax
 from ..models.transformer import (
     TransformerConfig,
     init_params,
+    init_params_sharded,
     loss_fn,
     param_logical_axes,
 )
@@ -78,13 +79,8 @@ def init_state(cfg: TransformerConfig, mesh, optimizer,
     """Initialize params directly into their target shardings (no host
     round-trip; each device materializes only its shard)."""
     p_shardings = tree_shardings(param_logical_axes(cfg), mesh)
-
-    @partial(jax.jit, out_shardings=p_shardings)
-    def _init(key):
-        return init_params(cfg, key)
-
+    params = init_params_sharded(cfg, jax.random.key(seed), mesh)
     with jax.sharding.set_mesh(mesh):
-        params = _init(jax.random.key(seed))
         o_shardings = opt_state_shardings(
             optimizer, params, p_shardings, mesh)
         opt_state = jax.jit(

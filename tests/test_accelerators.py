@@ -28,6 +28,38 @@ class TestAccelerators:
         monkeypatch.setenv(acc.VISIBLE_CHIPS_ENV, "0,1")
         assert acc.num_chips_per_host() == 2
 
+    def test_driver_counts_what_jax_sees_not_the_host_type(
+            self, monkeypatch):
+        """The chip machine is a one-chip container on a four-chip host
+        type: TPU_CHIPS_PER_HOST_BOUNDS=2,2,1 with one device visible.
+        The driver advertises what its jax client sees (none here)."""
+        monkeypatch.delenv(acc.VISIBLE_CHIPS_ENV, raising=False)
+        monkeypatch.setenv(acc.CHIPS_PER_HOST_BOUNDS_ENV, "2,2,1")
+        assert acc.num_chips_per_host() == 4
+        assert acc.num_chips_driven() == 0
+        monkeypatch.setenv(acc.VISIBLE_CHIPS_ENV, "0,1")
+        assert acc.num_chips_driven() == 2
+
+    @pytest.mark.parametrize("msg", [
+        {"type": "task", "num_tpus": 1.0},            # python planes
+        {"type": "actor_create", "num_tpus": 0.5},
+        {"type": "task", "resources": {"TPU": 4.0}},  # native hand-off
+    ])
+    def test_tpu_request_in_cpu_pinned_worker_fails(self, monkeypatch,
+                                                    msg):
+        """One process owns a chip: a worker pinned to the CPU refuses a
+        task or actor that asked for num_tpus instead of computing on
+        the CPU without a word."""
+        from ray_tpu.core.worker_main import _refuse_tpu_on_cpu_pin
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        with pytest.raises(RuntimeError, match="pinned to the CPU"):
+            _refuse_tpu_on_cpu_pin(msg)
+        _refuse_tpu_on_cpu_pin({"type": "task",
+                                "resources": {"CPU": 1.0}})
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        _refuse_tpu_on_cpu_pin(msg)  # this worker may own the chip
+
     def test_chips_per_host_from_visibility(self, monkeypatch):
         monkeypatch.delenv(acc.CHIPS_PER_HOST_BOUNDS_ENV, raising=False)
         monkeypatch.setenv(acc.VISIBLE_CHIPS_ENV, "0,1,2")

@@ -1,0 +1,236 @@
+"""The main path's programs, compiled at real widths for a TPU v5e that is
+described and not attached (on-chip-measurement guide, section 2).
+
+Interpret mode on the CPU cannot see what the TPU's compiler refuses: a
+block that is not aligned to the tiling, too much fast memory, an eager
+op on a device that is not there. These compiles can, at no chip time.
+Nothing runs, so nothing here says anything about results or speed.
+Skipped as a whole where the topology cannot be described.
+"""
+
+import dataclasses
+import importlib
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import configs
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compilation_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _qkv(topo, sq, skv, h, kvh, d):
+    one = SingleDeviceSharding(topo.devices[0])
+    return (jax.ShapeDtypeStruct((1, sq, h, d), jnp.bfloat16, sharding=one),
+            jax.ShapeDtypeStruct((1, skv, kvh, d), jnp.bfloat16,
+                                 sharding=one))
+
+
+def _fwd_bwd(q, k, v, **kw):
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, interpret=False, **kw
+        ).astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+GEOMETRY = {"llama-654m": (12, 4, 128), "gpt2-125m": (12, 12, 64)}
+
+
+@pytest.mark.parametrize("model", sorted(GEOMETRY))
+@pytest.mark.parametrize("seq", [1024, 8192])
+def test_flash_fwd_bwd_compiles(topo, model, seq):
+    """Forward and both backward kernels at the model's head geometry.
+    S=1024 is below the kv crossover (the models take XLA's attention
+    there), so the kernels are forced; S=8192 takes them by itself."""
+    q, k = _qkv(topo, seq, seq, *GEOMETRY[model])
+    text = jax.jit(_fwd_bwd, static_argnames="force_pallas").lower(
+        q, k, k, force_pallas=seq < fa._XLA_CROSSOVER_SKV
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3   # fwd, dq, dkv
+
+
+def test_flash_fwd_alone_compiles(topo):
+    q, k = _qkv(topo, 8192, 8192, *GEOMETRY["llama-654m"])
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, interpret=False)).lower(
+            q, k, k).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sq,skv,blocks", [
+    # S=1000: the largest divisors were 250 and 500, neither a multiple
+    # of 8 rows, and the lowering refused them.
+    (1000, 1000, (200, 200)),
+    # The serve suffix path, prefix 2048 + suffix bucket 16: kv length
+    # 2064 = 2^4·3·43 used to pick 516.
+    (16, 2064, (16, 344)),
+])
+def test_formerly_unaligned_blocks_compile(topo, sq, skv, blocks):
+    assert fa.tileable(sq, skv, 128, 256, 512) == blocks
+    q, k = _qkv(topo, sq, skv, *GEOMETRY["llama-654m"])
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, q_offset=skv - sq, interpret=False,
+        force_pallas=True)).lower(q, k, k).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_untileable_shape_is_counted_not_refused(topo):
+    """No multiple of 8 divides 2^2·503 = 2012 under the block target:
+    the call takes the reference, visibly, instead of failing in
+    Mosaic."""
+    assert fa.tileable(2012, 2012, 128, 256, 512) == (0, 0)
+    q, k = _qkv(topo, 2012, 2012, *GEOMETRY["llama-654m"])
+    before = fa.DISPATCH_COUNTS["reference_untileable"]
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, interpret=False, force_pallas=True)).lower(
+            q, k, k).compile().as_text()
+    assert fa.DISPATCH_COUNTS["reference_untileable"] == before + 1
+    assert "tpu_custom_call" not in text
+
+
+def _serve_structs(topo, cfg, slots, max_seq):
+    from ray_tpu.models.generate import init_kv_cache
+    from ray_tpu.models.transformer import init_params
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    params = on_chip(jax.eval_shape(lambda k: init_params(cfg, k), key))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, slots, max_seq)))
+    return one, key, params, cache
+
+
+@pytest.fixture(scope="module")
+def serve_654m(topo):
+    cfg = dataclasses.replace(configs.llama_654m(),
+                              param_dtype=jnp.bfloat16, max_seq_len=1024)
+    return (cfg,) + _serve_structs(topo, cfg, 16, 1024)
+
+
+def test_decode_multi_compiles_at_654m(serve_654m):
+    """The engine's fused decode block (8 steps, 16 slots, S_max 1024,
+    bf16 weights) fits and compiles for one chip."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, one, key, params, cache = serve_654m
+    toks = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((16,), jnp.float32, sharding=one)
+    mem = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                             key).compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_prefill_sample_batch_compiles_at_654m(serve_654m):
+    """One admission tile (W=8 prompts in the 128 bucket)."""
+    from ray_tpu.models.generate import prefill_sample_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, one, key, params, cache = serve_654m
+    W = LLMEngine._ADMIT_TILE
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    prefill_sample_batch.lower(
+        cfg, params, cache, arr((W, 128), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+
+
+def _aot_compile_step(topo, cfg, n_chips, **kw):
+    sys.path.insert(0, ROOT)
+    try:
+        from __graft_entry__ import _aot_compile_step as compile_step
+    finally:
+        sys.path.remove(ROOT)
+    from ray_tpu.parallel import ParallelPlan
+
+    plan = ParallelPlan.auto(n_chips) if n_chips > 1 else ParallelPlan()
+    return compile_step(cfg, plan, devices=topo.devices[:n_chips], **kw)
+
+
+def test_train_step_compiles_on_described_chips(topo):
+    """`_aot_compile_step` hands the whole sharded train step to the
+    TPU's compiler without dispatching anything onto devices that are
+    not attached (a `jax.random.key(0)` under the mesh used to). Tiny
+    width over four chips here; the 654M step, on one chip and on four,
+    is the slow case below."""
+    _aot_compile_step(topo, configs.tiny_test(), 4, batch=8, seq=128)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_654m_train_step_compiles_and_fits(topo, n_chips):
+    """llama-654m, batch 8 x 1024: about a quarter of a minute each, so
+    outside tier-1. Per-device bytes must fit a 16 GB chip."""
+    mem = _aot_compile_step(topo, configs.llama_654m(), n_chips, batch=8,
+                            seq=1024).memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_ring_attention_compiles_with_kernels_on_four_chips(topo,
+                                                            monkeypatch):
+    """The ring calls the same kernels with the same blocks, inside
+    shard_map: forward and backward over an sp=4 mesh of the described
+    chips, 654M geometry, 2048 positions a chip. `on_tpu()` answers for
+    the CPU host here, so the test answers for it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    ring = importlib.import_module("ray_tpu.ops.ring_attention")
+    monkeypatch.setattr(ring, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("sp",))
+    seq = NamedSharding(mesh, P(None, "sp"))
+    h, kvh, d = GEOMETRY["llama-654m"]
+    q = jax.ShapeDtypeStruct((1, 8192, h, d), jnp.bfloat16, sharding=seq)
+    k = jax.ShapeDtypeStruct((1, 8192, kvh, d), jnp.bfloat16, sharding=seq)
+
+    def loss(q, k, v):
+        out = jax.shard_map(
+            lambda q, k, v: ring.ring_attention(q, k, v, "sp"),
+            mesh=mesh, in_specs=(P(None, "sp"),) * 3,
+            out_specs=P(None, "sp"), check_vma=False)(q, k, v)
+        return jnp.sum(out.astype(jnp.float32))
+
+    before = fa.DISPATCH_COUNTS["ring_pallas"]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    assert fa.DISPATCH_COUNTS["ring_pallas"] == before + 1
+    assert "tpu_custom_call" in text and "collective-permute" in text
