@@ -25,6 +25,7 @@ Capacity control comes from S_max buckets instead of pages.
 
 from __future__ import annotations
 
+import types
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
@@ -42,6 +43,82 @@ from .transformer import (
     rms_norm,
     rope_tables,
 )
+
+
+# What the device's trace calls each serving program: its module events
+# read `jit_<name>`. The jitted functions below are built from this
+# table, so a refactor cannot rename one by accident. Two families, told
+# apart by name alone: a decode program's name contains `decode` and
+# neither `prefill` nor `first_token`; a prefill or first-token
+# program's name the reverse. A fused decode block carries its size
+# (`decode_k8`: 8 steps a launch), so the steps the device ran are the
+# sum of k x launches over the `jit_decode*_k<k>` events of a trace.
+PROGRAM_NAMES: Dict[str, str] = {
+    "prefill": "prefill",
+    "prefill_sample": "prefill_sample",
+    "prefill_sample_batch": "prefill_sample_batch",
+    "prefill_sample_batch_lp": "prefill_sample_batch_lp",
+    "prefill_suffix_batch": "prefill_suffix_batch",
+    "prefill_suffix_batch_lp": "prefill_suffix_batch_lp",
+    "first_token_sample": "first_token_sample",
+    "first_token_sample_lp": "first_token_sample_lp",
+    "first_token_suffix_sample": "first_token_suffix_sample",
+    "first_token_suffix_sample_lp": "first_token_suffix_sample_lp",
+    "decode_step": "decode_k1",
+    "decode_multi": "decode_k{k}",
+    "decode_multi_lp": "decode_lp_k{k}",
+    # The engine's own samplers after a one-step block (serve/llm.py).
+    "sample_batch": "sample_batch",
+    "sample_batch_lp": "sample_batch_lp",
+}
+
+
+def _named(fn, name: str):
+    """`fn` again under another name: the same code, signature and
+    globals. jit names a module after the function's `__name__`, and a
+    wrapper taking `*args` would not be the same function to it."""
+    twin = types.FunctionType(fn.__code__, fn.__globals__, name,
+                              fn.__defaults__, fn.__closure__)
+    twin.__kwdefaults__ = fn.__kwdefaults__
+    twin.__annotations__ = dict(fn.__annotations__)
+    twin.__doc__, twin.__module__ = fn.__doc__, fn.__module__
+    twin.__qualname__ = name
+    return twin
+
+
+def program(key: str, **jit_kw):
+    """Decorator: `jax.jit(fn, **jit_kw)` under the name the table gives
+    `key`."""
+
+    def build(fn):
+        return jax.jit(_named(fn, PROGRAM_NAMES[key]), **jit_kw)
+
+    return build
+
+
+class _BlockPrograms:
+    """The fused decode block, one jitted program per block size, each
+    named by its size. Called and lowered like the one jitted function it
+    stands for: `num_steps` is argument 5."""
+
+    def __init__(self, key: str, body, **jit_kw):
+        self._key, self._body, self._jit_kw = key, body, jit_kw
+        self._by_k: Dict[int, Any] = {}
+        self.__doc__ = body.__doc__
+
+    def program_for(self, num_steps: int):
+        fn = self._by_k.get(num_steps)
+        if fn is None:
+            name = PROGRAM_NAMES[self._key].format(k=num_steps)
+            fn = self._by_k[num_steps] = jax.jit(
+                _named(self._body, name), **self._jit_kw)
+        return fn
+
+    def __call__(self, *args):
+        return self.program_for(args[5])(*args)
+
+    def lower(self, *args):
+        return self.program_for(args[5]).lower(*args)
 
 
 class KVCache(NamedTuple):
@@ -194,7 +271,7 @@ def _prefill_core(cfg: TransformerConfig, params, cache: KVCache,
     return KVCache(k=k, v=v, seq_lens=seq_lens), last
 
 
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+@program("prefill", static_argnums=(0,), donate_argnums=(2,))
 def prefill(cfg: TransformerConfig, params, cache: KVCache,
             tokens: jax.Array, length: jax.Array, slot: jax.Array
             ) -> Tuple[KVCache, jax.Array]:
@@ -207,7 +284,7 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache,
     return _prefill_core(cfg, params, cache, tokens, length, slot)
 
 
-@partial(jax.jit, static_argnums=(0, 6), donate_argnums=(2,))
+@program("prefill_sample", static_argnums=(0, 6), donate_argnums=(2,))
 def prefill_sample(cfg: TransformerConfig, params, cache: KVCache,
                    tokens: jax.Array, length: jax.Array, slot: jax.Array,
                    top_k: int, temperature: jax.Array, key: jax.Array
@@ -259,7 +336,7 @@ def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
     return KVCache(k=k, v=v, seq_lens=seq_lens), logits
 
 
-@partial(jax.jit, static_argnums=(0, 6), donate_argnums=(2,))
+@program("prefill_sample_batch", static_argnums=(0, 6), donate_argnums=(2,))
 def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
                          tokens: jax.Array, lengths: jax.Array,
                          slots: jax.Array, top_k: int,
@@ -280,7 +357,7 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     return cache, toks
 
 
-@partial(jax.jit, static_argnums=(0, 6), donate_argnums=(2,))
+@program("prefill_sample_batch_lp", static_argnums=(0, 6), donate_argnums=(2,))
 def prefill_sample_batch_lp(cfg: TransformerConfig, params,
                             cache: KVCache, tokens: jax.Array,
                             lengths: jax.Array, slots: jax.Array,
@@ -354,7 +431,7 @@ def _last_token_sample(cfg: TransformerConfig, params, x, lens, temps,
     return sample(logits, key, temperature=temps, top_k=top_k)
 
 
-@partial(jax.jit, static_argnums=(0, 8), donate_argnums=(2,))
+@program("prefill_suffix_batch", static_argnums=(0, 8), donate_argnums=(2,))
 def prefill_suffix_batch(cfg: TransformerConfig, params, cache: KVCache,
                          prefix_k: jax.Array, prefix_v: jax.Array,
                          tokens: jax.Array, suffix_lens: jax.Array,
@@ -411,7 +488,7 @@ def _prefill_suffix_core(cfg: TransformerConfig, params, cache: KVCache,
     return KVCache(k=k, v=v, seq_lens=seq_lens), logits
 
 
-@partial(jax.jit, static_argnums=(0, 8), donate_argnums=(2,))
+@program("prefill_suffix_batch_lp", static_argnums=(0, 8), donate_argnums=(2,))
 def prefill_suffix_batch_lp(cfg: TransformerConfig, params,
                             cache: KVCache, prefix_k: jax.Array,
                             prefix_v: jax.Array, tokens: jax.Array,
@@ -427,7 +504,7 @@ def prefill_suffix_batch_lp(cfg: TransformerConfig, params,
     return cache, toks, token_logp(logits, toks)
 
 
-@partial(jax.jit, static_argnums=(0, 7))
+@program("first_token_suffix_sample", static_argnums=(0, 7))
 def first_token_suffix_sample(cfg: TransformerConfig, params,
                               prefix_k: jax.Array, prefix_v: jax.Array,
                               tokens: jax.Array, suffix_lens: jax.Array,
@@ -444,7 +521,7 @@ def first_token_suffix_sample(cfg: TransformerConfig, params,
                               top_k, key)
 
 
-@partial(jax.jit, static_argnums=(0, 7))
+@program("first_token_suffix_sample_lp", static_argnums=(0, 7))
 def first_token_suffix_sample_lp(cfg: TransformerConfig, params,
                                  prefix_k: jax.Array,
                                  prefix_v: jax.Array,
@@ -474,7 +551,7 @@ def compute_prefix_kv(cfg: TransformerConfig, params,
     return scratch.k[:, 0], scratch.v[:, 0]
 
 
-@partial(jax.jit, static_argnums=(0, 5))
+@program("first_token_sample", static_argnums=(0, 5))
 def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
                        lengths: jax.Array, temps: jax.Array, top_k: int,
                        key: jax.Array) -> jax.Array:
@@ -504,7 +581,7 @@ def _first_token_logits(cfg: TransformerConfig, params, tokens, lengths):
     return (last @ _lm_head(cfg, params)).astype(jnp.float32)[:, 0]
 
 
-@partial(jax.jit, static_argnums=(0, 5))
+@program("first_token_sample_lp", static_argnums=(0, 5))
 def first_token_sample_lp(cfg: TransformerConfig, params,
                           tokens: jax.Array, lengths: jax.Array,
                           temps: jax.Array, top_k: int, key: jax.Array
@@ -535,7 +612,7 @@ def _decode_core(cfg: TransformerConfig, params, cache: KVCache,
     return KVCache(k=k_new, v=v_new, seq_lens=positions + 1), logits
 
 
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+@program("decode_step", static_argnums=(0,), donate_argnums=(2,))
 def decode_step(cfg: TransformerConfig, params, cache: KVCache,
                 tokens: jax.Array) -> Tuple[KVCache, jax.Array]:
     """One decode step for every slot. tokens: (B,) int32 (last emitted
@@ -545,12 +622,12 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     return _decode_core(cfg, params, cache, tokens)
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6), donate_argnums=(2,))
-def decode_multi(cfg: TransformerConfig, params, cache: KVCache,
+def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
                  tokens: jax.Array, temps: jax.Array, num_steps: int,
                  top_k: int, key: jax.Array
                  ) -> Tuple[KVCache, jax.Array]:
-    """`num_steps` fused decode+sample ticks in ONE dispatch.
+    """`num_steps` fused decode+sample ticks in ONE dispatch, under the
+    name `decode_k<num_steps>`.
 
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
     temperature. Returns (cache', toks (num_steps, B)). The host engine
@@ -572,8 +649,12 @@ def decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     return cache, toks
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6), donate_argnums=(2,))
-def decode_multi_lp(cfg: TransformerConfig, params, cache: KVCache,
+decode_multi = _BlockPrograms("decode_multi", _decode_multi,
+                              static_argnums=(0, 5, 6),
+                              donate_argnums=(2,))
+
+
+def _decode_multi_lp(cfg: TransformerConfig, params, cache: KVCache,
                     tokens: jax.Array, temps: jax.Array, num_steps: int,
                     top_k: int, key: jax.Array
                     ) -> Tuple[KVCache, jax.Array, jax.Array]:
@@ -591,6 +672,11 @@ def decode_multi_lp(cfg: TransformerConfig, params, cache: KVCache,
     subs = jax.random.split(key, num_steps)
     (cache, _), (toks, lps) = lax.scan(body, (cache, tokens), subs)
     return cache, toks, lps
+
+
+decode_multi_lp = _BlockPrograms("decode_multi_lp", _decode_multi_lp,
+                                 static_argnums=(0, 5, 6),
+                                 donate_argnums=(2,))
 
 
 def sample(logits: jax.Array, key: jax.Array, *,

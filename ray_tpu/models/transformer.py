@@ -406,9 +406,13 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """tokens (B, S) int32 → (logits (B, S, V) float32, aux_loss)."""
     x, aux = forward_hidden(cfg, params, tokens)
+    return _logits(cfg, params, x), aux
+
+
+def _logits(cfg: TransformerConfig, params: Dict[str, Any],
+            x: jax.Array) -> jax.Array:
     logits = (x @ _lm_head(cfg, params)).astype(jnp.float32)
-    logits = wsc(logits, ("batch", "seq", "act_vocab"))
-    return logits, aux
+    return wsc(logits, ("batch", "seq", "act_vocab"))
 
 
 def token_cross_entropy(logits: jax.Array, targets: jax.Array,
@@ -471,17 +475,24 @@ def chunked_cross_entropy(cfg: TransformerConfig, params: Dict[str, Any],
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array, targets: jax.Array,
             mask: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
+    """The scopes name the phases in a device trace: an operation of
+    the trunk's forward pass reads `.../jvp(fwd)/...` under
+    `value_and_grad`, its backward pass (remat recomputation included)
+    `.../transpose(jvp(fwd))/...`, the head and the cross entropy the
+    same under `loss_head`."""
     S = tokens.shape[1]
-    if cfg.ce_chunk > 0:
-        if S % cfg.ce_chunk != 0:
-            # Accepted ≠ enforced: silently materializing the full
-            # logits tensor is exactly what the option exists to avoid.
-            raise ValueError(
-                f"ce_chunk={cfg.ce_chunk} must divide the sequence "
-                f"length (got S={S})")
-        if S > cfg.ce_chunk:
-            x, aux = forward_hidden(cfg, params, tokens)
+    chunked = cfg.ce_chunk > 0 and S > cfg.ce_chunk
+    if cfg.ce_chunk > 0 and S % cfg.ce_chunk != 0:
+        # Accepted ≠ enforced: silently materializing the full
+        # logits tensor is exactly what the option exists to avoid.
+        raise ValueError(
+            f"ce_chunk={cfg.ce_chunk} must divide the sequence "
+            f"length (got S={S})")
+    with jax.named_scope("fwd"):
+        x, aux = forward_hidden(cfg, params, tokens)
+    with jax.named_scope("loss_head"):
+        if chunked:
             return chunked_cross_entropy(cfg, params, x, targets, mask,
                                          aux, cfg.ce_chunk)
-    logits, aux = forward(cfg, params, tokens)
-    return token_cross_entropy(logits, targets, mask, aux)
+        return token_cross_entropy(_logits(cfg, params, x), targets, mask,
+                                   aux)

@@ -170,6 +170,7 @@ def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_fwd"},
     )(offs, q, k, v)
     return out, lse[..., 0]
 
@@ -331,6 +332,7 @@ def _bwd_impl(q, k, v, do, out, lse, offs, *, sm_scale, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_dq"},
     )(offs, q, k, v, do, lse_l, delta_l)[0]
 
     # dkv grid: kv blocks parallel, q loop innermost/sequential.
@@ -351,6 +353,7 @@ def _bwd_impl(q, k, v, do, out, lse, offs, *, sm_scale, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_dkv"},
     )(offs, q, k, v, do, lse_l, delta_l)
     return dq, dk, dv
 

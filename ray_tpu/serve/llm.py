@@ -27,7 +27,6 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 import jax
@@ -37,6 +36,7 @@ import numpy as np
 from ..core.graphable import graphable
 from ..models.generate import (
     KVCache,
+    program,
     compute_prefix_kv,
     decode_multi,
     decode_multi_lp,
@@ -56,6 +56,7 @@ from ..models.transformer import (
     init_params,
     init_params_sharded,
 )
+from ..util import tracing
 
 # Jitted so that under an ambient mesh the cache is created sharded
 # (eagerly, jnp.zeros would first place it whole on the default device).
@@ -71,7 +72,7 @@ def default_buckets(max_prompt_len: int) -> List[int]:
     return out
 
 
-@partial(jax.jit, static_argnums=(3,))
+@program("sample_batch", static_argnums=(3,))
 def _sample_batch(logits: jax.Array, temps: jax.Array, key: jax.Array,
                   top_k: int) -> jax.Array:
     """(B,V) logits -> (B,) tokens; temp<=0 slots decode greedily."""
@@ -80,7 +81,7 @@ def _sample_batch(logits: jax.Array, temps: jax.Array, key: jax.Array,
     return sample(logits, key, temperature=temps, top_k=top_k)
 
 
-@partial(jax.jit, static_argnums=(3,))
+@program("sample_batch_lp", static_argnums=(3,))
 def _sample_batch_lp(logits: jax.Array, temps: jax.Array, key: jax.Array,
                      top_k: int):
     """(B,V) logits -> ((B,) tokens, (B,) log-probs of those tokens)."""
@@ -104,6 +105,14 @@ class GenRequest:
     # queue_s (submit→admit) vs prefill_s (admit→first token) for the
     # critpath/TTFT waterfall.
     admit_ts: float = 0.0
+    # The engine tick (`engine.tick` span, attribute `tick`) that first
+    # touched the request, and the decode steps that stood between it
+    # and the device at that moment: steps dispatched by then, less
+    # those whose tokens the host had already read when the request
+    # was submitted. A request that missed the engine's look at its
+    # queue reads one block more than its twin that did not.
+    admit_tick: int = -1
+    steps_waited: int = 0
     first_token_ts: float = 0.0
     finish_ts: float = 0.0
     stream: "queue.Queue" = field(default_factory=queue.Queue)
@@ -118,6 +127,8 @@ class GenRequest:
     # First token served queue-side before any slot freed (engine-
     # internal; _admit resumes decode from it).
     _early_tok: Optional[int] = field(default=None, repr=False)
+    # engine.steps_processed at submit (engine-internal).
+    _steps_seen: int = field(default=0, repr=False)
 
     @property
     def ttft_s(self) -> float:
@@ -299,9 +310,21 @@ class LLMEngine:
         self._auto_inflight: set = set()
         self.prefix_register_failures = 0
         # aggregate stats
-        self.decode_ticks = 0
+        self.decode_ticks = 0       # decode steps dispatched
+        self.steps_processed = 0    # ... whose tokens the host has read
         self.tokens_out = 0
-        self.finished: List[Dict[str, float]] = []
+        # What the spans carry as attributes, summed where the work
+        # happens (stats()["counts"]; docs/METRICS.md): an operator has
+        # them without a profiler.
+        self.counts: Dict[str, Any] = {
+            "ticks": 0, "blocks": 0, "blocks_by_k": {}, "slot_steps": 0,
+            "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
+            "prefill_tile_rows": 0, "prefill_tokens": 0,
+            "prefill_tile_tokens": 0, "queue_side_first_tokens": 0}
+        # The last FINISHED_RING completed requests (ttft percentiles
+        # in stats() are over these).
+        self.finished: deque = deque(maxlen=self.FINISHED_RING)
+        self._n_finished = 0
         # Recent-TTFT EWMA: the router's SLO-aware tiebreak signal
         # (cheap to read every stats poll, unlike the sorted
         # percentiles in stats()).
@@ -335,6 +358,7 @@ class LLMEngine:
             req.id = self._next_id
             self._next_id += 1
         req.submit_ts = time.monotonic()
+        req._steps_seen = self.steps_processed
         if self.auto_prefix_min_hits > 0:
             self._note_prefix_candidates(prompt)
         with self.lock:
@@ -586,6 +610,7 @@ class LLMEngine:
         and queue-side finishes alike)."""
         req.finish_ts = time.monotonic()
         req.stream.put(None)
+        self._n_finished += 1
         self.finished.append({
             "id": req.id,
             "ttft_s": req.ttft_s,
@@ -607,6 +632,38 @@ class LLMEngine:
         self.slots[idx] = None
 
     _ADMIT_TILE = 8  # fixed batch tile: ONE compile per bucket, ever
+    FINISHED_RING = 1024
+
+    def _touch(self, reqs: Sequence[GenRequest]) -> None:
+        """Stamp the requests that engine compute touches for the first
+        time in this tick (slot admission or the queue-side pass)."""
+        now = time.monotonic()
+        for req in reqs:
+            if req.admit_ts == 0.0:
+                req.admit_ts = now
+                req.admit_tick = self.counts["ticks"] - 1
+                req.steps_waited = self.decode_ticks - req._steps_seen
+
+    def _tile_span(self, side: str, bucket: int, reqs: Sequence[GenRequest],
+                   skip: int = 0) -> tracing.span:
+        """The span of one prefill tile (build, transfer, program call),
+        with its counts: `rows` real of `tile_rows`, `tokens` real prompt
+        tokens of tile_rows x bucket computed (`skip`: tokens a cached
+        prefix already holds)."""
+        W = self._ADMIT_TILE
+        tokens = sum(len(r.prompt) - skip for r in reqs)
+        c = self.counts
+        c["prefill_tiles"] += 1
+        c["prefill_rows"] += len(reqs)
+        c["prefill_tile_rows"] += W
+        c["prefill_tokens"] += tokens
+        c["prefill_tile_tokens"] += W * bucket
+        if side == "queue":
+            c["queue_side_first_tokens"] += len(reqs)
+        return tracing.span(
+            "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
+            tile_rows=W, tokens=tokens,
+            req_ids=" ".join(str(r.id) for r in reqs))
 
     @classmethod
     def _build_tile(cls, bucket: int, rows: Sequence):
@@ -647,10 +704,7 @@ class LLMEngine:
                 take.append(self.waiting.popleft())
         if not take:
             return []
-        now = time.monotonic()
-        for req in take:
-            if req.admit_ts == 0.0:
-                req.admit_ts = now
+        self._touch(take)
 
         admitted: List = []  # (idx, tok_dev, lp_dev|None) — pending
         # Route: prompts strictly extending a registered prefix go
@@ -672,39 +726,42 @@ class LLMEngine:
                 slot_idx[j] = idx
             self._key, sub = jax.random.split(self._key)
             lps = None
+            reqs = [req for req, _ in chunk]
             try:
                 if kind == "full":
                     bucket = binfo
-                    buf, lens, temps = self._build_tile(
-                        bucket,
-                        [(req.prompt, req.temperature)
-                         for req, _ in chunk])
-                    args = (self.cfg, self.params, self.cache,
-                            jnp.asarray(buf), jnp.asarray(lens),
-                            jnp.asarray(slot_idx), self.top_k,
-                            jnp.asarray(temps), sub)
-                    if self.capture_logprobs:
-                        self.cache, toks, lps = \
-                            prefill_sample_batch_lp(*args)
-                    else:
-                        self.cache, toks = prefill_sample_batch(*args)
+                    with self._tile_span("slot", bucket, reqs):
+                        buf, lens, temps = self._build_tile(
+                            bucket,
+                            [(req.prompt, req.temperature)
+                             for req in reqs])
+                        args = (self.cfg, self.params, self.cache,
+                                jnp.asarray(buf), jnp.asarray(lens),
+                                jnp.asarray(slot_idx), self.top_k,
+                                jnp.asarray(temps), sub)
+                        if self.capture_logprobs:
+                            self.cache, toks, lps = \
+                                prefill_sample_batch_lp(*args)
+                        else:
+                            self.cache, toks = prefill_sample_batch(*args)
                 else:
                     pkey, bucket = binfo
                     sp = len(pkey)
-                    buf, lens, temps = self._build_tile(
-                        bucket,
-                        [(req.prompt[sp:], req.temperature)
-                         for req, _ in chunk])
-                    args = (self.cfg, self.params, self.cache,
-                            entry["k"], entry["v"],
-                            jnp.asarray(buf), jnp.asarray(lens),
-                            jnp.asarray(slot_idx), self.top_k,
-                            jnp.asarray(temps), sub)
-                    if self.capture_logprobs:
-                        self.cache, toks, lps = \
-                            prefill_suffix_batch_lp(*args)
-                    else:
-                        self.cache, toks = prefill_suffix_batch(*args)
+                    with self._tile_span("slot", bucket, reqs, skip=sp):
+                        buf, lens, temps = self._build_tile(
+                            bucket,
+                            [(req.prompt[sp:], req.temperature)
+                             for req in reqs])
+                        args = (self.cfg, self.params, self.cache,
+                                entry["k"], entry["v"],
+                                jnp.asarray(buf), jnp.asarray(lens),
+                                jnp.asarray(slot_idx), self.top_k,
+                                jnp.asarray(temps), sub)
+                        if self.capture_logprobs:
+                            self.cache, toks, lps = \
+                                prefill_suffix_batch_lp(*args)
+                        else:
+                            self.cache, toks = prefill_suffix_batch(*args)
                     self.prefix_hits += len(chunk)
                     self.prefix_tokens_saved += sp * len(chunk)
             except Exception:
@@ -751,41 +808,40 @@ class LLMEngine:
                     if r.first_token_ts == 0.0]
         if not todo:
             return []
-        now = time.monotonic()
-        for r in todo:
-            if r.admit_ts == 0.0:
-                # Queue-side compute IS this request's admission for
-                # TTFT-waterfall purposes (prefill starts here).
-                r.admit_ts = now
+        # Queue-side compute IS a request's admission for TTFT-waterfall
+        # purposes (prefill starts here).
+        self._touch(todo)
         outs = []
         full, suffix = self._group_by_route(todo, lambda r: r.prompt)
         for bucket, chunk in full:
-            buf, lens, temps = self._build_tile(
-                bucket, [(r.prompt, r.temperature) for r in chunk])
-            self._key, sub = jax.random.split(self._key)
-            args = (self.cfg, self.params, jnp.asarray(buf),
-                    jnp.asarray(lens), jnp.asarray(temps), self.top_k,
-                    sub)
-            if self.capture_logprobs:
-                toks, lps = first_token_sample_lp(*args)
-            else:
-                toks, lps = first_token_sample(*args), None
+            with self._tile_span("queue", bucket, chunk):
+                buf, lens, temps = self._build_tile(
+                    bucket, [(r.prompt, r.temperature) for r in chunk])
+                self._key, sub = jax.random.split(self._key)
+                args = (self.cfg, self.params, jnp.asarray(buf),
+                        jnp.asarray(lens), jnp.asarray(temps), self.top_k,
+                        sub)
+                if self.capture_logprobs:
+                    toks, lps = first_token_sample_lp(*args)
+                else:
+                    toks, lps = first_token_sample(*args), None
             outs.append((chunk, toks, lps))
         # Prefix-matched queued requests: suffix-only forward against
         # the stored prefix KV (same FLOP saving as slot admission).
         for pkey, entry, bucket, chunk in suffix:
             sp = len(pkey)
-            buf, lens, temps = self._build_tile(
-                bucket, [(r.prompt[sp:], r.temperature)
-                         for r in chunk])
-            self._key, sub = jax.random.split(self._key)
-            args = (self.cfg, self.params, entry["k"], entry["v"],
-                    jnp.asarray(buf), jnp.asarray(lens),
-                    jnp.asarray(temps), self.top_k, sub)
-            if self.capture_logprobs:
-                toks, lps = first_token_suffix_sample_lp(*args)
-            else:
-                toks, lps = first_token_suffix_sample(*args), None
+            with self._tile_span("queue", bucket, chunk, skip=sp):
+                buf, lens, temps = self._build_tile(
+                    bucket, [(r.prompt[sp:], r.temperature)
+                             for r in chunk])
+                self._key, sub = jax.random.split(self._key)
+                args = (self.cfg, self.params, entry["k"], entry["v"],
+                        jnp.asarray(buf), jnp.asarray(lens),
+                        jnp.asarray(temps), self.top_k, sub)
+                if self.capture_logprobs:
+                    toks, lps = first_token_suffix_sample_lp(*args)
+                else:
+                    toks, lps = first_token_suffix_sample(*args), None
             self.prefix_hits += len(chunk)
             self.prefix_tokens_saved += sp * len(chunk)
             outs.append((chunk, toks, lps))
@@ -799,25 +855,28 @@ class LLMEngine:
         block)."""
         if not admitted and not outs:
             return None
-        parts = []
-        if admitted:
-            parts.append(jnp.stack([t for _, t, _ in admitted]))
-        parts += [t for _, t, _ in outs]
-        fused = jnp.concatenate(parts)
-        fused_lp = None
-        if self.capture_logprobs:
-            lp_parts = []
+        with tracing.span("engine.fuse_first",
+                          parts=bool(admitted) + len(outs)):
+            parts = []
             if admitted:
-                lp_parts.append(jnp.stack([l for _, _, l in admitted]))
-            lp_parts += [l for _, _, l in outs]
-            fused_lp = jnp.concatenate(lp_parts)
-        for arr in (fused, fused_lp):
-            if arr is None:
-                continue
-            try:
-                arr.copy_to_host_async()
-            except Exception:  # noqa: BLE001 — no async copy
-                pass
+                parts.append(jnp.stack([t for _, t, _ in admitted]))
+            parts += [t for _, t, _ in outs]
+            fused = jnp.concatenate(parts)
+            fused_lp = None
+            if self.capture_logprobs:
+                lp_parts = []
+                if admitted:
+                    lp_parts.append(
+                        jnp.stack([l for _, _, l in admitted]))
+                lp_parts += [l for _, _, l in outs]
+                fused_lp = jnp.concatenate(lp_parts)
+            for arr in (fused, fused_lp):
+                if arr is None:
+                    continue
+                try:
+                    arr.copy_to_host_async()
+                except Exception:  # noqa: BLE001 — no async copy
+                    pass
         return fused, fused_lp
 
     def _deliver_first_tokens(self, fused_pair, admitted: List,
@@ -827,9 +886,16 @@ class LLMEngine:
         if fused_pair is None:
             return
         fused, fused_lp = fused_pair
-        fused = np.asarray(fused)
-        fused_lp = (np.asarray(fused_lp) if fused_lp is not None
-                    else None)
+        with tracing.span("engine.deliver_first", tokens=len(admitted)
+                          + sum(len(reqs) for reqs, _, _ in outs)):
+            with tracing.span("engine.fetch"):  # the host waits here
+                fused = np.asarray(fused)
+                fused_lp = (np.asarray(fused_lp) if fused_lp is not None
+                            else None)
+            self._emit_first_tokens(fused, fused_lp, admitted, outs)
+
+    def _emit_first_tokens(self, fused, fused_lp, admitted: List,
+                           outs: List) -> None:
         pos = 0
         now = time.monotonic()
         if admitted:
@@ -881,7 +947,11 @@ class LLMEngine:
         exact; the host only lags by one block in observing tokens, so
         EOS/finish frees a slot one tick late (bounded overshoot, same
         class as mid-block overshoot). Returns False when idle."""
-        with self._mesh_ctx():
+        tick = self.counts["ticks"]
+        self.counts["ticks"] = tick + 1
+        with self._mesh_ctx(), tracing.span(
+                "engine.tick", tick=tick, waiting=len(self.waiting),
+                active=sum(s is not None for s in self.slots)):
             return self._step_impl()
 
     def _step_impl(self) -> bool:
@@ -923,43 +993,7 @@ class LLMEngine:
                               max(1, headroom))
                 while k_block & (k_block - 1):
                     k_block &= k_block - 1
-
-                self._key, sub = jax.random.split(self._key)
-                lps = None
-                if k_block == 1:
-                    self.cache, logits = decode_step(
-                        self.cfg, self.params, self.cache,
-                        self.cur_tokens)
-                    if self.capture_logprobs:
-                        toks, lps = _sample_batch_lp(
-                            logits, self._temps, sub, self.top_k)
-                        toks, lps = toks[None], lps[None]      # (1, B)
-                    else:
-                        toks = _sample_batch(logits, self._temps, sub,
-                                             self.top_k)[None]  # (1, B)
-                elif self.capture_logprobs:
-                    self.cache, toks, lps = decode_multi_lp(
-                        self.cfg, self.params, self.cache,
-                        self.cur_tokens, self._temps, k_block,
-                        self.top_k, sub)                       # (k, B)
-                else:
-                    self.cache, toks = decode_multi(
-                        self.cfg, self.params, self.cache,
-                        self.cur_tokens, self._temps, k_block,
-                        self.top_k, sub)                       # (k, B)
-                self.cur_tokens = toks[-1]
-                # Start the host copy NOW, before the next tick enqueues
-                # prefills and the next block behind it.
-                for arr in ((toks,) if lps is None else (toks, lps)):
-                    try:
-                        arr.copy_to_host_async()
-                    except Exception:  # noqa: BLE001 — no async copy
-                        pass
-                self.decode_ticks += k_block
-                for i in active:
-                    snap[i].inflight += k_block
-                block = (toks, lps, k_block,
-                         [(i, snap[i]) for i in active])
+                block = self._dispatch_block(k_block, snap, active)
             # else: every active slot's budget is already covered by
             # the in-flight block — dispatching more would only burn
             # wasted ticks; process the pending block instead.
@@ -973,6 +1007,53 @@ class LLMEngine:
             self._process_block(prev)
         return bool(admitted or outs or block or prev or registered)
 
+    def _dispatch_block(self, k_block: int, snap: List, active: List[int]):
+        """One fused block of `k_block` decode steps for every slot (the
+        program computes all `num_slots`; `active` of them hold a
+        request). Returns the pending block `_process_block` takes."""
+        c = self.counts
+        number = c["blocks"]
+        c["blocks"] = number + 1
+        c["blocks_by_k"][k_block] = c["blocks_by_k"].get(k_block, 0) + 1
+        c["slot_steps"] += k_block * self.num_slots
+        with tracing.span("engine.dispatch_block", block=number, k=k_block,
+                          active=len(active), slots=self.num_slots):
+            self._key, sub = jax.random.split(self._key)
+            lps = None
+            if k_block == 1:
+                self.cache, logits = decode_step(
+                    self.cfg, self.params, self.cache,
+                    self.cur_tokens)
+                if self.capture_logprobs:
+                    toks, lps = _sample_batch_lp(
+                        logits, self._temps, sub, self.top_k)
+                    toks, lps = toks[None], lps[None]      # (1, B)
+                else:
+                    toks = _sample_batch(logits, self._temps, sub,
+                                         self.top_k)[None]  # (1, B)
+            elif self.capture_logprobs:
+                self.cache, toks, lps = decode_multi_lp(
+                    self.cfg, self.params, self.cache,
+                    self.cur_tokens, self._temps, k_block,
+                    self.top_k, sub)                       # (k, B)
+            else:
+                self.cache, toks = decode_multi(
+                    self.cfg, self.params, self.cache,
+                    self.cur_tokens, self._temps, k_block,
+                    self.top_k, sub)                       # (k, B)
+            self.cur_tokens = toks[-1]
+            # Start the host copy NOW, before the next tick enqueues
+            # prefills and the next block behind it.
+            for arr in ((toks,) if lps is None else (toks, lps)):
+                try:
+                    arr.copy_to_host_async()
+                except Exception:  # noqa: BLE001 — no async copy
+                    pass
+            self.decode_ticks += k_block
+            for i in active:
+                snap[i].inflight += k_block
+        return (toks, lps, k_block, [(i, snap[i]) for i in active], number)
+
     def _process_block(self, block) -> None:
         """Fetch a dispatched decode block's tokens and emit them.
 
@@ -981,9 +1062,23 @@ class LLMEngine:
         block was in flight now holds a different request, and the
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
-        toks, lps, k_block, slot_snap = block
-        host_toks = np.asarray(toks)
-        host_lps = np.asarray(lps) if lps is not None else None
+        toks, lps, k_block, slot_snap, number = block
+        span = tracing.span("engine.process_block", block=number, k=k_block,
+                            slots=self.num_slots, active=len(slot_snap))
+        with span:
+            with tracing.span("engine.fetch"):  # the host waits here
+                host_toks = np.asarray(toks)
+                host_lps = np.asarray(lps) if lps is not None else None
+            self.steps_processed += k_block
+            before = self.tokens_out
+            self._emit_block(host_toks, host_lps, k_block, slot_snap)
+            emitted = self.tokens_out - before
+            discarded = k_block * len(slot_snap) - emitted
+            self.counts["tokens_discarded"] += discarded
+            span.set(emitted=emitted, discarded=discarded)
+
+    def _emit_block(self, host_toks, host_lps, k_block: int,
+                    slot_snap: List) -> None:
         for i, slot0 in slot_snap:
             slot0.inflight -= k_block
             slot = self.slots[i]
@@ -1015,7 +1110,8 @@ class LLMEngine:
                 raise
             if not busy:
                 self._work.clear()
-                self._work.wait(timeout=0.1)
+                with tracing.span("engine.idle_wait"):
+                    self._work.wait(timeout=0.1)
 
     def _fail_all(self, exc: Exception) -> None:
         """A step blew up (OOM, XLA error): unblock every waiting client
@@ -1052,10 +1148,16 @@ class LLMEngine:
         self._fail_all(RuntimeError("engine stopped"))
 
     def stats(self) -> Dict[str, Any]:
-        fin = self.finished
+        """Totals since the engine started, and `counts` (what the
+        engine's spans carry, summed: docs/METRICS.md). `ttft_p50_s` and
+        `ttft_p99_s` are over the last FINISHED_RING (1,024) completed
+        requests, not over the engine's life."""
+        fin = list(self.finished)
         ttfts = sorted(f["ttft_s"] for f in fin)
         out: Dict[str, Any] = {
-            "finished": len(fin),
+            "finished": self._n_finished,
+            "counts": dict(self.counts,
+                           blocks_by_k=dict(self.counts["blocks_by_k"])),
             "decode_ticks": self.decode_ticks,
             "tokens_out": self.tokens_out,
             "waiting": len(self.waiting),

@@ -110,12 +110,16 @@ def make_train_step(cfg: TransformerConfig, optimizer, *, loss=None,
                    ) -> Tuple[TrainState, Dict[str, jax.Array]]:
         grad_fn = jax.value_and_grad(_loss, has_aux=True)
         (_, metrics), grads = grad_fn(state.params, tokens, targets, mask)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        if param_pspecs is not None:
-            params = jax.lax.with_sharding_constraint(params, param_pspecs)
-        gnorm = optax.global_norm(grads)
+        # Forward and backward name themselves in `loss_fn` (`fwd`,
+        # `loss_head`); the rest of the step is the optimizer's.
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            if param_pspecs is not None:
+                params = jax.lax.with_sharding_constraint(
+                    params, param_pspecs)
+            gnorm = optax.global_norm(grads)
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state)
         metrics = dict(metrics)

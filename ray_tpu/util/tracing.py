@@ -6,8 +6,13 @@ decorators around .remote() and execution, context propagated in task
 specs; _private/profiling.py + `ray timeline` for chrome traces;
 dashboard's py-spy hooks for CPU profiles):
 
-- span(name): context manager recording a chrome-trace span into the
+- span(name): context manager with two sinks. Every span enters a
+  jax.profiler.TraceAnnotation("ray_tpu:<name>"), so a running profiler
+  session (profile_tpu below, or anybody's start_trace) shows it on the
+  clock of the device's operations; and, when a hook is registered or a
+  runtime keeps a timeline, it records a chrome-trace span into the
   runtime's task-event buffer, with parent links via a contextvar.
+  With neither, a span costs the annotation alone.
   Spans root a Dapper-style trace: the first span in a context mints a
   trace_id, nested spans inherit it, and trace_context() re-installs a
   propagated (trace_id, parent_span_id) pair on the far side of a
@@ -36,6 +41,7 @@ import contextlib
 import contextvars
 import hashlib
 import os
+import sys
 import threading
 import time
 import uuid
@@ -146,39 +152,102 @@ def clear_tracing() -> None:
         _prev_enable_timeline = None
 
 
-@contextlib.contextmanager
-def span(name: str, category: str = "span", **attributes):
-    """Record a chrome-trace span; nests via contextvar parent links.
-    The outermost span in a context roots a new trace id."""
-    span_id = uuid.uuid4().hex[:16]
-    parent = _current_span.get()
-    trace_id = _current_trace.get()
-    trace_token = None
-    if trace_id is None:
-        trace_id = uuid.uuid4().hex[:16]
-        trace_token = _current_trace.set(trace_id)
-    token = _current_span.set(span_id)
-    t0 = time.time()
-    try:
-        yield span_id
-    finally:
-        t1 = time.time()
-        _current_span.reset(token)
-        if trace_token is not None:
-            _current_trace.reset(trace_token)
-        ev = {
-            "name": name, "cat": category, "ph": "X",
-            "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6,
-            "pid": _process_label, "tid": f"span:{span_id}",
-            "args": {"parent": parent, "trace_id": trace_id,
-                     **attributes},
-        }
-        # Record-time sampling gate: the trace id always propagates so
-        # every hop can evaluate the same deterministic verdict; only
-        # the recording is skipped. (No `return` here — a bare return
-        # in this finally would swallow in-flight exceptions.)
-        if trace_sampled(trace_id):
-            _record(ev)
+PROFILER_PREFIX = "ray_tpu:"
+
+
+def _recording() -> bool:
+    """Whether a finished span has anywhere to go besides the profiler:
+    an exporter hook, or the timeline of a runtime in this process."""
+    if _hooks:
+        return True
+    runtime = sys.modules.get("ray_tpu.core.runtime")
+    if runtime is None or runtime.global_runtime_or_none() is None:
+        return False
+    from .._private.config import config
+
+    return bool(config.enable_timeline)
+
+
+class span:
+    """Record a span: `with span("engine.tick", tick=3): ...`. Two sinks.
+
+    - Always, `jax.profiler.TraceAnnotation("ray_tpu:" + name,
+      **attributes)`: while a profiler session runs (`profile_tpu`, or
+      anybody's `start_trace`) the span lands in its `.xplane.pb` beside
+      the device's operations, on their clock, attributes as the event's
+      stats (keep commas out of string values: the profiler cuts there).
+      With no session it costs an object and a flag test; a process
+      that never imported jax has no session and skips it.
+    - Under `setup_tracing()` or a runtime's timeline, a chrome-trace
+      event with ids: nests via contextvar parent links, the outermost
+      span in a context roots a new trace id. `with ... as span_id`
+      gives the id.
+
+    With neither a hook nor a timeline to record into, nothing else
+    happens and the id is `None`: no ids, no environment read, no hash.
+    `set()` adds attributes that are known only before the span ends.
+    """
+
+    __slots__ = ("name", "category", "attributes", "_annotation", "_id",
+                 "_parent", "_trace_id", "_tokens", "_ts", "_t0")
+
+    def __init__(self, name: str, category: str = "span", **attributes):
+        self.name, self.category = name, category
+        self.attributes = attributes
+        self._annotation = self._id = None
+
+    def set(self, **attributes) -> None:
+        self.attributes.update(attributes)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attributes)
+
+    def __enter__(self) -> Optional[str]:
+        # Looked up, never imported: a process that has not imported
+        # jax's profiler has no session, and one that is importing it on
+        # another thread right now has half a module.
+        annotation = getattr(sys.modules.get("jax.profiler"),
+                             "TraceAnnotation", None)
+        if annotation is not None:
+            self._annotation = annotation(
+                PROFILER_PREFIX + self.name, **self.attributes)
+            self._annotation.__enter__()
+        if not _recording():
+            return None
+        self._id = uuid.uuid4().hex[:16]
+        self._parent = _current_span.get()
+        self._trace_id = _current_trace.get()
+        trace_token = None
+        if self._trace_id is None:
+            self._trace_id = uuid.uuid4().hex[:16]
+            trace_token = _current_trace.set(self._trace_id)
+        self._tokens = (_current_span.set(self._id), trace_token)
+        # `ts` is wall time so that merged chrome traces line up across
+        # processes; the duration comes from a clock that cannot step.
+        self._ts, self._t0 = time.time(), time.monotonic()
+        return self._id
+
+    def __exit__(self, *exc) -> bool:
+        if self._id is not None:
+            dur = time.monotonic() - self._t0
+            span_token, trace_token = self._tokens
+            _current_span.reset(span_token)
+            if trace_token is not None:
+                _current_trace.reset(trace_token)
+            # Record-time sampling gate: the trace id always propagates
+            # so every hop can evaluate the same deterministic verdict;
+            # only the recording is skipped.
+            if trace_sampled(self._trace_id):
+                _record({
+                    "name": self.name, "cat": self.category, "ph": "X",
+                    "ts": self._ts * 1e6, "dur": dur * 1e6,
+                    "pid": _process_label, "tid": f"span:{self._id}",
+                    "args": {"parent": self._parent,
+                             "trace_id": self._trace_id,
+                             **self.attributes},
+                })
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
 
 
 @contextlib.contextmanager
@@ -417,8 +486,10 @@ def export_chrome_trace(path: str) -> int:
 def profile_tpu(logdir: str, *, host_tracer_level: int = 2):
     """TPU-native profiler capture: everything inside the block is
     recorded by the jax/XLA profiler (view with tensorboard/xprof —
-    MXU utilisation, HBM traffic, ICI transfers). Replaces the
-    reference's py-spy/memray host profiling for device work."""
+    MXU utilisation, HBM traffic, ICI transfers), and every `span()`
+    that runs meanwhile is in the same trace as a `ray_tpu:<name>` host
+    event. Replaces the reference's py-spy/memray host profiling for
+    device work."""
     import jax
 
     os.makedirs(logdir, exist_ok=True)

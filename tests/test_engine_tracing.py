@@ -1,0 +1,345 @@
+"""The program naming itself: `tracing.span()`'s two sinks and its free
+off state, the engine's spans and counts, `steps_waited`, the serving
+programs' names, the kernels' names and the train step's phase scopes.
+All on the CPU; nothing sleeps or asserts a duration."""
+
+import importlib
+import re
+import uuid
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import configs, generate
+from ray_tpu.models.transformer import init_params
+from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.util import tracing
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = configs.tiny_test()
+    return cfg, init_params(cfg, jax.random.key(0))
+
+
+@pytest.fixture
+def hook():
+    got = []
+    tracing.setup_tracing(got.append)
+    yield got
+    tracing.clear_tracing()
+
+
+class _Annotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what entered."""
+
+    entered = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, dict(kw)
+
+    def __enter__(self):
+        _Annotation.entered.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **kw):
+        self.kw.update(kw)
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _Annotation.entered = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    return _Annotation.entered
+
+
+def _boom(*a, **k):
+    raise AssertionError("not in the off state")
+
+
+def test_span_with_no_sink_is_the_profiler_annotation_alone(
+        monkeypatch, annotations):
+    tracing.clear_tracing()
+    assert not tracing._recording()
+    monkeypatch.setattr(uuid, "uuid4", _boom)
+    monkeypatch.setattr(tracing, "trace_sampled", _boom)
+    sp = tracing.span("engine.tick", tick=3, waiting=0)
+    with sp as span_id:
+        sp.set(emitted=5)
+    assert span_id is None
+    assert [(a.name, a.kw) for a in annotations] == [
+        ("ray_tpu:engine.tick", {"tick": 3, "waiting": 0, "emitted": 5})]
+    assert tracing.current_span_id() is None
+
+
+def test_span_under_a_hook_keeps_ids_parents_and_late_attributes(
+        hook, annotations):
+    outer = tracing.span("outer", a=1)
+    with outer as oid:
+        with tracing.span("inner") as iid:
+            assert tracing.current_span_id() == iid
+        outer.set(b=2)
+    inner_ev, outer_ev = hook
+    assert outer_ev["args"] == {"parent": None, "a": 1, "b": 2,
+                                "trace_id": outer_ev["args"]["trace_id"]}
+    assert inner_ev["args"]["parent"] == oid
+    assert inner_ev["args"]["trace_id"] == outer_ev["args"]["trace_id"]
+    assert outer_ev["dur"] >= inner_ev["dur"] >= 0
+    assert outer_ev["tid"] == f"span:{oid}"
+    # Both sinks: the profiler saw the same two spans.
+    assert [a.name for a in annotations] == ["ray_tpu:outer",
+                                             "ray_tpu:inner"]
+    assert annotations[0].kw == {"a": 1, "b": 2}
+
+
+def test_a_span_does_not_swallow_an_exception(hook):
+    with pytest.raises(KeyError):
+        with tracing.span("boom"):
+            raise KeyError("x")
+    assert [e["name"] for e in hook] == ["boom"]
+    assert tracing.current_span_id() is None
+
+
+def _drain(engine, reqs):
+    for _ in range(10_000):
+        if all(r.finish_ts for r in reqs):
+            break
+        engine.step()
+    engine.step()           # the tick that processes the last block
+    assert all(r.finish_ts for r in reqs)
+
+
+def _engine(tiny_model, **kw):
+    cfg, params = tiny_model
+    return LLMEngine(cfg, params, num_slots=2, max_seq_len=64,
+                     decode_block=8, **kw)
+
+
+@pytest.fixture
+def drained(tiny_model, hook):
+    """An engine run to the end under a hook: five requests on two
+    slots, so some wait and get their first token queue-side."""
+    engine = _engine(tiny_model)
+    reqs = [engine.submit(list(range(1, 4 + 3 * i)), max_new_tokens=3 + i)
+            for i in range(5)]
+    _drain(engine, reqs)
+    return engine, reqs, list(hook)
+
+
+def test_engine_spans_nest_under_their_tick_and_share_its_trace(drained):
+    _, _, events = drained
+    by_id = {e["tid"].split(":", 1)[1]: e for e in events}
+    names = {e["name"] for e in events}
+    assert {"engine.tick", "engine.prefill_tile", "engine.fuse_first",
+            "engine.dispatch_block", "engine.deliver_first",
+            "engine.process_block", "engine.fetch"} <= names
+    for e in events:
+        if e["name"] in ("engine.prefill_tile", "engine.dispatch_block",
+                         "engine.process_block", "engine.deliver_first"):
+            parent = by_id[e["args"]["parent"]]
+            assert parent["name"] == "engine.tick"
+            assert parent["args"]["trace_id"] == e["args"]["trace_id"]
+        if e["name"] == "engine.fetch":
+            assert by_id[e["args"]["parent"]]["name"] in (
+                "engine.process_block", "engine.deliver_first")
+    ticks = [e["args"]["tick"] for e in events if e["name"] == "engine.tick"]
+    assert sorted(ticks) == list(range(len(ticks)))
+    # No span per token or per slot.
+    assert not names & {"engine.emit", "engine.slot"}
+
+
+def test_counts_equal_the_sums_of_the_span_attributes(drained):
+    engine, reqs, events = drained
+
+    def spans(name):
+        return [e["args"] for e in events if e["name"] == name]
+
+    c = engine.stats()["counts"]
+    tiles, blocks = spans("engine.prefill_tile"), spans("engine.dispatch_block")
+    done = spans("engine.process_block")
+    assert c["ticks"] == len(spans("engine.tick"))
+    assert c["blocks"] == len(blocks) == len(done)
+    assert c["blocks_by_k"] == {k: sum(b["k"] == k for b in blocks)
+                                for k in {b["k"] for b in blocks}}
+    assert c["slot_steps"] == sum(b["k"] * b["slots"] for b in blocks)
+    assert c["tokens_discarded"] == sum(b["discarded"] for b in done)
+    assert all(b["discarded"] == b["k"] * b["active"] - b["emitted"]
+               for b in done)
+    assert c["prefill_tiles"] == len(tiles)
+    assert c["prefill_rows"] == sum(t["rows"] for t in tiles)
+    assert c["prefill_tile_rows"] == sum(t["tile_rows"] for t in tiles)
+    assert c["prefill_tokens"] == sum(t["tokens"] for t in tiles)
+    assert c["prefill_tile_tokens"] == sum(t["tile_rows"] * t["bucket"]
+                                           for t in tiles)
+    assert c["queue_side_first_tokens"] == sum(
+        t["rows"] for t in tiles if t["side"] == "queue") > 0
+    assert sum(k * n for k, n in c["blocks_by_k"].items()) \
+        == engine.decode_ticks == engine.steps_processed
+    # Every token is a first token or a block's.
+    assert engine.tokens_out == sum(len(r.tokens) for r in reqs) == sum(
+        b["emitted"] for b in done) + sum(
+            d["tokens"] for d in spans("engine.deliver_first"))
+    # A tile names the requests it holds; every request is in a slot tile.
+    in_slot_tiles = sorted(int(i) for t in tiles if t["side"] == "slot"
+                           for i in t["req_ids"].split())
+    assert in_slot_tiles == sorted(r.id for r in reqs)
+    assert all("," not in t["req_ids"] for t in tiles)
+
+
+def test_steps_waited_counts_the_blocks_between_a_request_and_the_device(
+        tiny_model):
+    engine = _engine(tiny_model)
+    first = engine.submit([1, 2, 3], max_new_tokens=9)
+    engine.step()
+    # Admitted by the first tick after its submit, nothing in flight.
+    assert (first.admit_tick, first.steps_waited) == (0, 0)
+    k = engine.decode_ticks
+    assert k == 8 and engine.steps_processed == 0
+    # Submitted just after a dispatch: that block stands before it.
+    second = engine.submit([4, 5, 6], max_new_tokens=2)
+    engine.step()
+    assert (second.admit_tick, second.steps_waited) == (1, k)
+    _drain(engine, [first, second])
+    # Once the host has read every block, a new request waits for none.
+    third = engine.submit([7, 8], max_new_tokens=2)
+    engine.step()
+    assert third.steps_waited == 0 and third.admit_tick > 1
+    _drain(engine, [third])
+
+
+def test_finished_is_a_ring_and_stats_counts_all(tiny_model, monkeypatch):
+    monkeypatch.setattr(LLMEngine, "FINISHED_RING", 3)
+    engine = _engine(tiny_model)
+    reqs = [engine.submit([1, 2 + i], max_new_tokens=2) for i in range(5)]
+    _drain(engine, reqs)
+    st = engine.stats()
+    assert st["finished"] == 5 and len(engine.finished) == 3
+    assert [f["id"] for f in engine.finished] == [r.id for r in reqs[-3:]]
+    assert "ttft_p50_s" in st and "1,024" in LLMEngine.stats.__doc__
+
+
+DECODE = re.compile("decode")
+PREFILL = re.compile("prefill|first_token")   # the benchmark's patterns
+
+
+def _module_name(lowered):
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def test_every_serving_program_lowers_under_its_table_name(tiny_model):
+    cfg, params = tiny_model
+    W, S, B = 8, 16, 2
+    cache = generate.init_kv_cache(cfg, B, 32)
+    key = jax.random.key(0)
+    toks = jnp.zeros((W, S), jnp.int32)
+    lens = jnp.ones((W,), jnp.int32)
+    slots = jnp.zeros((W,), jnp.int32)
+    temps = jnp.zeros((W,), jnp.float32)
+    pk = jnp.zeros((cfg.n_layers, 8, cfg.n_kv_heads, cfg.head_dim),
+                   cfg.dtype)
+    cur, t2 = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.float32)
+    logits = jnp.zeros((B, cfg.vocab_size), jnp.float32)
+    from ray_tpu.serve import llm
+
+    args = {
+        "prefill": (generate.prefill, (cfg, params, cache, toks[:1], lens[0],
+                                       slots[0])),
+        "prefill_sample": (generate.prefill_sample, (
+            cfg, params, cache, toks[:1], lens[0], slots[0], 0, temps[0],
+            key)),
+        "prefill_sample_batch": (generate.prefill_sample_batch, (
+            cfg, params, cache, toks, lens, slots, 0, temps, key)),
+        "prefill_sample_batch_lp": (generate.prefill_sample_batch_lp, (
+            cfg, params, cache, toks, lens, slots, 0, temps, key)),
+        "prefill_suffix_batch": (generate.prefill_suffix_batch, (
+            cfg, params, cache, pk, pk, toks, lens, slots, 0, temps, key)),
+        "prefill_suffix_batch_lp": (generate.prefill_suffix_batch_lp, (
+            cfg, params, cache, pk, pk, toks, lens, slots, 0, temps, key)),
+        "first_token_sample": (generate.first_token_sample, (
+            cfg, params, toks, lens, temps, 0, key)),
+        "first_token_sample_lp": (generate.first_token_sample_lp, (
+            cfg, params, toks, lens, temps, 0, key)),
+        "first_token_suffix_sample": (generate.first_token_suffix_sample, (
+            cfg, params, pk, pk, toks, lens, temps, 0, key)),
+        "first_token_suffix_sample_lp": (
+            generate.first_token_suffix_sample_lp, (
+                cfg, params, pk, pk, toks, lens, temps, 0, key)),
+        "decode_step": (generate.decode_step, (cfg, params, cache, cur)),
+        "sample_batch": (llm._sample_batch, (logits, t2, key, 0)),
+        "sample_batch_lp": (llm._sample_batch_lp, (logits, t2, key, 0)),
+    }
+    blocks = {"decode_multi": generate.decode_multi,
+              "decode_multi_lp": generate.decode_multi_lp}
+    assert set(args) | set(blocks) == set(generate.PROGRAM_NAMES)
+    names = {}
+    for key_, (fn, a) in args.items():
+        names[key_] = _module_name(fn.lower(*a))
+        assert names[key_] == "jit_" + generate.PROGRAM_NAMES[key_]
+    for key_, fn in blocks.items():
+        for k in (2, 8, 64):
+            name = _module_name(fn.lower(cfg, params, cache, cur, t2, k, 0,
+                                         key))
+            assert name == "jit_" + generate.PROGRAM_NAMES[key_].format(k=k)
+            assert re.search(r"_k%d$" % k, name)
+            names[f"{key_}/{k}"] = name
+    assert names["decode_step"] == "jit_decode_k1"
+    for key_, name in names.items():
+        if key_.startswith("decode"):
+            assert DECODE.search(name) and not PREFILL.search(name), name
+        elif key_.startswith("sample_batch"):
+            assert not DECODE.search(name) and not PREFILL.search(name)
+        else:
+            assert PREFILL.search(name) and not DECODE.search(name), name
+    assert len(set(names.values())) == len(names)
+
+
+def test_a_block_size_is_one_program_compiled_once(tiny_model):
+    cfg, params = tiny_model
+    fn = generate.decode_multi.program_for(4)
+    assert generate.decode_multi.program_for(4) is fn
+    assert generate.decode_multi.program_for(2) is not fn
+    cache = generate.init_kv_cache(cfg, 2, 32)
+    cur, temps = jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.float32)
+    cache, toks = generate.decode_multi(cfg, params, cache, cur, temps, 4, 0,
+                                        jax.random.key(1))
+    assert toks.shape == (4, 2) and int(cache.seq_lens[0]) == 4
+
+
+def test_the_three_flash_kernels_name_themselves_in_the_jaxpr():
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    q = jnp.zeros((1, 2048, 2, 128), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True,
+                                          interpret=True))
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, q, q))
+    assert text.count("pallas_call") >= 3
+    found = re.findall(r"metadata=FrozenDict\(\{'kernel': '(\w+)'\}\)", text)
+    assert sorted(found) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    # No scope of the kernels' own: the device's event keeps the name
+    # the benchmark's reader matches (`closed_call.<n>`, `shard_map.<n>`).
+    assert "name=flash" not in text
+
+
+def test_the_train_step_marks_forward_loss_head_and_optimizer():
+    from ray_tpu.parallel import ParallelPlan, make_mesh
+    from ray_tpu.train.step import init_state, make_optimizer, make_train_step
+
+    cfg = configs.tiny_test()
+    mesh = make_mesh(ParallelPlan())
+    opt = make_optimizer(warmup_steps=1, total_steps=10)
+    with jax.sharding.set_mesh(mesh):
+        state = init_state(cfg, mesh, opt)
+        toks = jnp.zeros((2, 16), jnp.int32)
+        text = make_train_step(cfg, opt).lower(
+            state, toks, toks, jnp.ones((2, 16), jnp.float32)
+        ).as_text(debug_info=True)
+    for scope in ("jvp(fwd)", "transpose(jvp(fwd))", "jvp(loss_head)",
+                  "transpose(jvp(loss_head))", "/optimizer/"):
+        assert scope in text, scope
