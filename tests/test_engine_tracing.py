@@ -5,6 +5,7 @@ All on the CPU; nothing sleeps or asserts a duration."""
 
 import importlib
 import re
+import threading
 import uuid
 
 import jax
@@ -25,8 +26,16 @@ def tiny_model():
 
 @pytest.fixture
 def hook():
-    got = []
-    tracing.setup_tracing(got.append)
+    """The spans that finish on the test's own thread. The hook is the
+    process's: in a worker shared with other test files, an engine
+    thread one of them left running reports its ticks here too."""
+    got, own = [], threading.get_ident()
+
+    def mine(event):
+        if threading.get_ident() == own:
+            got.append(event)
+
+    tracing.setup_tracing(mine)
     yield got
     tracing.clear_tracing()
 

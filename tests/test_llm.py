@@ -1,6 +1,8 @@
 """LLM inference path: KV-cache decode equivalence, continuous batching,
 serve deployment integration."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import pytest
 
 from ray_tpu.models import configs
 from ray_tpu.models.generate import (
+    decode_multi,
     decode_step,
     greedy_generate,
     init_kv_cache,
@@ -47,6 +50,94 @@ def test_decode_logits_match_full_forward(tiny_model):
         np.testing.assert_allclose(a, np.asarray(full[0, i]),
                                    atol=2e-5, rtol=2e-4,
                                    err_msg=f"step {step}")
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_in_place_decode_is_the_plain_reference(kind, group):
+    """The decode programs write one row a slot a layer into the carried
+    cache and read it in place. Against the full-sequence forward and a
+    cache made by prefilling the whole sequence: same logits, the same
+    greedy tokens from `decode_step` x n and from one `decode_multi`
+    block of n, the reference's K/V in every row a request owns, and
+    every other row bit for bit what it was before the block."""
+    S, n = 16, 3
+    base = configs.tiny_test() if kind == "dense" else dataclasses.replace(
+        configs.tiny_moe_test(), moe_capacity_factor=4.0)  # nothing dropped
+    cfg = dataclasses.replace(base, n_kv_heads=base.n_heads // group,
+                              max_seq_len=S)
+    params = init_params(cfg, jax.random.key(0))
+    # Slots at unequal lengths: empty, short, a padded bucket, one row
+    # left (owned for one step, then past the end), and one no request
+    # owns whose position already stands at S.
+    prompt_lens = [0, 5, 9, S - 1]
+    owned_steps = [n, n, n, 1]
+    idle, B = len(prompt_lens), len(prompt_lens) + 1
+    rng = np.random.RandomState(group)
+    prompts = [rng.randint(0, cfg.vocab_size, size=m) for m in prompt_lens]
+
+    def start():
+        shape = (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.head_dim)
+        kk, kv = jax.random.split(jax.random.key(7))
+        cache = init_kv_cache(cfg, B, S)._replace(
+            k=jax.random.normal(kk, shape, cfg.dtype),
+            v=jax.random.normal(kv, shape, cfg.dtype))
+        first = [3]                      # the empty slot is fed a token
+        for slot, p in enumerate(prompts[1:], start=1):
+            bucket = max(8, 1 << (len(p) - 1).bit_length())
+            padded = jnp.zeros((1, bucket), jnp.int32).at[0, :len(p)].set(p)
+            cache, logits = prefill(cfg, params, cache, padded,
+                                    jnp.int32(len(p)), jnp.int32(slot))
+            first.append(int(jnp.argmax(logits)))
+        cache = cache._replace(seq_lens=cache.seq_lens.at[idle].set(S))
+        return cache, jnp.asarray(first + [0], jnp.int32)
+
+    cache, tok = start()
+    before_k, before_v = np.asarray(cache.k), np.asarray(cache.v)
+    fed, step_logits = [], []
+    for _ in range(n):
+        fed.append(np.asarray(tok))
+        cache, logits = decode_step(cfg, params, cache, tok)
+        step_logits.append(np.asarray(logits))
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    fed.append(np.asarray(tok))
+
+    cache_b, tok_b = start()
+    cache_b, toks_b = decode_multi(cfg, params, cache_b, tok_b,
+                                   jnp.zeros((B,), jnp.float32), n, 0,
+                                   jax.random.key(5))
+    toks_b = np.asarray(toks_b)
+
+    touched = np.zeros((B, S), bool)
+    for slot, (p, steps) in enumerate(zip(prompts, owned_steps)):
+        m = len(p)
+        seq = np.concatenate([p, [f[slot] for f in fed[:steps]]])
+        full, _ = forward(cfg, params, jnp.asarray(seq, jnp.int32)[None])
+        for i in range(steps):
+            np.testing.assert_allclose(
+                step_logits[i][slot], np.asarray(full[0, m + i]),
+                atol=2e-5, rtol=2e-4, err_msg=f"slot {slot} step {i}")
+            best = int(np.argmax(np.asarray(full[0, m + i])))
+            assert fed[i + 1][slot] == best == toks_b[i, slot]
+        # The reference cache: the whole sequence through prefill.
+        ref = init_kv_cache(cfg, 1, S)
+        padded = jnp.zeros((1, S), jnp.int32).at[0, :len(seq)].set(seq)
+        ref, _ = prefill(cfg, params, ref, padded, jnp.int32(len(seq)),
+                         jnp.int32(0))
+        touched[slot, m:m + steps] = True
+        for got in (cache, cache_b):
+            for have, want in ((got.k, ref.k), (got.v, ref.v)):
+                np.testing.assert_allclose(
+                    np.asarray(have)[:, slot, :len(seq)],
+                    np.asarray(want)[:, 0, :len(seq)],
+                    atol=2e-5, rtol=2e-4, err_msg=f"slot {slot}")
+    for got in (cache, cache_b):
+        np.testing.assert_array_equal(
+            np.asarray(got.seq_lens), np.asarray(prompt_lens + [S]) + n)
+        for have, was in ((got.k, before_k), (got.v, before_v)):
+            have = np.asarray(have)
+            assert np.array_equal(have[:, ~touched], was[:, ~touched])
+            assert not np.array_equal(have[:, touched], was[:, touched])
 
 
 def test_moe_decode_finite():

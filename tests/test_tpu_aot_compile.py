@@ -10,7 +10,9 @@ Skipped as a whole where the topology cannot be described.
 
 import dataclasses
 import importlib
+import json
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
@@ -156,6 +158,111 @@ def test_decode_multi_compiles_at_654m(serve_654m):
     mem = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
                              key).compile().memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+# The serving cells' shapes (benchmarks/cells/*.json), and the one the
+# batch cell's file says could not compile while decode copied the cache.
+DECODE_SHAPES = {
+    "batch-closed": ("internlm2-1.8b", 32, 1024),
+    "docqa-lone": ("mistral-7b-v0.3-l16", 4, 4096),
+    "batch-closed-at-64-slots": ("internlm2-1.8b", 64, 1024),
+}
+_HLO_INSTR = re.compile(
+    r"^\s+(?:ROOT )?%(?P<name>[\w.\-]+) = \w+\[(?P<dims>[\d,]*)\]\S* "
+    r"(?P<op>[\w\-]+)\((?:.*calls=%(?P<calls>[\w.\-]+))?")
+
+
+def _device_writes(text):
+    """{result dims: [(name, opcode, opcodes inside a fusion's body)]} of
+    the instructions outside any fused computation: what the device
+    writes out, the lines of a trace's operation list."""
+    bodies, current = {}, None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            current = line.split()[line.startswith("ENTRY")].lstrip("%")
+            bodies[current] = []
+        elif current and (m := _HLO_INSTR.match(line)):
+            bodies[current].append(m)
+    fused = {m["calls"] for ms in bodies.values() for m in ms
+             if m["op"] == "fusion"}
+
+    def ops_of(comp):
+        ops = set()
+        for m in bodies.get(comp, ()):
+            ops.add(m["op"])
+            if m["calls"]:
+                ops |= ops_of(m["calls"])
+        return ops
+
+    out = {}
+    for comp, ms in bodies.items():
+        for m in ms if comp not in fused else ():
+            dims = tuple(int(d) for d in m["dims"].split(",") if d)
+            out.setdefault(dims, []).append(
+                (m["name"], m["op"],
+                 ops_of(m["calls"]) if m["op"] == "fusion" else set()))
+    return out
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_SHAPES))
+def test_decode_block_updates_the_cache_in_place(topo, cell):
+    """`decode_multi` (k = 8) at a serving cell's real shapes, bf16: the
+    cache is carried and aliased, one scatter a layer writes K's rows
+    and one V's, and nothing else produces an array of the cache's
+    shape; of a layer slab's shape only the two reads that feed the
+    einsums. The compiler stages those reads (`constant_dynamic-slice_
+    fusion`, PERF.md section 5): they are counted here, not hidden."""
+    from ray_tpu.models.generate import decode_multi
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from lib import modelcfg
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+
+    name, slots, max_seq = DECODE_SHAPES[cell]
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        cfg = modelcfg.transformer_config(json.load(f), {"model": {
+            "dtype": "bfloat16", "param_dtype": "bfloat16",
+            "max_seq_len": max_seq}})
+    one, key, params, cache = _serve_structs(topo, cfg, slots, max_seq)
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key).compile()
+    mem, writes = compiled.memory_analysis(), _device_writes(
+        compiled.as_text())
+
+    def nbytes(x):
+        return x.size * x.dtype.itemsize
+
+    # (a) What has the whole cache's shape: parameters, the loops'
+    # carried tuples, and one in-place scatter each for K and V.
+    whole = [w for w in writes.get(cache.k.shape, ())
+             if w[1] not in ("parameter", "get-tuple-element", "bitcast")]
+    assert len(whole) == 2, whole
+    assert all(op == "fusion" and "scatter" in body
+               and not body & {"copy", "dynamic-update-slice"}
+               for _, op, body in whole), whole
+    # A layer slab: read once for K and once for V, never written back.
+    slab = cache.k.shape[1:]
+    slabs = [w for w in writes.get(slab, []) + writes.get((1,) + slab, [])
+             if w[1] != "bitcast"]
+    assert len(slabs) <= 2, slabs
+    assert all(op == "fusion" and "dynamic-slice" in body
+               and not body & {"copy", "dynamic-update-slice", "scatter"}
+               for _, op, body in slabs), slabs
+    # (b) Temporaries: the compiler's own relayout of the stacked wq,
+    # wk and wv once a block (more than K alone only at docqa's widths)
+    # and at most one layer slab (at 64 slots a slab is 128 MiB and no
+    # longer fits the core's fast memory).
+    relayout = sum(nbytes(params["layers"][w]) for w in ("wq", "wk", "wv"))
+    assert (mem.temp_size_in_bytes - relayout
+            < nbytes(cache.k) // cfg.n_layers + 2e6)
+    # (c) The output is the donated cache, and the program fits.
+    assert mem.alias_size_in_bytes >= 2 * nbytes(cache.k)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def test_prefill_sample_batch_compiles_at_654m(serve_654m):
