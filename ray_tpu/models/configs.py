@@ -23,6 +23,20 @@ def tiny_moe_test(vocab: int = 256) -> TransformerConfig:
         moe_experts=4, moe_top_k=2)
 
 
+def tiny_afmoe_test(vocab: int = 256) -> TransformerConfig:
+    """The period stack of models/periodic.py at a unit-test size: one
+    dense layer, then one period of three window layers and a global
+    one, sigmoid-routed experts beside a shared one. For the tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=32, d_ff=128, max_seq_len=128, dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False, tie_embeddings=False,
+        arch="afmoe", n_dense_layers=1, global_attn_every=4,
+        sliding_window=8, moe_experts=8, moe_top_k=2, moe_d_ff=32,
+        moe_shared_experts=1, score_func="sigmoid", route_norm=True,
+        route_scale=2.826)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -75,6 +89,7 @@ def mixtral_8x7b() -> TransformerConfig:
 NAMED = {
     "tiny": tiny_test,
     "tiny_moe": tiny_moe_test,
+    "tiny_afmoe": tiny_afmoe_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

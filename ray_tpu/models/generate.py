@@ -41,7 +41,6 @@ from .transformer import (
     TransformerConfig,
     apply_rope,
     dense_ffn,
-    moe_ffn,
     rms_norm,
     rope_tables,
 )
@@ -131,11 +130,18 @@ class KVCache(NamedTuple):
     a cache donates it and returns it updated in place. The decode
     programs carry k and v whole through their layer and step loops,
     write one row a slot a layer and read each layer once; a row no
-    request owns is never written."""
+    request owns is never written.
+
+    A period stack (`models/periodic.py`) keeps two kinds of state: k/v
+    hold its global layers, (Lg, B, S_max, KVH, Dh), and kw/vw its
+    window layers, (Lw, B, min(window, S_max), KVH, Dh), a ring written
+    at `position mod rows`. Every other model leaves kw/vw None."""
 
     k: jax.Array
     v: jax.Array
     seq_lens: jax.Array
+    kw: Optional[jax.Array] = None
+    vw: Optional[jax.Array] = None
 
     @property
     def max_seq_len(self) -> int:
@@ -149,6 +155,9 @@ class KVCache(NamedTuple):
 def init_kv_cache(cfg: TransformerConfig, num_slots: int,
                   max_seq_len: Optional[int] = None) -> KVCache:
     S = max_seq_len or cfg.max_seq_len
+    if cfg.arch == "afmoe":
+        from . import periodic
+        return periodic.init_cache(cfg, num_slots, S)
     shape = (cfg.n_layers, num_slots, S, cfg.n_kv_heads, cfg.head_dim)
     k = jnp.zeros(shape, cfg.dtype)
     k = wsc(k, ("layers", None, None, "act_kv_heads", None))
@@ -184,12 +193,15 @@ def _qkv(cfg: TransformerConfig, lp, x, sin, cos):
 
 
 def _ffn(cfg: TransformerConfig, lp, x):
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if cfg.is_moe:
-        f, _ = moe_ffn(cfg, lp, h)
-    else:
-        f = dense_ffn(lp, h)
-    return x + f
+        # Routed, nothing dropped (models/moe.py): scores and selection
+        # in float32 from the norm's float32 output.
+        from .moe import routed_ffn
+        B, S, D = x.shape
+        m = rms_norm(x.astype(jnp.float32), lp["ffn_norm"], cfg.norm_eps)
+        f, _, _ = routed_ffn(cfg, lp, m.reshape(B * S, D), x.dtype)
+        return x + f.reshape(B, S, D).astype(x.dtype)
+    return x + dense_ffn(lp, rms_norm(x, lp["ffn_norm"], cfg.norm_eps))
 
 
 def _prefill_layer(cfg: TransformerConfig, carry, lp):
@@ -218,11 +230,25 @@ def _decode_layer(cfg: TransformerConfig, sin, cos, positions, carry,
     """
     x, k_all, v_all = carry
     lp, l = scanned
-    B, S = k_all.shape[1], k_all.shape[2]
-    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _qkv(cfg, lp, h, sin, cos)       # q (B,1,H,Dh); k,v (B,1,KVH,Dh)
+    out, k_all, v_all = _attend_cache(cfg, q, k, v, k_all, v_all, l,
+                                      positions, positions)
+    x = x + (out @ lp["wo"].astype(x.dtype))
+    x = _ffn(cfg, lp, x)
+    return (x, k_all, v_all), None
+
+
+def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
+                  write_at, positions):
+    """One token a slot against layer `l` of a carried cache (L, B, S,
+    KVH, Dh): write this step's k and v at row `write_at` (B,), then
+    attend over the rows up to `positions` (B,). Returns (out (B, 1,
+    H*Dh), k_all, v_all). For a ring of S rows `write_at` is `positions
+    mod S`: every row is seen once `positions` has passed S - 1."""
+    B, S = k_all.shape[1], k_all.shape[2]
+    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     # Write new kv at each slot's position. A true scatter (one row per
     # slot), overwriting: prefill leaves pad-position kv beyond
@@ -230,8 +256,8 @@ def _decode_layer(cfg: TransformerConfig, sin, cos, positions, carry,
     # engine no longer owns keeps advancing and can reach S: its write
     # falls out of bounds and is dropped, never clamped onto row S-1.
     rows = jnp.arange(B)
-    k_all = k_all.at[l, rows, positions].set(k[:, 0], mode="drop")
-    v_all = v_all.at[l, rows, positions].set(v[:, 0], mode="drop")
+    k_all = k_all.at[l, rows, write_at].set(k[:, 0], mode="drop")
+    v_all = v_all.at[l, rows, write_at].set(v[:, 0], mode="drop")
     k_cache = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
     v_cache = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
 
@@ -245,11 +271,7 @@ def _decode_layer(cfg: TransformerConfig, sin, cos, positions, carry,
     scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(k_cache.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
-    out = out.reshape(B, 1, H * Dh)
-
-    x = x + (out @ lp["wo"].astype(x.dtype))
-    x = _ffn(cfg, lp, x)
-    return (x, k_all, v_all), None
+    return out.reshape(B, 1, H * Dh), k_all, v_all
 
 
 # ---------------------------------------------------------------------------
@@ -266,6 +288,11 @@ def _head_logits(cfg: TransformerConfig, params, x):
 def _prefill_core(cfg: TransformerConfig, params, cache: KVCache,
                   tokens: jax.Array, length: jax.Array, slot: jax.Array
                   ) -> Tuple[KVCache, jax.Array]:
+    if cfg.arch == "afmoe":
+        # A tile of one row: the head sees the last real position only.
+        cache, logits = _prefill_batch_core(
+            cfg, params, cache, tokens, length[None], slot[None])
+        return cache, logits[0]
     S = tokens.shape[1]
     x = params["embed"].astype(cfg.dtype)[tokens]          # (1, S, D)
     sin, cos = rope_tables(cfg, S)
@@ -330,6 +357,11 @@ def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
     """Batched-prefill body shared by the sampling wrappers: write each
     prompt's KV into its slot, return (cache', last-real-token logits
     (W, V))."""
+    if cfg.arch == "afmoe":
+        from . import periodic
+        cache, x = periodic.prefill(cfg, params, cache, tokens, lengths,
+                                    slots)
+        return cache, periodic.last_logits(cfg, params, x, lengths)
     W, S = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]          # (W, S, D)
     sin, cos = rope_tables(cfg, S)
@@ -417,6 +449,7 @@ def _suffix_forward(cfg: TransformerConfig, params, prefix_k, prefix_v,
     token — one implementation so the two paths can never drift apart,
     the _prefill_core pattern): returns (x final-normed (W, Sq, D),
     ks, vs (L, W, Sq, KVH, Dh))."""
+    _no_prefix_sharing(cfg)
     W, Sq = tokens.shape
     Sp = prefix_k.shape[1]
     x = params["embed"].astype(cfg.dtype)[tokens]
@@ -426,6 +459,18 @@ def _suffix_forward(cfg: TransformerConfig, params, prefix_k, prefix_v,
     (x,), (ks, vs) = lax.scan(
         layer, (x,), (params["layers"], prefix_k, prefix_v))
     return rms_norm(x, params["final_norm"], cfg.norm_eps), ks, vs
+
+
+def _no_prefix_sharing(cfg: TransformerConfig) -> None:
+    """The prefix programs install one (L, Sp, KVH, Dh) block of keys and
+    values a layer. A window layer's ring holds a slot's last rows at
+    `position mod rows`, not a prefix at [0, Sp): sharing it needs a
+    layout of its own."""
+    if cfg.arch == "afmoe":
+        raise NotImplementedError(
+            "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
+            "compute_prefix_kv) is not written for a windowed cache "
+            "(models/periodic.py)")
 
 
 def _last_token_logits(cfg: TransformerConfig, params, x, lens):
@@ -478,6 +523,7 @@ def prefill_suffix_batch(cfg: TransformerConfig, params, cache: KVCache,
 def _prefill_suffix_core(cfg: TransformerConfig, params, cache: KVCache,
                          prefix_k, prefix_v, tokens, suffix_lens, slots
                          ) -> Tuple[KVCache, jax.Array]:
+    _no_prefix_sharing(cfg)
     W, Sq = tokens.shape
     Sp = prefix_k.shape[1]
     # 1. Prefix KV into the slot rows (broadcast copy; padding rows
@@ -559,6 +605,7 @@ def compute_prefix_kv(cfg: TransformerConfig, params,
                       ) -> Tuple[jax.Array, jax.Array]:
     """KV for a prompt prefix, computed ONCE (registration-time half of
     prefix caching): (L, Sp, KVH, Dh) k/v in the cache dtype."""
+    _no_prefix_sharing(cfg)
     Sp = len(prefix)
     scratch = init_kv_cache(cfg, 1, Sp)
     tokens = jnp.asarray(list(prefix), jnp.int32)[None]    # (1, Sp)
@@ -586,6 +633,10 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
 
 
 def _first_token_logits(cfg: TransformerConfig, params, tokens, lengths):
+    if cfg.arch == "afmoe":
+        from . import periodic
+        x, _ = periodic.forward_free(cfg, params, tokens)
+        return periodic.last_logits(cfg, params, x, lengths)
     from .transformer import _lm_head, forward_hidden
 
     # forward_hidden output is ALREADY final-norm'd — apply the head
@@ -610,7 +661,15 @@ def first_token_sample_lp(cfg: TransformerConfig, params,
 
 
 def _decode_core(cfg: TransformerConfig, params, cache: KVCache,
-                 tokens: jax.Array) -> Tuple[KVCache, jax.Array]:
+                 tokens: jax.Array
+                 ) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+    """(cache', logits (B, V), routing stats of the step (3,): see
+    `periodic.decode`; None for every model but a routed period stack)."""
+    if cfg.arch == "afmoe":
+        from . import periodic
+        cache, x, stats = periodic.decode(cfg, params, cache, tokens)
+        return cache, periodic.head_logits(cfg, params, x[:, 0]), \
+            stats if reports_routing(cfg) else None
     B = cache.num_slots
     positions = cache.seq_lens                              # (B,)
     x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # (B,1,D)
@@ -627,7 +686,7 @@ def _decode_core(cfg: TransformerConfig, params, cache: KVCache,
         (params["layers"], jnp.arange(cfg.n_layers)))
 
     logits = _head_logits(cfg, params, x)[:, 0]             # (B, V)
-    return KVCache(k=k_new, v=v_new, seq_lens=positions + 1), logits
+    return KVCache(k=k_new, v=v_new, seq_lens=positions + 1), logits, None
 
 
 @program("decode_step", static_argnums=(0,), donate_argnums=(2,))
@@ -637,7 +696,24 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     token per slot). Returns (cache', logits (B, V)). Slots advance their
     seq_lens by 1; inactive slots are advanced too — the host engine
     simply ignores their output and reuses the slot via prefill."""
-    return _decode_core(cfg, params, cache, tokens)
+    cache, logits, _ = _decode_core(cfg, params, cache, tokens)
+    return cache, logits
+
+
+def reports_routing(cfg: TransformerConfig) -> bool:
+    """Whether the fused decode blocks of `cfg` return, after their
+    other results, the block's routing stats: int32 (3,) = [experts that
+    held a row, summed over steps and routed layers; rows routed; the
+    fullest expert's rows, summed over steps and layers]."""
+    return cfg.arch == "afmoe" and cfg.is_moe
+
+
+def _zero_stats(cfg: TransformerConfig) -> Optional[jax.Array]:
+    return jnp.zeros((3,), jnp.int32) if reports_routing(cfg) else None
+
+
+def _add_stats(total, stats):
+    return None if total is None else total + stats
 
 
 def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
@@ -660,14 +736,15 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     """
 
     def body(carry, sub):
-        cache, tok = carry
-        cache, logits = _decode_core(cfg, params, cache, tok)
+        cache, tok, routed = carry
+        cache, logits, stats = _decode_core(cfg, params, cache, tok)
         tok = sample(logits, sub, temperature=temps, top_k=top_k)
-        return (cache, tok), tok
+        return (cache, tok, _add_stats(routed, stats)), tok
 
     subs = jax.random.split(key, num_steps)
-    (cache, _), toks = lax.scan(body, (cache, tokens), subs)
-    return cache, toks
+    (cache, _, routed), toks = lax.scan(
+        body, (cache, tokens, _zero_stats(cfg)), subs)
+    return (cache, toks) if routed is None else (cache, toks, routed)
 
 
 decode_multi = _BlockPrograms("decode_multi", _decode_multi,
@@ -685,14 +762,17 @@ def _decode_multi_lp(cfg: TransformerConfig, params, cache: KVCache,
     fused tick; engines that don't need it keep using decode_multi."""
 
     def body(carry, sub):
-        cache, tok = carry
-        cache, logits = _decode_core(cfg, params, cache, tok)
+        cache, tok, routed = carry
+        cache, logits, stats = _decode_core(cfg, params, cache, tok)
         tok = sample(logits, sub, temperature=temps, top_k=top_k)
-        return (cache, tok), (tok, token_logp(logits, tok))
+        return (cache, tok, _add_stats(routed, stats)), \
+            (tok, token_logp(logits, tok))
 
     subs = jax.random.split(key, num_steps)
-    (cache, _), (toks, lps) = lax.scan(body, (cache, tokens), subs)
-    return cache, toks, lps
+    (cache, _, routed), (toks, lps) = lax.scan(
+        body, (cache, tokens, _zero_stats(cfg)), subs)
+    return (cache, toks, lps) if routed is None \
+        else (cache, toks, lps, routed)
 
 
 decode_multi_lp = _BlockPrograms("decode_multi_lp", _decode_multi_lp,

@@ -1,0 +1,511 @@
+"""The period stack (`TransformerConfig.arch == "afmoe"`): a decoder whose
+layers are not all alike, served through the programs of `generate.py`.
+
+`n_dense_layers` leading layers with a dense SwiGLU, then whole periods
+of `global_attn_every` layers with the routed layer of `models/moe.py`
+(plus always-on shared experts); the last layer of a period attends to
+every earlier position, the others and the leading layers to the last
+`sliding_window` (rotary embedding on those alone). Every layer: RMS
+norms before and after attention and before and after the FFN, a learned
+norm over each head of q and k, and the attention output gated by
+`sigmoid(h @ wg)` before `wo`. The embedding is scaled by sqrt(d_model).
+
+Weights: `dense_layers` (leaves stacked over the leading layers) and
+`periods` (leaves stacked over periods, then over a period's layers).
+One layer definition (`layer`) and one walk over the stack (`_run`: a
+`lax.scan` over each group with a period's layers unrolled inside)
+serve prefill, the cache-free first token and decode; they differ in
+the `attend` they hand in, which owns the cache.
+
+The cache holds two kinds of state in one `KVCache`: `k`/`v` for the
+global layers, (Lg, slots, S_max, KVH, Dh), and `kw`/`vw` for the window
+layers, (Lw, slots, min(sliding_window, S_max), KVH, Dh), a ring written
+at `position mod rows`. Softmax does not care in which order the ring
+holds its rows, and a key carries its rotary phase from when it was
+written, so decode reads the ring as it lies.
+
+Precision follows `cfg.dtype`, the dtype of the activations and of the
+cache. bfloat16: every product takes bf16 operands, as the dense stack
+does. float32 (with bf16 weights): nothing between the embedding and the
+head is rounded to bf16; a product against a weight takes the
+activation as two bf16 terms (`moe.dot`); the cache keeps a key or a
+value as two bf16 terms too (`cache_terms`: layer l's rows rounded to
+bf16 at [l], what the rounding left at [L + l], so a layer's slab is
+the size a bf16 cache's is and twice as many are read); and only the
+head's product takes bf16 (its error is continuous). That is what a
+routed stack needs to choose the experts a float32 reference chooses:
+one token-layer pair in seven flips under bf16 activations, and a flip
+moves that token's logits by a tenth of their size or more (PERF.md).
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..parallel.sharding import with_sharding_constraint as wsc
+from .generate import KVCache, _attend_cache, _rope
+from .moe import EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn
+from .transformer import TransformerConfig, rope_tables
+
+WINDOW, GLOBAL = "window", "global"
+KINDS = (WINDOW, GLOBAL)
+
+
+def layer_plan(cfg: TransformerConfig
+               ) -> List[Tuple[str, int, Tuple[str, ...], bool]]:
+    """[(weights' key, groups, kinds of a group's layers, routed)]."""
+    win = WINDOW if cfg.sliding_window else GLOBAL
+    every = cfg.global_attn_every
+    plan = []
+    if cfg.n_dense_layers:
+        plan.append(("dense_layers", cfg.n_dense_layers, (win,), False))
+    periods = (cfg.n_layers - cfg.n_dense_layers) // every
+    if periods:
+        plan.append(("periods", periods,
+                     (win,) * (every - 1) + (GLOBAL,), cfg.is_moe))
+    return plan
+
+
+def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
+    """How many layers keep each kind of state."""
+    return {kind: sum(n * kinds.count(kind)
+                      for _, n, kinds, _ in layer_plan(cfg))
+            for kind in KINDS}
+
+
+# ---------------------------------------------------------------------------
+# Weights
+# ---------------------------------------------------------------------------
+
+def _layer_shapes(cfg: TransformerConfig, routed: bool
+                  ) -> Dict[str, Tuple[int, ...]]:
+    d, hd = cfg.d_model, cfg.head_dim
+    q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    shapes = {
+        "attn_norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
+        "wg": (d, q), "wo": (q, d), "q_norm": (hd,), "k_norm": (hd,),
+        "post_attn_norm": (d,), "ffn_norm": (d,), "post_ffn_norm": (d,),
+    }
+    if not routed:
+        f = cfg.d_ff
+        shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
+        return shapes
+    E, f = cfg.moe_experts, cfg.expert_d_ff
+    shapes.update(router=(d, E), router_bias=(E,), w_gate=(E, d, f),
+                  w_up=(E, d, f), w_down=(E, f, d))
+    if cfg.moe_shared_experts:
+        fs = f * cfg.moe_shared_experts
+        shapes.update(shared_gate=(d, fs), shared_up=(d, fs),
+                      shared_down=(fs, d))
+    return shapes
+
+
+def _group_shape(n: int, kinds: Tuple[str, ...], key: str
+                 ) -> Tuple[int, ...]:
+    return (n,) if key == "dense_layers" else (n, len(kinds))
+
+
+def num_params(cfg: TransformerConfig) -> int:
+    total = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2) \
+        + cfg.d_model
+    for key, n, kinds, routed in layer_plan(cfg):
+        total += math.prod(_group_shape(n, kinds, key)) * sum(
+            math.prod(s) for s in _layer_shapes(cfg, routed).values())
+    return total
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
+    """Scaled-normal weights as `transformer.init_params` makes them:
+    norm gains one, the selection bias zero, residual-branch outputs
+    scaled down by depth. Each leaf is drawn, scaled and cast in one
+    expression, so under jit no float32 copy of a stacked leaf is kept."""
+    pd = cfg.param_dtype
+    k_emb, k_head, k_layers = jax.random.split(key, 3)
+
+    def normal(key, shape, scale):
+        return (jax.random.normal(key, shape, dtype=jnp.float32)
+                * scale).astype(pd)
+
+    d = cfg.d_model
+    params = {"embed": normal(k_emb, (cfg.vocab_size, d), 0.02),
+              "final_norm": jnp.ones((d,), dtype=pd)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
+    plan = layer_plan(cfg)
+    for (name, n, kinds, routed), k_group in zip(
+            plan, jax.random.split(k_layers, len(plan))):
+        shapes = _layer_shapes(cfg, routed)
+        lead = _group_shape(n, kinds, name)
+        leaves = {}
+        for (leaf, shape), k in zip(
+                sorted(shapes.items()),
+                jax.random.split(k_group, len(shapes))):
+            full = lead + shape
+            if leaf.endswith("norm"):
+                leaves[leaf] = jnp.ones(full, dtype=pd)
+            elif leaf == "router_bias":
+                leaves[leaf] = jnp.zeros(full, dtype=pd)
+            elif leaf in ("wo", "w_down", "shared_down"):
+                leaves[leaf] = normal(
+                    k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
+            else:
+                leaves[leaf] = normal(k, full, 0.02)
+        params[name] = leaves
+    return params
+
+
+def cache_terms(cfg: TransformerConfig) -> int:
+    """The bf16 terms a cached key or value is kept as: two for float32
+    activations on bf16 weights (hi + lo carry 16 bits of mantissa, and
+    the products of attention take bf16), else one value of `cfg.dtype`."""
+    return 2 if (cfg.dtype == jnp.float32
+                 and cfg.param_dtype == jnp.bfloat16) else 1
+
+
+def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
+               ) -> KVCache:
+    n = cache_layers(cfg)
+    terms = cache_terms(cfg)
+
+    def zeros(layers: int, rows: int):
+        z = jnp.zeros((terms * layers, num_slots, rows, cfg.n_kv_heads,
+                       cfg.head_dim),
+                      jnp.bfloat16 if terms == 2 else cfg.dtype)
+        return wsc(z, ("layers", None, None, "act_kv_heads", None))
+
+    ring = min(cfg.sliding_window, max_seq_len)
+    return KVCache(
+        k=zeros(n[GLOBAL], max_seq_len), v=zeros(n[GLOBAL], max_seq_len),
+        seq_lens=jnp.zeros((num_slots,), jnp.int32),
+        kw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
+        vw=zeros(n[WINDOW], ring) if n[WINDOW] else None)
+
+
+# ---------------------------------------------------------------------------
+# The layer, and the walk over the stack
+# ---------------------------------------------------------------------------
+
+def _norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMS norm, float32 out whatever comes in."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def _swiglu(m: jax.Array, gate, up, down) -> jax.Array:
+    h = jax.nn.silu(_dot(m, gate)) * _dot(m, up)
+    return _dot(h.astype(m.dtype), down)
+
+
+def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, sin, cos,
+          attend, state):
+    """One layer on x (B, S, D) in the activation dtype. `attend(kind, q, k,
+    v, state) -> (out (B, S, H, Dh), state)` does the attention and
+    whatever it keeps of k and v. `experts_at`: None for a dense FFN, else
+    (the stack's expert matrices, this layer's first group in them).
+    Returns (x, state, routing stats (3,), experts chosen (B*S, K) or
+    None)."""
+    B, S, _ = x.shape
+    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    dt, eps = cfg.dtype, cfg.norm_eps
+
+    # Every product hands back float32; what lies between two products
+    # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
+    # where it enters the next product or the cache (float32: never).
+    h = _norm(x, lp["attn_norm"], eps).astype(dt)
+    q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
+    k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
+    v = _dot(h, lp["wv"]).reshape(B, S, KVH, Dh).astype(dt)
+    gate = _dot(h, lp["wg"])
+    q = _norm(q, lp["q_norm"], eps)
+    k = _norm(k, lp["k_norm"], eps)
+    if kind == WINDOW:                 # a global layer has no position
+        q, k = _rope(q, sin, cos), _rope(k, sin, cos)
+    with jax.named_scope("attn_" + kind):
+        out, state = attend(kind, q.astype(dt), k.astype(dt), v, state)
+    out = (out.reshape(B, S, H * Dh).astype(jnp.float32)
+           * jax.nn.sigmoid(gate)).astype(dt)
+    a = _dot(out, lp["wo"])
+    x = x + _norm(a, lp["post_attn_norm"], eps).astype(x.dtype)
+
+    m = _norm(x, lp["ffn_norm"], eps)                      # float32
+    experts = None
+    stats = jnp.zeros((3,), jnp.int32)
+    if experts_at is not None:
+        flat = m.reshape(B * S, -1)
+        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at)
+        if cfg.moe_shared_experts:
+            with jax.named_scope("moe_shared"):
+                f = f + _swiglu(flat.astype(dt), lp["shared_gate"],
+                                lp["shared_up"], lp["shared_down"])
+        f = f.reshape(B, S, -1)
+    else:
+        f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
+    x = x + _norm(f, lp["post_ffn_norm"], eps).astype(x.dtype)
+    return x, state, stats, experts
+
+
+def _run(cfg: TransformerConfig, params, x, sin, cos, attend, state):
+    """x through every layer: one `lax.scan` a group of the plan, a
+    group's layers unrolled in its body, `state` (the cache, or nothing)
+    riding in the carry beside x. `attend(l, kind, q, k, v, state)` is
+    told which layer of its kind it serves. Returns (x, state, routing
+    stats summed over layers, experts chosen: a tuple a group of arrays
+    (groups, B*S, K), one a routed layer of the group)."""
+    at = dict.fromkeys(KINDS, 0)       # the group's first layer, by kind
+    stats = jnp.zeros((3,), jnp.int32)
+    chosen = []
+    for name, n, kinds, routed in layer_plan(cfg):
+        stacked = params[name]
+        if name == "dense_layers":
+            stacked = jax.tree.map(lambda a: a[:, None], stacked)
+        per = {kind: kinds.count(kind) for kind in KINDS}
+        # The expert matrices stay whole, every layer's groups in one
+        # array, and are not scanned over: models/moe.grouped_experts.
+        expert_w = {k: stacked[k].reshape((-1,) + stacked[k].shape[-2:])
+                    for k in EXPERT_LEAVES} if routed else None
+        if routed:
+            stacked = {k: v for k, v in stacked.items()
+                       if k not in EXPERT_LEAVES}
+
+        def body(carry, scanned, kinds=kinds, expert_w=expert_w, per=per,
+                 base=dict(at)):
+            x, state, stats = carry
+            weights, g = scanned
+            seen = dict.fromkeys(KINDS, 0)
+            experts = []
+            for j, kind in enumerate(kinds):
+                lp = jax.tree.map(lambda a: a[j], weights)
+                l = base[kind] + g * per[kind] + seen[kind]
+                seen[kind] += 1
+                first = (g * len(kinds) + j) * cfg.moe_experts
+                x, state, st, ex = layer(
+                    cfg, lp, x, kind, expert_w and (expert_w, first), sin,
+                    cos, partial(attend, l), state)
+                stats = stats + st
+                if ex is not None:
+                    experts.append(ex)
+            return (x, state, stats), tuple(experts)
+
+        (x, state, stats), experts = lax.scan(
+            body, (x, state, stats), (stacked, jnp.arange(n)))
+        chosen.append(experts)
+        for kind in KINDS:
+            at[kind] += n * per[kind]
+    return x, state, stats, tuple(chosen)
+
+
+def _embed(cfg: TransformerConfig, params, tokens):
+    x = params["embed"][tokens].astype(jnp.float32) * math.sqrt(cfg.d_model)
+    return x.astype(cfg.dtype)
+
+
+def _final(cfg: TransformerConfig, params, x):
+    return _norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
+
+
+def head_logits(cfg: TransformerConfig, params, x) -> jax.Array:
+    """Final-normed x (..., D) -> float32 logits (..., V). The product
+    takes the head as it lies and x in its dtype: float32 activations
+    never make a float32 copy of the head (1.6 GB at 200,192 rows)."""
+    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def last_logits(cfg: TransformerConfig, params, x, lengths) -> jax.Array:
+    """Logits (W, V) at the last real position of final-normed x (W, S, D)."""
+    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
+    last = jnp.take_along_axis(
+        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
+    return head_logits(cfg, params, last[:, 0])
+
+
+_QUERY_BLOCK = 256
+
+
+def _attention_f32(q, k, v, window: int):
+    """Causal (windowed) attention of float32 q (B, S, H, Dh) over float32
+    k, v (B, S, KVH, Dh), both products at the highest precision (the
+    flash kernel multiplies in bf16), a block of queries at a time so
+    that the scores held are (B, H, block, S)."""
+    B, S, H, Dh = q.shape
+    KVH = k.shape[2]
+    blk = _QUERY_BLOCK if S % _QUERY_BLOCK == 0 else S
+    hi = lax.Precision.HIGHEST
+    qb = q.reshape(B, S // blk, blk, KVH, H // KVH, Dh)
+    j = jnp.arange(S)[None, :]
+
+    def block(args):
+        qs, start = args                                # (B, blk, KVH, G, Dh)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qs, k, precision=hi) \
+            / math.sqrt(Dh)
+        i = start + jnp.arange(blk)[:, None]
+        seen = j <= i
+        if window:
+            seen = seen & (i - j < window)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p, v, precision=hi)
+
+    out = lax.map(block, (jnp.moveaxis(qb, 1, 0),
+                          jnp.arange(S // blk) * blk))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, Dh)
+
+
+def _flash(cfg: TransformerConfig, kind: str, q, k, v):
+    w = cfg.sliding_window if kind == WINDOW else 0
+    if q.dtype == jnp.float32:
+        return _attention_f32(q, k, v, w)
+    from ..ops import flash_attention
+
+    return flash_attention(
+        q, k, v, causal=True, window=w if w and q.shape[1] > w else None)
+
+
+def _put(cfg, cache, l, slots, rows):
+    """rows (W, R, KVH, Dh) into layer l of `cache`, rows [0, R) of each
+    slot (a slot out of range is dropped)."""
+    R = rows.shape[1]
+    if cache_terms(cfg) == 1:
+        return cache.at[l, slots, :R].set(rows.astype(cache.dtype),
+                                          mode="drop")
+    hi, lo = bf16_terms(rows)
+    L = cache.shape[0] // 2
+    return cache.at[l, slots, :R].set(hi, mode="drop") \
+        .at[L + l, slots, :R].set(lo, mode="drop")
+
+
+def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
+    """Causal (windowed) attention over the tile itself, and the tile's
+    k and v into each row's slot: a global layer's at [0, S), a window
+    layer's last `ring` real positions at `position mod ring` (a row at
+    or past `length` holds padding, which decode overwrites before it
+    reads it). A row whose slot is out of range is dropped."""
+    kg, vg, kw, vw = state
+    S = q.shape[1]
+    out = _flash(cfg, kind, q, k, v)
+    if kind == GLOBAL:
+        return out, (_put(cfg, kg, l, slots, k), _put(cfg, vg, l, slots, v),
+                     kw, vw)
+    ring = kw.shape[2]
+    if S > ring:
+        r = jnp.arange(ring)[None, :]
+        last = (lengths - 1).astype(jnp.int32)[:, None]
+        # Ring row r keeps the latest real position congruent to r.
+        src = jnp.where(r <= last, r + ring * ((last - r) // ring), r)
+        src = src[:, :, None, None]
+        k = jnp.take_along_axis(k, src, axis=1)
+        v = jnp.take_along_axis(v, src, axis=1)
+    return out, (kg, vg, _put(cfg, kw, l, slots, k),
+                 _put(cfg, vw, l, slots, v))
+
+
+def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions):
+    """`generate._attend_cache` over a cache of two bf16 terms (`k_all`
+    (2L, B, S, KVH, Dh): see `cache_terms`), q, k, v float32: every
+    product takes bf16 operands, the float32 side (q, then the
+    probabilities) as two terms stacked beside the heads of a group, the
+    cached side as its two slabs, one product each."""
+    L, B, S = k_all.shape[0] // 2, k_all.shape[1], k_all.shape[2]
+    KVH, Dh = cfg.n_kv_heads, cfg.head_dim
+    G = cfg.n_heads // KVH
+    at = (jnp.stack([l, L + l])[:, None], jnp.arange(B)[None, :],
+          write_at[None, :])
+    k_all = k_all.at[at].set(bf16_terms(k[:, 0]), mode="drop")
+    v_all = v_all.at[at].set(bf16_terms(v[:, 0]), mode="drop")
+
+    def against(x, eq, cached):       # x (B, KVH, G, .) float32
+        two = jnp.concatenate(list(bf16_terms(x)), axis=2)
+        y = sum(jnp.einsum(eq, two, lax.dynamic_index_in_dim(
+            cached, i, 0, keepdims=False),
+            preferred_element_type=jnp.float32) for i in (l, L + l))
+        return y[:, :, :G] + y[:, :, G:]
+
+    scores = against(q.reshape(B, KVH, G, Dh), "bkgd,bskd->bkgs", k_all) \
+        / (Dh ** 0.5)
+    valid = jnp.arange(S)[None, :] <= positions[:, None]
+    probs = jax.nn.softmax(
+        jnp.where(valid[:, None, None, :], scores, -jnp.inf), axis=-1)
+    out = against(probs, "bkgs,bskd->bkgd", v_all)
+    return out.reshape(B, 1, KVH * G * Dh), k_all, v_all
+
+
+def _decode_attend(cfg, positions, l, kind, q, k, v, state):
+    kg, vg, kw, vw = state
+    attend = _attend_terms if cache_terms(cfg) == 2 else _attend_cache
+    if kind == GLOBAL:
+        out, kg, vg = attend(cfg, q, k, v, kg, vg, l, positions, positions)
+    else:
+        out, kw, vw = attend(cfg, q, k, v, kw, vw, l,
+                             positions % kw.shape[2], positions)
+    B = q.shape[0]
+    return out.reshape(B, 1, cfg.n_heads, cfg.head_dim), (kg, vg, kw, vw)
+
+
+def _free_attend(cfg, l, kind, q, k, v, state):
+    return _flash(cfg, kind, q, k, v), state
+
+
+# ---------------------------------------------------------------------------
+# What generate.py's programs call
+# ---------------------------------------------------------------------------
+
+def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
+            slots) -> Tuple[KVCache, jax.Array]:
+    """tokens (W, S) into the slots' cache rows -> (cache', final-normed
+    hidden states (W, S, D))."""
+    S = tokens.shape[1]
+    sin, cos = rope_tables(cfg, S)
+    x, (kg, vg, kw, vw), _, _ = _run(
+        cfg, params, _embed(cfg, params, tokens), sin, cos,
+        partial(_prefill_attend, cfg, slots, lengths),
+        (cache.k, cache.v, cache.kw, cache.vw))
+    seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
+    return KVCache(k=kg, v=vg, seq_lens=seq_lens, kw=kw, vw=vw), \
+        _final(cfg, params, x)
+
+
+def forward_free(cfg: TransformerConfig, params, tokens):
+    """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
+    D), the experts every routed layer chose: see `_run`)."""
+    sin, cos = rope_tables(cfg, tokens.shape[1])
+    x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), sin,
+                           cos, partial(_free_attend, cfg), None)
+    return _final(cfg, params, x), chosen
+
+
+def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
+           ) -> Tuple[KVCache, jax.Array, jax.Array]:
+    """One token a slot -> (cache', final-normed hidden states (B, 1, D),
+    routing stats of the step (3,): experts holding a row summed over the
+    routed layers, rows routed, and the fullest expert's rows summed over
+    the layers)."""
+    positions = cache.seq_lens
+    sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
+    sin, cos = sin_t[positions][:, None, :], cos_t[positions][:, None, :]
+    x, (kg, vg, kw, vw), stats, _ = _run(
+        cfg, params, _embed(cfg, params, tokens)[:, None, :], sin, cos,
+        partial(_decode_attend, cfg, positions),
+        (cache.k, cache.v, cache.kw, cache.vw))
+    return KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw), \
+        _final(cfg, params, x), stats
+
+
+def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
+    """For tests and for telling a routing flip from arithmetic: the
+    experts each routed layer chose for tokens (S,), in layer order, each
+    (S, K)."""
+    _, chosen = jax.jit(partial(forward_free, cfg))(
+        params, jnp.asarray(tokens, jnp.int32)[None])
+    out = []
+    for group in chosen:
+        if group:
+            n = group[0].shape[0]
+            out.extend(group[j][g] for g in range(n)
+                       for j in range(len(group)))
+    return out

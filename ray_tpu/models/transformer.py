@@ -64,16 +64,52 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_loss_weight: float = 0.02
+    # 0 = d_model // n_heads (set in __post_init__).
+    head_dim: int = 0
+    # "llama": every layer alike (pre-norm, rotary, GQA, SwiGLU or the
+    # routed FFN above). "afmoe": the period stack of models/periodic.py
+    # (leading dense layers, then periods of window layers closed by a
+    # global one; QK-norm, a gated attention output, four norms a layer,
+    # rotary on window layers only, a scaled embedding). Served only:
+    # forward / loss_fn raise for it.
+    arch: str = "llama"
+    n_dense_layers: int = 0          # leading layers with a dense FFN
+    global_attn_every: int = 0       # period length; its last layer is global
+    sliding_window: int = 0          # keys a window layer sees (0 = all)
+    moe_d_ff: int = 0                # width of a routed / shared expert
+    moe_shared_experts: int = 0      # always-on experts of width moe_d_ff
+    score_func: str = "softmax"      # router scores: "softmax" | "sigmoid"
+    route_norm: bool = True          # chosen scores renormalised to sum 1
+    route_scale: float = 1.0
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.arch not in ("llama", "afmoe"):
+            raise ValueError(f"arch must be 'llama' or 'afmoe', got "
+                             f"{self.arch!r}")
+        if self.arch == "afmoe":
+            body = self.n_layers - self.n_dense_layers
+            if self.global_attn_every < 1 or body < 0 \
+                    or body % self.global_attn_every:
+                raise ValueError(
+                    f"afmoe: n_layers - n_dense_layers ({body}) must be "
+                    f"whole periods of global_attn_every "
+                    f"({self.global_attn_every})")
 
     @property
     def is_moe(self) -> bool:
         return self.moe_experts > 0
 
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
     def num_params(self) -> int:
+        if self.arch == "afmoe":
+            from .periodic import num_params
+            return num_params(self)
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layers
         hd = self.head_dim
         attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
@@ -119,6 +155,10 @@ def _dense_layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
 
 def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Same pytree structure as params, leaves = logical-axis tuples."""
+    if cfg.arch == "afmoe":
+        raise NotImplementedError(
+            "arch 'afmoe' has no sharding rules yet: it is served on one "
+            "chip (models/periodic.py)")
     if cfg.is_moe:
         ffn_axes = {
             "router": ("layers", "embed", "expert"),
@@ -153,6 +193,9 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     """Scaled-normal init; layer params stacked on a leading L axis for
     lax.scan."""
+    if cfg.arch == "afmoe":
+        from .periodic import init_params as init_periodic
+        return init_periodic(cfg, key)
     pd = cfg.param_dtype
     k_emb, k_layers, k_head = jax.random.split(key, 3)
 
@@ -367,6 +410,13 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
     """tokens (B, S) int32 → (final hidden states (B, S, D), aux_loss)
     — the trunk without the vocab projection (the chunked-CE loss
     applies the head blockwise instead of materializing logits)."""
+    if cfg.arch == "afmoe":
+        raise NotImplementedError(
+            "arch 'afmoe' is served only (models/generate.py): training "
+            "lacks a dropless routed layer under autodiff (moe_ffn drops "
+            "tokens over capacity), the backward of windowed flash "
+            "attention, and the load-balancing update of the selection "
+            "bias")
     B, S = tokens.shape
     # Constrain the table to replicated for the lookup: the stored param
     # is (vocab→tp, embed→fsdp)-sharded, and a gather from an

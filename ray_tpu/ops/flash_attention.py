@@ -67,7 +67,7 @@ def on_tpu() -> bool:
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k,
-                num_kv, causal):
+                num_kv, causal, window=None):
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -92,6 +92,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                      + lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 1))
             mask = q_pos >= k_pos
+            if window is not None:
+                mask = mask & (q_pos - k_pos < window)
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -113,8 +115,14 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # Block skip: whole kv block above the diagonal → no compute.
         last_q = q_off + (qi + 1) * block_q - 1
         first_k = kv_off + ki * block_k
+        live = last_q >= first_k
+        if window is not None:
+            # ... and whole kv block behind every query's window.
+            first_q = q_off + qi * block_q
+            last_k = first_k + block_k - 1
+            live = live & (first_q - last_k < window)
 
-        @pl.when(last_q >= first_k)
+        @pl.when(live)
         def _():
             compute()
     else:
@@ -129,7 +137,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
-              interpret) -> Tuple[jax.Array, jax.Array]:
+              interpret, window=None) -> Tuple[jax.Array, jax.Array]:
     """q,k,v: (B, H, S, D) (kv heads already expanded). → (out, lse)."""
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
@@ -137,7 +145,7 @@ def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
     grid = (B, H, nq, nk)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, num_kv=nk, causal=causal)
+        block_k=block_k, num_kv=nk, causal=causal, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -362,14 +370,17 @@ def _bwd_impl(q, k, v, do, out, lse, offs, *, sm_scale, block_q, block_k,
 # Reference fallback (pure jnp — differentiable, XLA-fused)
 # ---------------------------------------------------------------------------
 
-def _reference(q, k, v, offs, *, sm_scale, causal):
+def _reference(q, k, v, offs, *, sm_scale, causal, window=None):
     """(B, H, S, D) layout. Returns (out, lse)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         Sq, Skv = q.shape[2], k.shape[2]
         q_pos = offs[0, 0].astype(jnp.int32) + jnp.arange(Sq)[:, None]
         k_pos = offs[0, 1].astype(jnp.int32) + jnp.arange(Skv)[None, :]
-        s = jnp.where((q_pos >= k_pos)[None, None], s, NEG_INF)
+        mask = q_pos >= k_pos
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
+        s = jnp.where(mask[None, None], s, NEG_INF)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
@@ -380,35 +391,41 @@ def _reference(q, k, v, offs, *, sm_scale, causal):
 # custom-VJP wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, offs, causal, sm_scale, block_q, block_k, use_pallas,
-           interpret):
+           interpret, window=None):
     out, _ = _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k,
-                        use_pallas, interpret)[0], None
+                        use_pallas, interpret, window)[0], None
     return out
 
 
 def _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k,
-               use_pallas, interpret):
+               use_pallas, interpret, window=None):
     if use_pallas:
         out, lse = _fwd_impl(q, k, v, offs, sm_scale=sm_scale,
                              block_q=block_q, block_k=block_k,
-                             causal=causal, interpret=interpret)
+                             causal=causal, interpret=interpret,
+                             window=window)
     else:
         out, lse = _reference(q, k, v, offs, sm_scale=sm_scale,
-                              causal=causal)
+                              causal=causal, window=window)
     return out, (q, k, v, offs, out, lse)
 
 
 def _flash_fwd_rule(q, k, v, offs, causal, sm_scale, block_q, block_k,
-                    use_pallas, interpret):
+                    use_pallas, interpret, window=None):
     out, res = _flash_fwd(q, k, v, offs, causal, sm_scale, block_q,
-                          block_k, use_pallas, interpret)
+                          block_k, use_pallas, interpret, window)
     return out, res
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, use_pallas,
-                    interpret, res, g):
+                    interpret, window, res, g):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention: the backward pass is not written for a "
+            "window (the dq and dkv kernels mask causally only)")
     q, k, v, offs, out, lse = res
     if use_pallas:
         dq, dk, dv = _bwd_impl(q, k, v, g, out, lse, offs,
@@ -464,6 +481,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 256, block_k: int = 512,
                     q_offset=0, kv_offset=0,
+                    window: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     force_reference: bool = False,
                     force_pallas: bool = False) -> jax.Array:
@@ -472,6 +490,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     q: (B, Sq, H, D); k, v: (B, Skv, KVH, D) with H % KVH == 0 (GQA).
     Offsets are *global token positions* of element 0 of the q / kv
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
+    With `window`, a query also sees no key more than `window - 1`
+    positions behind it: (q_offset + i) - (kv_offset + j) < window.
+    Forward only; kv blocks wholly behind the window are skipped.
     Returns (B, Sq, H, D).
 
     `interpret=None` compiles the kernels on a TPU and takes the
@@ -483,6 +504,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Skv = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
+    if window is not None and (not causal or window < 1):
+        raise ValueError("flash_attention: a window needs causal=True "
+                         f"and at least one position, got {window!r}")
 
     qt = jnp.swapaxes(q, 1, 2)
     kt = _expand_kv(jnp.swapaxes(k, 1, 2), H)
@@ -510,7 +534,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     DISPATCH_COUNTS[path] += 1
     offs = jnp.asarray([[q_offset, kv_offset]], jnp.float32)
     out = _flash(qt, kt, vt, offs, causal, sm_scale, bq, bk,
-                 path.startswith("pallas"), not compiled)
+                 path.startswith("pallas"), not compiled, window)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -48,6 +48,7 @@ from ..models.generate import (
     init_kv_cache,
     prefill_sample_batch,
     prefill_sample_batch_lp,
+    reports_routing,
     prefill_suffix_batch,
     prefill_suffix_batch_lp,
 )
@@ -321,6 +322,16 @@ class LLMEngine:
             "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
             "prefill_tile_rows": 0, "prefill_tokens": 0,
             "prefill_tile_tokens": 0, "queue_side_first_tokens": 0}
+        # A routed period stack's decode blocks report how their experts
+        # were used (models/generate.reports_routing).
+        self._routed = reports_routing(cfg)
+        if self._routed:
+            from ..models.periodic import layer_plan
+            self._routed_layers = sum(
+                n * len(kinds) for _, n, kinds, routed in layer_plan(cfg)
+                if routed)
+            self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
+                               moe_rows=0, moe_rows_max=0)
         # The last FINISHED_RING completed requests (ttft percentiles
         # in stats() are over these).
         self.finished: deque = deque(maxlen=self.FINISHED_RING)
@@ -1019,8 +1030,8 @@ class LLMEngine:
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots):
             self._key, sub = jax.random.split(self._key)
-            lps = None
-            if k_block == 1:
+            lps = moe = None
+            if k_block == 1 and not self._routed:
                 self.cache, logits = decode_step(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens)
@@ -1032,19 +1043,20 @@ class LLMEngine:
                     toks = _sample_batch(logits, self._temps, sub,
                                          self.top_k)[None]  # (1, B)
             elif self.capture_logprobs:
-                self.cache, toks, lps = decode_multi_lp(
+                self.cache, toks, lps, *moe = decode_multi_lp(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens, self._temps, k_block,
                     self.top_k, sub)                       # (k, B)
             else:
-                self.cache, toks = decode_multi(
+                self.cache, toks, *moe = decode_multi(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens, self._temps, k_block,
                     self.top_k, sub)                       # (k, B)
+            moe = moe[0] if moe else None     # routing stats (3,)
             self.cur_tokens = toks[-1]
             # Start the host copy NOW, before the next tick enqueues
             # prefills and the next block behind it.
-            for arr in ((toks,) if lps is None else (toks, lps)):
+            for arr in (a for a in (toks, lps, moe) if a is not None):
                 try:
                     arr.copy_to_host_async()
                 except Exception:  # noqa: BLE001 — no async copy
@@ -1052,7 +1064,8 @@ class LLMEngine:
             self.decode_ticks += k_block
             for i in active:
                 snap[i].inflight += k_block
-        return (toks, lps, k_block, [(i, snap[i]) for i in active], number)
+        return (toks, lps, k_block, [(i, snap[i]) for i in active], number,
+                moe)
 
     def _process_block(self, block) -> None:
         """Fetch a dispatched decode block's tokens and emit them.
@@ -1062,13 +1075,14 @@ class LLMEngine:
         block was in flight now holds a different request, and the
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
-        toks, lps, k_block, slot_snap, number = block
+        toks, lps, k_block, slot_snap, number, moe = block
         span = tracing.span("engine.process_block", block=number, k=k_block,
                             slots=self.num_slots, active=len(slot_snap))
         with span:
             with tracing.span("engine.fetch"):  # the host waits here
                 host_toks = np.asarray(toks)
                 host_lps = np.asarray(lps) if lps is not None else None
+                host_moe = np.asarray(moe) if moe is not None else None
             self.steps_processed += k_block
             before = self.tokens_out
             self._emit_block(host_toks, host_lps, k_block, slot_snap)
@@ -1076,6 +1090,18 @@ class LLMEngine:
             discarded = k_block * len(slot_snap) - emitted
             self.counts["tokens_discarded"] += discarded
             span.set(emitted=emitted, discarded=discarded)
+            if host_moe is not None:
+                # Expert-steps a block offers: steps x routed layers x
+                # experts; the program says how many held a row.
+                routed = dict(
+                    moe_expert_steps=k_block * self._routed_layers
+                    * self.cfg.moe_experts,
+                    moe_experts_hit=int(host_moe[0]),
+                    moe_rows=int(host_moe[1]),
+                    moe_rows_max=int(host_moe[2]))
+                for name, n in routed.items():
+                    self.counts[name] += n
+                span.set(**routed)
 
     def _emit_block(self, host_toks, host_lps, k_block: int,
                     slot_snap: List) -> None:

@@ -341,3 +341,131 @@ def test_ring_attention_compiles_with_kernels_on_four_chips(topo,
         q, k, k).compile().as_text()
     assert fa.DISPATCH_COUNTS["ring_pallas"] == before + 1
     assert "tpu_custom_call" in text and "collective-permute" in text
+
+
+# -- the routed period stack (benchmarks/cells/trinity-mini-reason-closed) ---
+
+@pytest.fixture(scope="module")
+def serve_trinity(topo):
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from lib import modelcfg
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "trinity-mini-l5.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "trinity-mini-reason-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = modelcfg.transformer_config(config, sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """The grouped products take megablox's kernel, as on the chip
+    (`models/moe.grouped_dot` asks `on_tpu()`)."""
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+
+
+def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
+        serve_trinity, as_on_the_chip):
+    """`decode_multi` (k = 8) at the cell's 32 slots x 4096, bf16 weights
+    under float32 activations, all 128 experts of four layers and the
+    200,192-row head on one chip: it fits; both kinds of cache (global
+    rows of S_max, window rings of 2,048; two bf16 terms a row, so twice
+    the layers) are carried and aliased, written by scatters only and
+    never copied whole; and no layer's experts are sliced out of the
+    stack or cast to float32 (a copy of one layer's three matrices is
+    0.8 GB: the temporaries stay below one)."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = serve_trinity
+    assert cfg.dtype == jnp.float32 and cfg.param_dtype == jnp.bfloat16
+    assert cache.k.shape == (2, 32, 4096, 4, 128)
+    assert cache.kw.shape == (8, 32, 2048, 4, 128)
+    assert cache.k.dtype == cache.kw.dtype == jnp.bfloat16
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key).compile()
+    text = compiled.as_text()
+    mem, writes = compiled.memory_analysis(), _device_writes(text)
+    assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+
+    def nbytes(x):
+        return x.size * x.dtype.itemsize
+
+    held = sum(nbytes(x) for x in (cache.k, cache.v, cache.kw, cache.vw))
+    for whole_shape in (cache.k.shape, cache.kw.shape):
+        shapes = {whole_shape}
+        whole = [w for shape in shapes for w in writes.get(shape, ())
+                 if w[1] not in ("parameter", "get-tuple-element", "bitcast")]
+        assert whole, whole_shape
+        assert all(op == "fusion" and "scatter" in body
+                   and not body & {"copy", "dynamic-update-slice"}
+                   for _, op, body in whole), whole
+    experts = params["periods"]["w_gate"]
+    one_layer = nbytes(experts) // experts.shape[1]
+    assert not [w for dims in writes
+                if len(dims) >= 3 and dims[-3:] == experts.shape[-3:]
+                for w in writes[dims]
+                if w[1] not in ("parameter", "get-tuple-element", "bitcast")]
+    # 0.55 GB: staged cache slabs of 67 and 134 MB, scores of 33 MB.
+    assert mem.temp_size_in_bytes < 1.5 * one_layer
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def _trinity_lowerings(serve_trinity):
+    from ray_tpu.models.generate import (first_token_sample, prefill,
+                                         prefill_sample_batch)
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_trinity
+    W = LLMEngine._ADMIT_TILE
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return {
+        "tile": lambda: prefill_sample_batch.lower(
+            cfg, params, cache, arr((W, 1024), jnp.int32),
+            arr((W,), jnp.int32), arr((W,), jnp.int32), 0,
+            arr((W,), jnp.float32), key),
+        "queue_side": lambda: first_token_sample.lower(
+            cfg, params, arr((W, 1024), jnp.int32), arr((W,), jnp.int32),
+            arr((W,), jnp.float32), 0, key),
+        "long_prefill": lambda: prefill.lower(
+            cfg, params, cache, arr((1, 4096), jnp.int32),
+            arr((), jnp.int32), arr((), jnp.int32)),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "tile",
+    pytest.param("queue_side", marks=pytest.mark.slow),
+    pytest.param("long_prefill", marks=pytest.mark.slow)])
+def test_trinity_prefill_programs_fit(serve_trinity, as_on_the_chip,
+                                      program):
+    """The 8 x 1024 admission tile (65,536 token-expert pairs a layer
+    through the grouped products); with `-m slow` also the queue side's
+    cache-free first token and the reference check's one-row prefill at
+    4096 (some 20 s of compile each). `on_tpu()` is false here, so the
+    long prefill takes the reference path in this compile; the kernel
+    with a window is compiled below."""
+    mem = _trinity_lowerings(serve_trinity)[program]().compile() \
+        .memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_windowed_flash_forward_compiles(topo):
+    """The forward kernel with a window of 2,048 at the cell's long
+    prefill: 32 Q / 4 KV heads of 128 over 4,096 positions."""
+    q, k = _qkv(topo, 4096, 4096, 32, 4, 128)
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, window=2048, interpret=False)).lower(
+            q, k, k).compile().as_text()
+    assert "tpu_custom_call" in text
