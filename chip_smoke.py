@@ -585,7 +585,7 @@ def _prefill_logits(cfg, params, prompts, mesh=None):
 
     W, R = LLMEngine._ADMIT_TILE, len(prompts)
     bucket = max(len(p) for p in prompts)
-    buf, lens, _ = LLMEngine._build_tile(bucket,
+    buf, lens, _ = LLMEngine._build_tile(bucket, W,
                                          [(p, 0.0) for p in prompts])
     slot_idx = np.full((W,), W, np.int32)   # padding rows drop
     slot_idx[:R] = np.arange(R)

@@ -394,12 +394,16 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     """Prefill a BATCH of padded prompts (W, S_bucket) into their cache
     slots and sample each one's first token in ONE dispatch.
 
-    Admission waves are the engine's second-largest device cost: each
-    single-sequence prefill streams the full weights from HBM, so W
-    serial prefills cost ~W× one batched prefill (memory-bound). Rows
-    whose slot index is out of range (the fixed-W tile's padding) are
-    dropped by the scatter and their sampled token is garbage the
-    caller ignores. Compiles once per (W, S_bucket)."""
+    Every row shares one read of the weights. While that read bounds
+    the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
+    819 GB/s, two operations a position for a bf16 weight's two bytes)
+    W serial prefills cost ~W× one batched prefill; past it every row,
+    padding too, costs its own arithmetic. So the engine chooses W by
+    the bucket (serve/llm.py, `LLMEngine._tile_rows`): wide tiles of
+    short buckets, one row from 512 positions up. Rows whose slot index
+    is out of range (the tile's padding) are dropped by the scatter and
+    their sampled token is garbage the caller ignores. Compiles once
+    per (W, S_bucket)."""
     cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
                                         lengths, slots)
     toks = sample(logits, key, temperature=temps, top_k=top_k)

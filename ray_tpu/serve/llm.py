@@ -562,12 +562,13 @@ class LLMEngine:
                     return key, entry
         return None
 
-    def _group_by_route(self, items: List, prompt_of):
+    def _group_by_route(self, items: List, prompt_of, width):
         """Shared admission/early-token routing: split items into
-        full-prefill tiles and prefix-suffix tiles (fixed W rows each).
-        Returns (full [(bucket, chunk)], suffix [(pkey, entry, bucket,
-        chunk)]) — ONE implementation so the two call sites can never
-        route the same prompt differently."""
+        full-prefill tiles and prefix-suffix tiles, a bucket's items cut
+        into chunks of `width(bucket)` rows (a suffix tile's bucket is
+        the suffix's). Returns (full [(bucket, chunk)], suffix [(pkey,
+        entry, bucket, chunk)]) — ONE implementation so the two call
+        sites can never route the same prompt differently."""
         by_bucket: Dict[int, List] = {}
         by_prefix: Dict[tuple, List] = {}
         entries: Dict[tuple, Dict[str, Any]] = {}
@@ -581,10 +582,13 @@ class LLMEngine:
             else:
                 by_bucket.setdefault(
                     self._bucket_for(len(prompt)), []).append(it)
-        W = self._ADMIT_TILE
-        full = [(b, p[off:off + W])
-                for b, p in sorted(by_bucket.items())
-                for off in range(0, len(p), W)]
+
+        def cut(bucket, its):
+            W = width(bucket)
+            return [its[off:off + W] for off in range(0, len(its), W)]
+
+        full = [(b, chunk) for b, p in sorted(by_bucket.items())
+                for chunk in cut(b, p)]
         suffix = []
         for pkey, its in by_prefix.items():
             sub: Dict[int, List] = {}
@@ -592,10 +596,9 @@ class LLMEngine:
                 sub.setdefault(
                     self._bucket_for(len(prompt_of(it)) - len(pkey)),
                     []).append(it)
-            for b, p in sorted(sub.items()):
-                for off in range(0, len(p), W):
-                    suffix.append((pkey, entries[pkey], b,
-                                   p[off:off + W]))
+            suffix += [(pkey, entries[pkey], b, chunk)
+                       for b, p in sorted(sub.items())
+                       for chunk in cut(b, p)]
         return full, suffix
 
     # -- engine internals ---------------------------------------------
@@ -642,8 +645,29 @@ class LLMEngine:
         self._complete(slot.req, slot.emitted)
         self.slots[idx] = None
 
-    _ADMIT_TILE = 8  # fixed batch tile: ONE compile per bucket, ever
+    # The widest prefill tile, and the width of every queue-side tile
+    # (_early_first_tokens). Rows share one read of the weights, which
+    # pays while that read bounds the tile: a bf16 weight is 2 bytes and
+    # 2 operations a position, so a tile of P positions does P operations
+    # a byte, and a v5e turns from memory-bound to compute-bound at
+    # 197 TFLOP/s / 819 GB/s = 240. Under ~240 positions a tile more rows
+    # are free; past it each row costs its own arithmetic, real or not.
+    _ADMIT_TILE = 8
+    # Positions a slot-side tile is filled up to: the next power of two
+    # past the ridge with room (256 measured against 512: PERF.md,
+    # section 6, PR 29). _tile_rows() makes the width from it.
+    _TILE_POSITIONS = 512
     FINISHED_RING = 1024
+
+    @classmethod
+    def _tile_rows(cls, bucket: int) -> int:
+        """Rows of a slot-side admission tile of `bucket` positions a
+        row: a function of the bucket alone, so there is ONE program a
+        bucket and a lone request runs the program a full tile runs.
+        Buckets up to 64 keep _ADMIT_TILE rows, 128 gets 4, 256 gets 2,
+        512 and longer 1: several requests of a long bucket are several
+        tiles in one tick, at the same arithmetic."""
+        return max(1, min(cls._ADMIT_TILE, cls._TILE_POSITIONS // bucket))
 
     def _touch(self, reqs: Sequence[GenRequest]) -> None:
         """Stamp the requests that engine compute touches for the first
@@ -655,13 +679,13 @@ class LLMEngine:
                 req.admit_tick = self.counts["ticks"] - 1
                 req.steps_waited = self.decode_ticks - req._steps_seen
 
-    def _tile_span(self, side: str, bucket: int, reqs: Sequence[GenRequest],
-                   skip: int = 0) -> tracing.span:
-        """The span of one prefill tile (build, transfer, program call),
-        with its counts: `rows` real of `tile_rows`, `tokens` real prompt
-        tokens of tile_rows x bucket computed (`skip`: tokens a cached
-        prefix already holds)."""
-        W = self._ADMIT_TILE
+    def _tile_span(self, side: str, bucket: int, W: int,
+                   reqs: Sequence[GenRequest], skip: int = 0
+                   ) -> tracing.span:
+        """The span of one prefill tile of W rows (build, transfer,
+        program call), with its counts: `rows` real of `tile_rows`,
+        `tokens` real prompt tokens of tile_rows x bucket computed
+        (`skip`: tokens a cached prefix already holds)."""
         tokens = sum(len(r.prompt) - skip for r in reqs)
         c = self.counts
         c["prefill_tiles"] += 1
@@ -676,14 +700,13 @@ class LLMEngine:
             tile_rows=W, tokens=tokens,
             req_ids=" ".join(str(r.id) for r in reqs))
 
-    @classmethod
-    def _build_tile(cls, bucket: int, rows: Sequence):
-        """Pad up to _ADMIT_TILE token lists into one (W, bucket) host
-        tile (+ lengths and temps). rows: [(tokens, temperature)].
+    @staticmethod
+    def _build_tile(bucket: int, W: int, rows: Sequence):
+        """Pad up to W token lists into one (W, bucket) host tile
+        (+ lengths and temps). rows: [(tokens, temperature)].
         Padding on the HOST: an eager .at[].set() per prompt would
         compile a scatter kernel per distinct length (seconds each);
         numpy + one transfer doesn't."""
-        W = cls._ADMIT_TILE
         buf = np.zeros((W, bucket), np.int32)
         lens = np.ones((W,), np.int32)
         temps = np.zeros((W,), np.float32)
@@ -697,11 +720,13 @@ class LLMEngine:
     def _admit(self) -> List:
         """Prefill waiting requests into free slots (arrival order).
 
-        Admissions are BATCHED per prompt-length bucket into fixed
-        W-row tiles and dispatched through prefill_sample_batch — a
-        single-sequence prefill streams the full weights from HBM, so
-        per-slot serial prefills made admission waves cost ~W× more
-        device time than one batched call. All dispatches are async;
+        Admissions are BATCHED per prompt-length bucket into tiles of
+        _tile_rows(bucket) rows and dispatched through
+        prefill_sample_batch. Rows share one read of the weights, so in
+        a short bucket (a tile under ~240 positions: memory-bound) W
+        serial prefills would cost ~W x one batched call; a long
+        bucket's row is compute-bound alone, and there each request is
+        its own one-row tile, several a tick. All dispatches are async;
         first tokens are fetched later by _deliver_first_tokens with
         one fused host sync. Requests whose first token was already
         served by _early_first_tokens() are prefilled in the same
@@ -721,15 +746,16 @@ class LLMEngine:
         # Route: prompts strictly extending a registered prefix go
         # through the suffix path (prefix KV copied, only the suffix
         # prefilled); the rest through the full path.
-        W = self._ADMIT_TILE
         full, suffix = self._group_by_route(
-            list(zip(take, free)), lambda it: it[0].prompt)
-        chunks: List = [("full", bucket, None, chunk)
+            list(zip(take, free)), lambda it: it[0].prompt,
+            self._tile_rows)
+        chunks: List = [(bucket, None, None, chunk)
                         for bucket, chunk in full]
-        chunks += [("suffix", (pkey, bucket), entry, chunk)
+        chunks += [(bucket, pkey, entry, chunk)
                    for pkey, entry, bucket, chunk in suffix]
 
-        for ci, (kind, binfo, entry, chunk) in enumerate(chunks):
+        for ci, (bucket, pkey, entry, chunk) in enumerate(chunks):
+            W = self._tile_rows(bucket)
             # Padding rows scatter out of bounds (slot==num_slots) and
             # are dropped on device.
             slot_idx = np.full((W,), self.num_slots, np.int32)
@@ -739,11 +765,10 @@ class LLMEngine:
             lps = None
             reqs = [req for req, _ in chunk]
             try:
-                if kind == "full":
-                    bucket = binfo
-                    with self._tile_span("slot", bucket, reqs):
+                if pkey is None:
+                    with self._tile_span("slot", bucket, W, reqs):
                         buf, lens, temps = self._build_tile(
-                            bucket,
+                            bucket, W,
                             [(req.prompt, req.temperature)
                              for req in reqs])
                         args = (self.cfg, self.params, self.cache,
@@ -756,11 +781,10 @@ class LLMEngine:
                         else:
                             self.cache, toks = prefill_sample_batch(*args)
                 else:
-                    pkey, bucket = binfo
                     sp = len(pkey)
-                    with self._tile_span("slot", bucket, reqs, skip=sp):
+                    with self._tile_span("slot", bucket, W, reqs, skip=sp):
                         buf, lens, temps = self._build_tile(
-                            bucket,
+                            bucket, W,
                             [(req.prompt[sp:], req.temperature)
                              for req in reqs])
                         args = (self.cfg, self.params, self.cache,
@@ -823,11 +847,14 @@ class LLMEngine:
         # purposes (prefill starts here).
         self._touch(todo)
         outs = []
-        full, suffix = self._group_by_route(todo, lambda r: r.prompt)
+        # Queue-side tiles stay _ADMIT_TILE wide in every bucket.
+        W = self._ADMIT_TILE
+        full, suffix = self._group_by_route(todo, lambda r: r.prompt,
+                                            lambda bucket: W)
         for bucket, chunk in full:
-            with self._tile_span("queue", bucket, chunk):
+            with self._tile_span("queue", bucket, W, chunk):
                 buf, lens, temps = self._build_tile(
-                    bucket, [(r.prompt, r.temperature) for r in chunk])
+                    bucket, W, [(r.prompt, r.temperature) for r in chunk])
                 self._key, sub = jax.random.split(self._key)
                 args = (self.cfg, self.params, jnp.asarray(buf),
                         jnp.asarray(lens), jnp.asarray(temps), self.top_k,
@@ -841,10 +868,10 @@ class LLMEngine:
         # the stored prefix KV (same FLOP saving as slot admission).
         for pkey, entry, bucket, chunk in suffix:
             sp = len(pkey)
-            with self._tile_span("queue", bucket, chunk, skip=sp):
+            with self._tile_span("queue", bucket, W, chunk, skip=sp):
                 buf, lens, temps = self._build_tile(
-                    bucket, [(r.prompt[sp:], r.temperature)
-                             for r in chunk])
+                    bucket, W, [(r.prompt[sp:], r.temperature)
+                                for r in chunk])
                 self._key, sub = jax.random.split(self._key)
                 args = (self.cfg, self.params, entry["k"], entry["v"],
                         jnp.asarray(buf), jnp.asarray(lens),

@@ -199,6 +199,71 @@ def test_counts_equal_the_sums_of_the_span_attributes(drained):
     assert all("," not in t["req_ids"] for t in tiles)
 
 
+# What an admission tick builds: (prompt lengths submitted together,
+# slots, the slot tiles as (bucket, rows, tile_rows)). Buckets of a
+# 640-row engine: 16 ... 512, 640; a tile holds `_TILE_POSITIONS` = 512
+# positions, at most `_ADMIT_TILE` = 8 rows and at least one.
+TILES = {
+    "a_lone_long_prompt_is_one_row": ([300], 2, [(512, 1, 1)]),
+    "eight_of_the_smallest_bucket_share_a_tile": (
+        [3, 5, 16, 9, 2, 11, 7, 4], 8, [(16, 8, 8)]),
+    "three_of_a_one_row_bucket_are_three_tiles": (
+        [300, 400, 512], 3, [(512, 1, 1)] * 3),
+    "five_of_the_128_bucket_are_two_tiles_of_four": (
+        [100] * 5, 5, [(128, 4, 4), (128, 1, 4)]),
+    "three_of_the_256_bucket_are_two_tiles_of_two": (
+        [200, 129, 256], 4, [(256, 2, 2), (256, 1, 2)]),
+    "past_the_last_power_of_two_is_one_row": ([600], 1, [(640, 1, 1)]),
+    "a_mixed_wave_is_cut_bucket_by_bucket": (
+        [10, 300, 12, 120, 301], 8,
+        [(16, 2, 8), (128, 1, 4), (512, 1, 1), (512, 1, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILES))
+def test_a_slot_tile_is_as_wide_as_its_bucket_needs(tiny_model, hook, case):
+    """The wave is admitted by one tick, every request streams all its
+    tokens, and the counts are the sums of the spans' attributes."""
+    cfg, params = tiny_model
+    lens, slots, want = TILES[case]
+    engine = LLMEngine(cfg, params, num_slots=slots, max_seq_len=640,
+                       decode_block=4)
+    reqs = [engine.submit([1 + (i + j) % 250 for j in range(n)],
+                          max_new_tokens=3) for i, n in enumerate(lens)]
+    engine.step()
+    tiles = [e["args"] for e in hook if e["name"] == "engine.prefill_tile"]
+    assert [(t["bucket"], t["rows"], t["tile_rows"]) for t in tiles] == want
+    assert all(t["side"] == "slot" for t in tiles)
+    assert len({t["parent"] for t in tiles}) == 1        # one tick
+    assert all(t["tile_rows"] == LLMEngine._tile_rows(t["bucket"])
+               for t in tiles)
+    _drain(engine, reqs)
+    assert [len(list(r)) for r in reqs] == [3] * len(lens)
+    c = engine.stats()["counts"]
+    assert c["prefill_tiles"] == len(want)
+    assert c["prefill_rows"] == len(lens)
+    assert c["prefill_tile_rows"] == sum(t["tile_rows"] for t in tiles)
+    assert c["prefill_tokens"] == sum(lens) == sum(
+        t["tokens"] for t in tiles)
+    assert c["prefill_tile_tokens"] == sum(t["tile_rows"] * t["bucket"]
+                                           for t in tiles)
+
+
+def test_a_queue_side_tile_keeps_the_widest_width(tiny_model, hook):
+    """A long prompt that finds no slot gets its first token from an
+    `_ADMIT_TILE`-row tile, then a one-row slot tile when a slot frees."""
+    cfg, params = tiny_model
+    engine = LLMEngine(cfg, params, num_slots=1, max_seq_len=640,
+                       decode_block=4)
+    reqs = [engine.submit([7] * 300, max_new_tokens=3) for _ in range(2)]
+    _drain(engine, reqs)
+    tiles = [(t["args"]["side"], t["args"]["bucket"], t["args"]["tile_rows"])
+             for t in hook if t["name"] == "engine.prefill_tile"]
+    assert tiles == [("slot", 512, 1), ("queue", 512, LLMEngine._ADMIT_TILE),
+                     ("slot", 512, 1)]
+    assert reqs[0].tokens == reqs[1].tokens and len(reqs[1].tokens) == 3
+
+
 def test_steps_waited_counts_the_blocks_between_a_request_and_the_device(
         tiny_model):
     engine = _engine(tiny_model)

@@ -237,6 +237,88 @@ def test_default_buckets():
     assert default_buckets(16) == [16]
 
 
+# rows(bucket) = clamp(_TILE_POSITIONS // bucket, 1, _ADMIT_TILE) at
+# _TILE_POSITIONS = 512, for every bucket of a 4096-row engine.
+TILE_ROWS = {16: 8, 32: 8, 64: 8, 128: 4, 256: 2, 512: 1, 1024: 1, 2048: 1,
+             4096: 1}
+
+
+def test_tile_rows_covers_every_bucket():
+    assert sorted(TILE_ROWS) == default_buckets(4096)
+
+
+@pytest.mark.parametrize("bucket", sorted(TILE_ROWS))
+def test_tile_rows_by_bucket(bucket):
+    rows = LLMEngine._tile_rows(bucket)
+    assert rows == TILE_ROWS[bucket]
+    assert 1 <= rows <= LLMEngine._ADMIT_TILE
+    # As many positions as the constant allows, and never an empty tile.
+    assert rows * bucket <= max(LLMEngine._TILE_POSITIONS, bucket)
+    assert rows == LLMEngine._ADMIT_TILE or \
+        (rows + 1) * bucket > LLMEngine._TILE_POSITIONS
+
+
+def _single_row(cfg, params, prompt, new):
+    """Tokens and their log-probs from the single-row `prefill` program
+    and `decode_step`, greedy: what a tile of any width has to give."""
+    from ray_tpu.models.generate import token_logp
+
+    S = len(prompt)
+    bucket = max(8, 1 << (S - 1).bit_length())
+    cache = init_kv_cache(cfg, 1, bucket + new)
+    padded = jnp.zeros((1, bucket), jnp.int32).at[0, :S].set(
+        jnp.asarray(prompt, jnp.int32))
+    cache, logits = prefill(cfg, params, cache, padded, jnp.int32(S),
+                            jnp.int32(0))
+    toks, lps = [], []
+    for _ in range(new):
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(1)
+        toks.append(int(tok[0]))
+        lps.append(float(token_logp(logits.reshape(1, -1), tok)[0]))
+        cache, logits = decode_step(cfg, params, cache, tok)
+    return toks, lps
+
+
+# Prompt lengths in the buckets 16, 128, 256, 512 and 640 of a 640-row
+# engine: slot tiles of 8, 4, 2, 1 and 1 rows.
+TILE_LENS = (5, 100, 200, 300, 600)
+
+
+@pytest.mark.parametrize("case", ["dense", "period_stack", "lp_twin",
+                                  "registered_prefix"])
+def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
+    """Temperature 0: the first token and every later one are those of
+    the single-row `prefill` program, whatever the width of the tile the
+    bucket gave (and the log-probs, through the `_lp` twins; and behind
+    a registered prefix, where the suffix's bucket gives the width)."""
+    cfg = (configs.tiny_afmoe_test() if case == "period_stack"
+           else configs.tiny_test())
+    params = init_params(cfg, jax.random.key(2))
+    rng = np.random.RandomState(3)
+    prefix = list(rng.randint(0, cfg.vocab_size, size=13))
+    head = prefix if case == "registered_prefix" else []
+    prompts = [head + list(rng.randint(0, cfg.vocab_size, size=n))
+               for n in TILE_LENS]
+    eng = LLMEngine(cfg, params, num_slots=len(prompts), max_seq_len=640,
+                    decode_block=4, capture_logprobs=case == "lp_twin")
+    if head:
+        eng.register_prefix(prefix)
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    while eng.step():
+        pass
+    c = eng.stats()["counts"]
+    # 640 - 13 < 600 + 13: the longest prompt cannot sit behind the
+    # prefix and takes the full path's 640 bucket; the others' suffixes
+    # fall in the same buckets as the whole prompts do.
+    assert (c["prefill_tiles"], c["prefill_tile_rows"]) == (5, 8 + 4 + 2 + 2)
+    assert eng.stats()["prefix_hits"] == (4 if head else 0)
+    for p, r in zip(prompts, reqs):
+        toks, lps = _single_row(cfg, params, p, 5)
+        assert r.result(timeout=1) == toks
+        if case == "lp_twin":
+            np.testing.assert_allclose(r.logprobs, lps, atol=2e-5)
+
+
 def test_llm_serve_deployment(ray_start):
     """LLMServer behind a serve deployment handle."""
     serve = __import__("ray_tpu.serve", fromlist=["serve"])

@@ -123,6 +123,23 @@ def test_untileable_shape_is_counted_not_refused(topo):
     assert "tpu_custom_call" not in text
 
 
+def _benchmark_config(name, sizes):
+    """A configuration of benchmarks/configs/ at a cell's sizes."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from lib import modelcfg
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        return modelcfg.transformer_config(json.load(f), sizes)
+
+
+def _bf16(max_seq):
+    return {"model": {"dtype": "bfloat16", "param_dtype": "bfloat16",
+                      "max_seq_len": max_seq}}
+
+
 def _serve_structs(topo, cfg, slots, max_seq):
     from ray_tpu.models.generate import init_kv_cache
     from ray_tpu.models.transformer import init_params
@@ -214,18 +231,8 @@ def test_decode_block_updates_the_cache_in_place(topo, cell):
     fusion`, PERF.md section 5): they are counted here, not hidden."""
     from ray_tpu.models.generate import decode_multi
 
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    try:
-        from lib import modelcfg
-    finally:
-        sys.path.remove(os.path.join(ROOT, "benchmarks"))
-
     name, slots, max_seq = DECODE_SHAPES[cell]
-    with open(os.path.join(ROOT, "benchmarks", "configs",
-                           name + ".json")) as f:
-        cfg = modelcfg.transformer_config(json.load(f), {"model": {
-            "dtype": "bfloat16", "param_dtype": "bfloat16",
-            "max_seq_len": max_seq}})
+    cfg = _benchmark_config(name, _bf16(max_seq))
     one, key, params, cache = _serve_structs(topo, cfg, slots, max_seq)
     toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
     temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
@@ -266,12 +273,13 @@ def test_decode_block_updates_the_cache_in_place(topo, cell):
 
 
 def test_prefill_sample_batch_compiles_at_654m(serve_654m):
-    """One admission tile (W=8 prompts in the 128 bucket)."""
+    """One admission tile of the 128 bucket, as wide as the engine
+    builds it (4 rows)."""
     from ray_tpu.models.generate import prefill_sample_batch
     from ray_tpu.serve.llm import LLMEngine
 
     cfg, one, key, params, cache = serve_654m
-    W = LLMEngine._ADMIT_TILE
+    W = LLMEngine._tile_rows(128)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -279,6 +287,47 @@ def test_prefill_sample_batch_compiles_at_654m(serve_654m):
     prefill_sample_batch.lower(
         cfg, params, cache, arr((W, 128), jnp.int32), arr((W,), jnp.int32),
         arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+
+
+# The document cell's admission tile (benchmarks/cells/mistral7b-docqa-
+# lone.json: 4 slots x 4096), as the engine builds it, and the tile and
+# cache the cell would need at 8,192: (bucket, slots x max_seq_len,
+# most temporaries in GB). Read here (PR 29): 0.64 GB at 1 x 4096 (an
+# 8 x 4096 tile: 5.64), 1.41 GB at 1 x 8192 beside 9.66 GB of arguments.
+DOCQA_TILES = {"as-the-cell-runs": (4096, 4, 4096, 0.8),
+               "at-8192": (8192, 4, 8192, 1.8)}
+
+
+@pytest.mark.parametrize("case", sorted(DOCQA_TILES))
+def test_docqa_admission_tile_is_one_row_and_fits(topo, as_on_the_chip,
+                                                  case, record_property):
+    """`prefill_sample_batch` on `mistral-7b-v0.3-l16`, bf16, one row of
+    the bucket: it compiles for the described chip with the pallas
+    forward kernel in it, its temporaries are an eighth of the eight-row
+    tile's, and at 8,192 the tile, the weights and a 4 x 8192 cache fit
+    one chip (what a `benchmark` PR needs to lift the cell there)."""
+    from ray_tpu.models.generate import prefill_sample_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    bucket, slots, max_seq, temp_gb = DOCQA_TILES[case]
+    cfg = _benchmark_config("mistral-7b-v0.3-l16", _bf16(max_seq))
+    one, key, params, cache = _serve_structs(topo, cfg, slots, max_seq)
+    W = LLMEngine._tile_rows(bucket)
+    assert W == 1
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = prefill_sample_batch.lower(
+        cfg, params, cache, arr((W, bucket), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+    mem = compiled.memory_analysis()
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert mem.temp_size_in_bytes < temp_gb * 1e9
+    assert mem.alias_size_in_bytes >= 2 * cache.k.size * 2   # donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def _aot_compile_step(topo, cfg, n_chips, **kw):
@@ -347,18 +396,10 @@ def test_ring_attention_compiles_with_kernels_on_four_chips(topo,
 
 @pytest.fixture(scope="module")
 def serve_trinity(topo):
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    try:
-        from lib import modelcfg
-    finally:
-        sys.path.remove(os.path.join(ROOT, "benchmarks"))
-    with open(os.path.join(ROOT, "benchmarks", "configs",
-                           "trinity-mini-l5.json")) as f:
-        config = json.load(f)
     with open(os.path.join(ROOT, "benchmarks", "cells",
                            "trinity-mini-reason-closed.json")) as f:
         sizes = json.load(f)
-    cfg = modelcfg.transformer_config(config, sizes)
+    cfg = _benchmark_config("trinity-mini-l5", sizes)
     slots, max_seq = sizes["slots"], sizes["max_seq_len"]
     return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
 
@@ -425,16 +466,16 @@ def _trinity_lowerings(serve_trinity):
     from ray_tpu.serve.llm import LLMEngine
 
     cfg, slots, one, key, params, cache = serve_trinity
-    W = LLMEngine._ADMIT_TILE
+    W, T = LLMEngine._ADMIT_TILE, LLMEngine._tile_rows(1024)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     return {
         "tile": lambda: prefill_sample_batch.lower(
-            cfg, params, cache, arr((W, 1024), jnp.int32),
-            arr((W,), jnp.int32), arr((W,), jnp.int32), 0,
-            arr((W,), jnp.float32), key),
+            cfg, params, cache, arr((T, 1024), jnp.int32),
+            arr((T,), jnp.int32), arr((T,), jnp.int32), 0,
+            arr((T,), jnp.float32), key),
         "queue_side": lambda: first_token_sample.lower(
             cfg, params, arr((W, 1024), jnp.int32), arr((W,), jnp.int32),
             arr((W,), jnp.float32), 0, key),
@@ -450,10 +491,11 @@ def _trinity_lowerings(serve_trinity):
     pytest.param("long_prefill", marks=pytest.mark.slow)])
 def test_trinity_prefill_programs_fit(serve_trinity, as_on_the_chip,
                                       program):
-    """The 8 x 1024 admission tile (65,536 token-expert pairs a layer
-    through the grouped products); with `-m slow` also the queue side's
-    cache-free first token and the reference check's one-row prefill at
-    4096 (some 20 s of compile each). `on_tpu()` is false here, so the
+    """The admission tile of the 1024 bucket as the engine builds it,
+    one row (8,192 token-expert pairs a layer through the grouped
+    products); with `-m slow` also the queue side's cache-free first
+    token at 8 x 1024 and the reference check's one-row prefill at 4096
+    (some 20 s of compile each). `on_tpu()` is false here, so the
     long prefill takes the reference path in this compile; the kernel
     with a window is compiled below."""
     mem = _trinity_lowerings(serve_trinity)[program]().compile() \
