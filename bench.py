@@ -399,10 +399,10 @@ def _smoke_prefix_equivalence() -> None:
     temps = jnp.zeros((W,), jnp.float32)  # greedy
     key = jax.random.key(0)
 
-    _, toks_full = prefill_sample_batch(
+    _, toks_full, _ = prefill_sample_batch(
         cfg, params, init_kv_cache(cfg, slots, max_seq),
         jnp.asarray(fbuf), flens, slot_idx, 0, temps, key)
-    _, toks_suffix = prefill_suffix_batch(
+    _, toks_suffix, _ = prefill_suffix_batch(
         cfg, params, init_kv_cache(cfg, slots, max_seq), pk, pv,
         jnp.asarray(sbuf), slens, slot_idx, 0, temps, key)
     same = bool(np.array_equal(np.asarray(toks_full),
@@ -524,14 +524,14 @@ def bench_serve_prefix(quick: bool, model: str = "llama-654m",
         >=0.5 s — the subtracted RTT and its jitter stay <20% of the
         measurement even for the ~ms suffix waves."""
         cache = init_kv_cache(cfg, slots, max_seq)
-        cache, toks = fn(cache)            # compile + warm
+        cache, toks, _ = fn(cache)         # compile + warm
         np.asarray(toks)
 
         def run(k):
             nonlocal cache
             t0 = time.perf_counter()
             for _ in range(k):
-                cache, toks = fn(cache)
+                cache, toks, _ = fn(cache)
             np.asarray(toks)
             return time.perf_counter() - t0
 

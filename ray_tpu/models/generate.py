@@ -28,22 +28,13 @@ of pages.
 from __future__ import annotations
 
 import types
-from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..parallel.sharding import with_sharding_constraint as wsc
-from .transformer import (
-    TransformerConfig,
-    apply_rope,
-    dense_ffn,
-    rms_norm,
-    rope_tables,
-)
+from .transformer import TransformerConfig, apply_rope, offered, stack
 
 
 # What the device's trace calls each serving program: its module events
@@ -56,21 +47,14 @@ from .transformer import (
 # sum of k x launches over the `jit_decode*_k<k>` events of a trace.
 PROGRAM_NAMES: Dict[str, str] = {
     "prefill": "prefill",
-    "prefill_sample": "prefill_sample",
     "prefill_sample_batch": "prefill_sample_batch",
-    "prefill_sample_batch_lp": "prefill_sample_batch_lp",
     "prefill_suffix_batch": "prefill_suffix_batch",
-    "prefill_suffix_batch_lp": "prefill_suffix_batch_lp",
     "first_token_sample": "first_token_sample",
-    "first_token_sample_lp": "first_token_sample_lp",
     "first_token_suffix_sample": "first_token_suffix_sample",
-    "first_token_suffix_sample_lp": "first_token_suffix_sample_lp",
     "decode_step": "decode_k1",
     "decode_multi": "decode_k{k}",
-    "decode_multi_lp": "decode_lp_k{k}",
-    # The engine's own samplers after a one-step block (serve/llm.py).
+    # The engine's own sampler after a one-step block (serve/llm.py).
     "sample_batch": "sample_batch",
-    "sample_batch_lp": "sample_batch_lp",
 }
 
 
@@ -154,20 +138,12 @@ class KVCache(NamedTuple):
 
 def init_kv_cache(cfg: TransformerConfig, num_slots: int,
                   max_seq_len: Optional[int] = None) -> KVCache:
-    S = max_seq_len or cfg.max_seq_len
-    if cfg.arch == "afmoe":
-        from . import periodic
-        return periodic.init_cache(cfg, num_slots, S)
-    shape = (cfg.n_layers, num_slots, S, cfg.n_kv_heads, cfg.head_dim)
-    k = jnp.zeros(shape, cfg.dtype)
-    k = wsc(k, ("layers", None, None, "act_kv_heads", None))
-    v = jnp.zeros(shape, cfg.dtype)
-    v = wsc(v, ("layers", None, None, "act_kv_heads", None))
-    return KVCache(k=k, v=v, seq_lens=jnp.zeros((num_slots,), jnp.int32))
+    return stack(cfg).init_cache(cfg, num_slots,
+                                 max_seq_len or cfg.max_seq_len)
 
 
 # ---------------------------------------------------------------------------
-# Layer bodies (reuse transformer pieces; differ only in KV handling)
+# What the stacks share (the stacks themselves: `transformer.STACKS`)
 # ---------------------------------------------------------------------------
 
 def _rope(x, sin, cos):
@@ -183,61 +159,11 @@ def _rope(x, sin, cos):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _qkv(cfg: TransformerConfig, lp, x, sin, cos):
-    B, S, _ = x.shape
-    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (x @ lp["wq"].astype(x.dtype)).reshape(B, S, H, Dh)
-    k = (x @ lp["wk"].astype(x.dtype)).reshape(B, S, KVH, Dh)
-    v = (x @ lp["wv"].astype(x.dtype)).reshape(B, S, KVH, Dh)
-    return _rope(q, sin, cos), _rope(k, sin, cos), v
-
-
-def _ffn(cfg: TransformerConfig, lp, x):
-    if cfg.is_moe:
-        # Routed, nothing dropped (models/moe.py): scores and selection
-        # in float32 from the norm's float32 output.
-        from .moe import routed_ffn
-        B, S, D = x.shape
-        m = rms_norm(x.astype(jnp.float32), lp["ffn_norm"], cfg.norm_eps)
-        f, _, _ = routed_ffn(cfg, lp, m.reshape(B * S, D), x.dtype)
-        return x + f.reshape(B, S, D).astype(x.dtype)
-    return x + dense_ffn(lp, rms_norm(x, lp["ffn_norm"], cfg.norm_eps))
-
-
-def _prefill_layer(cfg: TransformerConfig, carry, lp):
-    """Full-prompt layer body; emits this layer's (k, v) for the cache."""
-    from ..ops import flash_attention
-
-    x, sin, cos = carry
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, h, sin, cos)
-    out = flash_attention(q, k, v, causal=True)
-    B, S, _, _ = q.shape
-    x = x + (out.reshape(B, S, -1) @ lp["wo"].astype(x.dtype))
-    x = _ffn(cfg, lp, x)
-    return (x, sin, cos), (k, v)
-
-
-def _decode_layer(cfg: TransformerConfig, sin, cos, positions, carry,
-                  scanned):
-    """One-token layer body on the carried cache.
-
-    sin, cos: (B, 1, half); positions: (B,), the row each slot writes.
-    carry: (x (B,1,D), k_all (L,B,S,KVH,Dh), v_all): the whole cache,
-    never sliced out and stacked back. scanned: (lp, l), this layer's
-    weights and its index. The layer writes B rows of KVH x Dh at
-    [l, slot, position] and reads layer l of the same buffer once.
-    """
-    x, k_all, v_all = carry
-    lp, l = scanned
-
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, h, sin, cos)       # q (B,1,H,Dh); k,v (B,1,KVH,Dh)
-    out, k_all, v_all = _attend_cache(cfg, q, k, v, k_all, v_all, l,
-                                      positions, positions)
-    x = x + (out @ lp["wo"].astype(x.dtype))
-    x = _ffn(cfg, lp, x)
-    return (x, k_all, v_all), None
+def _last_rows(x, lengths):
+    """The last real position of each row of x (W, S, D) -> (W, 1, D)."""
+    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
+    return jnp.take_along_axis(
+        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
 
 
 def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
@@ -278,67 +204,20 @@ def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
 # Prefill / decode steps
 # ---------------------------------------------------------------------------
 
-def _head_logits(cfg: TransformerConfig, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.dtype)
-    return (x @ head).astype(jnp.float32)
-
-
-def _prefill_core(cfg: TransformerConfig, params, cache: KVCache,
-                  tokens: jax.Array, length: jax.Array, slot: jax.Array
-                  ) -> Tuple[KVCache, jax.Array]:
-    if cfg.arch == "afmoe":
-        # A tile of one row: the head sees the last real position only.
-        cache, logits = _prefill_batch_core(
-            cfg, params, cache, tokens, length[None], slot[None])
-        return cache, logits[0]
-    S = tokens.shape[1]
-    x = params["embed"].astype(cfg.dtype)[tokens]          # (1, S, D)
-    sin, cos = rope_tables(cfg, S)
-
-    layer = partial(_prefill_layer, cfg)
-    (x, _, _), (ks, vs) = lax.scan(layer, (x, sin, cos), params["layers"])
-    # ks: (L, 1, S, KVH, Dh) → write into cache[:, slot, :S]
-    k = lax.dynamic_update_slice(
-        cache.k, ks.astype(cache.k.dtype),
-        (0, slot, 0, 0, 0))
-    v = lax.dynamic_update_slice(
-        cache.v, vs.astype(cache.v.dtype),
-        (0, slot, 0, 0, 0))
-    seq_lens = cache.seq_lens.at[slot].set(length)
-
-    logits = _head_logits(cfg, params, x)                  # (1, S, V)
-    last = jnp.take_along_axis(
-        logits, (length - 1)[None, None, None].astype(jnp.int32),
-        axis=1)[0, 0]
-    return KVCache(k=k, v=v, seq_lens=seq_lens), last
-
-
 @program("prefill", static_argnums=(0,), donate_argnums=(2,))
 def prefill(cfg: TransformerConfig, params, cache: KVCache,
             tokens: jax.Array, length: jax.Array, slot: jax.Array
             ) -> Tuple[KVCache, jax.Array]:
     """Run one padded prompt (1, S_bucket) through the model, write its
-    KV into `slot`, return last-real-token logits (V,).
+    KV into `slot`, return last-real-token logits (V,): the batch body
+    at one row.
 
     `length` = real prompt length; `slot` = cache row. Compiles once per
     (S_bucket,) — callers bucket prompt lengths.
     """
-    return _prefill_core(cfg, params, cache, tokens, length, slot)
-
-
-@program("prefill_sample", static_argnums=(0, 6), donate_argnums=(2,))
-def prefill_sample(cfg: TransformerConfig, params, cache: KVCache,
-                   tokens: jax.Array, length: jax.Array, slot: jax.Array,
-                   top_k: int, temperature: jax.Array, key: jax.Array
-                   ) -> Tuple[KVCache, jax.Array]:
-    """prefill + first-token sampling in ONE dispatch (one host sync
-    per admission instead of two). Returns (cache', token ())."""
-    cache, last = _prefill_core(cfg, params, cache, tokens, length, slot)
-    tok = sample(last[None], key, temperature=temperature[None],
-                 top_k=top_k)[0]
-    return cache, tok
+    cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
+                                        length[None], slot[None])
+    return cache, logits[0]
 
 
 def token_logp(logits: jax.Array, toks: jax.Array) -> jax.Array:
@@ -351,38 +230,25 @@ def token_logp(logits: jax.Array, toks: jax.Array) -> jax.Array:
         lp, toks[..., None].astype(jnp.int32), axis=-1)[..., 0]
 
 
+def sample_logp(logits: jax.Array, temps: jax.Array, key: jax.Array,
+                top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """What every program that samples returns of its logits (..., V):
+    the tokens drawn (temps <= 0: greedily) and `token_logp` of each,
+    float32. The engine fetches the log-probabilities as the program
+    hands them over; it runs no eager operation on them (its eager
+    first-token fusion is compiled for the tokens' int32 alone)."""
+    toks = sample(logits, key, temperature=temps, top_k=top_k)
+    return toks, token_logp(logits, toks)
+
+
 def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
                         tokens: jax.Array, lengths: jax.Array,
                         slots: jax.Array) -> Tuple[KVCache, jax.Array]:
-    """Batched-prefill body shared by the sampling wrappers: write each
-    prompt's KV into its slot, return (cache', last-real-token logits
-    (W, V))."""
-    if cfg.arch == "afmoe":
-        from . import periodic
-        cache, x = periodic.prefill(cfg, params, cache, tokens, lengths,
-                                    slots)
-        return cache, periodic.last_logits(cfg, params, x, lengths)
-    W, S = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]          # (W, S, D)
-    sin, cos = rope_tables(cfg, S)
-    layer = partial(_prefill_layer, cfg)
-    (x, _, _), (ks, vs) = lax.scan(layer, (x, sin, cos), params["layers"])
-    # ks: (L, W, S, KVH, Dh) → scatter into cache rows; padding rows
-    # (slot == num_slots) fall out of bounds and are dropped.
-    k = cache.k.at[:, slots, :S].set(ks.astype(cache.k.dtype),
-                                     mode="drop")
-    v = cache.v.at[:, slots, :S].set(vs.astype(cache.v.dtype),
-                                     mode="drop")
-    seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
-    last = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (W, 1, x.shape[2])), axis=1)  # (W,1,D)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.dtype)
-    logits = (last @ head).astype(jnp.float32)[:, 0]       # (W, V)
-    return KVCache(k=k, v=v, seq_lens=seq_lens), logits
+    """Batched-prefill body: write each prompt's KV into its slot,
+    return (cache', last-real-token logits (W, V))."""
+    st = stack(cfg)
+    cache, x = st.prefill(cfg, params, cache, tokens, lengths, slots)
+    return cache, st.last_logits(cfg, params, x, lengths)
 
 
 @program("prefill_sample_batch", static_argnums=(0, 6), donate_argnums=(2,))
@@ -390,9 +256,10 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
                          tokens: jax.Array, lengths: jax.Array,
                          slots: jax.Array, top_k: int,
                          temps: jax.Array, key: jax.Array
-                         ) -> Tuple[KVCache, jax.Array]:
+                         ) -> Tuple[KVCache, jax.Array, jax.Array]:
     """Prefill a BATCH of padded prompts (W, S_bucket) into their cache
-    slots and sample each one's first token in ONE dispatch.
+    slots and sample each one's first token in ONE dispatch. Returns
+    (cache', first tokens (W,), their log-probabilities (W,)).
 
     Every row shares one read of the weights. While that read bounds
     the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
@@ -406,95 +273,7 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     per (W, S_bucket)."""
     cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
                                         lengths, slots)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return cache, toks
-
-
-@program("prefill_sample_batch_lp", static_argnums=(0, 6), donate_argnums=(2,))
-def prefill_sample_batch_lp(cfg: TransformerConfig, params,
-                            cache: KVCache, tokens: jax.Array,
-                            lengths: jax.Array, slots: jax.Array,
-                            top_k: int, temps: jax.Array, key: jax.Array
-                            ) -> Tuple[KVCache, jax.Array, jax.Array]:
-    """prefill_sample_batch that ALSO returns each sampled token's
-    log-probability (W,) — the rollout plane's ratio-term capture."""
-    cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
-                                        lengths, slots)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return cache, toks, token_logp(logits, toks)
-
-
-def _suffix_layer(cfg: TransformerConfig, q_offset: int, sin, cos,
-                  carry, scanned):
-    """Suffix-prefill layer: queries at global positions [Sp, Sp+Sq)
-    attend to the shared prefix KV plus their own causal block."""
-    from ..ops import flash_attention
-
-    (x,) = carry
-    lp, pk, pv = scanned                 # pk/pv: (Sp, KVH, Dh)
-    W, Sq, _ = x.shape
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k_s, v_s = _qkv(cfg, lp, h, sin, cos)
-    pk_b = jnp.broadcast_to(pk[None].astype(q.dtype),
-                            (W,) + pk.shape)
-    pv_b = jnp.broadcast_to(pv[None].astype(q.dtype),
-                            (W,) + pv.shape)
-    kk = jnp.concatenate([pk_b, k_s], axis=1)     # (W, Sp+Sq, KVH, Dh)
-    vv = jnp.concatenate([pv_b, v_s], axis=1)
-    out = flash_attention(q, kk, vv, causal=True, q_offset=q_offset)
-    x = x + (out.reshape(W, Sq, -1) @ lp["wo"].astype(x.dtype))
-    x = _ffn(cfg, lp, x)
-    return (x,), (k_s, v_s)
-
-
-def _suffix_forward(cfg: TransformerConfig, params, prefix_k, prefix_v,
-                    tokens):
-    """Shared suffix forward (admission prefill AND queue-side first
-    token — one implementation so the two paths can never drift apart,
-    the _prefill_core pattern): returns (x final-normed (W, Sq, D),
-    ks, vs (L, W, Sq, KVH, Dh))."""
-    _no_prefix_sharing(cfg)
-    W, Sq = tokens.shape
-    Sp = prefix_k.shape[1]
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    sin_t, cos_t = rope_tables(cfg, Sp + Sq)
-    sin, cos = sin_t[Sp:], cos_t[Sp:]
-    layer = partial(_suffix_layer, cfg, Sp, sin, cos)
-    (x,), (ks, vs) = lax.scan(
-        layer, (x,), (params["layers"], prefix_k, prefix_v))
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), ks, vs
-
-
-def _no_prefix_sharing(cfg: TransformerConfig) -> None:
-    """The prefix programs install one (L, Sp, KVH, Dh) block of keys and
-    values a layer. A window layer's ring holds a slot's last rows at
-    `position mod rows`, not a prefix at [0, Sp): sharing it needs a
-    layout of its own."""
-    if cfg.arch == "afmoe":
-        raise NotImplementedError(
-            "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
-            "compute_prefix_kv) is not written for a windowed cache "
-            "(models/periodic.py)")
-
-
-def _last_token_logits(cfg: TransformerConfig, params, x, lens):
-    """Head logits from the last REAL position of a final-normed batch
-    (W, S, D) -> (W, V)."""
-    W = x.shape[0]
-    idx = (lens - 1).astype(jnp.int32)[:, None, None]
-    last = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (W, 1, x.shape[2])), axis=1)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.dtype)
-    return (last @ head).astype(jnp.float32)[:, 0]
-
-
-def _last_token_sample(cfg: TransformerConfig, params, x, lens, temps,
-                       top_k, key):
-    """Sample one token per row from the last REAL position of a
-    final-normed batch (W, S, D)."""
-    logits = _last_token_logits(cfg, params, x, lens)
-    return sample(logits, key, temperature=temps, top_k=top_k)
+    return (cache,) + sample_logp(logits, temps, key, top_k)
 
 
 @program("prefill_suffix_batch", static_argnums=(0, 8), donate_argnums=(2,))
@@ -502,7 +281,8 @@ def prefill_suffix_batch(cfg: TransformerConfig, params, cache: KVCache,
                          prefix_k: jax.Array, prefix_v: jax.Array,
                          tokens: jax.Array, suffix_lens: jax.Array,
                          slots: jax.Array, top_k: int, temps: jax.Array,
-                         key: jax.Array) -> Tuple[KVCache, jax.Array]:
+                         key: jax.Array
+                         ) -> Tuple[KVCache, jax.Array, jax.Array]:
     """Prefix-cached admission: install a REGISTERED prefix's KV
     (prefix_k/v: (L, Sp, KVH, Dh), computed once at registration) into
     each request's cache slot by copy — zero FLOPs — then prefill only
@@ -516,18 +296,9 @@ def prefill_suffix_batch(cfg: TransformerConfig, params, cache: KVCache,
 
     suffix_lens: REAL suffix token counts (>= 1; the engine never
     routes an exact-prefix prompt here). Returns (cache', first tokens
-    (W,)). Compiles once per (W, Sp, Sq_bucket)."""
-    cache, logits = _prefill_suffix_core(
-        cfg, params, cache, prefix_k, prefix_v, tokens, suffix_lens,
-        slots)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return cache, toks
-
-
-def _prefill_suffix_core(cfg: TransformerConfig, params, cache: KVCache,
-                         prefix_k, prefix_v, tokens, suffix_lens, slots
-                         ) -> Tuple[KVCache, jax.Array]:
-    _no_prefix_sharing(cfg)
+    (W,), their log-probabilities (W,)). Compiles once per (W, Sp,
+    Sq_bucket). Only for a stack with a `suffix`."""
+    st, suffix = stack(cfg), offered(cfg, "suffix")
     W, Sq = tokens.shape
     Sp = prefix_k.shape[1]
     # 1. Prefix KV into the slot rows (broadcast copy; padding rows
@@ -541,8 +312,9 @@ def _prefill_suffix_core(cfg: TransformerConfig, params, cache: KVCache,
                          (prefix_v.shape[0], W) + prefix_v.shape[1:]
                          ).astype(cache.v.dtype), mode="drop")
 
-    # 2. Suffix forward at offset positions (shared core).
-    x, ks, vs = _suffix_forward(cfg, params, prefix_k, prefix_v, tokens)
+    # 2. Suffix forward at offset positions: the walk the queue-side
+    #    first token runs too, so the two paths cannot drift apart.
+    x, ks, vs = suffix(cfg, params, prefix_k, prefix_v, tokens)
 
     # 3. Suffix KV behind the prefix (static offset).
     k = k.at[:, slots, Sp:Sp + Sq].set(ks.astype(k.dtype), mode="drop")
@@ -551,24 +323,9 @@ def _prefill_suffix_core(cfg: TransformerConfig, params, cache: KVCache,
         Sp + suffix_lens, mode="drop")
 
     # 4. Logits at the last REAL suffix position.
-    logits = _last_token_logits(cfg, params, x, suffix_lens)
-    return KVCache(k=k, v=v, seq_lens=seq_lens), logits
-
-
-@program("prefill_suffix_batch_lp", static_argnums=(0, 8), donate_argnums=(2,))
-def prefill_suffix_batch_lp(cfg: TransformerConfig, params,
-                            cache: KVCache, prefix_k: jax.Array,
-                            prefix_v: jax.Array, tokens: jax.Array,
-                            suffix_lens: jax.Array, slots: jax.Array,
-                            top_k: int, temps: jax.Array, key: jax.Array
-                            ) -> Tuple[KVCache, jax.Array, jax.Array]:
-    """prefill_suffix_batch that ALSO returns each first token's
-    log-probability (W,)."""
-    cache, logits = _prefill_suffix_core(
-        cfg, params, cache, prefix_k, prefix_v, tokens, suffix_lens,
-        slots)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return cache, toks, token_logp(logits, toks)
+    logits = st.last_logits(cfg, params, x, suffix_lens)
+    return (KVCache(k=k, v=v, seq_lens=seq_lens),) + sample_logp(
+        logits, temps, key, top_k)
 
 
 @program("first_token_suffix_sample", static_argnums=(0, 7))
@@ -576,32 +333,18 @@ def first_token_suffix_sample(cfg: TransformerConfig, params,
                               prefix_k: jax.Array, prefix_v: jax.Array,
                               tokens: jax.Array, suffix_lens: jax.Array,
                               temps: jax.Array, top_k: int,
-                              key: jax.Array) -> jax.Array:
+                              key: jax.Array
+                              ) -> Tuple[jax.Array, jax.Array]:
     """Cache-free first token for prompts sharing a REGISTERED prefix:
     runs only the suffix forward against the stored prefix KV (the
     queue-side analog of prefill_suffix_batch — without it, every
     queued request's early first token would re-pay the full-prefix
     FLOPs the prefix cache exists to save). tokens (W, Sq_bucket),
-    suffix_lens (W,) real counts; returns (W,) tokens."""
-    x, _, _ = _suffix_forward(cfg, params, prefix_k, prefix_v, tokens)
-    return _last_token_sample(cfg, params, x, suffix_lens, temps,
-                              top_k, key)
-
-
-@program("first_token_suffix_sample_lp", static_argnums=(0, 7))
-def first_token_suffix_sample_lp(cfg: TransformerConfig, params,
-                                 prefix_k: jax.Array,
-                                 prefix_v: jax.Array,
-                                 tokens: jax.Array,
-                                 suffix_lens: jax.Array,
-                                 temps: jax.Array, top_k: int,
-                                 key: jax.Array
-                                 ) -> Tuple[jax.Array, jax.Array]:
-    """first_token_suffix_sample + per-token log-probability (W,)."""
-    x, _, _ = _suffix_forward(cfg, params, prefix_k, prefix_v, tokens)
-    logits = _last_token_logits(cfg, params, x, suffix_lens)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return toks, token_logp(logits, toks)
+    suffix_lens (W,) real counts; returns (tokens (W,), their
+    log-probabilities (W,))."""
+    x, _, _ = offered(cfg, "suffix")(cfg, params, prefix_k, prefix_v, tokens)
+    logits = stack(cfg).last_logits(cfg, params, x, suffix_lens)
+    return sample_logp(logits, temps, key, top_k)
 
 
 def compute_prefix_kv(cfg: TransformerConfig, params,
@@ -609,7 +352,7 @@ def compute_prefix_kv(cfg: TransformerConfig, params,
                       ) -> Tuple[jax.Array, jax.Array]:
     """KV for a prompt prefix, computed ONCE (registration-time half of
     prefix caching): (L, Sp, KVH, Dh) k/v in the cache dtype."""
-    _no_prefix_sharing(cfg)
+    offered(cfg, "suffix")      # refused here, not at the first admission
     Sp = len(prefix)
     scratch = init_kv_cache(cfg, 1, Sp)
     tokens = jnp.asarray(list(prefix), jnp.int32)[None]    # (1, Sp)
@@ -622,9 +365,10 @@ def compute_prefix_kv(cfg: TransformerConfig, params,
 @program("first_token_sample", static_argnums=(0, 5))
 def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
                        lengths: jax.Array, temps: jax.Array, top_k: int,
-                       key: jax.Array) -> jax.Array:
+                       key: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """First token for a BATCH of prompts without touching any KV cache
-    (tokens (W, S_bucket), lengths (W,), temps (W,) → (W,) tokens).
+    (tokens (W, S_bucket), lengths (W,), temps (W,) → (tokens (W,),
+    their log-probabilities (W,))).
 
     The serving engine uses this to give QUEUED requests their first
     token while every cache slot is busy — TTFT decoupled from slot
@@ -632,65 +376,10 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
     and decode continues from this token (the engine overrides the
     slot's cur_token), so no recomputed sample can diverge from what
     the client already saw."""
-    logits = _first_token_logits(cfg, params, tokens, lengths)
-    return sample(logits, key, temperature=temps, top_k=top_k)
-
-
-def _first_token_logits(cfg: TransformerConfig, params, tokens, lengths):
-    if cfg.arch == "afmoe":
-        from . import periodic
-        x, _ = periodic.forward_free(cfg, params, tokens)
-        return periodic.last_logits(cfg, params, x, lengths)
-    from .transformer import _lm_head, forward_hidden
-
-    # forward_hidden output is ALREADY final-norm'd — apply the head
-    # directly (going through _head_logits would norm twice and sample
-    # from distorted logits for any final_norm gain != 1).
-    x, _aux = forward_hidden(cfg, params, tokens)         # (W, S, D)
-    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
-    last = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
-    return (last @ _lm_head(cfg, params)).astype(jnp.float32)[:, 0]
-
-
-@program("first_token_sample_lp", static_argnums=(0, 5))
-def first_token_sample_lp(cfg: TransformerConfig, params,
-                          tokens: jax.Array, lengths: jax.Array,
-                          temps: jax.Array, top_k: int, key: jax.Array
-                          ) -> Tuple[jax.Array, jax.Array]:
-    """first_token_sample + per-token log-probability (W,)."""
-    logits = _first_token_logits(cfg, params, tokens, lengths)
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return toks, token_logp(logits, toks)
-
-
-def _decode_core(cfg: TransformerConfig, params, cache: KVCache,
-                 tokens: jax.Array
-                 ) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
-    """(cache', logits (B, V), routing stats of the step (3,): see
-    `periodic.decode`; None for every model but a routed period stack)."""
-    if cfg.arch == "afmoe":
-        from . import periodic
-        cache, x, stats = periodic.decode(cfg, params, cache, tokens)
-        return cache, periodic.head_logits(cfg, params, x[:, 0]), \
-            stats if reports_routing(cfg) else None
-    B = cache.num_slots
-    positions = cache.seq_lens                              # (B,)
-    x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # (B,1,D)
-
-    sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
-    sin = sin_t[positions][:, None, :]                      # (B,1,half)
-    cos = cos_t[positions][:, None, :]
-
-    # Scan over the layers' weights only: the cache rides in the carry,
-    # so no layer slab is sliced out of it or stacked back into it.
-    layer = partial(_decode_layer, cfg, sin, cos, positions)
-    (x, k_new, v_new), _ = lax.scan(
-        layer, (x, cache.k, cache.v),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-
-    logits = _head_logits(cfg, params, x)[:, 0]             # (B, V)
-    return KVCache(k=k_new, v=v_new, seq_lens=positions + 1), logits, None
+    st = stack(cfg)
+    x, _ = st.forward_free(cfg, params, tokens)
+    return sample_logp(st.last_logits(cfg, params, x, lengths), temps, key,
+                       top_k)
 
 
 @program("decode_step", static_argnums=(0,), donate_argnums=(2,))
@@ -700,88 +389,58 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     token per slot). Returns (cache', logits (B, V)). Slots advance their
     seq_lens by 1; inactive slots are advanced too — the host engine
     simply ignores their output and reuses the slot via prefill."""
-    cache, logits, _ = _decode_core(cfg, params, cache, tokens)
+    cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens)
     return cache, logits
 
 
-def reports_routing(cfg: TransformerConfig) -> bool:
-    """Whether the fused decode blocks of `cfg` return, after their
-    other results, the block's routing stats: int32 (3,) = [experts that
-    held a row, summed over steps and routed layers; rows routed; the
-    fullest expert's rows, summed over steps and layers]."""
-    return cfg.arch == "afmoe" and cfg.is_moe
-
-
-def _zero_stats(cfg: TransformerConfig) -> Optional[jax.Array]:
-    return jnp.zeros((3,), jnp.int32) if reports_routing(cfg) else None
-
-
-def _add_stats(total, stats):
-    return None if total is None else total + stats
+def routed_layers(cfg: TransformerConfig) -> int:
+    """The layers whose experts' use the fused decode blocks of `cfg`
+    count: with any, a block returns, after its other results, int32
+    (3,) = [experts that held a row, summed over steps and those layers;
+    rows routed; the fullest expert's rows, summed over steps and
+    layers]."""
+    return stack(cfg).routed_layers(cfg)
 
 
 def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
                  tokens: jax.Array, temps: jax.Array, num_steps: int,
-                 top_k: int, key: jax.Array
-                 ) -> Tuple[KVCache, jax.Array]:
+                 top_k: int, key: jax.Array):
     """`num_steps` fused decode+sample ticks in ONE dispatch, under the
     name `decode_k<num_steps>`.
 
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
-    temperature. Returns (cache', toks (num_steps, B)). The host engine
-    truncates per-slot output at eos/max_new_tokens — slots that finish
-    mid-block burn at most num_steps-1 wasted ticks, the price of one
-    dispatch and one host fetch per num_steps tokens. The cache is the
-    scan's carry and the program's donated argument, so a block is one
-    buffer updated in place. A step costs the weights and one read of
-    the cache, whatever the lengths held: on a v5e 11.6 ms at 32 slots
-    x 1024 of internlm2-1.8b and 12.3 ms at 4 x 4096 of Mistral-7B's 16
-    layers (PERF.md section 5, PR 26).
+    temperature. Returns (cache', toks (num_steps, B), `token_logp` of
+    each (num_steps, B) float32[, routing stats: `routed_layers`]). The
+    host engine truncates per-slot output at eos/max_new_tokens — slots
+    that finish mid-block burn at most num_steps-1 wasted ticks, the
+    price of one dispatch and one host fetch per num_steps tokens. The
+    cache is the scan's carry and the program's donated argument, so a
+    block is one buffer updated in place. A step costs the weights and
+    one read of the cache, whatever the lengths held: on a v5e 11.6 ms
+    at 32 slots x 1024 of internlm2-1.8b and 12.3 ms at 4 x 4096 of
+    Mistral-7B's 16 layers (PERF.md section 5, PR 26).
     """
+    st = stack(cfg)
 
     def body(carry, sub):
         cache, tok, routed = carry
-        cache, logits, stats = _decode_core(cfg, params, cache, tok)
-        tok = sample(logits, sub, temperature=temps, top_k=top_k)
-        return (cache, tok, _add_stats(routed, stats)), tok
+        cache, logits, stats = st.decode(cfg, params, cache, tok)
+        tok, lp = sample_logp(logits, temps, sub, top_k)
+        if stats is not None:
+            routed = routed + stats
+        return (cache, tok, routed), (tok, lp)
 
     subs = jax.random.split(key, num_steps)
-    (cache, _, routed), toks = lax.scan(
-        body, (cache, tokens, _zero_stats(cfg)), subs)
-    return (cache, toks) if routed is None else (cache, toks, routed)
+    routed = jnp.zeros((3,), jnp.int32) if routed_layers(cfg) else None
+    (cache, _, routed), (toks, lps) = lax.scan(
+        body, (cache, tokens, routed), subs)
+    return (cache, toks, lps) if routed is None \
+        else (cache, toks, lps, routed)
 
 
 decode_multi = _BlockPrograms("decode_multi", _decode_multi,
                               static_argnums=(0, 5, 6),
                               donate_argnums=(2,))
-
-
-def _decode_multi_lp(cfg: TransformerConfig, params, cache: KVCache,
-                    tokens: jax.Array, temps: jax.Array, num_steps: int,
-                    top_k: int, key: jax.Array
-                    ) -> Tuple[KVCache, jax.Array, jax.Array]:
-    """decode_multi that ALSO returns each sampled token's
-    log-probability (num_steps, B) — per-token logp capture for the
-    RLHF rollout plane's ratio term. One extra log_softmax + gather per
-    fused tick; engines that don't need it keep using decode_multi."""
-
-    def body(carry, sub):
-        cache, tok, routed = carry
-        cache, logits, stats = _decode_core(cfg, params, cache, tok)
-        tok = sample(logits, sub, temperature=temps, top_k=top_k)
-        return (cache, tok, _add_stats(routed, stats)), \
-            (tok, token_logp(logits, tok))
-
-    subs = jax.random.split(key, num_steps)
-    (cache, _, routed), (toks, lps) = lax.scan(
-        body, (cache, tokens, _zero_stats(cfg)), subs)
-    return (cache, toks, lps) if routed is None \
-        else (cache, toks, lps, routed)
-
-
-decode_multi_lp = _BlockPrograms("decode_multi_lp", _decode_multi_lp,
-                                 static_argnums=(0, 5, 6),
-                                 donate_argnums=(2,))
 
 
 def sample(logits: jax.Array, key: jax.Array, *,

@@ -42,19 +42,37 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .generate import KVCache, _attend_cache, _rope
+from .generate import KVCache, _attend_cache, _last_rows, _rope
 from .moe import EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn
 from .transformer import TransformerConfig, rope_tables
 
 WINDOW, GLOBAL = "window", "global"
 KINDS = (WINDOW, GLOBAL)
+
+# What the dense stack offers and this one does not (`transformer.offered`).
+MISSING = {
+    # The prefix programs install one (L, Sp, KVH, Dh) block of keys and
+    # values a layer. A window layer's ring holds a slot's last rows at
+    # `position mod rows`, not a prefix at [0, Sp): sharing it needs a
+    # layout of its own.
+    "suffix": "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
+              "compute_prefix_kv) is not written for a windowed cache "
+              "(models/periodic.py)",
+    "param_logical_axes": "arch 'afmoe' has no sharding rules yet: it is "
+                          "served on one chip (models/periodic.py)",
+    "forward_train": "arch 'afmoe' is served only (models/generate.py): "
+                     "training lacks a dropless routed layer under "
+                     "autodiff (moe_ffn drops tokens over capacity), the "
+                     "backward of windowed flash attention, and the "
+                     "load-balancing update of the selection bias",
+}
 
 
 def layer_plan(cfg: TransformerConfig
@@ -70,6 +88,12 @@ def layer_plan(cfg: TransformerConfig
         plan.append(("periods", periods,
                      (win,) * (every - 1) + (GLOBAL,), cfg.is_moe))
     return plan
+
+
+def routed_layers(cfg: TransformerConfig) -> int:
+    """Layers whose use of their experts `decode` reports."""
+    return sum(n * len(kinds) for _, n, kinds, routed in layer_plan(cfg)
+               if routed)
 
 
 def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
@@ -320,10 +344,7 @@ def head_logits(cfg: TransformerConfig, params, x) -> jax.Array:
 
 def last_logits(cfg: TransformerConfig, params, x, lengths) -> jax.Array:
     """Logits (W, V) at the last real position of final-normed x (W, S, D)."""
-    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
-    last = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
-    return head_logits(cfg, params, last[:, 0])
+    return head_logits(cfg, params, _last_rows(x, lengths)[:, 0])
 
 
 _QUERY_BLOCK = 256
@@ -480,11 +501,11 @@ def forward_free(cfg: TransformerConfig, params, tokens):
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
-           ) -> Tuple[KVCache, jax.Array, jax.Array]:
-    """One token a slot -> (cache', final-normed hidden states (B, 1, D),
-    routing stats of the step (3,): experts holding a row summed over the
-    routed layers, rows routed, and the fullest expert's rows summed over
-    the layers)."""
+           ) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+    """One token a slot -> (cache', logits (B, V), routing stats of the
+    step (3,): experts holding a row summed over the routed layers, rows
+    routed, and the fullest expert's rows summed over the layers; None
+    with no routed layer)."""
     positions = cache.seq_lens
     sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
     sin, cos = sin_t[positions][:, None, :], cos_t[positions][:, None, :]
@@ -492,8 +513,9 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
         cfg, params, _embed(cfg, params, tokens)[:, None, :], sin, cos,
         partial(_decode_attend, cfg, positions),
         (cache.k, cache.v, cache.kw, cache.vw))
-    return KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw), \
-        _final(cfg, params, x), stats
+    cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)
+    return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
+        stats if routed_layers(cfg) else None
 
 
 def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:

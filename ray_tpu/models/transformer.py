@@ -19,8 +19,8 @@ is new TPU-native code.
 
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass, field
+import importlib
+from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -29,6 +29,40 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
+
+
+# `TransformerConfig.arch` -> the module of `models/` that holds that
+# architecture's stack. The one place an architecture is named: to add
+# one, write its module and add its line. A stack module offers
+#   weights  init_params(cfg, key), num_params(cfg)
+#   cache    init_cache(cfg, num_slots, max_seq_len) -> generate.KVCache
+#   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
+#              -> (cache', final-normed hidden states (W, S, D))
+#            forward_free(cfg, params, tokens (W, S))
+#              -> (final-normed hidden states, experts chosen or None)
+#            decode(cfg, params, cache, tokens (B,))
+#              -> (cache', logits (B, V), routing stats (3,) or None)
+#            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
+#            routed_layers(cfg): the layers `decode`'s stats count over
+# and, where it has them (`offered`): `suffix` (the walk behind a shared
+# prefix), `param_logical_axes` (sharding rules), `forward_train` (the
+# walk `forward` and `loss_fn` differentiate). A stack that lacks one
+# says why in its `MISSING`.
+STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic"}
+
+
+def stack(cfg: "TransformerConfig"):
+    """The stack module of `cfg`'s architecture."""
+    return importlib.import_module(f"{__package__}.{STACKS[cfg.arch]}")
+
+
+def offered(cfg: "TransformerConfig", name: str):
+    """`name` of `cfg`'s stack; NotImplementedError, with the stack's own
+    reason, where the stack does not have it."""
+    st = stack(cfg)
+    if not hasattr(st, name):
+        raise NotImplementedError(st.MISSING[name])
+    return getattr(st, name)
 
 
 @dataclass(frozen=True)
@@ -71,7 +105,7 @@ class TransformerConfig:
     # (leading dense layers, then periods of window layers closed by a
     # global one; QK-norm, a gated attention output, four norms a layer,
     # rotary on window layers only, a scaled embedding). Served only:
-    # forward / loss_fn raise for it.
+    # forward / loss_fn raise for it. STACKS above holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length; its last layer is global
@@ -86,8 +120,8 @@ class TransformerConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
-        if self.arch not in ("llama", "afmoe"):
-            raise ValueError(f"arch must be 'llama' or 'afmoe', got "
+        if self.arch not in STACKS:
+            raise ValueError(f"arch must be one of {sorted(STACKS)}, got "
                              f"{self.arch!r}")
         if self.arch == "afmoe":
             body = self.n_layers - self.n_dense_layers
@@ -107,124 +141,20 @@ class TransformerConfig:
         return self.moe_d_ff or self.d_ff
 
     def num_params(self) -> int:
-        if self.arch == "afmoe":
-            from .periodic import num_params
-            return num_params(self)
-        d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.n_layers
-        hd = self.head_dim
-        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
-            + (self.n_heads * hd) * d
-        if self.is_moe:
-            ffn = self.moe_experts * 3 * d * f + d * self.moe_experts
-        else:
-            ffn = 3 * d * f
-        per_layer = attn + ffn + 2 * d
-        emb = v * d if self.tie_embeddings else 2 * v * d
-        return L * per_layer + emb + d
+        return stack(self).num_params(self)
 
 
 # ---------------------------------------------------------------------------
-# Parameter init + logical axes
+# Parameter init + logical axes: the stack's own
 # ---------------------------------------------------------------------------
-
-def _dense_layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
-    d, hd = cfg.d_model, cfg.head_dim
-    shapes = {
-        "attn_norm": (d,),
-        "wq": (d, cfg.n_heads * hd),
-        "wk": (d, cfg.n_kv_heads * hd),
-        "wv": (d, cfg.n_kv_heads * hd),
-        "wo": (cfg.n_heads * hd, d),
-        "ffn_norm": (d,),
-    }
-    if cfg.is_moe:
-        shapes.update({
-            "router": (d, cfg.moe_experts),
-            "w_gate": (cfg.moe_experts, d, cfg.d_ff),
-            "w_up": (cfg.moe_experts, d, cfg.d_ff),
-            "w_down": (cfg.moe_experts, cfg.d_ff, d),
-        })
-    else:
-        shapes.update({
-            "w_gate": (d, cfg.d_ff),
-            "w_up": (d, cfg.d_ff),
-            "w_down": (cfg.d_ff, d),
-        })
-    return shapes
-
 
 def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Same pytree structure as params, leaves = logical-axis tuples."""
-    if cfg.arch == "afmoe":
-        raise NotImplementedError(
-            "arch 'afmoe' has no sharding rules yet: it is served on one "
-            "chip (models/periodic.py)")
-    if cfg.is_moe:
-        ffn_axes = {
-            "router": ("layers", "embed", "expert"),
-            "w_gate": ("layers", "expert", "embed", "mlp"),
-            "w_up": ("layers", "expert", "embed", "mlp"),
-            "w_down": ("layers", "expert", "mlp", "embed"),
-        }
-    else:
-        ffn_axes = {
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        }
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": {
-            "attn_norm": ("layers", None),
-            "wq": ("layers", "embed", "heads"),
-            "wk": ("layers", "embed", "kv_heads"),
-            "wv": ("layers", "embed", "kv_heads"),
-            "wo": ("layers", "heads", "embed"),
-            "ffn_norm": ("layers", None),
-            **ffn_axes,
-        },
-        "final_norm": (None,),
-    }
-    if not cfg.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
+    return offered(cfg, "param_logical_axes")(cfg)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Scaled-normal init; layer params stacked on a leading L axis for
-    lax.scan."""
-    if cfg.arch == "afmoe":
-        from .periodic import init_params as init_periodic
-        return init_periodic(cfg, key)
-    pd = cfg.param_dtype
-    k_emb, k_layers, k_head = jax.random.split(key, 3)
-
-    def normal(key, shape, scale):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * scale).astype(pd)
-
-    d = cfg.d_model
-    layer_shapes = _dense_layer_shapes(cfg)
-    keys = jax.random.split(k_layers, len(layer_shapes))
-    layers = {}
-    for (name, shape), k in zip(sorted(layer_shapes.items()), keys):
-        full = (cfg.n_layers,) + shape
-        if name.endswith("norm"):
-            layers[name] = jnp.ones(full, dtype=pd)
-        elif name in ("wo", "w_down"):
-            # residual-branch outputs: scale down by depth
-            layers[name] = normal(
-                k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
-        else:
-            layers[name] = normal(k, full, 0.02)
-    params = {
-        "embed": normal(k_emb, (cfg.vocab_size, d), 0.02),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), dtype=pd),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
-    return params
+    return stack(cfg).init_params(cfg, key)
 
 
 def init_params_sharded(cfg: TransformerConfig, key: jax.Array, mesh
@@ -409,14 +339,8 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
                    tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """tokens (B, S) int32 → (final hidden states (B, S, D), aux_loss)
     — the trunk without the vocab projection (the chunked-CE loss
-    applies the head blockwise instead of materializing logits)."""
-    if cfg.arch == "afmoe":
-        raise NotImplementedError(
-            "arch 'afmoe' is served only (models/generate.py): training "
-            "lacks a dropless routed layer under autodiff (moe_ffn drops "
-            "tokens over capacity), the backward of windowed flash "
-            "attention, and the load-balancing update of the selection "
-            "bias")
+    applies the head blockwise instead of materializing logits). The
+    dense stack's training walk (`dense.forward_train`)."""
     B, S = tokens.shape
     # Constrain the table to replicated for the lookup: the stored param
     # is (vocab→tp, embed→fsdp)-sharded, and a gather from an
@@ -455,7 +379,7 @@ def _lm_head(cfg: TransformerConfig, params: Dict[str, Any]) -> jax.Array:
 def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """tokens (B, S) int32 → (logits (B, S, V) float32, aux_loss)."""
-    x, aux = forward_hidden(cfg, params, tokens)
+    x, aux = offered(cfg, "forward_train")(cfg, params, tokens)
     return _logits(cfg, params, x), aux
 
 
@@ -539,7 +463,7 @@ def loss_fn(cfg: TransformerConfig, params: Dict[str, Any],
             f"ce_chunk={cfg.ce_chunk} must divide the sequence "
             f"length (got S={S})")
     with jax.named_scope("fwd"):
-        x, aux = forward_hidden(cfg, params, tokens)
+        x, aux = offered(cfg, "forward_train")(cfg, params, tokens)
     with jax.named_scope("loss_head"):
         if chunked:
             return chunked_cross_entropy(cfg, params, x, targets, mask,

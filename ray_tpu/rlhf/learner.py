@@ -6,7 +6,7 @@ is the model-scale variant: the learner takes a `ParallelPlan`, holds a
 (dp/fsdp/tp — same `init_state` path the trainer uses), and runs
 advantage normalization + the clipped update inside ONE jitted SPMD
 program over the mesh. Rollout data arrives from the serve engine's
-logprob capture (`LLMEngine(capture_logprobs=True)`) — the ratio term's
+logprob capture (`GenRequest.logprobs`) — the ratio term's
 old-policy logps are recorded at sampling time, never recomputed with a
 second forward.
 

@@ -1,6 +1,6 @@
 """Rollout plane: generator actors hosting a logprob-capturing engine.
 
-Each `RolloutWorker` owns one `LLMEngine(capture_logprobs=True)` —
+Each `RolloutWorker` owns one `LLMEngine` —
 continuous batching, registered-prefix KV reuse for the shared system
 prompt, and per-token logp capture at sampling time (the GRPO ratio
 term's old-policy logps, recorded for free instead of recomputed with
@@ -41,8 +41,7 @@ class RolloutWorker:
         self.cfg = cfg
         params = init_params(cfg, jax.random.key(seed))
         self.engine = LLMEngine(cfg, params, num_slots=num_slots,
-                                seed=seed, decode_block=decode_block,
-                                capture_logprobs=True)
+                                seed=seed, decode_block=decode_block)
         self._version = -1  # seed weights; refresh installs version >= 0
         self._refresh_bytes = 0
         self._inject_delay_s = 0.0

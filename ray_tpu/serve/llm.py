@@ -36,21 +36,17 @@ import numpy as np
 from ..core.graphable import graphable
 from ..models.generate import (
     KVCache,
-    program,
     compute_prefix_kv,
     decode_multi,
-    decode_multi_lp,
     decode_step,
     first_token_sample,
-    first_token_sample_lp,
     first_token_suffix_sample,
-    first_token_suffix_sample_lp,
     init_kv_cache,
     prefill_sample_batch,
-    prefill_sample_batch_lp,
-    reports_routing,
     prefill_suffix_batch,
-    prefill_suffix_batch_lp,
+    program,
+    routed_layers,
+    sample_logp,
 )
 from ..models.transformer import (
     TransformerConfig,
@@ -73,23 +69,19 @@ def default_buckets(max_prompt_len: int) -> List[int]:
     return out
 
 
-@program("sample_batch", static_argnums=(3,))
-def _sample_batch(logits: jax.Array, temps: jax.Array, key: jax.Array,
-                  top_k: int) -> jax.Array:
-    """(B,V) logits -> (B,) tokens; temp<=0 slots decode greedily."""
-    from ..models.generate import sample
-
-    return sample(logits, key, temperature=temps, top_k=top_k)
+# The sampler after a one-step block: (B, V) logits -> ((B,) tokens, (B,)
+# log-probs of those tokens).
+_sample_batch = program("sample_batch", static_argnums=(3,))(sample_logp)
 
 
-@program("sample_batch_lp", static_argnums=(3,))
-def _sample_batch_lp(logits: jax.Array, temps: jax.Array, key: jax.Array,
-                     top_k: int):
-    """(B,V) logits -> ((B,) tokens, (B,) log-probs of those tokens)."""
-    from ..models.generate import sample, token_logp
-
-    toks = sample(logits, key, temperature=temps, top_k=top_k)
-    return toks, token_logp(logits, toks)
+def _copy_to_host_async(*arrays: Optional[jax.Array]) -> None:
+    """Start the host copy of what the host will read a tick later."""
+    for arr in arrays:
+        if arr is not None:
+            try:
+                arr.copy_to_host_async()
+            except Exception:  # noqa: BLE001 — no async copy
+                pass
 
 
 @dataclass
@@ -118,9 +110,8 @@ class GenRequest:
     finish_ts: float = 0.0
     stream: "queue.Queue" = field(default_factory=queue.Queue)
     tokens: List[int] = field(default_factory=list)
-    # log π(tok) per emitted token (raw-logits log_softmax), filled only
-    # on engines built with capture_logprobs=True; index-aligned with
-    # `tokens`.
+    # log π(tok) per emitted token (raw-logits log_softmax),
+    # index-aligned with `tokens`.
     logprobs: List[float] = field(default_factory=list)
     error: Optional[str] = None
     # Set once the terminal None has been consumed (engine-internal).
@@ -223,8 +214,7 @@ class LLMEngine:
                  top_k: int = 0, seed: int = 0, decode_block: int = 64,
                  auto_prefix_min_hits: int = 0,
                  auto_prefix_lens: Sequence[int] = (64, 128, 256, 512),
-                 mesh: Optional["jax.sharding.Mesh"] = None,
-                 capture_logprobs: bool = False):
+                 mesh: Optional["jax.sharding.Mesh"] = None):
         from .._private import compile_cache
 
         compile_cache.enable()
@@ -232,12 +222,6 @@ class LLMEngine:
         self.num_slots = num_slots
         self.max_seq_len = max_seq_len or cfg.max_seq_len
         self.top_k = top_k
-        # Per-token logp capture (RLHF rollout plane): every dispatch
-        # goes through the *_lp variants, which also return
-        # log_softmax(raw logits)[sampled token]; GenRequest.logprobs
-        # fills index-aligned with tokens. Off by default — plain
-        # serving skips the extra gather and the (k, B) f32 transfer.
-        self.capture_logprobs = bool(capture_logprobs)
         # Multi-chip serving (VERDICT r4 #3): with a mesh, weights are
         # laid out by their logical axes (megatron TP via "heads"/"mlp"/
         # "vocab"→tp, ZeRO-style "embed"→fsdp) and the KV cache shards
@@ -322,14 +306,10 @@ class LLMEngine:
             "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
             "prefill_tile_rows": 0, "prefill_tokens": 0,
             "prefill_tile_tokens": 0, "queue_side_first_tokens": 0}
-        # A routed period stack's decode blocks report how their experts
-        # were used (models/generate.reports_routing).
-        self._routed = reports_routing(cfg)
-        if self._routed:
-            from ..models.periodic import layer_plan
-            self._routed_layers = sum(
-                n * len(kinds) for _, n, kinds, routed in layer_plan(cfg)
-                if routed)
+        # The decode blocks of a stack with routed layers report how
+        # their experts were used (models/generate.routed_layers).
+        self._routed_layers = routed_layers(cfg)
+        if self._routed_layers:
             self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
                                moe_rows=0, moe_rows_max=0)
         # The last FINISHED_RING completed requests (ttft percentiles
@@ -384,21 +364,14 @@ class LLMEngine:
                  timeout: Optional[float] = None) -> Dict[str, Any]:
         """Synchronous generation: submit + wait for completion.
 
-        With `return_logprobs=True` (requires an engine built with
-        capture_logprobs=True) the result carries per-token
+        With `return_logprobs=True` the result carries per-token
         log-probabilities of the sampled tokens — log_softmax of the
         RAW logits, index-aligned with `tokens` — which is what the
-        RLHF rollout plane feeds the GRPO ratio term (previously GRPO
-        re-ran a full forward to recompute them).
+        RLHF rollout plane feeds the GRPO ratio term.
 
         If no background loop is running (`start()` not called), the
         engine is driven from this thread — deterministic single-thread
         mode for tests and rollout actors that own their engine."""
-        if return_logprobs and not self.capture_logprobs:
-            raise ValueError(
-                "return_logprobs=True requires "
-                "LLMEngine(..., capture_logprobs=True) — the engine "
-                "only records per-token logps when built to")
         req = self.submit(prompt, max_new_tokens=max_new_tokens,
                           temperature=temperature, eos_token=eos_token)
         loop = getattr(self, "_loop_thread", None)
@@ -609,11 +582,9 @@ class LLMEngine:
                 return b
         return self.buckets[-1]
 
-    def _emit(self, slot: _Slot, tok: int,
-              lp: Optional[float] = None) -> None:
+    def _emit(self, slot: _Slot, tok: int, lp: float) -> None:
         slot.req.tokens.append(tok)
-        if lp is not None:
-            slot.req.logprobs.append(float(lp))
+        slot.req.logprobs.append(float(lp))
         slot.req.stream.put(tok)
         slot.emitted += 1
         slot.length += 1
@@ -731,7 +702,8 @@ class LLMEngine:
         one fused host sync. Requests whose first token was already
         served by _early_first_tokens() are prefilled in the same
         batch (their sampled token is discarded and decode continues
-        from the token the client saw). Returns [(idx, tok_dev)].
+        from the token the client saw). Returns [(idx, tok_dev,
+        the tile's log-probs, the request's row in them)].
         """
         with self.lock:
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -742,7 +714,7 @@ class LLMEngine:
             return []
         self._touch(take)
 
-        admitted: List = []  # (idx, tok_dev, lp_dev|None) — pending
+        admitted: List = []  # (idx, tok_dev, lps_dev, row) — pending
         # Route: prompts strictly extending a registered prefix go
         # through the suffix path (prefix KV copied, only the suffix
         # prefilled); the rest through the full path.
@@ -762,7 +734,6 @@ class LLMEngine:
             for j, (_, idx) in enumerate(chunk):
                 slot_idx[j] = idx
             self._key, sub = jax.random.split(self._key)
-            lps = None
             reqs = [req for req, _ in chunk]
             try:
                 if pkey is None:
@@ -771,15 +742,11 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt, req.temperature)
                              for req in reqs])
-                        args = (self.cfg, self.params, self.cache,
-                                jnp.asarray(buf), jnp.asarray(lens),
-                                jnp.asarray(slot_idx), self.top_k,
-                                jnp.asarray(temps), sub)
-                        if self.capture_logprobs:
-                            self.cache, toks, lps = \
-                                prefill_sample_batch_lp(*args)
-                        else:
-                            self.cache, toks = prefill_sample_batch(*args)
+                        self.cache, toks, lps = prefill_sample_batch(
+                            self.cfg, self.params, self.cache,
+                            jnp.asarray(buf), jnp.asarray(lens),
+                            jnp.asarray(slot_idx), self.top_k,
+                            jnp.asarray(temps), sub)
                 else:
                     sp = len(pkey)
                     with self._tile_span("slot", bucket, W, reqs, skip=sp):
@@ -787,16 +754,12 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt[sp:], req.temperature)
                              for req in reqs])
-                        args = (self.cfg, self.params, self.cache,
-                                entry["k"], entry["v"],
-                                jnp.asarray(buf), jnp.asarray(lens),
-                                jnp.asarray(slot_idx), self.top_k,
-                                jnp.asarray(temps), sub)
-                        if self.capture_logprobs:
-                            self.cache, toks, lps = \
-                                prefill_suffix_batch_lp(*args)
-                        else:
-                            self.cache, toks = prefill_suffix_batch(*args)
+                        self.cache, toks, lps = prefill_suffix_batch(
+                            self.cfg, self.params, self.cache,
+                            entry["k"], entry["v"],
+                            jnp.asarray(buf), jnp.asarray(lens),
+                            jnp.asarray(slot_idx), self.top_k,
+                            jnp.asarray(temps), sub)
                     self.prefix_hits += len(chunk)
                     self.prefix_tokens_saved += sp * len(chunk)
             except Exception:
@@ -807,6 +770,7 @@ class LLMEngine:
                         for req, _ in reversed(later):
                             self.waiting.appendleft(req)
                 raise
+            _copy_to_host_async(lps)
             self._temps = self._temps.at[slot_idx].set(
                 jnp.asarray(temps), mode="drop")
             self.cur_tokens = self.cur_tokens.at[slot_idx].set(
@@ -824,9 +788,7 @@ class LLMEngine:
                     self.cur_tokens = self.cur_tokens.at[idx].set(
                         int(early_tok))
                 else:
-                    admitted.append(
-                        (idx, toks[j],
-                         lps[j] if lps is not None else None))
+                    admitted.append((idx, toks[j], lps, j))
         return admitted
 
     def _early_first_tokens(self) -> List:
@@ -836,7 +798,7 @@ class LLMEngine:
         arrival order, one dispatch per prompt-bucket tile. When a slot
         frees, _admit prefills the prompt and decode resumes from this
         token — the client's stream stays consistent. Returns
-        [(chunk_requests, toks_dev)]; fetched by
+        [(chunk_requests, toks_dev, lps_dev)]; fetched by
         _deliver_first_tokens."""
         with self.lock:
             todo = [r for r in self.waiting
@@ -856,13 +818,10 @@ class LLMEngine:
                 buf, lens, temps = self._build_tile(
                     bucket, W, [(r.prompt, r.temperature) for r in chunk])
                 self._key, sub = jax.random.split(self._key)
-                args = (self.cfg, self.params, jnp.asarray(buf),
-                        jnp.asarray(lens), jnp.asarray(temps), self.top_k,
-                        sub)
-                if self.capture_logprobs:
-                    toks, lps = first_token_sample_lp(*args)
-                else:
-                    toks, lps = first_token_sample(*args), None
+                toks, lps = first_token_sample(
+                    self.cfg, self.params, jnp.asarray(buf),
+                    jnp.asarray(lens), jnp.asarray(temps), self.top_k, sub)
+            _copy_to_host_async(lps)
             outs.append((chunk, toks, lps))
         # Prefix-matched queued requests: suffix-only forward against
         # the stored prefix KV (same FLOP saving as slot admission).
@@ -873,15 +832,13 @@ class LLMEngine:
                     bucket, W, [(r.prompt[sp:], r.temperature)
                                 for r in chunk])
                 self._key, sub = jax.random.split(self._key)
-                args = (self.cfg, self.params, entry["k"], entry["v"],
-                        jnp.asarray(buf), jnp.asarray(lens),
-                        jnp.asarray(temps), self.top_k, sub)
-                if self.capture_logprobs:
-                    toks, lps = first_token_suffix_sample_lp(*args)
-                else:
-                    toks, lps = first_token_suffix_sample(*args), None
+                toks, lps = first_token_suffix_sample(
+                    self.cfg, self.params, entry["k"], entry["v"],
+                    jnp.asarray(buf), jnp.asarray(lens),
+                    jnp.asarray(temps), self.top_k, sub)
             self.prefix_hits += len(chunk)
             self.prefix_tokens_saved += sp * len(chunk)
+            _copy_to_host_async(lps)
             outs.append((chunk, toks, lps))
         return outs
 
@@ -890,46 +847,35 @@ class LLMEngine:
         and start its host copy — enqueued BEFORE the decode block so
         the device serves it first (device execution is in-order; a
         fetch enqueued after the block would wait out the whole
-        block)."""
+        block). Tokens alone: the stack and the concatenation are eager,
+        one compiled program per aval, and a benchmark warms them for
+        int32. The log-probabilities come over tile by tile, as their
+        programs returned them."""
         if not admitted and not outs:
             return None
         with tracing.span("engine.fuse_first",
                           parts=bool(admitted) + len(outs)):
             parts = []
             if admitted:
-                parts.append(jnp.stack([t for _, t, _ in admitted]))
+                parts.append(jnp.stack([t for _, t, _, _ in admitted]))
             parts += [t for _, t, _ in outs]
             fused = jnp.concatenate(parts)
-            fused_lp = None
-            if self.capture_logprobs:
-                lp_parts = []
-                if admitted:
-                    lp_parts.append(
-                        jnp.stack([l for _, _, l in admitted]))
-                lp_parts += [l for _, _, l in outs]
-                fused_lp = jnp.concatenate(lp_parts)
-            for arr in (fused, fused_lp):
-                if arr is None:
-                    continue
-                try:
-                    arr.copy_to_host_async()
-                except Exception:  # noqa: BLE001 — no async copy
-                    pass
-        return fused, fused_lp
+            _copy_to_host_async(fused)
+        return fused
 
-    def _deliver_first_tokens(self, fused_pair, admitted: List,
+    def _deliver_first_tokens(self, fused, admitted: List,
                               outs: List) -> None:
         """Emit the fused first tokens (one host sync, usually already
         in flight via copy_to_host_async)."""
-        if fused_pair is None:
+        if fused is None:
             return
-        fused, fused_lp = fused_pair
         with tracing.span("engine.deliver_first", tokens=len(admitted)
                           + sum(len(reqs) for reqs, _, _ in outs)):
             with tracing.span("engine.fetch"):  # the host waits here
                 fused = np.asarray(fused)
-                fused_lp = (np.asarray(fused_lp) if fused_lp is not None
-                            else None)
+                fused_lp = np.concatenate(
+                    [np.asarray(lps)[j:j + 1] for _, _, lps, j in admitted]
+                    + [np.asarray(lps) for _, _, lps in outs])
             self._emit_first_tokens(fused, fused_lp, admitted, outs)
 
     def _emit_first_tokens(self, fused, fused_lp, admitted: List,
@@ -937,32 +883,28 @@ class LLMEngine:
         pos = 0
         now = time.monotonic()
         if admitted:
-            for j, ((idx, _, _), tok) in enumerate(
+            for j, ((idx, _, _, _), tok) in enumerate(
                     zip(admitted, fused[:len(admitted)])):
                 slot = self.slots[idx]
                 if slot is None:  # drained by a concurrent stop()
                     continue
                 tok = int(tok)
                 slot.req.first_token_ts = now
-                self._emit(slot, tok,
-                           fused_lp[j] if fused_lp is not None
-                           else None)
+                self._emit(slot, tok, fused_lp[j])
                 if (tok == slot.req.eos_token
                         or slot.emitted >= slot.req.max_new_tokens):
                     self._finish(idx)
             pos = len(admitted)
         for reqs, toks, _ in outs:
             host = fused[pos:pos + toks.shape[0]]
-            host_lp = (fused_lp[pos:pos + toks.shape[0]]
-                       if fused_lp is not None else None)
+            host_lp = fused_lp[pos:pos + toks.shape[0]]
             pos += toks.shape[0]
             for j, r in enumerate(reqs):
                 tok = int(host[j])
                 r.first_token_ts = now
                 r._early_tok = tok
                 r.tokens.append(tok)
-                if host_lp is not None:
-                    r.logprobs.append(float(host_lp[j]))
+                r.logprobs.append(float(host_lp[j]))
                 r.stream.put(tok)
                 self.tokens_out += 1
                 if tok == r.eos_token or r.max_new_tokens <= 1:
@@ -1057,37 +999,24 @@ class LLMEngine:
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots):
             self._key, sub = jax.random.split(self._key)
-            lps = moe = None
-            if k_block == 1 and not self._routed:
+            moe = None
+            if k_block == 1 and not self._routed_layers:
                 self.cache, logits = decode_step(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens)
-                if self.capture_logprobs:
-                    toks, lps = _sample_batch_lp(
-                        logits, self._temps, sub, self.top_k)
-                    toks, lps = toks[None], lps[None]      # (1, B)
-                else:
-                    toks = _sample_batch(logits, self._temps, sub,
-                                         self.top_k)[None]  # (1, B)
-            elif self.capture_logprobs:
-                self.cache, toks, lps, *moe = decode_multi_lp(
-                    self.cfg, self.params, self.cache,
-                    self.cur_tokens, self._temps, k_block,
-                    self.top_k, sub)                       # (k, B)
+                toks, lps = _sample_batch(logits, self._temps, sub,
+                                          self.top_k)
+                toks = toks[None]                          # (1, B)
             else:
-                self.cache, toks, *moe = decode_multi(
+                self.cache, toks, lps, *moe = decode_multi(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens, self._temps, k_block,
                     self.top_k, sub)                       # (k, B)
-            moe = moe[0] if moe else None     # routing stats (3,)
+                moe = moe[0] if moe else None   # routing stats (3,)
             self.cur_tokens = toks[-1]
             # Start the host copy NOW, before the next tick enqueues
             # prefills and the next block behind it.
-            for arr in (a for a in (toks, lps, moe) if a is not None):
-                try:
-                    arr.copy_to_host_async()
-                except Exception:  # noqa: BLE001 — no async copy
-                    pass
+            _copy_to_host_async(toks, lps, moe)
             self.decode_ticks += k_block
             for i in active:
                 snap[i].inflight += k_block
@@ -1108,7 +1037,8 @@ class LLMEngine:
         with span:
             with tracing.span("engine.fetch"):  # the host waits here
                 host_toks = np.asarray(toks)
-                host_lps = np.asarray(lps) if lps is not None else None
+                # (B,) after a one-step block's own sampler
+                host_lps = np.asarray(lps).reshape(host_toks.shape)
                 host_moe = np.asarray(moe) if moe is not None else None
             self.steps_processed += k_block
             before = self.tokens_out
@@ -1141,9 +1071,7 @@ class LLMEngine:
                 if slot is None or slot is not slot0:
                     break  # drained by stop() / finished below
                 tok = int(host_toks[t, i])
-                self._emit(slot, tok,
-                           host_lps[t, i] if host_lps is not None
-                           else None)
+                self._emit(slot, tok, host_lps[t, i])
                 done = (tok == slot.req.eos_token
                         or slot.emitted >= slot.req.max_new_tokens
                         or slot.length >= self.max_seq_len - 1)
@@ -1247,8 +1175,7 @@ class LLMServer:
                  seed: int = 0, auto_prefix_min_hits: int = 0,
                  auto_prefix_lens: Sequence[int] = (64, 128, 256, 512),
                  plan: Any = None,
-                 mesh: Optional["jax.sharding.Mesh"] = None,
-                 capture_logprobs: bool = False):
+                 mesh: Optional["jax.sharding.Mesh"] = None):
         if mesh is None and plan is not None:
             # Replica-level sharding plan (tp/fsdp) → device mesh; the
             # deployment config carries the plan, each replica builds
@@ -1263,8 +1190,7 @@ class LLMServer:
                                 max_seq_len=max_seq_len,
                                 auto_prefix_min_hits=auto_prefix_min_hits,
                                 auto_prefix_lens=auto_prefix_lens,
-                                mesh=mesh,
-                                capture_logprobs=capture_logprobs)
+                                mesh=mesh)
         self.engine.start()
 
     def generate(self, prompt: Sequence[int], *, max_new_tokens: int = 64,

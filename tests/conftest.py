@@ -98,13 +98,22 @@ if _LOCKTRACE_ON:
 
 
 # -- tier-1 wall-clock budget ledger ----------------------------------
-# Every run records per-test durations (setup+call+teardown) to a JSON
-# ledger; tests/test_tier1_budget.py gates the NEXT run on the previous
-# total so tier-1 growth past the verify flow's timeout budget fails
-# loudly instead of as an opaque `timeout` kill.
+# Every run records the session's wall clock and per-test durations
+# (setup+call+teardown) to a JSON ledger; tests/test_tier1_budget.py
+# gates the NEXT run on the previous wall clock so tier-1 growth past
+# the verify flow's timeout fails loudly instead of as an opaque
+# `timeout` kill. Under xdist the controller hears every worker's
+# reports and is the one that writes.
 _T1_DURATIONS: dict = {}
 _T1_LEDGER = os.environ.get("RAY_TPU_T1_DURATIONS_FILE",
                             "/tmp/_t1_durations.json")
+_T1_START = [0.0]
+
+
+def pytest_sessionstart(session):
+    import time
+
+    _T1_START[0] = time.monotonic()
 
 
 def pytest_runtest_logreport(report):
@@ -115,14 +124,18 @@ def pytest_runtest_logreport(report):
 
 def pytest_sessionfinish(session, exitstatus):
     import json
+    import time
 
-    try:
-        tests = {k: round(v, 3) for k, v in _T1_DURATIONS.items()}
-        with open(_T1_LEDGER, "w") as f:
-            json.dump({"total_s": round(sum(tests.values()), 3),
-                       "count": len(tests), "tests": tests}, f)
-    except OSError:
-        pass  # read-only /tmp must not fail the suite
+    if not hasattr(session.config, "workerinput"):
+        try:
+            tests = {k: round(v, 3) for k, v in _T1_DURATIONS.items()}
+            with open(_T1_LEDGER, "w") as f:
+                json.dump({"wall_s": round(
+                               time.monotonic() - _T1_START[0], 3),
+                           "total_s": round(sum(tests.values()), 3),
+                           "count": len(tests), "tests": tests}, f)
+        except OSError:
+            pass  # read-only /tmp must not fail the suite
     if _LOCKTRACE_ON:
         _locktrace_sessionfinish(session)
 

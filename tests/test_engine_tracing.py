@@ -322,33 +322,20 @@ def test_every_serving_program_lowers_under_its_table_name(tiny_model):
     args = {
         "prefill": (generate.prefill, (cfg, params, cache, toks[:1], lens[0],
                                        slots[0])),
-        "prefill_sample": (generate.prefill_sample, (
-            cfg, params, cache, toks[:1], lens[0], slots[0], 0, temps[0],
-            key)),
         "prefill_sample_batch": (generate.prefill_sample_batch, (
-            cfg, params, cache, toks, lens, slots, 0, temps, key)),
-        "prefill_sample_batch_lp": (generate.prefill_sample_batch_lp, (
             cfg, params, cache, toks, lens, slots, 0, temps, key)),
         "prefill_suffix_batch": (generate.prefill_suffix_batch, (
             cfg, params, cache, pk, pk, toks, lens, slots, 0, temps, key)),
-        "prefill_suffix_batch_lp": (generate.prefill_suffix_batch_lp, (
-            cfg, params, cache, pk, pk, toks, lens, slots, 0, temps, key)),
         "first_token_sample": (generate.first_token_sample, (
-            cfg, params, toks, lens, temps, 0, key)),
-        "first_token_sample_lp": (generate.first_token_sample_lp, (
             cfg, params, toks, lens, temps, 0, key)),
         "first_token_suffix_sample": (generate.first_token_suffix_sample, (
             cfg, params, pk, pk, toks, lens, temps, 0, key)),
-        "first_token_suffix_sample_lp": (
-            generate.first_token_suffix_sample_lp, (
-                cfg, params, pk, pk, toks, lens, temps, 0, key)),
         "decode_step": (generate.decode_step, (cfg, params, cache, cur)),
         "sample_batch": (llm._sample_batch, (logits, t2, key, 0)),
-        "sample_batch_lp": (llm._sample_batch_lp, (logits, t2, key, 0)),
     }
-    blocks = {"decode_multi": generate.decode_multi,
-              "decode_multi_lp": generate.decode_multi_lp}
+    blocks = {"decode_multi": generate.decode_multi}
     assert set(args) | set(blocks) == set(generate.PROGRAM_NAMES)
+    assert len(generate.PROGRAM_NAMES) == 8
     names = {}
     for key_, (fn, a) in args.items():
         names[key_] = _module_name(fn.lower(*a))
@@ -378,9 +365,9 @@ def test_a_block_size_is_one_program_compiled_once(tiny_model):
     assert generate.decode_multi.program_for(2) is not fn
     cache = generate.init_kv_cache(cfg, 2, 32)
     cur, temps = jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.float32)
-    cache, toks = generate.decode_multi(cfg, params, cache, cur, temps, 4, 0,
-                                        jax.random.key(1))
-    assert toks.shape == (4, 2) and int(cache.seq_lens[0]) == 4
+    cache, toks, lps = generate.decode_multi(cfg, params, cache, cur, temps,
+                                             4, 0, jax.random.key(1))
+    assert toks.shape == lps.shape == (4, 2) and int(cache.seq_lens[0]) == 4
 
 
 def test_the_three_flash_kernels_name_themselves_in_the_jaxpr():

@@ -103,9 +103,9 @@ def test_in_place_decode_is_the_plain_reference(kind, group):
     fed.append(np.asarray(tok))
 
     cache_b, tok_b = start()
-    cache_b, toks_b = decode_multi(cfg, params, cache_b, tok_b,
-                                   jnp.zeros((B,), jnp.float32), n, 0,
-                                   jax.random.key(5))
+    cache_b, toks_b, _ = decode_multi(cfg, params, cache_b, tok_b,
+                                      jnp.zeros((B,), jnp.float32), n, 0,
+                                      jax.random.key(5))
     toks_b = np.asarray(toks_b)
 
     touched = np.zeros((B, S), bool)
@@ -287,10 +287,12 @@ TILE_LENS = (5, 100, 200, 300, 600)
 @pytest.mark.parametrize("case", ["dense", "period_stack", "lp_twin",
                                   "registered_prefix"])
 def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
-    """Temperature 0: the first token and every later one are those of
-    the single-row `prefill` program, whatever the width of the tile the
-    bucket gave (and the log-probs, through the `_lp` twins; and behind
-    a registered prefix, where the suffix's bucket gives the width)."""
+    """Temperature 0: the first token and every later one, and the
+    log-probability of each, are those of the single-row `prefill`
+    program, whatever the width of the tile the bucket gave (`lp_twin`:
+    blocks of one step, whose tokens the engine's own sampler draws;
+    and behind a registered prefix, where the suffix's bucket gives the
+    width)."""
     cfg = (configs.tiny_afmoe_test() if case == "period_stack"
            else configs.tiny_test())
     params = init_params(cfg, jax.random.key(2))
@@ -300,7 +302,7 @@ def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
     prompts = [head + list(rng.randint(0, cfg.vocab_size, size=n))
                for n in TILE_LENS]
     eng = LLMEngine(cfg, params, num_slots=len(prompts), max_seq_len=640,
-                    decode_block=4, capture_logprobs=case == "lp_twin")
+                    decode_block=1 if case == "lp_twin" else 4)
     if head:
         eng.register_prefix(prefix)
     reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
@@ -315,8 +317,7 @@ def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
     for p, r in zip(prompts, reqs):
         toks, lps = _single_row(cfg, params, p, 5)
         assert r.result(timeout=1) == toks
-        if case == "lp_twin":
-            np.testing.assert_allclose(r.logprobs, lps, atol=2e-5)
+        np.testing.assert_allclose(r.logprobs, lps, atol=2e-5)
 
 
 def test_llm_serve_deployment(ray_start):
@@ -403,7 +404,7 @@ def test_queue_side_first_token_matches_slot_path():
     from ray_tpu.models.generate import (
         first_token_sample,
         init_kv_cache,
-        prefill_sample,
+        prefill_sample_batch,
     )
     from ray_tpu.models.transformer import init_params
 
@@ -418,15 +419,18 @@ def test_queue_side_first_token_matches_slot_path():
     padded = jnp.zeros((1, bucket), jnp.int32).at[0, :24].set(prompt)
 
     cache = init_kv_cache(cfg, 2, 64)
-    _, tok_slot = prefill_sample(
-        cfg, params, cache, padded, jnp.int32(24), jnp.int32(0), 0,
-        jnp.float32(0.0), jax.random.key(2))
+    _, tok_slot, lp_slot = prefill_sample_batch(
+        cfg, params, cache, padded, jnp.full((1,), 24, jnp.int32),
+        jnp.zeros((1,), jnp.int32), 0, jnp.zeros((1,), jnp.float32),
+        jax.random.key(2))
 
-    toks = first_token_sample(
+    toks, lps = first_token_sample(
         cfg, params, jnp.broadcast_to(padded, (4, bucket)),
         jnp.full((4,), 24, jnp.int32), jnp.zeros((4,), jnp.float32), 0,
         jax.random.key(3))
-    assert int(toks[0]) == int(tok_slot)
+    assert int(toks[0]) == int(tok_slot[0])
+    assert lps.shape == toks.shape
+    np.testing.assert_allclose(lps[0], lp_slot[0], atol=2e-5)
 
 
 def test_oversubscribed_burst_first_tokens_before_slots_free():
@@ -641,3 +645,54 @@ class TestPrefixCaching:
             assert len(toks) == 4
             assert all(0 <= t < cfg.vocab_size for t in toks)
         assert eng.stats()["prefix_hits"] >= 3
+
+
+@pytest.mark.parametrize("arch", ["dense", "period_stack"])
+def test_after_the_benchmarks_warm_up_admissions_compile_nothing(arch):
+    """The benchmark warms the engine's eager first-token fusion on int32
+    avals only (benchmarks/lib/serving.warm_up). The engine fuses the
+    tokens alone and fetches the log-probabilities as their programs
+    returned them, so after that warm-up bursts of every size, slot-side
+    and queue-side, compile nothing."""
+    import os
+    import sys
+
+    import jax.monitoring as mon
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    try:
+        from lib import serving, traffic
+    finally:
+        sys.path.pop(0)
+
+    cfg = (configs.tiny_afmoe_test() if arch == "period_stack"
+           else configs.tiny_test())
+    params = init_params(cfg, jax.random.key(0))
+    eng = LLMEngine(cfg, params, num_slots=3, max_seq_len=64,
+                    decode_block=4)
+    trace = [traffic.Request(i, 0.0, n, 6) for i, n in enumerate((5, 20, 40))]
+    serving.warm_up(eng, trace, queueing=True)
+
+    compiled = []
+
+    def on_duration(event, duration_secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(duration_secs)
+
+    mon.register_event_duration_secs_listener(on_duration)
+    try:
+        rng = np.random.RandomState(0)
+        for burst in (1, 2, 3, 5, 7):
+            reqs = [eng.submit(list(rng.randint(1, cfg.vocab_size,
+                                                size=rng.choice((5, 20, 40)))),
+                               max_new_tokens=int(rng.randint(1, 7)))
+                    for _ in range(burst)]
+            while eng.step():
+                pass
+            assert all(len(r.logprobs) == len(r.result(timeout=1)) > 0
+                       for r in reqs)
+    finally:
+        mon.unregister_event_duration_listener(on_duration)
+    assert eng.stats()["counts"]["queue_side_first_tokens"] > 0
+    assert compiled == []

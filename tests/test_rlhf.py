@@ -40,8 +40,7 @@ def test_engine_logprobs_token_exact_vs_generate():
 
     cfg = _tiny_cfg()
     params = init_params(cfg, jax.random.key(0))
-    engine = LLMEngine(cfg, params, num_slots=2, seed=0,
-                       capture_logprobs=True)
+    engine = LLMEngine(cfg, params, num_slots=2, seed=0)
     prompt = [3, 14, 15, 9, 2, 6]
     T = 8
     out = engine.generate(prompt, max_new_tokens=T, temperature=0.0,
