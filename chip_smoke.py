@@ -709,6 +709,7 @@ def phase_server(rep: Report, sz: Sizes) -> None:
 
     from ray_tpu.models.generate import greedy_generate
     from ray_tpu.models.transformer import init_params
+    from ray_tpu.ops import decode_attention
     from ray_tpu.serve.llm import LLMEngine
 
     base = _baseline()
@@ -716,23 +717,36 @@ def phase_server(rep: Report, sz: Sizes) -> None:
     prompts = _prompts(sz, len(sz.new_tokens) + 1)
     direct, http_prompt = prompts[:-1], prompts[-1]
     params = init_params(cfg, jax.random.key(sz.seed))
+    counts0 = _attention_dispatch()
     engine = LLMEngine(cfg, params, num_slots=sz.slots,
                        max_seq_len=sz.max_seq_len, seed=sz.seed)
+    # Where the cache's rows tile (they do at the real sizes) the decode
+    # blocks read it through the kernel of ops/decode_attention.py,
+    # fewer slots owned than the engine has; the tokens are held to the
+    # plain forward below either way.
+    by_kernel = decode_attention.usable(engine.cache.k, cfg.head_dim)
     engine.start()
     try:
         ans = _answer(engine, direct, sz.new_tokens)
         stats = engine.stats()
     finally:
         _stop_engine(engine)
+    traced = (_attention_dispatch() - counts0).get("decode_attn", 0)
     rep.note(engine={"requests": len(direct),
                      "new_tokens": [len(t) for t in ans["tokens"]],
                      "ttft_s": ans["ttft_s"],
                      "wall_s": round(ans["wall_s"], 3),
                      "tokens_per_s": round(ans["tokens_per_s"], 1),
-                     "decode_ticks": stats["decode_ticks"]},
+                     "decode_ticks": stats["decode_ticks"],
+                     "decode_attn_kernels_traced": traced,
+                     "cache_rows_held": stats["counts"]["cache_rows_held"],
+                     "cache_rows": stats["counts"]["cache_rows"]},
              memory_with_engine=device_memory())
     rep.require("engine_answered_every_request",
                 [len(t) for t in ans["tokens"]] == list(sz.new_tokens))
+    rep.require("decode_blocks_read_the_cache_through_the_kernel",
+                traced > 0 if by_kernel else traced == 0,
+                {"usable": by_kernel, "traced": traced})
 
     http = _http_request(sz, http_prompt)
     rep.note(http={k: http[k] for k in ("status", "pid", "ttft_s",

@@ -199,11 +199,11 @@ def _tile_attend(q, k, v):
     return flash_attention(q, k, v, causal=True), (k, v)
 
 
-def _cache_attend(cfg, k_all, v_all, l, positions, q, k, v):
+def _cache_attend(cfg, k_all, v_all, l, positions, live, q, k, v):
     """One token a slot: append its k and v to layer `l` of the carried
-    cache at the slot's position, and read that layer once."""
+    cache at the slot's position, and read the rows the slot holds."""
     out, k_all, v_all = _attend_cache(cfg, q, k, v, k_all, v_all, l,
-                                      positions, positions)
+                                      positions, positions, live)
     return out, (k_all, v_all)
 
 
@@ -263,11 +263,12 @@ def forward_free(cfg: TransformerConfig, params, tokens):
     return x, None
 
 
-def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
-           ) -> Tuple[KVCache, jax.Array, None]:
+def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
+           live=None) -> Tuple[KVCache, jax.Array, None]:
     """One token a slot -> (cache', logits (B, V), None: see
     `routed_layers`). The cache rides in the scan's carry, so no layer's
-    slab is sliced out of it or stacked back into it."""
+    slab is sliced out of it or stacked back into it. `live` (B,) bool:
+    the slots a request owns (None: every one)."""
     positions = cache.seq_lens                              # (B,)
     x = _embed(cfg, params, tokens)[:, None, :]             # (B, 1, D)
     sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
@@ -279,7 +280,7 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
         lp, l = scanned
         x, (k_all, v_all) = layer(
             cfg, lp, x, sin, cos,
-            partial(_cache_attend, cfg, k_all, v_all, l, positions))
+            partial(_cache_attend, cfg, k_all, v_all, l, positions, live))
         return (x, k_all, v_all), None
 
     (x, k, v), _ = lax.scan(

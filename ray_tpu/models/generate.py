@@ -113,8 +113,9 @@ class KVCache(NamedTuple):
     One buffer each for the life of an engine: every program that takes
     a cache donates it and returns it updated in place. The decode
     programs carry k and v whole through their layer and step loops,
-    write one row a slot a layer and read each layer once; a row no
-    request owns is never written.
+    write one row a slot a layer and read, of each layer, the rows the
+    owned slots hold (`_attend_cache`); a row no request owns is never
+    written.
 
     A period stack (`models/periodic.py`) keeps two kinds of state: k/v
     hold its global layers, (Lg, B, S_max, KVH, Dh), and kw/vw its
@@ -166,13 +167,38 @@ def _last_rows(x, lengths):
         x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
 
 
+def rows_held(positions, S: int, live=None):
+    """The rows of its S a slot at `positions` (B,) holds once this
+    step's row is written: `positions + 1`, all S once a ring has gone
+    round, and none for a slot no request owns (`live` (B,) bool; None:
+    every slot is owned)."""
+    n = jnp.minimum(positions + 1, S).astype(jnp.int32)
+    return n if live is None else jnp.where(live, n, 0)
+
+
+def masked_softmax(scores, n_rows, live):
+    """Softmax of scores (B, KVH, G, S) over the first `n_rows` (B,) of
+    S; where a slot may hold none (`live` given), zeros for it."""
+    valid = (jnp.arange(scores.shape[-1])[None, :]
+             < n_rows[:, None])[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(valid, scores, -jnp.inf), axis=-1)
+    return probs if live is None else jnp.where(valid, probs, 0.0)
+
+
 def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
-                  write_at, positions):
+                  write_at, positions, live=None):
     """One token a slot against layer `l` of a carried cache (L, B, S,
     KVH, Dh): write this step's k and v at row `write_at` (B,), then
-    attend over the rows up to `positions` (B,). Returns (out (B, 1,
-    H*Dh), k_all, v_all). For a ring of S rows `write_at` is `positions
-    mod S`: every row is seen once `positions` has passed S - 1."""
+    attend over the rows the slot holds (`rows_held`). Returns (out (B,
+    1, H*Dh), k_all, v_all). For a ring of S rows `write_at` is
+    `positions mod S`: every row is seen once `positions` has passed
+    S - 1. A slot that holds no row attends to nothing: zeros.
+
+    On a TPU, where the rows tile, the read is `ops/decode_attention`'s
+    kernel: the cache where it lies, only the rows held. Elsewhere the
+    products below, over every row with a mask."""
+    from ..ops import decode_attention as da
+
     B, S = k_all.shape[1], k_all.shape[2]
     H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -184,18 +210,19 @@ def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
     rows = jnp.arange(B)
     k_all = k_all.at[l, rows, write_at].set(k[:, 0], mode="drop")
     v_all = v_all.at[l, rows, write_at].set(v[:, 0], mode="drop")
+    n_rows = rows_held(positions, S, live)
+    G = H // KVH
+    qg = q.reshape(B, KVH, G, Dh)
+    if da.usable(k_all, Dh):
+        return da.decode_attention(qg, k_all, v_all, l, n_rows), k_all, v_all
     k_cache = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
     v_cache = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
 
     # GQA decode attention over the cache with a length mask. The cache
     # stays in its own dtype; products accumulate in float32.
-    G = H // KVH
-    qg = q.reshape(B, KVH, G, Dh)
     scores = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
                         preferred_element_type=jnp.float32) / (Dh ** 0.5)
-    valid = (jnp.arange(S)[None, :] <= positions[:, None])  # (B, S)
-    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(k_cache.dtype)
+    probs = masked_softmax(scores, n_rows, live).astype(k_cache.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
     return out.reshape(B, 1, H * Dh), k_all, v_all
 
@@ -384,12 +411,15 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
 
 @program("decode_step", static_argnums=(0,), donate_argnums=(2,))
 def decode_step(cfg: TransformerConfig, params, cache: KVCache,
-                tokens: jax.Array) -> Tuple[KVCache, jax.Array]:
+                tokens: jax.Array, live: Optional[jax.Array] = None
+                ) -> Tuple[KVCache, jax.Array]:
     """One decode step for every slot. tokens: (B,) int32 (last emitted
     token per slot). Returns (cache', logits (B, V)). Slots advance their
     seq_lens by 1; inactive slots are advanced too — the host engine
-    simply ignores their output and reuses the slot via prefill."""
-    cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens)
+    simply ignores their output and reuses the slot via prefill. `live`
+    (B,) bool: the slots a request owns (None: all of them); the others'
+    cache rows are not read (`_attend_cache`)."""
+    cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens, live)
     return cache, logits
 
 
@@ -404,27 +434,31 @@ def routed_layers(cfg: TransformerConfig) -> int:
 
 def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
                  tokens: jax.Array, temps: jax.Array, num_steps: int,
-                 top_k: int, key: jax.Array):
+                 top_k: int, key: jax.Array,
+                 live: Optional[jax.Array] = None):
     """`num_steps` fused decode+sample ticks in ONE dispatch, under the
     name `decode_k<num_steps>`.
 
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
-    temperature. Returns (cache', toks (num_steps, B), `token_logp` of
+    temperature; live: (B,) bool, the slots a request owns (`decode_step`).
+    Returns (cache', toks (num_steps, B), `token_logp` of
     each (num_steps, B) float32[, routing stats: `routed_layers`]). The
     host engine truncates per-slot output at eos/max_new_tokens — slots
     that finish mid-block burn at most num_steps-1 wasted ticks, the
     price of one dispatch and one host fetch per num_steps tokens. The
     cache is the scan's carry and the program's donated argument, so a
     block is one buffer updated in place. A step costs the weights and
-    one read of the cache, whatever the lengths held: on a v5e 11.6 ms
-    at 32 slots x 1024 of internlm2-1.8b and 12.3 ms at 4 x 4096 of
-    Mistral-7B's 16 layers (PERF.md section 5, PR 26).
+    the cache rows the owned slots hold (`_attend_cache`): on a v5e 7.6
+    ms at 32 slots x 1024 of internlm2-1.8b holding 43% of their rows
+    and 10.5 ms at 4 x 4096 of Mistral-7B's 16 layers with one slot
+    owned (11.6 and 12.3 while every row was read; PERF.md section 5,
+    PR 31).
     """
     st = stack(cfg)
 
     def body(carry, sub):
         cache, tok, routed = carry
-        cache, logits, stats = st.decode(cfg, params, cache, tok)
+        cache, logits, stats = st.decode(cfg, params, cache, tok, live)
         tok, lp = sample_logp(logits, temps, sub, top_k)
         if stats is not None:
             routed = routed + stats

@@ -49,7 +49,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .generate import KVCache, _attend_cache, _last_rows, _rope
+from .generate import (KVCache, _attend_cache, _last_rows, _rope,
+                       masked_softmax, rows_held)
 from .moe import EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn
 from .transformer import TransformerConfig, rope_tables
 
@@ -426,12 +427,17 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
                  _put(cfg, vw, l, slots, v))
 
 
-def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions):
+def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions,
+                  live=None):
     """`generate._attend_cache` over a cache of two bf16 terms (`k_all`
     (2L, B, S, KVH, Dh): see `cache_terms`), q, k, v float32: every
     product takes bf16 operands, the float32 side (q, then the
     probabilities) as two terms stacked beside the heads of a group, the
-    cached side as its two slabs, one product each."""
+    cached side as its two slabs, one product each. The kernel of
+    `ops/decode_attention` makes the same four products a block of the
+    rows held; the code below makes them over every row."""
+    from ..ops import decode_attention as da
+
     L, B, S = k_all.shape[0] // 2, k_all.shape[1], k_all.shape[2]
     KVH, Dh = cfg.n_kv_heads, cfg.head_dim
     G = cfg.n_heads // KVH
@@ -439,6 +445,10 @@ def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions):
           write_at[None, :])
     k_all = k_all.at[at].set(bf16_terms(k[:, 0]), mode="drop")
     v_all = v_all.at[at].set(bf16_terms(v[:, 0]), mode="drop")
+    n_rows = rows_held(positions, S, live)
+    qg = q.reshape(B, KVH, G, Dh)
+    if da.usable(k_all, Dh):
+        return da.decode_attention(qg, k_all, v_all, l, n_rows), k_all, v_all
 
     def against(x, eq, cached):       # x (B, KVH, G, .) float32
         two = jnp.concatenate(list(bf16_terms(x)), axis=2)
@@ -447,23 +457,21 @@ def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions):
             preferred_element_type=jnp.float32) for i in (l, L + l))
         return y[:, :, :G] + y[:, :, G:]
 
-    scores = against(q.reshape(B, KVH, G, Dh), "bkgd,bskd->bkgs", k_all) \
-        / (Dh ** 0.5)
-    valid = jnp.arange(S)[None, :] <= positions[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(valid[:, None, None, :], scores, -jnp.inf), axis=-1)
-    out = against(probs, "bkgs,bskd->bkgd", v_all)
+    scores = against(qg, "bkgd,bskd->bkgs", k_all) / (Dh ** 0.5)
+    out = against(masked_softmax(scores, n_rows, live), "bkgs,bskd->bkgd",
+                  v_all)
     return out.reshape(B, 1, KVH * G * Dh), k_all, v_all
 
 
-def _decode_attend(cfg, positions, l, kind, q, k, v, state):
+def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
     kg, vg, kw, vw = state
     attend = _attend_terms if cache_terms(cfg) == 2 else _attend_cache
     if kind == GLOBAL:
-        out, kg, vg = attend(cfg, q, k, v, kg, vg, l, positions, positions)
+        out, kg, vg = attend(cfg, q, k, v, kg, vg, l, positions, positions,
+                             live)
     else:
         out, kw, vw = attend(cfg, q, k, v, kw, vw, l,
-                             positions % kw.shape[2], positions)
+                             positions % kw.shape[2], positions, live)
     B = q.shape[0]
     return out.reshape(B, 1, cfg.n_heads, cfg.head_dim), (kg, vg, kw, vw)
 
@@ -500,18 +508,19 @@ def forward_free(cfg: TransformerConfig, params, tokens):
     return _final(cfg, params, x), chosen
 
 
-def decode(cfg: TransformerConfig, params, cache: KVCache, tokens
-           ) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
+           live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
     """One token a slot -> (cache', logits (B, V), routing stats of the
     step (3,): experts holding a row summed over the routed layers, rows
     routed, and the fullest expert's rows summed over the layers; None
-    with no routed layer)."""
+    with no routed layer). `live` (B,) bool: the slots a request owns
+    (None: every one)."""
     positions = cache.seq_lens
     sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
     sin, cos = sin_t[positions][:, None, :], cos_t[positions][:, None, :]
     x, (kg, vg, kw, vw), stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], sin, cos,
-        partial(_decode_attend, cfg, positions),
+        partial(_decode_attend, cfg, positions, live),
         (cache.k, cache.v, cache.kw, cache.vw))
     cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \

@@ -40,7 +40,7 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #              -> (cache', final-normed hidden states (W, S, D))
 #            forward_free(cfg, params, tokens (W, S))
 #              -> (final-normed hidden states, experts chosen or None)
-#            decode(cfg, params, cache, tokens (B,))
+#            decode(cfg, params, cache, tokens (B,), live (B,) bool or None)
 #              -> (cache', logits (B, V), routing stats (3,) or None)
 #            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
 #            routed_layers(cfg): the layers `decode`'s stats count over

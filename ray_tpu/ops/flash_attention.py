@@ -42,7 +42,8 @@ _SUBLANES = 8
 # path -> times chosen, counted when a call is TRACED (once per compile,
 # not per execution). Paths: "pallas", "pallas_interpret", and
 # "reference_<why>" with why in forced / untileable / no_tpu / short_kv;
-# ring_attention counts "ring_pallas" and "ring_reference_<why>".
+# ring_attention counts "ring_pallas" and "ring_reference_<why>",
+# decode_attention "decode_attn" where its kernel is traced.
 DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
 
 

@@ -305,7 +305,8 @@ class LLMEngine:
             "ticks": 0, "blocks": 0, "blocks_by_k": {}, "slot_steps": 0,
             "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
             "prefill_tile_rows": 0, "prefill_tokens": 0,
-            "prefill_tile_tokens": 0, "queue_side_first_tokens": 0}
+            "prefill_tile_tokens": 0, "queue_side_first_tokens": 0,
+            "cache_rows": 0, "cache_rows_held": 0}
         # The decode blocks of a stack with routed layers report how
         # their experts were used (models/generate.routed_layers).
         self._routed_layers = routed_layers(cfg)
@@ -990,20 +991,34 @@ class LLMEngine:
     def _dispatch_block(self, k_block: int, snap: List, active: List[int]):
         """One fused block of `k_block` decode steps for every slot (the
         program computes all `num_slots`; `active` of them hold a
-        request). Returns the pending block `_process_block` takes."""
+        request, and it is told which: the others' cache rows are not
+        read). Returns the pending block `_process_block` takes."""
         c = self.counts
         number = c["blocks"]
         c["blocks"] = number + 1
         c["blocks_by_k"][k_block] = c["blocks_by_k"].get(k_block, 0) + 1
         c["slot_steps"] += k_block * self.num_slots
+        # Cache rows the block's steps could read, and the rows its
+        # owned slots hold over those steps (a slot at device position p
+        # holds p + 1 once the step's row is written).
+        rows = k_block * self.num_slots * self.max_seq_len
+        held = sum(min(k_block * (snap[i].length + snap[i].inflight + 1)
+                       + k_block * (k_block - 1) // 2,
+                       k_block * self.max_seq_len) for i in active)
+        c["cache_rows"] += rows
+        c["cache_rows_held"] += held
+        owned = np.zeros((self.num_slots,), bool)
+        owned[active] = True
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
-                          active=len(active), slots=self.num_slots):
+                          active=len(active), slots=self.num_slots,
+                          cache_rows=rows, cache_rows_held=held):
             self._key, sub = jax.random.split(self._key)
+            live = jnp.asarray(owned)
             moe = None
             if k_block == 1 and not self._routed_layers:
                 self.cache, logits = decode_step(
                     self.cfg, self.params, self.cache,
-                    self.cur_tokens)
+                    self.cur_tokens, live)
                 toks, lps = _sample_batch(logits, self._temps, sub,
                                           self.top_k)
                 toks = toks[None]                          # (1, B)
@@ -1011,7 +1026,7 @@ class LLMEngine:
                 self.cache, toks, lps, *moe = decode_multi(
                     self.cfg, self.params, self.cache,
                     self.cur_tokens, self._temps, k_block,
-                    self.top_k, sub)                       # (k, B)
+                    self.top_k, sub, live)                 # (k, B)
                 moe = moe[0] if moe else None   # routing stats (3,)
             self.cur_tokens = toks[-1]
             # Start the host copy NOW, before the next tick enqueues

@@ -178,8 +178,14 @@ def test_rehearse_server_phases(rehearsal, chip_smoke):
     assert three["engine"]["new_tokens"] == list(sz.new_tokens)
     assert three["tokens_vs_forward"]["mismatches"] == 0
     assert three["tokens_vs_forward"]["positions_checked"] > 0
+    # Rows of 128 do not fill the kernel's lanes at a head of 16: the XLA
+    # code ran, and the phase says so; the engine counted the rows held.
+    assert three["engine"]["decode_attn_kernels_traced"] == 0
+    assert 0 < three["engine"]["cache_rows_held"] \
+        < three["engine"]["cache_rows"]
     names = {c["name"] for c in three["checks"] + four["checks"]}
     assert {"first_token_logits_abs_err", "live_bytes_after_release",
+            "decode_blocks_read_the_cache_through_the_kernel",
             "tp4_vs_one_chip_first_token_logits_abs_err",
             "tp4_tokens_vs_forward_match_reference_argmax"} <= names
 

@@ -272,6 +272,83 @@ def test_decode_block_updates_the_cache_in_place(topo, cell):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+# The carried caches of the three serving cells (leading dimension: layers
+# x bf16 terms a row), with the query's heads and dtype.
+ATTN_SHAPES = {
+    "batch-closed": ((24, 32, 1024, 8, 128), 2, jnp.bfloat16),
+    "docqa-lone": ((16, 4, 4096, 8, 128), 4, jnp.bfloat16),
+    "trinity-global": ((2, 32, 4096, 4, 128), 8, jnp.float32),
+    "trinity-ring": ((8, 32, 2048, 4, 128), 8, jnp.float32),
+    # chip_smoke.py's server: 12 query heads, not a whole tile of them.
+    "llama-654m": ((20, 16, 1024, 4, 128), 3, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTN_SHAPES))
+def test_decode_attention_kernel_compiles(topo, case):
+    """`ops/decode_attention` over a cache carried through a layer loop,
+    a row written before each read as the decode programs do it: one
+    kernel, and no copy of the cache or of a layer of it beside it."""
+    from ray_tpu.ops import decode_attention as da
+
+    shape, G, q_dtype = ATTN_SHAPES[case]
+    Lt, B, S, KVH, Dh = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    L = Lt // da.terms_of(q_dtype, jnp.bfloat16)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def walk(q, k_all, v_all, n_rows):
+        def layer(carry, l):
+            k_all, v_all, acc = carry
+            k_all = k_all.at[l, jnp.arange(B), n_rows % S].set(
+                jnp.ones((B, KVH, Dh), k_all.dtype), mode="drop")
+            out = da.decode_attention(q, k_all, v_all, l, n_rows)
+            return (k_all, v_all, acc + out.astype(jnp.float32)), None
+        acc = jnp.zeros((B, 1, KVH * G * Dh), jnp.float32)
+        return jax.lax.scan(layer, (k_all, v_all, acc), jnp.arange(L))[0]
+
+    compiled = jax.jit(walk, donate_argnums=(1, 2)).lower(
+        arr((B, KVH, G, Dh), q_dtype), arr(shape, jnp.bfloat16),
+        arr(shape, jnp.bfloat16), arr((B,), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1 and "decode_attn" in text
+    assert mem.temp_size_in_bytes < 2e6
+
+
+@pytest.mark.parametrize("cell", ["batch-closed", "docqa-lone"])
+def test_decode_block_reads_the_cache_through_the_kernel(
+        topo, as_on_the_chip, cell):
+    """`decode_multi` (k = 8) as the chip compiles it, told which slots
+    are owned: attention is the kernel, nothing of a layer slab's shape
+    is produced any more, and the cache is still written in place."""
+    from ray_tpu.models.generate import decode_multi
+
+    name, slots, max_seq = DECODE_SHAPES[cell]
+    cfg = _benchmark_config(name, _bf16(max_seq))
+    one, key, params, cache = _serve_structs(topo, cfg, slots, max_seq)
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key, live).compile()
+    text = compiled.as_text()
+    mem, writes = compiled.memory_analysis(), _device_writes(text)
+    assert text.count("tpu_custom_call") == 1 and "decode_attn" in text
+    slab = cache.k.shape[1:]
+    assert not [w for w in writes.get(slab, []) + writes.get((1,) + slab, [])
+                if w[1] != "bitcast"]
+    whole = [w for w in writes.get(cache.k.shape, ())
+             if w[1] not in ("parameter", "get-tuple-element", "bitcast")]
+    assert len(whole) == 2 and all(
+        op == "fusion" and "scatter" in body
+        and not body & {"copy", "dynamic-update-slice"}
+        for _, op, body in whole), whole
+    assert mem.alias_size_in_bytes >= 2 * cache.k.size * 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 def test_prefill_sample_batch_compiles_at_654m(serve_654m):
     """One admission tile of the 128 bucket, as wide as the engine
     builds it (4 rows)."""
@@ -454,7 +531,9 @@ def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
                 if len(dims) >= 3 and dims[-3:] == experts.shape[-3:]
                 for w in writes[dims]
                 if w[1] not in ("parameter", "get-tuple-element", "bitcast")]
-    # 0.55 GB: staged cache slabs of 67 and 134 MB, scores of 33 MB.
+    # 0.55 GB while XLA staged cache slabs of 67 and 134 MB and scores of
+    # 33 MB; `as_on_the_chip` takes ops/decode_attention's kernel, which
+    # stages none.
     assert mem.temp_size_in_bytes < 1.5 * one_layer
     assert mem.alias_size_in_bytes >= held
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
