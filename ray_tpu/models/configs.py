@@ -37,6 +37,28 @@ def tiny_afmoe_test(vocab: int = 256) -> TransformerConfig:
         route_scale=2.826)
 
 
+def tiny_mellum_test(vocab: int = 256) -> TransformerConfig:
+    """The period stack's other layer (`transformer.PERIOD_FORMS`) at a
+    unit-test size: two periods of three window layers and a global one,
+    every layer routed by a softmax over 8 experts, no shared expert, a
+    rotary table a kind of layer (YaRN on the global ones). For the
+    tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=32, d_ff=128, max_seq_len=128, norm_eps=1e-6,
+        dtype=jnp.float32, param_dtype=jnp.float32, remat=False,
+        tie_embeddings=False, arch="mellum", global_attn_every=4,
+        sliding_window=8, moe_experts=8, moe_top_k=2, moe_d_ff=32,
+        score_func="softmax", route_norm=True,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 32, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000}})
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -90,6 +112,7 @@ NAMED = {
     "tiny": tiny_test,
     "tiny_moe": tiny_moe_test,
     "tiny_afmoe": tiny_afmoe_test,
+    "tiny_mellum": tiny_mellum_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

@@ -235,10 +235,10 @@ def _final(cfg: TransformerConfig, params, x):
 
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
-            slots) -> Tuple[KVCache, jax.Array]:
+            slots) -> Tuple[KVCache, jax.Array, None]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D)). A row whose slot is out of range (a tile's
-    padding) is dropped."""
+    hidden states (W, S, D), None: no routed layer to report on). A row
+    whose slot is out of range (a tile's padding) is dropped."""
     S = tokens.shape[1]
     x = _embed(cfg, params, tokens)
     sin, cos = rope_tables(cfg, S)
@@ -253,7 +253,8 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     k = cache.k.at[:, slots, :S].set(ks.astype(cache.k.dtype), mode="drop")
     v = cache.v.at[:, slots, :S].set(vs.astype(cache.v.dtype), mode="drop")
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
-    return KVCache(k=k, v=v, seq_lens=seq_lens), _final(cfg, params, x)
+    return KVCache(k=k, v=v, seq_lens=seq_lens), _final(cfg, params, x), \
+        None
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):

@@ -242,8 +242,8 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache,
     `length` = real prompt length; `slot` = cache row. Compiles once per
     (S_bucket,) — callers bucket prompt lengths.
     """
-    cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
-                                        length[None], slot[None])
+    cache, logits, _ = _prefill_batch_core(cfg, params, cache, tokens,
+                                           length[None], slot[None])
     return cache, logits[0]
 
 
@@ -270,23 +270,24 @@ def sample_logp(logits: jax.Array, temps: jax.Array, key: jax.Array,
 
 def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
                         tokens: jax.Array, lengths: jax.Array,
-                        slots: jax.Array) -> Tuple[KVCache, jax.Array]:
+                        slots: jax.Array):
     """Batched-prefill body: write each prompt's KV into its slot,
-    return (cache', last-real-token logits (W, V))."""
+    return (cache', last-real-token logits (W, V), the tile's routing
+    stats (3,) or None: `routed_layers`)."""
     st = stack(cfg)
-    cache, x = st.prefill(cfg, params, cache, tokens, lengths, slots)
-    return cache, st.last_logits(cfg, params, x, lengths)
+    cache, x, stats = st.prefill(cfg, params, cache, tokens, lengths, slots)
+    return cache, st.last_logits(cfg, params, x, lengths), stats
 
 
 @program("prefill_sample_batch", static_argnums=(0, 6), donate_argnums=(2,))
 def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
                          tokens: jax.Array, lengths: jax.Array,
                          slots: jax.Array, top_k: int,
-                         temps: jax.Array, key: jax.Array
-                         ) -> Tuple[KVCache, jax.Array, jax.Array]:
+                         temps: jax.Array, key: jax.Array):
     """Prefill a BATCH of padded prompts (W, S_bucket) into their cache
     slots and sample each one's first token in ONE dispatch. Returns
-    (cache', first tokens (W,), their log-probabilities (W,)).
+    (cache', first tokens (W,), their log-probabilities (W,)[, routing
+    stats of the tile's W x S_bucket positions: `routed_layers`]).
 
     Every row shares one read of the weights. While that read bounds
     the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
@@ -298,9 +299,10 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     is out of range (the tile's padding) are dropped by the scatter and
     their sampled token is garbage the caller ignores. Compiles once
     per (W, S_bucket)."""
-    cache, logits = _prefill_batch_core(cfg, params, cache, tokens,
-                                        lengths, slots)
-    return (cache,) + sample_logp(logits, temps, key, top_k)
+    cache, logits, stats = _prefill_batch_core(cfg, params, cache, tokens,
+                                               lengths, slots)
+    out = (cache,) + sample_logp(logits, temps, key, top_k)
+    return out if stats is None else out + (stats,)
 
 
 @program("prefill_suffix_batch", static_argnums=(0, 8), donate_argnums=(2,))
@@ -424,11 +426,11 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
 
 
 def routed_layers(cfg: TransformerConfig) -> int:
-    """The layers whose experts' use the fused decode blocks of `cfg`
-    count: with any, a block returns, after its other results, int32
-    (3,) = [experts that held a row, summed over steps and those layers;
-    rows routed; the fullest expert's rows, summed over steps and
-    layers]."""
+    """The layers whose experts' use the fused decode blocks and the
+    admission tiles (`prefill_sample_batch`) of `cfg` count: with any,
+    either returns, after its other results, int32 (3,) = [experts that
+    held a row, summed over steps (one for a tile) and those layers; rows
+    routed; the fullest expert's rows, summed over steps and layers]."""
     return stack(cfg).routed_layers(cfg)
 
 

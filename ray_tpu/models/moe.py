@@ -90,17 +90,34 @@ def route(cfg, lp: Dict[str, jax.Array], m: jax.Array
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
+_GMM_TILE = 2 ** 20         # elements of an expert a grid step: 2 MB
+# (k, n) -> tn where a chip run read another tile faster than the rule's.
+# 896 x 2304 (mellum's down product): all of n, 3.71 against 1152's 3.85
+# ms at 131,072 rows and 0.29 against 0.36 at 128 (my chip runs, PR 32).
+_GMM_MEASURED_TN = {(896, 2304): 2304}
+
+
 def _gmm_tiling(rows: int, k: int, n: int):
-    """(tm, tk, tn) for megablox's kernel, or None where it does not
-    tile the shape. The whole contraction a tile, some 2 MB of an expert
-    a step: it reads each expert hit once and is bound by those bytes
-    (0.69 ms against `lax.ragged_dot`'s 1.58 for 512 rows over 110 of
-    128 experts of 2048 x 1024; 4.3 against 6.4 for an admission tile's
-    131,072 rows: my chip runs, PR 28)."""
+    """(tm, tk, tn) for megablox's kernel over `rows` (a multiple of 128:
+    `grouped_dot` pads), or None where it does not tile the shape. The
+    whole contraction a tile, so that an expert's slab is fetched once
+    for all its row tiles, and of n the multiple of 128 dividing it whose
+    slab is nearest 2 MB (2048 x 1024: 512 of 1024; 1024 x 2048: 1024;
+    2304 x 896: all 896, since 896 = 7 x 128 leaves only 128 and 896 and
+    a slab of 128 re-reads the rows seven times), or the tile a chip run
+    found faster (`_GMM_MEASURED_TN`). It reads each expert hit once and
+    is bound by those bytes at decode's rows (0.69 ms against
+    `lax.ragged_dot`'s 1.58 for 512 rows over 110 of 128 experts of
+    2048 x 1024; 4.3 against 6.4 for an admission tile's 131,072 rows: my
+    chip runs, PR 28)."""
     if rows % 128 or k % 128 or n % 128 or k > 4096:
         return None
     tm = 256 if rows % 256 == 0 and rows >= 4096 else 128
-    return tm, k, min(n, max(128, 2 ** 20 // k))
+    want = _GMM_TILE / k
+    tn = _GMM_MEASURED_TN.get((k, n)) or min(
+        (128 * d for d in range(1, n // 128 + 1) if n % (128 * d) == 0),
+        key=lambda t: max(t / want, want / t))
+    return tm, k, tn
 
 
 def grouped_dot(a: jax.Array, w: jax.Array, groups: jax.Array,
@@ -117,14 +134,21 @@ def grouped_dot(a: jax.Array, w: jax.Array, groups: jax.Array,
         two = jnp.swapaxes(bf16_terms(a), 0, 1).reshape(2 * R, a.shape[-1])
         y = grouped_dot(two, w, 2 * groups, kernel)
         return jnp.sum(y.reshape(R, 2, -1), axis=1)
-    tiling = _gmm_tiling(R, a.shape[1], w.shape[2])
+    # Rows up to the kernel's row tile: the rows added belong to no
+    # group, so the kernel neither reads nor writes them (a lone caller's
+    # decode is 64 rows, and `lax.ragged_dot` costs twice the kernel).
+    pad = -R % 128
+    tiling = _gmm_tiling(R + pad, a.shape[1], w.shape[2])
     if kernel is None:
         from ..ops.flash_attention import on_tpu
         kernel = on_tpu()
     if kernel and tiling and a.dtype == w.dtype == jnp.bfloat16:
         from jax.experimental.pallas.ops.tpu import megablox
-        return megablox.gmm(a, w, groups, jnp.float32, tiling,
-                            interpret=kernel == "interpret")
+        if pad:
+            a = jnp.pad(a, ((0, pad), (0, 0)))
+        y = megablox.gmm(a, w, groups, jnp.float32, tiling,
+                         interpret=kernel == "interpret")
+        return y[:R] if pad else y
     return lax.ragged_dot(a, w.astype(a.dtype), groups, precision=_exact(a),
                           preferred_element_type=jnp.float32)
 

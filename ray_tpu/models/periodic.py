@@ -1,14 +1,19 @@
-"""The period stack (`TransformerConfig.arch == "afmoe"`): a decoder whose
-layers are not all alike, served through the programs of `generate.py`.
+"""The period stack (the architectures of `transformer.PERIOD_FORMS`): a
+decoder whose layers are not all alike, served through the programs of
+`generate.py`.
 
 `n_dense_layers` leading layers with a dense SwiGLU, then whole periods
 of `global_attn_every` layers with the routed layer of `models/moe.py`
-(plus always-on shared experts); the last layer of a period attends to
-every earlier position, the others and the leading layers to the last
-`sliding_window` (rotary embedding on those alone). Every layer: RMS
-norms before and after attention and before and after the FFN, a learned
-norm over each head of q and k, and the attention output gated by
-`sigmoid(h @ wg)` before `wo`. The embedding is scaled by sqrt(d_model).
+(plus always-on shared experts where the configuration has them); the
+last layer of a period attends to every earlier position, the others and
+the leading layers to the last `sliding_window`. Every layer: an RMS norm
+before attention and before the FFN and a learned norm over each head of
+q and k. What else a layer has is data, `cfg.period_form`: norms on the
+attention's and the FFN's output, the attention output gated by
+`sigmoid(h @ wg)` before `wo`, the embedding scaled by sqrt(d_model), a
+selection bias, and which kinds of layer rotate q and k, each kind with
+the table of its own section of `cfg.rope_parameters` (Trinity: window
+layers only; Mellum 2: both, the global ones by YaRN).
 
 Weights: `dense_layers` (leaves stacked over the leading layers) and
 `periods` (leaves stacked over periods, then over a period's layers).
@@ -56,6 +61,9 @@ from .transformer import TransformerConfig, rope_tables
 
 WINDOW, GLOBAL = "window", "global"
 KINDS = (WINDOW, GLOBAL)
+# A kind's section of `TransformerConfig.rope_parameters`, under the key a
+# published config.json gives it.
+ROPE_SECTION = {WINDOW: "sliding_attention", GLOBAL: "full_attention"}
 
 # What the dense stack offers and this one does not (`transformer.offered`).
 MISSING = {
@@ -66,9 +74,9 @@ MISSING = {
     "suffix": "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
               "compute_prefix_kv) is not written for a windowed cache "
               "(models/periodic.py)",
-    "param_logical_axes": "arch 'afmoe' has no sharding rules yet: it is "
-                          "served on one chip (models/periodic.py)",
-    "forward_train": "arch 'afmoe' is served only (models/generate.py): "
+    "param_logical_axes": "the period stack has no sharding rules yet: it "
+                          "is served on one chip (models/periodic.py)",
+    "forward_train": "the period stack is served only (models/generate.py): "
                      "training lacks a dropless routed layer under "
                      "autodiff (moe_ffn drops tokens over capacity), the "
                      "backward of windowed flash attention, and the "
@@ -112,18 +120,24 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
                   ) -> Dict[str, Tuple[int, ...]]:
     d, hd = cfg.d_model, cfg.head_dim
     q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    form = cfg.period_form
     shapes = {
         "attn_norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
-        "wg": (d, q), "wo": (q, d), "q_norm": (hd,), "k_norm": (hd,),
-        "post_attn_norm": (d,), "ffn_norm": (d,), "post_ffn_norm": (d,),
+        "wo": (q, d), "q_norm": (hd,), "k_norm": (hd,), "ffn_norm": (d,),
     }
+    if form.attn_gate:
+        shapes["wg"] = (d, q)
+    if form.post_norms:
+        shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
     if not routed:
         f = cfg.d_ff
         shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
         return shapes
     E, f = cfg.moe_experts, cfg.expert_d_ff
-    shapes.update(router=(d, E), router_bias=(E,), w_gate=(E, d, f),
-                  w_up=(E, d, f), w_down=(E, f, d))
+    shapes.update(router=(d, E), w_gate=(E, d, f), w_up=(E, d, f),
+                  w_down=(E, f, d))
+    if form.router_bias:
+        shapes["router_bias"] = (E,)
     if cfg.moe_shared_experts:
         fs = f * cfg.moe_shared_experts
         shapes.update(shared_gate=(d, fs), shared_up=(d, fs),
@@ -228,10 +242,11 @@ def _swiglu(m: jax.Array, gate, up, down) -> jax.Array:
     return _dot(h.astype(m.dtype), down)
 
 
-def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, sin, cos,
+def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
           attend, state):
-    """One layer on x (B, S, D) in the activation dtype. `attend(kind, q, k,
-    v, state) -> (out (B, S, H, Dh), state)` does the attention and
+    """One layer on x (B, S, D) in the activation dtype. `rope`: {kind:
+    (sin, cos)} for the kinds that rotate (`rope_by_kind`). `attend(kind,
+    q, k, v, state) -> (out (B, S, H, Dh), state)` does the attention and
     whatever it keeps of k and v. `experts_at`: None for a dense FFN, else
     (the stack's expert matrices, this layer's first group in them).
     Returns (x, state, routing stats (3,), experts chosen (B*S, K) or
@@ -239,6 +254,13 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, sin, cos,
     B, S, _ = x.shape
     H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt, eps = cfg.dtype, cfg.norm_eps
+    form = cfg.period_form
+
+    def joins(branch, norm):
+        # A branch's output on its way into the residual stream.
+        if form.post_norms:
+            branch = _norm(branch, lp[norm], eps)
+        return x + branch.astype(x.dtype)
 
     # Every product hands back float32; what lies between two products
     # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
@@ -247,17 +269,17 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, sin, cos,
     q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
     k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
     v = _dot(h, lp["wv"]).reshape(B, S, KVH, Dh).astype(dt)
-    gate = _dot(h, lp["wg"])
+    gate = _dot(h, lp["wg"]) if form.attn_gate else None
     q = _norm(q, lp["q_norm"], eps)
     k = _norm(k, lp["k_norm"], eps)
-    if kind == WINDOW:                 # a global layer has no position
-        q, k = _rope(q, sin, cos), _rope(k, sin, cos)
+    if kind in rope:                   # else the kind has no position
+        q, k = _rope(q, *rope[kind]), _rope(k, *rope[kind])
     with jax.named_scope("attn_" + kind):
         out, state = attend(kind, q.astype(dt), k.astype(dt), v, state)
-    out = (out.reshape(B, S, H * Dh).astype(jnp.float32)
-           * jax.nn.sigmoid(gate)).astype(dt)
-    a = _dot(out, lp["wo"])
-    x = x + _norm(a, lp["post_attn_norm"], eps).astype(x.dtype)
+    out = out.reshape(B, S, H * Dh)
+    if gate is not None:
+        out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(dt)
+    x = joins(_dot(out, lp["wo"]), "post_attn_norm")
 
     m = _norm(x, lp["ffn_norm"], eps)                      # float32
     experts = None
@@ -272,11 +294,24 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, sin, cos,
         f = f.reshape(B, S, -1)
     else:
         f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
-    x = x + _norm(f, lp["post_ffn_norm"], eps).astype(x.dtype)
-    return x, state, stats, experts
+    return joins(f, "post_ffn_norm"), state, stats, experts
 
 
-def _run(cfg: TransformerConfig, params, x, sin, cos, attend, state):
+def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
+    """{kind: (sin, cos)} for the kinds of layer that rotate q and k,
+    each from its own section of `cfg.rope_parameters`: tables (S, half)
+    for a tile, or, with `positions` (B,), each slot's row of a table of
+    `seq_len` positions, (B, 1, half)."""
+    out = {}
+    for kind in cfg.period_form.rotary:
+        sin, cos = rope_tables(cfg, seq_len, ROPE_SECTION[kind])
+        if positions is not None:
+            sin, cos = sin[positions][:, None, :], cos[positions][:, None, :]
+        out[kind] = (sin, cos)
+    return out
+
+
+def _run(cfg: TransformerConfig, params, x, rope, attend, state):
     """x through every layer: one `lax.scan` a group of the plan, a
     group's layers unrolled in its body, `state` (the cache, or nothing)
     riding in the carry beside x. `attend(l, kind, q, k, v, state)` is
@@ -311,8 +346,8 @@ def _run(cfg: TransformerConfig, params, x, sin, cos, attend, state):
                 seen[kind] += 1
                 first = (g * len(kinds) + j) * cfg.moe_experts
                 x, state, st, ex = layer(
-                    cfg, lp, x, kind, expert_w and (expert_w, first), sin,
-                    cos, partial(attend, l), state)
+                    cfg, lp, x, kind, expert_w and (expert_w, first), rope,
+                    partial(attend, l), state)
                 stats = stats + st
                 if ex is not None:
                     experts.append(ex)
@@ -327,7 +362,9 @@ def _run(cfg: TransformerConfig, params, x, sin, cos, attend, state):
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
-    x = params["embed"][tokens].astype(jnp.float32) * math.sqrt(cfg.d_model)
+    x = params["embed"][tokens].astype(jnp.float32)
+    if cfg.period_form.embed_scale:
+        x = x * math.sqrt(cfg.d_model)
     return x.astype(cfg.dtype)
 
 
@@ -355,24 +392,35 @@ def _attention_f32(q, k, v, window: int):
     """Causal (windowed) attention of float32 q (B, S, H, Dh) over float32
     k, v (B, S, KVH, Dh), both products at the highest precision (the
     flash kernel multiplies in bf16), a block of queries at a time so
-    that the scores held are (B, H, block, S)."""
+    that the scores held are (B, H, block, keys). A block meets all S
+    keys and a mask, or, where a window reaches fewer, the slice of
+    `window` + a block (in whole blocks) that ends with the block's last
+    query: a window layer's work follows its window and not S (8,192
+    positions under a window of 1,024: 1,280 keys a block, not 8,192)."""
     B, S, H, Dh = q.shape
     KVH = k.shape[2]
     blk = _QUERY_BLOCK if S % _QUERY_BLOCK == 0 else S
     hi = lax.Precision.HIGHEST
     qb = q.reshape(B, S // blk, blk, KVH, H // KVH, Dh)
     j = jnp.arange(S)[None, :]
+    keys = blk * (-(-window // blk) + 1) if window else S
 
     def block(args):
         qs, start = args                                # (B, blk, KVH, G, Dh)
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qs, k, precision=hi) \
+        kb, vb, jb = k, v, j
+        if keys < S:
+            first = jnp.clip(start + blk - keys, 0, S - keys)
+            kb, vb = (lax.dynamic_slice_in_dim(x, first, keys, axis=1)
+                      for x in (k, v))
+            jb = first + jnp.arange(keys)[None, :]
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qs, kb, precision=hi) \
             / math.sqrt(Dh)
         i = start + jnp.arange(blk)[:, None]
-        seen = j <= i
+        seen = jb <= i
         if window:
-            seen = seen & (i - j < window)
+            seen = seen & (i - jb < window)
         p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bkgqs,bskd->bqkgd", p, v, precision=hi)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p, vb, precision=hi)
 
     out = lax.map(block, (jnp.moveaxis(qb, 1, 0),
                           jnp.arange(S // blk) * blk))
@@ -385,8 +433,12 @@ def _flash(cfg: TransformerConfig, kind: str, q, k, v):
         return _attention_f32(q, k, v, w)
     from ..ops import flash_attention
 
-    return flash_attention(
-        q, k, v, causal=True, window=w if w and q.shape[1] > w else None)
+    if not w or q.shape[1] <= w:
+        return flash_attention(q, k, v, causal=True)
+    # Blocks of 512 queries: 4.49 ms a layer at 8,192 positions under a
+    # window of 1,024 against 5.51 with the kernel's 256 (and 10.79 when
+    # its grid walked all of kv; a global layer 15.10: my chip runs, PR 32).
+    return flash_attention(q, k, v, causal=True, window=w, block_q=512)
 
 
 def _put(cfg, cache, l, slots, rows):
@@ -485,26 +537,27 @@ def _free_attend(cfg, l, kind, q, k, v, state):
 # ---------------------------------------------------------------------------
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
-            slots) -> Tuple[KVCache, jax.Array]:
+            slots) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D))."""
-    S = tokens.shape[1]
-    sin, cos = rope_tables(cfg, S)
-    x, (kg, vg, kw, vw), _, _ = _run(
-        cfg, params, _embed(cfg, params, tokens), sin, cos,
+    hidden states (W, S, D), routing stats of the tile (3,) as `decode`
+    gives a step's, over all W x S positions, padding too; None with no
+    routed layer)."""
+    rope = rope_by_kind(cfg, tokens.shape[1])
+    x, (kg, vg, kw, vw), stats, _ = _run(
+        cfg, params, _embed(cfg, params, tokens), rope,
         partial(_prefill_attend, cfg, slots, lengths),
         (cache.k, cache.v, cache.kw, cache.vw))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     return KVCache(k=kg, v=vg, seq_lens=seq_lens, kw=kw, vw=vw), \
-        _final(cfg, params, x)
+        _final(cfg, params, x), stats if routed_layers(cfg) else None
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
     D), the experts every routed layer chose: see `_run`)."""
-    sin, cos = rope_tables(cfg, tokens.shape[1])
-    x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), sin,
-                           cos, partial(_free_attend, cfg), None)
+    rope = rope_by_kind(cfg, tokens.shape[1])
+    x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
+                           partial(_free_attend, cfg), None)
     return _final(cfg, params, x), chosen
 
 
@@ -516,10 +569,9 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     with no routed layer). `live` (B,) bool: the slots a request owns
     (None: every one)."""
     positions = cache.seq_lens
-    sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
-    sin, cos = sin_t[positions][:, None, :], cos_t[positions][:, None, :]
+    rope = rope_by_kind(cfg, cache.max_seq_len, positions)
     x, (kg, vg, kw, vw), stats, _ = _run(
-        cfg, params, _embed(cfg, params, tokens)[:, None, :], sin, cos,
+        cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
         partial(_decode_attend, cfg, positions, live),
         (cache.k, cache.v, cache.kw, cache.vw))
     cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)

@@ -20,6 +20,7 @@ is new TPU-native code.
 from __future__ import annotations
 
 import importlib
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -37,18 +38,60 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #   weights  init_params(cfg, key), num_params(cfg)
 #   cache    init_cache(cfg, num_slots, max_seq_len) -> generate.KVCache
 #   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
-#              -> (cache', final-normed hidden states (W, S, D))
+#              -> (cache', final-normed hidden states (W, S, D),
+#                  routing stats (3,) or None)
 #            forward_free(cfg, params, tokens (W, S))
 #              -> (final-normed hidden states, experts chosen or None)
 #            decode(cfg, params, cache, tokens (B,), live (B,) bool or None)
 #              -> (cache', logits (B, V), routing stats (3,) or None)
 #            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
-#            routed_layers(cfg): the layers `decode`'s stats count over
+#            routed_layers(cfg): the layers the stats count over
 # and, where it has them (`offered`): `suffix` (the walk behind a shared
 # prefix), `param_logical_axes` (sharding rules), `forward_train` (the
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one
 # says why in its `MISSING`.
-STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic"}
+STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
+                          "mellum": "periodic"}
+
+
+@dataclass(frozen=True)
+class PeriodForm:
+    """What a layer of the period stack (models/periodic.py) has: the
+    stack reads this and never asks for an architecture by name.
+    `rotary`: the kinds of layer ("window", "global") whose q and k get
+    the rotary embedding, each with the table of its own section of
+    `TransformerConfig.rope_parameters` (`rope_tables`)."""
+
+    attn_gate: bool       # attention output x sigmoid(h @ wg) before wo
+    post_norms: bool      # RMS norms on the attention and FFN outputs
+    embed_scale: bool     # embedding x sqrt(d_model)
+    router_bias: bool     # a per-expert bias added for the selection only
+    rotary: Tuple[str, ...]
+
+
+# `TransformerConfig.arch` -> its layer, for the architectures of STACKS
+# that the period stack serves.
+PERIOD_FORMS: Dict[str, PeriodForm] = {
+    # Arcee Trinity: four norms a layer, a gated attention output, a
+    # scaled embedding, rotary on window layers only.
+    "afmoe": PeriodForm(attn_gate=True, post_norms=True, embed_scale=True,
+                        router_bias=True, rotary=("window",)),
+    # JetBrains Mellum 2: a pre-norm layer of two norms, rotary on both
+    # kinds of layer, a table a kind.
+    "mellum": PeriodForm(attn_gate=False, post_norms=False,
+                         embed_scale=False, router_bias=False,
+                         rotary=("window", "global")),
+}
+
+
+def _frozen(value):
+    """A JSON value as something hashable (a config is a static argument
+    of every jitted program): dicts as sorted tuples of pairs."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _frozen(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
 
 
 def stack(cfg: "TransformerConfig"):
@@ -105,7 +148,9 @@ class TransformerConfig:
     # (leading dense layers, then periods of window layers closed by a
     # global one; QK-norm, a gated attention output, four norms a layer,
     # rotary on window layers only, a scaled embedding). Served only:
-    # forward / loss_fn raise for it. STACKS above holds the names.
+    # forward / loss_fn raise for it. "mellum": the same stack with
+    # another layer (PERIOD_FORMS: two norms, no gate, rotary on both
+    # kinds of layer). STACKS above holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length; its last layer is global
@@ -115,21 +160,29 @@ class TransformerConfig:
     score_func: str = "softmax"      # router scores: "softmax" | "sigmoid"
     route_norm: bool = True          # chosen scores renormalised to sum 1
     route_scale: float = 1.0
+    # A rotary description a section, as a model's config.json gives it
+    # ({"full_attention": {"rope_type": "yarn", "rope_theta": ..,
+    # "factor": .., ...}, "sliding_attention": {"rope_type": "default",
+    # "rope_theta": ..}}); None = `rope_theta`, unscaled, for every
+    # section. Kept as sorted tuples of pairs (hashable): `rope_section`.
+    rope_parameters: Any = None
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        object.__setattr__(self, "rope_parameters",
+                           _frozen(self.rope_parameters))
         if self.arch not in STACKS:
             raise ValueError(f"arch must be one of {sorted(STACKS)}, got "
                              f"{self.arch!r}")
-        if self.arch == "afmoe":
+        if self.arch in PERIOD_FORMS:
             body = self.n_layers - self.n_dense_layers
             if self.global_attn_every < 1 or body < 0 \
                     or body % self.global_attn_every:
                 raise ValueError(
-                    f"afmoe: n_layers - n_dense_layers ({body}) must be "
-                    f"whole periods of global_attn_every "
+                    f"{self.arch}: n_layers - n_dense_layers ({body}) must "
+                    f"be whole periods of global_attn_every "
                     f"({self.global_attn_every})")
 
     @property
@@ -139,6 +192,17 @@ class TransformerConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def period_form(self) -> PeriodForm:
+        return PERIOD_FORMS[self.arch]
+
+    def rope_section(self, section: Optional[str]) -> Dict[str, Any]:
+        """The rotary description of `section` (None, or no
+        `rope_parameters`: `rope_theta`, unscaled)."""
+        if section is None or self.rope_parameters is None:
+            return {"rope_type": "default", "rope_theta": self.rope_theta}
+        return dict(dict(self.rope_parameters)[section])
 
     def num_params(self) -> int:
         return stack(self).num_params(self)
@@ -182,14 +246,46 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x * scale.astype(jnp.float32)).astype(dt)
 
 
-def rope_tables(cfg: TransformerConfig, seq_len: int
+def rope_tables(cfg: TransformerConfig, seq_len: int,
+                section: Optional[str] = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    half = cfg.head_dim // 2
-    freqs = cfg.rope_theta ** (
-        -jnp.arange(0, half, dtype=jnp.float32) / half)
+    """(sin, cos), each (seq_len, head_dim / 2), of `cfg.rope_section(
+    section)`. "default": pos x theta^(-2i/d). "yarn" (arXiv:2309.00071,
+    as transformers' `_compute_yarn_parameters` has it): the frequencies
+    that turn more than `beta_fast` times over the original context are
+    kept, those that turn fewer than `beta_slow` times are divided by
+    `factor`, a linear ramp between; sin and cos are multiplied by
+    `attention_factor` (q and k both carry it)."""
+    rope = cfg.rope_section(section)
+    dim, half = cfg.head_dim, cfg.head_dim // 2
+    theta = float(rope["rope_theta"])
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     pos = jnp.arange(seq_len, dtype=jnp.float32)
-    ang = pos[:, None] * freqs[None, :]          # (S, half)
-    return jnp.sin(ang), jnp.cos(ang)
+    kind = rope["rope_type"]
+    if kind == "default":
+        ang = pos[:, None] * freqs[None, :]          # (S, half)
+        return jnp.sin(ang), jnp.cos(ang)
+    if kind != "yarn":
+        raise ValueError(f"rope_type must be 'default' or 'yarn', got "
+                         f"{kind!r}")
+    # Every yarn key as a config.json states it: none is guessed.
+    factor = float(rope["factor"])
+    original = float(rope["original_max_position_embeddings"])
+
+    def turns_at(rotations: float) -> float:
+        # The pair index whose frequency makes `rotations` turns over
+        # the original context.
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(float(rope["beta_fast"]))), 0)
+    high = min(math.ceil(turns_at(float(rope["beta_slow"]))), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    freqs = (1.0 - ramp) * freqs + ramp * freqs / factor
+    scale = float(rope["attention_factor"])
+    ang = pos[:, None] * freqs[None, :]
+    return jnp.sin(ang) * scale, jnp.cos(ang) * scale
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

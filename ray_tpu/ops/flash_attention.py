@@ -66,19 +66,38 @@ def on_tpu() -> bool:
 # Forward kernel
 # ---------------------------------------------------------------------------
 
+def _first_kv_block(q_off, kv_off, qi, block_q, block_k, window):
+    """The kv block holding the first key that the window of q block
+    `qi`'s first query reaches (block 0 where that lies before kv's
+    start)."""
+    first_key = q_off + qi * block_q - (window - 1) - kv_off
+    return jnp.maximum(first_key, 0) // block_k
+
+
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k,
-                num_kv, causal, window=None):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+                num_kv, causal, window=None, kv_steps=None):
+    """One (q block, kv block) step. The last grid axis walks all
+    `num_kv` kv blocks, or, with `kv_steps` (a window: `_fwd_impl`), the
+    `kv_steps` blocks from the first one the q block's window reaches;
+    `offs_ref` is then the scalar-prefetched int32 (2,)."""
+    qi, step = pl.program_id(2), pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_off = offs_ref[0, 0].astype(jnp.int32)
-    kv_off = offs_ref[0, 1].astype(jnp.int32)
+    if kv_steps is None:
+        q_off = offs_ref[0, 0].astype(jnp.int32)
+        kv_off = offs_ref[0, 1].astype(jnp.int32)
+        ki, last = step, num_kv - 1
+    else:
+        q_off, kv_off = offs_ref[0], offs_ref[1]
+        ki = _first_kv_block(q_off, kv_off, qi, block_q, block_k,
+                             window) + step
+        last = kv_steps - 1
 
     def compute():
         q = q_ref[0, 0, :, :]
@@ -122,6 +141,10 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             first_q = q_off + qi * block_q
             last_k = first_k + block_k - 1
             live = live & (first_q - last_k < window)
+            if kv_steps is not None:
+                # ... and a step past kv's last block (its fetch is the
+                # last block again).
+                live = live & (ki < num_kv)
 
         @pl.when(live)
         def _():
@@ -129,7 +152,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         compute()
 
-    @pl.when(ki == num_kv - 1)
+    @pl.when(step == last)
     def _finalize():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -139,47 +162,74 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
               interpret, window=None) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: (B, H, S, D) (kv heads already expanded). → (out, lse)."""
+    """q,k,v: (B, H, S, D) (kv heads already expanded). → (out, lse).
+
+    With a window the last grid axis is as long as the kv blocks a q
+    block's window can reach (`window` - 1 + `block_q` keys: at most
+    that span's blocks and one more, whatever the offsets), not all of
+    kv's: a block behind the window or above the diagonal is neither
+    fetched nor given a step (8,192 positions under a window of 1,024
+    in blocks of 256 x 512: 4 steps a q block where all of kv is 16).
+    The offsets then reach the index maps as a prefetched scalar."""
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
     nq, nk = Sq // block_q, Skv // block_k
-    grid = (B, H, nq, nk)
+    kv_steps = None
+    if window is not None:
+        kv_steps = min(nk, (window + block_q - 3) // block_k + 2)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, num_kv=nk, causal=causal, window=window)
+        block_k=block_k, num_kv=nk, causal=causal, window=window,
+        kv_steps=kv_steps)
+
+    def q_block(b, h, qi, ki, *_):
+        return b, h, qi, 0
+
+    def kv_block(b, h, qi, ki, *offs_ref):
+        if offs_ref:
+            first = _first_kv_block(offs_ref[0][0], offs_ref[0][1], qi,
+                                    block_q, block_k, window)
+            ki = jnp.minimum(first + ki, nk - 1)
+        return b, h, ki, 0
+
+    in_specs = [
+        pl.BlockSpec((1, 1, block_q, D), q_block),
+        pl.BlockSpec((1, 1, block_k, D), kv_block),
+        pl.BlockSpec((1, 1, block_k, D), kv_block),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, 1, block_q, D), q_block),
+        pl.BlockSpec((1, 1, block_q, _LANES), q_block),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+    ]
+    if kv_steps is None:
+        grid = dict(
+            grid=(B, H, nq, nk),
+            in_specs=[pl.BlockSpec((1, 2), lambda b, h, qi, ki: (0, 0),
+                                   memory_space=pltpu.SMEM)] + in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes)
+    else:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, H, nq, kv_steps),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes))
+        offs = offs.astype(jnp.int32).reshape(2)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda b, h, qi, ki: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki: (b, h, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-        ],
         out_shape=[
             _sds((B, H, Sq, D), q.dtype, q, k, v, offs),
             _sds((B, H, Sq, _LANES), jnp.float32, q, k, v, offs),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         metadata={"kernel": "flash_fwd"},
+        **grid,
     )(offs, q, k, v)
     return out, lse[..., 0]
 
@@ -493,7 +543,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     With `window`, a query also sees no key more than `window - 1`
     positions behind it: (q_offset + i) - (kv_offset + j) < window.
-    Forward only; kv blocks wholly behind the window are skipped.
+    Forward only; kv blocks wholly behind the window are given no grid
+    step.
     Returns (B, Sq, H, D).
 
     `interpret=None` compiles the kernels on a TPU and takes the

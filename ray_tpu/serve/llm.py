@@ -307,12 +307,18 @@ class LLMEngine:
             "prefill_tile_rows": 0, "prefill_tokens": 0,
             "prefill_tile_tokens": 0, "queue_side_first_tokens": 0,
             "cache_rows": 0, "cache_rows_held": 0}
-        # The decode blocks of a stack with routed layers report how
-        # their experts were used (models/generate.routed_layers).
+        # The decode blocks and the admission tiles of a stack with
+        # routed layers report how their experts were used
+        # (models/generate.routed_layers).
         self._routed_layers = routed_layers(cfg)
         if self._routed_layers:
             self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
-                               moe_rows=0, moe_rows_max=0)
+                               moe_rows=0, moe_rows_max=0,
+                               prefill_moe_experts_hit=0,
+                               prefill_moe_rows=0, prefill_moe_rows_max=0)
+        # Admission tiles' routing stats, on their way to the host: read
+        # where the host next waits for a tile (_deliver_first_tokens).
+        self._tile_moe: List[jax.Array] = []
         # The last FINISHED_RING completed requests (ttft percentiles
         # in stats() are over these).
         self.finished: deque = deque(maxlen=self.FINISHED_RING)
@@ -743,11 +749,13 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt, req.temperature)
                              for req in reqs])
-                        self.cache, toks, lps = prefill_sample_batch(
+                        self.cache, toks, lps, *moe = prefill_sample_batch(
                             self.cfg, self.params, self.cache,
                             jnp.asarray(buf), jnp.asarray(lens),
                             jnp.asarray(slot_idx), self.top_k,
                             jnp.asarray(temps), sub)
+                    self._tile_moe += moe       # routing stats (3,)
+                    _copy_to_host_async(*moe)
                 else:
                     sp = len(pkey)
                     with self._tile_span("slot", bucket, W, reqs, skip=sp):
@@ -870,13 +878,28 @@ class LLMEngine:
         in flight via copy_to_host_async)."""
         if fused is None:
             return
-        with tracing.span("engine.deliver_first", tokens=len(admitted)
-                          + sum(len(reqs) for reqs, _, _ in outs)):
+        span = tracing.span("engine.deliver_first", tokens=len(admitted)
+                            + sum(len(reqs) for reqs, _, _ in outs))
+        with span:
             with tracing.span("engine.fetch"):  # the host waits here
                 fused = np.asarray(fused)
                 fused_lp = np.concatenate(
                     [np.asarray(lps)[j:j + 1] for _, _, lps, j in admitted]
                     + [np.asarray(lps) for _, _, lps in outs])
+                tile_moe = [np.asarray(m) for m in self._tile_moe]
+                self._tile_moe = []
+            if tile_moe:
+                # What the admission tiles behind these tokens (and any
+                # whose tokens the queue side had served) routed: a
+                # tile's span ends at its dispatch, before the device
+                # knows, so the numbers ride the span that waits for it.
+                hit, rows, fullest = (int(n) for n in np.sum(tile_moe, 0))
+                routed = dict(prefill_moe_experts_hit=hit,
+                              prefill_moe_rows=rows,
+                              prefill_moe_rows_max=fullest)
+                for name, n in routed.items():
+                    self.counts[name] += n
+                span.set(moe_tiles=len(tile_moe), **routed)
             self._emit_first_tokens(fused, fused_lp, admitted, outs)
 
     def _emit_first_tokens(self, fused, fused_lp, admitted: List,

@@ -141,7 +141,7 @@ def test_tile_first_token_and_block_agree_with_the_reference(params):
     temps = jnp.zeros((4,), jnp.float32)
     key = jax.random.key(0)
     cache = init_kv_cache(CFG, 3, 48)
-    cache, first, _ = prefill_sample_batch(
+    cache, first, *_ = prefill_sample_batch(
         CFG, params, cache, jnp.asarray(toks), lengths, slots, 0, temps, key)
     free, _ = first_token_sample(CFG, params, jnp.asarray(toks), lengths,
                                  temps, 0, key)
@@ -382,7 +382,7 @@ def _serve_outputs(cfg):
     temps = jnp.zeros((2,), jnp.float32)
     key = jax.random.key(1)
     cache = init_kv_cache(cfg, 2, 32)
-    cache, first, _ = prefill_sample_batch(
+    cache, first, *_ = prefill_sample_batch(
         cfg, params, cache, toks, lengths, jnp.asarray([0, 1], jnp.int32),
         0, temps, key)
     cache, block, _ = decode_multi(cfg, params, cache, first, temps, 4, 0,
@@ -500,6 +500,37 @@ def test_windowed_kernel_in_the_interpreter_against_the_reference(
     np.testing.assert_allclose(
         np.asarray(want), np.asarray(_plain(q, k, v, window, q_offset)),
         rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("window,q_offset,kv_offset", [
+    (33, 0, 0), (64, 0, 0), (100, 0, 0), (511, 0, 0), (64, 160, 0),
+    (64, 96, 32)])
+def test_windowed_kernel_walks_the_blocks_its_window_reaches(
+        window, q_offset, kv_offset):
+    """512 keys in blocks of 32 x 64: the grid's kv axis is as long as
+    the blocks a q block's window can reach (`window` - 1 + 32 keys: 3,
+    3, 4 of the 8, all 8 for a window of 511), wherever the offsets put
+    the window's first block, and a step past kv's end adds nothing."""
+    sq = 512 - q_offset
+    q, k, v = _qkv(sq, 512, seed=window + q_offset)
+    kw = dict(causal=True, window=window, q_offset=q_offset,
+              kv_offset=kv_offset, block_q=32, block_k=64)
+    got = fa_fn(q, k, v, interpret=True, **kw)
+    want = fa_fn(q, k, v, force_reference=True, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: fa_fn(q, k, v, interpret=True, **kw))(q, k, v)
+    grids = [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert grids == [(2, 4, sq // 32, min(8, (window + 29) // 64 + 2))]
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 def test_no_window_is_the_call_it_was():

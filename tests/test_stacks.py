@@ -15,7 +15,8 @@ from ray_tpu.models import configs, generate
 from ray_tpu.models.transformer import STACKS, init_params, offered, stack
 
 # The tiny preset of each architecture of the table.
-TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test}
+TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
+        "mellum": configs.tiny_mellum_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -42,7 +43,7 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
 
     toks = jax.ShapeDtypeStruct((W, S), jnp.int32)
     rows = jax.ShapeDtypeStruct((W,), jnp.int32)
-    filled, x = jax.eval_shape(
+    filled, x, tile_stats = jax.eval_shape(
         lambda p, c, t, n, s: st.prefill(cfg, p, c, t, n, s),
         params, cache, toks, rows, rows)
     assert jax.tree.map(lambda a: (a.shape, a.dtype), filled) == \
@@ -61,6 +62,8 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
     assert jax.tree.structure(stepped) == jax.tree.structure(cache)
     assert (logits.shape, logits.dtype) == ((B, V), jnp.float32)
     assert (stats is None) == (st.routed_layers(cfg) == 0)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), tile_stats) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), stats)
     assert generate.routed_layers(cfg) == st.routed_layers(cfg)
     if stats is not None:
         assert (stats.shape, stats.dtype) == ((3,), jnp.int32)

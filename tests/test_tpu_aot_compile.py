@@ -582,11 +582,112 @@ def test_trinity_prefill_programs_fit(serve_trinity, as_on_the_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
-def test_windowed_flash_forward_compiles(topo):
-    """The forward kernel with a window of 2,048 at the cell's long
-    prefill: 32 Q / 4 KV heads of 128 over 4,096 positions."""
-    q, k = _qkv(topo, 4096, 4096, 32, 4, 128)
+@pytest.mark.parametrize("S,window", [(4096, 2048), (8192, 1024)])
+def test_windowed_flash_forward_compiles(topo, S, window):
+    """The forward kernel with a window of 2,048 at trinity's long
+    prefill and of 1,024 at mellum's admission tile (32 Q / 4 KV heads
+    of 128), in the blocks of 512 queries `periodic._flash` asks for: the
+    kv axis of the grid is the blocks a window reaches, found from the
+    prefetched offsets."""
+    q, k = _qkv(topo, S, S, 32, 4, 128)
     text = jax.jit(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=True, window=2048, interpret=False)).lower(
-            q, k, k).compile().as_text()
+        q, k, v, causal=True, window=window, block_q=512,
+        interpret=False)).lower(q, k, k).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# -- the period stack's other layer (benchmarks/cells/mellum2-repoctx-lone) --
+
+@pytest.fixture(scope="module")
+def serve_mellum(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "mellum2-repoctx-lone.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("mellum2-12b-l8", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _mellum_under(serve_mellum, activations):
+    """The cell's configuration and cache (bf16 activations) or the
+    float32 form PERF.md compares it with (two bf16 terms a cache row,
+    so twice the layers' entries)."""
+    from ray_tpu.models.generate import init_kv_cache
+
+    cfg, slots, one, key, params, cache = serve_mellum
+    assert cfg.dtype == cfg.param_dtype == jnp.bfloat16
+    assert cache.k.shape == (2, 4, 8192, 4, 128)
+    assert cache.kw.shape == (6, 4, 1024, 4, 128)
+    if activations == "float32":
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+        cache = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            jax.eval_shape(lambda: init_kv_cache(cfg, slots, 8192)))
+        assert cache.kw.shape == (12, 4, 1024, 4, 128)
+    return cfg, slots, one, key, params, cache
+
+
+@pytest.mark.parametrize("activations", ["float32", "bfloat16"])
+def test_mellum_decode_block_reaches_the_grouped_kernel_under_128_rows(
+        serve_mellum, as_on_the_chip, activations):
+    """`decode_multi` (k = 8) at the cell's 4 slots x 8192, and under
+    float32 activations: 4 slots x 8 pairs = 32 (two bf16 terms: 64)
+    rows a grouped product, padded to the kernel's row tile:
+    megablox's kernel at 2304 x 896 and 896 x 2304 and no `ragged-dot`;
+    both kinds of cache aliased; all 64 experts of eight layers and the
+    98,304-row head on one chip."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = _mellum_under(serve_mellum,
+                                                        activations)
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key, live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+    assert "decode_attn" in text
+    held = sum(x.size * x.dtype.itemsize
+               for x in (cache.k, cache.v, cache.kw, cache.vw))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("activations", ["float32", "bfloat16"])
+def test_mellum_admission_tile_fits_beside_weights_and_cache(
+        serve_mellum, as_on_the_chip, record_property, activations):
+    """The one-row tile of the 8,192 bucket: 65,536 token-expert pairs a
+    grouped product through megablox's kernel (131,072 rows as two bf16
+    terms under float32 activations); attention through the flash
+    kernel, a window layer's with the window (float32: products over
+    slices of 1,280 keys, and all 8,192 on a global layer); it compiles
+    for the described chip beside 7.6 GB of weights and the cell's cache
+    (bf16 7.77 GB of arguments + 1.45 of temporaries; float32 7.96 +
+    3.29: read here in PR 32)."""
+    from ray_tpu.models.generate import prefill_sample_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = _mellum_under(serve_mellum,
+                                                        activations)
+    W = LLMEngine._tile_rows(8192)
+    assert W == 1
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    before = fa.DISPATCH_COUNTS["pallas"]
+    compiled = prefill_sample_batch.lower(
+        cfg, params, cache, arr((W, 8192), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+    for scope in ("attn_window", "attn_global", "moe_router"):
+        assert scope in text, scope
+    # The flash kernel a layer of the period (three window layers and
+    # a global one; the periods are a scan), traced once each.
+    assert fa.DISPATCH_COUNTS["pallas"] - before == \
+        (4 if activations == "bfloat16" else 0)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
