@@ -435,10 +435,10 @@ def _flash(cfg: TransformerConfig, kind: str, q, k, v):
 
     if not w or q.shape[1] <= w:
         return flash_attention(q, k, v, causal=True)
-    # Blocks of 512 queries: 4.49 ms a layer at 8,192 positions under a
-    # window of 1,024 against 5.51 with the kernel's 256 (and 10.79 when
-    # its grid walked all of kv; a global layer 15.10: my chip runs, PR 32).
-    return flash_attention(q, k, v, causal=True, window=w, block_q=512)
+    # The kernel chooses its blocks and gives a step to the blocks a window
+    # reaches and no other (`ops/flash_attention._fwd_blocks`, `_fwd_grid`);
+    # PERF.md section 6, PR 33, has what a layer costs with and without.
+    return flash_attention(q, k, v, causal=True, window=w)
 
 
 def _put(cfg, cache, l, slots, rows):

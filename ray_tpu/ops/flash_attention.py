@@ -1,10 +1,12 @@
 """Fused flash attention (pallas, TPU).
 
-FlashAttention-2-style tiling for the MXU: grid over (batch, head,
-q-block, kv-block) with the kv-block dimension innermost/sequential;
-online-softmax statistics (m, l) and the output accumulator live in VMEM
-scratch across kv iterations, so HBM traffic is O(S) per head instead of
-the O(S^2) score matrix. The backward pass recomputes scores blockwise
+FlashAttention-2-style tiling for the MXU: grid over (batch, head) and,
+innermost and sequential, the (q-block, kv-block) pairs the mask leaves
+a live pair in (the forward: `_fwd_grid`; the backward kernels walk
+every pair of blocks); online-softmax statistics (m, l) and the output
+accumulator live in VMEM scratch across a q block's kv steps, so HBM
+traffic is O(S) per head instead of the O(S^2) score matrix. The backward
+pass recomputes scores blockwise
 (two kernels: dq with a kv loop, dk/dv with a q loop) from the saved
 logsumexp — the standard remat trade that keeps HBM residency at
 activation size.
@@ -27,10 +29,11 @@ from __future__ import annotations
 import collections
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -45,6 +48,12 @@ _SUBLANES = 8
 # ring_attention counts "ring_pallas" and "ring_reference_<why>",
 # decode_attention "decode_attn" where its kernel is traced.
 DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
+
+# What the forward kernel's grids were given, summed over the calls traced
+# (x batch x heads), the same way: `steps`, `live_steps`, `masked_steps`
+# (`grid_steps`) of the calls whose offsets were Python ints, `traced_steps`
+# of the others.
+FLASH_GRID: "collections.Counter[str]" = collections.Counter()
 
 
 def _sds(shape, dtype, *like):
@@ -66,171 +75,282 @@ def on_tpu() -> bool:
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _first_kv_block(q_off, kv_off, qi, block_q, block_k, window):
-    """The kv block holding the first key that the window of q block
-    `qi`'s first query reaches (block 0 where that lies before kv's
-    start)."""
-    first_key = q_off + qi * block_q - (window - 1) - kv_off
-    return jnp.maximum(first_key, 0) // block_k
+# What a (q block, kv block) pair of a causal layer holds: no live pair
+# (above the diagonal, or behind every query's window), live pairs only,
+# or both (the diagonal or the window's far edge crosses it).
+_DEAD, _INSIDE, _EDGE = 0, 1, 2
+
+# A table of pairs longer than this (three int32 words a pair in scalar
+# memory; 128k positions in blocks of 512 are 32,896) falls back to the
+# grid that walks runs of kv blocks.
+_MAX_PAIRS = 16384
+
+# Fast memory the forward may take: a block's scores, their exponentials
+# and the bf16 copy are some 10 bytes a pair, 10.5 MB at 1024 x 1024 and 40
+# at 2048 x 2048, where the default limit of 16 MB stopped 2048 x 512 with
+# the mask at 16.4 (my chip runs, PR 33). A v5e has 128 MB.
+_FWD_VMEM_BYTES = 48 * 2 ** 20
 
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k,
-                num_kv, causal, window=None, kv_steps=None):
-    """One (q block, kv block) step. The last grid axis walks all
-    `num_kv` kv blocks, or, with `kv_steps` (a window: `_fwd_impl`), the
-    `kv_steps` blocks from the first one the q block's window reaches;
-    `offs_ref` is then the scalar-prefetched int32 (2,)."""
-    qi, step = pl.program_id(2), pl.program_id(3)
+def _block_kind(first_q, first_k, block_q, block_k, window):
+    """(live, inside) of the block of causal scores whose first query and
+    first key stand at global positions `first_q` and `first_k`: whether
+    it holds a live pair at all, and whether it holds nothing else (no
+    mask needed). Python ints, numpy arrays and traced scalars alike."""
+    last_q, last_k = first_q + block_q - 1, first_k + block_k - 1
+    live, inside = last_q >= first_k, first_q >= last_k
+    if window is not None:
+        live = live & (first_q - last_k < window)
+        inside = inside & (last_q - first_k < window)
+    return live, inside
 
-    @pl.when(step == 0)
+
+def _live_pairs(nq, nk, block_q, block_k, causal, window, q_off, kv_off):
+    """The (q block, kv block) pairs a forward with offsets known at trace
+    time walks, as int32 arrays (qi, ki, kind), q blocks in order and each
+    one's kv blocks in order: every pair that holds a live pair once, no
+    other, but for a q block that sees no key at all, which keeps its
+    first pair (_DEAD) as the step that writes its zeros."""
+    qi, ki = np.meshgrid(np.arange(nq, dtype=np.int32),
+                         np.arange(nk, dtype=np.int32), indexing="ij")
+    if causal:
+        live, inside = _block_kind(q_off + qi * block_q,
+                                   kv_off + ki * block_k, block_q, block_k,
+                                   window)
+    else:
+        live = inside = np.ones((nq, nk), bool)
+    kind = np.where(live, np.where(inside, _INSIDE, _EDGE), _DEAD)
+    keep = live.copy()
+    keep[~live.any(axis=1), 0] = True
+    return qi[keep], ki[keep], kind[keep].astype(np.int32)
+
+
+def _kv_run(nk, block_q, block_k, window):
+    """kv steps a q block of the run grid: all of kv's blocks, or the
+    blocks a window can reach (`window` - 1 + `block_q` keys: at most
+    that span's blocks and one more, whatever the offsets)."""
+    if window is None:
+        return nk
+    return min(nk, (window + block_q - 3) // block_k + 2)
+
+
+def grid_steps(sq, skv, block_q, block_k, *, causal, window=None,
+               q_offset=0, kv_offset=0):
+    """What the forward's grid spends on one (batch, head) of a call:
+    `steps` it is given, `live_steps` whose block holds a live pair,
+    `masked_steps` that build the mask (blocks an edge crosses). The
+    grid is built from the same table (`_fwd_grid`). With an offset the
+    trace cannot see (None) only `traced_steps` is known: a run of kv
+    blocks a q block, right for any offset."""
+    nq, nk = sq // block_q, skv // block_k
+    if not causal:
+        q_offset = kv_offset = 0
+    if q_offset is None or kv_offset is None:
+        return {"traced_steps": nq * _kv_run(nk, block_q, block_k, window)}
+    kind = _live_pairs(nq, nk, block_q, block_k, causal, window, q_offset,
+                       kv_offset)[2]
+    steps = len(kind)
+    if steps > _MAX_PAIRS:
+        steps = nq * _kv_run(nk, block_q, block_k, window)
+    return {"steps": steps, "live_steps": int(np.sum(kind != _DEAD)),
+            "masked_steps": int(np.sum(kind == _EDGE))}
+
+
+class _Step(NamedTuple):
+    """Where a grid step stands (`_fwd_grid`'s `locate`)."""
+    qi: Any          # q block
+    ki: Any          # kv block scored
+    fetch: Any       # kv block fetched (a dead step repeats a live one)
+    first: Any       # first / last step of its q block
+    last: Any
+    kind: Any        # _DEAD / _INSIDE / _EDGE
+    q_off: Any       # global position of q's, kv's element 0
+    kv_off: Any
+
+
+def _fwd_grid(nq, nk, block_q, block_k, causal, window, static_offs, offs):
+    """-> (grid behind (B, H), scalar-prefetch operands, locate).
+    `locate(ids, refs)` places a step from its grid indices and the
+    prefetched operands, in the kernel and in the index maps.
+
+    Offsets known at trace time (`static_offs`; any `causal=False` call):
+    one grid axis over the table of live pairs, so a block with no live
+    pair is neither given a step nor fetched. Traced offsets (a ring
+    step's): (nq, run of kv blocks), the run starting at the first block
+    a q block's window reaches and a step above the diagonal repeating
+    the last block under it, which fetches nothing."""
+    if not causal:
+        static_offs = (0, 0)
+    if static_offs is not None:
+        qi, ki, kind = _live_pairs(nq, nk, block_q, block_k, causal, window,
+                                   *static_offs)
+        if len(qi) <= _MAX_PAIRS:
+            turn = qi[1:] != qi[:-1]
+            flags = (np.r_[True, turn] | np.r_[turn, True] << 1
+                     | kind << 2).astype(np.int32)
+
+            def locate(ids, refs):
+                (p,), (qi_ref, ki_ref, flag_ref) = ids, refs
+                f = flag_ref[p]
+                return _Step(qi_ref[p], ki_ref[p], ki_ref[p], (f & 1) == 1,
+                             (f & 2) == 2, f >> 2, *static_offs)
+
+            return (len(qi),), (qi, ki, flags), locate
+        offs = np.asarray(static_offs, np.int32)
+    run = _kv_run(nk, block_q, block_k, window)
+
+    def locate(ids, refs):
+        (qi, step), (offs_ref,) = ids, refs
+        q_off, kv_off = offs_ref[0], offs_ref[1]
+        if not causal:
+            return _Step(qi, step, step, step == 0, step == run - 1,
+                         _INSIDE, q_off, kv_off)
+        first_q = q_off + qi * block_q
+        ki = step
+        if window is not None:
+            ki += jnp.maximum(first_q - (window - 1) - kv_off, 0) // block_k
+        under = jnp.maximum(first_q + block_q - 1 - kv_off, 0) // block_k
+        live, inside = _block_kind(first_q, kv_off + ki * block_k, block_q,
+                                   block_k, window)
+        live = live & (ki < nk)
+        kind = jnp.where(live, jnp.where(inside, _INSIDE, _EDGE), _DEAD)
+        return _Step(qi, ki, jnp.minimum(jnp.minimum(ki, under), nk - 1),
+                     step == 0, step == run - 1, kind, q_off, kv_off)
+
+    return (nq, run), (jnp.asarray(offs, jnp.int32).reshape(2),), locate
+
+
+def _fwd_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
+                causal, window):
+    """One (q block, kv block) step of the online softmax."""
+    (q_ref, k_ref, v_ref, o_ref, lse_ref,
+     qs_ref, acc_ref, m_ref, l_ref) = refs[n_scalars:]
+    at = locate(tuple(pl.program_id(2 + i) for i in range(n_ids)),
+                refs[:n_scalars])
+
+    @pl.when(at.first)
     def _init():
+        # The score scale, once a q block and over (block_q, D), not once
+        # a step over (block_q, block_k).
+        qs_ref[...] = (q_ref[0, 0, :, :].astype(jnp.float32)
+                       * sm_scale).astype(qs_ref.dtype)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    if kv_steps is None:
-        q_off = offs_ref[0, 0].astype(jnp.int32)
-        kv_off = offs_ref[0, 1].astype(jnp.int32)
-        ki, last = step, num_kv - 1
-    else:
-        q_off, kv_off = offs_ref[0], offs_ref[1]
-        ki = _first_kv_block(q_off, kv_off, qi, block_q, block_k,
-                             window) + step
-        last = kv_steps - 1
-
-    def compute():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = (q_off + qi * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (kv_off + ki * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            mask = q_pos >= k_pos
+    def compute(masked):
+        s = lax.dot_general(qs_ref[...], k_ref[0, 0, :, :],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        if masked:
+            # q_pos >= k_pos, as row - column against one scalar.
+            ahead = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                     - lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            diag = (at.kv_off + at.ki * block_k) - (at.q_off
+                                                    + at.qi * block_q)
+            mask = ahead >= diag
             if window is not None:
-                mask = mask & (q_pos - k_pos < window)
+                mask = mask & (ahead < diag + window)
             s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        # One reduction over the lanes a step, the max's. m_ref holds a
+        # row's running max in every lane; l_ref its running sum a lane
+        # (summed over the lanes once a q block, in `_finalize`), so the
+        # step's sum is adds between the scores' 128-lane columns. Blocks
+        # that are no multiple of 128 keys keep the row's sum in lane 0.
+        wide = _LANES if block_k % _LANES == 0 else block_k
+        cols = range(0, block_k, wide)
+        tiles = [s[:, c:c + wide] for c in cols]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, tiles), axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = p * mask  # fully-masked rows must contribute exactly 0
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        m_tile = m_new if wide == _LANES else m_new[:, :1]
+        ps = [jnp.exp(t - m_tile) for t in tiles]
+        if masked:
+            # A row masked whole, here and in every block before (the
+            # far edge of a window): it must contribute exactly 0.
+            ps = [jnp.where(mask[:, c:c + wide], t, 0.0)
+                  for c, t in zip(cols, ps)]
+        part = sum(ps[1:], ps[0]) if wide == _LANES else jnp.sum(
+            ps[0], axis=-1, keepdims=True)
+        w = part.shape[1]
+        l_ref[:, :w] = alpha[:, :w] * l_ref[:, :w] + part
+        m_ref[...] = m_new
         v = v_ref[0, 0, :, :]
-        pv = lax.dot(p.astype(v.dtype), v,
+        pv = lax.dot(jnp.concatenate(ps, axis=1).astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[...] = acc_ref[...] * (
+            alpha if alpha.shape == acc_ref.shape else alpha[:, :1]) + pv
 
+    # A block wholly inside the mask takes the body without one; a fully
+    # masked row can only occur in a block an edge crosses.
+    pl.when(at.kind == _INSIDE)(functools.partial(compute, False))
     if causal:
-        # Block skip: whole kv block above the diagonal → no compute.
-        last_q = q_off + (qi + 1) * block_q - 1
-        first_k = kv_off + ki * block_k
-        live = last_q >= first_k
-        if window is not None:
-            # ... and whole kv block behind every query's window.
-            first_q = q_off + qi * block_q
-            last_k = first_k + block_k - 1
-            live = live & (first_q - last_k < window)
-            if kv_steps is not None:
-                # ... and a step past kv's last block (its fetch is the
-                # last block again).
-                live = live & (ki < num_kv)
+        pl.when(at.kind == _EDGE)(functools.partial(compute, True))
 
-        @pl.when(live)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(step == last)
+    @pl.when(at.last)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
         o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :, :] = jnp.broadcast_to(
-            m_ref[:, :1] + jnp.log(l), lse_ref.shape[2:])
+        lse_ref[0, 0, :, :] = m_ref[...] + jnp.log(l)
 
 
 def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
-              interpret, window=None) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: (B, H, S, D) (kv heads already expanded). → (out, lse).
-
-    With a window the last grid axis is as long as the kv blocks a q
-    block's window can reach (`window` - 1 + `block_q` keys: at most
-    that span's blocks and one more, whatever the offsets), not all of
-    kv's: a block behind the window or above the diagonal is neither
-    fetched nor given a step (8,192 positions under a window of 1,024
-    in blocks of 256 x 512: 4 steps a q block where all of kv is 16).
-    The offsets then reach the index maps as a prefetched scalar."""
+              interpret, window=None, static_offs=None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """q (B, H, Sq, D); k, v (B, KVH, Skv, D), KVH dividing H: a query
+    head reads kv head `h // (H // KVH)`, nothing is expanded.
+    -> (out, lse). `static_offs`: (q_offset, kv_offset) as Python ints
+    where the caller knows them, and then `offs` is not read; else `offs`
+    (two numbers, traced or not) reaches the index maps as a prefetched
+    scalar. The grid: `_fwd_grid`."""
     B, H, Sq, D = q.shape
-    Skv = k.shape[2]
-    nq, nk = Sq // block_q, Skv // block_k
-    kv_steps = None
-    if window is not None:
-        kv_steps = min(nk, (window + block_q - 3) // block_k + 2)
+    group = H // k.shape[1]
+    nq, nk = Sq // block_q, k.shape[2] // block_k
+    tail, scalars, locate = _fwd_grid(nq, nk, block_q, block_k, causal,
+                                      window, static_offs, offs)
+    n_ids = len(tail)
+
+    def q_block(b, h, *rest):
+        return b, h, locate(rest[:n_ids], rest[n_ids:]).qi, 0
+
+    def kv_block(b, h, *rest):
+        return b, h // group, locate(rest[:n_ids], rest[n_ids:]).fetch, 0
+
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, num_kv=nk, causal=causal, window=window,
-        kv_steps=kv_steps)
-
-    def q_block(b, h, qi, ki, *_):
-        return b, h, qi, 0
-
-    def kv_block(b, h, qi, ki, *offs_ref):
-        if offs_ref:
-            first = _first_kv_block(offs_ref[0][0], offs_ref[0][1], qi,
-                                    block_q, block_k, window)
-            ki = jnp.minimum(first + ki, nk - 1)
-        return b, h, ki, 0
-
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, D), q_block),
-        pl.BlockSpec((1, 1, block_k, D), kv_block),
-        pl.BlockSpec((1, 1, block_k, D), kv_block),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, D), q_block),
-        pl.BlockSpec((1, 1, block_q, _LANES), q_block),
-    ]
-    scratch_shapes = [
-        pltpu.VMEM((block_q, D), jnp.float32),
-        pltpu.VMEM((block_q, _LANES), jnp.float32),
-        pltpu.VMEM((block_q, _LANES), jnp.float32),
-    ]
-    if kv_steps is None:
-        grid = dict(
-            grid=(B, H, nq, nk),
-            in_specs=[pl.BlockSpec((1, 2), lambda b, h, qi, ki: (0, 0),
-                                   memory_space=pltpu.SMEM)] + in_specs,
-            out_specs=out_specs, scratch_shapes=scratch_shapes)
-    else:
-        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, H, nq, kv_steps),
-            in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=scratch_shapes))
-        offs = offs.astype(jnp.int32).reshape(2)
+        _fwd_kernel, locate=locate, n_scalars=len(scalars), n_ids=n_ids,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k, causal=causal,
+        window=window)
     out, lse = pl.pallas_call(
         kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(B, H) + tail,
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_block),
+                pl.BlockSpec((1, 1, block_k, D), kv_block),
+                pl.BlockSpec((1, 1, block_k, D), kv_block),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_block),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_block),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), q.dtype),
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ]),
         out_shape=[
             _sds((B, H, Sq, D), q.dtype, q, k, v, offs),
             _sds((B, H, Sq, _LANES), jnp.float32, q, k, v, offs),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel",) * (n_ids + 1)
+            + ("arbitrary",),
+            vmem_limit_bytes=_FWD_VMEM_BYTES),
         interpret=interpret,
         metadata={"kernel": "flash_fwd"},
-        **grid,
-    )(offs, q, k, v)
+    )(*scalars, q, k, v)
     return out, lse[..., 0]
 
 
@@ -443,46 +563,49 @@ def _reference(q, k, v, offs, *, sm_scale, causal, window=None):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, offs, causal, sm_scale, block_q, block_k, use_pallas,
-           interpret, window=None):
-    out, _ = _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k,
-                        use_pallas, interpret, window)[0], None
-    return out
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
+           use_pallas, interpret, window=None, static_offs=None):
+    """q (B, H, Sq, D); k, v (B, KVH, Skv, D): unexpanded for the kernels,
+    which read kv head `h // (H // KVH)`; the reference is handed them
+    expanded (KVH = H), as it always was. `fwd_blocks`, `bwd_blocks`:
+    (block_q, block_k) of the forward kernel and of dq / dkv."""
+    return _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks,
+                      bwd_blocks, use_pallas, interpret, window,
+                      static_offs)[0]
 
 
-def _flash_fwd(q, k, v, offs, causal, sm_scale, block_q, block_k,
-               use_pallas, interpret, window=None):
+def _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
+               use_pallas, interpret, window=None, static_offs=None):
     if use_pallas:
         out, lse = _fwd_impl(q, k, v, offs, sm_scale=sm_scale,
-                             block_q=block_q, block_k=block_k,
+                             block_q=fwd_blocks[0], block_k=fwd_blocks[1],
                              causal=causal, interpret=interpret,
-                             window=window)
+                             window=window, static_offs=static_offs)
     else:
         out, lse = _reference(q, k, v, offs, sm_scale=sm_scale,
                               causal=causal, window=window)
     return out, (q, k, v, offs, out, lse)
 
 
-def _flash_fwd_rule(q, k, v, offs, causal, sm_scale, block_q, block_k,
-                    use_pallas, interpret, window=None):
-    out, res = _flash_fwd(q, k, v, offs, causal, sm_scale, block_q,
-                          block_k, use_pallas, interpret, window)
-    return out, res
-
-
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, use_pallas,
-                    interpret, window, res, g):
+def _flash_bwd_rule(causal, sm_scale, fwd_blocks, bwd_blocks, use_pallas,
+                    interpret, window, static_offs, res, g):
     if window is not None:
         raise NotImplementedError(
             "flash_attention: the backward pass is not written for a "
             "window (the dq and dkv kernels mask causally only)")
     q, k, v, offs, out, lse = res
     if use_pallas:
-        dq, dk, dv = _bwd_impl(q, k, v, g, out, lse, offs,
-                               sm_scale=sm_scale, block_q=block_q,
-                               block_k=block_k, causal=causal,
-                               interpret=interpret)
+        # dq and dkv take a kv head a query head; the transpose of that
+        # expansion is the sum over a kv head's group.
+        H, KVH = q.shape[1], k.shape[1]
+        dq, dk, dv = _bwd_impl(q, _expand_kv(k, H), _expand_kv(v, H), g,
+                               out, lse, offs, sm_scale=sm_scale,
+                               block_q=bwd_blocks[0], block_k=bwd_blocks[1],
+                               causal=causal, interpret=interpret)
+        if KVH != H:
+            dk, dv = (x.reshape(x.shape[0], KVH, H // KVH, *x.shape[2:])
+                      .sum(axis=2) for x in (dk, dv))
     else:
         def f(q, k, v):
             return _reference(q, k, v, offs, sm_scale=sm_scale,
@@ -491,7 +614,7 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, use_pallas,
     return dq, dk, dv, jnp.zeros_like(offs)
 
 
-_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash.defvjp(_flash_fwd, _flash_bwd_rule)
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -518,6 +641,30 @@ def tileable(sq: int, skv: int, d: int, block_q: int, block_k: int
     return 0, 0
 
 
+# The backward kernels' blocks, and the forward's until it chose its own.
+_BWD_BLOCKS = (256, 512)
+
+# (Sq, Skv, D, window) -> the forward's (block_q, block_k) where a chip run
+# read another pair faster than `_fwd_blocks`' rule (chip_flash_table.py,
+# bf16 on a v5e; my chip runs, PR 33). 8,192 causal positions at 32 / 4
+# heads of 128: 1024 x 1024 4.62 ms against the rule's 1024 x 512 4.68.
+_FWD_MEASURED_BLOCKS = {(8192, 8192, 128, None): (1024, 1024)}
+
+
+def _fwd_blocks(sq: int, skv: int, d: int, window: Optional[int]
+                ) -> Tuple[int, int]:
+    """The forward kernel's (block_q, block_k) targets from what a call
+    shows. The rule: q blocks of 1,024 rows against 512 keys, a step's
+    row statistics paid once for more rows (4,096 causal positions at
+    32 / 8 heads of 128: 1.39 ms, 512 x 512 1.40, the backward's 256 x
+    512 1.89, 512 x 2048 1.81); 512 rows under a window, where a taller
+    block only adds masked pairs at its far edge (8,192 positions, a
+    window of 1,024: 2.12 ms against 2.38). `tileable` cuts either to
+    what divides the call's lengths."""
+    return _FWD_MEASURED_BLOCKS.get(
+        (sq, skv, d, window), (1024, 512) if window is None else (512, 512))
+
+
 def _expand_kv(x: jax.Array, n_heads: int) -> jax.Array:
     kvh = x.shape[1]
     if kvh == n_heads:
@@ -528,9 +675,14 @@ def _expand_kv(x: jax.Array, n_heads: int) -> jax.Array:
 _XLA_CROSSOVER_SKV = 2048
 
 
+def _static_offset(x) -> Optional[int]:
+    return int(x) if isinstance(x, (int, np.integer)) else None
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 256, block_k: int = 512,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     q_offset=0, kv_offset=0,
                     window: Optional[int] = None,
                     interpret: Optional[bool] = None,
@@ -542,10 +694,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Offsets are *global token positions* of element 0 of the q / kv
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     With `window`, a query also sees no key more than `window - 1`
-    positions behind it: (q_offset + i) - (kv_offset + j) < window.
-    Forward only; kv blocks wholly behind the window are given no grid
-    step.
+    positions behind it: (q_offset + i) - (kv_offset + j) < window
+    (forward only). The forward kernel's grid follows the mask: with
+    offsets given as Python ints a block that holds no live pair gets
+    neither a step nor a fetch (`grid_steps`, counted in `FLASH_GRID`).
     Returns (B, Sq, H, D).
+
+    `block_q`, `block_k`: None lets the forward choose its blocks from
+    the call's shapes (`_fwd_blocks`) and the backward keep 256 x 512;
+    a number is a target for both.
 
     `interpret=None` compiles the kernels on a TPU and takes the
     reference anywhere else; `True` runs them in the Pallas interpreter
@@ -560,15 +717,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError("flash_attention: a window needs causal=True "
                          f"and at least one position, got {window!r}")
 
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = _expand_kv(jnp.swapaxes(k, 1, 2), H)
-    vt = _expand_kv(jnp.swapaxes(v, 1, 2), H)
-
-    bq, bk = tileable(Sq, Skv, D, block_q, block_k)
+    bwd = tileable(Sq, Skv, D, block_q or _BWD_BLOCKS[0],
+                   block_k or _BWD_BLOCKS[1])
+    want = _fwd_blocks(Sq, Skv, D, window)
+    fwd = tileable(Sq, Skv, D, block_q or want[0], block_k or want[1])
     compiled = on_tpu() if interpret is None else not interpret
     if force_reference:
         path = "reference_forced"
-    elif not bq:
+    elif not bwd[0]:
         path = "reference_untileable"
     elif force_pallas or interpret:
         path = "pallas" if compiled else "pallas_interpret"
@@ -584,9 +740,24 @@ def flash_attention(q, k, v, *, causal: bool = True,
     else:
         path = "pallas"
     DISPATCH_COUNTS[path] += 1
+    use_pallas = path.startswith("pallas")
+    offsets = (_static_offset(q_offset), _static_offset(kv_offset))
+    static_offs = None if None in offsets else offsets
+    if use_pallas:
+        for name, n in grid_steps(
+                Sq, Skv, *fwd, causal=causal, window=window,
+                q_offset=offsets[0], kv_offset=offsets[1]).items():
+            FLASH_GRID[name] += B * H * n
+
+    # The kernels read a kv head a group of query heads; the reference
+    # takes K and V expanded.
+    kv_heads = k.shape[2] if use_pallas else H
+    qt = jnp.swapaxes(q, 1, 2)
+    kt = _expand_kv(jnp.swapaxes(k, 1, 2), kv_heads)
+    vt = _expand_kv(jnp.swapaxes(v, 1, 2), kv_heads)
     offs = jnp.asarray([[q_offset, kv_offset]], jnp.float32)
-    out = _flash(qt, kt, vt, offs, causal, sm_scale, bq, bk,
-                 path.startswith("pallas"), not compiled, window)
+    out = _flash(qt, kt, vt, offs, causal, sm_scale, fwd, bwd, use_pallas,
+                 not compiled, window, static_offs)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -210,8 +210,10 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
         rep = H // kt.shape[1]
         kt = jnp.repeat(kt, rep, axis=1)
         vt = jnp.repeat(vt, rep, axis=1)
-    # Same blocks and the same decision as flash_attention: the kernels
-    # on a TPU, the reference (never the interpreter) anywhere else.
+    # The same decision as flash_attention: the kernels on a TPU, the
+    # reference (never the interpreter) anywhere else. A step's offsets
+    # are traced, so its forward walks a run of kv blocks a q block
+    # (`flash_attention._fwd_grid`), here in the backward's blocks.
     bq, bk = tileable(S, S, D, block_q, block_k)
     tpu = on_tpu()
     use_pallas = bool(bq) and tpu

@@ -507,23 +507,35 @@ def test_windowed_kernel_in_the_interpreter_against_the_reference(
     (64, 96, 32)])
 def test_windowed_kernel_walks_the_blocks_its_window_reaches(
         window, q_offset, kv_offset):
-    """512 keys in blocks of 32 x 64: the grid's kv axis is as long as
-    the blocks a q block's window can reach (`window` - 1 + 32 keys: 3,
-    3, 4 of the 8, all 8 for a window of 511), wherever the offsets put
-    the window's first block, and a step past kv's end adds nothing."""
+    """512 keys in blocks of 32 x 64. With offsets the trace cannot see,
+    the grid's kv axis is as long as the blocks a q block's window can
+    reach (`window` - 1 + 32 keys: 3, 3, 4 of the 8, all 8 for a window
+    of 511), wherever the offsets put the window's first block, and a
+    step past kv's end adds nothing. With offsets it can see, the grid is
+    the blocks that hold a live pair and no other."""
     sq = 512 - q_offset
     q, k, v = _qkv(sq, 512, seed=window + q_offset)
-    kw = dict(causal=True, window=window, q_offset=q_offset,
-              kv_offset=kv_offset, block_q=32, block_k=64)
-    got = fa_fn(q, k, v, interpret=True, **kw)
-    want = fa_fn(q, k, v, force_reference=True, **kw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-6)
-    jaxpr = jax.make_jaxpr(
-        lambda q, k, v: fa_fn(q, k, v, interpret=True, **kw))(q, k, v)
-    grids = [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
-             if e.primitive.name == "pallas_call"]
-    assert grids == [(2, 4, sq // 32, min(8, (window + 29) // 64 + 2))]
+    kw = dict(causal=True, window=window, block_q=32, block_k=64)
+    want = fa_fn(q, k, v, force_reference=True, q_offset=q_offset,
+                 kv_offset=kv_offset, **kw)
+    live = sum(
+        any(0 <= (q_offset + i) - (kv_offset + j) < window
+            for i in range(qi * 32, qi * 32 + 32)
+            for j in (ki * 64, ki * 64 + 63))
+        for qi in range(sq // 32) for ki in range(8))
+    for offsets, grid in (
+            (dict(q_offset=jnp.int32(q_offset),
+                  kv_offset=jnp.int32(kv_offset)),
+             (2, 4, sq // 32, min(8, (window + 29) // 64 + 2))),
+            (dict(q_offset=q_offset, kv_offset=kv_offset), (2, 4, live))):
+        got = fa_fn(q, k, v, interpret=True, **offsets, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: fa_fn(
+            q, k, v, interpret=True, **offsets, **kw))(q, k, v)
+        grids = [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert grids == [grid]
 
 
 def _eqns(jaxpr):

@@ -123,6 +123,73 @@ def test_untileable_shape_is_counted_not_refused(topo):
     assert "tpu_custom_call" not in text
 
 
+# (B, S, H, KVH, window, trains): the flash calls of the benchmark's cells:
+# docqa's one-row tile, a chip's share of the train step's batch, and
+# mellum's tile on a global and on a window layer.
+CELL_FLASH = {
+    "mistral7b-docqa-lone": (1, 4096, 32, 8, None, False),
+    "internlm2-1b8-train-fsdp4": (2, 4096, 16, 8, None, True),
+    "mellum2-repoctx-lone.global": (1, 8192, 32, 4, None, False),
+    "mellum2-repoctx-lone.window": (1, 8192, 32, 4, 1024, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_FLASH))
+def test_flash_compiles_at_the_cells_shapes(topo, cell):
+    """The forward in the blocks it chooses itself, K and V unexpanded,
+    its grid the table of live pairs (`FLASH_GRID`: no step without a
+    live pair); where the cell trains, dq and dkv behind it in the
+    blocks they always had."""
+    B, S, H, KVH, window, trains = CELL_FLASH[cell]
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((B, S, H, 128), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((B, S, KVH, 128), jnp.bfloat16, sharding=one)
+    before = dict(fa.FLASH_GRID)
+    if trains:
+        lowered = jax.jit(_fwd_bwd).lower(q, k, k)
+    else:
+        lowered = jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, interpret=False)).lower(
+                q, k, k)
+    grid = {n: c - before.get(n, 0) for n, c in fa.FLASH_GRID.items()}
+    assert grid["steps"] == grid["live_steps"] > grid["masked_steps"] > 0
+    assert not grid.get("traced_steps")
+    jaxpr_kernels = [e.params["metadata"]["kernel"] for e in _eqns(
+        jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, interpret=False))(
+                q, k, k).jaxpr) if e.primitive.name == "pallas_call"]
+    assert jaxpr_kernels == ["flash_fwd"]       # one call a forward
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (3 if trains else 1)
+    for kernel in ("flash_fwd",) + (("flash_dq", "flash_dkv")
+                                    if trains else ()):
+        assert f'"kernel":"{kernel}"' in text.replace(" ", "")
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def test_train_step_holds_four_flash_kernels(topo, as_on_the_chip):
+    """The cell's whole step (`internlm2-1b8-train-fsdp4`: 24 scanned
+    layers under full remat, fsdp=4, two sequences of 4,096 a chip): the
+    forward, remat's second forward, dq and dkv, each once in the
+    compiled text, and nothing expanded K and V for the forward."""
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "internlm2-1b8-train-fsdp4.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("internlm2-1.8b", sizes)
+    text = _aot_compile_step(topo, cfg, 4, batch=8, seq=4096).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    kernels = re.findall(r'"kernel":"(\w+)"', text.replace(" ", "")
+                         .replace("\n", ""))
+    assert sorted(set(kernels)) == ["flash_dkv", "flash_dq", "flash_fwd"]
+
+
 def _benchmark_config(name, sizes):
     """A configuration of benchmarks/configs/ at a cell's sizes."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
@@ -586,14 +653,15 @@ def test_trinity_prefill_programs_fit(serve_trinity, as_on_the_chip,
 def test_windowed_flash_forward_compiles(topo, S, window):
     """The forward kernel with a window of 2,048 at trinity's long
     prefill and of 1,024 at mellum's admission tile (32 Q / 4 KV heads
-    of 128), in the blocks of 512 queries `periodic._flash` asks for: the
-    kv axis of the grid is the blocks a window reaches, found from the
-    prefetched offsets."""
+    of 128), as `periodic._flash` calls it, and with offsets the trace
+    cannot see: the kv axis of the grid is then the blocks a window
+    reaches, found from the prefetched offsets."""
     q, k = _qkv(topo, S, S, 32, 4, 128)
-    text = jax.jit(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=True, window=window, block_q=512,
-        interpret=False)).lower(q, k, k).compile().as_text()
-    assert "tpu_custom_call" in text
+    for offset in (0, jnp.int32(0)):
+        text = jax.jit(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, q_offset=offset,
+            interpret=False)).lower(q, k, k).compile().as_text()
+        assert "tpu_custom_call" in text
 
 
 # -- the period stack's other layer (benchmarks/cells/mellum2-repoctx-lone) --
