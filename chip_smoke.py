@@ -417,6 +417,59 @@ def phase_kernels(rep: Report, sz: Sizes) -> None:
     rep.check("fwd_rel_err", _rel_err(out, ref_o), FLASH_FWD_TOL)
     for name, a, b in zip(("dq", "dk", "dv"), grads, ref_g):
         rep.check(f"bwd_{name}_rel_err", _rel_err(a, b), FLASH_BWD_TOL)
+    _latent_kernels(rep, sz)
+
+
+def _latent_kernels(rep: Report, sz: Sizes) -> None:
+    """The two kernels as latent attention calls them (models/latent.py),
+    one shot each against their plain forms in float32: the flash forward
+    with keys 192 wide over values 128 wide, and the decode kernel with
+    one array for keys and values (rows of 512 + 64 in whole lanes under
+    128 query heads; one slot holds no row, one ends inside a block)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    S, H, dk, dv = sz.flash[1], 16, 192, 128
+    ks = jax.random.split(jax.random.key(sz.seed + 1), 5)
+    q = jax.random.normal(ks[0], (1, S, H, dk), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, S, H, dk), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, S, H, dv), jnp.bfloat16)
+    scale = 1.0 / math.sqrt(dk)
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, sm_scale=scale, force_pallas=True))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, sm_scale=scale, force_reference=True))(
+                *(x.astype(jnp.float32) for x in (q, k, v)))
+    rep.check("flash_fwd_192_over_128_rel_err", _rel_err(out, ref),
+              FLASH_FWD_TOL)
+
+    L, B, rows, C, kvr, heads = 2, 4, sz.flash[1], 640, 512, 128
+    c_all = jax.random.normal(ks[3], (L, B, rows, C), jnp.bfloat16)
+    qd = jax.random.normal(ks[4], (B, 1, heads, C), jnp.bfloat16)
+    n = jnp.asarray([rows, 0, rows // 2 + 3, 1], jnp.int32)
+    rep.require("latent_rows_tile", da.usable(c_all, C, kvr))
+    got = jax.jit(lambda q, c, n: da.decode_attention(
+        q, c, None, jnp.int32(1), n, sm_scale=scale, v_width=kvr))(
+            qd, c_all, n).reshape(B, heads, kvr)
+
+    def plain(q, c, n):
+        held = c[1].astype(jnp.float32)
+        s = jnp.einsum("bhc,bsc->bhs", q[:, 0].astype(jnp.float32),
+                       held) * scale
+        seen = jnp.arange(rows)[None, None, :] < n[:, None, None]
+        p = jnp.where(seen, jax.nn.softmax(
+            jnp.where(seen, s, -jnp.inf), axis=-1), 0.0)
+        return jnp.einsum("bhs,bsc->bhc", p, held[..., :kvr])
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(plain)(qd, c_all, n)
+    rep.require("latent_decode_empty_slot_is_zero",
+                not bool(jnp.any(got[1].astype(jnp.float32))))
+    rep.check("latent_decode_rel_err", _rel_err(got, want), FLASH_FWD_TOL)
 
 
 # -- phase 2: trainer ------------------------------------------------------

@@ -59,6 +59,25 @@ def tiny_mellum_test(vocab: int = 256) -> TransformerConfig:
                                   "rope_theta": 500000}})
 
 
+def tiny_pangu_test(vocab: int = 256, router_experts: int = 16,
+                    held: int = 4, first: int = 4) -> TransformerConfig:
+    """The latent-attention stack of models/latent.py at a unit-test
+    size: one dense layer, then two layers that hold experts [first,
+    first + held) of the `router_experts` their sigmoid router scores,
+    beside a shared one; latent attention with ranks of 32 and heads of
+    16 + 8 (no position, rotary) over values of 16. For the tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=128, max_seq_len=128, rope_theta=25600000.0, dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False, tie_embeddings=False,
+        arch="pangu_ultra_moe", n_dense_layers=1, q_lora_rank=32,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, moe_experts=held, moe_router_experts=router_experts,
+        moe_first_expert=first, moe_top_k=2, moe_d_ff=32,
+        moe_shared_experts=1, score_func="sigmoid", route_norm=True,
+        route_scale=2.5)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -113,6 +132,7 @@ NAMED = {
     "tiny_moe": tiny_moe_test,
     "tiny_afmoe": tiny_afmoe_test,
     "tiny_mellum": tiny_mellum_test,
+    "tiny_pangu": tiny_pangu_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

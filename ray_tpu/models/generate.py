@@ -120,21 +120,31 @@ class KVCache(NamedTuple):
     A period stack (`models/periodic.py`) keeps two kinds of state: k/v
     hold its global layers, (Lg, B, S_max, KVH, Dh), and kw/vw its
     window layers, (Lw, B, min(window, S_max), KVH, Dh), a ring written
-    at `position mod rows`. Every other model leaves kw/vw None."""
+    at `position mod rows`. Every other model leaves kw/vw None.
 
-    k: jax.Array
-    v: jax.Array
+    A latent stack (`models/latent.py`) keeps neither keys nor values a
+    head: `c`, (L, B, S_max, C), holds a token's latent vector and its
+    rotary key, every head's keys and values are products of it, and k
+    and v are None. Every other model leaves c None."""
+
+    k: Optional[jax.Array]
+    v: Optional[jax.Array]
     seq_lens: jax.Array
     kw: Optional[jax.Array] = None
     vw: Optional[jax.Array] = None
+    c: Optional[jax.Array] = None
+
+    @property
+    def _rows(self) -> jax.Array:
+        return self.c if self.k is None else self.k
 
     @property
     def max_seq_len(self) -> int:
-        return self.k.shape[2]
+        return self._rows.shape[2]
 
     @property
     def num_slots(self) -> int:
-        return self.k.shape[1]
+        return self._rows.shape[1]
 
 
 def init_kv_cache(cfg: TransformerConfig, num_slots: int,
@@ -430,7 +440,10 @@ def routed_layers(cfg: TransformerConfig) -> int:
     admission tiles (`prefill_sample_batch`) of `cfg` count: with any,
     either returns, after its other results, int32 (3,) = [experts that
     held a row, summed over steps (one for a tile) and those layers; rows
-    routed; the fullest expert's rows, summed over steps and layers]."""
+    routed; the fullest expert's rows, summed over steps and layers]; a
+    stack whose layers hold a share of their experts counts those three
+    over the experts held and adds the pairs routed over all of them
+    (its `routing_stats(cfg)` says 4)."""
     return stack(cfg).routed_layers(cfg)
 
 
@@ -467,7 +480,8 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
         return (cache, tok, routed), (tok, lp)
 
     subs = jax.random.split(key, num_steps)
-    routed = jnp.zeros((3,), jnp.int32) if routed_layers(cfg) else None
+    routed = jnp.zeros((getattr(st, "routing_stats", lambda _: 3)(cfg),),
+                       jnp.int32) if routed_layers(cfg) else None
     (cache, _, routed), (toks, lps) = lax.scan(
         body, (cache, tokens, routed), subs)
     return (cache, toks, lps) if routed is None \

@@ -110,7 +110,7 @@ def _gmm_tiling(rows: int, k: int, n: int):
     `lax.ragged_dot`'s 1.58 for 512 rows over 110 of 128 experts of
     2048 x 1024; 4.3 against 6.4 for an admission tile's 131,072 rows: my
     chip runs, PR 28)."""
-    if rows % 128 or k % 128 or n % 128 or k > 4096:
+    if rows % 128 or k % 128 or n % 128 or k > 8192:
         return None
     tm = 256 if rows % 256 == 0 and rows >= 4096 else 128
     want = _GMM_TILE / k
@@ -153,6 +153,14 @@ def grouped_dot(a: jax.Array, w: jax.Array, groups: jax.Array,
                           preferred_element_type=jnp.float32)
 
 
+def _grouped_swiglu(w: Dict[str, jax.Array], xs: jax.Array,
+                    groups: jax.Array) -> jax.Array:
+    """SwiGLU_g of rows xs sorted by group, a group's own matrices."""
+    h = jax.nn.silu(grouped_dot(xs, w["w_gate"], groups)) \
+        * grouped_dot(xs, w["w_up"], groups)
+    return grouped_dot(h.astype(xs.dtype), w["w_down"], groups)
+
+
 def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
                     weights: jax.Array, experts: jax.Array, n_experts: int,
                     first=0) -> Tuple[jax.Array, jax.Array]:
@@ -173,14 +181,96 @@ def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     groups = sizes if G == E else lax.dynamic_update_slice(
         jnp.zeros((G,), jnp.int32), sizes, (first,))
-    xs = x[order // K]                           # (T*K, D)
-
-    h = jax.nn.silu(grouped_dot(xs, w["w_gate"], groups)) \
-        * grouped_dot(xs, w["w_up"], groups)
-    ys = grouped_dot(h.astype(x.dtype), w["w_down"], groups)
+    ys = _grouped_swiglu(w, x[order // K], groups)    # (T*K, D)
     ys = ys[jnp.argsort(order)].reshape(T, K, -1)     # back in order
     out = jnp.sum(ys * weights[..., None], axis=1)
     return out, sizes
+
+
+def held_pass_rows(pairs: int, held: int, routed: int) -> int:
+    """Rows a pass of `held_experts` takes of `pairs` token-expert pairs
+    when `held` of the `routed` experts are here: twice the share a
+    uniform router would keep, in whole row tiles of the grouped kernel,
+    and never more than there are pairs."""
+    want = -(-2 * pairs * held // routed)
+    return min(-(-max(want, 1) // 128) * 128, -(-pairs // 128) * 128)
+
+
+def held_experts(w: Dict[str, jax.Array], x: jax.Array, weights: jax.Array,
+                 experts: jax.Array, n_held: int, first_held: int,
+                 n_routed: int, first=0) -> Tuple[jax.Array, jax.Array]:
+    """`grouped_experts` for a layer that holds experts [first_held,
+    first_held + n_held) of the `n_routed` its router chose among:
+    `experts` (T, K) name any of them, the pairs that chose a held one
+    are kept, and the result is the part of the sum the held experts
+    give, float32 (T, D), beside the rows each held expert took, int32
+    (n_held,). The rest of the sum is other chips'; nothing stands in
+    for it here.
+
+    Kept pairs come first in the sort, by expert, and are worked off a
+    pass of `held_pass_rows` at a time: a pass gathers its rows of x,
+    multiplies them by groups, weights them and adds each to its token's
+    row: one product of a 0 / 1 matrix (token t owns row r of the pass)
+    against the weighted rows, in x's dtype or, float32 over bf16
+    weights, as two bf16 terms (a scatter-add of 8,192 rows of 7,680 took
+    22 ms a layer on a v5e where this takes 7: PERF.md, PR 34). The
+    passes are a loop the
+    device counts, as many as the kept pairs need: none where no pair
+    chose a held expert (no product runs, the result is zeros), one at a
+    uniform router's load, more where the load leans on this share. No
+    kept pair is dropped and no absent pair is gathered or multiplied."""
+    T, K = experts.shape
+    E, G, P = n_held, w["w_gate"].shape[0], T * K
+    C = held_pass_rows(P, E, n_routed)
+    local = experts.reshape(P) - first_held
+    key = jnp.where((local >= 0) & (local < E), local, E)   # absent: last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, kept = ends - sizes, ends[-1]
+    room = -(-P // C) * C - P           # the last pass may reach past P
+    token = jnp.pad(order // K, (0, room))
+    weight = jnp.pad(weights.reshape(P)[order], (0, room))
+    # Float32 rows against bf16 weights keep to bf16 products here too.
+    terms = jnp.bfloat16 if _split(x, w["w_down"]) else x.dtype
+
+    def one_pass(i, out):
+        lo = i * C
+        tok = lax.dynamic_slice_in_dim(token, lo, C)
+        here = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
+        groups = here if G == E else lax.dynamic_update_slice(
+            jnp.zeros((G,), jnp.int32), here, (first,))
+        ys = _grouped_swiglu(w, x[tok], groups)         # (C, D)
+        # A row past the kept pairs belongs to no group: the kernel
+        # never wrote it, and what lies there must not reach the sum.
+        live = lo + jnp.arange(C) < kept
+        ys = jnp.where(live[:, None], ys * lax.dynamic_slice_in_dim(
+            weight, lo, C)[:, None], 0.0)
+        owns = ((jnp.arange(T)[:, None] == tok[None, :])
+                & live[None, :]).astype(terms)          # (T, C)
+        return out + _own_rows(owns, ys.astype(x.dtype))
+
+    out = lax.fori_loop(0, (kept + C - 1) // C, one_pass,
+                        jnp.zeros((T, w["w_down"].shape[-1]), jnp.float32))
+    return out, sizes
+
+
+def _own_rows(owns: jax.Array, ys: jax.Array) -> jax.Array:
+    """owns (T, C) of 0 and 1 @ ys (C, D) -> float32 (T, D): each token's
+    sum of the rows it owns. bf16 ys in one product, float32 ys as two
+    bf16 terms (0 and 1 are exact, so the sum carries 2^-17 of ys)."""
+    if ys.dtype == jnp.float32 and owns.dtype == jnp.bfloat16:
+        two = jnp.einsum("tc,kcd->ktd", owns, bf16_terms(ys),
+                         preferred_element_type=jnp.float32)
+        return two[0] + two[1]
+    return jnp.dot(owns, ys, precision=_exact(ys),
+                   preferred_element_type=jnp.float32)
+
+
+def routing_stats(cfg) -> int:
+    """Entries of `routed_ffn`'s stats: three, and the pairs routed
+    where the layer holds a share of the experts its router scores."""
+    return 3 if cfg.router_experts == cfg.moe_experts else 4
 
 
 def routed_ffn(cfg, lp: Dict[str, jax.Array], m: jax.Array, dtype,
@@ -189,13 +279,25 @@ def routed_ffn(cfg, lp: Dict[str, jax.Array], m: jax.Array, dtype,
     """The routed experts of one layer on m (T, D) float32: (out (T, D)
     float32, stats int32 (3,) = [experts holding a row, rows, rows of
     the fullest expert], experts (T, K)). The expert matrices are `lp`'s
-    own, or `expert_weights` from group `first` on (`grouped_experts`)."""
+    own, or `expert_weights` from group `first` on (`grouped_experts`).
+
+    Where the layer holds a share of the experts its router scores
+    (`cfg.router_experts` > `cfg.moe_experts`: `held_experts`) the three
+    count over the experts held and the pairs kept, and a fourth entry
+    has the pairs routed, kept or not."""
     with jax.named_scope("moe_router"):
         weights, experts = route(cfg, lp, m)
+    w = lp if expert_weights is None else expert_weights
+    share = routing_stats(cfg) == 4
     with jax.named_scope("moe_experts"):
-        out, sizes = grouped_experts(
-            lp if expert_weights is None else expert_weights,
-            m.astype(dtype), weights, experts, cfg.moe_experts, first)
-    stats = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes),
-                       jnp.max(sizes)]).astype(jnp.int32)
-    return out, stats, experts
+        if not share:
+            out, sizes = grouped_experts(w, m.astype(dtype), weights,
+                                         experts, cfg.moe_experts, first)
+        else:
+            out, sizes = held_experts(
+                w, m.astype(dtype), weights, experts, cfg.moe_experts,
+                cfg.moe_first_expert, cfg.router_experts, first)
+    stats = [jnp.sum(sizes > 0), jnp.sum(sizes), jnp.max(sizes)]
+    if share:
+        stats.append(experts.size)
+    return out, jnp.stack(stats).astype(jnp.int32), experts

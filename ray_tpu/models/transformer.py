@@ -43,15 +43,19 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #            forward_free(cfg, params, tokens (W, S))
 #              -> (final-normed hidden states, experts chosen or None)
 #            decode(cfg, params, cache, tokens (B,), live (B,) bool or None)
-#              -> (cache', logits (B, V), routing stats (3,) or None)
+#              -> (cache', logits (B, V), routing stats (3,) or None; (4,)
+#                  where the layer holds a share of its experts: the
+#                  pairs routed over the router's whole width behind)
 #            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
 #            routed_layers(cfg): the layers the stats count over
+#            routing_stats(cfg): how many entries the stats have, where
+#              that is not three
 # and, where it has them (`offered`): `suffix` (the walk behind a shared
 # prefix), `param_logical_axes` (sharding rules), `forward_train` (the
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one
 # says why in its `MISSING`.
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
-                          "mellum": "periodic"}
+                          "mellum": "periodic", "pangu_ultra_moe": "latent"}
 
 
 @dataclass(frozen=True)
@@ -150,7 +154,9 @@ class TransformerConfig:
     # rotary on window layers only, a scaled embedding). Served only:
     # forward / loss_fn raise for it. "mellum": the same stack with
     # another layer (PERIOD_FORMS: two norms, no gate, rotary on both
-    # kinds of layer). STACKS above holds the names.
+    # kinds of layer). "pangu_ultra_moe": the latent-attention stack of
+    # models/latent.py (the fields at the end). STACKS above holds the
+    # names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length; its last layer is global
@@ -166,6 +172,21 @@ class TransformerConfig:
     # "rope_theta": ..}}); None = `rope_theta`, unscaled, for every
     # section. Kept as sorted tuples of pairs (hashable): `rope_section`.
     rope_parameters: Any = None
+    # Latent attention (models/latent.py), under the published keys: the
+    # ranks the query and the keys-and-values are projected down to, and
+    # a head's three widths (the part of q and k with no position, the
+    # rotary part, which every head's key shares, and the values).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # An expert layer that holds a share of its experts: the router
+    # scores `moe_router_experts` of them (0 = `moe_experts`, all held)
+    # and the layer holds [moe_first_expert, moe_first_expert +
+    # moe_experts), computing the part of the sum those give.
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -188,6 +209,10 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_experts > 0
+
+    @property
+    def router_experts(self) -> int:
+        return self.moe_router_experts or self.moe_experts
 
     @property
     def expert_d_ff(self) -> int:
@@ -247,17 +272,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 
 
 def rope_tables(cfg: TransformerConfig, seq_len: int,
-                section: Optional[str] = None
+                section: Optional[str] = None, dim: int = 0
                 ) -> Tuple[jax.Array, jax.Array]:
-    """(sin, cos), each (seq_len, head_dim / 2), of `cfg.rope_section(
-    section)`. "default": pos x theta^(-2i/d). "yarn" (arXiv:2309.00071,
+    """(sin, cos), each (seq_len, dim / 2), of `cfg.rope_section(
+    section)`; `dim`: the width rotated (0 = `cfg.head_dim`). "default": pos x theta^(-2i/d). "yarn" (arXiv:2309.00071,
     as transformers' `_compute_yarn_parameters` has it): the frequencies
     that turn more than `beta_fast` times over the original context are
     kept, those that turn fewer than `beta_slow` times are divided by
     `factor`, a linear ramp between; sin and cos are multiplied by
     `attention_factor` (q and k both carry it)."""
     rope = cfg.rope_section(section)
-    dim, half = cfg.head_dim, cfg.head_dim // 2
+    dim = dim or cfg.head_dim
+    half = dim // 2
     theta = float(rope["rope_theta"])
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     pos = jnp.arange(seq_len, dtype=jnp.float32)

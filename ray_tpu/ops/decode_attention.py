@@ -29,6 +29,12 @@ as two bf16 terms stacked under each other and each cached term in a
 product of its own, float32 accumulation: the four products
 `periodic._attend_terms` makes.
 
+One array for keys and values (`models/latent.py`: a row is a latent
+vector and a rotary key, the values are the row's first `v_width`
+columns): `v_all` is None, a block is fetched once and serves both
+products, and the output is `v_width` wide. The row's width then need
+not fill whole lanes (576 = 512 + 64); the values' does.
+
 `usable()` says where the kernel runs: on a TPU, where the rows tile
 into a block and the head size fills the lanes, and outside any mesh
 with a used axis (a pallas call is opaque to the partitioner). Anywhere
@@ -54,14 +60,24 @@ from .flash_attention import DISPATCH_COUNTS, NEG_INF, _LANES
 # it (PERF.md, PR 31): wider blocks fetch more rows past a slot's last,
 # narrower ones pay more grid steps.
 _ROWS = (256, 128)
+# One array for keys and values under many query heads (latent attention:
+# 128 heads over rows of 640 lanes): a block's products are 0.3 MFLOP a
+# row, so a grid step's fixed cost shows beside them and wider blocks pay.
+# 32 slots x 10,240 holding 4,200-10,000 rows, five layers, ms on a v5e:
+# 256 rows 4.51, 512 3.29, 1024 2.72, 2048 2.60 (my chip run, PR 34).
+_ROWS_ONE_ARRAY = (1024, 512, 256, 128)
 
 
-def block_rows(S: int, Dh: int) -> int:
+def block_rows(S: int, Dh: int, v_width: Optional[int] = None) -> int:
     """Rows a block, 0 where the shapes do not tile: the head size fills
-    whole lanes and a block's rows divide S."""
-    if Dh % _LANES:
+    whole lanes and a block's rows divide S. `v_width`: the values are
+    the first `v_width` columns of the keys' rows (one array); they fill
+    whole lanes, and the rest of a row half of one at least."""
+    if (Dh % _LANES if v_width is None
+            else v_width % _LANES or (Dh - v_width) % (_LANES // 2)):
         return 0
-    return next((r for r in _ROWS if S % r == 0), 0)
+    return next((r for r in (_ROWS if v_width is None else _ROWS_ONE_ARRAY)
+                 if S % r == 0), 0)
 
 
 def terms_of(q_dtype, cache_dtype) -> int:
@@ -71,7 +87,8 @@ def terms_of(q_dtype, cache_dtype) -> int:
                  and cache_dtype == jnp.bfloat16) else 1
 
 
-def usable(k_all: jax.Array, Dh: int) -> bool:
+def usable(k_all: jax.Array, Dh: int, v_width: Optional[int] = None
+           ) -> bool:
     """Whether `decode_attention` runs for this cache here: read from the
     backend, the cache's shape and the ambient mesh."""
     from .flash_attention import on_tpu   # asked at the call, as moe.py does
@@ -80,7 +97,7 @@ def usable(k_all: jax.Array, Dh: int) -> bool:
     sharded = any(n > 1 for n in dict(getattr(mesh, "shape", None)
                                       or {}).values())
     return (on_tpu() and not sharded
-            and block_rows(k_all.shape[2], Dh) > 0)
+            and block_rows(k_all.shape[2], Dh, v_width) > 0)
 
 
 def _bf16_terms(x, in_kernel: bool):
@@ -97,9 +114,11 @@ def _bf16_terms(x, in_kernel: bool):
 
 
 def _kernel(l_ref, n_ref, slot_ref, block_ref, q_ref, *refs,
-            terms, H, G, KVH, rows, sm_scale):
-    k_refs, v_refs = refs[:terms], refs[terms:2 * terms]
-    o_ref, acc_ref, m_ref, sum_ref, bias_ref = refs[2 * terms:]
+            terms, H, G, KVH, rows, sm_scale, v_width):
+    k_refs = refs[:terms]
+    v_refs = k_refs if v_width else refs[terms:2 * terms]
+    o_ref, acc_ref, m_ref, sum_ref, bias_ref = refs[
+        (1 if v_width else 2) * terms:]
     t = pl.program_id(0)
     j = block_ref[t]
     n = n_ref[slot_ref[t]]
@@ -144,7 +163,7 @@ def _kernel(l_ref, n_ref, slot_ref, block_ref, q_ref, *refs,
             p = p.astype(v_refs[0].dtype)
         pv = 0.0
         for v_ref in v_refs:
-            v = v_ref[...]
+            v = v_ref[:, :v_width] if v_width else v_ref[...]
             if edge:
                 # 0 x NaN is NaN: a row past `n` must not reach the sum.
                 vrow = lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -184,13 +203,19 @@ def _work_list(n_rows: jax.Array, rows: int, num_blocks: int):
     return jnp.maximum(ends[-1], 1), slot, block.astype(jnp.int32)
 
 
-def decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
-                     l: jax.Array, n_rows: jax.Array, *,
+def decode_attention(q: jax.Array, k_all: jax.Array,
+                     v_all: Optional[jax.Array], l: jax.Array,
+                     n_rows: jax.Array, *,
                      interpret: Optional[bool] = None,
-                     rows: Optional[int] = None) -> jax.Array:
+                     rows: Optional[int] = None,
+                     sm_scale: Optional[float] = None,
+                     v_width: Optional[int] = None) -> jax.Array:
     """q (B, KVH, G, Dh) against rows [0, n_rows[b]) of layer `l` of the
     cache `k_all`, `v_all` ((terms*L, B, S, KVH, Dh)) -> (B, 1, H*Dh), in
-    q's dtype. `n_rows` (B,) int32 in [0, S]: a slot holding no row
+    q's dtype. `v_all` None: the values are the first `v_width` columns
+    of `k_all`'s rows, read once for both products, and the result is
+    (B, 1, H*v_width). `sm_scale`: the scores' scale where it is not
+    1/sqrt(Dh). `n_rows` (B,) int32 in [0, S]: a slot holding no row
     reads nothing and returns zeros. The order the rows lie in does not
     matter (a ring is read as it lies). `rows`: rows a block, where
     `block_rows` is not to choose.
@@ -203,7 +228,11 @@ def decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
     H = KVH * G
     terms = terms_of(q.dtype, k_all.dtype)
     L = Lt // terms
-    rows = rows or block_rows(S, Dh)
+    if (v_all is None) != bool(v_width):
+        raise ValueError("decode_attention: `v_width` goes with one array "
+                         "for keys and values (v_all=None), and only then")
+    Dv = v_width or Dh
+    rows = rows or block_rows(S, Dh, v_width)
     if not rows or S % rows:
         raise ValueError(f"decode_attention: {S} rows of {KVH} x {Dh} do "
                          "not tile into blocks")
@@ -229,29 +258,30 @@ def decode_attention(q: jax.Array, k_all: jax.Array, v_all: jax.Array,
 
     kernel = functools.partial(
         _kernel, terms=terms, H=H, G=G, KVH=KVH, rows=rows,
-        sm_scale=1.0 / math.sqrt(Dh))
+        sm_scale=sm_scale or 1.0 / math.sqrt(Dh), v_width=v_width)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(steps,),
             in_specs=[pl.BlockSpec((None, R, Dh), own)]
-            + [cached(t) for t in range(terms)] * 2,
-            out_specs=pl.BlockSpec((None, H, Dh), own),
+            + [cached(t) for t in range(terms)] * (1 if v_width else 2),
+            out_specs=pl.BlockSpec((None, H, Dv), own),
             scratch_shapes=[
-                pltpu.VMEM((H, Dh), jnp.float32),        # acc
+                pltpu.VMEM((H, Dv), jnp.float32),        # acc
                 pltpu.VMEM((H, _LANES), jnp.float32),    # running max
                 pltpu.VMEM((H, _LANES), jnp.float32),    # running sum
                 pltpu.VMEM((R, N), jnp.float32),         # the heads' bias
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=bool(interpret),
         metadata={"kernel": "decode_attn"},
     )(jnp.reshape(l, (1,)).astype(jnp.int32), n_rows, slot, block, q2,
-      *[k_all.reshape(flat)] * terms, *[v_all.reshape(flat)] * terms)
+      *[k_all.reshape(flat)] * terms,
+      *([] if v_all is None else [v_all.reshape(flat)] * terms))
     # A slot that holds no row was given no step, and its block of the
     # output was never written.
     out = jnp.where((n_rows > 0)[:, None, None], out, jnp.zeros_like(out))
-    return out.reshape(B, 1, H * Dh)
+    return out.reshape(B, 1, H * Dv)

@@ -298,13 +298,16 @@ def _fwd_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
 def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
               interpret, window=None, static_offs=None
               ) -> Tuple[jax.Array, jax.Array]:
-    """q (B, H, Sq, D); k, v (B, KVH, Skv, D), KVH dividing H: a query
-    head reads kv head `h // (H // KVH)`, nothing is expanded.
-    -> (out, lse). `static_offs`: (q_offset, kv_offset) as Python ints
+    """q (B, H, Sq, D); k (B, KVH, Skv, D), v (B, KVH, Skv, Dv), KVH
+    dividing H: a query head reads kv head `h // (H // KVH)`, nothing is
+    expanded; the values may be another width than the keys (latent
+    attention: 192-wide scores over 128-wide values), and the output is
+    theirs. -> (out, lse). `static_offs`: (q_offset, kv_offset) as Python ints
     where the caller knows them, and then `offs` is not read; else `offs`
     (two numbers, traced or not) reaches the index maps as a prefetched
     scalar. The grid: `_fwd_grid`."""
     B, H, Sq, D = q.shape
+    Dv = v.shape[-1]
     group = H // k.shape[1]
     nq, nk = Sq // block_q, k.shape[2] // block_k
     tail, scalars, locate = _fwd_grid(nq, nk, block_q, block_k, causal,
@@ -328,20 +331,20 @@ def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
             in_specs=[
                 pl.BlockSpec((1, 1, block_q, D), q_block),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
+                pl.BlockSpec((1, 1, block_k, Dv), kv_block),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_block),
+                pl.BlockSpec((1, 1, block_q, Dv), q_block),
                 pl.BlockSpec((1, 1, block_q, _LANES), q_block),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), q.dtype),
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
             ]),
         out_shape=[
-            _sds((B, H, Sq, D), q.dtype, q, k, v, offs),
+            _sds((B, H, Sq, Dv), q.dtype, q, k, v, offs),
             _sds((B, H, Sq, _LANES), jnp.float32, q, k, v, offs),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -595,6 +598,10 @@ def _flash_bwd_rule(causal, sm_scale, fwd_blocks, bwd_blocks, use_pallas,
             "flash_attention: the backward pass is not written for a "
             "window (the dq and dkv kernels mask causally only)")
     q, k, v, offs, out, lse = res
+    if v.shape[-1] != k.shape[-1]:
+        raise NotImplementedError(
+            "flash_attention: the backward pass is not written for values "
+            "of another width than the keys")
     if use_pallas:
         # dq and dkv take a kv head a query head; the transpose of that
         # expansion is the sum over a kv head's group.
@@ -690,7 +697,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     force_pallas: bool = False) -> jax.Array:
     """Fused multi-head attention.
 
-    q: (B, Sq, H, D); k, v: (B, Skv, KVH, D) with H % KVH == 0 (GQA).
+    q: (B, Sq, H, D); k, v: (B, Skv, KVH, D) with H % KVH == 0 (GQA); v
+    may be (B, Skv, KVH, Dv) of another width (forward only), and the
+    result is then (B, Sq, H, Dv).
     Offsets are *global token positions* of element 0 of the q / kv
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     With `window`, a query also sees no key more than `window - 1`

@@ -191,6 +191,18 @@ class GenRequest:
                 return list(self.tokens)
 
 
+def _routing_sums(stats) -> Dict[str, int]:
+    """A program's routing stats (`models/generate.routed_layers`) under
+    the counters' names: experts hit, rows routed to the experts held and
+    the fullest expert's rows; and the token-expert pairs routed beside
+    the pairs that chose an expert held here (a layer that holds all its
+    experts keeps every pair; one that holds a share says how many were
+    routed in a fourth entry)."""
+    hit, rows, fullest, *pairs = (int(n) for n in stats)
+    return dict(moe_experts_hit=hit, moe_rows=rows, moe_rows_max=fullest,
+                moe_pairs=pairs[0] if pairs else rows, moe_pairs_held=rows)
+
+
 class _Slot:
     __slots__ = ("req", "emitted", "length", "inflight")
 
@@ -314,8 +326,11 @@ class LLMEngine:
         if self._routed_layers:
             self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
                                moe_rows=0, moe_rows_max=0,
+                               moe_pairs=0, moe_pairs_held=0,
                                prefill_moe_experts_hit=0,
-                               prefill_moe_rows=0, prefill_moe_rows_max=0)
+                               prefill_moe_rows=0, prefill_moe_rows_max=0,
+                               prefill_moe_pairs=0,
+                               prefill_moe_pairs_held=0)
         # Admission tiles' routing stats, on their way to the host: read
         # where the host next waits for a tile (_deliver_first_tokens).
         self._tile_moe: List[jax.Array] = []
@@ -635,6 +650,11 @@ class LLMEngine:
     # past the ridge with room (256 measured against 512: PERF.md,
     # section 6, PR 29). _tile_rows() makes the width from it.
     _TILE_POSITIONS = 512
+    # Positions a queue-side tile holds at most: _ADMIT_TILE rows up to
+    # the 1024 bucket, fewer past it, one from 8192 on (a tile's
+    # temporaries grow with its positions: eight rows of 8192 do not fit
+    # a chip beside 12 GB of weights and cache).
+    _QUEUE_TILE_POSITIONS = 8192
     FINISHED_RING = 1024
 
     @classmethod
@@ -646,6 +666,14 @@ class LLMEngine:
         512 and longer 1: several requests of a long bucket are several
         tiles in one tick, at the same arithmetic."""
         return max(1, min(cls._ADMIT_TILE, cls._TILE_POSITIONS // bucket))
+
+    @classmethod
+    def _queue_tile_rows(cls, bucket: int) -> int:
+        """Rows of a queue-side tile (`_early_first_tokens`) of `bucket`
+        positions a row: _ADMIT_TILE while they fit
+        _QUEUE_TILE_POSITIONS."""
+        return max(1, min(cls._ADMIT_TILE,
+                          cls._QUEUE_TILE_POSITIONS // bucket))
 
     def _touch(self, reqs: Sequence[GenRequest]) -> None:
         """Stamp the requests that engine compute touches for the first
@@ -818,11 +846,13 @@ class LLMEngine:
         # purposes (prefill starts here).
         self._touch(todo)
         outs = []
-        # Queue-side tiles stay _ADMIT_TILE wide in every bucket.
-        W = self._ADMIT_TILE
+        # Queue-side tiles are _ADMIT_TILE wide up to the 1024 bucket
+        # (_queue_tile_rows); a narrower tile's results are padded to
+        # that width, so the first-token fusion sees one shape a tile.
         full, suffix = self._group_by_route(todo, lambda r: r.prompt,
-                                            lambda bucket: W)
+                                            self._queue_tile_rows)
         for bucket, chunk in full:
+            W = self._queue_tile_rows(bucket)
             with self._tile_span("queue", bucket, W, chunk):
                 buf, lens, temps = self._build_tile(
                     bucket, W, [(r.prompt, r.temperature) for r in chunk])
@@ -830,8 +860,12 @@ class LLMEngine:
                 toks, lps = first_token_sample(
                     self.cfg, self.params, jnp.asarray(buf),
                     jnp.asarray(lens), jnp.asarray(temps), self.top_k, sub)
+                if W < self._ADMIT_TILE:
+                    toks, lps = (jnp.pad(x, (0, self._ADMIT_TILE - W))
+                                 for x in (toks, lps))
             _copy_to_host_async(lps)
             outs.append((chunk, toks, lps))
+        W = self._ADMIT_TILE      # a suffix tile's: its bucket is short
         # Prefix-matched queued requests: suffix-only forward against
         # the stored prefix KV (same FLOP saving as slot admission).
         for pkey, entry, bucket, chunk in suffix:
@@ -893,10 +927,8 @@ class LLMEngine:
                 # whose tokens the queue side had served) routed: a
                 # tile's span ends at its dispatch, before the device
                 # knows, so the numbers ride the span that waits for it.
-                hit, rows, fullest = (int(n) for n in np.sum(tile_moe, 0))
-                routed = dict(prefill_moe_experts_hit=hit,
-                              prefill_moe_rows=rows,
-                              prefill_moe_rows_max=fullest)
+                routed = {"prefill_" + name: n for name, n in
+                          _routing_sums(np.sum(tile_moe, 0)).items()}
                 for name, n in routed.items():
                     self.counts[name] += n
                 span.set(moe_tiles=len(tile_moe), **routed)
@@ -1090,10 +1122,7 @@ class LLMEngine:
                 # experts; the program says how many held a row.
                 routed = dict(
                     moe_expert_steps=k_block * self._routed_layers
-                    * self.cfg.moe_experts,
-                    moe_experts_hit=int(host_moe[0]),
-                    moe_rows=int(host_moe[1]),
-                    moe_rows_max=int(host_moe[2]))
+                    * self.cfg.moe_experts, **_routing_sums(host_moe))
                 for name, n in routed.items():
                     self.counts[name] += n
                 span.set(**routed)

@@ -759,3 +759,87 @@ def test_mellum_admission_tile_fits_beside_weights_and_cache(
     assert fa.DISPATCH_COUNTS["pallas"] - before == \
         (4 if activations == "bfloat16" else 0)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- openpangu-longgen-closed: a latent cache and a share of the experts ----
+
+@pytest.fixture(scope="module")
+def serve_pangu(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "openpangu-longgen-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("openpangu-ultra-moe-l5-ep16", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _pangu_fits(serve_pangu, mem, record_property):
+    cfg, slots, one, key, params, cache = serve_pangu
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    held = cache.c.size * cache.c.dtype.itemsize
+    # 9.84 GB of weights beside 32 x 10,240 rows of 512 + 64 values in
+    # whole lanes (640), five layers: 2.10 GB.
+    assert 9.83e9 < weights < 9.85e9 and cache.c.shape == (5, 32, 10240, 640)
+    assert cache.k is None and cache.v is None and 2.09e9 < held < 2.10e9
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    # The whole cache aliased: no program copies it in or out.
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_pangu_decode_block_reads_latent_rows_through_the_kernel(
+        serve_pangu, as_on_the_chip, record_property):
+    """`decode_multi` (k = 8) at the cell's 32 slots x 10,240: the decode
+    kernel over one array for keys and values (rows of 640 lanes, 128
+    query heads), megablox's kernel at 7680 x 2048 and 2048 x 7680 inside
+    the loop over the kept pairs' passes, no `ragged-dot`, the cache
+    updated in place (11.94 GB of arguments + 0.42 of temporaries: read
+    here in PR 34)."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = serve_pangu
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key, live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts/while/body/jit(gmm)" in text
+    assert "ragged-dot" not in text and "decode_attn" in text
+    for scope in ("attn_latent", "mla_proj", "moe_router", "moe_shared"):
+        assert scope in text, scope
+    _pangu_fits(serve_pangu, mem, record_property)
+    assert mem.temp_size_in_bytes < 1e9
+
+
+def test_pangu_admission_tile_fits_beside_weights_and_latent_cache(
+        serve_pangu, as_on_the_chip, record_property):
+    """The one-row tile of the 8,192 bucket: per-head attention through
+    the flash kernel with keys 192 wide over values 128 wide, a layer one
+    launch; 65,536 token-expert pairs of which a pass takes 8,192 through
+    megablox's kernel; it compiles for the described chip beside 9.84 GB
+    of weights and the 2.10 GB cache (11.94 GB of arguments + 2.54 of
+    temporaries: read here in PR 34), the cache rows-major throughout."""
+    from ray_tpu.models.generate import prefill_sample_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_pangu
+    W = LLMEngine._tile_rows(8192)
+    assert W == 1
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    before = fa.DISPATCH_COUNTS["pallas"]
+    compiled = prefill_sample_batch.lower(
+        cfg, params, cache, arr((W, 8192), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts/while/body/jit(gmm)" in text
+    assert "ragged-dot" not in text and "flash_fwd" in text
+    # One trace a group of the plan (the dense layer, the routed layers).
+    assert fa.DISPATCH_COUNTS["pallas"] - before == 2
+    _pangu_fits(serve_pangu, mem, record_property)
