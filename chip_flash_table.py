@@ -1,9 +1,11 @@
 #!/usr/bin/env python3
-"""The flash forward kernel alone on the chip: the table PERF.md section 6
-(PR 33) gives and `ops/flash_attention._FWD_MEASURED_BLOCKS` was chosen
-from. Run by no cell and by no test but its own rehearsal:
+"""The flash kernels alone on the chip: the tables PERF.md section 6 (PR
+33 the forward, PR 35 dq and dkv) gives and `ops/flash_attention`'s
+`_FWD_MEASURED_BLOCKS` / `_BWD_MEASURED_BLOCKS` were chosen from. Run by
+no cell and by no test but its own rehearsal:
 
     chiprun -- python chip_flash_table.py [--parent .archive/parent]
+                                          [--only fwd|bwd]
 
 One JSON line a reading (`ms`: the least mean over `--reps` batches of
 `--calls` back-to-back calls, host clock around `block_until_ready`), all
@@ -13,7 +15,11 @@ built on every live block against the edge blocks only, the table of live
 pairs against the grid that walks runs of kv blocks (what a traced offset
 gets), K and V expanded before the call against kv head `h // group`;
 with `--parent`, that checkout's kernel on the same inputs. Then the
-reference against the kernel under the kv crossover. `--tiny` rehearses
+reference against the kernel under the kv crossover. Then the backward
+at the shapes that train or could (`BWD_SHAPES`): dq and dkv apart (each
+jitted alone: the other kernel is dead code) at every block pair, on the
+run grid, with K and V expanded before the call and dk, dv summed behind
+it, and the parent's two kernels. `--tiny` rehearses
 the control flow in the Pallas interpreter on a CPU: its times mean
 nothing.
 """
@@ -21,6 +27,7 @@ nothing.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib
 import importlib.util
 import json
@@ -43,6 +50,11 @@ SHAPES = [
 ]
 BLOCKS = [(256, 512), (512, 256), (512, 512), (512, 1024), (1024, 256),
           (1024, 512), (1024, 1024), (2048, 512), (512, 2048), (2048, 2048)]
+# The backward's rows: the train cell's launch and 8,192 positions at 32 / 4.
+BWD_SHAPES = SHAPES[1:3]
+BWD_BLOCKS = [(256, 256), (256, 512), (512, 256), (512, 512), (256, 1024),
+              (512, 1024), (1024, 256), (1024, 512), (1024, 1024),
+              (2048, 512), (512, 2048)]
 # (B, S, H, KVH, D): a one-row tile and batch's eight rows under the
 # crossover, where `flash_attention` takes the reference today.
 SHORT = [(1, 512, 16, 8, 128), (1, 1024, 16, 8, 128), (8, 512, 16, 8, 128),
@@ -90,14 +102,18 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--seed", type=int, default=33)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--only", choices=["fwd", "bwd"])
     a = ap.parse_args(argv)
 
     shapes, blocks, short = SHAPES, BLOCKS, SHORT
+    bwd_shapes, bwd_blocks = BWD_SHAPES, BWD_BLOCKS
     interpret = False
     if a.tiny:
         shapes = [("tiny", 1, 256, 4, 2, 32, None),
                   ("tiny window", 1, 256, 4, 1, 32, 64)]
         blocks, short = [(32, 64), (64, 64)], [(1, 64, 4, 2, 32)]
+        bwd_shapes = [("tiny", 1, 1024, 4, 2, 32, None)]
+        bwd_blocks = [(256, 512), (512, 512)]
         interpret, a.calls, a.reps = True, 1, 1
     elif jax.default_backend() != "tpu":
         print("chip_flash_table: no TPU here (--tiny rehearses on a CPU)",
@@ -111,16 +127,19 @@ def main(argv=None) -> int:
             out_f.write(line + "\n")
             out_f.flush()
 
-        _table(a, say, shapes, blocks, short, interpret)
+        parent = _load_parent(a.parent) if a.parent else None
+        dev = jax.devices()[0]
+        say(what="device", platform=dev.platform, kind=dev.device_kind,
+            calls=a.calls, reps=a.reps, tiny=a.tiny)
+        if a.only != "bwd":
+            _table(a, say, shapes, blocks, short, interpret, parent)
+        if a.only != "fwd":
+            _bwd_table(a, say, bwd_shapes, bwd_blocks, interpret, parent)
     return 0
 
 
-def _table(a, say, shapes, blocks, short, interpret) -> None:
+def _table(a, say, shapes, blocks, short, interpret, parent) -> None:
     dtype = jnp.bfloat16
-    parent = _load_parent(a.parent) if a.parent else None
-    dev = jax.devices()[0]
-    say(what="device", platform=dev.platform, kind=dev.device_kind,
-        calls=a.calls, reps=a.reps, tiny=a.tiny)
     offs = jnp.zeros((1, 2), jnp.float32)
 
     def kernel(bq, bk, window, D, *, static=True, mod=fa):
@@ -206,6 +225,112 @@ def _table(a, say, shapes, blocks, short, interpret) -> None:
                 q, k, v, causal=True, **kw))
             row[path + "_ms"] = _timed(fn, (q, k, v), a.calls, a.reps)
         say(**row)
+
+
+def _bwd_table(a, say, shapes, blocks, interpret, parent) -> None:
+    dtype = jnp.bfloat16
+    offs = jnp.zeros((1, 2), jnp.float32)
+
+    def err(got, want):
+        return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                     / jnp.max(jnp.abs(want)))
+
+    for name, B, S, H, KVH, D, _ in shapes:
+        q, k, v = _inputs(a.seed, B, S, H, KVH, D, dtype)
+        do = _inputs(a.seed + 1, B, S, H, KVH, D, dtype)[0]
+        shape = dict(shape=name, B=B, S=S, H=H, KVH=KVH, D=D)
+        group = H // KVH
+        kw = dict(sm_scale=1.0 / math.sqrt(D), causal=True,
+                  interpret=interpret)
+
+        def kernels(bq, bk, *, static=True, mod=fa):
+            """dq alone, dkv alone, both: each a jitted call of
+            `mod._bwd_impl` on the residuals the forward left."""
+            more = {"static_offs": (0, 0) if static else None} \
+                if mod is fa else {}
+            bwd = functools.partial(mod._bwd_impl, block_q=bq, block_k=bk,
+                                    **kw, **more)
+            return (jax.jit(lambda *x: bwd(*x, offs)[0]),
+                    jax.jit(lambda *x: bwd(*x, offs)[1:]),
+                    jax.jit(lambda *x: bwd(*x, offs)))
+
+        def expanded(bwd):
+            """What `_flash_bwd_rule` did before dkv read the group: K
+            and V expanded in front, dk and dv summed over it behind."""
+            def f(q, k, v, do, out, lse):
+                dq, dk, dv = bwd(q, fa._expand_kv(k, H),
+                                 fa._expand_kv(v, H), do, out, lse)
+                return (dq,) + tuple(
+                    x.reshape(B, KVH, group, S, D).sum(axis=2)
+                    for x in (dk, dv))
+            return jax.jit(f)
+
+        out, lse = jax.jit(lambda q, k, v: fa._fwd_impl(
+            q, k, v, offs, block_q=min(S, 512), block_k=min(S, 512),
+            static_offs=(0, 0), **kw))(q, k, v)
+        res = (q, k, v, do, out, lse)
+        # Checked on the first kv head and its group of query heads.
+        want = jax.jit(lambda q, k, v, do: jax.vjp(
+            lambda q, k, v: fa._reference(
+                q, fa._expand_kv(k, group), fa._expand_kv(v, group), offs,
+                sm_scale=kw["sm_scale"], causal=True)[0], q, k, v)[1](do))(
+                    *(x[:, :n].astype(jnp.float32) for x, n in (
+                        (q, group), (k, 1), (v, 1), (do, group))))
+        chosen = fa.tileable(S, S, D, *fa._bwd_blocks(S, S, D))
+        for bq, bk in blocks:
+            if S % bq or S % bk:
+                continue
+            dq_fn, dkv_fn, both = kernels(bq, bk)
+            row = dict(what="bwd_table_grid", **shape, blocks=[bq, bk],
+                       chosen=(bq, bk) == chosen,
+                       dq_ms=_timed(dq_fn, res, a.calls, a.reps),
+                       dkv_ms=_timed(dkv_fn, res, a.calls, a.reps))
+            if not isinstance(row["dq_ms"], str) \
+                    and not isinstance(row["dkv_ms"], str):
+                got = both(*res)
+                row.update(
+                    ms=_timed(both, res, a.calls, a.reps),
+                    dq_err=err(got[0][:, :group], want[0]),
+                    dk_err=err(got[1][:, :1], want[1]),
+                    dv_err=err(got[2][:, :1], want[2]),
+                    dq=fa.grid_steps(S, S, bq, bk, causal=True),
+                    dkv=fa.grid_steps(S, S, bq, bk, causal=True,
+                                      by_kv=True, group=group))
+            say(**row)
+        bq, bk = chosen
+        dq_fn, dkv_fn, both = kernels(bq, bk, static=False)
+        say(what="bwd_run_grid", **shape, blocks=[bq, bk],
+            dq_ms=_timed(dq_fn, res, a.calls, a.reps),
+            dkv_ms=_timed(dkv_fn, res, a.calls, a.reps),
+            **fa.grid_steps(S, S, bq, bk, causal=True, q_offset=None))
+        # The mask on every live block: `_block_kind` never says inside.
+        kind = fa._block_kind
+        fa._block_kind = lambda *x: (lambda live, inside: (
+            live, inside & False))(*kind(*x))
+        try:
+            dq_fn, dkv_fn, both = kernels(bq, bk)
+            say(what="bwd_mask_on_every_live_block", **shape,
+                blocks=[bq, bk],
+                dq_ms=_timed(dq_fn, res, a.calls, a.reps),
+                dkv_ms=_timed(dkv_fn, res, a.calls, a.reps))
+        finally:
+            fa._block_kind = kind
+        say(what="bwd_expanded_before_the_call", **shape, blocks=[bq, bk],
+            ms=_timed(expanded(functools.partial(
+                fa._bwd_impl, offs=offs, block_q=bq, block_k=bk,
+                static_offs=(0, 0), **kw)), res, a.calls, a.reps))
+        if parent is not None:
+            for pbq, pbk in {fa.tileable(S, S, D, 256, 512), chosen}:
+                dq_fn, dkv_fn, both = kernels(pbq, pbk, mod=parent)
+                pres = (q, fa._expand_kv(k, H), fa._expand_kv(v, H), do,
+                        out, lse)
+                say(what="bwd_parent_kernel", **shape, blocks=[pbq, pbk],
+                    dq_ms=_timed(dq_fn, pres, a.calls, a.reps),
+                    dkv_ms=_timed(dkv_fn, pres, a.calls, a.reps),
+                    ms=_timed(both, pres, a.calls, a.reps),
+                    ms_with_expansion=_timed(expanded(functools.partial(
+                        parent._bwd_impl, offs=offs, block_q=pbq,
+                        block_k=pbk, **kw)), res, a.calls, a.reps))
 
 
 if __name__ == "__main__":

@@ -360,7 +360,7 @@ def phase_kernels(rep: Report, sz: Sizes) -> None:
     import jax.numpy as jnp
 
     from ray_tpu.ops.flash_attention import (
-        _expand_kv,
+        FLASH_GRID,
         _reference,
         flash_attention,
     )
@@ -384,13 +384,14 @@ def phase_kernels(rep: Report, sz: Sizes) -> None:
     def reference(q, k, v):   # float32, HIGHEST, same bf16 inputs
         qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 1, 2)
                       for x in (q, k, v))
-        out, _ = _reference(
-            qt, _expand_kv(kt, H), _expand_kv(vt, H),
+        out, _ = _reference(   # a kv head a query head: only here
+            qt, jnp.repeat(kt, H // KVH, axis=1),
+            jnp.repeat(vt, H // KVH, axis=1),
             jnp.zeros((1, 2), jnp.float32),
             sm_scale=1.0 / math.sqrt(D), causal=True)
         return jnp.swapaxes(out, 1, 2)
 
-    counts0 = _attention_dispatch()
+    counts0, grid0 = _attention_dispatch(), collections.Counter(FLASH_GRID)
     fwd = jax.jit(kernel).lower(q, k, v).compile()
     bwd = jax.jit(jax.grad(weighted(kernel), argnums=(0, 1, 2))
                   ).lower(q, k, v).compile()
@@ -400,7 +401,8 @@ def phase_kernels(rep: Report, sz: Sizes) -> None:
     rep.note(shape={"B": B, "S": S, "H": H, "KVH": KVH, "D": D,
                     "dtype": "bfloat16"},
              dispatch=dispatch, tpu_custom_calls_fwd=n_fwd,
-             tpu_custom_calls_bwd=n_bwd)
+             tpu_custom_calls_bwd=n_bwd,
+             grid=dict(collections.Counter(FLASH_GRID) - grid0))
     rep.require("fwd_program_has_tpu_custom_call", n_fwd > 0)
     rep.require("bwd_program_has_tpu_custom_call", n_bwd > 0)
     rep.require("dispatch_counted_pallas_only",

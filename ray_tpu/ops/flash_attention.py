@@ -2,12 +2,13 @@
 
 FlashAttention-2-style tiling for the MXU: grid over (batch, head) and,
 innermost and sequential, the (q-block, kv-block) pairs the mask leaves
-a live pair in (the forward: `_fwd_grid`; the backward kernels walk
-every pair of blocks); online-softmax statistics (m, l) and the output
+a live pair in (`_fwd_grid`: the forward and dq; `_dkv_grid`: dk/dv);
+online-softmax statistics (m, l) and the output
 accumulator live in VMEM scratch across a q block's kv steps, so HBM
 traffic is O(S) per head instead of the O(S^2) score matrix. The backward
 pass recomputes scores blockwise
-(two kernels: dq with a kv loop, dk/dv with a q loop) from the saved
+(two kernels: dq with a kv loop, dk/dv with a loop over a kv head's
+query heads and their q blocks) from the saved
 logsumexp — the standard remat trade that keeps HBM residency at
 activation size.
 
@@ -49,10 +50,11 @@ _SUBLANES = 8
 # decode_attention "decode_attn" where its kernel is traced.
 DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
 
-# What the forward kernel's grids were given, summed over the calls traced
-# (x batch x heads), the same way: `steps`, `live_steps`, `masked_steps`
+# What the kernels' grids were given, summed over the calls traced (x batch
+# x heads), the same way: `steps`, `live_steps`, `masked_steps`
 # (`grid_steps`) of the calls whose offsets were Python ints, `traced_steps`
-# of the others.
+# of the others; the forward's under those names, the backward's two
+# kernels' under `dq_` and `dkv_` + the same.
 FLASH_GRID: "collections.Counter[str]" = collections.Counter()
 
 
@@ -105,12 +107,15 @@ def _block_kind(first_q, first_k, block_q, block_k, window):
     return live, inside
 
 
-def _live_pairs(nq, nk, block_q, block_k, causal, window, q_off, kv_off):
-    """The (q block, kv block) pairs a forward with offsets known at trace
+def _live_pairs(nq, nk, block_q, block_k, causal, window, q_off, kv_off,
+                by_kv=False):
+    """The (q block, kv block) pairs a kernel with offsets known at trace
     time walks, as int32 arrays (qi, ki, kind), q blocks in order and each
     one's kv blocks in order: every pair that holds a live pair once, no
     other, but for a q block that sees no key at all, which keeps its
-    first pair (_DEAD) as the step that writes its zeros."""
+    first pair (_DEAD) as the step that writes its zeros. `by_kv`: kv
+    blocks in order and each one's q blocks in order (dkv's walk), a kv
+    block that no query sees keeping its first pair."""
     qi, ki = np.meshgrid(np.arange(nq, dtype=np.int32),
                          np.arange(nk, dtype=np.int32), indexing="ij")
     if causal:
@@ -121,7 +126,11 @@ def _live_pairs(nq, nk, block_q, block_k, causal, window, q_off, kv_off):
         live = inside = np.ones((nq, nk), bool)
     kind = np.where(live, np.where(inside, _INSIDE, _EDGE), _DEAD)
     keep = live.copy()
-    keep[~live.any(axis=1), 0] = True
+    if by_kv:
+        keep[0, ~live.any(axis=0)] = True
+        qi, ki, kind, keep = qi.T, ki.T, kind.T, keep.T
+    else:
+        keep[~live.any(axis=1), 0] = True
     return qi[keep], ki[keep], kind[keep].astype(np.int32)
 
 
@@ -135,22 +144,24 @@ def _kv_run(nk, block_q, block_k, window):
 
 
 def grid_steps(sq, skv, block_q, block_k, *, causal, window=None,
-               q_offset=0, kv_offset=0):
-    """What the forward's grid spends on one (batch, head) of a call:
+               q_offset=0, kv_offset=0, by_kv=False, group=1):
+    """What a kernel's grid spends on one (batch, query head) of a call:
     `steps` it is given, `live_steps` whose block holds a live pair,
     `masked_steps` that build the mask (blocks an edge crosses). The
-    grid is built from the same table (`_fwd_grid`). With an offset the
-    trace cannot see (None) only `traced_steps` is known: a run of kv
-    blocks a q block, right for any offset."""
+    forward's and dq's grid is built from the same table (`_fwd_grid`);
+    `by_kv`: dkv's (`_dkv_grid`, whose table holds a kv head's `group` of
+    query heads). With an offset the trace cannot see (None) only
+    `traced_steps` is known: a run of kv blocks a q block, right for any
+    offset."""
     nq, nk = sq // block_q, skv // block_k
     if not causal:
         q_offset = kv_offset = 0
     if q_offset is None or kv_offset is None:
         return {"traced_steps": nq * _kv_run(nk, block_q, block_k, window)}
     kind = _live_pairs(nq, nk, block_q, block_k, causal, window, q_offset,
-                       kv_offset)[2]
+                       kv_offset, by_kv)[2]
     steps = len(kind)
-    if steps > _MAX_PAIRS:
+    if steps * group > _MAX_PAIRS:
         steps = nq * _kv_run(nk, block_q, block_k, window)
     return {"steps": steps, "live_steps": int(np.sum(kind != _DEAD)),
             "masked_steps": int(np.sum(kind == _EDGE))}
@@ -160,12 +171,22 @@ class _Step(NamedTuple):
     """Where a grid step stands (`_fwd_grid`'s `locate`)."""
     qi: Any          # q block
     ki: Any          # kv block scored
-    fetch: Any       # kv block fetched (a dead step repeats a live one)
+    fetch: Any       # block fetched on the side walked (kv; dkv: q): a
+                     # dead step repeats a live one
     first: Any       # first / last step of its q block
     last: Any
     kind: Any        # _DEAD / _INSIDE / _EDGE
     q_off: Any       # global position of q's, kv's element 0
     kv_off: Any
+    g: Any = 0       # dkv: query head of the kv head's group
+
+
+def _table_flags(outer, kind):
+    """A table entry's word: bit 0 the first and bit 1 the last step of
+    its `outer` block, the kind from bit 2."""
+    turn = outer[1:] != outer[:-1]
+    return (np.r_[True, turn] | np.r_[turn, True] << 1
+            | kind << 2).astype(np.int32)
 
 
 def _fwd_grid(nq, nk, block_q, block_k, causal, window, static_offs, offs):
@@ -185,9 +206,7 @@ def _fwd_grid(nq, nk, block_q, block_k, causal, window, static_offs, offs):
         qi, ki, kind = _live_pairs(nq, nk, block_q, block_k, causal, window,
                                    *static_offs)
         if len(qi) <= _MAX_PAIRS:
-            turn = qi[1:] != qi[:-1]
-            flags = (np.r_[True, turn] | np.r_[turn, True] << 1
-                     | kind << 2).astype(np.int32)
+            flags = _table_flags(qi, kind)
 
             def locate(ids, refs):
                 (p,), (qi_ref, ki_ref, flag_ref) = ids, refs
@@ -361,182 +380,251 @@ def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, sm_scale, block_q, block_k, num_kv,
-               causal):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+def _dkv_grid(nq, nk, block_q, block_k, causal, group, static_offs, offs):
+    """`_fwd_grid` for the kernel that walks by kv block: behind (B, KVH),
+    sequential for one kv block, every query head of its group (`g`) and
+    every q block that holds a live pair with it, so dk and dv sum over
+    the group where they accumulate. A `_Step`'s `fetch` is the q block
+    fetched. Offsets known at trace time: the table of live pairs by kv
+    block, a group's heads in turn; traced: (nk, group x nq), a head's
+    run starting at the first q block under the kv block's diagonal and
+    a step past the last q block repeating it. No window: the backward
+    is not written for one."""
+    if not causal:
+        static_offs = (0, 0)
+    if static_offs is not None:
+        qi, ki, kind = _live_pairs(nq, nk, block_q, block_k, causal, None,
+                                   *static_offs, by_kv=True)
+        if group * len(qi) <= _MAX_PAIRS:
+            g = np.repeat(np.arange(group, dtype=np.int32), len(qi))
+            qi, ki, kind = (np.tile(x, group) for x in (qi, ki, kind))
+            order = np.lexsort((qi, g, ki))
+            qi, ki, kind, g = (x[order] for x in (qi, ki, kind, g))
+            flags = _table_flags(ki, kind) | g << 4
 
-    @pl.when(ki == 0)
+            def locate(ids, refs):
+                (p,), (qi_ref, ki_ref, flag_ref) = ids, refs
+                f = flag_ref[p]
+                return _Step(qi_ref[p], ki_ref[p], qi_ref[p], (f & 1) == 1,
+                             (f & 2) == 2, (f >> 2) & 3, *static_offs,
+                             f >> 4)
+
+            return (len(qi),), (qi, ki, flags), locate
+        offs = np.asarray(static_offs, np.int32)
+
+    def locate(ids, refs):
+        (ki, step), (offs_ref,) = ids, refs
+        q_off, kv_off = offs_ref[0], offs_ref[1]
+        g, qi = step // nq, step % nq
+        first, last = step == 0, step == group * nq - 1
+        if not causal:
+            return _Step(qi, ki, qi, first, last, _INSIDE, q_off, kv_off, g)
+        first_k = kv_off + ki * block_k
+        qi += jnp.maximum(first_k - q_off, 0) // block_q
+        live, inside = _block_kind(q_off + qi * block_q, first_k, block_q,
+                                   block_k, None)
+        live = live & (qi < nq)
+        kind = jnp.where(live, jnp.where(inside, _INSIDE, _EDGE), _DEAD)
+        return _Step(qi, ki, jnp.minimum(qi, nq - 1), first, last, kind,
+                     q_off, kv_off, g)
+
+    return ((nk, group * nq), (jnp.asarray(offs, jnp.int32).reshape(2),),
+            locate)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+
+
+def _bwd_step(refs, locate, n_scalars, n_ids):
+    """(where this grid step stands, the kernel's operand refs)."""
+    return (locate(tuple(pl.program_id(2 + i) for i in range(n_ids)),
+                   refs[:n_scalars]), refs[n_scalars:])
+
+
+def _seen(shape, at, block_q, block_k, q_axis):
+    """q_pos >= k_pos over a block of scores whose queries run along
+    `q_axis`, as one iota difference against one scalar."""
+    ahead = (lax.broadcasted_iota(jnp.int32, shape, q_axis)
+             - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    return ahead >= (at.kv_off + at.ki * block_k) - (at.q_off
+                                                     + at.qi * block_q)
+
+
+def _on_live_blocks(at, causal, compute):
+    """A block wholly inside the mask takes the body without one, a block
+    an edge crosses the body with it, a dead block neither."""
+    pl.when(at.kind == _INSIDE)(functools.partial(compute, False))
+    if causal:
+        pl.when(at.kind == _EDGE)(functools.partial(compute, True))
+
+
+def _dq_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
+               causal):
+    """One (q block, kv block) step of dq += ds k, on the forward's walk."""
+    at, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, qs_ref,
+         lse_col, delta_col, dq_acc) = _bwd_step(refs, locate, n_scalars,
+                                                 n_ids)
+
+    @pl.when(at.first)
     def _init():
+        # Once a q block: the scale into q (the forward's scores bit for
+        # bit) and the row statistics, which arrive along the lanes,
+        # stood up as columns.
+        qs_ref[...] = (q_ref[0, 0, :, :].astype(jnp.float32)
+                       * sm_scale).astype(qs_ref.dtype)
+        lse_col[...] = lse_ref[0, 0, 0, 0, :][:, None]
+        delta_col[...] = delta_ref[0, 0, 0, 0, :][:, None]
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_off = offs_ref[0, 0].astype(jnp.int32)
-    kv_off = offs_ref[0, 1].astype(jnp.int32)
-
-    def compute():
-        q = q_ref[0, 0, :, :]
+    def compute(masked):
         k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        lse = lse_ref[0, 0, :, :1]
-        p = jnp.exp(s - lse)
-        if causal:
-            q_pos = (q_off + qi * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (kv_off + ki * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            p = p * (q_pos >= k_pos)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        s = lax.dot_general(qs_ref[...], k, _NT,
+                            preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse_col[...])
+        if masked:
+            # Not a product with 0: a dead pair's exp is not clamped.
+            p = jnp.where(_seen(s.shape, at, block_q, block_k, 0), p, 0.0)
+        dp = lax.dot_general(do_ref[0, 0, :, :], v_ref[0, 0, :, :], _NT,
                              preferred_element_type=jnp.float32)
-        delta = delta_ref[0, 0, :, :1]
-        ds = p * (dp - delta) * sm_scale
+        ds = p * (dp - delta_col[...])
         dq_acc[...] += lax.dot(ds.astype(k.dtype), k,
                                preferred_element_type=jnp.float32)
 
-    if causal:
-        last_q = q_off + (qi + 1) * block_q - 1
-        first_k = kv_off + ki * block_k
+    _on_live_blocks(at, causal, compute)
 
-        @pl.when(last_q >= first_k)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(ki == num_kv - 1)
+    @pl.when(at.last)
     def _finalize():
-        dq_ref[0, 0, :, :] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0, 0, :, :] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block_q,
-                block_k, num_q, causal):
-    ki, qi = pl.program_id(2), pl.program_id(3)
+def _dkv_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
+                causal):
+    """One (kv block, query head of its group, q block) step of dv += p^T
+    do and dk += ds^T q. The block is scored transposed (k q^T and v do^T,
+    the forward's product form), so p^T and ds^T are left operands as
+    they stand and a q row's lse and delta lie along the lanes."""
+    at, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         ks_ref, dk_acc, dv_acc) = _bwd_step(refs, locate, n_scalars, n_ids)
 
-    @pl.when(qi == 0)
+    @pl.when(at.first)
     def _init():
+        ks_ref[...] = (k_ref[0, 0, :, :].astype(jnp.float32)
+                       * sm_scale).astype(ks_ref.dtype)
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_off = offs_ref[0, 0].astype(jnp.int32)
-    kv_off = offs_ref[0, 1].astype(jnp.int32)
-
-    def compute():
+    def compute(masked):
         q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        lse = lse_ref[0, 0, :, :1]
-        p = jnp.exp(s - lse)
-        if causal:
-            q_pos = (q_off + qi * block_q
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-            k_pos = (kv_off + ki * block_k
-                     + lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-            p = p * (q_pos >= k_pos)
-        # dv += p^T do  (contract the q dimension)
-        dv_acc[...] += lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        st = lax.dot_general(ks_ref[...], q, _NT,
                              preferred_element_type=jnp.float32)
-        delta = delta_ref[0, 0, :, :1]
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc[...] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pt = jnp.exp(st - lse_ref[0, 0, 0, :, :])
+        if masked:
+            pt = jnp.where(_seen(st.shape, at, block_q, block_k, 1), pt,
+                           0.0)
+        dv_acc[...] += lax.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v_ref[0, 0, :, :], do, _NT,
+                              preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0, 0, :, :])
+        dk_acc[...] += lax.dot(dst.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
 
-    if causal:
-        last_q = q_off + (qi + 1) * block_q - 1
-        first_k = kv_off + ki * block_k
+    _on_live_blocks(at, causal, compute)
 
-        @pl.when(last_q >= first_k)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(qi == num_q - 1)
+    @pl.when(at.last)
     def _finalize():
-        dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0, 0, :, :] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_call(kernel, name, grid, outer, q_block, kv_block, operands, outs,
+              scratch, *, block_q, block_k, interpret, **kw):
+    """dq's or dkv's `pallas_call` over (B, `outer`) + `grid`'s axes.
+    `operands`: q, k, v, do, lse, delta; `q_block`, `kv_block`: (b, h,
+    step) -> the index of either side's block; `outs`: (side, dtype) an
+    output."""
+    tail, scalars, locate = grid
+    n_ids = len(tail)
+    (B, _, Sq, D), Skv = operands[0].shape, operands[1].shape[2]
+    rows = {"q": Sq, "kv": Skv}
+
+    def placed(index, stat=False):
+        def block(b, h, *rest):
+            at = index(b, h, locate(rest[:n_ids], rest[n_ids:]))
+            return at[:3] + (0, 0) if stat else at
+        return block
+
+    side = {"q": pl.BlockSpec((1, 1, block_q, D), placed(q_block)),
+            "kv": pl.BlockSpec((1, 1, block_k, D), placed(kv_block))}
+    stat = pl.BlockSpec((1, 1, 1, 1, block_q), placed(q_block, True))
+    return pl.pallas_call(
+        functools.partial(kernel, locate=locate, n_scalars=len(scalars),
+                          n_ids=n_ids, block_q=block_q, block_k=block_k,
+                          **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(B, outer) + tail,
+            in_specs=[side["q"], side["kv"], side["kv"], side["q"], stat,
+                      stat],
+            out_specs=[side[s] for s, _ in outs],
+            scratch_shapes=scratch),
+        out_shape=[_sds((B, outer, rows[s], D), dt, *operands, scalars[-1])
+                   for s, dt in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (n_ids + 1)
+            + ("arbitrary",),
+            vmem_limit_bytes=_FWD_VMEM_BYTES),
+        interpret=interpret,
+        metadata={"kernel": name},
+    )(*scalars, *operands)
+
+
 def _bwd_impl(q, k, v, do, out, lse, offs, *, sm_scale, block_q, block_k,
-              causal, interpret):
-    """→ (dq, dk, dv) for expanded-head layout (B, H, S, D)."""
+              causal, interpret, static_offs=None):
+    """q, do, out (B, H, Sq, D); k, v (B, KVH, Skv, D), KVH dividing H and
+    nothing expanded; lse (B, H, Sq) -> dq (B, H, Sq, D), dk and dv (B,
+    KVH, Skv, D), summed over a kv head's group in the kernel. Two
+    kernels, each on a grid that follows the mask: dq on the forward's
+    (`_fwd_grid`), dkv on `_dkv_grid`. `static_offs`, `offs`: as
+    `_fwd_impl`'s."""
     B, H, Sq, D = q.shape
-    Skv = k.shape[2]
+    KVH, Skv = k.shape[1], k.shape[2]
+    group = H // KVH
     nq, nk = Sq // block_q, Skv // block_k
+    q_off, kv_off = static_offs or (None, None)
+    for name, by_kv in (("dq", False), ("dkv", True)):
+        for what, n in grid_steps(
+                Sq, Skv, block_q, block_k, causal=causal, q_offset=q_off,
+                kv_offset=kv_off, by_kv=by_kv, group=group).items():
+            FLASH_GRID[f"{name}_{what}"] += B * H * n
+    # The row statistics, a q block a row of (1, block_q) along the lanes:
+    # such a block is whole in its last two dimensions whatever block_q.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)  # (B, H, Sq)
-    # Lane-broadcast the per-row stats: TPU blocks need (…, 8k, 128)-
-    # tileable trailing dims.
-    lse_l = jnp.broadcast_to(lse[..., None], (B, H, Sq, _LANES))
-    delta_l = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
-
-    smem = pl.BlockSpec((1, 2), lambda b, h, i, j: (0, 0),
-                        memory_space=pltpu.SMEM)
-
-    def q_spec(i_of):
-        return pl.BlockSpec((1, 1, block_q, D),
-                            lambda b, h, i, j, f=i_of: (b, h, f(i, j), 0))
-
-    def k_spec(i_of):
-        return pl.BlockSpec((1, 1, block_k, D),
-                            lambda b, h, i, j, f=i_of: (b, h, f(i, j), 0))
-
-    def row_spec(i_of):
-        return pl.BlockSpec((1, 1, block_q, _LANES),
-                            lambda b, h, i, j, f=i_of: (b, h, f(i, j), 0))
-
-    qi_of = lambda i, j: i   # noqa: E731
-    kj_of = lambda i, j: j   # noqa: E731
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, num_kv=nk, causal=causal),
-        grid=(B, H, nq, nk),
-        in_specs=[smem, q_spec(qi_of), k_spec(kj_of), k_spec(kj_of),
-                  q_spec(qi_of), row_spec(qi_of), row_spec(qi_of)],
-        out_specs=[q_spec(qi_of)],
-        out_shape=[_sds((B, H, Sq, D), q.dtype, q, k, v, do, offs)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        metadata={"kernel": "flash_dq"},
-    )(offs, q, k, v, do, lse_l, delta_l)[0]
-
-    # dkv grid: kv blocks parallel, q loop innermost/sequential.
-    ki_of = lambda i, j: i   # noqa: E731
-    qj_of = lambda i, j: j   # noqa: E731
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, num_q=nq, causal=causal),
-        grid=(B, H, nk, nq),
-        in_specs=[smem, q_spec(qj_of), k_spec(ki_of), k_spec(ki_of),
-                  q_spec(qj_of), row_spec(qj_of), row_spec(qj_of)],
-        out_specs=[k_spec(ki_of), k_spec(ki_of)],
-        out_shape=[_sds((B, H, Skv, D), k.dtype, q, k, v, do, offs),
-                   _sds((B, H, Skv, D), v.dtype, q, k, v, do, offs)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        metadata={"kernel": "flash_dkv"},
-    )(offs, q, k, v, do, lse_l, delta_l)
+                    axis=-1)
+    operands = (q, k, v, do, lse.reshape(B, H, nq, 1, block_q),
+                delta.reshape(B, H, nq, 1, block_q))
+    kw = dict(block_q=block_q, block_k=block_k, interpret=interpret,
+              sm_scale=sm_scale, causal=causal)
+    dq, = _bwd_call(
+        _dq_kernel, "flash_dq",
+        _fwd_grid(nq, nk, block_q, block_k, causal, None, static_offs, offs),
+        H, lambda b, h, at: (b, h, at.qi, 0),
+        lambda b, h, at: (b, h // group, at.fetch, 0),
+        operands, [("q", q.dtype)],
+        [pltpu.VMEM((block_q, D), q.dtype),
+         pltpu.VMEM((block_q, 1), jnp.float32),
+         pltpu.VMEM((block_q, 1), jnp.float32),
+         pltpu.VMEM((block_q, D), jnp.float32)], **kw)
+    dk, dv = _bwd_call(
+        _dkv_kernel, "flash_dkv",
+        _dkv_grid(nq, nk, block_q, block_k, causal, group, static_offs,
+                  offs),
+        KVH, lambda b, h, at: (b, h * group + at.g, at.fetch, 0),
+        lambda b, h, at: (b, h, at.ki, 0),
+        operands, [("kv", k.dtype), ("kv", v.dtype)],
+        [pltpu.VMEM((block_k, D), k.dtype),
+         pltpu.VMEM((block_k, D), jnp.float32),
+         pltpu.VMEM((block_k, D), jnp.float32)], **kw)
     return dq, dk, dv
 
 
@@ -571,8 +659,9 @@ def _flash(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
            use_pallas, interpret, window=None, static_offs=None):
     """q (B, H, Sq, D); k, v (B, KVH, Skv, D): unexpanded for the kernels,
     which read kv head `h // (H // KVH)`; the reference is handed them
-    expanded (KVH = H), as it always was. `fwd_blocks`, `bwd_blocks`:
-    (block_q, block_k) of the forward kernel and of dq / dkv."""
+    expanded (KVH = H), as it always was, and its vjp sums dk and dv over
+    a group through that expansion. `fwd_blocks`, `bwd_blocks`: (block_q,
+    block_k) of the forward kernel and of dq / dkv."""
     return _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks,
                       bwd_blocks, use_pallas, interpret, window,
                       static_offs)[0]
@@ -603,16 +692,10 @@ def _flash_bwd_rule(causal, sm_scale, fwd_blocks, bwd_blocks, use_pallas,
             "flash_attention: the backward pass is not written for values "
             "of another width than the keys")
     if use_pallas:
-        # dq and dkv take a kv head a query head; the transpose of that
-        # expansion is the sum over a kv head's group.
-        H, KVH = q.shape[1], k.shape[1]
-        dq, dk, dv = _bwd_impl(q, _expand_kv(k, H), _expand_kv(v, H), g,
-                               out, lse, offs, sm_scale=sm_scale,
-                               block_q=bwd_blocks[0], block_k=bwd_blocks[1],
-                               causal=causal, interpret=interpret)
-        if KVH != H:
-            dk, dv = (x.reshape(x.shape[0], KVH, H // KVH, *x.shape[2:])
-                      .sum(axis=2) for x in (dk, dv))
+        dq, dk, dv = _bwd_impl(q, k, v, g, out, lse, offs,
+                               sm_scale=sm_scale, block_q=bwd_blocks[0],
+                               block_k=bwd_blocks[1], causal=causal,
+                               interpret=interpret, static_offs=static_offs)
     else:
         def f(q, k, v):
             return _reference(q, k, v, offs, sm_scale=sm_scale,
@@ -648,9 +731,6 @@ def tileable(sq: int, skv: int, d: int, block_q: int, block_k: int
     return 0, 0
 
 
-# The backward kernels' blocks, and the forward's until it chose its own.
-_BWD_BLOCKS = (256, 512)
-
 # (Sq, Skv, D, window) -> the forward's (block_q, block_k) where a chip run
 # read another pair faster than `_fwd_blocks`' rule (chip_flash_table.py,
 # bf16 on a v5e; my chip runs, PR 33). 8,192 causal positions at 32 / 4
@@ -670,6 +750,25 @@ def _fwd_blocks(sq: int, skv: int, d: int, window: Optional[int]
     what divides the call's lengths."""
     return _FWD_MEASURED_BLOCKS.get(
         (sq, skv, d, window), (1024, 512) if window is None else (512, 512))
+
+
+# (Sq, Skv, D) -> dq's and dkv's (block_q, block_k) where a chip run read
+# another pair faster than `_bwd_blocks`' rule (chip_flash_table.py, bf16
+# on a v5e; my chip runs, PR 35), dq + dkv in ms. 4,096 causal positions
+# at 2 x 16 / 8 heads of 128: 1024 x 1024 1.79 + 2.10 against the rule's
+# 1.89 + 2.17; 8,192 at 32 / 4: 5.70 + 6.98 against 6.30 + 7.60.
+_BWD_MEASURED_BLOCKS = {(4096, 4096, 128): (1024, 1024),
+                        (8192, 8192, 128): (1024, 1024)}
+
+
+def _bwd_blocks(sq: int, skv: int, d: int) -> Tuple[int, int]:
+    """dq's and dkv's (block_q, block_k) targets from what a call shows.
+    The rule: 512 x 512, the least of the pairs that waste an eighth of
+    the blocks' pairs above the diagonal or less at 4,096 positions (256
+    x 512, the pair both kernels had, 2.25 + 2.96 ms; 512 x 1024 and 1024
+    x 512 level with it at a quarter wasted; 2048-wide blocks lose).
+    `tileable` cuts either to what divides the call's lengths."""
+    return _BWD_MEASURED_BLOCKS.get((sq, skv, d), (512, 512))
 
 
 def _expand_kv(x: jax.Array, n_heads: int) -> jax.Array:
@@ -704,13 +803,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     With `window`, a query also sees no key more than `window - 1`
     positions behind it: (q_offset + i) - (kv_offset + j) < window
-    (forward only). The forward kernel's grid follows the mask: with
+    (forward only). The kernels' grids follow the mask: with
     offsets given as Python ints a block that holds no live pair gets
     neither a step nor a fetch (`grid_steps`, counted in `FLASH_GRID`).
     Returns (B, Sq, H, D).
 
-    `block_q`, `block_k`: None lets the forward choose its blocks from
-    the call's shapes (`_fwd_blocks`) and the backward keep 256 x 512;
+    `block_q`, `block_k`: None lets the forward and the backward choose
+    their blocks from the call's shapes (`_fwd_blocks`, `_bwd_blocks`);
     a number is a target for both.
 
     `interpret=None` compiles the kernels on a TPU and takes the
@@ -726,8 +825,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError("flash_attention: a window needs causal=True "
                          f"and at least one position, got {window!r}")
 
-    bwd = tileable(Sq, Skv, D, block_q or _BWD_BLOCKS[0],
-                   block_k or _BWD_BLOCKS[1])
+    want = _bwd_blocks(Sq, Skv, D)
+    bwd = tileable(Sq, Skv, D, block_q or want[0], block_k or want[1])
     want = _fwd_blocks(Sq, Skv, D, window)
     fwd = tileable(Sq, Skv, D, block_q or want[0], block_k or want[1])
     compiled = on_tpu() if interpret is None else not interpret

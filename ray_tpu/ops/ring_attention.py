@@ -212,8 +212,9 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
         vt = jnp.repeat(vt, rep, axis=1)
     # The same decision as flash_attention: the kernels on a TPU, the
     # reference (never the interpreter) anywhere else. A step's offsets
-    # are traced, so its forward walks a run of kv blocks a q block
-    # (`flash_attention._fwd_grid`), here in the backward's blocks.
+    # are traced, so its forward and dq walk a run of kv blocks a q block
+    # (`flash_attention._fwd_grid`) and dkv a run of q blocks a kv block
+    # (`_dkv_grid`), all three in the blocks given here.
     bq, bk = tileable(S, S, D, block_q, block_k)
     tpu = on_tpu()
     use_pallas = bool(bq) and tpu

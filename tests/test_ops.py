@@ -99,6 +99,64 @@ def _fwd_both(key, sq, skv, h, kvh, *, causal, window=None, q_off=0,
     return got, want, live
 
 
+def _bwd_both(key, sq, skv, h, kvh, *, causal, q_off=0, kv_off=0,
+              traced=False, blocks=(32, 64)):
+    """(dq, dk, dv) of the two kernels in the interpreter, on the
+    residuals the forward kernel leaves, and of `_reference`'s vjp (K and
+    V expanded in front, so its dk and dv sum over a kv head's group),
+    (B, H, S, D) layout, and which rows see a key at all."""
+    kq, kk, kv_, kg = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (2, h, sq, 32))
+    k = jax.random.normal(kk, (2, kvh, skv, 32))
+    v = jax.random.normal(kv_, (2, kvh, skv, 32))
+    do = jax.random.normal(kg, (2, h, sq, 32))
+    offs = jnp.asarray([[q_off, kv_off]], jnp.float32)
+    kw = dict(sm_scale=32 ** -0.5, causal=causal, block_q=blocks[0],
+              block_k=blocks[1], interpret=True,
+              static_offs=None if traced else (q_off, kv_off))
+
+    @jax.jit
+    def kernels(q, k, v, do, offs):
+        out, lse = flash_mod._fwd_impl(q, k, v, offs, **kw)
+        return flash_mod._bwd_impl(q, k, v, do, out, lse, offs, **kw)
+
+    want = jax.vjp(lambda q, k, v: flash_mod._reference(
+        q, flash_mod._expand_kv(k, h), flash_mod._expand_kv(v, h), offs,
+        sm_scale=kw["sm_scale"], causal=causal)[0], q, k, v)[1](do)
+    i = q_off + np.arange(sq)[:, None]
+    j = kv_off + np.arange(skv)[None, :]
+    live = np.ones(sq, bool) if not causal else (i >= j).any(axis=1)
+    return kernels(q, k, v, do, offs), want, live
+
+
+def _assert_bwd(got, want, live):
+    """Where every row sees a key the three gradients agree; where none
+    does (a shard above the diagonal: the reference spreads such a row
+    over every key) they are exactly 0."""
+    assert live.all() or not live.any()
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        if live.all():
+            np.testing.assert_allclose(g, w, atol=5e-4, rtol=5e-4)
+        else:
+            assert not np.asarray(g).any()
+
+
+def _assert_grads_match_the_reference(q, k, v):
+    """dq, dk, dv of sum(attention^2) through the kernels in the
+    interpreter against the same through the reference path."""
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+
+    g1 = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(functools.partial(
+        _flash_attention, force_reference=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
 def _assert_fwd(got, want, live):
     """Rows that see a key agree in out and lse; a row that sees none
     contributes exactly 0 and its lse stays at the floor (the reference
@@ -223,31 +281,109 @@ class TestFlashAttention:
     @pytest.mark.parametrize("kvh", [2, 1])
     def test_grads_with_the_forwards_blocks_and_the_backwards(self, kvh):
         """1,024 positions: the forward in its own blocks (1024 x 512),
-        dq and dkv in 256 x 512 as before, dk and dv summed over a kv
-        head's group from the unexpanded residuals."""
+        dq and dkv in theirs (`_bwd_blocks`), dk and dv summed over a kv
+        head's group in the kernel, from the unexpanded residuals."""
         q, k, v = rand_qkv(jax.random.key(14), B=1, S=1024, H=2, KVH=kvh,
                            D=32)
-        assert flash_mod._fwd_blocks(1024, 1024, 32, None) \
-            != flash_mod._BWD_BLOCKS
+        bwd = flash_mod.tileable(1024, 1024, 32,
+                                 *flash_mod._bwd_blocks(1024, 1024, 32))
+        assert 0 < bwd[0] < 1024 and flash_mod._fwd_blocks(
+            1024, 1024, 32, None) != bwd
+        _assert_grads_match_the_reference(q, k, v)
 
-        def loss(attn):
-            return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+    # -- and the backward's (PR 35) --------------------------------------
 
-        g1 = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss(functools.partial(
-            _flash_attention, force_reference=True)),
-            argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            assert a.shape == b.shape
-            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+    @pytest.mark.parametrize("offsets", sorted(FWD_OFFSETS))
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["static", "traced"])
+    @pytest.mark.parametrize("mask", ["full", "causal"])
+    def test_backward_dq_dk_dv(self, mask, traced, offsets):
+        """`_bwd_impl` against `_reference`'s vjp, K and V unexpanded (4
+        query heads a kv head, summed in dkv): no mask and the diagonal;
+        offsets the trace sees (dq on the forward's table, dkv on the
+        table by kv block) and ones it does not (runs of blocks: a ring
+        step's shard on, below and above the diagonal), and a prefix
+        call's Skv > Sq behind a `q_offset`."""
+        sq, skv, q_off, kv_off = FWD_OFFSETS[offsets]
+        _assert_bwd(*_bwd_both(
+            jax.random.key(16), sq, skv, 4, 1, causal=mask != "full",
+            q_off=q_off, kv_off=kv_off, traced=traced))
 
+    @pytest.mark.parametrize("kvh", [8, 4, 2, 1])
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["static", "traced"])
+    def test_backward_sums_the_group_in_the_kernel(self, traced, kvh):
+        """Head groups of 1 / 2 / 4 / 8: dk and dv leave as (B, KVH, Skv,
+        D), every query head of a kv head's group walked before its
+        block is written."""
+        got, want, live = _bwd_both(jax.random.key(17), 128, 128, 8, kvh,
+                                    causal=True, traced=traced)
+        assert got[1].shape == got[2].shape == (2, kvh, 128, 32)
+        _assert_bwd(got, want, live)
+
+    @pytest.mark.parametrize("kvh", [2, 1])
+    @pytest.mark.parametrize("s", [192, 320, 768])
+    def test_grads_where_the_large_block_does_not_divide(self, s, kvh):
+        """192 and 320 positions fit in one block; 768 takes 384 x 384
+        for dq and dkv under the forward's q block of all 768 rows."""
+        q, k, v = rand_qkv(jax.random.key(18), B=1, S=s, H=2, KVH=kvh,
+                           D=32)
+        assert flash_mod.tileable(s, s, 32, *flash_mod._bwd_blocks(
+            s, s, 32)) == ((384, 384) if s == 768 else (s, s))
+        _assert_grads_match_the_reference(q, k, v)
+
+    def test_backward_grid_at_the_cells_shapes(self):
+        """A head of the train cell's launch in 256 x 512 was 128 steps a
+        kernel, 72 live, every live one masked; the call's trace counts
+        what dq and dkv are given now, beside the forward's."""
+        def steps(bq, bk, by_kv):
+            return flash_mod.grid_steps(4096, 4096, bq, bk, causal=True,
+                                        by_kv=by_kv, group=2)
+
+        bq, bk = flash_mod._bwd_blocks(4096, 4096, 128)
+        assert (bq, bk) == (1024, 1024)
+        for by_kv in (False, True):
+            assert steps(256, 512, by_kv) == {
+                "steps": 72, "live_steps": 72, "masked_steps": 16}
+            assert steps(512, 512, by_kv) == {
+                "steps": 36, "live_steps": 36, "masked_steps": 8}
+            assert steps(bq, bk, by_kv) == {
+                "steps": 10, "live_steps": 10, "masked_steps": 4}
+        steps = steps(bq, bk, True)
+        q = jax.ShapeDtypeStruct((2, 4096, 16, 128), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((2, 4096, 8, 128), jnp.bfloat16)
+        before = dict(flash_mod.FLASH_GRID)
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))(q, k, k)
+        added = {n: c - before.get(n, 0)
+                 for n, c in flash_mod.FLASH_GRID.items()
+                 if c != before.get(n, 0)}
+        assert added == {
+            "steps": 2 * 16 * 20, "live_steps": 2 * 16 * 20,
+            "masked_steps": 2 * 16 * 8,
+            **{f"{kern}_{name}": 2 * 16 * c for kern in ("dq", "dkv")
+               for name, c in steps.items()}}
+        # Offsets the trace cannot see: a run of blocks, counted apart.
+        before = dict(flash_mod.FLASH_GRID)
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, q_offset=jnp.int32(0)).astype(jnp.float32))))(q, k, k)
+        assert {n: c - before.get(n, 0)
+                for n, c in flash_mod.FLASH_GRID.items()
+                if n.startswith("d") and c != before.get(n, 0)} == {
+            f"{kern}_traced_steps": 2 * 16 * (4096 // bq) * (4096 // bk)
+            for kern in ("dq", "dkv")}
+
+    @pytest.mark.parametrize("walk", ["by_q", "by_kv"])
     @pytest.mark.parametrize("case", sorted(GRID_CASES))
-    def test_grid_is_the_live_blocks(self, case):
+    def test_grid_is_the_live_blocks(self, case, walk):
         """No pair scheduled whose block holds no live pair (but the one
         step of a q block that sees nothing), every block with a live
         pair scheduled exactly once, in order, and `masked_steps` = the
-        blocks that hold a live and a dead pair."""
+        blocks that hold a live and a dead pair. `by_kv`, dkv's walk:
+        the same pairs by kv block, and the one step is of a kv block
+        that no query sees."""
         sq, skv, bq, bk, causal, window, q_off, kv_off = GRID_CASES[case]
+        by_kv = walk == "by_kv"
         i = q_off + np.arange(sq)[:, None]
         j = kv_off + np.arange(skv)[None, :]
         seen = np.ones((sq, skv), bool) if not causal else (
@@ -255,20 +391,24 @@ class TestFlashAttention:
         blocks = seen.reshape(sq // bq, bq, skv // bk, bk)
         live, whole = blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
         qi, ki, kind = flash_mod._live_pairs(
-            sq // bq, skv // bk, bq, bk, causal, window, q_off, kv_off)
+            sq // bq, skv // bk, bq, bk, causal, window, q_off, kv_off,
+            by_kv)
         pairs = list(zip(qi.tolist(), ki.tolist()))
-        assert pairs == sorted(set(pairs))
-        blind = [a for a in range(sq // bq) if not live[a].any()]
+        assert pairs == sorted(set(pairs), key=lambda p: p[::-1 if by_kv
+                                                           else 1])
+        blind = [(0, b) for b in range(skv // bk) if not live[:, b].any()
+                 ] if by_kv else [(a, 0) for a in range(sq // bq)
+                                  if not live[a].any()]
         assert set(pairs) == {(int(a), int(b))
                               for a, b in zip(*np.nonzero(live))} \
-            | {(a, 0) for a in blind}
+            | set(blind)
         for a, b, kd in zip(qi, ki, kind):
             assert kd == (flash_mod._DEAD if not live[a, b] else
                           flash_mod._INSIDE if whole[a, b] else
                           flash_mod._EDGE)
         assert flash_mod.grid_steps(
             sq, skv, bq, bk, causal=causal, window=window, q_offset=q_off,
-            kv_offset=kv_off) == {
+            kv_offset=kv_off, by_kv=by_kv) == {
                 "steps": len(pairs), "live_steps": int(live.sum()),
                 "masked_steps": int((live & ~whole).sum())}
 

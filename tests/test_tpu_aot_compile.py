@@ -138,8 +138,9 @@ CELL_FLASH = {
 def test_flash_compiles_at_the_cells_shapes(topo, cell):
     """The forward in the blocks it chooses itself, K and V unexpanded,
     its grid the table of live pairs (`FLASH_GRID`: no step without a
-    live pair); where the cell trains, dq and dkv behind it in the
-    blocks they always had."""
+    live pair); where the cell trains, dq and dkv behind it in theirs
+    (`_bwd_blocks`), on tables of their own, K and V of 8 heads in and
+    dk and dv of 8 heads out."""
     B, S, H, KVH, window, trains = CELL_FLASH[cell]
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((B, S, H, 128), jnp.bfloat16, sharding=one)
@@ -152,8 +153,10 @@ def test_flash_compiles_at_the_cells_shapes(topo, cell):
             q, k, v, causal=True, window=window, interpret=False)).lower(
                 q, k, k)
     grid = {n: c - before.get(n, 0) for n, c in fa.FLASH_GRID.items()}
-    assert grid["steps"] == grid["live_steps"] > grid["masked_steps"] > 0
-    assert not grid.get("traced_steps")
+    for kernel in ("", "dq_", "dkv_") if trains else ("",):
+        assert grid[kernel + "steps"] == grid[kernel + "live_steps"] \
+            > grid[kernel + "masked_steps"] > 0
+        assert not grid.get(kernel + "traced_steps")
     jaxpr_kernels = [e.params["metadata"]["kernel"] for e in _eqns(
         jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
             q, k, v, causal=True, window=window, interpret=False))(
@@ -165,6 +168,37 @@ def test_flash_compiles_at_the_cells_shapes(topo, cell):
     for kernel in ("flash_fwd",) + (("flash_dq", "flash_dkv")
                                     if trains else ()):
         assert f'"kernel":"{kernel}"' in text.replace(" ", "")
+    if trains:
+        _assert_backward_reads_kv_heads(text, B, S, H, KVH)
+
+
+def _flash_calls(text):
+    """{kernel: [(result shapes, operand shapes)]} of a compiled text's
+    pallas calls, by their `kernel_metadata`."""
+    calls = {}
+    for m in re.finditer(
+            r'= ([^\n]*?) custom-call\([^\n]*?custom_call_target='
+            r'"tpu_custom_call", operand_layout_constraints=\{(.*?)\}, '
+            r'frontend_attributes.*?"kernel"\s*:\s*"(\w+)"', text, re.S):
+        calls.setdefault(m[3], []).append(tuple(
+            re.findall(r"\w+\[[\d,]*\]", part) for part in m.group(1, 2)))
+    return calls
+
+
+def _assert_backward_reads_kv_heads(text, B, S, H, KVH):
+    """dq and dkv take K and V of KVH heads and dkv writes dk and dv of
+    KVH heads: nothing expands them in front or sums them behind; the
+    row statistics arrive a q block a row, not 128 lanes wide."""
+    q, kv = f"bf16[{B},{H},{S},128]", f"bf16[{B},{KVH},{S},128]"
+    calls = _flash_calls(text)
+    (dq_out, dq_in), = calls["flash_dq"]
+    (dkv_out, dkv_in), = calls["flash_dkv"]
+    assert dq_out == [q] and dkv_out == [kv, kv]
+    for operands in (dq_in, dkv_in):
+        wide = [x for x in operands if not x.startswith("s32")]
+        assert wide[:4] == [q, kv, kv, q]               # q, k, v, do
+        assert all(re.fullmatch(rf"f32\[{B},{H},\d+,1,\d+\]", x)
+                   for x in wide[4:]) and len(wide) == 6, wide
 
 
 def _eqns(jaxpr):
@@ -178,7 +212,8 @@ def test_train_step_holds_four_flash_kernels(topo, as_on_the_chip):
     """The cell's whole step (`internlm2-1b8-train-fsdp4`: 24 scanned
     layers under full remat, fsdp=4, two sequences of 4,096 a chip): the
     forward, remat's second forward, dq and dkv, each once in the
-    compiled text, and nothing expanded K and V for the forward."""
+    compiled text, and nothing expanded K and V for the forward or for
+    the backward (`_assert_backward_reads_kv_heads`)."""
     with open(os.path.join(ROOT, "benchmarks", "cells",
                            "internlm2-1b8-train-fsdp4.json")) as f:
         sizes = json.load(f)
@@ -188,6 +223,8 @@ def test_train_step_holds_four_flash_kernels(topo, as_on_the_chip):
     kernels = re.findall(r'"kernel":"(\w+)"', text.replace(" ", "")
                          .replace("\n", ""))
     assert sorted(set(kernels)) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert len(_flash_calls(text)["flash_fwd"]) == 2
+    _assert_backward_reads_kv_heads(text, 2, 4096, 16, 8)
 
 
 def _benchmark_config(name, sizes):
