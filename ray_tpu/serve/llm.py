@@ -22,6 +22,7 @@ p50 TTFT).
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -35,6 +36,7 @@ import numpy as np
 
 from ..core.graphable import graphable
 from ..models.generate import (
+    PROGRAM_NAMES,
     KVCache,
     compute_prefix_kv,
     decode_multi,
@@ -191,6 +193,12 @@ class GenRequest:
                 return list(self.tokens)
 
 
+def _ids(reqs) -> str:
+    """Request ids as a span attribute: space-separated (the profiler
+    cuts a string value at a comma)."""
+    return " ".join(str(r.id) for r in reqs)
+
+
 def _routing_sums(stats) -> Dict[str, int]:
     """A program's routing stats (`models/generate.routed_layers`) under
     the counters' names: experts hit, rows routed to the experts held and
@@ -280,7 +288,6 @@ class LLMEngine:
         self.lock = threading.Lock()
         self._work = threading.Event()
         self._stop = False
-        self._next_id = 0
         self.buckets = default_buckets(self.max_seq_len)
         # Registered prompt prefixes (system prompts): token-tuple ->
         # {"k","v"} device KV computed once; admission copies it into
@@ -318,7 +325,15 @@ class LLMEngine:
             "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
             "prefill_tile_rows": 0, "prefill_tokens": 0,
             "prefill_tile_tokens": 0, "queue_side_first_tokens": 0,
-            "cache_rows": 0, "cache_rows_held": 0}
+            "cache_rows": 0, "cache_rows_held": 0,
+            # The engine thread's clock: requests submitted, device
+            # programs it called (`engine.launch`, by program), the
+            # ticks' length and the thread's CPU time inside them
+            # (`engine.tick`: `cpu_us`), and the time it stood waiting
+            # for the device (`engine.fetch`) and for work
+            # (`engine.idle_wait`).
+            "submitted": 0, "launches": {}, "tick_ns": 0, "tick_cpu_ns": 0,
+            "fetch_wait_ns": 0, "idle_wait_ns": 0}
         # The decode blocks and the admission tiles of a stack with
         # routed layers report how their experts were used
         # (models/generate.routed_layers).
@@ -349,7 +364,6 @@ class LLMEngine:
         against it, turning the same compiled steps into SPMD programs.
         No-op (and zero-cost) for single-chip engines."""
         if self.mesh is None:
-            import contextlib
             return contextlib.nullcontext()
         return jax.sharding.set_mesh(self.mesh)
 
@@ -365,18 +379,22 @@ class LLMEngine:
         if len(prompt) >= self.max_seq_len:
             raise ValueError(
                 f"prompt len {len(prompt)} >= max_seq_len {self.max_seq_len}")
-        req = GenRequest(prompt=list(prompt), max_new_tokens=max_new_tokens,
-                         temperature=temperature, eos_token=eos_token)
-        with self.lock:
-            req.id = self._next_id
-            self._next_id += 1
-        req.submit_ts = time.monotonic()
-        req._steps_seen = self.steps_processed
-        if self.auto_prefix_min_hits > 0:
-            self._note_prefix_candidates(prompt)
-        with self.lock:
-            self.waiting.append(req)
-        self._work.set()
+        span = tracing.span("engine.submit", prompt_tokens=len(prompt))
+        with span:      # on the caller's thread
+            req = GenRequest(prompt=list(prompt),
+                             max_new_tokens=max_new_tokens,
+                             temperature=temperature, eos_token=eos_token)
+            with self.lock:
+                req.id = self.counts["submitted"]
+                self.counts["submitted"] += 1
+            span.set(req=req.id)
+            req.submit_ts = time.monotonic()
+            req._steps_seen = self.steps_processed
+            if self.auto_prefix_min_hits > 0:
+                self._note_prefix_candidates(prompt)
+            with self.lock:
+                self.waiting.append(req)
+            self._work.set()
         return req
 
     def generate(self, prompt: Sequence[int], *,
@@ -703,8 +721,30 @@ class LLMEngine:
             c["queue_side_first_tokens"] += len(reqs)
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
-            tile_rows=W, tokens=tokens,
-            req_ids=" ".join(str(r.id) for r in reqs))
+            tile_rows=W, tokens=tokens, req_ids=_ids(reqs))
+
+    def _launch_span(self, program: str) -> tracing.span:
+        """The span of one device program the engine's thread calls
+        (its transfers and the call), numbered by program: the n-th
+        `engine.launch` of a program is the n-th event of its module on
+        the device, which is how a trace joins the two."""
+        by_program = self.counts["launches"]
+        seq = by_program.get(program, 0)
+        by_program[program] = seq + 1
+        return tracing.span("engine.launch", cpu=True, program=program,
+                            seq=seq)
+
+    @contextlib.contextmanager
+    def _wait_span(self, name: str, count: str):
+        """A span in which the engine's thread only waits, for the
+        device (`engine.fetch`) or for work (`engine.idle_wait`), its
+        length added to `counts[count]`."""
+        t0 = time.monotonic_ns()
+        try:
+            with tracing.span(name):
+                yield
+        finally:
+            self.counts[count] += time.monotonic_ns() - t0
 
     @staticmethod
     def _build_tile(bucket: int, W: int, rows: Sequence):
@@ -713,14 +753,15 @@ class LLMEngine:
         Padding on the HOST: an eager .at[].set() per prompt would
         compile a scatter kernel per distinct length (seconds each);
         numpy + one transfer doesn't."""
-        buf = np.zeros((W, bucket), np.int32)
-        lens = np.ones((W,), np.int32)
-        temps = np.zeros((W,), np.float32)
-        for j, (tokens, temp) in enumerate(rows):
-            pl = len(tokens)
-            buf[j, :pl] = np.asarray(tokens, np.int32)
-            lens[j] = pl
-            temps[j] = temp
+        with tracing.span("engine.tile_build"):
+            buf = np.zeros((W, bucket), np.int32)
+            lens = np.ones((W,), np.int32)
+            temps = np.zeros((W,), np.float32)
+            for j, (tokens, temp) in enumerate(rows):
+                pl = len(tokens)
+                buf[j, :pl] = np.asarray(tokens, np.int32)
+                lens[j] = pl
+                temps[j] = temp
         return buf, lens, temps
 
     def _admit(self) -> List:
@@ -747,6 +788,13 @@ class LLMEngine:
                 take.append(self.waiting.popleft())
         if not take:
             return []
+        with tracing.span("engine.admit", cpu=True, side="slot",
+                          taken=len(take), req_ids=_ids(take)):
+            return self._admit_taken(take, free)
+
+    def _admit_taken(self, take: List[GenRequest], free: List[int]) -> List:
+        """`_admit`'s tiles, for the requests it took off the queue and
+        the free slots they go to."""
         self._touch(take)
 
         admitted: List = []  # (idx, tok_dev, lps_dev, row) — pending
@@ -777,11 +825,14 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt, req.temperature)
                              for req in reqs])
-                        self.cache, toks, lps, *moe = prefill_sample_batch(
-                            self.cfg, self.params, self.cache,
-                            jnp.asarray(buf), jnp.asarray(lens),
-                            jnp.asarray(slot_idx), self.top_k,
-                            jnp.asarray(temps), sub)
+                        with self._launch_span(
+                                PROGRAM_NAMES["prefill_sample_batch"]):
+                            self.cache, toks, lps, *moe = \
+                                prefill_sample_batch(
+                                    self.cfg, self.params, self.cache,
+                                    jnp.asarray(buf), jnp.asarray(lens),
+                                    jnp.asarray(slot_idx), self.top_k,
+                                    jnp.asarray(temps), sub)
                     self._tile_moe += moe       # routing stats (3,)
                     _copy_to_host_async(*moe)
                 else:
@@ -791,12 +842,14 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt[sp:], req.temperature)
                              for req in reqs])
-                        self.cache, toks, lps = prefill_suffix_batch(
-                            self.cfg, self.params, self.cache,
-                            entry["k"], entry["v"],
-                            jnp.asarray(buf), jnp.asarray(lens),
-                            jnp.asarray(slot_idx), self.top_k,
-                            jnp.asarray(temps), sub)
+                        with self._launch_span(
+                                PROGRAM_NAMES["prefill_suffix_batch"]):
+                            self.cache, toks, lps = prefill_suffix_batch(
+                                self.cfg, self.params, self.cache,
+                                entry["k"], entry["v"],
+                                jnp.asarray(buf), jnp.asarray(lens),
+                                jnp.asarray(slot_idx), self.top_k,
+                                jnp.asarray(temps), sub)
                     self.prefix_hits += len(chunk)
                     self.prefix_tokens_saved += sp * len(chunk)
             except Exception:
@@ -842,6 +895,12 @@ class LLMEngine:
                     if r.first_token_ts == 0.0]
         if not todo:
             return []
+        with tracing.span("engine.admit", cpu=True, side="queue",
+                          taken=len(todo), req_ids=_ids(todo)):
+            return self._first_token_tiles(todo)
+
+    def _first_token_tiles(self, todo: List[GenRequest]) -> List:
+        """`_early_first_tokens`' tiles."""
         # Queue-side compute IS a request's admission for TTFT-waterfall
         # purposes (prefill starts here).
         self._touch(todo)
@@ -857,9 +916,11 @@ class LLMEngine:
                 buf, lens, temps = self._build_tile(
                     bucket, W, [(r.prompt, r.temperature) for r in chunk])
                 self._key, sub = jax.random.split(self._key)
-                toks, lps = first_token_sample(
-                    self.cfg, self.params, jnp.asarray(buf),
-                    jnp.asarray(lens), jnp.asarray(temps), self.top_k, sub)
+                with self._launch_span(PROGRAM_NAMES["first_token_sample"]):
+                    toks, lps = first_token_sample(
+                        self.cfg, self.params, jnp.asarray(buf),
+                        jnp.asarray(lens), jnp.asarray(temps), self.top_k,
+                        sub)
                 if W < self._ADMIT_TILE:
                     toks, lps = (jnp.pad(x, (0, self._ADMIT_TILE - W))
                                  for x in (toks, lps))
@@ -875,10 +936,12 @@ class LLMEngine:
                     bucket, W, [(r.prompt[sp:], r.temperature)
                                 for r in chunk])
                 self._key, sub = jax.random.split(self._key)
-                toks, lps = first_token_suffix_sample(
-                    self.cfg, self.params, entry["k"], entry["v"],
-                    jnp.asarray(buf), jnp.asarray(lens),
-                    jnp.asarray(temps), self.top_k, sub)
+                with self._launch_span(
+                        PROGRAM_NAMES["first_token_suffix_sample"]):
+                    toks, lps = first_token_suffix_sample(
+                        self.cfg, self.params, entry["k"], entry["v"],
+                        jnp.asarray(buf), jnp.asarray(lens),
+                        jnp.asarray(temps), self.top_k, sub)
             self.prefix_hits += len(chunk)
             self.prefix_tokens_saved += sp * len(chunk)
             _copy_to_host_async(lps)
@@ -915,7 +978,8 @@ class LLMEngine:
         span = tracing.span("engine.deliver_first", tokens=len(admitted)
                             + sum(len(reqs) for reqs, _, _ in outs))
         with span:
-            with tracing.span("engine.fetch"):  # the host waits here
+            # the host waits here
+            with self._wait_span("engine.fetch", "fetch_wait_ns"):
                 fused = np.asarray(fused)
                 fused_lp = np.concatenate(
                     [np.asarray(lps)[j:j + 1] for _, _, lps, j in admitted]
@@ -932,7 +996,23 @@ class LLMEngine:
                 for name, n in routed.items():
                     self.counts[name] += n
                 span.set(moe_tiles=len(tile_moe), **routed)
-            self._emit_first_tokens(fused, fused_lp, admitted, outs)
+            slots = (self.slots[idx] for idx, _, _, _ in admitted)
+            first = [s.req for s in slots if s is not None] \
+                + [r for reqs, _, _ in outs for r in reqs]
+            with self._emit_span(first=1, req_ids=_ids(first)):
+                self._emit_first_tokens(fused, fused_lp, admitted, outs)
+
+    @contextlib.contextmanager
+    def _emit_span(self, **attributes):
+        """The span of handing a block's tokens, or a tick's first
+        tokens, to their requests: how many, and how many requests that
+        ended."""
+        tokens, finished = self.tokens_out, self._n_finished
+        span = tracing.span("engine.emit", cpu=True, **attributes)
+        with span:
+            yield
+            span.set(tokens=self.tokens_out - tokens,
+                     finished=self._n_finished - finished)
 
     def _emit_first_tokens(self, fused, fused_lp, admitted: List,
                            outs: List) -> None:
@@ -983,12 +1063,19 @@ class LLMEngine:
         exact; the host only lags by one block in observing tokens, so
         EOS/finish frees a slot one tick late (bounded overshoot, same
         class as mid-block overshoot). Returns False when idle."""
-        tick = self.counts["ticks"]
-        self.counts["ticks"] = tick + 1
-        with self._mesh_ctx(), tracing.span(
-                "engine.tick", tick=tick, waiting=len(self.waiting),
-                active=sum(s is not None for s in self.slots)):
-            return self._step_impl()
+        c = self.counts
+        tick = c["ticks"]
+        c["ticks"] = tick + 1
+        span = tracing.span(
+            "engine.tick", cpu=True, tick=tick, waiting=len(self.waiting),
+            active=sum(s is not None for s in self.slots))
+        t0 = time.monotonic_ns()
+        try:
+            with self._mesh_ctx(), span:
+                return self._step_impl()
+        finally:
+            c["tick_ns"] += time.monotonic_ns() - t0
+            c["tick_cpu_ns"] += span.attributes.get("cpu_us", 0) * 1000
 
     def _step_impl(self) -> bool:
         registered = (self._drain_auto_registrations()
@@ -1067,26 +1154,30 @@ class LLMEngine:
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held):
-            self._key, sub = jax.random.split(self._key)
-            live = jnp.asarray(owned)
-            moe = None
-            if k_block == 1 and not self._routed_layers:
-                self.cache, logits = decode_step(
-                    self.cfg, self.params, self.cache,
-                    self.cur_tokens, live)
-                toks, lps = _sample_batch(logits, self._temps, sub,
-                                          self.top_k)
-                toks = toks[None]                          # (1, B)
-            else:
-                self.cache, toks, lps, *moe = decode_multi(
-                    self.cfg, self.params, self.cache,
-                    self.cur_tokens, self._temps, k_block,
-                    self.top_k, sub, live)                 # (k, B)
-                moe = moe[0] if moe else None   # routing stats (3,)
-            self.cur_tokens = toks[-1]
-            # Start the host copy NOW, before the next tick enqueues
-            # prefills and the next block behind it.
-            _copy_to_host_async(toks, lps, moe)
+            # Everything the block asks of the device: the key's split,
+            # the transfer, the program, the slice and the copies' start.
+            with self._launch_span(
+                    PROGRAM_NAMES["decode_multi"].format(k=k_block)):
+                self._key, sub = jax.random.split(self._key)
+                live = jnp.asarray(owned)
+                moe = None
+                if k_block == 1 and not self._routed_layers:
+                    self.cache, logits = decode_step(
+                        self.cfg, self.params, self.cache,
+                        self.cur_tokens, live)
+                    toks, lps = _sample_batch(logits, self._temps, sub,
+                                              self.top_k)
+                    toks = toks[None]                      # (1, B)
+                else:
+                    self.cache, toks, lps, *moe = decode_multi(
+                        self.cfg, self.params, self.cache,
+                        self.cur_tokens, self._temps, k_block,
+                        self.top_k, sub, live)             # (k, B)
+                    moe = moe[0] if moe else None   # routing stats (3,)
+                self.cur_tokens = toks[-1]
+                # Start the host copy NOW, before the next tick enqueues
+                # prefills and the next block behind it.
+                _copy_to_host_async(toks, lps, moe)
             self.decode_ticks += k_block
             for i in active:
                 snap[i].inflight += k_block
@@ -1105,14 +1196,16 @@ class LLMEngine:
         span = tracing.span("engine.process_block", block=number, k=k_block,
                             slots=self.num_slots, active=len(slot_snap))
         with span:
-            with tracing.span("engine.fetch"):  # the host waits here
+            # the host waits here
+            with self._wait_span("engine.fetch", "fetch_wait_ns"):
                 host_toks = np.asarray(toks)
                 # (B,) after a one-step block's own sampler
                 host_lps = np.asarray(lps).reshape(host_toks.shape)
                 host_moe = np.asarray(moe) if moe is not None else None
             self.steps_processed += k_block
             before = self.tokens_out
-            self._emit_block(host_toks, host_lps, k_block, slot_snap)
+            with self._emit_span():
+                self._emit_block(host_toks, host_lps, k_block, slot_snap)
             emitted = self.tokens_out - before
             discarded = k_block * len(slot_snap) - emitted
             self.counts["tokens_discarded"] += discarded
@@ -1158,7 +1251,7 @@ class LLMEngine:
                 raise
             if not busy:
                 self._work.clear()
-                with tracing.span("engine.idle_wait"):
+                with self._wait_span("engine.idle_wait", "idle_wait_ns"):
                     self._work.wait(timeout=0.1)
 
     def _fail_all(self, exc: Exception) -> None:
@@ -1181,8 +1274,13 @@ class LLMEngine:
             req.stream.put(None)
 
     def start(self) -> threading.Thread:
-        t = threading.Thread(target=self.run_forever, daemon=True,
-                             name="llm-engine")
+        def loop() -> None:
+            # Before its first span: a profile then keeps this thread's
+            # spans apart from its callers' (`engine.submit`).
+            tracing.name_thread("llm-engine")
+            self.run_forever()
+
+        t = threading.Thread(target=loop, daemon=True, name="llm-engine")
         self._loop_thread = t
         t.start()
         return t
@@ -1205,7 +1303,8 @@ class LLMEngine:
         out: Dict[str, Any] = {
             "finished": self._n_finished,
             "counts": dict(self.counts,
-                           blocks_by_k=dict(self.counts["blocks_by_k"])),
+                           blocks_by_k=dict(self.counts["blocks_by_k"]),
+                           launches=dict(self.counts["launches"])),
             "decode_ticks": self.decode_ticks,
             "tokens_out": self.tokens_out,
             "waiting": len(self.waiting),

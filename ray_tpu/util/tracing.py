@@ -155,6 +155,21 @@ def clear_tracing() -> None:
 PROFILER_PREFIX = "ray_tpu:"
 
 
+def name_thread(name: str) -> None:
+    """Give the calling thread `name` (its first 15 bytes) as the OS
+    knows it. A profiler session labels a thread's line of spans by that
+    name, and Python leaves every thread its process's: two threads of
+    one name read as one timeline, and their spans nest into each other.
+    Call it before the thread's first span. Linux only (the thread's
+    `comm` file); elsewhere, and on any failure, nothing happens."""
+    try:
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm",
+                  "wb") as f:
+            f.write(name.encode()[:15])
+    except OSError:
+        pass
+
+
 def _recording() -> bool:
     """Whether a finished span has anywhere to go besides the profiler:
     an exporter hook, or the timeline of a runtime in this process."""
@@ -186,15 +201,23 @@ class span:
     With neither a hook nor a timeline to record into, nothing else
     happens and the id is `None`: no ids, no environment read, no hash.
     `set()` adds attributes that are known only before the span ends.
+
+    `cpu=True` adds the attribute `cpu_us` at exit, to both sinks: the
+    CPU time this thread spent inside the span, its children's included
+    (`time.thread_time_ns`). The span's length less `cpu_us` is time the
+    thread stood blocked: in a call that waits, or for the interpreter
+    lock. Two clock reads; off by default.
     """
 
     __slots__ = ("name", "category", "attributes", "_annotation", "_id",
-                 "_parent", "_trace_id", "_tokens", "_ts", "_t0")
+                 "_parent", "_trace_id", "_tokens", "_ts", "_t0", "_cpu0")
 
-    def __init__(self, name: str, category: str = "span", **attributes):
+    def __init__(self, name: str, category: str = "span", cpu: bool = False,
+                 **attributes):
         self.name, self.category = name, category
         self.attributes = attributes
         self._annotation = self._id = None
+        self._cpu0 = 0 if cpu else None
 
     def set(self, **attributes) -> None:
         self.attributes.update(attributes)
@@ -211,22 +234,27 @@ class span:
             self._annotation = annotation(
                 PROFILER_PREFIX + self.name, **self.attributes)
             self._annotation.__enter__()
-        if not _recording():
-            return None
-        self._id = uuid.uuid4().hex[:16]
-        self._parent = _current_span.get()
-        self._trace_id = _current_trace.get()
-        trace_token = None
-        if self._trace_id is None:
-            self._trace_id = uuid.uuid4().hex[:16]
-            trace_token = _current_trace.set(self._trace_id)
-        self._tokens = (_current_span.set(self._id), trace_token)
-        # `ts` is wall time so that merged chrome traces line up across
-        # processes; the duration comes from a clock that cannot step.
-        self._ts, self._t0 = time.time(), time.monotonic()
+        if _recording():
+            self._id = uuid.uuid4().hex[:16]
+            self._parent = _current_span.get()
+            self._trace_id = _current_trace.get()
+            trace_token = None
+            if self._trace_id is None:
+                self._trace_id = uuid.uuid4().hex[:16]
+                trace_token = _current_trace.set(self._trace_id)
+            self._tokens = (_current_span.set(self._id), trace_token)
+            # `ts` is wall time so that merged chrome traces line up
+            # across processes; the duration comes from a clock that
+            # cannot step.
+            self._ts, self._t0 = time.time(), time.monotonic()
+        # Read last here and first at exit: inside both sinks' spans.
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time_ns()
         return self._id
 
     def __exit__(self, *exc) -> bool:
+        if self._cpu0 is not None:
+            self.set(cpu_us=(time.thread_time_ns() - self._cpu0) // 1000)
         if self._id is not None:
             dur = time.monotonic() - self._t0
             span_token, trace_token = self._tokens

@@ -70,57 +70,75 @@ def pytest_collection_finish(session):
         _tell_of_entries_appended_since(mod)
 
 
-def _tell_of_entries_appended_since(mod):
-    """A test file a PR added with its cell (tests/benchmark/
-    test_afmoe_cell.py) holds that PR's per-layer entries (`NEW_METRICS`,
-    `ENTRIES["per_layer"]`) to be the last of BENCHMARK.json. Later PRs may
-    only append behind them, and may not edit the file: what the
-    benchmark lists behind the file's own last entry is added to its two
-    lists here, so that it goes on holding its entries to be whole, in
-    order, and followed by nothing but appended ones."""
-    new, entries = getattr(mod, "NEW_METRICS", None), getattr(
-        mod, "ENTRIES", None)
-    if not isinstance(new, list) or not isinstance(entries, dict) \
-            or getattr(mod, "_told_of_later_entries", False):
-        return
-    mod._told_of_later_entries = True
+def _per_layer():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    names = [m["name"] for m in per_layer]
-    if new and new[-1][0] in names:
-        later = per_layer[names.index(new[-1][0]) + 1:]
-        entries["per_layer"].extend(later)
-        new.extend((m["name"], m["unit"], m["better"], m["source"],
-                    m["layer"]) for m in later)
+        return json.load(f)["per_layer"]
+
+
+def _tell_of_entries_appended_since(mod):
+    """A test file a PR added with its cell (tests/benchmark/
+    test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py) names
+    its cell (`REAL`) and the per-layer entries it appended
+    (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
+    lists its cell to be one it knew (`listed == ...`, `spec.metrics(
+    "per_layer") == ...`, "REAL in no other metric's `workloads`").
+    Later PRs may only append behind its entries, and may not edit the
+    file. An entry appended since that lists the file's cell is added to
+    its names here: it goes on holding that nothing but appended entries
+    and the ones it knew list its cell, and that each one's reader loads.
+    Its own entries it holds whole and in order against the `bench`
+    fixture, which `pytest_runtest_call` cuts behind the file's own last
+    entry (`_own_last`)."""
+    names = getattr(mod, "NEW_READERS", None) or getattr(
+        mod, "NEW_NAMES", None)
+    cell = getattr(mod, "REAL", None)
+    if not isinstance(names, list) or not isinstance(cell, str) \
+            or hasattr(mod, "_own_last"):
+        return
+    per_layer = _per_layer()
+    order = [m["name"] for m in per_layer]
+    mod._own_last = max((order.index(n) for n in names if n in order),
+                        default=len(order) - 1)
+    names.extend(m["name"] for m in per_layer[mod._own_last + 1:]
+                 if cell in m.get("workloads", ()))
 
 
 @pytest.hookimpl(tryfirst=True)
 def pytest_runtest_call(item):
-    """The same file holds its PR's `configs` and `workloads` entries
-    (`ENTRIES["config"]`, `ENTRIES["workload"]`) to be the last of their
-    lists, and a later PR's go behind them (the driver reads an entry put
-    in the middle as a change to what was there). Its tests see the two
-    lists as that PR left them: cut behind its own entry, nothing else
-    touched, so an entry edited, moved or taken away still fails. The
-    same for every metric's `workloads` list its cell is in: it holds its
-    cell to be the last name there (and its own metrics to list its cell
-    alone), and a later cell that the same reader fits goes behind it."""
-    entries = getattr(getattr(item, "module", None), "ENTRIES", None)
+    """A frozen file's tests see `per_layer` as its PR left it: the
+    `bench` fixture cut behind the file's own last entry, nothing else
+    touched, so an entry of its own edited, moved or taken away still
+    fails.
+
+    tests/benchmark/test_afmoe_cell.py also holds its PR's `configs` and
+    `workloads` entries (`ENTRIES["config"]`, `ENTRIES["workload"]`) to
+    be the last of their lists, and a later PR's go behind them (the
+    driver reads an entry put in the middle as a change to what was
+    there): the two lists are cut behind its own entry in the same way.
+    The same for every metric's `workloads` list its cell is in: it holds
+    its cell to be the last name there (and its own metrics to list its
+    cell alone), and a later cell that the same reader fits goes behind
+    it."""
+    mod = getattr(item, "module", None)
     bench = getattr(item, "funcargs", {}).get("bench")
-    if not isinstance(entries, dict) or not isinstance(bench, dict):
+    own_last = getattr(mod, "_own_last", None)
+    if own_last is None or not isinstance(bench, dict):
         return
-    seen = dict(bench)
-    for key, mine in (("configs", entries.get("config")),
-                      ("workloads", entries.get("workload"))):
-        if mine in bench.get(key, ()):
-            seen[key] = bench[key][:bench[key].index(mine) + 1]
-    cell = (entries.get("workload") or {}).get("name")
-    for kind in ("end_to_end", "per_layer"):
-        seen[kind] = [
-            dict(m, workloads=m["workloads"][:m["workloads"].index(cell) + 1])
-            if cell in m.get("workloads", ()) else m
-            for m in bench.get(kind, ())]
+    seen = dict(bench, per_layer=bench["per_layer"][:own_last + 1])
+    entries = getattr(mod, "ENTRIES", None)
+    if isinstance(entries, dict):
+        for key, mine in (("configs", entries.get("config")),
+                          ("workloads", entries.get("workload"))):
+            if mine in bench.get(key, ()):
+                seen[key] = bench[key][:bench[key].index(mine) + 1]
+        cell = (entries.get("workload") or {}).get("name")
+        for kind in ("end_to_end", "per_layer"):
+            seen[kind] = [
+                dict(m, workloads=m["workloads"][
+                    :m["workloads"].index(cell) + 1])
+                if cell in m.get("workloads", ()) else m
+                for m in seen[kind]]
     item.funcargs["bench"] = seen
 
 

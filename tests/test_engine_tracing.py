@@ -113,6 +113,66 @@ def test_a_span_does_not_swallow_an_exception(hook):
     assert tracing.current_span_id() is None
 
 
+def test_a_cpu_span_carries_the_threads_cpu_time_to_both_sinks(
+        hook, annotations):
+    with tracing.span("outer", cpu=True, a=1):
+        with tracing.span("inner"):
+            sum(range(20_000))
+    inner_ev, outer_ev = hook
+    assert "cpu_us" not in inner_ev["args"]           # off by default
+    assert 0 < outer_ev["args"]["cpu_us"] <= outer_ev["dur"]
+    assert annotations[0].kw == {"a": 1,
+                                 "cpu_us": outer_ev["args"]["cpu_us"]}
+    assert "cpu" not in annotations[0].kw and annotations[1].kw == {}
+
+
+def test_a_cpu_span_with_no_hook_and_no_session_costs_two_clock_reads(
+        monkeypatch):
+    tracing.clear_tracing()
+    monkeypatch.setattr(uuid, "uuid4", _boom)
+    monkeypatch.setattr(tracing, "trace_sampled", _boom)
+    sp = tracing.span("engine.tick", cpu=True, tick=3)
+    with sp as span_id:
+        pass
+    assert span_id is None and sp.attributes["cpu_us"] >= 0
+    with pytest.raises(KeyError):
+        with tracing.span("boom", cpu=True):
+            raise KeyError("x")
+
+
+def _os_name(native_id):
+    with open(f"/proc/self/task/{native_id}/comm") as f:
+        return f.read().strip()
+
+
+def test_a_thread_names_itself_to_the_os_and_no_other_thread():
+    """What a profiler session labels a thread's spans by."""
+    before = _os_name(threading.get_native_id())
+    seen = []
+    t = threading.Thread(target=lambda: (
+        tracing.name_thread("a-name-longer-than-15-bytes"),
+        seen.append(_os_name(threading.get_native_id()))))
+    t.start()
+    t.join()
+    assert seen == ["a-name-longer-t"]
+    assert _os_name(threading.get_native_id()) == before
+
+
+def test_the_engines_thread_has_a_name_of_its_own(tiny_model):
+    """`engine.submit` is a span of the caller's thread: a profile keeps
+    the engine's spans apart from it by the thread's name."""
+    engine = _engine(tiny_model)
+    thread = engine.start()
+    try:
+        req = engine.submit([1, 2, 3], max_new_tokens=2)
+        assert len(req.result(timeout=120)) == 2
+        assert _os_name(thread.native_id) == "llm-engine"
+        assert _os_name(threading.get_native_id()) != "llm-engine"
+    finally:
+        engine.stop()
+        thread.join(timeout=60)
+
+
 def _drain(engine, reqs):
     for _ in range(10_000):
         if all(r.finish_ts for r in reqs):
@@ -143,22 +203,113 @@ def test_engine_spans_nest_under_their_tick_and_share_its_trace(drained):
     _, _, events = drained
     by_id = {e["tid"].split(":", 1)[1]: e for e in events}
     names = {e["name"] for e in events}
-    assert {"engine.tick", "engine.prefill_tile", "engine.fuse_first",
+    assert {"engine.tick", "engine.admit", "engine.prefill_tile",
+            "engine.tile_build", "engine.launch", "engine.fuse_first",
             "engine.dispatch_block", "engine.deliver_first",
-            "engine.process_block", "engine.fetch"} <= names
+            "engine.process_block", "engine.fetch", "engine.emit",
+            "engine.submit"} == names
+    parents = {
+        "engine.admit": {"engine.tick"},
+        "engine.dispatch_block": {"engine.tick"},
+        "engine.process_block": {"engine.tick"},
+        "engine.deliver_first": {"engine.tick"},
+        "engine.fuse_first": {"engine.tick"},
+        "engine.prefill_tile": {"engine.admit"},
+        "engine.tile_build": {"engine.prefill_tile"},
+        "engine.launch": {"engine.prefill_tile", "engine.dispatch_block"},
+        "engine.fetch": {"engine.process_block", "engine.deliver_first"},
+        "engine.emit": {"engine.process_block", "engine.deliver_first"}}
     for e in events:
-        if e["name"] in ("engine.prefill_tile", "engine.dispatch_block",
-                         "engine.process_block", "engine.deliver_first"):
+        if e["name"] in parents:
             parent = by_id[e["args"]["parent"]]
-            assert parent["name"] == "engine.tick"
+            assert parent["name"] in parents[e["name"]], e["name"]
             assert parent["args"]["trace_id"] == e["args"]["trace_id"]
-        if e["name"] == "engine.fetch":
-            assert by_id[e["args"]["parent"]]["name"] in (
-                "engine.process_block", "engine.deliver_first")
+        elif e["name"] == "engine.submit":      # the caller's, a root
+            assert e["args"]["parent"] is None
     ticks = [e["args"]["tick"] for e in events if e["name"] == "engine.tick"]
     assert sorted(ticks) == list(range(len(ticks)))
-    # No span per token or per slot.
-    assert not names & {"engine.emit", "engine.slot"}
+    # No span per token or per slot: one emit a block, one a tick's
+    # first tokens.
+    count = {n: sum(e["name"] == n for e in events) for n in names}
+    assert count["engine.emit"] == (count["engine.process_block"]
+                                    + count["engine.deliver_first"])
+    assert count["engine.launch"] == (count["engine.prefill_tile"]
+                                      + count["engine.dispatch_block"])
+    assert count["engine.tile_build"] == count["engine.prefill_tile"]
+
+
+def test_the_new_spans_carry_their_attributes(drained):
+    engine, reqs, events = drained
+
+    def spans(name):
+        return [e for e in events if e["name"] == name]
+
+    keys = {"parent", "trace_id"}
+    for e in spans("engine.submit"):
+        assert set(e["args"]) == keys | {"req", "prompt_tokens"}
+    assert [(e["args"]["req"], e["args"]["prompt_tokens"])
+            for e in spans("engine.submit")] == [
+        (r.id, len(r.prompt)) for r in reqs]
+    for e in spans("engine.admit"):
+        a = e["args"]
+        assert set(a) == keys | {"side", "taken", "req_ids", "cpu_us"}
+        assert a["side"] in ("slot", "queue")
+        assert a["taken"] == len(a["req_ids"].split()) > 0
+    assert {e["args"]["side"] for e in spans("engine.admit")} == {
+        "slot", "queue"}
+    assert all(set(e["args"]) == keys for e in spans("engine.tile_build"))
+    programs = set()
+    for e in spans("engine.launch"):
+        assert set(e["args"]) == keys | {"program", "seq", "cpu_us"}
+        programs.add(e["args"]["program"])
+    assert {"prefill_sample_batch", "first_token_sample"} <= programs
+    assert programs - {"prefill_sample_batch", "first_token_sample"} <= {
+        f"decode_k{k}" for k in (1, 2, 4, 8)}
+    for e in spans("engine.emit"):
+        a = e["args"]
+        first = {"first", "req_ids"} if "first" in a else set()
+        assert set(a) == keys | {"tokens", "finished", "cpu_us"} | first
+        if first:
+            assert a["first"] == 1 and a["tokens"] == len(a["req_ids"].split())
+    assert sum(e["args"]["finished"] for e in spans("engine.emit")) \
+        == len(reqs)
+    assert all("cpu_us" in e["args"] for e in spans("engine.tick"))
+    # CPU time is a part of the span's length, and a tick that handed
+    # tokens over spent some.
+    by_id = {e["tid"].split(":", 1)[1]: e for e in events}
+    emitting = {by_id[e["args"]["parent"]]["args"]["parent"]
+                for e in spans("engine.emit") if e["args"]["tokens"]}
+    for e in events:
+        if "cpu_us" in e["args"]:
+            assert 0 <= e["args"]["cpu_us"] <= e["dur"], e["name"]
+    for tick in spans("engine.tick"):
+        if tick["tid"].split(":", 1)[1] in emitting:
+            assert tick["args"]["cpu_us"] > 0
+
+
+def test_one_request_shares_its_id_from_submit_to_its_first_token(drained):
+    _, reqs, events = drained
+    by_id = {e["tid"].split(":", 1)[1]: e for e in events}
+
+    def holding(name, rid, **where):
+        return [e for e in events if e["name"] == name
+                and str(rid) in e["args"].get("req_ids", "").split()
+                and all(e["args"].get(k) == v for k, v in where.items())]
+
+    for r in reqs:
+        submit, = [e for e in events if e["name"] == "engine.submit"
+                   and e["args"]["req"] == r.id]
+        # The tile whose program gives the first token: the earliest.
+        tile = min(holding("engine.prefill_tile", r.id),
+                   key=lambda e: e["ts"])
+        launch, = [e for e in events if e["name"] == "engine.launch"
+                   and by_id[e["args"]["parent"]] is tile]
+        emit, = holding("engine.emit", r.id, first=1)
+        assert holding("engine.admit", r.id)
+        assert submit["ts"] <= tile["ts"] <= launch["ts"] <= emit["ts"]
+        assert launch["args"]["program"] == (
+            "prefill_sample_batch" if tile["args"]["side"] == "slot"
+            else "first_token_sample")
 
 
 def test_counts_equal_the_sums_of_the_span_attributes(drained):
@@ -186,6 +337,24 @@ def test_counts_equal_the_sums_of_the_span_attributes(drained):
                                            for t in tiles)
     assert c["queue_side_first_tokens"] == sum(
         t["rows"] for t in tiles if t["side"] == "queue") > 0
+    # The engine thread's clock.
+    launches = spans("engine.launch")
+    assert c["submitted"] == len(spans("engine.submit")) == len(reqs)
+    assert c["launches"] == {p: sum(x["program"] == p for x in launches)
+                             for p in {x["program"] for x in launches}}
+    for p, n in c["launches"].items():      # numbered without a hole
+        assert [x["seq"] for x in launches if x["program"] == p] \
+            == list(range(n))
+    assert c["tick_cpu_ns"] == 1000 * sum(
+        t["cpu_us"] for t in spans("engine.tick")) > 0
+    # Read outside the spans they sum: never less.
+    durs = {n: 1000 * sum(e["dur"] for e in events if e["name"] == n)
+            for n in ("engine.tick", "engine.fetch")}
+    assert c["tick_ns"] >= durs["engine.tick"] > 0
+    assert c["tick_ns"] >= c["tick_cpu_ns"]
+    assert c["fetch_wait_ns"] >= durs["engine.fetch"] > 0
+    assert c["idle_wait_ns"] == 0           # no `run_forever` here
+    assert engine.stats()["counts"]["launches"] is not c["launches"]
     assert sum(k * n for k, n in c["blocks_by_k"].items()) \
         == engine.decode_ticks == engine.steps_processed
     # Every token is a first token or a block's.
