@@ -28,7 +28,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from ..parallel.sharding import logical_to_mesh_axes
 from ..parallel.sharding import with_sharding_constraint as wsc
 
 
@@ -127,8 +129,13 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32   # master weights
     tie_embeddings: bool = True
     remat: bool = True
-    # None = full per-layer remat; "dots" = save matmul outputs and
-    # recompute only elementwise ops (less recompute, more HBM).
+    # None = a layer recomputes in its backward what does not fit: where
+    # `remat_fits` (the kept bytes and the training state a device
+    # within `REMAT_DEVICE_BYTES`) the attention half is kept (`_remat`:
+    # q, k, v, the flash output, its row statistic and the stream behind
+    # attention) and only the FFN's norm, two products and activation
+    # run again; where not, full per-layer remat. "dots" = save matmul outputs and recompute only
+    # elementwise ops (less recompute, more HBM).
     remat_policy: Optional[str] = None
     # >0: blockwise vocab-projection + cross entropy with this chunk
     # size — the f32 (B, S, V) logits tensor is never materialized
@@ -324,6 +331,12 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
+def _mesh_sizes() -> Dict[str, int]:
+    """{axis: size} of the mesh a function is traced under; {} with none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return dict(getattr(mesh, "shape", None) or {})
+
+
 def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
             v: jax.Array) -> jax.Array:
     """Dispatch causal attention to the right kernel for the ambient mesh.
@@ -337,13 +350,12 @@ def _attend(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     never an all-gather of the sequence.
     """
     from ..ops import flash_attention, ring_attention, ulysses_attention
-    from ..parallel.sharding import logical_to_mesh_axes
 
     if cfg.attn_impl == "reference":
         return flash_attention(q, k, v, causal=True, force_reference=True)
 
     mesh = jax.sharding.get_abstract_mesh()
-    sizes = dict(getattr(mesh, "shape", None) or {})
+    sizes = _mesh_sizes()
     used = {a for a, n in sizes.items() if n > 1} & {
         "dcn", "dp", "fsdp", "ep", "tp", "sp"}
     if not used:
@@ -442,11 +454,105 @@ def moe_ffn(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     return out.reshape(B, S, D), aux
 
 
+# What a layer's `jax.checkpoint` keeps by name where it fits: the flash
+# call's residuals (`ops/flash_attention.RESIDUAL_NAMES`: without all
+# five its forward kernel runs again) and the stream behind attention
+# (without it `wo` is multiplied again to feed the FFN's norm).
+ATTN_STREAM = "attn_stream"
+
+# What a training step holds of each parameter: the weight, its gradient
+# and Adam's two moments (`train/step.make_optimizer`), in its dtype.
+STATE_COPIES = 4
+
+# The most the kept values and that state may take of a device: the
+# 15.75e9 a step compiled for the smallest chip this trains on (v5e, 16
+# GiB) is held to, less the 4e9 a step under full remat needs beside its
+# state at 8,192 tokens a device (the described compile: 4.06e9 at
+# internlm2-1.8b over fsdp=4, 3.24e9 at llama-654m and 3.87e9 at
+# llama-1b4 on one chip). internlm2-1.8b over fsdp=4 at 2 x 4,096 tokens
+# a device holds 7.56e9 of state and keeps 3.23e9: it passes, and
+# compiles to 14.88e9; at three sequences a device it does not. llama-654m
+# at 8 x 1,024 on one chip (10.47e9 + 1.48e9) does not either, and
+# should not: there XLA holds the kept values twice.
+REMAT_DEVICE_BYTES = int(15.75e9 - 4e9)
+
+
+def _shards(logical: Tuple[Optional[str], ...],
+            mesh_sizes: Dict[str, int]) -> int:
+    """Into how many parts a mesh of `mesh_sizes` ({axis: size}) cuts a
+    value of these logical axes."""
+    n = 1
+    for target in logical_to_mesh_axes(logical):
+        for axis in (target,) if isinstance(target, str) else target or ():
+            n *= mesh_sizes.get(axis, 1)
+    return n
+
+
+def remat_kept_bytes(cfg: TransformerConfig, batch: int, seq: int,
+                     mesh_sizes: Dict[str, int]) -> int:
+    """Bytes a device that the flash call's residuals and `ATTN_STREAM`
+    hold for a (batch, seq) step over the stack's layers: each value's
+    width a token in the activation dtype (the row statistic in float32)
+    over the mesh axes its logical axes are sharded on."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    a_token = (
+        (2 * H * Dh * item + 4 * H, "act_heads"),       # q, out; lse
+        (2 * KVH * Dh * item, "act_kv_heads"),          # k, v
+        (cfg.d_model * item, "act_embed"))              # the stream
+    return cfg.n_layers * sum(
+        -(-batch * seq * width // _shards(("batch", "seq", last),
+                                          mesh_sizes))
+        for width, last in a_token)
+
+
+def param_bytes(cfg: TransformerConfig, params: Dict[str, Any],
+                mesh_sizes: Dict[str, int]) -> int:
+    """Bytes a device of `params` (arrays or their shapes) under the
+    stack's `param_logical_axes`."""
+    def is_axes(x):
+        return isinstance(x, tuple)
+
+    parts = jax.tree.map(
+        lambda axes, p: -(-p.size * jnp.dtype(p.dtype).itemsize
+                          // _shards(axes, mesh_sizes)),
+        param_logical_axes(cfg), params, is_leaf=is_axes)
+    return sum(jax.tree.leaves(parts))
+
+
+def remat_fits(cfg: TransformerConfig, params: Dict[str, Any], batch: int,
+               seq: int, mesh_sizes: Dict[str, int]) -> bool:
+    """Whether a (batch, seq) training step of `params` on this mesh has
+    room to keep the attention half of every layer."""
+    return remat_kept_bytes(cfg, batch, seq, mesh_sizes) \
+        + STATE_COPIES * param_bytes(cfg, params, mesh_sizes) \
+        <= REMAT_DEVICE_BYTES
+
+
+def _remat(cfg: TransformerConfig, layer, fits: bool):
+    """`layer` under the checkpoint `cfg.remat_policy` asks for; with
+    none named, one that keeps the attention half where it `fits`."""
+    if cfg.remat_policy == "dots":
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    elif cfg.remat_policy is not None:
+        raise ValueError(
+            f"remat_policy must be None or 'dots', got "
+            f"{cfg.remat_policy!r}")
+    elif fits:
+        from ..ops.flash_attention import RESIDUAL_NAMES
+
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES, ATTN_STREAM)
+    else:
+        policy = None
+    return jax.checkpoint(layer, policy=policy)
+
+
 def _layer(cfg: TransformerConfig, carry, lp):
     x, sin, cos = carry
     a = attention(cfg, lp, rms_norm(x, lp["attn_norm"], cfg.norm_eps),
                   sin, cos)
-    x = x + a
+    x = checkpoint_name(x + a, ATTN_STREAM)
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if cfg.is_moe:
         f, aux = moe_ffn(cfg, lp, h)
@@ -478,15 +584,8 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
 
     layer = partial(_layer, cfg)
     if cfg.remat:
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        elif cfg.remat_policy is None:
-            policy = None
-        else:
-            raise ValueError(
-                f"remat_policy must be None or 'dots', got "
-                f"{cfg.remat_policy!r}")
-        layer = jax.checkpoint(layer, policy=policy)
+        layer = _remat(cfg, layer, remat_fits(cfg, params, B, S,
+                                              _mesh_sizes()))
     (x, _, _), aux = lax.scan(layer, (x, sin, cos), params["layers"])
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,6 +57,14 @@ DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
 # of the others; the forward's under those names, the backward's two
 # kernels' under `dq_` and `dkv_` + the same.
 FLASH_GRID: "collections.Counter[str]" = collections.Counter()
+
+# The names (`jax.ad_checkpoint.checkpoint_name`) of what the backward
+# rule reads, as the kernels take them: q (B, H, S, D), k and v (B, KVH, S,
+# D), the output and its row statistic (B, H, S) float32. A caller's
+# `jax.checkpoint` that saves all five (`save_only_these_names`) keeps
+# the forward kernel out of its recomputation; with one missing the
+# forward runs again. Outside a checkpoint a name is the identity.
+RESIDUAL_NAMES = ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse")
 
 
 def _sds(shape, dtype, *like):
@@ -677,6 +686,8 @@ def _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
     else:
         out, lse = _reference(q, k, v, offs, sm_scale=sm_scale,
                               causal=causal, window=window)
+    q, k, v, out, lse = map(checkpoint_name, (q, k, v, out, lse),
+                            RESIDUAL_NAMES)
     return out, (q, k, v, offs, out, lse)
 
 

@@ -93,6 +93,12 @@ def _tell_of_entries_appended_since(mod):
     names = getattr(mod, "NEW_READERS", None) or getattr(
         mod, "NEW_NAMES", None)
     cell = getattr(mod, "REAL", None)
+    if names is None and cell is None and all(isinstance(
+            getattr(mod, k, None), list) for k in ("ONLINE", "BATCH")):
+        # tests/benchmark/test_reqpath.py (PR 36): five entries over
+        # cells that were there, which it holds to be `per_layer`'s last
+        # five; it tells its own by name, so it needs the cut alone.
+        names, cell = mod.ONLINE + mod.BATCH, ""
     if not isinstance(names, list) or not isinstance(cell, str) \
             or hasattr(mod, "_own_last"):
         return
