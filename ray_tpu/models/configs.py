@@ -59,6 +59,25 @@ def tiny_mellum_test(vocab: int = 256) -> TransformerConfig:
                                   "rope_theta": 500000}})
 
 
+def tiny_sdar_test(vocab: int = 256, **changes) -> TransformerConfig:
+    """The period stack's layer that generates by diffusion over blocks
+    (`transformer.PERIOD_FORMS`: "sdar_moe") at a unit-test size: two
+    full-attention layers, each routed by a softmax over 8 experts,
+    blocks of four positions, the mask token the vocabulary's last. For
+    the tests only."""
+    kw = dict(
+        vocab_size=vocab, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=128, norm_eps=1e-6,
+        rope_theta=1e6, dtype=jnp.float32, param_dtype=jnp.float32,
+        remat=False, tie_embeddings=False, arch="sdar_moe",
+        global_attn_every=1, sliding_window=0, moe_experts=8, moe_top_k=2,
+        moe_d_ff=64, score_func="softmax", route_norm=True, block_length=4,
+        mask_token_id=vocab - 1, denoise_steps=4,
+        remask="low_confidence_dynamic", confidence_threshold=0.9)
+    kw.update(changes)
+    return TransformerConfig(**kw)
+
+
 def tiny_pangu_test(vocab: int = 256, router_experts: int = 16,
                     held: int = 4, first: int = 4) -> TransformerConfig:
     """The latent-attention stack of models/latent.py at a unit-test

@@ -53,6 +53,13 @@ PROGRAM_NAMES: Dict[str, str] = {
     "first_token_suffix_sample": "first_token_suffix_sample",
     "decode_step": "decode_k1",
     "decode_multi": "decode_k{k}",
+    # A configuration with `block_length` (generation by diffusion over
+    # blocks) runs these in their place, under the same names: a step of
+    # its decode programs is one pass of the model over a block of
+    # positions a slot, and `decode_k8` eight such passes.
+    "prefill_block_batch": "prefill_block_batch",
+    "decode_block_step": "decode_k1",
+    "decode_block_multi": "decode_k{k}",
     # The engine's own sampler after a one-step block (serve/llm.py).
     "sample_batch": "sample_batch",
 }
@@ -115,7 +122,13 @@ class KVCache(NamedTuple):
     programs carry k and v whole through their layer and step loops,
     write one row a slot a layer and read, of each layer, the rows the
     owned slots hold (`_attend_cache`); a row no request owns is never
-    written.
+    written. A row below `seq_lens` is final; what lies at or past it is
+    padding a prefill left or, where a configuration generates a block
+    of positions a pass (`decode_block_multi`), the rows [seq_lens,
+    seq_lens + block_length) of the open block as its last pass wrote
+    them: every pass of the block overwrites them, and they are final
+    only once a pass over the block's final tokens has advanced
+    `seq_lens` past them.
 
     A period stack (`models/periodic.py`) keeps two kinds of state: k/v
     hold its global layers, (Lg, B, S_max, KVH, Dh), and kw/vw its
@@ -235,6 +248,45 @@ def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
     probs = masked_softmax(scores, n_rows, live).astype(k_cache.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
     return out.reshape(B, 1, H * Dh), k_all, v_all
+
+
+def _attend_cache_block(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
+                        p0, live):
+    """A block of Bd positions a slot against layer `l` of a carried
+    cache: q (B, Bd, H, Dh), k and v (B, Bd, KVH, Dh), the block standing
+    at rows [p0, p0 + Bd) (p0 (B,)). Writes the block's k and v there,
+    then every query of the block attends over rows [0, p0 + Bd): all Bd
+    see the same keys, so they stand beside the heads of their group,
+    (B, KVH, Bd x G, Dh), and a slot's rows are read once for the whole
+    block, by the kernel `_attend_cache` uses or, where it does not run,
+    by the same products over every row with a mask. A slot that is not
+    `live` (B,) writes nothing and gets zeros. Returns (out (B, Bd,
+    H*Dh), k_all, v_all)."""
+    from ..ops import decode_attention as da
+
+    B, S = k_all.shape[1], k_all.shape[2]
+    Bd = q.shape[1]
+    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    G = H // KVH
+    # A slot that does not write aims past the cache's end: dropped.
+    at = jnp.where(live[:, None], p0[:, None] + jnp.arange(Bd)[None, :], S)
+    rows = jnp.arange(B)[:, None]
+    k_all = k_all.at[l, rows, at].set(k.astype(k_all.dtype), mode="drop")
+    v_all = v_all.at[l, rows, at].set(v.astype(v_all.dtype), mode="drop")
+    n_rows = rows_held(p0 + Bd - 1, S, live)
+    qg = q.reshape(B, Bd, KVH, G, Dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, KVH, Bd * G, Dh)
+    if da.usable(k_all, Dh):
+        out = da.decode_attention(qg, k_all, v_all, l, n_rows)
+    else:
+        k_cache = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
+        v_cache = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
+        scores = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+                            preferred_element_type=jnp.float32) / (Dh ** 0.5)
+        probs = masked_softmax(scores, n_rows, live).astype(k_cache.dtype)
+        out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
+    out = out.reshape(B, KVH, Bd, G, Dh).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, Bd, H * Dh), k_all, v_all
 
 
 # ---------------------------------------------------------------------------
@@ -462,7 +514,12 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     that finish mid-block burn at most num_steps-1 wasted ticks, the
     price of one dispatch and one host fetch per num_steps tokens. The
     cache is the scan's carry and the program's donated argument, so a
-    block is one buffer updated in place. A step costs the weights and
+    block is one buffer updated in place. Every step writes one row a
+    slot a layer at `seq_lens` and moves `seq_lens` past it: a row is
+    final the step it is written (where a model generates a block of
+    positions a pass, `_decode_block_multi` stands in this program's
+    place and a block's rows are final only at its commit pass). A step
+    costs the weights and
     the cache rows the owned slots hold (`_attend_cache`): on a v5e 7.6
     ms at 32 slots x 1024 of internlm2-1.8b holding 43% of their rows
     and 10.5 ms at 4 x 4096 of Mistral-7B's 16 layers with one slot
@@ -491,6 +548,215 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
 decode_multi = _BlockPrograms("decode_multi", _decode_multi,
                               static_argnums=(0, 5, 6),
                               donate_argnums=(2,))
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (`cfg.block_length`)
+# ---------------------------------------------------------------------------
+
+class BlockState(NamedTuple):
+    """Each slot's open block, on the device between dispatches, beside
+    the cache (whose `seq_lens` is the block's first position). Which
+    positions are masked is state of its own, never read off a token id.
+
+    x (B, Bd) int32: the block's tokens, `mask_token_id` where `masked`
+    (B, Bd) bool; at_pass (B, Bd) int32: the denoising pass of the block
+    (from 1) that unmasked a position, 0 for one that came fixed (what a
+    prompt left over of its last whole block); logp (B, Bd) float32:
+    `token_logp` of each token at the pass that unmasked it; passes
+    (B,) int32: denoising passes the block has had; and what the slot's
+    request asked for: steps (B,) int32 passes a block, rule (B,) int32
+    (index into `transformer.REMASK_RULES`), threshold (B,) float32."""
+
+    x: jax.Array
+    masked: jax.Array
+    at_pass: jax.Array
+    logp: jax.Array
+    passes: jax.Array
+    steps: jax.Array
+    rule: jax.Array
+    threshold: jax.Array
+
+
+# What a pass did for a slot (`decode_block_multi`'s `kind`).
+PASS_IDLE, PASS_DENOISE, PASS_COMMIT = 0, 1, 2
+
+
+def init_block_state(cfg: TransformerConfig, num_slots: int) -> BlockState:
+    shape = (num_slots, cfg.block_length)
+    return BlockState(
+        x=jnp.full(shape, cfg.mask_token_id, jnp.int32),
+        masked=jnp.ones(shape, bool), at_pass=jnp.zeros(shape, jnp.int32),
+        logp=jnp.zeros(shape, jnp.float32),
+        passes=jnp.zeros((num_slots,), jnp.int32),
+        steps=jnp.full((num_slots,), cfg.denoise_steps, jnp.int32),
+        rule=jnp.zeros((num_slots,), jnp.int32),
+        threshold=jnp.full((num_slots,), cfg.confidence_threshold,
+                           jnp.float32))
+
+
+@program("prefill_block_batch", static_argnums=(0,), donate_argnums=(2, 3))
+def prefill_block_batch(cfg: TransformerConfig, params, cache: KVCache,
+                        blocks: BlockState, tokens: jax.Array,
+                        lengths: jax.Array, slots: jax.Array,
+                        first_x: jax.Array, first_masked: jax.Array,
+                        steps: jax.Array, rule: jax.Array,
+                        threshold: jax.Array):
+    """Admission where a model generates a block at a time: the whole
+    blocks of a tile of prompts (tokens (W, S_bucket), `lengths` (W,)
+    multiples of `block_length`, 0 for a prompt shorter than a block)
+    into their slots' rows under the block-causal mask, and each slot's
+    first block opened: `first_x` (W, Bd) holds what the prompt left
+    over, fixed, then the mask token where `first_masked`. No token is
+    sampled: a prompt's last logits are not how such a model starts.
+    Returns (cache', blocks'[, routing stats of the tile])."""
+    cache, _, stats = stack(cfg).prefill(cfg, params, cache, tokens, lengths,
+                                         slots)
+
+    def put(old, new):
+        return old.at[slots].set(new.astype(old.dtype), mode="drop")
+
+    W = slots.shape[0]
+    blocks = BlockState(
+        x=put(blocks.x, first_x), masked=put(blocks.masked, first_masked),
+        at_pass=put(blocks.at_pass, jnp.zeros_like(first_x)),
+        logp=put(blocks.logp, jnp.zeros(first_x.shape, jnp.float32)),
+        passes=put(blocks.passes, jnp.zeros((W,), jnp.int32)),
+        steps=put(blocks.steps, steps), rule=put(blocks.rule, rule),
+        threshold=put(blocks.threshold, threshold))
+    return (cache, blocks) if stats is None else (cache, blocks, stats)
+
+
+@program("decode_block_step", static_argnums=(0,), donate_argnums=(2,))
+def decode_block_step(cfg: TransformerConfig, params, cache: KVCache,
+                      tokens: jax.Array, p0: jax.Array,
+                      live: Optional[jax.Array] = None
+                      ) -> Tuple[KVCache, jax.Array]:
+    """One pass of the model over a block a slot: tokens (B, Bd) at
+    positions [p0, p0 + Bd) -> (cache', logits (B, Bd, V)): the stack's
+    `decode_block`. The caller keeps the blocks and advances `seq_lens`
+    (checks and tests; the engine runs `decode_block_multi`)."""
+    cache, logits, _ = stack(cfg).decode_block(cfg, params, cache, tokens,
+                                               p0, live)
+    return cache, logits
+
+
+def _unmask(blocks: BlockState, conf: jax.Array) -> jax.Array:
+    """Which masked positions (B, Bd) a denoising pass unmasks, from the
+    confidence `conf` (B, Bd) float32 of its samples (-inf where not
+    masked). The pass's share of the schedule is n = Bd // steps, one
+    more on the first Bd % steps passes. `low_confidence_static`: the n
+    masked positions of largest confidence, ties to the lower position;
+    `low_confidence_dynamic`: every masked position surer than the
+    threshold where there are n of them at least, else the static rule;
+    `sequential`: the first n masked positions."""
+    Bd = conf.shape[1]
+    masked = blocks.masked
+    steps = jnp.maximum(blocks.steps, 1)
+    n = Bd // steps + (blocks.passes < Bd % steps)
+    n = jnp.where(blocks.passes >= steps, Bd, n)[:, None]     # never stuck
+    pos = jnp.arange(Bd)
+    # Positions that go before j: surer, or as sure and lower.
+    before = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (pos[None, None, :] < pos[None, :, None]))
+    static = masked & (jnp.sum(before, axis=-1) < n)
+    sure = masked & (conf > blocks.threshold[:, None])
+    enough = jnp.sum(sure, axis=-1, keepdims=True) >= n
+    in_order = masked & (jnp.cumsum(masked, axis=-1) <= n)
+    rule = blocks.rule[:, None]
+    return jnp.where(rule == 2, in_order,
+                     jnp.where((rule == 1) & enough, sure, static))
+
+
+def _block_pass(cfg: TransformerConfig, params, cache: KVCache,
+                blocks: BlockState, temps, top_k: int, key, live):
+    """One pass for every slot, whatever pass of its block a slot is at.
+    A block with a masked position left is denoised: the model runs on
+    it, each position's token is sampled (greedily at temperature 0)
+    with its confidence softmax(logits / T)[token] (T = 1 when greedy),
+    and `_unmask` says which masked positions take theirs. A block with
+    none left is committed: the same walk over its final tokens makes
+    rows [p0, p0 + Bd) the block's for good, `seq_lens` moves past them
+    and the next block opens all masked. Returns (cache', blocks',
+    (kind, x, at_pass, logp, unmasked): what the pass did a slot
+    (`PASS_*`), the block as it came in (final where kind is
+    `PASS_COMMIT`), and how many positions it unmasked; routing stats)."""
+    st = stack(cfg)
+    Bd, S = cfg.block_length, cache.max_seq_len
+    p0 = cache.seq_lens
+    ok = p0 + Bd <= S
+    ok = ok if live is None else ok & live
+    cache, logits, stats = st.decode_block(cfg, params, cache, blocks.x, p0,
+                                           ok)
+    open_ = jnp.any(blocks.masked, axis=-1)
+    denoise, commit = ok & open_, ok & ~open_
+    hot = jnp.broadcast_to(temps[:, None], blocks.x.shape)
+
+    def greedy():
+        x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lp = token_logp(logits, x0)
+        return x0, lp, jnp.exp(lp)
+
+    def drawn():
+        x0, lp = sample_logp(logits, hot, key, top_k)
+        scaled = logits / jnp.where(hot > 0.0, hot, 1.0)[..., None]
+        return x0, lp, jnp.exp(token_logp(scaled, x0))
+
+    with jax.named_scope("block_sample"):
+        # The draw costs a random number a logit: only where a slot asks.
+        x0, lp, conf = lax.cond(jnp.any(hot > 0.0), drawn, greedy)
+        conf = jnp.where(blocks.masked, conf, -jnp.inf)
+        take = _unmask(blocks, conf) & denoise[:, None]
+    out = (jnp.where(commit, PASS_COMMIT,
+                     jnp.where(denoise, PASS_DENOISE, PASS_IDLE)),
+           blocks.x, blocks.at_pass, blocks.logp,
+           jnp.sum(take, axis=-1).astype(jnp.int32))
+    fresh = commit[:, None]
+    blocks = blocks._replace(
+        x=jnp.where(fresh, cfg.mask_token_id, jnp.where(take, x0, blocks.x)),
+        masked=fresh | (blocks.masked & ~take),
+        at_pass=jnp.where(fresh, 0, jnp.where(
+            take, blocks.passes[:, None] + 1, blocks.at_pass)),
+        logp=jnp.where(fresh, 0.0, jnp.where(take, lp, blocks.logp)),
+        passes=jnp.where(commit, 0, blocks.passes + denoise))
+    cache = cache._replace(seq_lens=jnp.where(commit, p0 + Bd, p0))
+    return cache, blocks, out, stats
+
+
+def _decode_block_multi(cfg: TransformerConfig, params, cache: KVCache,
+                        blocks: BlockState, temps: jax.Array, num_steps: int,
+                        top_k: int, key: jax.Array,
+                        live: Optional[jax.Array] = None):
+    """`num_steps` fused passes (`_block_pass`) in ONE dispatch, under
+    the name `decode_k<num_steps>`: `_decode_multi`'s place where a model
+    generates a block of positions a pass. Slots admitted at different
+    times are at different passes of their blocks and one pass serves
+    them all. Returns (cache', blocks', (kind (num_steps, B), the block's
+    tokens (num_steps, B, Bd), the pass that unmasked each, `token_logp`
+    of each, positions unmasked (num_steps, B))[, routing stats:
+    `routed_layers`, over B x Bd rows a pass]). The host emits a block
+    where `kind` says its pass committed it."""
+    def body(carry, sub):
+        cache, blocks, routed = carry
+        cache, blocks, out, stats = _block_pass(cfg, params, cache, blocks,
+                                                temps, top_k, sub, live)
+        if stats is not None:
+            routed = routed + stats
+        return (cache, blocks, routed), out
+
+    subs = jax.random.split(key, num_steps)
+    routed = jnp.zeros((3,), jnp.int32) if routed_layers(cfg) else None
+    (cache, blocks, routed), out = lax.scan(
+        body, (cache, blocks, routed), subs)
+    return (cache, blocks, out) if routed is None \
+        else (cache, blocks, out, routed)
+
+
+decode_block_multi = _BlockPrograms("decode_block_multi",
+                                    _decode_block_multi,
+                                    static_argnums=(0, 5, 6),
+                                    donate_argnums=(2, 3))
 
 
 def sample(logits: jax.Array, key: jax.Array, *,

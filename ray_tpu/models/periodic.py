@@ -29,6 +29,12 @@ at `position mod rows`. Softmax does not care in which order the ring
 holds its rows, and a key carries its rotary phase from when it was
 written, so decode reads the ring as it lies.
 
+A configuration that generates by diffusion over blocks
+(`cfg.block_length`: every layer global) takes the same layer and the
+same walk: `prefill` masks block-causally (a query sees its own block
+whole) and `decode_block` runs a block of positions a slot against the
+rows the slot holds and the block's own; `decode` is not its walk.
+
 Precision follows `cfg.dtype`, the dtype of the activations and of the
 cache. bfloat16: every product takes bf16 operands, as the dense stack
 does. float32 (with bf16 weights): nothing between the embedding and the
@@ -54,8 +60,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .generate import (KVCache, _attend_cache, _last_rows, _rope,
-                       masked_softmax, rows_held)
+from .generate import (KVCache, _attend_cache, _attend_cache_block,
+                       _last_rows, _rope, masked_softmax, rows_held)
 from .moe import EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn
 from .transformer import TransformerConfig, rope_tables
 
@@ -81,6 +87,18 @@ MISSING = {
                      "autodiff (moe_ffn drops tokens over capacity), the "
                      "backward of windowed flash attention, and the "
                      "load-balancing update of the selection bias",
+}
+# What a configuration with `block_length` lacks of this stack, and one
+# without it of the block walk.
+NOT_ITS_WALK = {
+    "decode": "a configuration with block_length generates a block of "
+              "positions a pass (decode_block, generate.decode_block_*): "
+              "one token a step (decode_step, decode_multi) is not how it "
+              "generates",
+    "decode_block": "decode_block is the walk of a configuration with "
+                    "block_length; this one generates one token a step",
+    "terms": "decode_block is not written for a cache of two bf16 terms "
+             "(float32 activations on bf16 weights)",
 }
 
 
@@ -301,11 +319,15 @@ def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
     """{kind: (sin, cos)} for the kinds of layer that rotate q and k,
     each from its own section of `cfg.rope_parameters`: tables (S, half)
     for a tile, or, with `positions` (B,), each slot's row of a table of
-    `seq_len` positions, (B, 1, half)."""
+    `seq_len` positions, (B, 1, half); with `positions` (B, Bd), a row a
+    position of each slot's block, (B, Bd, half) (one past the table's
+    end reads its last row: such a slot's pass is dropped)."""
     out = {}
     for kind in cfg.period_form.rotary:
         sin, cos = rope_tables(cfg, seq_len, ROPE_SECTION[kind])
-        if positions is not None:
+        if positions is not None and positions.ndim == 2:
+            sin, cos = sin[positions], cos[positions]
+        elif positions is not None:
             sin, cos = sin[positions][:, None, :], cos[positions][:, None, :]
         out[kind] = (sin, cos)
     return out
@@ -388,8 +410,9 @@ def last_logits(cfg: TransformerConfig, params, x, lengths) -> jax.Array:
 _QUERY_BLOCK = 256
 
 
-def _attention_f32(q, k, v, window: int):
-    """Causal (windowed) attention of float32 q (B, S, H, Dh) over float32
+def _attention_f32(q, k, v, window: int, block_len: int = 0):
+    """Causal (windowed; `block_len`: block-causal, a query standing at its
+    block's last position) attention of float32 q (B, S, H, Dh) over float32
     k, v (B, S, KVH, Dh), both products at the highest precision (the
     flash kernel multiplies in bf16), a block of queries at a time so
     that the scores held are (B, H, block, keys). A block meets all S
@@ -416,6 +439,8 @@ def _attention_f32(q, k, v, window: int):
         s = jnp.einsum("bqkgd,bskd->bkgqs", qs, kb, precision=hi) \
             / math.sqrt(Dh)
         i = start + jnp.arange(blk)[:, None]
+        if block_len:
+            i = i | (block_len - 1)
         seen = jb <= i
         if window:
             seen = seen & (i - jb < window)
@@ -430,9 +455,11 @@ def _attention_f32(q, k, v, window: int):
 def _flash(cfg: TransformerConfig, kind: str, q, k, v):
     w = cfg.sliding_window if kind == WINDOW else 0
     if q.dtype == jnp.float32:
-        return _attention_f32(q, k, v, w)
+        return _attention_f32(q, k, v, w, cfg.block_length)
     from ..ops import flash_attention
 
+    if cfg.block_length:
+        return flash_attention(q, k, v, causal=True, block=cfg.block_length)
     if not w or q.shape[1] <= w:
         return flash_attention(q, k, v, causal=True)
     # The kernel chooses its blocks and gives a step to the blocks a window
@@ -528,6 +555,14 @@ def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
     return out.reshape(B, 1, cfg.n_heads, cfg.head_dim), (kg, vg, kw, vw)
 
 
+def _block_attend(cfg, p0, live, l, kind, q, k, v, state):
+    """A block of positions a slot (`decode_block`): every layer of such
+    a configuration is global."""
+    kg, vg, kw, vw = state
+    out, kg, vg = _attend_cache_block(cfg, q, k, v, kg, vg, l, p0, live)
+    return out.reshape(q.shape), (kg, vg, kw, vw)
+
+
 def _free_attend(cfg, l, kind, q, k, v, state):
     return _flash(cfg, kind, q, k, v), state
 
@@ -541,7 +576,9 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
     hidden states (W, S, D), routing stats of the tile (3,) as `decode`
     gives a step's, over all W x S positions, padding too; None with no
-    routed layer)."""
+    routed layer). With `cfg.block_length` the mask is block-causal and
+    `lengths` are whole blocks (what is left of a prompt opens the
+    slot's first block: `generate.prefill_block_batch`)."""
     rope = rope_by_kind(cfg, tokens.shape[1])
     x, (kg, vg, kw, vw), stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens), rope,
@@ -568,6 +605,8 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     routed, and the fullest expert's rows summed over the layers; None
     with no routed layer). `live` (B,) bool: the slots a request owns
     (None: every one)."""
+    if cfg.block_length:
+        raise NotImplementedError(NOT_ITS_WALK["decode"])
     positions = cache.seq_lens
     rope = rope_by_kind(cfg, cache.max_seq_len, positions)
     x, (kg, vg, kw, vw), stats, _ = _run(
@@ -577,6 +616,40 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
         stats if routed_layers(cfg) else None
+
+
+def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
+                 live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+    """One pass over a block a slot: tokens (B, Bd) (the mask token's id
+    where a position is still masked) at positions p0 .. p0 + Bd - 1 (p0
+    (B,): the rows the slot has committed, a multiple of Bd) -> (cache',
+    logits (B, Bd, V), routing stats of the pass (3,) as `decode` gives a
+    step's, over all B x Bd rows; None with no routed layer). Each layer
+    writes the block's keys and values at rows [p0, p0 + Bd) and every
+    query of the block attends over rows [0, p0 + Bd): the committed
+    prefix and the block itself, no mask inside it. `seq_lens` is left
+    as it is: the rows are the block's for good only when the caller
+    advances it (a pass over the block's final tokens), and the block's
+    next pass overwrites them until then. A slot that is not `live`, or
+    whose block would pass the cache's end, writes and reads nothing."""
+    if not cfg.block_length:
+        raise NotImplementedError(NOT_ITS_WALK["decode_block"])
+    if cache_terms(cfg) == 2:
+        raise NotImplementedError(NOT_ITS_WALK["terms"])
+    B, Bd = tokens.shape
+    S = cache.max_seq_len
+    fits = p0 + Bd <= S
+    live = fits if live is None else live & fits
+    positions = jnp.minimum(p0[:, None] + jnp.arange(Bd)[None, :], S - 1)
+    rope = rope_by_kind(cfg, S, positions)
+    x, (kg, vg, kw, vw), stats, _ = _run(
+        cfg, params, _embed(cfg, params, tokens), rope,
+        partial(_block_attend, cfg, p0, live),
+        (cache.k, cache.v, cache.kw, cache.vw))
+    cache = KVCache(k=kg, v=vg, seq_lens=cache.seq_lens, kw=kw, vw=vw)
+    with jax.named_scope("block_head"):
+        logits = head_logits(cfg, params, _final(cfg, params, x))
+    return cache, logits, stats if routed_layers(cfg) else None
 
 
 def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:

@@ -48,6 +48,19 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #              -> (cache', logits (B, V), routing stats (3,) or None; (4,)
 #                  where the layer holds a share of its experts: the
 #                  pairs routed over the router's whole width behind)
+#            decode_block(cfg, params, cache, tokens (B, Bd), p0 (B,), live)
+#              -> (cache', logits (B, Bd, V), routing stats): a stack that
+#              serves `cfg.block_length` > 0 (generation by diffusion over
+#              blocks) offers this walk beside `decode`: the block's Bd
+#              keys and values go to rows [p0, p0 + Bd) of each slot and
+#              every query of the block sees rows [0, p0 + Bd). The rows
+#              are final only once the caller advances `seq_lens` past
+#              them (a commit pass over the block's final tokens): until
+#              then the block's next pass overwrites them. With such a
+#              configuration `prefill` masks block-causally and `decode`
+#              raises (one token a step is not how it generates); a
+#              stack without the walk refuses the configuration
+#              (`TransformerConfig.__post_init__`)
 #            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
 #            routed_layers(cfg): the layers the stats count over
 #            routing_stats(cfg): how many entries the stats have, where
@@ -57,7 +70,14 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one
 # says why in its `MISSING`.
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
-                          "mellum": "periodic", "pangu_ultra_moe": "latent"}
+                          "mellum": "periodic", "pangu_ultra_moe": "latent",
+                          "sdar_moe": "periodic"}
+
+# How a block of `TransformerConfig.block_length` positions is unmasked
+# (models/generate.py, `_unmask`): the names a request or a configuration
+# gives `remask`, in the order of the code the programs carry a slot.
+REMASK_RULES = ("low_confidence_static", "low_confidence_dynamic",
+                "sequential")
 
 
 @dataclass(frozen=True)
@@ -87,6 +107,11 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     "mellum": PeriodForm(attn_gate=False, post_norms=False,
                          embed_scale=False, router_bias=False,
                          rotary=("window", "global")),
+    # JetLM SDAR (MoE): the same pre-norm layer, every layer full
+    # attention; what is its own is how it generates (`block_length`).
+    "sdar_moe": PeriodForm(attn_gate=False, post_norms=False,
+                           embed_scale=False, router_bias=False,
+                           rotary=("global",)),
 }
 
 
@@ -161,7 +186,9 @@ class TransformerConfig:
     # rotary on window layers only, a scaled embedding). Served only:
     # forward / loss_fn raise for it. "mellum": the same stack with
     # another layer (PERIOD_FORMS: two norms, no gate, rotary on both
-    # kinds of layer). "pangu_ultra_moe": the latent-attention stack of
+    # kinds of layer). "sdar_moe": that layer again, every layer global,
+    # generating a block at a time (`block_length`, at the end).
+    # "pangu_ultra_moe": the latent-attention stack of
     # models/latent.py (the fields at the end). STACKS above holds the
     # names.
     arch: str = "llama"
@@ -194,6 +221,21 @@ class TransformerConfig:
     # moe_experts), computing the part of the sum those give.
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # Generation by diffusion over blocks (models/generate.py, the block
+    # programs): 0 = one token a step, every other configuration's value.
+    # A block of `block_length` positions (a power of two) opens masked
+    # (the embedding of `mask_token_id`), is denoised by passes that see
+    # the committed rows and the whole block, and reaches the cache by a
+    # pass over its final tokens; attention is causal between blocks and
+    # unmasked inside one. The engine's defaults a request may override:
+    # `denoise_steps` passes unmask a block by the rule `remask`
+    # (`REMASK_RULES`), the dynamic one every position surer than
+    # `confidence_threshold`.
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoise_steps: int = 0           # 0 = `block_length`
+    remask: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
 
     def __post_init__(self):
         if not self.head_dim:
@@ -201,6 +243,8 @@ class TransformerConfig:
                                self.d_model // self.n_heads)
         object.__setattr__(self, "rope_parameters",
                            _frozen(self.rope_parameters))
+        if self.sliding_window is None:     # a published `null`: no window
+            object.__setattr__(self, "sliding_window", 0)
         if self.arch not in STACKS:
             raise ValueError(f"arch must be one of {sorted(STACKS)}, got "
                              f"{self.arch!r}")
@@ -212,6 +256,25 @@ class TransformerConfig:
                     f"{self.arch}: n_layers - n_dense_layers ({body}) must "
                     f"be whole periods of global_attn_every "
                     f"({self.global_attn_every})")
+        if self.block_length:
+            Bd = self.block_length
+            if self.arch not in PERIOD_FORMS or self.sliding_window:
+                raise ValueError(
+                    f"block_length {Bd}: only the period stack has the "
+                    "block walk (`decode_block`), and only over layers "
+                    "that keep every row (sliding_window 0: a ring "
+                    "cannot take a block's rows back)")
+            if Bd < 1 or Bd & (Bd - 1) \
+                    or not 0 <= self.mask_token_id < self.vocab_size \
+                    or not 0 <= self.denoise_steps <= Bd \
+                    or self.remask not in REMASK_RULES:
+                raise ValueError(
+                    f"block_length {Bd} must be a power of two, "
+                    f"mask_token_id {self.mask_token_id} a token, "
+                    f"denoise_steps {self.denoise_steps} at most a block "
+                    f"and remask {self.remask!r} one of {REMASK_RULES}")
+            if not self.denoise_steps:
+                object.__setattr__(self, "denoise_steps", Bd)
 
     @property
     def is_moe(self) -> bool:

@@ -249,7 +249,7 @@ def _fwd_grid(nq, nk, block_q, block_k, causal, window, static_offs, offs):
 
 
 def _fwd_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
-                causal, window):
+                causal, window, block=None):
     """One (q block, kv block) step of the online softmax."""
     (q_ref, k_ref, v_ref, o_ref, lse_ref,
      qs_ref, acc_ref, m_ref, l_ref) = refs[n_scalars:]
@@ -272,8 +272,13 @@ def _fwd_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
                             preferred_element_type=jnp.float32)
         if masked:
             # q_pos >= k_pos, as row - column against one scalar.
-            ahead = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                     - lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            if block is not None:
+                # Block-causal: a query sees its whole block, so it stands
+                # at its block's last position (offsets and the kernel's
+                # blocks are multiples of `block`, a power of two).
+                row = row | (block - 1)
+            ahead = row - lax.broadcasted_iota(jnp.int32, s.shape, 1)
             diag = (at.kv_off + at.ki * block_k) - (at.q_off
                                                     + at.qi * block_q)
             mask = ahead >= diag
@@ -324,13 +329,16 @@ def _fwd_kernel(*refs, locate, n_scalars, n_ids, sm_scale, block_q, block_k,
 
 
 def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
-              interpret, window=None, static_offs=None
+              interpret, window=None, static_offs=None, block=None
               ) -> Tuple[jax.Array, jax.Array]:
     """q (B, H, Sq, D); k (B, KVH, Skv, D), v (B, KVH, Skv, Dv), KVH
     dividing H: a query head reads kv head `h // (H // KVH)`, nothing is
     expanded; the values may be another width than the keys (latent
     attention: 192-wide scores over 128-wide values), and the output is
-    theirs. -> (out, lse). `static_offs`: (q_offset, kv_offset) as Python ints
+    theirs. -> (out, lse). `block`: the block-causal mask (`flash_attention`);
+    the table of live pairs is the causal one (`block` divides the
+    kernel's blocks and the offsets), only the mask of the blocks the
+    diagonal crosses differs. `static_offs`: (q_offset, kv_offset) as Python ints
     where the caller knows them, and then `offs` is not read; else `offs`
     (two numbers, traced or not) reaches the index maps as a prefetched
     scalar. The grid: `_fwd_grid`."""
@@ -351,7 +359,7 @@ def _fwd_impl(q, k, v, offs, *, sm_scale, block_q, block_k, causal,
     kernel = functools.partial(
         _fwd_kernel, locate=locate, n_scalars=len(scalars), n_ids=n_ids,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k, causal=causal,
-        window=window)
+        window=window, block=block)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -641,13 +649,15 @@ def _bwd_impl(q, k, v, do, out, lse, offs, *, sm_scale, block_q, block_k,
 # Reference fallback (pure jnp — differentiable, XLA-fused)
 # ---------------------------------------------------------------------------
 
-def _reference(q, k, v, offs, *, sm_scale, causal, window=None):
+def _reference(q, k, v, offs, *, sm_scale, causal, window=None, block=None):
     """(B, H, S, D) layout. Returns (out, lse)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         Sq, Skv = q.shape[2], k.shape[2]
         q_pos = offs[0, 0].astype(jnp.int32) + jnp.arange(Sq)[:, None]
         k_pos = offs[0, 1].astype(jnp.int32) + jnp.arange(Skv)[None, :]
+        if block is not None:
+            q_pos = q_pos | (block - 1)      # its block's last position
         mask = q_pos >= k_pos
         if window is not None:
             mask = mask & (q_pos - k_pos < window)
@@ -663,9 +673,9 @@ def _reference(q, k, v, offs, *, sm_scale, causal, window=None):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
-           use_pallas, interpret, window=None, static_offs=None):
+           use_pallas, interpret, window=None, static_offs=None, block=None):
     """q (B, H, Sq, D); k, v (B, KVH, Skv, D): unexpanded for the kernels,
     which read kv head `h // (H // KVH)`; the reference is handed them
     expanded (KVH = H), as it always was, and its vjp sums dk and dv over
@@ -673,30 +683,33 @@ def _flash(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
     block_k) of the forward kernel and of dq / dkv."""
     return _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks,
                       bwd_blocks, use_pallas, interpret, window,
-                      static_offs)[0]
+                      static_offs, block)[0]
 
 
 def _flash_fwd(q, k, v, offs, causal, sm_scale, fwd_blocks, bwd_blocks,
-               use_pallas, interpret, window=None, static_offs=None):
+               use_pallas, interpret, window=None, static_offs=None,
+               block=None):
     if use_pallas:
         out, lse = _fwd_impl(q, k, v, offs, sm_scale=sm_scale,
                              block_q=fwd_blocks[0], block_k=fwd_blocks[1],
                              causal=causal, interpret=interpret,
-                             window=window, static_offs=static_offs)
+                             window=window, static_offs=static_offs,
+                             block=block)
     else:
         out, lse = _reference(q, k, v, offs, sm_scale=sm_scale,
-                              causal=causal, window=window)
+                              causal=causal, window=window, block=block)
     q, k, v, out, lse = map(checkpoint_name, (q, k, v, out, lse),
                             RESIDUAL_NAMES)
     return out, (q, k, v, offs, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, fwd_blocks, bwd_blocks, use_pallas,
-                    interpret, window, static_offs, res, g):
-    if window is not None:
+                    interpret, window, static_offs, block, res, g):
+    if window is not None or block is not None:
         raise NotImplementedError(
             "flash_attention: the backward pass is not written for a "
-            "window (the dq and dkv kernels mask causally only)")
+            "window or a block-causal mask (the dq and dkv kernels mask "
+            "causally only)")
     q, k, v, offs, out, lse = res
     if v.shape[-1] != k.shape[-1]:
         raise NotImplementedError(
@@ -802,6 +815,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_k: Optional[int] = None,
                     q_offset=0, kv_offset=0,
                     window: Optional[int] = None,
+                    block: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     force_reference: bool = False,
                     force_pallas: bool = False) -> jax.Array:
@@ -814,7 +828,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     sequence — the causal mask is (q_offset + i) >= (kv_offset + j).
     With `window`, a query also sees no key more than `window - 1`
     positions behind it: (q_offset + i) - (kv_offset + j) < window
-    (forward only). The kernels' grids follow the mask: with
+    (forward only). With `block` (a power of two; no window; offsets
+    Python ints and multiples of it; forward only) the mask is
+    block-causal: a query sees every key of its own block of `block`
+    positions and of the blocks before, (q_offset + i) // block >=
+    (kv_offset + j) // block. The kernels' grids follow the mask: with
     offsets given as Python ints a block that holds no live pair gets
     neither a step nor a fetch (`grid_steps`, counted in `FLASH_GRID`).
     Returns (B, Sq, H, D).
@@ -836,6 +854,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError("flash_attention: a window needs causal=True "
                          f"and at least one position, got {window!r}")
 
+    if block is not None:
+        ints = all(isinstance(x, (int, np.integer))
+                   for x in (q_offset, kv_offset))
+        if not causal or window is not None or block < 1 \
+                or block & (block - 1) or not ints \
+                or q_offset % block or kv_offset % block \
+                or Sq % block or Skv % block:
+            raise ValueError(
+                "flash_attention: a block-causal mask needs causal=True, "
+                "no window, a power of two that divides both lengths, "
+                f"and offsets known and multiples of it, got {block!r} "
+                f"at offsets {q_offset!r}, {kv_offset!r}")
     want = _bwd_blocks(Sq, Skv, D)
     bwd = tileable(Sq, Skv, D, block_q or want[0], block_k or want[1])
     want = _fwd_blocks(Sq, Skv, D, window)
@@ -875,8 +905,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kt = _expand_kv(jnp.swapaxes(k, 1, 2), kv_heads)
     vt = _expand_kv(jnp.swapaxes(v, 1, 2), kv_heads)
     offs = jnp.asarray([[q_offset, kv_offset]], jnp.float32)
+    if block is not None and use_pallas and (fwd[0] % block
+                                             or fwd[1] % block):
+        raise ValueError(f"flash_attention: block {block} does not divide "
+                         f"the kernel's blocks {fwd}")
     out = _flash(qt, kt, vt, offs, causal, sm_scale, fwd, bwd, use_pallas,
-                 not compiled, window, static_offs)
+                 not compiled, window, static_offs, block)
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -36,14 +36,20 @@ import numpy as np
 
 from ..core.graphable import graphable
 from ..models.generate import (
+    PASS_COMMIT,
+    PASS_DENOISE,
     PROGRAM_NAMES,
+    BlockState,
     KVCache,
     compute_prefix_kv,
+    decode_block_multi,
     decode_multi,
     decode_step,
     first_token_sample,
     first_token_suffix_sample,
+    init_block_state,
     init_kv_cache,
+    prefill_block_batch,
     prefill_sample_batch,
     prefill_suffix_batch,
     program,
@@ -51,6 +57,7 @@ from ..models.generate import (
     sample_logp,
 )
 from ..models.transformer import (
+    REMASK_RULES,
     TransformerConfig,
     init_params,
     init_params_sharded,
@@ -60,6 +67,7 @@ from ..util import tracing
 # Jitted so that under an ambient mesh the cache is created sharded
 # (eagerly, jnp.zeros would first place it whole on the default device).
 _init_kv_cache = jax.jit(init_kv_cache, static_argnums=(0, 1, 2))
+_init_block_state = jax.jit(init_block_state, static_argnums=(0, 1))
 
 
 def default_buckets(max_prompt_len: int) -> List[int]:
@@ -92,6 +100,13 @@ class GenRequest:
     max_new_tokens: int = 64
     temperature: float = 0.0
     eos_token: Optional[int] = None
+    # Where the model generates a block of positions a pass
+    # (`TransformerConfig.block_length`): denoising passes a block, the
+    # rule that unmasks (`transformer.REMASK_RULES`) and the dynamic
+    # rule's threshold; None = the configuration's.
+    denoise_steps: Optional[int] = None
+    remask: Optional[str] = None
+    confidence_threshold: Optional[float] = None
     # filled by the engine
     id: int = 0
     submit_ts: float = 0.0
@@ -115,6 +130,9 @@ class GenRequest:
     # log π(tok) per emitted token (raw-logits log_softmax),
     # index-aligned with `tokens`.
     logprobs: List[float] = field(default_factory=list)
+    # Block generation: the denoising pass of its block (from 1) that
+    # unmasked each emitted token, index-aligned with `tokens`.
+    unmasked_at: List[int] = field(default_factory=list)
     error: Optional[str] = None
     # Set once the terminal None has been consumed (engine-internal).
     _done: bool = field(default=False, repr=False)
@@ -212,16 +230,21 @@ def _routing_sums(stats) -> Dict[str, int]:
 
 
 class _Slot:
-    __slots__ = ("req", "emitted", "length", "inflight")
+    __slots__ = ("req", "emitted", "length", "inflight", "blocks_left")
 
     def __init__(self, req: GenRequest, prompt_len: int):
         self.req = req
         self.emitted = 0
-        self.length = prompt_len  # tokens in cache (grows per tick)
-        # Decode ticks dispatched to the device but not yet processed
-        # on the host (the pipelined block in flight). The device-side
-        # cache position for this slot is length + inflight.
+        # Final rows in the cache: grows by a row a step, or, where the
+        # model generates a block a pass, by a block a commit.
+        self.length = prompt_len
+        # Decode steps dispatched to the device but not yet processed
+        # on the host (the pipelined block in flight). One token a
+        # step: the device-side cache position for this slot is length
+        # + inflight.
         self.inflight = 0
+        # Block generation: blocks the request still needs committed.
+        self.blocks_left = 0
 
 
 class LLMEngine:
@@ -271,9 +294,21 @@ class LLMEngine:
         # worst-case admission latency). A fused block costs one
         # dispatch and one host fetch for its tokens.
         self.decode_block = max(1, decode_block)
+        # Positions a slot a decode step: 1, or the block a pass of the
+        # model works on (generation by diffusion over blocks). Then a
+        # step is a pass, a slot's open block lives on the device
+        # (`_blocks`) and tokens are emitted a committed block at a time.
+        self.block_length = cfg.block_length
+        self._step_rows = max(1, cfg.block_length)
+        if self.block_length and mesh is not None:
+            raise NotImplementedError(
+                "block generation (block_length) is served on one chip: "
+                "the period stack has no sharding rules yet")
         with self._mesh_ctx():
             self.cache: KVCache = _init_kv_cache(cfg, num_slots,
                                                  self.max_seq_len)
+            self._blocks: Optional[BlockState] = _init_block_state(
+                cfg, num_slots) if self.block_length else None
             self.cur_tokens = jnp.zeros((num_slots,), jnp.int32)
             # Device-resident per-slot temperatures: updated by scatter
             # at admission, never re-uploaded per tick.
@@ -288,7 +323,8 @@ class LLMEngine:
         self.lock = threading.Lock()
         self._work = threading.Event()
         self._stop = False
-        self.buckets = default_buckets(self.max_seq_len)
+        self.buckets = [b for b in default_buckets(self.max_seq_len)
+                        if b % self._step_rows == 0]
         # Registered prompt prefixes (system prompts): token-tuple ->
         # {"k","v"} device KV computed once; admission copies it into
         # the slot and prefills only the suffix (vLLM-style prefix
@@ -314,7 +350,8 @@ class LLMEngine:
         self._auto_inflight: set = set()
         self.prefix_register_failures = 0
         # aggregate stats
-        self.decode_ticks = 0       # decode steps dispatched
+        # decode steps dispatched (block generation: passes)
+        self.decode_ticks = 0
         self.steps_processed = 0    # ... whose tokens the host has read
         self.tokens_out = 0
         # What the spans carry as attributes, summed where the work
@@ -334,6 +371,11 @@ class LLMEngine:
             # (`engine.idle_wait`).
             "submitted": 0, "launches": {}, "tick_ns": 0, "tick_cpu_ns": 0,
             "fetch_wait_ns": 0, "idle_wait_ns": 0}
+        if self.block_length:
+            # What `engine.process_block` says of its passes, summed.
+            self.counts.update(denoise_passes=0, commit_passes=0,
+                               blocks_committed=0, positions_unmasked=0,
+                               tokens_truncated=0)
         # The decode blocks and the admission tiles of a stack with
         # routed layers report how their experts were used
         # (models/generate.routed_layers).
@@ -371,7 +413,14 @@ class LLMEngine:
 
     def submit(self, prompt: Sequence[int], *, max_new_tokens: int = 64,
                temperature: float = 0.0,
-               eos_token: Optional[int] = None) -> GenRequest:
+               eos_token: Optional[int] = None,
+               denoise_steps: Optional[int] = None,
+               remask: Optional[str] = None,
+               confidence_threshold: Optional[float] = None) -> GenRequest:
+        """`denoise_steps`, `remask`, `confidence_threshold`: how a block
+        is unmasked, where the model generates a block of positions a
+        pass (`TransformerConfig.block_length`); None = the
+        configuration's."""
         if self._stop:
             raise RuntimeError("engine is stopped")
         if len(prompt) == 0:
@@ -379,11 +428,32 @@ class LLMEngine:
         if len(prompt) >= self.max_seq_len:
             raise ValueError(
                 f"prompt len {len(prompt)} >= max_seq_len {self.max_seq_len}")
+        asked = (denoise_steps, remask, confidence_threshold)
+        if self.block_length:
+            cfg = self.cfg
+            denoise_steps = cfg.denoise_steps if denoise_steps is None \
+                else int(denoise_steps)
+            remask = cfg.remask if remask is None else remask
+            if confidence_threshold is None:
+                confidence_threshold = cfg.confidence_threshold
+            if not 1 <= denoise_steps <= self.block_length \
+                    or remask not in REMASK_RULES:
+                raise ValueError(
+                    f"denoise_steps {denoise_steps} must be 1 to "
+                    f"{self.block_length} and remask {remask!r} one of "
+                    f"{REMASK_RULES}")
+        elif any(a is not None for a in asked):
+            raise ValueError(
+                "denoise_steps, remask and confidence_threshold are for a "
+                "model that generates by blocks (block_length): this one "
+                "generates one token a step")
         span = tracing.span("engine.submit", prompt_tokens=len(prompt))
         with span:      # on the caller's thread
             req = GenRequest(prompt=list(prompt),
                              max_new_tokens=max_new_tokens,
-                             temperature=temperature, eos_token=eos_token)
+                             temperature=temperature, eos_token=eos_token,
+                             denoise_steps=denoise_steps, remask=remask,
+                             confidence_threshold=confidence_threshold)
             with self.lock:
                 req.id = self.counts["submitted"]
                 self.counts["submitted"] += 1
@@ -401,8 +471,11 @@ class LLMEngine:
                  max_new_tokens: int = 64, temperature: float = 0.0,
                  eos_token: Optional[int] = None,
                  return_logprobs: bool = False,
-                 timeout: Optional[float] = None) -> Dict[str, Any]:
-        """Synchronous generation: submit + wait for completion.
+                 timeout: Optional[float] = None,
+                 **block_options) -> Dict[str, Any]:
+        """Synchronous generation: submit + wait for completion
+        (`block_options`: `submit`'s `denoise_steps`, `remask`,
+        `confidence_threshold`).
 
         With `return_logprobs=True` the result carries per-token
         log-probabilities of the sampled tokens — log_softmax of the
@@ -413,7 +486,8 @@ class LLMEngine:
         engine is driven from this thread — deterministic single-thread
         mode for tests and rollout actors that own their engine."""
         req = self.submit(prompt, max_new_tokens=max_new_tokens,
-                          temperature=temperature, eos_token=eos_token)
+                          temperature=temperature, eos_token=eos_token,
+                          **block_options)
         loop = getattr(self, "_loop_thread", None)
         if loop is None or not loop.is_alive():
             deadline = (time.monotonic() + timeout
@@ -622,13 +696,25 @@ class LLMEngine:
                 return b
         return self.buckets[-1]
 
-    def _emit(self, slot: _Slot, tok: int, lp: float) -> None:
+    def _emit(self, slot: _Slot, tok: int, lp: float, rows: int = 1) -> None:
+        """`rows`: final rows the token adds to the slot's cache (0 where
+        a committed block has counted its rows already)."""
         slot.req.tokens.append(tok)
         slot.req.logprobs.append(float(lp))
         slot.req.stream.put(tok)
         slot.emitted += 1
-        slot.length += 1
+        slot.length += rows
         self.tokens_out += 1
+
+    def _cache_ended(self, slot: _Slot) -> bool:
+        """Whether the slot's cache has no room for another step, stated
+        once for a row and for a block: a row's step reads the token at
+        `length` and writes its row, so it needs `length + 1` rows under
+        `max_seq_len`; a block's pass writes rows [length, length +
+        block_length), which may end at `max_seq_len`."""
+        if self.block_length:
+            return slot.length + self.block_length > self.max_seq_len
+        return slot.length >= self.max_seq_len - 1
 
     def _complete(self, req: GenRequest, new_tokens: int) -> None:
         """Single place for request-completion bookkeeping (slot-path
@@ -704,13 +790,15 @@ class LLMEngine:
                 req.steps_waited = self.decode_ticks - req._steps_seen
 
     def _tile_span(self, side: str, bucket: int, W: int,
-                   reqs: Sequence[GenRequest], skip: int = 0
-                   ) -> tracing.span:
+                   reqs: Sequence[GenRequest], skip: int = 0,
+                   tokens: Optional[int] = None, **more) -> tracing.span:
         """The span of one prefill tile of W rows (build, transfer,
         program call), with its counts: `rows` real of `tile_rows`,
         `tokens` real prompt tokens of tile_rows x bucket computed
-        (`skip`: tokens a cached prefix already holds)."""
-        tokens = sum(len(r.prompt) - skip for r in reqs)
+        (`skip`: tokens a cached prefix already holds; `tokens`: where
+        not every token of a prompt is prefilled, how many are)."""
+        if tokens is None:
+            tokens = sum(len(r.prompt) - skip for r in reqs)
         c = self.counts
         c["prefill_tiles"] += 1
         c["prefill_rows"] += len(reqs)
@@ -721,7 +809,7 @@ class LLMEngine:
             c["queue_side_first_tokens"] += len(reqs)
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
-            tile_rows=W, tokens=tokens, req_ids=_ids(reqs))
+            tile_rows=W, tokens=tokens, req_ids=_ids(reqs), **more)
 
     def _launch_span(self, program: str) -> tracing.span:
         """The span of one device program the engine's thread calls
@@ -796,6 +884,9 @@ class LLMEngine:
         """`_admit`'s tiles, for the requests it took off the queue and
         the free slots they go to."""
         self._touch(take)
+        if self.block_length:
+            self._admit_blocks(take, free)
+            return []
 
         admitted: List = []  # (idx, tok_dev, lps_dev, row) — pending
         # Route: prompts strictly extending a registered prefix go
@@ -881,6 +972,85 @@ class LLMEngine:
                     admitted.append((idx, toks[j], lps, j))
         return admitted
 
+    def _admit_blocks(self, take: List[GenRequest], free: List[int]) -> None:
+        """Admission where the model generates a block a pass: the whole
+        blocks of each prompt are prefilled under the block-causal mask
+        (a tile a bucket of that length, as `_admit_taken` cuts them) and
+        what is left of the prompt opens the slot's first block, fixed,
+        beside masks. No first token comes from the prompt's last logits:
+        the first tokens are the first block's, a few passes on."""
+        Bd = self.block_length
+        by_bucket: Dict[int, List] = {}
+        for req, idx in zip(take, free):
+            whole = len(req.prompt) // Bd * Bd
+            by_bucket.setdefault(self._bucket_for(whole), []).append(
+                (req, idx, whole))
+        chunks = [(bucket, its[off:off + self._tile_rows(bucket)])
+                  for bucket, its in sorted(by_bucket.items())
+                  for off in range(0, len(its), self._tile_rows(bucket))]
+        for ci, (bucket, chunk) in enumerate(chunks):
+            W = self._tile_rows(bucket)
+            slot_idx = np.full((W,), self.num_slots, np.int32)
+            first_x = np.full((W, Bd), self.cfg.mask_token_id, np.int32)
+            first_masked = np.ones((W, Bd), bool)
+            steps = np.ones((W,), np.int32)
+            rule = np.zeros((W,), np.int32)
+            threshold = np.zeros((W,), np.float32)
+            reqs = [req for req, _, _ in chunk]
+            for j, (req, idx, whole) in enumerate(chunk):
+                rest = req.prompt[whole:]
+                slot_idx[j] = idx
+                first_x[j, :len(rest)] = rest
+                first_masked[j, :len(rest)] = False
+                steps[j] = req.denoise_steps
+                rule[j] = REMASK_RULES.index(req.remask)
+                threshold[j] = req.confidence_threshold
+            try:
+                with self._tile_span(
+                        "slot", bucket, W, reqs,
+                        tokens=sum(whole for _, _, whole in chunk),
+                        mask="block_causal"):
+                    buf, lens, temps = self._build_tile(
+                        bucket, W, [(req.prompt[:whole], req.temperature)
+                                    for req, _, whole in chunk])
+                    with self._launch_span(
+                            PROGRAM_NAMES["prefill_block_batch"]):
+                        self.cache, self._blocks, *moe = prefill_block_batch(
+                            self.cfg, self.params, self.cache, self._blocks,
+                            jnp.asarray(buf), jnp.asarray(lens),
+                            jnp.asarray(slot_idx), jnp.asarray(first_x),
+                            jnp.asarray(first_masked), jnp.asarray(steps),
+                            jnp.asarray(rule), jnp.asarray(threshold))
+                        self._temps = self._temps.at[slot_idx].set(
+                            jnp.asarray(temps), mode="drop")
+            except Exception:
+                with self.lock:
+                    for _, later in reversed(chunks[ci:]):
+                        for req, _, _ in reversed(later):
+                            self.waiting.appendleft(req)
+                raise
+            self._tile_moe += moe
+            _copy_to_host_async(*moe)
+            for req, idx, whole in chunk:
+                slot = _Slot(req, whole)
+                # What the prompt left over stands in the first block.
+                slot.blocks_left = -(-(len(req.prompt) - whole
+                                       + req.max_new_tokens) // Bd)
+                self.slots[idx] = slot
+
+    def _read_tile_moe(self, span=None) -> None:
+        """The admission tiles' routing stats, once the device has them:
+        onto the counters and onto `span`."""
+        tile_moe = [np.asarray(m) for m in self._tile_moe]
+        self._tile_moe = []
+        if tile_moe:
+            routed = {"prefill_" + name: n for name, n in
+                      _routing_sums(np.sum(tile_moe, 0)).items()}
+            for name, n in routed.items():
+                self.counts[name] += n
+            if span is not None:
+                span.set(moe_tiles=len(tile_moe), **routed)
+
     def _early_first_tokens(self) -> List:
         """TTFT decoupled from slot availability: queued requests that
         could not be admitted get their FIRST token from a cache-free
@@ -890,6 +1060,8 @@ class LLMEngine:
         token — the client's stream stays consistent. Returns
         [(chunk_requests, toks_dev, lps_dev)]; fetched by
         _deliver_first_tokens."""
+        if self.block_length:
+            return []       # no token comes from a prompt's last logits
         with self.lock:
             todo = [r for r in self.waiting
                     if r.first_token_ts == 0.0]
@@ -984,18 +1156,11 @@ class LLMEngine:
                 fused_lp = np.concatenate(
                     [np.asarray(lps)[j:j + 1] for _, _, lps, j in admitted]
                     + [np.asarray(lps) for _, _, lps in outs])
-                tile_moe = [np.asarray(m) for m in self._tile_moe]
-                self._tile_moe = []
-            if tile_moe:
                 # What the admission tiles behind these tokens (and any
                 # whose tokens the queue side had served) routed: a
                 # tile's span ends at its dispatch, before the device
                 # knows, so the numbers ride the span that waits for it.
-                routed = {"prefill_" + name: n for name, n in
-                          _routing_sums(np.sum(tile_moe, 0)).items()}
-                for name, n in routed.items():
-                    self.counts[name] += n
-                span.set(moe_tiles=len(tile_moe), **routed)
+                self._read_tile_moe(span)
             slots = (self.slots[idx] for idx, _, _, _ in admitted)
             first = [s.req for s in slots if s is not None] \
                 + [r for reqs, _, _ in outs for r in reqs]
@@ -1100,15 +1265,17 @@ class LLMEngine:
             # by self.decode_block (compile-cache/latency bound) and by
             # every slot's DEVICE-side cache headroom (length +
             # inflight) so no in-block write can run past max_seq_len.
-            headroom = min(self.max_seq_len - 1
-                           - snap[i].length - snap[i].inflight
-                           for i in active)
-            budget = max(snap[i].req.max_new_tokens - snap[i].emitted
-                         - snap[i].inflight for i in active)
+            # Where a step is a pass over a block, a slot's budget is
+            # the passes its blocks can still take (a commit behind each
+            # block's denoising passes), and the program itself stops a
+            # slot at the cache's end.
+            left = [self._steps_left(snap[i]) for i in active]
+            headroom = self.decode_block if self.block_length else min(
+                self.max_seq_len - 1 - snap[i].length - snap[i].inflight
+                for i in active)
+            budget = max(left)
             if budget > 0 or self._pending is None:
-                remaining = max(1, min(
-                    max(1, snap[i].req.max_new_tokens - snap[i].emitted
-                        - snap[i].inflight) for i in active))
+                remaining = max(1, min(max(1, n) for n in left))
                 k_block = 1
                 while k_block < remaining:
                     k_block *= 2
@@ -1130,38 +1297,76 @@ class LLMEngine:
             self._process_block(prev)
         return bool(admitted or outs or block or prev or registered)
 
+    def _steps_left(self, slot: _Slot) -> int:
+        """Decode steps the slot's request can still use beyond those in
+        flight: its tokens left, or, where a step is a pass over a block,
+        the passes its blocks left can take (each its denoising passes
+        and a commit: fewer where the dynamic rule finishes a block
+        early)."""
+        if self.block_length:
+            return slot.blocks_left * (slot.req.denoise_steps + 1) \
+                - slot.inflight
+        return slot.req.max_new_tokens - slot.emitted - slot.inflight
+
+    def _rows_held(self, k_block: int, slot: _Slot) -> int:
+        """Cache rows the slot's attention reads over the next `k_block`
+        steps, once a step. One token a step: a slot at device position
+        p holds p + 1 once the step's row is written. A block a pass: a
+        pass reads the committed rows and the block's own, and the rows
+        grow by a block a commit, reckoned here from the request's
+        static schedule (a commit every `denoise_steps` + 1 passes; the
+        program does not say before the host has to count)."""
+        S = self.max_seq_len
+        if self.block_length:
+            Bd, cycle = self.block_length, slot.req.denoise_steps + 1
+            p0 = slot.length + Bd * (slot.inflight // cycle)
+            return sum(min(p0 + Bd * (t // cycle) + Bd, S)
+                       for t in range(k_block))
+        return min(k_block * (slot.length + slot.inflight + 1)
+                   + k_block * (k_block - 1) // 2, k_block * S)
+
     def _dispatch_block(self, k_block: int, snap: List, active: List[int]):
         """One fused block of `k_block` decode steps for every slot (the
         program computes all `num_slots`; `active` of them hold a
         request, and it is told which: the others' cache rows are not
-        read). Returns the pending block `_process_block` takes."""
+        read). Where the model generates a block of positions a pass, a
+        step is a pass. Returns the pending block `_process_block`
+        takes."""
         c = self.counts
         number = c["blocks"]
         c["blocks"] = number + 1
         c["blocks_by_k"][k_block] = c["blocks_by_k"].get(k_block, 0) + 1
-        c["slot_steps"] += k_block * self.num_slots
+        # Positions computed: a slot a step, times the block a pass
+        # works on.
+        c["slot_steps"] += k_block * self.num_slots * self._step_rows
         # Cache rows the block's steps could read, and the rows its
-        # owned slots hold over those steps (a slot at device position p
-        # holds p + 1 once the step's row is written).
+        # owned slots hold over those steps.
         rows = k_block * self.num_slots * self.max_seq_len
-        held = sum(min(k_block * (snap[i].length + snap[i].inflight + 1)
-                       + k_block * (k_block - 1) // 2,
-                       k_block * self.max_seq_len) for i in active)
+        held = sum(self._rows_held(k_block, snap[i]) for i in active)
         c["cache_rows"] += rows
         c["cache_rows_held"] += held
         owned = np.zeros((self.num_slots,), bool)
         owned[active] = True
+        more = dict(passes=k_block, block_length=self.block_length) \
+            if self.block_length else {}
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
-                          cache_rows=rows, cache_rows_held=held):
+                          cache_rows=rows, cache_rows_held=held, **more):
             # Everything the block asks of the device: the key's split,
             # the transfer, the program, the slice and the copies' start.
             with self._launch_span(
                     PROGRAM_NAMES["decode_multi"].format(k=k_block)):
                 self._key, sub = jax.random.split(self._key)
                 live = jnp.asarray(owned)
-                moe = None
-                if k_block == 1 and not self._routed_layers:
+                moe = lps = None
+                if self.block_length:
+                    self.cache, self._blocks, toks, *moe = \
+                        decode_block_multi(
+                            self.cfg, self.params, self.cache, self._blocks,
+                            self._temps, k_block, self.top_k, sub, live)
+                    moe = moe[0] if moe else None
+                    _copy_to_host_async(*toks)
+                elif k_block == 1 and not self._routed_layers:
                     self.cache, logits = decode_step(
                         self.cfg, self.params, self.cache,
                         self.cur_tokens, live)
@@ -1174,15 +1379,32 @@ class LLMEngine:
                         self.cur_tokens, self._temps, k_block,
                         self.top_k, sub, live)             # (k, B)
                     moe = moe[0] if moe else None   # routing stats (3,)
-                self.cur_tokens = toks[-1]
                 # Start the host copy NOW, before the next tick enqueues
                 # prefills and the next block behind it.
-                _copy_to_host_async(toks, lps, moe)
+                if not self.block_length:
+                    self.cur_tokens = toks[-1]
+                    _copy_to_host_async(toks, lps)
+                _copy_to_host_async(moe)
             self.decode_ticks += k_block
             for i in active:
                 snap[i].inflight += k_block
         return (toks, lps, k_block, [(i, snap[i]) for i in active], number,
                 moe)
+
+    def warm_decode_blocks(self) -> List[int]:
+        """Run the fused decode program of every size the adaptive block
+        can choose that has not run yet, with no slot owned: each
+        compiles, the cache and the slots stay as they are. For a caller
+        that must not compile later (a benchmark's set-up), before the
+        engine's thread starts. Returns the sizes it ran."""
+        ran, k = [], 1
+        while k <= self.decode_block:
+            if k not in self.counts["blocks_by_k"]:
+                self._process_block(self._dispatch_block(
+                    k, list(self.slots), []))
+                ran.append(k)
+            k *= 2
+        return ran
 
     def _process_block(self, block) -> None:
         """Fetch a dispatched decode block's tokens and emit them.
@@ -1193,21 +1415,36 @@ class LLMEngine:
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
         toks, lps, k_block, slot_snap, number, moe = block
+        # `slots`: positions a step computes (a slot's block a pass).
         span = tracing.span("engine.process_block", block=number, k=k_block,
-                            slots=self.num_slots, active=len(slot_snap))
+                            slots=self.num_slots * self._step_rows,
+                            active=len(slot_snap))
         with span:
             # the host waits here
             with self._wait_span("engine.fetch", "fetch_wait_ns"):
-                host_toks = np.asarray(toks)
-                # (B,) after a one-step block's own sampler
-                host_lps = np.asarray(lps).reshape(host_toks.shape)
+                if self.block_length:
+                    host = [np.asarray(a) for a in toks]
+                    # The tiles behind these passes: no first token
+                    # waits for them, so their numbers ride this span.
+                    self._read_tile_moe(span)
+                else:
+                    host_toks = np.asarray(toks)
+                    # (B,) after a one-step block's own sampler
+                    host_lps = np.asarray(lps).reshape(host_toks.shape)
                 host_moe = np.asarray(moe) if moe is not None else None
             self.steps_processed += k_block
             before = self.tokens_out
             with self._emit_span():
-                self._emit_block(host_toks, host_lps, k_block, slot_snap)
+                if self.block_length:
+                    passes = self._emit_passes(host, k_block, slot_snap)
+                else:
+                    self._emit_block(host_toks, host_lps, k_block, slot_snap)
             emitted = self.tokens_out - before
-            discarded = k_block * len(slot_snap) - emitted
+            discarded = k_block * len(slot_snap) * self._step_rows - emitted
+            if self.block_length:
+                for name, n in passes.items():
+                    self.counts[name] += n
+                span.set(block_length=self.block_length, **passes)
             self.counts["tokens_discarded"] += discarded
             span.set(emitted=emitted, discarded=discarded)
             if host_moe is not None:
@@ -1234,13 +1471,60 @@ class LLMEngine:
                 self._emit(slot, tok, host_lps[t, i])
                 done = (tok == slot.req.eos_token
                         or slot.emitted >= slot.req.max_new_tokens
-                        or slot.length >= self.max_seq_len - 1)
+                        or self._cache_ended(slot))
                 if done:
                     # Remaining in-block tokens for this slot are
                     # discarded; the slot frees for readmission.
                     self._finish(i)
                     break
                 slot = self.slots[i]
+
+    def _emit_passes(self, host, k_block: int, slot_snap: List
+                     ) -> Dict[str, int]:
+        """A fused block of passes (`decode_block_multi`): where a pass
+        committed a slot's block, the block's new tokens go to the
+        request, each with the pass that unmasked it. The last block is
+        generated whole and cut at `max_new_tokens`, or behind an eos.
+        Returns what the passes did, for the span and the counters."""
+        kind, x, at_pass, logp, unmasked = host
+        Bd = self.block_length
+        owned = [i for i, _ in slot_snap]
+        done = dict(
+            denoise_passes=int(np.sum(kind[:, owned] == PASS_DENOISE)),
+            commit_passes=int(np.sum(kind[:, owned] == PASS_COMMIT)),
+            blocks_committed=0,
+            positions_unmasked=int(np.sum(unmasked[:, owned])),
+            tokens_truncated=0)
+        now = time.monotonic()
+        for i, slot0 in slot_snap:
+            slot0.inflight -= k_block
+            if self.slots[i] is not slot0:
+                continue  # freed (and possibly readmitted) meanwhile
+            req = slot0.req
+            for t in np.flatnonzero(kind[:, i] == PASS_COMMIT):
+                # The block stood at rows [length, length + Bd): what the
+                # prompt left over of its last whole block came fixed.
+                fixed = max(0, len(req.prompt) - slot0.length)
+                slot0.length += Bd
+                slot0.blocks_left -= 1
+                done["blocks_committed"] += 1
+                ended = False
+                for j in range(fixed, Bd):
+                    if ended or slot0.emitted >= req.max_new_tokens:
+                        done["tokens_truncated"] += 1
+                        continue
+                    if req.first_token_ts == 0.0:
+                        req.first_token_ts = now
+                    tok = int(x[t, i, j])
+                    req.unmasked_at.append(int(at_pass[t, i, j]))
+                    self._emit(slot0, tok, logp[t, i, j], rows=0)
+                    ended = tok == req.eos_token
+                if ended or slot0.emitted >= req.max_new_tokens \
+                        or self._cache_ended(slot0):
+                    # What the program ran on for this slot is discarded.
+                    self._finish(i)
+                    break
+        return done
 
     def run_forever(self) -> None:
         while not self._stop:

@@ -66,6 +66,8 @@ def pytest_collection_finish(session):
             tiny.setdefault("mellum2-repoctx-lone", "tiny-mellum-lone")
             # tests/benchmark/test_pangu_cell.py makes this one's.
             tiny.setdefault("openpangu-longgen-closed", "tiny-pangu-closed")
+            # tests/benchmark/test_sdar_cell.py makes this one's.
+            tiny.setdefault("sdar-blockgen-closed", "tiny-sdar-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -78,7 +80,8 @@ def _per_layer():
 
 def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
-    test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py) names
+    test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
+    test_sdar_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(
@@ -138,7 +141,13 @@ def pytest_runtest_call(item):
                           ("workloads", entries.get("workload"))):
             if mine in bench.get(key, ()):
                 seen[key] = bench[key][:bench[key].index(mine) + 1]
-        cell = (entries.get("workload") or {}).get("name")
+    # A file without `ENTRIES` (test_pangu_cell.py) holds the metrics it
+    # appended to list its cell (`REAL`) alone: a later cell that one of
+    # their readers fits goes behind it there too.
+    cell = ((entries.get("workload") or {}).get("name")
+            if isinstance(entries, dict) else None) \
+        or getattr(mod, "REAL", None)
+    if cell:
         for kind in ("end_to_end", "per_layer"):
             seen[kind] = [
                 dict(m, workloads=m["workloads"][

@@ -473,6 +473,28 @@ def _module_name(lowered):
     return re.search(r"module @(\S+)", lowered.as_text()).group(1)
 
 
+def _block_generation_programs():
+    """The three programs of a configuration with `block_length`,
+    lowered at a tiny size (the fused one at k = 8)."""
+    cfg = configs.tiny_sdar_test()
+    params = init_params(cfg, jax.random.key(0))
+    W, S, B, Bd = 8, 16, 2, cfg.block_length
+    cache = generate.init_kv_cache(cfg, B, 32)
+    state = generate.init_block_state(cfg, B)
+    rows, temps = jnp.zeros((W,), jnp.int32), jnp.zeros((B,), jnp.float32)
+    return {
+        "prefill_block_batch": generate.prefill_block_batch.lower(
+            cfg, params, cache, state, jnp.zeros((W, S), jnp.int32), rows,
+            rows, jnp.zeros((W, Bd), jnp.int32), jnp.ones((W, Bd), bool),
+            rows, rows, jnp.zeros((W,), jnp.float32)),
+        "decode_block_step": generate.decode_block_step.lower(
+            cfg, params, cache, jnp.zeros((B, Bd), jnp.int32),
+            jnp.zeros((B,), jnp.int32)),
+        "decode_block_multi": generate.decode_block_multi.lower(
+            cfg, params, cache, state, temps, 8, 0, jax.random.key(0)),
+    }
+
+
 def test_every_serving_program_lowers_under_its_table_name(tiny_model):
     cfg, params = tiny_model
     W, S, B = 8, 16, 2
@@ -503,8 +525,17 @@ def test_every_serving_program_lowers_under_its_table_name(tiny_model):
         "sample_batch": (llm._sample_batch, (logits, t2, key, 0)),
     }
     blocks = {"decode_multi": generate.decode_multi}
-    assert set(args) | set(blocks) == set(generate.PROGRAM_NAMES)
-    assert len(generate.PROGRAM_NAMES) == 8
+    # Where a model generates a block of positions a pass, its programs
+    # stand in the decode programs' places, under their names.
+    by_blocks = _block_generation_programs()
+    assert set(args) | set(blocks) | set(by_blocks) \
+        == set(generate.PROGRAM_NAMES)
+    assert len(generate.PROGRAM_NAMES) == 11
+    for key_, lowered in by_blocks.items():
+        name = generate.PROGRAM_NAMES[key_].format(k=8)
+        assert _module_name(lowered) == "jit_" + name
+        assert bool(DECODE.search(name)) == key_.startswith("decode")
+        assert bool(PREFILL.search(name)) == key_.startswith("prefill")
     names = {}
     for key_, (fn, a) in args.items():
         names[key_] = _module_name(fn.lower(*a))

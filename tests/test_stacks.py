@@ -17,7 +17,8 @@ from ray_tpu.models.transformer import STACKS, init_params, offered, stack
 # The tiny preset of each architecture of the table.
 TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "mellum": configs.tiny_mellum_test,
-        "pangu_ultra_moe": configs.tiny_pangu_test}
+        "pangu_ultra_moe": configs.tiny_pangu_test,
+        "sdar_moe": configs.tiny_sdar_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -57,11 +58,34 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
         lambda p, x, n: st.last_logits(cfg, p, x, n), params, x, rows)
     assert (logits.shape, logits.dtype) == ((W, V), jnp.float32)
 
-    stepped, logits, stats = jax.eval_shape(
-        lambda p, c, t: st.decode(cfg, p, c, t), params, cache,
-        jax.ShapeDtypeStruct((B,), jnp.int32))
+    def decode():
+        return jax.eval_shape(
+            lambda p, c, t: st.decode(cfg, p, c, t), params, cache,
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+
+    if cfg.block_length:
+        # A block of positions a slot a pass is its walk; one token a
+        # step is refused with the reason, here and in the programs.
+        Bd = cfg.block_length
+        with pytest.raises(NotImplementedError) as e:
+            decode()
+        assert str(e.value) == st.NOT_ITS_WALK["decode"]
+        with pytest.raises(NotImplementedError):
+            generate.decode_step.lower(
+                cfg, params, cache, jax.ShapeDtypeStruct((B,), jnp.int32))
+        stepped, logits, stats = jax.eval_shape(
+            lambda p, c, t, p0: st.decode_block(cfg, p, c, t, p0), params,
+            cache, jax.ShapeDtypeStruct((B, Bd), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+        assert (logits.shape, logits.dtype) == ((B, Bd, V), jnp.float32)
+    else:
+        stepped, logits, stats = decode()
+        assert (logits.shape, logits.dtype) == ((B, V), jnp.float32)
+        if hasattr(st, "decode_block"):
+            with pytest.raises(NotImplementedError) as e:
+                st.decode_block(cfg, params, cache, None, None)
+            assert str(e.value) == st.NOT_ITS_WALK["decode_block"]
     assert jax.tree.structure(stepped) == jax.tree.structure(cache)
-    assert (logits.shape, logits.dtype) == ((B, V), jnp.float32)
     assert (stats is None) == (st.routed_layers(cfg) == 0)
     assert jax.tree.map(lambda a: (a.shape, a.dtype), tile_stats) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), stats)

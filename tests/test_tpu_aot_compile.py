@@ -903,3 +903,93 @@ def test_pangu_admission_tile_fits_beside_weights_and_latent_cache(
     # One trace a group of the plan (the dense layer, the routed layers).
     assert fa.DISPATCH_COUNTS["pallas"] - before == 2
     _pangu_fits(serve_pangu, mem, record_property)
+
+
+# -- sdar-blockgen-closed: a block of four positions a slot a pass -----------
+
+@pytest.fixture(scope="module")
+def serve_sdar(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "sdar-blockgen-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("sdar-30b-a3b-l7", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _sdar_blocks(cfg, slots, one):
+    from ray_tpu.models.generate import init_block_state
+
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: init_block_state(cfg, slots)))
+
+
+def _sdar_fits(serve_sdar, mem, record_property):
+    cfg, slots, one, key, params, cache = serve_sdar
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    held = 2 * cache.k.size * cache.k.dtype.itemsize
+    # ISSUE 39's arithmetic: 4.984 B parameters, 9.97 GB in bf16, beside
+    # 64 slots x 2,048 rows of 2 x 4 x 128 values over seven layers.
+    assert 9.96e9 < weights < 9.98e9
+    assert cache.k.shape == (7, 64, 2048, 4, 128) and cache.kw is None
+    assert 1.87e9 < held < 1.89e9
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    # The whole cache aliased: no program copies it in or out.
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_sdar_block_program_runs_four_positions_a_slot_through_the_kernels(
+        serve_sdar, as_on_the_chip, record_property):
+    """`decode_block_multi` (k = 8 passes) at the cell's 64 slots x 2,048:
+    the decode kernel with a block's four queries beside the eight heads
+    of their group (32 query rows a KV head), megablox's kernel over 256
+    rows x top 8, the head and the sampler under their scopes, cache and
+    block state updated in place."""
+    from ray_tpu.models.generate import decode_block_multi
+
+    cfg, slots, one, key, params, cache = serve_sdar
+    assert (cfg.block_length, cfg.denoise_steps, cfg.remask) == (
+        4, 2, "low_confidence_static")
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_block_multi.lower(
+        cfg, params, cache, _sdar_blocks(cfg, slots, one), temps, 8, 0, key,
+        live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts" in text and "jit(gmm)" in text
+    assert "ragged-dot" not in text and "decode_attn" in text
+    for scope in ("attn_global", "moe_router", "block_head", "block_sample"):
+        assert scope in text, scope
+    _sdar_fits(serve_sdar, mem, record_property)
+
+
+@pytest.mark.parametrize("bucket", [64, 128, 256, 512])
+def test_sdar_admission_tiles_fit_beside_weights_and_cache(
+        serve_sdar, as_on_the_chip, record_property, bucket):
+    """The tiles of the buckets the cell's prompts (64-508 tokens) reach,
+    as the engine builds them (`_tile_rows`: 512 positions a tile), under
+    the block-causal mask: no head, no sample."""
+    from ray_tpu.models.generate import prefill_block_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_sdar
+    W = LLMEngine._tile_rows(bucket)
+    assert W * bucket == 512
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = prefill_block_batch.lower(
+        cfg, params, cache, _sdar_blocks(cfg, slots, one),
+        arr((W, bucket), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), arr((W, 4), jnp.int32), arr((W, 4), jnp.bool_),
+        arr((W,), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.float32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "jit(gmm)" in text and "ragged-dot" not in text
+    _sdar_fits(serve_sdar, mem, record_property)
