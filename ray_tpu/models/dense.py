@@ -167,28 +167,31 @@ def _qkv(cfg: TransformerConfig, lp, x, sin, cos):
     return _rope(q, sin, cos), _rope(k, sin, cos), v
 
 
-def _ffn(cfg: TransformerConfig, lp, x):
+def _ffn(cfg: TransformerConfig, lp, x, rows=None):
     if cfg.is_moe:
         # Routed, nothing dropped (models/moe.py): scores and selection
         # in float32 from the norm's float32 output.
         from .moe import routed_ffn
         B, S, D = x.shape
         m = rms_norm(x.astype(jnp.float32), lp["ffn_norm"], cfg.norm_eps)
-        f, _, _ = routed_ffn(cfg, lp, m.reshape(B * S, D), x.dtype)
+        f, _, _ = routed_ffn(cfg, lp, m.reshape(B * S, D), x.dtype,
+                             rows=rows)
         return x + f.reshape(B, S, D).astype(x.dtype)
     return x + dense_ffn(lp, rms_norm(x, lp["ffn_norm"], cfg.norm_eps))
 
 
-def layer(cfg: TransformerConfig, lp, x, sin, cos, attend):
+def layer(cfg: TransformerConfig, lp, x, sin, cos, attend, rows=None):
     """One layer on x (B, S, D). `attend(q, k, v) -> (out, kept)` does
     the attention (out: B x S rows of H*Dh, in any grouping) and says
-    what it keeps of k and v. Returns (x, kept)."""
+    what it keeps of k and v. `rows` (B*S,) bool: the rows somebody owns,
+    the only ones a routed FFN's experts take (None: every row). Returns
+    (x, kept)."""
     B, S, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _qkv(cfg, lp, h, sin, cos)
     out, kept = attend(q, k, v)
     x = x + (out.reshape(B, S, -1) @ lp["wo"].astype(x.dtype))
-    return _ffn(cfg, lp, x), kept
+    return _ffn(cfg, lp, x, rows), kept
 
 
 def _tile_attend(q, k, v):
@@ -281,7 +284,8 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
         lp, l = scanned
         x, (k_all, v_all) = layer(
             cfg, lp, x, sin, cos,
-            partial(_cache_attend, cfg, k_all, v_all, l, positions, live))
+            partial(_cache_attend, cfg, k_all, v_all, l, positions, live),
+            live)
         return (x, k_all, v_all), None
 
     (x, k, v), _ = lax.scan(

@@ -335,7 +335,7 @@ def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
                         slots: jax.Array):
     """Batched-prefill body: write each prompt's KV into its slot,
     return (cache', last-real-token logits (W, V), the tile's routing
-    stats (3,) or None: `routed_layers`)."""
+    stats or None: `routed_layers`)."""
     st = stack(cfg)
     cache, x, stats = st.prefill(cfg, params, cache, tokens, lengths, slots)
     return cache, st.last_logits(cfg, params, x, lengths), stats
@@ -490,12 +490,14 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
 def routed_layers(cfg: TransformerConfig) -> int:
     """The layers whose experts' use the fused decode blocks and the
     admission tiles (`prefill_sample_batch`) of `cfg` count: with any,
-    either returns, after its other results, int32 (3,) = [experts that
-    held a row, summed over steps (one for a tile) and those layers; rows
-    routed; the fullest expert's rows, summed over steps and layers]; a
-    stack whose layers hold a share of their experts counts those three
-    over the experts held and adds the pairs routed over all of them
-    (its `routing_stats(cfg)` says 4)."""
+    either returns, after its other results, int32 (4,) = [experts that
+    took a row, summed over steps (one for a tile) and those layers;
+    pairs routed, every slot's; the pairs of the expert most chosen,
+    summed over steps and layers; rows the experts took: the pairs of
+    the slots a request owns (a tile: every position's)]; a stack whose
+    layers hold a share of their experts counts those four over the
+    experts held and adds the pairs routed over all of them (the stack's
+    `routing_stats(cfg)` says how many entries: `moe.routed_ffn`)."""
     return stack(cfg).routed_layers(cfg)
 
 
@@ -537,8 +539,8 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
         return (cache, tok, routed), (tok, lp)
 
     subs = jax.random.split(key, num_steps)
-    routed = jnp.zeros((getattr(st, "routing_stats", lambda _: 3)(cfg),),
-                       jnp.int32) if routed_layers(cfg) else None
+    routed = jnp.zeros((st.routing_stats(cfg),), jnp.int32) \
+        if routed_layers(cfg) else None
     (cache, _, routed), (toks, lps) = lax.scan(
         body, (cache, tokens, routed), subs)
     return (cache, toks, lps) if routed is None \
@@ -746,7 +748,8 @@ def _decode_block_multi(cfg: TransformerConfig, params, cache: KVCache,
         return (cache, blocks, routed), out
 
     subs = jax.random.split(key, num_steps)
-    routed = jnp.zeros((3,), jnp.int32) if routed_layers(cfg) else None
+    routed = jnp.zeros((stack(cfg).routing_stats(cfg),), jnp.int32) \
+        if routed_layers(cfg) else None
     (cache, blocks, routed), out = lax.scan(
         body, (cache, blocks, routed), subs)
     return (cache, blocks, out) if routed is None \

@@ -338,13 +338,16 @@ def _attend_rows(cfg: TransformerConfig, positions, live, l, lp, q_nope,
     return out.reshape(B, 1, -1).astype(dt), c_all
 
 
-def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state):
+def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
+          rows=None):
     """One layer on x (B, S, D) in the activation dtype. `attend(lp,
     q_nope, q_r, row, state) -> (out (B, S, H*vd), state)` does the
     attention and whatever it keeps of the row. `experts_at`: None for a
     dense FFN, else (the stack's expert matrices, this layer's first
-    group in them). Returns (x, state, routing stats or None, experts
-    chosen (B*S, K) or None)."""
+    group in them). `rows` (B*S,) bool: the rows somebody owns, the only
+    ones the routed experts take (`moe.routed_ffn`; None: every row).
+    Returns (x, state, routing stats or None, experts chosen (B*S, K) or
+    None)."""
     B, S, _ = x.shape
     dt, eps = cfg.dtype, cfg.norm_eps
 
@@ -361,7 +364,8 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state):
     stats = experts = None
     if experts_at is not None:
         flat = m.reshape(B * S, -1)
-        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at)
+        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at,
+                                       rows=rows)
         if cfg.moe_shared_experts:
             with jax.named_scope("moe_shared"):
                 f = f + _swiglu(flat.astype(dt), lp["shared_gate"],
@@ -372,12 +376,13 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state):
     return joins(f, "post_ffn_norm"), state, stats, experts
 
 
-def _run(cfg: TransformerConfig, params, x, rope, attend, state):
+def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
     """x through every layer: one `lax.scan` a group of the plan, `state`
     (the cache, or nothing) riding in the carry beside x. `attend(l, lp,
-    q_nope, q_r, row, state)` is told which layer it serves. Returns (x,
-    state, routing stats summed over layers, experts chosen: one array
-    (layers, B*S, K) a routed group)."""
+    q_nope, q_r, row, state)` is told which layer it serves; `rows`
+    (see `layer`) are the rows somebody owns. Returns (x, state, routing
+    stats summed over layers, experts chosen: one array (layers, B*S, K)
+    a routed group)."""
     stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
     chosen, base = [], 0
     for name, n, routed in layer_plan(cfg):
@@ -395,7 +400,7 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state):
             lp, g = scanned
             x, state, st, ex = layer(
                 cfg, lp, x, expert_w and (expert_w, g * cfg.moe_experts),
-                rope, partial(attend, base + g), state)
+                rope, partial(attend, base + g), state, rows)
             if st is not None:
                 stats = stats + st
             return (x, state, stats), ex
@@ -465,16 +470,17 @@ def forward_free(cfg: TransformerConfig, params, tokens):
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
            live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
     """One token a slot -> (cache', logits (B, V), routing stats of the
-    step: held experts holding a row summed over the routed layers, pairs
-    kept, the fullest held expert's rows summed over the layers and,
-    where the layer holds a share, the pairs routed; None with no routed
-    layer). `live` (B,) bool: the slots a request owns (None: every
-    one)."""
+    step (`moe.routed_ffn`'s, summed over the routed layers): held
+    experts that took a row, pairs kept, the pairs of the held expert
+    most chosen, rows the experts took and, where the layer holds a
+    share, the pairs routed; None with no routed layer). `live` (B,)
+    bool: the slots a request owns (None: every one): any other slot
+    reads and writes no cache row and its token meets no expert."""
     positions = cache.seq_lens
     rope = _rope_tables(cfg, cache.max_seq_len, positions)
     x, c_all, stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
-        partial(_attend_rows, cfg, positions, live), cache.c)
+        partial(_attend_rows, cfg, positions, live), cache.c, live)
     cache = cache._replace(c=c_all, seq_lens=positions + 1)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
         stats if routed_layers(cfg) else None

@@ -163,10 +163,17 @@ def _grouped_swiglu(w: Dict[str, jax.Array], xs: jax.Array,
 
 def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
                     weights: jax.Array, experts: jax.Array, n_experts: int,
-                    first=0) -> Tuple[jax.Array, jax.Array]:
+                    first=0, rows=None
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x (T, D) in the products' dtype; weights, experts (T, K). Returns
     (sum over a token's experts of weight x SwiGLU_e(x), float32 (T, D);
-    rows an expert holds, int32 (E,)).
+    rows an expert took, int32 (E,); pairs that chose it, int32 (E,)).
+
+    `rows` (T,) bool: the rows somebody owns (None: every one). A pair
+    of any other row chooses no expert: it sorts last and counts in no
+    group, so the products neither read nor write its row, the experts
+    only it chose are not fetched, and its row of the result is zero.
+    The pairs that chose an expert count it all the same.
 
     `w` holds the three expert matrices, (G, D, F) and (G, F, D): this
     layer's E experts are groups [first, first + E) of G. A stack hands
@@ -177,14 +184,26 @@ def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
     T, K = experts.shape
     E, G = n_experts, w["w_gate"].shape[0]
     flat = experts.reshape(T * K)
-    order = jnp.argsort(flat, stable=True)       # pairs, sorted by expert
-    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    # An unowned pair of expert e takes the key E + e: last in the sort,
+    # and counted apart in the one count.
+    key = flat if rows is None else jnp.where(
+        jnp.repeat(rows, K), flat, E + flat)
+    order = jnp.argsort(key, stable=True)        # pairs, sorted by expert
+    count = jnp.bincount(
+        key, length=E if rows is None else 2 * E).astype(jnp.int32)
+    sizes = chose = count
+    if rows is not None:
+        sizes, chose = count[:E], count[:E] + count[E:]
     groups = sizes if G == E else lax.dynamic_update_slice(
         jnp.zeros((G,), jnp.int32), sizes, (first,))
     ys = _grouped_swiglu(w, x[order // K], groups)    # (T*K, D)
     ys = ys[jnp.argsort(order)].reshape(T, K, -1)     # back in order
-    out = jnp.sum(ys * weights[..., None], axis=1)
-    return out, sizes
+    ys = ys * weights[..., None]
+    if rows is not None:
+        # A row of no group was never written: what lies there must not
+        # reach the sum.
+        ys = jnp.where(rows[:, None, None], ys, 0.0)
+    return jnp.sum(ys, axis=1), sizes, chose
 
 
 def held_pass_rows(pairs: int, held: int, routed: int) -> int:
@@ -198,14 +217,16 @@ def held_pass_rows(pairs: int, held: int, routed: int) -> int:
 
 def held_experts(w: Dict[str, jax.Array], x: jax.Array, weights: jax.Array,
                  experts: jax.Array, n_held: int, first_held: int,
-                 n_routed: int, first=0) -> Tuple[jax.Array, jax.Array]:
+                 n_routed: int, first=0, rows=None
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """`grouped_experts` for a layer that holds experts [first_held,
     first_held + n_held) of the `n_routed` its router chose among:
     `experts` (T, K) name any of them, the pairs that chose a held one
     are kept, and the result is the part of the sum the held experts
-    give, float32 (T, D), beside the rows each held expert took, int32
-    (n_held,). The rest of the sum is other chips'; nothing stands in
-    for it here.
+    give, float32 (T, D), beside the rows each held expert took and the
+    pairs that chose it, int32 (n_held,) each. The rest of the sum is
+    other chips'; nothing stands in for it here. A pair of a row nobody
+    owns (`rows`) is an absent pair.
 
     Kept pairs come first in the sort, by expert, and are worked off a
     pass of `held_pass_rows` at a time: a pass gathers its rows of x,
@@ -224,8 +245,14 @@ def held_experts(w: Dict[str, jax.Array], x: jax.Array, weights: jax.Array,
     C = held_pass_rows(P, E, n_routed)
     local = experts.reshape(P) - first_held
     key = jnp.where((local >= 0) & (local < E), local, E)   # absent: last
+    if rows is not None:
+        # An unowned pair is absent too, and counted apart: E + 1 + key.
+        key = jnp.where(jnp.repeat(rows, K), key, E + 1 + key)
     order = jnp.argsort(key, stable=True)
-    sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+    count = jnp.bincount(key, length=E + 1 if rows is None else 2 * E + 2)
+    sizes = chose = count[:E].astype(jnp.int32)
+    if rows is not None:
+        chose = sizes + count[E + 1:2 * E + 1].astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     starts, kept = ends - sizes, ends[-1]
     room = -(-P // C) * C - P           # the last pass may reach past P
@@ -252,7 +279,7 @@ def held_experts(w: Dict[str, jax.Array], x: jax.Array, weights: jax.Array,
 
     out = lax.fori_loop(0, (kept + C - 1) // C, one_pass,
                         jnp.zeros((T, w["w_down"].shape[-1]), jnp.float32))
-    return out, sizes
+    return out, sizes, chose
 
 
 def _own_rows(owns: jax.Array, ys: jax.Array) -> jax.Array:
@@ -268,36 +295,45 @@ def _own_rows(owns: jax.Array, ys: jax.Array) -> jax.Array:
 
 
 def routing_stats(cfg) -> int:
-    """Entries of `routed_ffn`'s stats: three, and the pairs routed
+    """Entries of `routed_ffn`'s stats: four, and the pairs routed
     where the layer holds a share of the experts its router scores."""
-    return 3 if cfg.router_experts == cfg.moe_experts else 4
+    return 4 if cfg.router_experts == cfg.moe_experts else 5
 
 
 def routed_ffn(cfg, lp: Dict[str, jax.Array], m: jax.Array, dtype,
-               expert_weights=None, first=0
+               expert_weights=None, first=0, rows=None
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The routed experts of one layer on m (T, D) float32: (out (T, D)
-    float32, stats int32 (3,) = [experts holding a row, rows, rows of
-    the fullest expert], experts (T, K)). The expert matrices are `lp`'s
+    float32, stats int32 (4,) = [experts that took a row, pairs that
+    chose an expert here, pairs of the expert most chosen, rows the
+    products took], experts (T, K)). The expert matrices are `lp`'s
     own, or `expert_weights` from group `first` on (`grouped_experts`).
 
+    `rows` (T,) bool: the rows somebody owns (None: every one). The
+    router scores every row, and the second and third entries count
+    every row's pairs: the router's balance. The products take the owned
+    rows' pairs alone, an unowned row comes back zero, and the first and
+    fourth entries count what the products took and fetched.
+
     Where the layer holds a share of the experts its router scores
-    (`cfg.router_experts` > `cfg.moe_experts`: `held_experts`) the three
-    count over the experts held and the pairs kept, and a fourth entry
+    (`cfg.router_experts` > `cfg.moe_experts`: `held_experts`) the four
+    count over the experts held and the pairs kept, and a fifth entry
     has the pairs routed, kept or not."""
     with jax.named_scope("moe_router"):
         weights, experts = route(cfg, lp, m)
     w = lp if expert_weights is None else expert_weights
-    share = routing_stats(cfg) == 4
+    share = routing_stats(cfg) == 5
     with jax.named_scope("moe_experts"):
         if not share:
-            out, sizes = grouped_experts(w, m.astype(dtype), weights,
-                                         experts, cfg.moe_experts, first)
-        else:
-            out, sizes = held_experts(
+            out, sizes, chose = grouped_experts(
                 w, m.astype(dtype), weights, experts, cfg.moe_experts,
-                cfg.moe_first_expert, cfg.router_experts, first)
-    stats = [jnp.sum(sizes > 0), jnp.sum(sizes), jnp.max(sizes)]
+                first, rows)
+        else:
+            out, sizes, chose = held_experts(
+                w, m.astype(dtype), weights, experts, cfg.moe_experts,
+                cfg.moe_first_expert, cfg.router_experts, first, rows)
+    stats = [jnp.sum(sizes > 0), jnp.sum(chose), jnp.max(chose),
+             jnp.sum(sizes)]
     if share:
         stats.append(experts.size)
     return out, jnp.stack(stats).astype(jnp.int32), experts

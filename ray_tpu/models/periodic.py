@@ -62,7 +62,8 @@ from jax import lax
 from ..parallel.sharding import with_sharding_constraint as wsc
 from .generate import (KVCache, _attend_cache, _attend_cache_block,
                        _last_rows, _rope, masked_softmax, rows_held)
-from .moe import EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn
+from .moe import (EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn,
+                  routing_stats)
 from .transformer import TransformerConfig, rope_tables
 
 WINDOW, GLOBAL = "window", "global"
@@ -261,14 +262,15 @@ def _swiglu(m: jax.Array, gate, up, down) -> jax.Array:
 
 
 def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
-          attend, state):
+          attend, state, rows=None):
     """One layer on x (B, S, D) in the activation dtype. `rope`: {kind:
     (sin, cos)} for the kinds that rotate (`rope_by_kind`). `attend(kind,
     q, k, v, state) -> (out (B, S, H, Dh), state)` does the attention and
     whatever it keeps of k and v. `experts_at`: None for a dense FFN, else
     (the stack's expert matrices, this layer's first group in them).
-    Returns (x, state, routing stats (3,), experts chosen (B*S, K) or
-    None)."""
+    `rows` (B*S,) bool: the rows somebody owns, the only ones a routed
+    layer's experts take (`moe.routed_ffn`; None: every row). Returns (x,
+    state, routing stats, experts chosen (B*S, K) or None)."""
     B, S, _ = x.shape
     H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt, eps = cfg.dtype, cfg.norm_eps
@@ -301,10 +303,11 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
 
     m = _norm(x, lp["ffn_norm"], eps)                      # float32
     experts = None
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
     if experts_at is not None:
         flat = m.reshape(B * S, -1)
-        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at)
+        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at,
+                                       rows=rows)
         if cfg.moe_shared_experts:
             with jax.named_scope("moe_shared"):
                 f = f + _swiglu(flat.astype(dt), lp["shared_gate"],
@@ -333,15 +336,16 @@ def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
     return out
 
 
-def _run(cfg: TransformerConfig, params, x, rope, attend, state):
+def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
     """x through every layer: one `lax.scan` a group of the plan, a
     group's layers unrolled in its body, `state` (the cache, or nothing)
     riding in the carry beside x. `attend(l, kind, q, k, v, state)` is
-    told which layer of its kind it serves. Returns (x, state, routing
-    stats summed over layers, experts chosen: a tuple a group of arrays
-    (groups, B*S, K), one a routed layer of the group)."""
+    told which layer of its kind it serves; `rows`: see `layer`. Returns
+    (x, state, routing stats summed over layers, experts chosen: a tuple
+    a group of arrays (groups, B*S, K), one a routed layer of the
+    group)."""
     at = dict.fromkeys(KINDS, 0)       # the group's first layer, by kind
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
     chosen = []
     for name, n, kinds, routed in layer_plan(cfg):
         stacked = params[name]
@@ -369,7 +373,7 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state):
                 first = (g * len(kinds) + j) * cfg.moe_experts
                 x, state, st, ex = layer(
                     cfg, lp, x, kind, expert_w and (expert_w, first), rope,
-                    partial(attend, l), state)
+                    partial(attend, l), state, rows)
                 stats = stats + st
                 if ex is not None:
                     experts.append(ex)
@@ -574,7 +578,7 @@ def _free_attend(cfg, l, kind, q, k, v, state):
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
             slots) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D), routing stats of the tile (3,) as `decode`
+    hidden states (W, S, D), routing stats of the tile as `decode`
     gives a step's, over all W x S positions, padding too; None with no
     routed layer). With `cfg.block_length` the mask is block-causal and
     `lengths` are whole blocks (what is left of a prompt opens the
@@ -601,10 +605,11 @@ def forward_free(cfg: TransformerConfig, params, tokens):
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
            live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
     """One token a slot -> (cache', logits (B, V), routing stats of the
-    step (3,): experts holding a row summed over the routed layers, rows
-    routed, and the fullest expert's rows summed over the layers; None
-    with no routed layer). `live` (B,) bool: the slots a request owns
-    (None: every one)."""
+    step (`moe.routed_ffn`'s, summed over the routed layers): experts
+    that took a row, pairs routed, the pairs of the expert most chosen,
+    rows the experts took; None with no routed layer). `live` (B,) bool:
+    the slots a request owns (None: every one): any other slot reads and
+    writes no cache row and its token meets no expert."""
     if cfg.block_length:
         raise NotImplementedError(NOT_ITS_WALK["decode"])
     positions = cache.seq_lens
@@ -612,7 +617,7 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     x, (kg, vg, kw, vw), stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
         partial(_decode_attend, cfg, positions, live),
-        (cache.k, cache.v, cache.kw, cache.vw))
+        (cache.k, cache.v, cache.kw, cache.vw), live)
     cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
         stats if routed_layers(cfg) else None
@@ -623,15 +628,16 @@ def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
     """One pass over a block a slot: tokens (B, Bd) (the mask token's id
     where a position is still masked) at positions p0 .. p0 + Bd - 1 (p0
     (B,): the rows the slot has committed, a multiple of Bd) -> (cache',
-    logits (B, Bd, V), routing stats of the pass (3,) as `decode` gives a
-    step's, over all B x Bd rows; None with no routed layer). Each layer
+    logits (B, Bd, V), routing stats of the pass as `decode` gives a
+    step's, over B x Bd rows; None with no routed layer). Each layer
     writes the block's keys and values at rows [p0, p0 + Bd) and every
     query of the block attends over rows [0, p0 + Bd): the committed
     prefix and the block itself, no mask inside it. `seq_lens` is left
     as it is: the rows are the block's for good only when the caller
     advances it (a pass over the block's final tokens), and the block's
     next pass overwrites them until then. A slot that is not `live`, or
-    whose block would pass the cache's end, writes and reads nothing."""
+    whose block would pass the cache's end, writes and reads nothing and
+    its block's positions meet no expert."""
     if not cfg.block_length:
         raise NotImplementedError(NOT_ITS_WALK["decode_block"])
     if cache_terms(cfg) == 2:
@@ -645,7 +651,7 @@ def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
     x, (kg, vg, kw, vw), stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens), rope,
         partial(_block_attend, cfg, p0, live),
-        (cache.k, cache.v, cache.kw, cache.vw))
+        (cache.k, cache.v, cache.kw, cache.vw), jnp.repeat(live, Bd))
     cache = KVCache(k=kg, v=vg, seq_lens=cache.seq_lens, kw=kw, vw=vw)
     with jax.named_scope("block_head"):
         logits = head_logits(cfg, params, _final(cfg, params, x))

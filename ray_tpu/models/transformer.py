@@ -41,13 +41,15 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #   cache    init_cache(cfg, num_slots, max_seq_len) -> generate.KVCache
 #   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
 #              -> (cache', final-normed hidden states (W, S, D),
-#                  routing stats (3,) or None)
+#                  routing stats or None)
 #            forward_free(cfg, params, tokens (W, S))
 #              -> (final-normed hidden states, experts chosen or None)
 #            decode(cfg, params, cache, tokens (B,), live (B,) bool or None)
-#              -> (cache', logits (B, V), routing stats (3,) or None; (4,)
+#              -> (cache', logits (B, V), routing stats (4,) or None; (5,)
 #                  where the layer holds a share of its experts: the
-#                  pairs routed over the router's whole width behind)
+#                  pairs routed over the router's whole width behind).
+#                  A slot that is not live reads and writes no cache row
+#                  and its token meets no expert (`moe.routed_ffn`)
 #            decode_block(cfg, params, cache, tokens (B, Bd), p0 (B,), live)
 #              -> (cache', logits (B, Bd, V), routing stats): a stack that
 #              serves `cfg.block_length` > 0 (generation by diffusion over
@@ -63,8 +65,8 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #              (`TransformerConfig.__post_init__`)
 #            last_logits(cfg, params, x (W, S, D), lengths) -> (W, V)
 #            routed_layers(cfg): the layers the stats count over
-#            routing_stats(cfg): how many entries the stats have, where
-#              that is not three
+#            routing_stats(cfg): how many entries the stats have, for a
+#              stack with routed layers
 # and, where it has them (`offered`): `suffix` (the walk behind a shared
 # prefix), `param_logical_axes` (sharding rules), `forward_train` (the
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one

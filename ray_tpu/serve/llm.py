@@ -219,13 +219,15 @@ def _ids(reqs) -> str:
 
 def _routing_sums(stats) -> Dict[str, int]:
     """A program's routing stats (`models/generate.routed_layers`) under
-    the counters' names: experts hit, rows routed to the experts held and
-    the fullest expert's rows; and the token-expert pairs routed beside
-    the pairs that chose an expert held here (a layer that holds all its
-    experts keeps every pair; one that holds a share says how many were
-    routed in a fourth entry)."""
-    hit, rows, fullest, *pairs = (int(n) for n in stats)
+    the counters' names: experts that took a row; the pairs that chose
+    an expert held here and those of the expert most chosen, every
+    slot's; the pairs the products took (the slots a request owns); and
+    the token-expert pairs routed beside the pairs that chose an expert
+    held here (a layer that holds all its experts keeps every pair; one
+    that holds a share says how many were routed in a fifth entry)."""
+    hit, rows, fullest, taken, *pairs = (int(n) for n in stats)
     return dict(moe_experts_hit=hit, moe_rows=rows, moe_rows_max=fullest,
+                moe_rows_taken=taken,
                 moe_pairs=pairs[0] if pairs else rows, moe_pairs_held=rows)
 
 
@@ -382,10 +384,11 @@ class LLMEngine:
         self._routed_layers = routed_layers(cfg)
         if self._routed_layers:
             self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
-                               moe_rows=0, moe_rows_max=0,
+                               moe_rows=0, moe_rows_max=0, moe_rows_taken=0,
                                moe_pairs=0, moe_pairs_held=0,
                                prefill_moe_experts_hit=0,
                                prefill_moe_rows=0, prefill_moe_rows_max=0,
+                               prefill_moe_rows_taken=0,
                                prefill_moe_pairs=0,
                                prefill_moe_pairs_held=0)
         # Admission tiles' routing stats, on their way to the host: read
@@ -924,7 +927,7 @@ class LLMEngine:
                                     jnp.asarray(buf), jnp.asarray(lens),
                                     jnp.asarray(slot_idx), self.top_k,
                                     jnp.asarray(temps), sub)
-                    self._tile_moe += moe       # routing stats (3,)
+                    self._tile_moe += moe       # routing stats
                     _copy_to_host_async(*moe)
                 else:
                     sp = len(pkey)
@@ -1378,7 +1381,7 @@ class LLMEngine:
                         self.cfg, self.params, self.cache,
                         self.cur_tokens, self._temps, k_block,
                         self.top_k, sub, live)             # (k, B)
-                    moe = moe[0] if moe else None   # routing stats (3,)
+                    moe = moe[0] if moe else None   # routing stats
                 # Start the host copy NOW, before the next tick enqueues
                 # prefills and the next block behind it.
                 if not self.block_length:

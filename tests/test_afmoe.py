@@ -159,8 +159,9 @@ def test_tile_first_token_and_block_agree_with_the_reference(params):
         seq = toks[i, :lens[i]].tolist() + [want[i]] + out[:, slot].tolist()
         logits = np.asarray(ref.forward_logits(ARCH, params, seq[:-1]))
         assert list(np.argmax(logits[lens[i]:], -1)) == out[:, slot].tolist()
-    hit, rows, fullest = (int(x) for x in np.asarray(stats))
-    assert rows == 4 * 4 * 3 * 2            # steps x layers x slots x top 2
+    hit, rows, fullest, taken = (int(x) for x in np.asarray(stats))
+    # steps x layers x slots x top 2; no `live`: every slot's are taken
+    assert rows == taken == 4 * 4 * 3 * 2
     assert 4 * 4 <= hit <= 4 * 4 * min(8, 3 * 2)
     assert 4 * 4 * 1 <= fullest <= 4 * 4 * 3
 
@@ -331,7 +332,7 @@ def test_routed_layer_against_the_loop(case):
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-6)
     sizes = np.bincount(np.asarray(experts).ravel(), minlength=6)
     assert list(np.asarray(stats)) == [int((sizes > 0).sum()), 18,
-                                       int(sizes.max())]
+                                       int(sizes.max()), 18]
     if case == "one_expert_takes_every_row":
         assert list(sizes) == [0, 9, 0, 0, 9, 0]
         # The bias chooses; the weights are the scores without it.

@@ -696,3 +696,50 @@ def test_after_the_benchmarks_warm_up_admissions_compile_nothing(arch):
         mon.unregister_event_duration_listener(on_duration)
     assert eng.stats()["counts"]["queue_side_first_tokens"] > 0
     assert compiled == []
+
+
+@pytest.mark.parametrize("arch", ["mellum", "pangu", "sdar"])
+def test_a_lone_request_on_four_slots_meets_its_own_experts_only(arch):
+    """One request on four slots: three slots are dead in every decode
+    block. The routers score every slot (`moe_rows`, `moe_pairs`), the
+    experts take the owned slot's pairs alone (`moe_rows_taken`) and no
+    more experts are fetched than those pairs can name
+    (`moe_experts_hit`), on the block's span as in the counts."""
+    import threading
+
+    from ray_tpu.models.generate import routed_layers
+    from ray_tpu.util import tracing
+
+    cfg = {"mellum": configs.tiny_mellum_test, "pangu": configs.tiny_pangu_test,
+           "sdar": configs.tiny_sdar_test}[arch]()
+    params = init_params(cfg, jax.random.key(0))
+    eng = LLMEngine(cfg, params, num_slots=4, max_seq_len=64, decode_block=4)
+    spans, own = [], threading.get_ident()
+    tracing.setup_tracing(lambda e: threading.get_ident() == own
+                          and spans.append(e))
+    try:
+        req = eng.submit(list(range(1, 10)), max_new_tokens=8)
+        while eng.step():
+            pass
+    finally:
+        tracing.clear_tracing()
+    assert len(req.result(timeout=5)) == 8
+    blocks = [e["args"] for e in spans if e["name"] == "engine.process_block"]
+    c = eng.stats()["counts"]
+    names = ("moe_rows", "moe_rows_taken", "moe_experts_hit", "moe_pairs",
+             "moe_pairs_held")
+    assert blocks and all(b["active"] == 1 for b in blocks)
+    assert {n: c[n] for n in names} == {n: sum(b[n] for b in blocks)
+                                        for n in names}
+    for b in blocks:
+        # Positions a step x top k x routed layers, over the block's steps.
+        scored = b["k"] * b["slots"] * cfg.moe_top_k * routed_layers(cfg)
+        assert b["moe_pairs"] == scored
+        if arch == "pangu":     # of its pairs, those on an expert held
+            assert b["moe_rows_taken"] <= b["moe_rows"] <= scored
+            assert b["moe_rows_taken"] <= scored // 4
+        else:
+            assert b["moe_rows"] == scored == 4 * b["moe_rows_taken"]
+        assert b["moe_experts_hit"] <= b["moe_rows_taken"]
+    assert c["moe_experts_hit"] <= c["moe_rows_taken"] < c["moe_pairs"]
+    assert c["prefill_moe_rows_taken"] == c["prefill_moe_rows"] > 0

@@ -300,8 +300,9 @@ def test_tile_first_token_and_block_agree_and_count_their_routing(params):
         ARCH, params, toks[i, :n].tolist()))[-1]))
         for i, n in enumerate(lens)]
     assert list(np.asarray(first)[:3]) == want == list(np.asarray(free)[:3])
-    hit, rows, fullest = (int(x) for x in np.asarray(tile))
-    assert rows == 8 * 4 * 16 * 2           # layers x positions x top 2
+    hit, rows, fullest, taken = (int(x) for x in np.asarray(tile))
+    # layers x positions x top 2; a tile's padding is taken too
+    assert rows == taken == 8 * 4 * 16 * 2
     assert 8 * 2 <= hit <= 8 * 8 and 8 * 16 <= fullest <= 8 * 64
 
     cur = jnp.asarray([want[1], want[2], want[0]], jnp.int32)   # by slot

@@ -63,7 +63,7 @@ def test_the_preset_is_the_published_shape_in_small(params):
     assert STACKS["pangu_ultra_moe"] == "latent"
     assert latent.layer_plan(CFG) == [("dense_layers", 1, False),
                                       ("routed_layers", 2, True)]
-    assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 4
+    assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     # One vector a token a layer, latent + rotary key in whole lanes, and
     # nothing a head.
@@ -280,18 +280,19 @@ def test_all_sixteen_shares_add_up_to_the_uncut_layer():
         part = {k: (v[16 * share:16 * share + 16] if k in moe.EXPERT_LEAVES
                     else v) for k, v in lp.items()}
         out, stats, experts = moe.routed_ffn(cfg, part, m, jnp.float32)
-        assert experts.shape == (40, 8) and int(stats[3]) == 40 * 8
+        assert experts.shape == (40, 8) and int(stats[4]) == 40 * 8
         # The reference given the same share leaves out the same experts.
         alone = ref.routed_layer_output(dataclasses.asdict(cfg), part, m)
         np.testing.assert_allclose(np.asarray(out + shared), alone,
                                    rtol=0, atol=2e-6)
         total = total + np.asarray(out)
         pairs += int(stats[1])
+        assert int(stats[3]) == int(stats[1])      # each one taken
     assert pairs == 40 * 8                     # every pair is some share's
     np.testing.assert_allclose(total, want, rtol=0, atol=5e-6)
     # And with every expert held, the layer takes the path that holds all.
     out, stats, _ = moe.routed_ffn(whole, lp, m, jnp.float32)
-    assert stats.shape == (3,)
+    assert stats.shape == (4,)
     np.testing.assert_allclose(np.asarray(out + shared), want, rtol=0,
                                atol=5e-6)
 
@@ -312,7 +313,8 @@ def test_every_token_on_held_experts_and_none_is_dropped(tokens):
     m = jnp.abs(jax.random.normal(jax.random.key(14), (tokens, 64))) + 0.1
     out, stats, experts = moe.routed_ffn(CFG, lp, m, jnp.float32)
     assert set(np.asarray(experts).ravel()) == {5, 6}
-    assert [int(x) for x in stats] == [2, 2 * tokens, tokens, 2 * tokens]
+    assert [int(x) for x in stats] == [2, 2 * tokens, tokens,
+                                     2 * tokens, 2 * tokens]
     if tokens == 200:
         assert moe.held_pass_rows(400, 4, 16) == 256 < 2 * tokens
     want = ref.routed_layer_output(ARCH, lp, m) - latent._swiglu(
@@ -337,7 +339,7 @@ def test_no_token_on_a_held_expert_and_no_product_runs(monkeypatch):
     out, stats, _ = moe.routed_ffn(CFG, lp, m, jnp.float32)
     jax.effects_barrier()
     assert not np.any(np.asarray(out)) and not calls
-    assert [int(x) for x in stats] == [0, 0, 0, 66]
+    assert [int(x) for x in stats] == [0, 0, 0, 0, 66]
     # The same trap does spring when a pair is kept.
     held = _pinned_router(lp, (4, 9))
     out, stats, _ = moe.routed_ffn(CFG, held, m, jnp.float32)
@@ -367,7 +369,8 @@ def test_tile_first_token_and_block_agree_and_count_their_routing(params):
         ARCH, params, toks[i, :n].tolist()))[-1]))
         for i, n in enumerate(lens)]
     assert list(np.asarray(first)[:3]) == want == list(np.asarray(free)[:3])
-    hit, kept, fullest, pairs = (int(x) for x in np.asarray(tile))
+    hit, kept, fullest, taken, pairs = (int(x) for x in np.asarray(tile))
+    assert taken == kept                    # a tile's padding is taken too
     assert pairs == 2 * 4 * 16 * 2          # layers x positions x top 2
     assert 0 < kept < pairs and 0 < hit <= 2 * 4 and fullest <= kept
 
@@ -379,8 +382,8 @@ def test_tile_first_token_and_block_agree_and_count_their_routing(params):
         seq = toks[i, :lens[i]].tolist() + [want[i]] + out[:, slot].tolist()
         logits = np.asarray(ref.forward_logits(ARCH, params, seq[:-1]))
         assert list(np.argmax(logits[lens[i]:], -1)) == out[:, slot].tolist()
-    assert int(stats[3]) == 4 * 2 * 3 * 2   # steps x layers x slots x top 2
-    assert int(stats[1]) <= int(stats[3])
+    assert int(stats[4]) == 4 * 2 * 3 * 2   # steps x layers x slots x top 2
+    assert int(stats[3]) == int(stats[1]) <= int(stats[4])
 
 
 def test_the_engine_counts_pairs_routed_and_pairs_held(params):

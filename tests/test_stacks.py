@@ -91,10 +91,10 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
         jax.tree.map(lambda a: (a.shape, a.dtype), stats)
     assert generate.routed_layers(cfg) == st.routed_layers(cfg)
     if stats is not None:
-        # Three sums; a stack whose layers hold a share of their experts
-        # adds the pairs routed (its `routing_stats`).
-        n = getattr(st, "routing_stats", lambda _: 3)(cfg)
-        assert n in (3, 4)
+        # Four sums; a stack whose layers hold a share of their experts
+        # adds the pairs routed (`routing_stats`).
+        n = st.routing_stats(cfg)
+        assert n in (4, 5)
         assert (stats.shape, stats.dtype) == ((n,), jnp.int32)
 
     # What a stack lacks, it says why; what it has, `offered` hands over.
