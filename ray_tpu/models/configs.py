@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax.numpy as jnp
 
 from .transformer import TransformerConfig
@@ -97,6 +99,19 @@ def tiny_pangu_test(vocab: int = 256, router_experts: int = 16,
         route_scale=2.5)
 
 
+def tiny_glm_test(vocab: int = 256, router_experts: int = 16,
+                  held: int = 4, first: int = 4, index_topk: int = 8
+                  ) -> TransformerConfig:
+    """The latent stack's other layer at a unit-test size: `tiny_pangu_test`'s
+    widths with two norms a layer, the router's selection bias, and the
+    sparse-attention indexer (2 heads of 16, the first 8 rotated) choosing
+    `index_topk` rows a query. For the tests only."""
+    return dataclasses.replace(
+        tiny_pangu_test(vocab, router_experts, held, first),
+        arch="glm_moe_dsa", rope_theta=1000000.0, index_n_heads=2,
+        index_head_dim=16, index_topk=index_topk)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -152,6 +167,7 @@ NAMED = {
     "tiny_afmoe": tiny_afmoe_test,
     "tiny_mellum": tiny_mellum_test,
     "tiny_pangu": tiny_pangu_test,
+    "tiny_glm": tiny_glm_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

@@ -138,7 +138,11 @@ class KVCache(NamedTuple):
     A latent stack (`models/latent.py`) keeps neither keys nor values a
     head: `c`, (L, B, S_max, C), holds a token's latent vector and its
     rotary key, every head's keys and values are products of it, and k
-    and v are None. Every other model leaves c None."""
+    and v are None. Every other model leaves c None. Where such a stack
+    chooses the rows a query attends (`TransformerConfig.index_topk`) it
+    keeps a fifth kind of state beside them: `ki`, (L, B, S_max,
+    index_head_dim) float32, the one key a token a layer that its indexer
+    scores (after its norm and rotation); None anywhere else."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -146,6 +150,7 @@ class KVCache(NamedTuple):
     kw: Optional[jax.Array] = None
     vw: Optional[jax.Array] = None
     c: Optional[jax.Array] = None
+    ki: Optional[jax.Array] = None
 
     @property
     def _rows(self) -> jax.Array:

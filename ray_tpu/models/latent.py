@@ -1,11 +1,15 @@
 """The latent-attention stack (multi-head latent attention, arXiv:2405.04434
 section 2.1, with the routed layer of `models/moe.py`), served through the
-programs of `generate.py`.
+programs of `generate.py`. One stack, two architectures told apart by
+data (`transformer.LATENT_FORMS`, `TransformerConfig.index_topk`):
+openPangu-Ultra-MoE and GLM-5.
 
 `n_dense_layers` leading layers with a dense SwiGLU, then layers of routed
-experts beside always-on shared ones. Four RMS norms a layer: before the
-attention and before the FFN, and on each branch's output before it joins
-the residual stream.
+experts beside always-on shared ones. RMS norms before the attention and
+before the FFN; where the architecture has sandwich norms
+(`LatentForm.post_norms`) two more, on each branch's output before it
+joins the residual stream. The router may add a bias an expert for its
+choice alone (`LatentForm.router_bias`).
 
 Attention keeps no key and no value a head. A token's keys and values are
 one vector, `c = N(x W_kva)[:kv_lora_rank]`, from which every head's key
@@ -25,21 +29,70 @@ and `wq_rope`, the published `q_b_proj`'s columns by what they make) to
 
 The same products in two orders:
 
-- a tile (`prefill`, `forward_free`) up-projects the tile's own rows and
-  attends per head: scores `(q_nope . k_nope + q_r . k_r) / sqrt(nope +
-  rope)` over keys `nope + rope` wide, values `v_head_dim` wide (the
-  flash kernel takes values of another width than the keys);
+- a tile (`prefill`, `forward_free`) up-projects rows and attends per
+  head: scores `(q_nope . k_nope + q_r . k_r) / sqrt(nope + rope)` over
+  keys `nope + rope` wide, values `v_head_dim` wide (the flash kernel
+  takes values of another width than the keys);
 - a decode step never up-projects a cached row. `W_UK` goes into the
   query (`q_nope W_UK^T`, `kv_lora_rank` wide a head) and `W_UV` into the
   output, so the step attends as one key head of C under all the query
   heads, whose first `kv_lora_rank` columns are also the values:
-  `ops/decode_attention` with one array, each held row read once.
+  `ops/decode_attention` with one array, each row it attends read once.
+
+Learned sparse attention (`cfg.index_topk` > 0; DeepSeek-V3.2's, as
+GLM-5's `glm_moe_dsa` follows it): which rows a query attends is decided
+by the data. A layer has a second, small scorer, the indexer: `qI = c_q
+WqI` (`index_n_heads` heads of `index_head_dim`, from the query's normed
+rank), one key a token `kI = LayerNorm(a WkI)` (weight and bias) and a
+weight a head a query `wI = a WwI / sqrt(heads x width)`, the first
+`qk_rope_head_dim` values of every `qI` head and of `kI` rotated as the
+attention's rotary part is. Query t scores every row it may see, `I_ts =
+sum_h wI_th relu(qI_th . kI_s)` in float32, and attends the `index_topk`
+rows of largest score, exactly (ties to the lower row; every row while
+it sees no more than `index_topk`). The indexer's keys are a cache of
+their own beside the latent rows (`KVCache.ki`, (L, slots, S_max,
+index_head_dim)). What the choice is made in is the configuration's,
+`cfg.index_dtype` (None: the activation dtype, as everything else): the
+residual stream between such a stack's layers, the indexer's projections
+of it, the cached keys and the scores' operands (`_embed`,
+`_index_project`). A query's chosen set turns on the last bits of
+thousands of scores, a row that changes sides changes the layer's
+output, and that output feeds the next layer's indexer, so what a
+rounding leaves is amplified a layer at a time: under seeded weights a
+bf16 choice differs from a float32 reference's in a few rows of a
+hundred (PERF.md section 6, PR 41), and a cell that wants the
+reference's choices states float32 there. Every other product and the
+latent cache take the activation dtype whatever it says. The steps are
+`ops/sparse_attention`'s:
+
+- a decode step writes its latent row and its indexer key, scores its
+  slot's keys (`index_scores_rows`, rows past those held at `-inf`),
+  takes the `index_topk` best (`lax.top_k`), gathers those latent rows
+  and attends them in the latent space as above: no latent row it did
+  not choose is read (the gather has one shape, so a slot nobody owns
+  still fetches `index_topk` rows, which the kernel then skips);
+- a tile writes its rows and keys, scores every row of its bucket each
+  query may see (`index_scores_tile`), finds each query's chosen set
+  (`topk_bias`: the k-th largest score a bit at a time, no sort) and
+  attends per head under the chosen sets as a bias (`masked_attention`),
+  the rows of a chunk of keys at a time up-projected from the cache. A
+  bucket no longer than `index_topk` chooses every row: the tile over
+  itself, as without an indexer.
+
+A tile longer than `PREFILL_CHUNK` rows is walked a chunk at a time
+inside its one program (`_walk`), both caches in the loop's carry: a
+chunk goes through every layer, each writing the chunk's rows and
+attending rows [0, chunk's end) of the cache, before the next chunk;
+the walk ends at the last chunk that holds a prompt's token. So a prompt
+may be longer than what one pass's temporaries allow beside the weights;
+without an indexer (openPangu's cells) a tile still goes through whole
+and its programs are what they were.
 
 Precision follows `cfg.dtype` as the period stack's does: every product
 hands back float32, what lies between two products stays float32 and is
-rounded to `cfg.dtype` once, where it enters the next product or the
+rounded to `cfg.dtype` once, where it enters the next product or a
 cache; float32 activations against bf16 weights go in as two bf16 terms
-(`moe.dot`) and the cache is then float32.
+(`moe.dot`) and the caches are then float32.
 
 The routed layer holds `cfg.moe_experts` of the `cfg.router_experts` its
 router scores, from `cfg.moe_first_expert` on (`moe.held_experts`): on
@@ -68,9 +121,13 @@ from .transformer import TransformerConfig, rope_tables
 MISSING = {
     "suffix": "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
               "compute_prefix_kv) installs a block of keys and values a "
-              "layer; a latent cache has one array of rows and its suffix "
-              "walk would up-project the prefix's rows a tile: not written "
-              "(models/latent.py)",
+              "layer; a latent cache has one array of rows (two with an "
+              "indexer's keys) and its suffix walk would up-project the "
+              "prefix's rows a tile; the chunk walk of a stack with an "
+              "indexer already attends rows of the cache it did not write "
+              "in this pass, so a registered prefix's rows and keys "
+              "copied into a slot and a walk begun at their end is what "
+              "is left: not written (models/latent.py)",
     "param_logical_axes": "the latent stack has no sharding rules yet: it "
                           "is served on one chip, which holds its share of "
                           "each layer's experts (models/latent.py)",
@@ -115,14 +172,20 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
+    form = cfg.latent_form
     shapes = {
         "attn_norm": (d,), "wq_a": (d, qr), "q_a_norm": (qr,),
         "wq_nope": (qr, H * nope), "wq_rope": (qr, H * rope),
         "wkv_a": (d, kvr + rope), "kv_a_norm": (kvr,),
         "wk_b": (H, nope, kvr), "wv_b": (H, kvr, vd),
-        "wo": (H * vd, d), "post_attn_norm": (d,), "ffn_norm": (d,),
-        "post_ffn_norm": (d,),
+        "wo": (H * vd, d), "ffn_norm": (d,),
     }
+    if form.post_norms:
+        shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
+    if cfg.index_topk:
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        shapes.update(idx_wq=(qr, Hi * Di), idx_wk=(d, Di),
+                      idx_k_norm=(Di,), idx_k_bias=(Di,), idx_wp=(d, Hi))
     if not routed:
         f = cfg.d_ff
         shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
@@ -130,6 +193,8 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
     E, f = cfg.moe_experts, cfg.expert_d_ff
     shapes.update(router=(d, cfg.router_experts), w_gate=(E, d, f),
                   w_up=(E, d, f), w_down=(E, f, d))
+    if form.router_bias:
+        shapes["router_bias"] = (cfg.router_experts,)
     if cfg.moe_shared_experts:
         fs = f * cfg.moe_shared_experts
         shapes.update(shared_gate=(d, fs), shared_up=(d, fs),
@@ -173,6 +238,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             full = (n,) + shape
             if leaf.endswith("norm"):
                 leaves[leaf] = jnp.ones(full, dtype=pd)
+            elif leaf == "router_bias":
+                leaves[leaf] = jnp.zeros(full, dtype=pd)
             elif leaf in ("wo", "w_down", "shared_down"):
                 leaves[leaf] = normal(
                     k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
@@ -182,12 +249,19 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def index_dtype(cfg: TransformerConfig):
+    """What the indexer and the stream that feeds it are kept in."""
+    return jnp.dtype(cfg.index_dtype or cfg.dtype).type
+
+
 def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
                ) -> KVCache:
+    rows = (cfg.n_layers, num_slots, max_seq_len)
     return KVCache(
         k=None, v=None, seq_lens=jnp.zeros((num_slots,), jnp.int32),
-        c=jnp.zeros((cfg.n_layers, num_slots, max_seq_len,
-                     cache_lanes(cfg)), cfg.dtype))
+        c=jnp.zeros(rows + (cache_lanes(cfg),), cfg.dtype),
+        ki=jnp.zeros(rows + (cfg.index_head_dim,), index_dtype(cfg))
+        if cfg.index_topk else None)
 
 
 # ---------------------------------------------------------------------------
@@ -227,11 +301,13 @@ def _project(cfg: TransformerConfig, lp, x, rope):
     """x (B, S, D) -> (q_nope (B, S, H, nope) float32, q_r (B, S, H,
     rope) float32 and rotated, the row the cache keeps (B, S, C) in the
     activation dtype: the latent vector after its norm, the rotary key
-    after its rotation, zeros up to whole lanes)."""
+    after its rotation, zeros up to whole lanes; and the layer's normed
+    input (B, S, D) float32, before it is rounded for the products)."""
     B, S, _ = x.shape
     H, dt, eps = cfg.n_heads, cfg.dtype, cfg.norm_eps
     kvr = cfg.kv_lora_rank
-    h = _norm(x, lp["attn_norm"], eps).astype(dt)
+    h32 = _norm(x, lp["attn_norm"], eps)
+    h = h32.astype(dt)
     c_q = _norm(_dot(h, lp["wq_a"]), lp["q_a_norm"], eps).astype(dt)
     q_nope = _dot(c_q, lp["wq_nope"]).reshape(B, S, H, -1)
     q_r = _dot(c_q, lp["wq_rope"]).reshape(B, S, H, -1)
@@ -241,7 +317,45 @@ def _project(cfg: TransformerConfig, lp, x, rope):
     row = jnp.concatenate([c, k_r], axis=-1).astype(dt)
     row = jnp.pad(row, ((0, 0), (0, 0),
                         (0, cache_lanes(cfg) - cache_width(cfg))))
-    return q_nope, _rope(q_r, *rope), row
+    return q_nope, _rope(q_r, *rope), row, h32
+
+
+# The indexer key's LayerNorm (guess: the family's published code builds
+# it with this epsilon; the catalog row has the RMS norms' alone).
+_INDEX_NORM_EPS = 1e-6
+
+
+def _index_project(cfg: TransformerConfig, lp, h, rope):
+    """The indexer's projections of a layer's normed input h (B, S, D)
+    float32 -> (q (B, S, Hi, Di) and the key the cache keeps (B, S, Di),
+    the first `qk_rope_head_dim` of each rotated, both in
+    `index_dtype(cfg)`; w (B, S, Hi) float32, a weight a head a query,
+    the two scales folded in). What enters a product is rounded to that
+    dtype, as the layer's other products round to the activation dtype:
+    in bf16 the query's rank is `_project`'s own, the same expression,
+    which the compiler computes once; in float32 every product takes h,
+    and the rank made from it once more, as two bf16 terms (`moe.dot`),
+    and nothing is rounded on the way to the scores. The key goes through a LayerNorm (mean taken out,
+    weight and bias)."""
+    B, S, _ = h.shape
+    Hi, Di, r = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    it = index_dtype(cfg)
+    h = h.astype(it)
+
+    def rotated(x):                                     # (B, S, heads, Di)
+        return jnp.concatenate([_rope(x[..., :r], *rope), x[..., r:]],
+                               axis=-1)
+
+    c_q = _norm(_dot(h, lp["wq_a"]), lp["q_a_norm"], cfg.norm_eps).astype(it)
+    q = rotated(_dot(c_q, lp["idx_wq"]).reshape(B, S, Hi, Di))
+    k = _dot(h, lp["idx_wk"])
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                      + _INDEX_NORM_EPS)
+    k = k * lp["idx_k_norm"].astype(jnp.float32) \
+        + lp["idx_k_bias"].astype(jnp.float32)
+    w = _dot(h, lp["idx_wp"]) * (Hi ** -0.5 * Di ** -0.5)
+    return q.astype(it), w, rotated(k[:, :, None, :])[:, :, 0].astype(it)
 
 
 def _scale(cfg: TransformerConfig) -> float:
@@ -273,7 +387,8 @@ def _attention_f32(q, k, v, sm_scale: float):
     return jnp.moveaxis(out, 0, 1).reshape(B, S, H, -1)
 
 
-def _attend_tile(cfg: TransformerConfig, lp, q_nope, q_r, row):
+def _attend_tile(cfg: TransformerConfig, lp, q_nope, q_r, row,
+                 scope: str = "attn_latent"):
     """A tile over itself, per head: the rows' keys and values
     up-projected from what the cache keeps of them (so a tile and the
     decode steps behind it see the same rounding), causal attention with
@@ -284,7 +399,7 @@ def _attend_tile(cfg: TransformerConfig, lp, q_nope, q_r, row):
         c = row[..., :kvr]
         k_nope = _heads_dot("bsc,hdc->bshd", c, lp["wk_b"]).astype(dt)
         v = _heads_dot("bsc,hcd->bshd", c, lp["wv_b"]).astype(dt)
-    with jax.named_scope("attn_latent"):
+    with jax.named_scope(scope):
         k_r = jnp.broadcast_to(row[:, :, None, kvr:cache_width(cfg)],
                                (B, S, H, cfg.qk_rope_head_dim))
         q = jnp.concatenate([q_nope, q_r], axis=-1).astype(dt)
@@ -298,14 +413,20 @@ def _attend_tile(cfg: TransformerConfig, lp, q_nope, q_r, row):
 
 
 def _attend_rows(cfg: TransformerConfig, positions, live, l, lp, q_nope,
-                 q_r, row, c_all):
+                 q_r, row, idx, state):
     """One token a slot against layer `l` of the carried cache (L, B, S,
     C), in the latent space: this step's row is written at `positions`,
     `W_UK` goes into the query and `W_UV` onto the weighted rows, and
-    every held row is read once, for scores and values together. ->
-    (out (B, 1, H*vd), c_all)."""
+    every held row is read once, for scores and values together. With an
+    indexer (`idx`: `_index_project`'s three) the step's indexer key is
+    written beside the row, the slot's held keys are scored, and only the
+    `index_topk` rows of largest score are gathered and attended
+    (`_chosen_rows`): no other latent row is read. `state`: (the latent
+    cache, the indexer's or None, the chosen rows a layer (L, B, k) for
+    whoever asks or None). -> (out (B, 1, H*vd), state)."""
     from ..ops import decode_attention as da
 
+    c_all, ki_all, picks = state
     B, S, C = c_all.shape[1:]
     H, dt, kvr = cfg.n_heads, cfg.dtype, cfg.kv_lora_rank
     with jax.named_scope("mla_proj"):
@@ -313,18 +434,34 @@ def _attend_rows(cfg: TransformerConfig, positions, live, l, lp, q_nope,
                            lp["wk_b"])
         q = jnp.concatenate([q_lat, q_r[:, 0]], axis=-1).astype(dt)
         q = jnp.pad(q, ((0, 0), (0, 0), (0, C - q.shape[-1])))
-    with jax.named_scope("attn_latent"):
+    if idx is not None:
+        with jax.named_scope("attn_index"):
+            n_rows = rows_held(positions, S, live)
+            ki_all, chosen = _chosen_rows(cfg, positions, l, idx, ki_all,
+                                          n_rows)
+            if picks is not None:
+                picks = picks.at[l].set(chosen)
+    with jax.named_scope("attn_latent" if idx is None else "attn_sparse"):
         # A slot the engine no longer owns keeps advancing and can reach
         # S: its write falls out of bounds and is dropped.
         c_all = c_all.at[l, jnp.arange(B), positions].set(row[:, 0],
                                                           mode="drop")
-        n_rows = rows_held(positions, S, live)
-        if dt == c_all.dtype and da.usable(c_all, C, kvr):
+        if idx is not None:
+            # The chosen rows alone, gathered: the best first, so the
+            # rows that count are the first `n_rows` of them where a
+            # slot holds fewer than it may choose.
+            rows_all = c_all[l, jnp.arange(B)[:, None], chosen][None]
+            n_rows = jnp.minimum(n_rows, chosen.shape[1])
+            at = jnp.int32(0)
+        else:
+            rows_all, at = c_all, l
+            n_rows = rows_held(positions, S, live)
+        if dt == c_all.dtype and da.usable(rows_all, C, kvr):
             o_lat = da.decode_attention(
-                q[:, None], c_all, None, l, n_rows, sm_scale=_scale(cfg),
+                q[:, None], rows_all, None, at, n_rows, sm_scale=_scale(cfg),
                 v_width=kvr).reshape(B, H, kvr)
         else:
-            rows = lax.dynamic_index_in_dim(c_all, l, 0, keepdims=False)
+            rows = lax.dynamic_index_in_dim(rows_all, at, 0, keepdims=False)
             hi = _exact(q)
             scores = jnp.einsum("bhc,bsc->bhs", q, rows, precision=hi,
                                 preferred_element_type=jnp.float32)
@@ -335,28 +472,188 @@ def _attend_rows(cfg: TransformerConfig, positions, live, l, lp, q_nope,
                                preferred_element_type=jnp.float32)
     with jax.named_scope("mla_proj"):
         out = _heads_dot("bhc,hcd->bhd", o_lat.astype(dt), lp["wv_b"])
-    return out.reshape(B, 1, -1).astype(dt), c_all
+    return out.reshape(B, 1, -1).astype(dt), (c_all, ki_all, picks)
+
+
+def _chosen_rows(cfg: TransformerConfig, positions, l, idx, ki_all, n_rows):
+    """A decode step's choice: the step's indexer key into layer `l` of
+    the indexer's cache (L, B, S, Di) at `positions`, every key a slot
+    holds scored against the step's indexer query (`n_rows` (B,) of
+    them), and the `index_topk` rows of largest score, exactly
+    (`lax.top_k`: ties to the lower row), best first: a row past
+    `n_rows` scores `-inf` and comes last. -> (ki_all, rows (B, k))."""
+    from ..ops import sparse_attention as sa
+
+    q, w, key = idx
+    B, S = ki_all.shape[1:3]
+    ki_all = ki_all.at[l, jnp.arange(B), positions].set(key[:, 0],
+                                                         mode="drop")
+    scores = sa.index_scores_rows(q[:, 0], w[:, 0], ki_all, l, n_rows)
+    _, chosen = lax.top_k(scores, min(cfg.index_topk, S))
+    return ki_all, chosen
+
+
+# Rows of a tile that go through the layers together where a stack
+# chooses the rows a query attends: a longer bucket is walked a chunk at
+# a time against the cache (`prefill`). A chunk's temporaries (its
+# scores against every row of the bucket, float32, and the chosen-set
+# bias behind them) grow with chunk x bucket: 0.8 GB at 4,096 x 32,768.
+PREFILL_CHUNK = 2048
+# Queries of a chunk whose scores against the bucket are held at once.
+_CHOICE_ROWS = 1024
+
+
+def chunk_rows(cfg: TransformerConfig, bucket: int) -> int:
+    """Rows a chunk of a tile of `bucket` positions: all of them where the
+    stack attends every row or the bucket is no longer than a chunk."""
+    if cfg.index_topk and bucket > PREFILL_CHUNK \
+            and bucket % PREFILL_CHUNK == 0:
+        return PREFILL_CHUNK
+    return bucket
+
+
+def _write_rows(rows_all, l, slots, start, rows):
+    """rows (W, T, width) into rows [start, start + T) of layer `l` of
+    each of `slots` (W,) of a cache (L, B, S, width); a slot out of
+    range (a tile's padding) is dropped. A tile from its first row is
+    one scatter of whole runs; a chunk further in (`start` a number the
+    device counts) is written a row of the tile at a time, where it
+    lies."""
+    if isinstance(start, int):
+        return rows_all.at[l, slots, start:start + rows.shape[1]].set(
+            rows, mode="drop")
+    B = rows_all.shape[1]
+    for w in range(rows.shape[0]):
+        at = (l, slots[w], start, 0)
+        old = lax.dynamic_slice(rows_all, at, (1, 1) + rows.shape[1:])
+        new = jnp.where((slots[w] >= 0) & (slots[w] < B), rows[w][None, None],
+                        old)
+        rows_all = lax.dynamic_update_slice(rows_all, new, at)
+    return rows_all
+
+
+def _slot_rows(rows_all, l, slots, start, n: int):
+    """Rows [start, start + n) of layer `l` of each of `slots` (W,), from
+    a cache (L, B, S, width) -> (W, n, width)."""
+    width = rows_all.shape[-1]
+    return jax.vmap(lambda s: lax.dynamic_slice(
+        rows_all, (l, s, start, 0), (1, 1, n, width))[0, 0])(slots)
+
+
+def _attend_chunk(cfg: TransformerConfig, slots, start, bucket: int, l, lp,
+                  q_nope, q_r, row, idx, state):
+    """A chunk of a tile, rows [start, start + T) of a bucket of `bucket`
+    positions, against layer `l` of the cache: the chunk's latent rows
+    and indexer keys are written to their slots, then each query t
+    scores the indexer keys of rows [0, start + T) it may see (s <= t),
+    chooses its `index_topk` best, and attends them per head: the rows
+    of a chunk of keys at a time are up-projected from the cache (so a
+    chunk, the chunks behind it and the decode steps behind those see
+    the same rounding) and attended under the chosen sets as a bias, the
+    chunks' parts added up by their log-sum-exp. A bucket in one chunk and
+    no longer than `index_topk` chooses every row a query sees: the tile
+    over itself,
+    as a stack without an indexer attends it (`_attend_tile`). `state` as
+    `_attend_rows` has it; the chosen sets for whoever asks are (L, W,
+    bucket, bucket) bool. -> (out (W, T, H*vd), state)."""
+    from ..ops import sparse_attention as sa
+    from ..ops.flash_attention import NEG_INF
+
+    c_all, ki_all, picks = state
+    W, T, H, _ = q_nope.shape
+    dt, kvr = cfg.dtype, cfg.kv_lora_rank
+    q_idx, w_idx, key = idx
+    rows_major = Layout(major_to_minor=tuple(range(c_all.ndim)))
+    with jax.named_scope("attn_index"):
+        ki_all = with_layout_constraint(
+            _write_rows(ki_all, l, slots, start, key), rows_major)
+    with jax.named_scope("attn_sparse"):
+        # Rows-major: see `_prefill_attend`.
+        c_all = with_layout_constraint(
+            _write_rows(c_all, l, slots, start, row), rows_major)
+    if bucket <= cfg.index_topk and T == bucket:
+        out = _attend_tile(cfg, lp, q_nope, q_r, row, scope="attn_sparse")
+        if picks is not None:
+            picks = picks.at[l].set(jnp.broadcast_to(
+                jnp.tril(jnp.ones((bucket, bucket), bool)), picks.shape[1:]))
+        return out, (c_all, ki_all, picks)
+
+    with jax.named_scope("attn_index"):
+        keys = _slot_rows(ki_all, l, slots, 0, bucket)
+
+        def choose(part):
+            # A block of the chunk's queries: their scores against every
+            # row of the bucket, float32, live only until the choice.
+            q_part, w_part, first = part
+            return sa.topk_bias(
+                sa.index_scores_tile(q_part, w_part, keys, first),
+                cfg.index_topk, dtype=dt)
+
+        n = T // _CHOICE_ROWS if T % _CHOICE_ROWS == 0 else 1
+        bias = lax.map(choose, (
+            jnp.moveaxis(q_idx.reshape((W, n, T // n) + q_idx.shape[2:]),
+                         1, 0),
+            jnp.moveaxis(w_idx.reshape(W, n, T // n, -1), 1, 0),
+            start + jnp.arange(n) * (T // n)))
+        bias = jnp.moveaxis(bias, 0, 1).reshape(W, T, bucket)
+        if picks is not None:
+            picks = lax.dynamic_update_slice(
+                picks, (bias == 0)[None], (l, 0, start, 0))
+    with jax.named_scope("attn_sparse"):
+        q = jnp.concatenate([q_nope, q_r], axis=-1).astype(dt)
+
+    def one(j, carry):
+        out, lse = carry
+        rows = _slot_rows(c_all, l, slots, j * T, T)          # (W, T, C)
+        with jax.named_scope("mla_proj"):
+            c = rows[..., :kvr]
+            k_nope = _heads_dot("bsc,hdc->bshd", c, lp["wk_b"]).astype(dt)
+            v = _heads_dot("bsc,hcd->bshd", c, lp["wv_b"]).astype(dt)
+        with jax.named_scope("attn_sparse"):
+            k_r = jnp.broadcast_to(rows[:, :, None, kvr:cache_width(cfg)],
+                                   (W, T, H, cfg.qk_rope_head_dim))
+            k = jnp.concatenate([k_nope, k_r], axis=-1)
+            part, part_lse = sa.masked_attention(
+                q, k, v, lax.dynamic_slice_in_dim(bias, j * T, T, 2),
+                _scale(cfg))
+            return sa.merge_parts(out, lse, part, part_lse)
+
+    out, _ = lax.fori_loop(
+        0, start // T + 1, one,
+        (jnp.zeros((W, T, H, cfg.v_head_dim), jnp.float32),
+         jnp.full((W, T, H), NEG_INF, jnp.float32)))
+    return out.reshape(W, T, -1).astype(dt), (c_all, ki_all, picks)
 
 
 def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
           rows=None):
     """One layer on x (B, S, D) in the activation dtype. `attend(lp,
-    q_nope, q_r, row, state) -> (out (B, S, H*vd), state)` does the
-    attention and whatever it keeps of the row. `experts_at`: None for a
-    dense FFN, else (the stack's expert matrices, this layer's first
-    group in them). `rows` (B*S,) bool: the rows somebody owns, the only
-    ones the routed experts take (`moe.routed_ffn`; None: every row).
-    Returns (x, state, routing stats or None, experts chosen (B*S, K) or
-    None)."""
+    q_nope, q_r, row, idx, state) -> (out (B, S, H*vd), state)` does the
+    attention and whatever it keeps of the row; `idx` is what the
+    layer's indexer projects (`_index_project`), None without one.
+    `experts_at`: None for a dense FFN, else (the stack's expert
+    matrices, this layer's first group in them). `rows` (B*S,) bool: the
+    rows somebody owns, the only ones the routed experts take
+    (`moe.routed_ffn`; None: every row). A branch joins the residual
+    stream through a norm of its own where the architecture has one
+    (`LatentForm.post_norms`). Returns (x, state, routing stats or None,
+    experts chosen (B*S, K) or None)."""
     B, S, _ = x.shape
     dt, eps = cfg.dtype, cfg.norm_eps
+    post_norms = cfg.latent_form.post_norms
 
     def joins(branch, norm):
+        if not post_norms:
+            return x + branch.astype(x.dtype)
         return x + _norm(branch, lp[norm], eps).astype(x.dtype)
 
     with jax.named_scope("mla_proj"):
-        q_nope, q_r, row = _project(cfg, lp, x, rope)
-    out, state = attend(lp, q_nope, q_r, row, state)
+        q_nope, q_r, row, h = _project(cfg, lp, x, rope)
+    idx = None
+    if cfg.index_topk:
+        with jax.named_scope("attn_index"):
+            idx = _index_project(cfg, lp, h, rope)
+    out, state = attend(lp, q_nope, q_r, row, idx, state)
     with jax.named_scope("mla_proj"):
         x = joins(_dot(out, lp["wo"]), "post_attn_norm")
 
@@ -378,8 +675,8 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
 
 def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
     """x through every layer: one `lax.scan` a group of the plan, `state`
-    (the cache, or nothing) riding in the carry beside x. `attend(l, lp,
-    q_nope, q_r, row, state)` is told which layer it serves; `rows`
+    (the caches, or nothing) riding in the carry beside x. `attend(l, lp,
+    q_nope, q_r, row, idx, state)` is told which layer it serves; `rows`
     (see `layer`) are the rows somebody owns. Returns (x, state, routing
     stats summed over layers, experts chosen: one array (layers, B*S, K)
     a routed group)."""
@@ -414,7 +711,12 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
-    return params["embed"][tokens].astype(cfg.dtype)
+    """The residual stream's first value, in the dtype it is carried in
+    between the layers: the activation dtype, or the indexer's where a
+    stack has one (what enters a product is rounded to the activation
+    dtype either way)."""
+    return params["embed"][tokens].astype(
+        index_dtype(cfg) if cfg.index_topk else cfg.dtype)
 
 
 def _final(cfg: TransformerConfig, params, x):
@@ -425,7 +727,8 @@ def _final(cfg: TransformerConfig, params, x):
 # What generate.py's programs call
 # ---------------------------------------------------------------------------
 
-def _prefill_attend(cfg, slots, l, lp, q_nope, q_r, row, c_all):
+def _prefill_attend(cfg, slots, l, lp, q_nope, q_r, row, idx, state):
+    c_all, ki_all, picks = state
     out = _attend_tile(cfg, lp, q_nope, q_r, row)
     with jax.named_scope("attn_latent"):
         # The tile's rows into each row's slot, [0, S); a slot out of
@@ -437,7 +740,59 @@ def _prefill_attend(cfg, slots, l, lp, q_nope, q_r, row, c_all):
         # that way) and copies all of it in and out of the program.
         c_all = with_layout_constraint(
             c_all, Layout(major_to_minor=tuple(range(c_all.ndim))))
-    return out, c_all
+    return out, (c_all, ki_all, picks)
+
+
+def _walk(cfg: TransformerConfig, params, state, tokens, lengths, slots,
+          whole: bool = False):
+    """A tile of a stack with an indexer: tokens (W, S) through the layers
+    against `state` (`_attend_rows`'s) -> (final-normed hidden states (W,
+    S, D), state, routing stats, experts chosen or ()). A bucket longer
+    than a chunk (`chunk_rows`) is walked a chunk at a time inside this
+    one program, the caches in the loop's carry: a chunk's rows go
+    through every layer, each layer writing them and attending the
+    cache, before the next chunk's; the walk ends at the last chunk that
+    holds a token of any prompt (`lengths` (W,); None: every chunk), so a
+    padding chunk is not run: its hidden states stay zero and its
+    positions are in no routing count. `whole`: the tile in one chunk
+    whatever its length (for `chosen_experts`, which wants every
+    position's)."""
+    W, S = tokens.shape
+    rope = _rope_tables(cfg, S)
+    T = S if whole else chunk_rows(cfg, S)
+    if T == S:
+        x, state, stats, chosen = _run(
+            cfg, params, _embed(cfg, params, tokens), rope,
+            partial(_attend_chunk, cfg, slots, 0, S), state)
+        return _final(cfg, params, x), state, stats, chosen
+
+    def chunk(i, carry):
+        state, out, stats = carry
+        start = i * T
+        x, state, st, _ = _run(
+            cfg, params,
+            _embed(cfg, params, lax.dynamic_slice_in_dim(tokens, start, T, 1)),
+            tuple(lax.dynamic_slice_in_dim(t, start, T, 0) for t in rope),
+            partial(_attend_chunk, cfg, slots, start, S), state)
+        out = lax.dynamic_update_slice_in_dim(out, _final(cfg, params, x),
+                                              start, 1)
+        return state, out, stats + st
+
+    run = S // T if lengths is None else jnp.clip(
+        (jnp.max(lengths) + T - 1) // T, 1, S // T)
+    state, out, stats = lax.fori_loop(
+        0, run, chunk,
+        (state, jnp.zeros((W, S, cfg.d_model), cfg.dtype),
+         jnp.zeros((routing_stats(cfg),), jnp.int32)))
+    return out, state, stats, ()
+
+
+def prefill_chunks(cfg: TransformerConfig, bucket: int, length: int
+                   ) -> Tuple[int, int]:
+    """(chunks a tile of `bucket` positions runs for a prompt of `length`
+    tokens, chunks the bucket has): `_walk`'s count, for the host."""
+    T = chunk_rows(cfg, bucket)
+    return min(max(-(-length // T), 1), bucket // T), bucket // T
 
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
@@ -445,26 +800,60 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
     hidden states (W, S, D), routing stats of the tile as `decode` gives a
     step's, over all W x S positions, padding too; None with no routed
-    layer)."""
+    layer). Without an indexer the tile goes through whole and attends
+    itself; with one, `_walk` (a chunk that is not run counts nothing)."""
+    if cfg.index_topk:
+        x, (c_all, ki_all, _), stats, _ = _walk(
+            cfg, params, (cache.c, cache.ki, None), tokens, lengths, slots)
+        seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
+        return cache._replace(c=c_all, ki=ki_all, seq_lens=seq_lens), x, \
+            stats if routed_layers(cfg) else None
     rope = _rope_tables(cfg, tokens.shape[1])
-    x, c_all, stats, _ = _run(cfg, params, _embed(cfg, params, tokens), rope,
-                              partial(_prefill_attend, cfg, slots), cache.c)
+    x, (c_all, _, _), stats, _ = _run(
+        cfg, params, _embed(cfg, params, tokens), rope,
+        partial(_prefill_attend, cfg, slots), (cache.c, None, None))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     return cache._replace(c=c_all, seq_lens=seq_lens), \
         _final(cfg, params, x), stats if routed_layers(cfg) else None
 
 
-def _free_attend(cfg, l, lp, q_nope, q_r, row, state):
+def _free_attend(cfg, l, lp, q_nope, q_r, row, idx, state):
     return _attend_tile(cfg, lp, q_nope, q_r, row), state
 
 
-def forward_free(cfg: TransformerConfig, params, tokens):
+def _scratch(cfg: TransformerConfig, W: int, S: int):
+    """A cache of W slots x S rows that lives as long as one program."""
+    cache = init_cache(cfg, W, S)
+    return cache.c, cache.ki
+
+
+def forward_free(cfg: TransformerConfig, params, tokens, whole: bool = False):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), the experts every routed layer chose: see `_run`)."""
+    D), the experts every routed layer chose: see `_run`; none where the
+    tile is walked in chunks, unless `whole`). A stack that chooses its
+    rows walks the tile against a cache of its own, one slot a row."""
+    if cfg.index_topk:
+        W, S = tokens.shape
+        x, _, _, chosen = _walk(cfg, params, _scratch(cfg, W, S) + (None,),
+                                tokens, None, jnp.arange(W), whole)
+        return x, chosen
     rope = _rope_tables(cfg, tokens.shape[1])
     x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
                            partial(_free_attend, cfg), None)
     return _final(cfg, params, x), chosen
+
+
+def _decode(cfg: TransformerConfig, params, cache: KVCache, tokens, live,
+            picks=None):
+    positions = cache.seq_lens
+    rope = _rope_tables(cfg, cache.max_seq_len, positions)
+    x, (c_all, ki_all, picks), stats, _ = _run(
+        cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
+        partial(_attend_rows, cfg, positions, live),
+        (cache.c, cache.ki, picks), live)
+    cache = cache._replace(c=c_all, ki=ki_all, seq_lens=positions + 1)
+    return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
+        stats if routed_layers(cfg) else None, picks
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
@@ -476,20 +865,42 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     share, the pairs routed; None with no routed layer). `live` (B,)
     bool: the slots a request owns (None: every one): any other slot
     reads and writes no cache row and its token meets no expert."""
-    positions = cache.seq_lens
-    rope = _rope_tables(cfg, cache.max_seq_len, positions)
-    x, c_all, stats, _ = _run(
-        cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
-        partial(_attend_rows, cfg, positions, live), cache.c, live)
-    cache = cache._replace(c=c_all, seq_lens=positions + 1)
-    return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
-        stats if routed_layers(cfg) else None
+    return _decode(cfg, params, cache, tokens, live)[:3]
 
 
 def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
     """For tests and for telling a routing flip from arithmetic: the
     experts each routed layer chose for tokens (S,), in layer order, each
     (S, K), numbered as the router numbers them."""
-    _, chosen = jax.jit(partial(forward_free, cfg))(
+    _, chosen = jax.jit(partial(forward_free, cfg, whole=True))(
         params, jnp.asarray(tokens, jnp.int32)[None])
     return [layers[g] for layers in chosen for g in range(layers.shape[0])]
+
+
+def chosen_rows(cfg: TransformerConfig, params, tokens) -> jax.Array:
+    """For tests and for telling a flip of the indexer's choice from
+    arithmetic: the rows each layer's queries chose for tokens (S,), as a
+    tile chooses them -> bool (L, S, S): [l, t, s] says that query t of
+    layer l attends row s."""
+    S = len(tokens)
+
+    def walk(params, tokens):
+        picks = jnp.zeros((cfg.n_layers, 1, S, S), bool)
+        _, (_, _, picks), _, _ = _walk(
+            cfg, params, _scratch(cfg, 1, S) + (picks,), tokens, None,
+            jnp.arange(1))
+        return picks[:, 0]
+
+    return jax.jit(walk)(params, jnp.asarray(tokens, jnp.int32)[None])
+
+
+def decode_chosen_rows(cfg: TransformerConfig, params, cache: KVCache,
+                       tokens, live=None):
+    """`decode` that also says which rows each layer's step chose ->
+    (cache', logits, rows (L, B, k) int32, best first: of a slot's k the
+    first min(rows it holds, k) count)."""
+    k = min(cfg.index_topk, cache.max_seq_len)
+    picks = jnp.zeros((cfg.n_layers, cache.num_slots, k), jnp.int32)
+    cache, logits, _, picks = _decode(cfg, params, cache, tokens, live,
+                                      picks)
+    return cache, logits, picks

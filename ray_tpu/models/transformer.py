@@ -73,7 +73,7 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 # says why in its `MISSING`.
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
                           "mellum": "periodic", "pangu_ultra_moe": "latent",
-                          "sdar_moe": "periodic"}
+                          "sdar_moe": "periodic", "glm_moe_dsa": "latent"}
 
 # How a block of `TransformerConfig.block_length` positions is unmasked
 # (models/generate.py, `_unmask`): the names a request or a configuration
@@ -114,6 +114,29 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     "sdar_moe": PeriodForm(attn_gate=False, post_norms=False,
                            embed_scale=False, router_bias=False,
                            rotary=("global",)),
+}
+
+
+@dataclass(frozen=True)
+class LatentForm:
+    """What a layer of the latent stack (models/latent.py) has, as
+    `PeriodForm` says it of the period stack: the stack reads this and
+    never asks for an architecture by name. Whether a layer has the
+    sparse-attention indexer is the configuration's own
+    (`TransformerConfig.index_topk`)."""
+
+    post_norms: bool      # RMS norms on the attention and FFN outputs
+    router_bias: bool     # a per-expert bias added for the selection only
+
+
+# `TransformerConfig.arch` -> its layer, for the architectures of STACKS
+# that the latent stack serves.
+LATENT_FORMS: Dict[str, LatentForm] = {
+    # openPangu-Ultra-MoE: sandwich norms, four a layer; no selection bias.
+    "pangu_ultra_moe": LatentForm(post_norms=True, router_bias=False),
+    # GLM-5: a pre-norm layer of two norms, the router's selection bias
+    # (`noaux_tc`), and the learned sparse-attention indexer.
+    "glm_moe_dsa": LatentForm(post_norms=False, router_bias=True),
 }
 
 
@@ -191,8 +214,10 @@ class TransformerConfig:
     # kinds of layer). "sdar_moe": that layer again, every layer global,
     # generating a block at a time (`block_length`, at the end).
     # "pangu_ultra_moe": the latent-attention stack of
-    # models/latent.py (the fields at the end). STACKS above holds the
-    # names.
+    # models/latent.py (the fields at the end); "glm_moe_dsa": the same
+    # stack with another layer (LATENT_FORMS: two norms, a selection
+    # bias) and the sparse-attention indexer (`index_topk`). STACKS
+    # above holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length; its last layer is global
@@ -223,6 +248,19 @@ class TransformerConfig:
     # moe_experts), computing the part of the sum those give.
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # Learned sparse attention over the latent cache (models/latent.py):
+    # a second scorer a layer, `index_n_heads` heads of `index_head_dim`
+    # with a cache of one key a token of its own, scores every row a
+    # query may see, and the query attends the `index_topk` best of them
+    # (all of them while it sees no more). 0 = every row, no indexer.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # The dtype the choice is made in (a name or a type; None = `dtype`):
+    # the residual stream between such a stack's layers, the indexer's
+    # projections of it, the keys it caches and its scores' operands.
+    # Every other product and the latent cache keep `dtype`.
+    index_dtype: Any = None
     # Generation by diffusion over blocks (models/generate.py, the block
     # programs): 0 = one token a step, every other configuration's value.
     # A block of `block_length` positions (a power of two) opens masked
@@ -258,6 +296,14 @@ class TransformerConfig:
                     f"{self.arch}: n_layers - n_dense_layers ({body}) must "
                     f"be whole periods of global_attn_every "
                     f"({self.global_attn_every})")
+        if self.index_topk:
+            if STACKS[self.arch] != "latent" or self.index_n_heads < 1 \
+                    or self.index_head_dim < self.qk_rope_head_dim \
+                    or self.index_topk < 1:
+                raise ValueError(
+                    f"index_topk {self.index_topk}: only the latent stack "
+                    "has the indexer, with index_n_heads heads of "
+                    "index_head_dim >= qk_rope_head_dim")
         if self.block_length:
             Bd = self.block_length
             if self.arch not in PERIOD_FORMS or self.sliding_window:
@@ -293,6 +339,10 @@ class TransformerConfig:
     @property
     def period_form(self) -> PeriodForm:
         return PERIOD_FORMS[self.arch]
+
+    @property
+    def latent_form(self) -> LatentForm:
+        return LATENT_FORMS[self.arch]
 
     def rope_section(self, section: Optional[str]) -> Dict[str, Any]:
         """The rotary description of `section` (None, or no

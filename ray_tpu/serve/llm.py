@@ -61,6 +61,7 @@ from ..models.transformer import (
     TransformerConfig,
     init_params,
     init_params_sharded,
+    stack,
 )
 from ..util import tracing
 
@@ -391,6 +392,15 @@ class LLMEngine:
                                prefill_moe_rows_taken=0,
                                prefill_moe_pairs=0,
                                prefill_moe_pairs_held=0)
+        if cfg.index_topk:
+            # A stack that chooses the rows a step attends
+            # (models/latent.py): the latent rows the blocks' steps
+            # were to read (a slot's min(rows held, index_topk) a step;
+            # its indexer scores every row held, `cache_rows_held`), and
+            # the chunks its admission tiles ran of those their buckets
+            # have.
+            self.counts.update(sparse_rows_read=0,
+                               prefill_chunks=0, prefill_chunks_of=0)
         # Admission tiles' routing stats, on their way to the host: read
         # where the host next waits for a tile (_deliver_first_tokens).
         self._tile_moe: List[jax.Array] = []
@@ -810,6 +820,17 @@ class LLMEngine:
         c["prefill_tile_tokens"] += W * bucket
         if side == "queue":
             c["queue_side_first_tokens"] += len(reqs)
+        if self.cfg.index_topk:
+            # A tile walked a chunk at a time (models/latent.py): the
+            # chunks it runs, to the longest prompt's last token (a
+            # queue-side tile has no lengths and runs them all), of
+            # those its bucket has.
+            run, of = stack(self.cfg).prefill_chunks(
+                self.cfg, bucket, bucket if side == "queue"
+                else max(len(r.prompt) - skip for r in reqs))
+            c["prefill_chunks"] += run
+            c["prefill_chunks_of"] += of
+            more = dict(more, chunks=run, chunks_of=of)
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
             tile_rows=W, tokens=tokens, req_ids=_ids(reqs), **more)
@@ -1328,6 +1349,16 @@ class LLMEngine:
         return min(k_block * (slot.length + slot.inflight + 1)
                    + k_block * (k_block - 1) // 2, k_block * S)
 
+    def _rows_read(self, k_block: int, slot: _Slot) -> int:
+        """Latent rows the slot's attention is to read over the next
+        `k_block` steps where an indexer chooses them: of the rows it
+        holds at a step (`_rows_held`) `index_topk`, all of them while
+        it holds no more. The host's arithmetic, as `_rows_held` is: what
+        the program was asked for, not what it was seen to do."""
+        first = slot.length + slot.inflight + 1
+        cap = min(self.cfg.index_topk, self.max_seq_len)
+        return sum(min(first + t, cap) for t in range(k_block))
+
     def _dispatch_block(self, k_block: int, snap: List, active: List[int]):
         """One fused block of `k_block` decode steps for every slot (the
         program computes all `num_slots`; `active` of them hold a
@@ -1352,6 +1383,10 @@ class LLMEngine:
         owned[active] = True
         more = dict(passes=k_block, block_length=self.block_length) \
             if self.block_length else {}
+        if self.cfg.index_topk:
+            read = sum(self._rows_read(k_block, snap[i]) for i in active)
+            c["sparse_rows_read"] += read
+            more.update(sparse_rows_read=read)
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held, **more):

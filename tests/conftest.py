@@ -68,6 +68,8 @@ def pytest_collection_finish(session):
             tiny.setdefault("openpangu-longgen-closed", "tiny-pangu-closed")
             # tests/benchmark/test_sdar_cell.py makes this one's.
             tiny.setdefault("sdar-blockgen-closed", "tiny-sdar-closed")
+            # tests/benchmark/test_glm_cell.py makes this one's.
+            tiny.setdefault("glm5-longctx-closed", "tiny-glm-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -81,7 +83,7 @@ def _per_layer():
 def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
     test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
-    test_sdar_cell.py) names
+    test_sdar_cell.py, test_glm_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(

@@ -235,8 +235,9 @@ def test_absorbed_decode_is_unabsorbed_attention_over_the_same_rows(params):
     row = jnp.pad(jax.random.normal(ks[3], (B, 1, kvr + rope), jnp.float32),
                   ((0, 0), (0, 0), (0, 128 - kvr - rope)))
     positions = jnp.asarray([5, 23, 11], jnp.int32)
-    got, after = latent._attend_rows(CFG, positions, None, jnp.int32(0), lp,
-                                     q_nope, q_r, row, c_all)
+    got, (after, _, _) = latent._attend_rows(
+        CFG, positions, None, jnp.int32(0), lp, q_nope, q_r, row, None,
+        (c_all, None, None))
     assert np.array_equal(after[0, 1, 23], row[1, 0])
     rows = np.asarray(after[0])
     want = np.zeros((B, H, vd), np.float32)

@@ -18,7 +18,8 @@ from ray_tpu.models.transformer import STACKS, init_params, offered, stack
 TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "mellum": configs.tiny_mellum_test,
         "pangu_ultra_moe": configs.tiny_pangu_test,
-        "sdar_moe": configs.tiny_sdar_test}
+        "sdar_moe": configs.tiny_sdar_test,
+        "glm_moe_dsa": configs.tiny_glm_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 

@@ -993,3 +993,96 @@ def test_sdar_admission_tiles_fit_beside_weights_and_cache(
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "jit(gmm)" in text and "ragged-dot" not in text
     _sdar_fits(serve_sdar, mem, record_property)
+
+
+# -- glm5-longctx-closed: rows an indexer chooses, a tile walked in chunks ---
+
+@pytest.fixture(scope="module")
+def serve_glm(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "glm5-longctx-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("glm-5-l5-ep16", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _glm_fits(serve_glm, mem, record_property):
+    cfg, slots, one, key, params, cache = serve_glm
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    held = sum(x.size * x.dtype.itemsize for x in (cache.c, cache.ki))
+    # 7.82 GB of weights beside 16 x 32,768 rows of 512 + 64 values in
+    # whole lanes (640), bf16, and of the indexer's 128 in the dtype the
+    # cell states for the choice (`model.index_dtype`: float32), five
+    # layers: 3.36 + 1.34.
+    assert 7.81e9 < weights < 7.83e9
+    assert cache.c.shape == (5, 16, 32768, 640)
+    assert cache.ki.shape == (5, 16, 32768, 128)
+    assert cfg.dtype == jnp.bfloat16 and cfg.index_dtype == "float32"
+    assert cache.ki.dtype == jnp.float32 and cache.c.dtype == jnp.bfloat16
+    assert cache.k is None and cache.v is None and 4.69e9 < held < 4.70e9
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    # Both caches aliased: no program copies either in or out.
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_glm_decode_block_scores_chooses_and_reads_the_chosen_rows(
+        serve_glm, as_on_the_chip, record_property):
+    """`decode_multi` (k = 8) at the cell's 16 slots x 32,768: the
+    indexer's scorer over its slots' keys (one sum in XLA), an exact
+    top-k (no `approx`), the decode kernel over the 2,048 gathered rows
+    of 640 lanes under 64 query heads, megablox's kernel for the held
+    experts, both caches updated in place."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = serve_glm
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 8, 0,
+                                  key, live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts/while/body/jit(gmm)" in text
+    assert "ragged-dot" not in text and "approx" not in text.lower()
+    assert "decode_attn" in text
+    for scope in ("attn_index", "attn_sparse", "mla_proj", "moe_router",
+                  "moe_shared"):
+        assert scope in text, scope
+    assert "attn_latent" not in text
+    _glm_fits(serve_glm, mem, record_property)
+    assert mem.temp_size_in_bytes < 1e9
+
+
+def test_glm_admission_tile_walks_the_longest_bucket_in_chunks(
+        serve_glm, as_on_the_chip, record_property):
+    """The one-row tile of the 32,768 bucket: sixteen chunks of 2,048 in
+    one program (a loop the device counts), each chunk's scorer, exact
+    threshold and masked per-head attention as kernels; it compiles for
+    the described chip beside 7.82 GB of weights and 4.70 GB of caches,
+    both rows-major throughout."""
+    from ray_tpu.models import latent
+    from ray_tpu.models.generate import prefill_sample_batch
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_glm
+    W = LLMEngine._tile_rows(32768)
+    assert W == 1 and latent.prefill_chunks(cfg, 32768, 20000) == (
+        -(-20000 // latent.PREFILL_CHUNK), 32768 // latent.PREFILL_CHUNK)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = prefill_sample_batch.lower(
+        cfg, params, cache, arr((W, 32768), jnp.int32), arr((W,), jnp.int32),
+        arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "moe_experts/while/body/jit(gmm)" in text
+    assert "ragged-dot" not in text and "approx" not in text.lower()
+    for kernel in ("index_scores_tile", "topk_threshold",
+                   "sparse_prefill_attn"):
+        assert kernel in text, kernel
+    _glm_fits(serve_glm, mem, record_property)
