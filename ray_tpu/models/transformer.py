@@ -586,7 +586,7 @@ STATE_COPIES = 4
 # internlm2-1.8b over fsdp=4, 3.24e9 at llama-654m and 3.87e9 at
 # llama-1b4 on one chip). internlm2-1.8b over fsdp=4 at 2 x 4,096 tokens
 # a device holds 7.56e9 of state and keeps 3.23e9: it passes, and
-# compiles to 14.88e9; at three sequences a device it does not. llama-654m
+# compiles to 14.22e9; at three sequences a device it does not. llama-654m
 # at 8 x 1,024 on one chip (10.47e9 + 1.48e9) does not either, and
 # should not: there XLA holds the kept values twice.
 REMAT_DEVICE_BYTES = int(15.75e9 - 4e9)
@@ -743,17 +743,107 @@ def token_cross_entropy(logits: jax.Array, targets: jax.Array,
                    "tokens": jnp.sum(mask)}
 
 
+def _chunk_nll(xc: jax.Array, head: jax.Array, tc: jax.Array,
+               mc: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One chunk's float32 logits, their log-sum-exp and the sum of the
+    masked negative log-likelihoods."""
+    logits = (xc @ head).astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+    return logits, logz, jnp.sum((logz - gold) * mc)
+
+
+def _gathered(head: jax.Array) -> jax.Array:
+    """The head whole along `embed` before a scan over chunks: the
+    parameter is sharded there (fsdp), and gathered where it is used it
+    is gathered once a chunk, inside the scan."""
+    return wsc(head, (None, "vocab"))
+
+
+@jax.custom_vjp
+def _chunked_nll(xs: jax.Array, head: jax.Array, ts: jax.Array,
+                 ms: jax.Array) -> jax.Array:
+    """Mean masked negative log-likelihood of chunks xs (n, B, chunk, D)
+    under `head` (D, V). Undifferentiated (an evaluation), the scan
+    makes the logits and nothing else."""
+    head = _gathered(head)
+
+    def body(tot, inp):
+        xc, tc, mc = inp
+        return tot + _chunk_nll(xc, head, tc, mc)[2], None
+
+    tot, _ = lax.scan(body, jnp.zeros(()), (xs, ts, ms))
+    return tot / jnp.maximum(jnp.sum(ms), 1.0)
+
+
+def _chunked_nll_fwd(xs, head, ts, ms):
+    """The differentiated forward: a cross entropy's gradient is known
+    the moment a chunk's logits are, so the pass that makes them makes
+    both gradients while they are there (softmax - onehot in float32,
+    its two products over operands of the activation dtype accumulated
+    in float32, as the transposes of `xc @ head` are) and keeps those:
+    the logits are neither held nor multiplied again.
+
+    No collective inside the scan: on the chip one costs the step
+    nearly its whole length, whatever a profile shows beside it
+    (PERF.md, PR 43: eight all-reduces of the head's gradient a step 37
+    ms, eight all-gathers of the head 25). So the head is gathered
+    before the scan, and its gradient, which sums over the batch that a
+    mesh cuts, is carried as one float32 partial sum a batch shard and
+    reduced once behind the scan (a carry of the head's own shape the
+    partitioner reduces a chunk). The barrier has that reduction done
+    before the backward pass takes `dxs`: left to the scheduler it waits
+    for the optimizer, and the partial sums (758 MB a device in the
+    cell) with it."""
+    count = jnp.maximum(jnp.sum(ms), 1.0)
+    head = _gathered(head)
+    shards = _shards(("batch",), _mesh_sizes())
+    if xs.shape[1] % shards:
+        shards = 1
+    partial_axes = ("batch", None, "vocab")
+
+    def body(carry, inp):
+        tot, dhead = carry
+        xc, tc, mc = inp
+        logits, logz, nll = _chunk_nll(xc, head, tc, mc)
+        with jax.named_scope("head_grad"):
+            p = jnp.exp(logits - logz[..., None])
+            p = (p - jax.nn.one_hot(tc, p.shape[-1], dtype=p.dtype)) \
+                * (mc / count)[..., None]
+            p = p.astype(xc.dtype)
+            dxc = jnp.einsum("bcv,dv->bcd", p, head)
+            dhead = wsc(dhead + jnp.einsum(
+                "sbcd,sbcv->sdv", xc.reshape((shards, -1) + xc.shape[1:]),
+                p.reshape((shards, -1) + p.shape[1:]),
+                preferred_element_type=jnp.float32), partial_axes)
+        return (tot + nll, dhead), dxc
+
+    zero = wsc(jnp.zeros((shards,) + head.shape, jnp.float32), partial_axes)
+    (tot, dhead), dxs = lax.scan(body, (jnp.zeros(()), zero), (xs, ts, ms))
+    dhead = wsc(dhead.sum(0).astype(head.dtype), ("embed", "vocab"))
+    return tot / count, lax.optimization_barrier((dxs, dhead))
+
+
+def _chunked_nll_bwd(res, g):
+    dxs, dhead = res
+    return ((g * dxs).astype(dxs.dtype), (g * dhead).astype(dhead.dtype),
+            None, None)
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
+
+
 def chunked_cross_entropy(cfg: TransformerConfig, params: Dict[str, Any],
                           x: jax.Array, targets: jax.Array,
                           mask: Optional[jax.Array], aux: jax.Array,
                           chunk: int) -> Tuple[jax.Array, Dict]:
     """Fused/blockwise vocab projection + cross entropy: scans the
-    sequence in chunks, computing each chunk's logits inside a
-    jax.checkpoint so the full f32 (B, S, V) logits tensor is never
+    sequence in chunks, so the full f32 (B, S, V) logits tensor is never
     materialized (for GPT-2-125M at B16×S1024 that tensor is 3.3 GB
-    each for value and grad — the dominant HBM cost of the step).
-    Numerically identical to token_cross_entropy (same per-position
-    logsumexp in f32)."""
+    each for value and grad — the dominant HBM cost of the step), and
+    under differentiation a chunk's pass makes its gradients too
+    (`_chunked_nll`). Numerically identical to token_cross_entropy (same
+    per-position logsumexp in f32)."""
     B, S, D = x.shape
     head = _lm_head(cfg, params)
     n_chunks = S // chunk
@@ -764,22 +854,10 @@ def chunked_cross_entropy(cfg: TransformerConfig, params: Dict[str, Any],
     else:
         ms = mask.astype(jnp.float32).reshape(
             B, n_chunks, chunk).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def body(carry, inp):
-        xc, tc, mc = inp
-        logits = (xc @ head).astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mc
-        tot, cnt = carry
-        return (tot + jnp.sum(nll), cnt + jnp.sum(mc)), None
-
-    (tot, cnt), _ = lax.scan(body, (jnp.zeros(()), jnp.zeros(())),
-                             (xs, ts, ms))
-    ce = tot / jnp.maximum(cnt, 1.0)
+    ce = _chunked_nll(xs, head, ts, ms)
     total = ce + aux
-    return total, {"loss": total, "ce": ce, "aux": aux, "tokens": cnt}
+    return total, {"loss": total, "ce": ce, "aux": aux,
+                   "tokens": jnp.sum(ms)}
 
 
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any],

@@ -225,10 +225,7 @@ def test_train_step_holds_one_flash_forward_where_its_residuals_fit(
     from ray_tpu.models import transformer
 
     batch, forwards = TRAIN_STEPS[case]
-    with open(os.path.join(ROOT, "benchmarks", "cells",
-                           "internlm2-1b8-train-fsdp4.json")) as f:
-        sizes = json.load(f)
-    cfg = _benchmark_config("internlm2-1.8b", sizes)
+    cfg = _train_cell_config()
     kept = transformer.remat_kept_bytes(cfg, batch, 4096, {"fsdp": 4})
     shapes = jax.eval_shape(lambda k: transformer.init_params(cfg, k),
                             jax.random.key(0))
@@ -248,6 +245,53 @@ def test_train_step_holds_one_flash_forward_where_its_residuals_fit(
     record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
     if forwards == 1:
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_train_step_makes_the_heads_gradients_where_it_makes_the_logits(
+        topo, as_on_the_chip, record_property):
+    """The same step (`internlm2-1b8-train-fsdp4`, two sequences a chip):
+    the loss head's scan sits in the forward pass with its gradient half
+    (`head_grad`) and nothing of it runs again in the backward pass; the
+    head is gathered before the scan and its gradient, a partial sum a
+    device, reduced once behind it, neither once a chunk inside it; and
+    the whole still fits the described chip, with the partial sums gone
+    before the backward."""
+    cfg = _train_cell_config()
+    compiled = _aot_compile_step(topo, cfg, 4, batch=8, seq=4096)
+    text = compiled.as_text()
+    scopes = set(re.findall(r'op_name="([^"]*loss_head[^"]*)"', text))
+    assert any("/jvp(loss_head)/while/body/" in s and "/head_grad/" in s
+               for s in scopes)
+    assert not [s for s in scopes if "rematted_computation" in s
+                or "transpose(jvp(loss_head))/while" in s]
+    head = f"[{cfg.d_model},{cfg.vocab_size}]"
+
+    def scopes_of(collective):
+        """Scope paths of the collectives whose result has the head's
+        shape."""
+        return [m.group(2) for m in re.finditer(
+            rf'= (\S+) {collective}\(.*?op_name="([^"]*)"', text)
+            if head in m.group(1)]
+
+    reduces, gathers = scopes_of("all-reduce"), scopes_of("all-gather")
+    assert len(reduces) == 1 and gathers
+    assert not [s for s in reduces + gathers
+                if "loss_head" not in s or "/while/" in s], (reduces, gathers)
+    mem = compiled.memory_analysis()
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 15.75e9, (
+        f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + temporaries "
+        f"{mem.temp_size_in_bytes / 1e9:.2f} GB = {total / 1e9:.2f} GB a "
+        f"device (output {mem.output_size_in_bytes / 1e9:.2f}, aliased "
+        f"{mem.alias_size_in_bytes / 1e9:.2f})")
+
+
+def _train_cell_config():
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "internlm2-1b8-train-fsdp4.json")) as f:
+        return _benchmark_config("internlm2-1.8b", json.load(f))
 
 
 def _benchmark_config(name, sizes):
