@@ -4,8 +4,9 @@
 drops the tokens over it; its `(T, K, E, capacity)` dispatch tensor is
 also out of reach at serving sizes. Here the token-expert pairs are
 sorted by expert and multiplied by groups (`grouped_dot`: a grouped-matmul
-kernel that visits only the experts that hold rows), then put back in
-order, weighted and summed. Router scores and the selection
+kernel that visits only the experts that hold rows; `grouped_swiglu`: the
+gate and up products and the activation between them as one such kernel),
+then put back in order, weighted and summed. Router scores and the selection
 are float32 from a float32 input: routing is discontinuous, and a score
 rounded to bf16 picks another expert where two lie close.
 
@@ -120,6 +121,26 @@ def _gmm_tiling(rows: int, k: int, n: int):
     return tm, k, tn
 
 
+def _kernel_rows(a: jax.Array, w: jax.Array, kernel):
+    """Where a pallas kernel takes a (R, D) against w (G, D, N) by
+    groups: (a padded to the kernel's row tile, its tiling, whether it
+    runs in the interpreter); None where XLA's `ragged_dot` does: off
+    the TPU, a shape that does not tile, operands not both bf16. `kernel`
+    as `grouped_dot`'s."""
+    pad = -a.shape[0] % 128
+    tiling = _gmm_tiling(a.shape[0] + pad, a.shape[1], w.shape[2])
+    if kernel is None:
+        from ..ops.flash_attention import on_tpu
+        kernel = on_tpu()
+    if not (kernel and tiling and a.dtype == w.dtype == jnp.bfloat16):
+        return None
+    # The rows added belong to no group, so a grouped kernel neither
+    # reads nor writes them (a lone caller's decode is 64 rows, and
+    # `lax.ragged_dot` costs twice the kernel).
+    rows = jnp.pad(a, ((0, pad), (0, 0))) if pad else a
+    return rows, tiling, kernel == "interpret"
+
+
 def grouped_dot(a: jax.Array, w: jax.Array, groups: jax.Array,
                 kernel=None) -> jax.Array:
     """a (R, D), rows sorted by group, against w (G, D, N); `groups` (G,)
@@ -134,31 +155,42 @@ def grouped_dot(a: jax.Array, w: jax.Array, groups: jax.Array,
         two = jnp.swapaxes(bf16_terms(a), 0, 1).reshape(2 * R, a.shape[-1])
         y = grouped_dot(two, w, 2 * groups, kernel)
         return jnp.sum(y.reshape(R, 2, -1), axis=1)
-    # Rows up to the kernel's row tile: the rows added belong to no
-    # group, so the kernel neither reads nor writes them (a lone caller's
-    # decode is 64 rows, and `lax.ragged_dot` costs twice the kernel).
-    pad = -R % 128
-    tiling = _gmm_tiling(R + pad, a.shape[1], w.shape[2])
-    if kernel is None:
-        from ..ops.flash_attention import on_tpu
-        kernel = on_tpu()
-    if kernel and tiling and a.dtype == w.dtype == jnp.bfloat16:
+    takes = _kernel_rows(a, w, kernel)
+    if takes:
         from jax.experimental.pallas.ops.tpu import megablox
-        if pad:
-            a = jnp.pad(a, ((0, pad), (0, 0)))
-        y = megablox.gmm(a, w, groups, jnp.float32, tiling,
-                         interpret=kernel == "interpret")
-        return y[:R] if pad else y
+        rows, tiling, interpret = takes
+        return megablox.gmm(rows, w, groups, jnp.float32, tiling,
+                            interpret=interpret)[:R]
     return lax.ragged_dot(a, w.astype(a.dtype), groups, precision=_exact(a),
                           preferred_element_type=jnp.float32)
+
+
+def grouped_swiglu(a: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                   groups: jax.Array, kernel=None) -> jax.Array:
+    """`silu(a @ w_gate) * (a @ w_up)` by groups, in a's dtype: both
+    products accumulated and the activation taken in float32, rounded
+    once. Where `grouped_dot` would take the kernel for both products
+    (`kernel` as there) they are one kernel, `ops/grouped_swiglu`: the
+    rows are read once and no float32 (R, N) array is written. Anywhere
+    else two `grouped_dot` calls and XLA's activation: off the TPU, a
+    shape that does not tile, and float32 rows over bf16 weights, whose
+    two terms a row are summed before the activation."""
+    takes = _kernel_rows(a, w_gate, kernel)
+    if takes:
+        from ..ops.grouped_swiglu import gmm_swiglu
+        rows, tiling, interpret = takes
+        return gmm_swiglu(rows, w_gate, w_up, groups, tiling,
+                          interpret=interpret)[:a.shape[0]]
+    h = jax.nn.silu(grouped_dot(a, w_gate, groups, kernel)) \
+        * grouped_dot(a, w_up, groups, kernel)
+    return h.astype(a.dtype)
 
 
 def _grouped_swiglu(w: Dict[str, jax.Array], xs: jax.Array,
                     groups: jax.Array) -> jax.Array:
     """SwiGLU_g of rows xs sorted by group, a group's own matrices."""
-    h = jax.nn.silu(grouped_dot(xs, w["w_gate"], groups)) \
-        * grouped_dot(xs, w["w_up"], groups)
-    return grouped_dot(h.astype(xs.dtype), w["w_down"], groups)
+    h = grouped_swiglu(xs, w["w_gate"], w["w_up"], groups)
+    return grouped_dot(h, w["w_down"], groups)
 
 
 def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,

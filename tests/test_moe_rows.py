@@ -19,6 +19,8 @@ import pytest
 
 from ray_tpu.models import configs, generate, moe
 from ray_tpu.models.transformer import init_params, loss_fn, stack
+from ray_tpu.ops import grouped_swiglu
+from ray_tpu.ops.grouped_swiglu import gmm_swiglu
 
 T, K, E, D, F = 6, 2, 4, 128, 128
 ROUTED, FIRST_HELD = 8, 2           # `held_experts`: experts [2, 6) of 8
@@ -61,26 +63,29 @@ LAYERS = {"grouped": _grouped, "held": _held}
 
 @pytest.fixture
 def products(request, monkeypatch):
-    """The grouped products as a CPU runs them, or megablox's kernel in
-    the interpreter; and, `poison` "written_nowhere", every row past the
-    last group filled with NaN behind either: what a kernel that writes
-    no such row may leave there."""
+    """The grouped products as a CPU runs them, or the kernels in the
+    interpreter: megablox's for a product, and for a bf16 layer's gate,
+    up and activation the one of `ops/grouped_swiglu`; and, `poison`
+    "written_nowhere", every row past the last group filled with NaN
+    behind either: what a kernel that writes no such row may leave
+    there."""
     path, poison = request.param
-    ours = moe.grouped_dot
     force = "interpret" if path == "interpret" else None
 
-    def real(a, w, groups, kernel=None):
-        return ours(a, w, groups, force)
-
-    def poisoned(a, w, groups, kernel=None):
-        y = real(a, w, groups)
-        past = jnp.arange(a.shape[0]) >= jnp.sum(groups)
-        return jnp.where(past[:, None], jnp.nan, y)
+    def forced(product, operands):
+        def run(a, *rest):
+            rest = rest[:operands]          # the caller's `kernel` goes
+            y = product(a, *rest, force)
+            if poison != "written_nowhere":
+                return y
+            past = jnp.arange(a.shape[0]) >= jnp.sum(rest[-1])
+            return jnp.where(past[:, None], jnp.nan, y)
+        return run
 
     if path == "interpret" or poison == "written_nowhere":
-        monkeypatch.setattr(moe, "grouped_dot",
-                            poisoned if poison == "written_nowhere"
-                            else real)
+        monkeypatch.setattr(moe, "grouped_dot", forced(moe.grouped_dot, 2))
+        monkeypatch.setattr(moe, "grouped_swiglu",
+                            forced(moe.grouped_swiglu, 3))
     return path, poison
 
 
@@ -103,7 +108,8 @@ CASES = [(dtype, (path, poison))
     "dtype,products", CASES, indirect=["products"],
     ids=["-".join((dtype,) + products) for dtype, products in CASES])
 @pytest.mark.parametrize("layer", sorted(LAYERS))
-def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products):
+def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products,
+                                                    monkeypatch):
     path, poison = products
     w, x, weights, key = _case(dtype)
     rows = jnp.asarray(OWNED)
@@ -111,6 +117,13 @@ def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products):
         # The kernel does run: it tiles these shapes.
         assert moe._gmm_tiling(128, D, F) is not None
     run = LAYERS[layer]
+    fused = []
+
+    def counted(*args, **kw):
+        fused.append(args[0].shape)
+        return gmm_swiglu(*args, **kw)
+
+    monkeypatch.setattr(grouped_swiglu, "gmm_swiglu", counted)
     want, all_sizes, all_chose, keys = run(w, x, weights, key, None)
     if poison == "a_nan_row_of_x":
         x = jnp.where(rows[:, None], x, jnp.nan)
@@ -124,6 +137,9 @@ def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products):
     assert np.asarray(chose).tolist() == scored.tolist() \
         == np.asarray(all_sizes).tolist() == np.asarray(all_chose).tolist()
     assert 0 < taken.sum() < scored.sum()
+    # bf16 rows in the interpreter: gate, up and the activation between
+    # them were the one kernel, in both calls of the layer.
+    assert bool(fused) == ((dtype, path) == ("bfloat16", "interpret"))
 
 
 @pytest.mark.parametrize("products", [("ragged", "written_nowhere"),

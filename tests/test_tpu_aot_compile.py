@@ -659,6 +659,15 @@ def as_on_the_chip(monkeypatch):
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
 
 
+def _fused(text, loop=False):
+    """Whether the routed layers' gate and up products are the one
+    kernel of `ops/grouped_swiglu`, beside megablox's for the down
+    product (`loop`: inside `held_experts`' loop over passes)."""
+    at = "moe_experts/while/body/" if loop else "moe_experts/"
+    assert at + "jit(gmm)" in text and "ragged-dot" not in text
+    return at + "jit(gmm_swiglu)" in text and '"kernel":"gmm_swiglu"' in text
+
+
 def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
         serve_trinity, as_on_the_chip):
     """`decode_multi` (k = 8) at the cell's 32 slots x 4096, bf16 weights
@@ -683,6 +692,9 @@ def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
     text = compiled.as_text()
     mem, writes = compiled.memory_analysis(), _device_writes(text)
     assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+    # Float32 rows sum their two bf16 terms before the activation: the
+    # three products stay megablox's.
+    assert not _fused(text)
 
     def nbytes(x):
         return x.size * x.dtype.itemsize
@@ -805,7 +817,9 @@ def test_mellum_decode_block_reaches_the_grouped_kernel_under_128_rows(
     """`decode_multi` (k = 8) at the cell's 4 slots x 8192, and under
     float32 activations: 4 slots x 8 pairs = 32 (two bf16 terms: 64)
     rows a grouped product, padded to the kernel's row tile:
-    megablox's kernel at 2304 x 896 and 896 x 2304 and no `ragged-dot`;
+    megablox's kernel at 2304 x 896 and 896 x 2304 and no `ragged-dot`
+    (bf16: gate, up and the activation one kernel, `gmm_swiglu`, and
+    megablox's for the down product);
     both kinds of cache aliased; all 64 experts of eight layers and the
     98,304-row head on one chip."""
     from ray_tpu.models.generate import decode_multi
@@ -819,6 +833,7 @@ def test_mellum_decode_block_reaches_the_grouped_kernel_under_128_rows(
                                   key, live).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+    assert _fused(text) == (activations == "bfloat16")
     assert "decode_attn" in text
     held = sum(x.size * x.dtype.itemsize
                for x in (cache.k, cache.v, cache.kw, cache.vw))
@@ -856,6 +871,7 @@ def test_mellum_admission_tile_fits_beside_weights_and_cache(
     record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
     record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
     assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
+    assert _fused(text) == (activations == "bfloat16")
     for scope in ("attn_window", "attn_global", "moe_router"):
         assert scope in text, scope
     # The flash kernel a layer of the period (three window layers and
@@ -863,6 +879,39 @@ def test_mellum_admission_tile_fits_beside_weights_and_cache(
     assert fa.DISPATCH_COUNTS["pallas"] - before == \
         (4 if activations == "bfloat16" else 0)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("rows,k,n,experts", [
+    (65536, 2304, 896, 64), (32, 2304, 896, 64), (1024, 7680, 2048, 16)],
+    ids=["mellum_tile", "mellum_decode", "openpangu_pass"])
+def test_gate_up_and_activation_compile_as_one_kernel(topo, as_on_the_chip,
+                                                      rows, k, n, experts):
+    """`moe.grouped_swiglu` alone at the cells' shapes. A tile of
+    mellum's takes row tiles of 256 and all of 2304 x 896 a weight tile:
+    two of them double-buffered are 16.5 MB, so the compile stands on
+    the `vmem_limit_bytes` the call states. The result is bf16 and no
+    float32 (rows, n) array is left in the program."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import grouped_swiglu
+
+    one = SingleDeviceSharding(topo.devices[0])
+    tm, tk, tn = moe._gmm_tiling(rows + -rows % 128, k, n)
+    assert (tm, tk) == (256 if rows == 65536 else 128, k)
+    assert tn == (896 if n == 896 else 128)
+    held = 2 * 2 * tk * tn * 2 + 2 * tm * tk * 2 + 2 * tm * tn * 4 \
+        + 2 * tm * tn * 2
+    assert held < grouped_swiglu.VMEM_LIMIT_BYTES
+    assert (held > 16 * 2 ** 20) == (n == 896)
+    a = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((experts, k, n), jnp.bfloat16, sharding=one)
+    groups = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one)
+    compiled = jax.jit(moe.grouped_swiglu).lower(a, w, w, groups).compile()
+    text = compiled.as_text()
+    assert '"kernel":"gmm_swiglu"' in text and "ragged-dot" not in text
+    assert "jit(gmm)" not in text
+    assert f"f32[{rows + -rows % 128},{n}]" not in text
+    assert jax.eval_shape(moe.grouped_swiglu, a, w, w, groups).dtype \
+        == jnp.bfloat16
 
 
 # -- openpangu-longgen-closed: a latent cache and a share of the experts ----
@@ -913,6 +962,7 @@ def test_pangu_decode_block_reads_latent_rows_through_the_kernel(
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/while/body/jit(gmm)" in text
     assert "ragged-dot" not in text and "decode_attn" in text
+    assert _fused(text, loop=True)
     for scope in ("attn_latent", "mla_proj", "moe_router", "moe_shared"):
         assert scope in text, scope
     _pangu_fits(serve_pangu, mem, record_property)
@@ -943,6 +993,7 @@ def test_pangu_admission_tile_fits_beside_weights_and_latent_cache(
         arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/while/body/jit(gmm)" in text
+    assert _fused(text, loop=True)
     assert "ragged-dot" not in text and "flash_fwd" in text
     # One trace a group of the plan (the dense layer, the routed layers).
     assert fa.DISPATCH_COUNTS["pallas"] - before == 2
@@ -1006,6 +1057,7 @@ def test_sdar_block_program_runs_four_positions_a_slot_through_the_kernels(
         live).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts" in text and "jit(gmm)" in text
+    assert "jit(gmm_swiglu)" in text
     assert "ragged-dot" not in text and "decode_attn" in text
     for scope in ("attn_global", "moe_router", "block_head", "block_sample"):
         assert scope in text, scope
@@ -1036,6 +1088,7 @@ def test_sdar_admission_tiles_fit_beside_weights_and_cache(
         arr((W,), jnp.float32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "jit(gmm)" in text and "ragged-dot" not in text
+    assert "jit(gmm_swiglu)" in text
     _sdar_fits(serve_sdar, mem, record_property)
 
 
@@ -1091,6 +1144,7 @@ def test_glm_decode_block_scores_chooses_and_reads_the_chosen_rows(
                                   key, live).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/while/body/jit(gmm)" in text
+    assert _fused(text, loop=True)
     assert "ragged-dot" not in text and "approx" not in text.lower()
     assert "decode_attn" in text
     for scope in ("attn_index", "attn_sparse", "mla_proj", "moe_router",
@@ -1125,6 +1179,7 @@ def test_glm_admission_tile_walks_the_longest_bucket_in_chunks(
         arr((W,), jnp.int32), 0, arr((W,), jnp.float32), key).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/while/body/jit(gmm)" in text
+    assert _fused(text, loop=True)
     assert "ragged-dot" not in text and "approx" not in text.lower()
     for kernel in ("index_scores_tile", "topk_threshold",
                    "sparse_prefill_attn"):
