@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .generate import KVCache, _attend_cache, _last_rows, _rope
+from .stackparts import KVCache, _attend_cache, _last_rows, _rope
 from .transformer import (
     TransformerConfig,
     dense_ffn,

@@ -1,28 +1,28 @@
-"""Autoregressive generation: prefill/decode split with a static KV cache.
+"""The serving programs: what `serve/llm.py` launches on the device, each
+jitted under the name `PROGRAM_NAMES` gives it. They are written once
+for every architecture: a program reaches the model through the stack
+module of its configuration (`transformer.stack(cfg)`, `offered`) and
+never asks which one that is (tests/test_stacks.py). Designed for XLA's
+compilation model:
 
-TPU-native inference path (the reference serves LLMs only through vLLM
-integration — SURVEY.md §2.3 Serve row, doc vllm_example.py; this is
-in-framework capability). Design for XLA's compilation model:
-
-- **Static shapes everywhere.** The KV cache is a fixed (L, B, S_max,
-  KVH, Dh) buffer; sequences occupy slots. Prompt lengths are bucketed
-  (powers of two) so prefill compiles once per bucket, decode compiles
-  once, period.
-- **Prefill/decode split.** Prefill runs the full prompt through the
-  flash-attention forward (MXU-heavy, one sequence at a time into its
-  slot); decode runs one token for ALL slots per step (batched matmuls
-  keep the MXU fed; attention reads the cache with a length mask).
-- **Per-slot positions.** Each slot sits at its own position; RoPE tables
-  are gathered per slot, so one compiled decode step serves any mix of
-  sequence lengths (the continuous-batching property).
-
-The cache favors a contiguous per-slot layout over a paged one: with
-slot-bucketed static shapes XLA keeps the whole cache resident in HBM,
-prefill writes are dynamic-update-slices and decode writes are one-row
-scatters into the buffer the decode programs carry (never a layer
-sliced out and stacked back); a page table would force gathers on the
-attention read path. Capacity control comes from S_max buckets instead
-of pages.
+- **Static shapes everywhere.** Sequences occupy slots of a cache whose
+  shape is fixed for the life of an engine (`init_kv_cache`; what it
+  holds is its stack's business: `stackparts.KVCache`); every program
+  that takes it donates it and returns it, one contiguous layout a slot
+  (a page table would force gathers on attention's read path). Prompt
+  lengths are bucketed (powers of two), so an admission tile compiles
+  once a bucket and a decode block once a size (`decode_k<k>`).
+- **Prefill/decode split.** An admission tile (`prefill_sample_batch`;
+  `prefill_suffix_batch` behind a registered prefix, `prefill_block_batch`
+  where generation is by diffusion over blocks) runs a few padded
+  prompts through the stack's tile walk into their slots and samples
+  each one's first token; `first_token_sample` does that for requests
+  still queued, with no cache. A decode block (`decode_multi`,
+  `decode_block_multi`) runs k steps for ALL slots in one launch, the
+  cache carried through its loops and sampling on the device.
+- **Per-slot positions.** Each slot sits at its own position, so one
+  compiled decode step serves any mix of sequence lengths; a slot no
+  request owns reads and writes no cache row (`live`).
 """
 
 from __future__ import annotations
@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .transformer import TransformerConfig, apply_rope, offered, stack
+from .stackparts import KVCache
+from .transformer import TransformerConfig, offered, stack
 
 
 # What the device's trace calls each serving program: its module events
@@ -113,185 +114,10 @@ class _BlockPrograms:
         return self.program_for(args[5]).lower(*args)
 
 
-class KVCache(NamedTuple):
-    """Static decode state. k/v: (L, B, S_max, KVH, Dh) activation dtype;
-    seq_lens: (B,) int32 — tokens already written per slot.
-
-    One buffer each for the life of an engine: every program that takes
-    a cache donates it and returns it updated in place. The decode
-    programs carry k and v whole through their layer and step loops,
-    write one row a slot a layer and read, of each layer, the rows the
-    owned slots hold (`_attend_cache`); a row no request owns is never
-    written. A row below `seq_lens` is final; what lies at or past it is
-    padding a prefill left or, where a configuration generates a block
-    of positions a pass (`decode_block_multi`), the rows [seq_lens,
-    seq_lens + block_length) of the open block as its last pass wrote
-    them: every pass of the block overwrites them, and they are final
-    only once a pass over the block's final tokens has advanced
-    `seq_lens` past them.
-
-    A period stack (`models/periodic.py`) keeps two kinds of state: k/v
-    hold its global layers, (Lg, B, S_max, KVH, Dh), and kw/vw its
-    window layers, (Lw, B, min(window, S_max), KVH, Dh), a ring written
-    at `position mod rows`. Every other model leaves kw/vw None.
-
-    A latent stack (`models/latent.py`) keeps neither keys nor values a
-    head: `c`, (L, B, S_max, C), holds a token's latent vector and its
-    rotary key, every head's keys and values are products of it, and k
-    and v are None. Every other model leaves c None. Where such a stack
-    chooses the rows a query attends (`TransformerConfig.index_topk`) it
-    keeps a fifth kind of state beside them: `ki`, (L, B, S_max,
-    index_head_dim) float32, the one key a token a layer that its indexer
-    scores (after its norm and rotation); None anywhere else."""
-
-    k: Optional[jax.Array]
-    v: Optional[jax.Array]
-    seq_lens: jax.Array
-    kw: Optional[jax.Array] = None
-    vw: Optional[jax.Array] = None
-    c: Optional[jax.Array] = None
-    ki: Optional[jax.Array] = None
-
-    @property
-    def _rows(self) -> jax.Array:
-        return self.c if self.k is None else self.k
-
-    @property
-    def max_seq_len(self) -> int:
-        return self._rows.shape[2]
-
-    @property
-    def num_slots(self) -> int:
-        return self._rows.shape[1]
-
-
 def init_kv_cache(cfg: TransformerConfig, num_slots: int,
                   max_seq_len: Optional[int] = None) -> KVCache:
     return stack(cfg).init_cache(cfg, num_slots,
                                  max_seq_len or cfg.max_seq_len)
-
-
-# ---------------------------------------------------------------------------
-# What the stacks share (the stacks themselves: `transformer.STACKS`)
-# ---------------------------------------------------------------------------
-
-def _rope(x, sin, cos):
-    """apply_rope accepting either shared (S, half) tables or per-slot
-    (B, S, half) tables (decode: every slot is at its own position)."""
-    if sin.ndim == 2:
-        return apply_rope(x, sin, cos)
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    sin = sin[:, :, None, :].astype(x.dtype)     # (B, S, 1, half)
-    cos = cos[:, :, None, :].astype(x.dtype)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _last_rows(x, lengths):
-    """The last real position of each row of x (W, S, D) -> (W, 1, D)."""
-    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
-    return jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
-
-
-def rows_held(positions, S: int, live=None):
-    """The rows of its S a slot at `positions` (B,) holds once this
-    step's row is written: `positions + 1`, all S once a ring has gone
-    round, and none for a slot no request owns (`live` (B,) bool; None:
-    every slot is owned)."""
-    n = jnp.minimum(positions + 1, S).astype(jnp.int32)
-    return n if live is None else jnp.where(live, n, 0)
-
-
-def masked_softmax(scores, n_rows, live):
-    """Softmax of scores (B, KVH, G, S) over the first `n_rows` (B,) of
-    S; where a slot may hold none (`live` given), zeros for it."""
-    valid = (jnp.arange(scores.shape[-1])[None, :]
-             < n_rows[:, None])[:, None, None, :]
-    probs = jax.nn.softmax(jnp.where(valid, scores, -jnp.inf), axis=-1)
-    return probs if live is None else jnp.where(valid, probs, 0.0)
-
-
-def _attend_cache(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
-                  write_at, positions, live=None):
-    """One token a slot against layer `l` of a carried cache (L, B, S,
-    KVH, Dh): write this step's k and v at row `write_at` (B,), then
-    attend over the rows the slot holds (`rows_held`). Returns (out (B,
-    1, H*Dh), k_all, v_all). For a ring of S rows `write_at` is
-    `positions mod S`: every row is seen once `positions` has passed
-    S - 1. A slot that holds no row attends to nothing: zeros.
-
-    On a TPU, where the rows tile, the read is `ops/decode_attention`'s
-    kernel: the cache where it lies, only the rows held. Elsewhere the
-    products below, over every row with a mask."""
-    from ..ops import decode_attention as da
-
-    B, S = k_all.shape[1], k_all.shape[2]
-    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-
-    # Write new kv at each slot's position. A true scatter (one row per
-    # slot), overwriting: prefill leaves pad-position kv beyond
-    # `length`, so the target row may hold stale values. A slot the
-    # engine no longer owns keeps advancing and can reach S: its write
-    # falls out of bounds and is dropped, never clamped onto row S-1.
-    rows = jnp.arange(B)
-    k_all = k_all.at[l, rows, write_at].set(k[:, 0], mode="drop")
-    v_all = v_all.at[l, rows, write_at].set(v[:, 0], mode="drop")
-    n_rows = rows_held(positions, S, live)
-    G = H // KVH
-    qg = q.reshape(B, KVH, G, Dh)
-    if da.usable(k_all, Dh):
-        return da.decode_attention(qg, k_all, v_all, l, n_rows), k_all, v_all
-    k_cache = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
-    v_cache = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
-
-    # GQA decode attention over the cache with a length mask. The cache
-    # stays in its own dtype; products accumulate in float32.
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
-                        preferred_element_type=jnp.float32) / (Dh ** 0.5)
-    probs = masked_softmax(scores, n_rows, live).astype(k_cache.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
-    return out.reshape(B, 1, H * Dh), k_all, v_all
-
-
-def _attend_cache_block(cfg: TransformerConfig, q, k, v, k_all, v_all, l,
-                        p0, live):
-    """A block of Bd positions a slot against layer `l` of a carried
-    cache: q (B, Bd, H, Dh), k and v (B, Bd, KVH, Dh), the block standing
-    at rows [p0, p0 + Bd) (p0 (B,)). Writes the block's k and v there,
-    then every query of the block attends over rows [0, p0 + Bd): all Bd
-    see the same keys, so they stand beside the heads of their group,
-    (B, KVH, Bd x G, Dh), and a slot's rows are read once for the whole
-    block, by the kernel `_attend_cache` uses or, where it does not run,
-    by the same products over every row with a mask. A slot that is not
-    `live` (B,) writes nothing and gets zeros. Returns (out (B, Bd,
-    H*Dh), k_all, v_all)."""
-    from ..ops import decode_attention as da
-
-    B, S = k_all.shape[1], k_all.shape[2]
-    Bd = q.shape[1]
-    H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    G = H // KVH
-    # A slot that does not write aims past the cache's end: dropped.
-    at = jnp.where(live[:, None], p0[:, None] + jnp.arange(Bd)[None, :], S)
-    rows = jnp.arange(B)[:, None]
-    k_all = k_all.at[l, rows, at].set(k.astype(k_all.dtype), mode="drop")
-    v_all = v_all.at[l, rows, at].set(v.astype(v_all.dtype), mode="drop")
-    n_rows = rows_held(p0 + Bd - 1, S, live)
-    qg = q.reshape(B, Bd, KVH, G, Dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(B, KVH, Bd * G, Dh)
-    if da.usable(k_all, Dh):
-        out = da.decode_attention(qg, k_all, v_all, l, n_rows)
-    else:
-        k_cache = lax.dynamic_index_in_dim(k_all, l, 0, keepdims=False)
-        v_cache = lax.dynamic_index_in_dim(v_all, l, 0, keepdims=False)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
-                            preferred_element_type=jnp.float32) / (Dh ** 0.5)
-        probs = masked_softmax(scores, n_rows, live).astype(k_cache.dtype)
-        out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
-    out = out.reshape(B, KVH, Bd, G, Dh).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, Bd, H * Dh), k_all, v_all
 
 
 # ---------------------------------------------------------------------------
@@ -487,7 +313,7 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     seq_lens by 1; inactive slots are advanced too — the host engine
     simply ignores their output and reuses the slot via prefill. `live`
     (B,) bool: the slots a request owns (None: all of them); the others'
-    cache rows are not read (`_attend_cache`)."""
+    cache rows are not read (`stackparts._attend_cache`)."""
     cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens, live)
     return cache, logits
 
@@ -527,7 +353,8 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     positions a pass, `_decode_block_multi` stands in this program's
     place and a block's rows are final only at its commit pass). A step
     costs the weights and
-    the cache rows the owned slots hold (`_attend_cache`): on a v5e 7.6
+    the cache rows the owned slots hold (`stackparts._attend_cache`): on a
+    v5e 7.6
     ms at 32 slots x 1024 of internlm2-1.8b holding 43% of their rows
     and 10.5 ms at 4 x 4096 of Mistral-7B's 16 layers with one slot
     owned (11.6 and 12.3 while every row was read; PERF.md section 5,

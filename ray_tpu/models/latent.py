@@ -111,10 +111,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from .generate import KVCache, _rope, masked_softmax, rows_held
-from .moe import EXPERT_LEAVES, _exact, _split, bf16_terms, dot as _dot, \
-    routed_ffn, routing_stats  # noqa: F401 (routing_stats: the seam's)
-from .periodic import _norm, _swiglu, head_logits, last_logits  # noqa: F401
+from . import stackparts
+# `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
+from .moe import _exact, _split, bf16_terms, dot as _dot, \
+    routing_stats  # noqa: F401
+from .stackparts import (Group, KVCache, _final, _norm, _rope,  # noqa: F401
+                         _swiglu, ffn_half, head_logits, joins, last_logits,
+                         masked_softmax, rows_held)
 from .transformer import TransformerConfig, rope_tables
 
 # What the dense stack offers and this one does not (`transformer.offered`).
@@ -140,16 +143,16 @@ MISSING = {
 DENSE, ROUTED = "dense_layers", "routed_layers"
 
 
-def layer_plan(cfg: TransformerConfig) -> List[Tuple[str, int, bool]]:
-    """[(weights' key, layers, routed)]."""
-    plan = [(DENSE, cfg.n_dense_layers, False),
-            (ROUTED, cfg.n_layers - cfg.n_dense_layers, cfg.is_moe)]
-    return [p for p in plan if p[1]]
+def layer_plan(cfg: TransformerConfig) -> List[Group]:
+    """The leading layers, then the routed ones: a layer a scan step."""
+    plan = [Group(DENSE, (cfg.n_dense_layers,), False),
+            Group(ROUTED, (cfg.n_layers - cfg.n_dense_layers,), cfg.is_moe)]
+    return [group for group in plan if group.layers]
 
 
 def routed_layers(cfg: TransformerConfig) -> int:
     """Layers whose use of their experts `decode` reports."""
-    return sum(n for _, n, routed in layer_plan(cfg) if routed)
+    return stackparts.routed_layers(layer_plan(cfg))
 
 
 def cache_width(cfg: TransformerConfig) -> int:
@@ -186,67 +189,15 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
         Hi, Di = cfg.index_n_heads, cfg.index_head_dim
         shapes.update(idx_wq=(qr, Hi * Di), idx_wk=(d, Di),
                       idx_k_norm=(Di,), idx_k_bias=(Di,), idx_wp=(d, Hi))
-    if not routed:
-        f = cfg.d_ff
-        shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
-        return shapes
-    E, f = cfg.moe_experts, cfg.expert_d_ff
-    shapes.update(router=(d, cfg.router_experts), w_gate=(E, d, f),
-                  w_up=(E, d, f), w_down=(E, f, d))
-    if form.router_bias:
-        shapes["router_bias"] = (cfg.router_experts,)
-    if cfg.moe_shared_experts:
-        fs = f * cfg.moe_shared_experts
-        shapes.update(shared_gate=(d, fs), shared_up=(d, fs),
-                      shared_down=(fs, d))
-    return shapes
+    return {**shapes, **stackparts.ffn_shapes(cfg, routed, form.router_bias)}
 
 
 def num_params(cfg: TransformerConfig) -> int:
-    total = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2) \
-        + cfg.d_model
-    for _, n, routed in layer_plan(cfg):
-        total += n * sum(math.prod(s)
-                         for s in _layer_shapes(cfg, routed).values())
-    return total
+    return stackparts.num_params(cfg, layer_plan(cfg), _layer_shapes)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Scaled-normal weights as `periodic.init_params` makes them: norm
-    gains one, residual-branch outputs scaled down by depth, each leaf
-    drawn, scaled and cast in one expression."""
-    pd = cfg.param_dtype
-    k_emb, k_head, k_layers = jax.random.split(key, 3)
-
-    def normal(key, shape, scale):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * scale).astype(pd)
-
-    d = cfg.d_model
-    params = {"embed": normal(k_emb, (cfg.vocab_size, d), 0.02),
-              "final_norm": jnp.ones((d,), dtype=pd)}
-    if not cfg.tie_embeddings:
-        params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
-    plan = layer_plan(cfg)
-    for (name, n, routed), k_group in zip(
-            plan, jax.random.split(k_layers, len(plan))):
-        shapes = _layer_shapes(cfg, routed)
-        leaves = {}
-        for (leaf, shape), k in zip(
-                sorted(shapes.items()),
-                jax.random.split(k_group, len(shapes))):
-            full = (n,) + shape
-            if leaf.endswith("norm"):
-                leaves[leaf] = jnp.ones(full, dtype=pd)
-            elif leaf == "router_bias":
-                leaves[leaf] = jnp.zeros(full, dtype=pd)
-            elif leaf in ("wo", "w_down", "shared_down"):
-                leaves[leaf] = normal(
-                    k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
-            else:
-                leaves[leaf] = normal(k, full, 0.02)
-        params[name] = leaves
-    return params
+    return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes)
 
 
 def index_dtype(cfg: TransformerConfig):
@@ -631,21 +582,11 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
     q_nope, q_r, row, idx, state) -> (out (B, S, H*vd), state)` does the
     attention and whatever it keeps of the row; `idx` is what the
     layer's indexer projects (`_index_project`), None without one.
-    `experts_at`: None for a dense FFN, else (the stack's expert
-    matrices, this layer's first group in them). `rows` (B*S,) bool: the
-    rows somebody owns, the only ones the routed experts take
-    (`moe.routed_ffn`; None: every row). A branch joins the residual
-    stream through a norm of its own where the architecture has one
-    (`LatentForm.post_norms`). Returns (x, state, routing stats or None,
-    experts chosen (B*S, K) or None)."""
-    B, S, _ = x.shape
-    dt, eps = cfg.dtype, cfg.norm_eps
+    `experts_at`, `rows`: as `ffn_half` takes them. A branch joins the
+    residual stream through a norm of its own where the architecture has
+    one (`LatentForm.post_norms`). Returns (x, state, routing stats and
+    experts chosen (B*S, K), both None for a dense FFN)."""
     post_norms = cfg.latent_form.post_norms
-
-    def joins(branch, norm):
-        if not post_norms:
-            return x + branch.astype(x.dtype)
-        return x + _norm(branch, lp[norm], eps).astype(x.dtype)
 
     with jax.named_scope("mla_proj"):
         q_nope, q_r, row, h = _project(cfg, lp, x, rope)
@@ -655,59 +596,25 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
             idx = _index_project(cfg, lp, h, rope)
     out, state = attend(lp, q_nope, q_r, row, idx, state)
     with jax.named_scope("mla_proj"):
-        x = joins(_dot(out, lp["wo"]), "post_attn_norm")
-
-    m = _norm(x, lp["ffn_norm"], eps)                      # float32
-    stats = experts = None
-    if experts_at is not None:
-        flat = m.reshape(B * S, -1)
-        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at,
-                                       rows=rows)
-        if cfg.moe_shared_experts:
-            with jax.named_scope("moe_shared"):
-                f = f + _swiglu(flat.astype(dt), lp["shared_gate"],
-                                lp["shared_up"], lp["shared_down"])
-        f = f.reshape(B, S, -1)
-    else:
-        f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
-    return joins(f, "post_ffn_norm"), state, stats, experts
+        x = joins(x, _dot(out, lp["wo"]),
+                  lp["post_attn_norm"] if post_norms else None, cfg.norm_eps)
+    x, stats, experts = ffn_half(cfg, lp, x, experts_at, post_norms, rows)
+    return x, state, stats, experts
 
 
 def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
-    """x through every layer: one `lax.scan` a group of the plan, `state`
-    (the caches, or nothing) riding in the carry beside x. `attend(l, lp,
-    q_nope, q_r, row, idx, state)` is told which layer it serves; `rows`
-    (see `layer`) are the rows somebody owns. Returns (x, state, routing
-    stats summed over layers, experts chosen: one array (layers, B*S, K)
-    a routed group)."""
-    stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
-    chosen, base = [], 0
-    for name, n, routed in layer_plan(cfg):
-        stacked = params[name]
-        # The expert matrices stay whole, every layer's groups in one
-        # array, and are not scanned over: models/moe.grouped_experts.
-        expert_w = {k: stacked[k].reshape((-1,) + stacked[k].shape[-2:])
-                    for k in EXPERT_LEAVES} if routed else None
-        if routed:
-            stacked = {k: v for k, v in stacked.items()
-                       if k not in EXPERT_LEAVES}
+    """`stackparts.run` over the plan. `attend(l, lp, q_nope, q_r, row,
+    idx, state)` is told which layer it serves."""
+    plan = layer_plan(cfg)
+    base = [sum(group.layers for group in plan[:i])
+            for i in range(len(plan))]
 
-        def body(carry, scanned, expert_w=expert_w, base=base):
-            x, state, stats = carry
-            lp, g = scanned
-            x, state, st, ex = layer(
-                cfg, lp, x, expert_w and (expert_w, g * cfg.moe_experts),
-                rope, partial(attend, base + g), state, rows)
-            if st is not None:
-                stats = stats + st
-            return (x, state, stats), ex
+    def layer_at(i, g, j):
+        return lambda lp, x, experts_at, state: layer(
+            cfg, lp, x, experts_at, rope, partial(attend, base[i] + g),
+            state, rows)
 
-        (x, state, stats), experts = lax.scan(
-            body, (x, state, stats), (stacked, jnp.arange(n)))
-        if routed:
-            chosen.append(experts)
-        base += n
-    return x, state, stats, tuple(chosen)
+    return stackparts.run(cfg, params, plan, x, layer_at, state)
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
@@ -717,10 +624,6 @@ def _embed(cfg: TransformerConfig, params, tokens):
     dtype either way)."""
     return params["embed"][tokens].astype(
         index_dtype(cfg) if cfg.index_topk else cfg.dtype)
-
-
-def _final(cfg: TransformerConfig, params, x):
-    return _norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -829,9 +732,10 @@ def _scratch(cfg: TransformerConfig, W: int, S: int):
 
 def forward_free(cfg: TransformerConfig, params, tokens, whole: bool = False):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), the experts every routed layer chose: see `_run`; none where the
-    tile is walked in chunks, unless `whole`). A stack that chooses its
-    rows walks the tile against a cache of its own, one slot a row."""
+    D), the experts every routed layer chose: see `stackparts.run`; none
+    where the tile is walked in chunks, unless `whole`). A stack that
+    chooses its rows walks the tile against a cache of its own, one slot
+    a row."""
     if cfg.index_topk:
         W, S = tokens.shape
         x, _, _, chosen = _walk(cfg, params, _scratch(cfg, W, S) + (None,),
@@ -874,7 +778,7 @@ def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
     (S, K), numbered as the router numbers them."""
     _, chosen = jax.jit(partial(forward_free, cfg, whole=True))(
         params, jnp.asarray(tokens, jnp.int32)[None])
-    return [layers[g] for layers in chosen for g in range(layers.shape[0])]
+    return stackparts.chosen_by_layer(chosen)
 
 
 def chosen_rows(cfg: TransformerConfig, params, tokens) -> jax.Array:

@@ -17,10 +17,9 @@ layers only; Mellum 2: both, the global ones by YaRN).
 
 Weights: `dense_layers` (leaves stacked over the leading layers) and
 `periods` (leaves stacked over periods, then over a period's layers).
-One layer definition (`layer`) and one walk over the stack (`_run`: a
-`lax.scan` over each group with a period's layers unrolled inside)
-serve prefill, the cache-free first token and decode; they differ in
-the `attend` they hand in, which owns the cache.
+One layer definition (`layer`) and one walk (`stackparts.run`) serve
+prefill, the cache-free first token and decode; they differ in the
+`attend` they hand in, which owns the cache.
 
 The cache holds two kinds of state in one `KVCache`: `k`/`v` for the
 global layers, (Lg, slots, S_max, KVH, Dh), and `kw`/`vw` for the window
@@ -60,10 +59,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .generate import (KVCache, _attend_cache, _attend_cache_block,
-                       _last_rows, _rope, masked_softmax, rows_held)
-from .moe import (EXPERT_LEAVES, bf16_terms, dot as _dot, routed_ffn,
-                  routing_stats)
+from . import stackparts
+# `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
+from .moe import bf16_terms, dot as _dot, routing_stats  # noqa: F401
+from .stackparts import (Group, KVCache, _attend_cache,  # noqa: F401
+                         _attend_cache_block, _final, _norm, _rope, ffn_half,
+                         head_logits, joins, last_logits, masked_softmax,
+                         rows_held)
 from .transformer import TransformerConfig, rope_tables
 
 WINDOW, GLOBAL = "window", "global"
@@ -103,31 +105,40 @@ NOT_ITS_WALK = {
 }
 
 
-def layer_plan(cfg: TransformerConfig
-               ) -> List[Tuple[str, int, Tuple[str, ...], bool]]:
-    """[(weights' key, groups, kinds of a group's layers, routed)]."""
-    win = WINDOW if cfg.sliding_window else GLOBAL
+DENSE, PERIODS = "dense_layers", "periods"
+
+
+def layer_plan(cfg: TransformerConfig) -> List[Group]:
+    """The leading layers, a layer a scan step, then whole periods, a
+    period a step."""
     every = cfg.global_attn_every
     plan = []
     if cfg.n_dense_layers:
-        plan.append(("dense_layers", cfg.n_dense_layers, (win,), False))
+        plan.append(Group(DENSE, (cfg.n_dense_layers,), False))
     periods = (cfg.n_layers - cfg.n_dense_layers) // every
     if periods:
-        plan.append(("periods", periods,
-                     (win,) * (every - 1) + (GLOBAL,), cfg.is_moe))
+        plan.append(Group(PERIODS, (periods, every), cfg.is_moe))
     return plan
+
+
+def step_kinds(cfg: TransformerConfig) -> List[Tuple[str, ...]]:
+    """The kinds of a scan step's layers, a group of `layer_plan`."""
+    win = WINDOW if cfg.sliding_window else GLOBAL
+    period = (win,) * (cfg.global_attn_every - 1) + (GLOBAL,)
+    return [(win,) if group.key == DENSE else period
+            for group in layer_plan(cfg)]
 
 
 def routed_layers(cfg: TransformerConfig) -> int:
     """Layers whose use of their experts `decode` reports."""
-    return sum(n * len(kinds) for _, n, kinds, routed in layer_plan(cfg)
-               if routed)
+    return stackparts.routed_layers(layer_plan(cfg))
 
 
 def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
     """How many layers keep each kind of state."""
-    return {kind: sum(n * kinds.count(kind)
-                      for _, n, kinds, _ in layer_plan(cfg))
+    return {kind: sum(group.lead[0] * kinds.count(kind)
+                      for group, kinds in zip(layer_plan(cfg),
+                                              step_kinds(cfg)))
             for kind in KINDS}
 
 
@@ -148,74 +159,15 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
         shapes["wg"] = (d, q)
     if form.post_norms:
         shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
-    if not routed:
-        f = cfg.d_ff
-        shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
-        return shapes
-    E, f = cfg.moe_experts, cfg.expert_d_ff
-    shapes.update(router=(d, E), w_gate=(E, d, f), w_up=(E, d, f),
-                  w_down=(E, f, d))
-    if form.router_bias:
-        shapes["router_bias"] = (E,)
-    if cfg.moe_shared_experts:
-        fs = f * cfg.moe_shared_experts
-        shapes.update(shared_gate=(d, fs), shared_up=(d, fs),
-                      shared_down=(fs, d))
-    return shapes
-
-
-def _group_shape(n: int, kinds: Tuple[str, ...], key: str
-                 ) -> Tuple[int, ...]:
-    return (n,) if key == "dense_layers" else (n, len(kinds))
+    return {**shapes, **stackparts.ffn_shapes(cfg, routed, form.router_bias)}
 
 
 def num_params(cfg: TransformerConfig) -> int:
-    total = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2) \
-        + cfg.d_model
-    for key, n, kinds, routed in layer_plan(cfg):
-        total += math.prod(_group_shape(n, kinds, key)) * sum(
-            math.prod(s) for s in _layer_shapes(cfg, routed).values())
-    return total
+    return stackparts.num_params(cfg, layer_plan(cfg), _layer_shapes)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Scaled-normal weights as `transformer.init_params` makes them:
-    norm gains one, the selection bias zero, residual-branch outputs
-    scaled down by depth. Each leaf is drawn, scaled and cast in one
-    expression, so under jit no float32 copy of a stacked leaf is kept."""
-    pd = cfg.param_dtype
-    k_emb, k_head, k_layers = jax.random.split(key, 3)
-
-    def normal(key, shape, scale):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * scale).astype(pd)
-
-    d = cfg.d_model
-    params = {"embed": normal(k_emb, (cfg.vocab_size, d), 0.02),
-              "final_norm": jnp.ones((d,), dtype=pd)}
-    if not cfg.tie_embeddings:
-        params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
-    plan = layer_plan(cfg)
-    for (name, n, kinds, routed), k_group in zip(
-            plan, jax.random.split(k_layers, len(plan))):
-        shapes = _layer_shapes(cfg, routed)
-        lead = _group_shape(n, kinds, name)
-        leaves = {}
-        for (leaf, shape), k in zip(
-                sorted(shapes.items()),
-                jax.random.split(k_group, len(shapes))):
-            full = lead + shape
-            if leaf.endswith("norm"):
-                leaves[leaf] = jnp.ones(full, dtype=pd)
-            elif leaf == "router_bias":
-                leaves[leaf] = jnp.zeros(full, dtype=pd)
-            elif leaf in ("wo", "w_down", "shared_down"):
-                leaves[leaf] = normal(
-                    k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
-            else:
-                leaves[leaf] = normal(k, full, 0.02)
-        params[name] = leaves
-    return params
+    return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes)
 
 
 def cache_terms(cfg: TransformerConfig) -> int:
@@ -249,38 +201,18 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
 # The layer, and the walk over the stack
 # ---------------------------------------------------------------------------
 
-def _norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMS norm, float32 out whatever comes in."""
-    x = x.astype(jnp.float32)
-    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
-        * scale.astype(jnp.float32)
-
-
-def _swiglu(m: jax.Array, gate, up, down) -> jax.Array:
-    h = jax.nn.silu(_dot(m, gate)) * _dot(m, up)
-    return _dot(h.astype(m.dtype), down)
-
-
 def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
           attend, state, rows=None):
     """One layer on x (B, S, D) in the activation dtype. `rope`: {kind:
     (sin, cos)} for the kinds that rotate (`rope_by_kind`). `attend(kind,
     q, k, v, state) -> (out (B, S, H, Dh), state)` does the attention and
-    whatever it keeps of k and v. `experts_at`: None for a dense FFN, else
-    (the stack's expert matrices, this layer's first group in them).
-    `rows` (B*S,) bool: the rows somebody owns, the only ones a routed
-    layer's experts take (`moe.routed_ffn`; None: every row). Returns (x,
-    state, routing stats, experts chosen (B*S, K) or None)."""
+    whatever it keeps of k and v. `experts_at`, `rows`: as `ffn_half`
+    takes them. Returns (x, state, routing stats, experts chosen (B*S,
+    K) or None)."""
     B, S, _ = x.shape
     H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt, eps = cfg.dtype, cfg.norm_eps
     form = cfg.period_form
-
-    def joins(branch, norm):
-        # A branch's output on its way into the residual stream.
-        if form.post_norms:
-            branch = _norm(branch, lp[norm], eps)
-        return x + branch.astype(x.dtype)
 
     # Every product hands back float32; what lies between two products
     # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
@@ -299,23 +231,16 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     out = out.reshape(B, S, H * Dh)
     if gate is not None:
         out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(dt)
-    x = joins(_dot(out, lp["wo"]), "post_attn_norm")
-
-    m = _norm(x, lp["ffn_norm"], eps)                      # float32
-    experts = None
-    stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
-    if experts_at is not None:
-        flat = m.reshape(B * S, -1)
-        f, stats, experts = routed_ffn(cfg, lp, flat, dt, *experts_at,
-                                       rows=rows)
-        if cfg.moe_shared_experts:
-            with jax.named_scope("moe_shared"):
-                f = f + _swiglu(flat.astype(dt), lp["shared_gate"],
-                                lp["shared_up"], lp["shared_down"])
-        f = f.reshape(B, S, -1)
-    else:
-        f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
-    return joins(f, "post_ffn_norm"), state, stats, experts
+    x = joins(x, _dot(out, lp["wo"]),
+              lp["post_attn_norm"] if form.post_norms else None, eps)
+    # A dense layer's stats are zeros, which the walk adds to its sum as
+    # it adds a routed layer's: without that add the tile and the decode
+    # block of a configuration with leading dense layers compile to
+    # another module (ROADMAP D19).
+    zeros = jnp.zeros((routing_stats(cfg),), jnp.int32)
+    x, stats, experts = ffn_half(cfg, lp, x, experts_at, form.post_norms,
+                                 rows)
+    return x, state, zeros if stats is None else stats, experts
 
 
 def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
@@ -337,54 +262,31 @@ def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
 
 
 def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
-    """x through every layer: one `lax.scan` a group of the plan, a
-    group's layers unrolled in its body, `state` (the cache, or nothing)
-    riding in the carry beside x. `attend(l, kind, q, k, v, state)` is
-    told which layer of its kind it serves; `rows`: see `layer`. Returns
-    (x, state, routing stats summed over layers, experts chosen: a tuple
-    a group of arrays (groups, B*S, K), one a routed layer of the
-    group)."""
-    at = dict.fromkeys(KINDS, 0)       # the group's first layer, by kind
-    stats = jnp.zeros((routing_stats(cfg),), jnp.int32)
-    chosen = []
-    for name, n, kinds, routed in layer_plan(cfg):
-        stacked = params[name]
-        if name == "dense_layers":
-            stacked = jax.tree.map(lambda a: a[:, None], stacked)
-        per = {kind: kinds.count(kind) for kind in KINDS}
-        # The expert matrices stay whole, every layer's groups in one
-        # array, and are not scanned over: models/moe.grouped_experts.
-        expert_w = {k: stacked[k].reshape((-1,) + stacked[k].shape[-2:])
-                    for k in EXPERT_LEAVES} if routed else None
-        if routed:
-            stacked = {k: v for k, v in stacked.items()
-                       if k not in EXPERT_LEAVES}
+    """`stackparts.run` over the plan. `attend(l, kind, q, k, v, state)`
+    is told which layer of its kind it serves, counted over the whole
+    stack: the cache layer it keeps."""
+    plan, kinds = layer_plan(cfg), step_kinds(cfg)
+    if plan[0].key == DENSE:
+        # The leading layers walk as periods of one layer, their leaves
+        # given that axis here: scanned as they lie, a decode block
+        # compiles to another module (ROADMAP D19).
+        params = {**params, DENSE: jax.tree.map(lambda a: a[:, None],
+                                                params[DENSE])}
+        plan = [plan[0]._replace(lead=plan[0].lead + (1,))] + plan[1:]
+    per = [{kind: ks.count(kind) for kind in KINDS} for ks in kinds]
+    # Each group's first layer of a kind: those the groups before it hold.
+    base = [{kind: sum(g.lead[0] * p[kind]
+                       for g, p in zip(plan[:i], per[:i])) for kind in KINDS}
+            for i in range(len(plan))]
 
-        def body(carry, scanned, kinds=kinds, expert_w=expert_w, per=per,
-                 base=dict(at)):
-            x, state, stats = carry
-            weights, g = scanned
-            seen = dict.fromkeys(KINDS, 0)
-            experts = []
-            for j, kind in enumerate(kinds):
-                lp = jax.tree.map(lambda a: a[j], weights)
-                l = base[kind] + g * per[kind] + seen[kind]
-                seen[kind] += 1
-                first = (g * len(kinds) + j) * cfg.moe_experts
-                x, state, st, ex = layer(
-                    cfg, lp, x, kind, expert_w and (expert_w, first), rope,
-                    partial(attend, l), state, rows)
-                stats = stats + st
-                if ex is not None:
-                    experts.append(ex)
-            return (x, state, stats), tuple(experts)
+    def layer_at(i, g, j):
+        kind = kinds[i][j]
+        l = base[i][kind] + g * per[i][kind] + kinds[i][:j].count(kind)
+        return lambda lp, x, experts_at, state: layer(
+            cfg, lp, x, kind, experts_at, rope, partial(attend, l), state,
+            rows)
 
-        (x, state, stats), experts = lax.scan(
-            body, (x, state, stats), (stacked, jnp.arange(n)))
-        chosen.append(experts)
-        for kind in KINDS:
-            at[kind] += n * per[kind]
-    return x, state, stats, tuple(chosen)
+    return stackparts.run(cfg, params, plan, x, layer_at, state)
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
@@ -392,23 +294,6 @@ def _embed(cfg: TransformerConfig, params, tokens):
     if cfg.period_form.embed_scale:
         x = x * math.sqrt(cfg.d_model)
     return x.astype(cfg.dtype)
-
-
-def _final(cfg: TransformerConfig, params, x):
-    return _norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
-
-
-def head_logits(cfg: TransformerConfig, params, x) -> jax.Array:
-    """Final-normed x (..., D) -> float32 logits (..., V). The product
-    takes the head as it lies and x in its dtype: float32 activations
-    never make a float32 copy of the head (1.6 GB at 200,192 rows)."""
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def last_logits(cfg: TransformerConfig, params, x, lengths) -> jax.Array:
-    """Logits (W, V) at the last real position of final-normed x (W, S, D)."""
-    return head_logits(cfg, params, _last_rows(x, lengths)[:, 0])
 
 
 _QUERY_BLOCK = 256
@@ -512,7 +397,7 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
 
 def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions,
                   live=None):
-    """`generate._attend_cache` over a cache of two bf16 terms (`k_all`
+    """`stackparts._attend_cache` over a cache of two bf16 terms (`k_all`
     (2L, B, S, KVH, Dh): see `cache_terms`), q, k, v float32: every
     product takes bf16 operands, the float32 side (q, then the
     probabilities) as two terms stacked beside the heads of a group, the
@@ -595,7 +480,7 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
 
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), the experts every routed layer chose: see `_run`)."""
+    D), the experts every routed layer chose: see `stackparts.run`)."""
     rope = rope_by_kind(cfg, tokens.shape[1])
     x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
                            partial(_free_attend, cfg), None)
@@ -664,10 +549,4 @@ def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
     (S, K)."""
     _, chosen = jax.jit(partial(forward_free, cfg))(
         params, jnp.asarray(tokens, jnp.int32)[None])
-    out = []
-    for group in chosen:
-        if group:
-            n = group[0].shape[0]
-            out.extend(group[j][g] for g in range(n)
-                       for j in range(len(group)))
-    return out
+    return stackparts.chosen_by_layer(chosen)

@@ -38,7 +38,7 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 # architecture's stack. The one place an architecture is named: to add
 # one, write its module and add its line. A stack module offers
 #   weights  init_params(cfg, key), num_params(cfg)
-#   cache    init_cache(cfg, num_slots, max_seq_len) -> generate.KVCache
+#   cache    init_cache(cfg, num_slots, max_seq_len) -> stackparts.KVCache
 #   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
 #              -> (cache', final-normed hidden states (W, S, D),
 #                  routing stats or None)

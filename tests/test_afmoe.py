@@ -64,9 +64,10 @@ def _rel(got, want):
 
 def test_the_preset_is_the_published_shape_in_small():
     assert CFG.head_dim == 32 != CFG.d_model // CFG.n_heads
-    assert periodic.layer_plan(CFG) == [
-        ("dense_layers", 1, ("window",), False),
-        ("periods", 1, ("window", "window", "window", "global"), True)]
+    assert periodic.layer_plan(CFG) == [("dense_layers", (1,), False),
+                                        ("periods", (1, 4), True)]
+    assert periodic.step_kinds(CFG) == [
+        ("window",), ("window", "window", "window", "global")]
     assert periodic.cache_layers(CFG) == {"window": 4, "global": 1}
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     assert cache.k.shape == (1, 3, 64, 2, 32)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import configs, generate
+from ray_tpu.models import configs, generate, stackparts
 from ray_tpu.models.transformer import (REMASK_RULES, TransformerConfig,
                                         init_params, stack)
 from ray_tpu.ops import decode_attention as da
@@ -402,7 +402,7 @@ def test_decode_attention_takes_a_blocks_queries_beside_the_heads(Bd):
     got = da.decode_attention(q, k_all, v_all, jnp.int32(1), n_rows,
                               interpret=True, rows=128)
     s = jnp.einsum("bkgd,bskd->bkgs", q, k_all[1]) / np.sqrt(Dh)
-    p = generate.masked_softmax(s, n_rows, n_rows > 0)
+    p = stackparts.masked_softmax(s, n_rows, n_rows > 0)
     want = jnp.einsum("bkgs,bskd->bkgd", p, v_all[1])
     np.testing.assert_allclose(got.reshape(want.shape), want, rtol=2e-5,
                                atol=2e-5)

@@ -1,5 +1,5 @@
 """`ops/decode_attention`: the kernel in the Pallas interpreter against the
-XLA code it stands in for (`generate._attend_cache`, `periodic.
+XLA code it stands in for (`stackparts._attend_cache`, `periodic.
 _attend_terms`), the rows it must not read, and `live` through the decode
 programs and the engine. On the CPU the models take the XLA code; the
 tests that drive the kernel through a program steer it there themselves.
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import configs, generate, periodic
+from ray_tpu.models import configs, generate, periodic, stackparts
 from ray_tpu.models.moe import bf16_terms
 from ray_tpu.models.transformer import TransformerConfig, init_params
 from ray_tpu.ops import decode_attention as da
@@ -45,7 +45,7 @@ def _case(heads: str, seed: int = 0):
         v_all = jnp.concatenate(list(bf16_terms(v_all)))
     else:
         k_all, v_all = k_all.astype(dt), v_all.astype(dt)
-    xla = periodic._attend_terms if terms == 2 else generate._attend_cache
+    xla = periodic._attend_terms if terms == 2 else stackparts._attend_cache
     return cfg, xla, q, k, v, k_all, v_all
 
 
@@ -60,7 +60,7 @@ def test_kernel_is_the_xla_code(heads, kind, rows):
     for l in range(LAYERS):
         want, k_new, v_new = xla(cfg, q, k, v, k_all, v_all, jnp.int32(l),
                                  pos % S, pos, live)
-        n_rows = generate.rows_held(pos, S, live)
+        n_rows = stackparts.rows_held(pos, S, live)
         got = da.decode_attention(
             q.reshape(-1, KVH, G, DH), k_new, v_new, jnp.int32(l), n_rows,
             interpret=True, rows=rows)

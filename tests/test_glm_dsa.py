@@ -83,8 +83,8 @@ def test_the_preset_is_the_published_shape_in_small(params):
     assert LATENT_FORMS["glm_moe_dsa"].post_norms is False
     assert LATENT_FORMS["glm_moe_dsa"].router_bias is True
     assert LATENT_FORMS["pangu_ultra_moe"].post_norms is True
-    assert latent.layer_plan(CFG) == [("dense_layers", 1, False),
-                                      ("routed_layers", 2, True)]
+    assert latent.layer_plan(CFG) == [("dense_layers", (1,), False),
+                                      ("routed_layers", (2,), True)]
     assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     # Two arrays of rows: the latent vector with its rotary key in whole

@@ -422,11 +422,18 @@ def _bench():
     return mod
 
 
+# slow: a 30-second timing gate. It passes alone (29.75 s on PR 44's tree)
+# and has failed (`SystemExit: 1`) in every run of the tier the ledger
+# holds (PR 40-44), where six workers share the machine and its leak-age
+# thresholds do not hold: the tier's exit code was 1 whatever else
+# happened. It is `test_soak_full`'s gate at a smaller size and runs
+# with it.
+@pytest.mark.slow
 def test_soak_quick_smoke():
     """The `bench.py --soak --quick` gate, trimmed to a short load
-    phase for the fast tier: chaos + quiescence must reconcile green
-    with zero live suspects, and the injected dropped release must be
-    flagged and attributed."""
+    phase: chaos + quiescence must reconcile green with zero live
+    suspects, and the injected dropped release must be flagged and
+    attributed."""
     keys = ("ledger_interval_s", "ledger_leak_min_age_s",
             "ledger_leak_k")
     old = {k: getattr(config, k) for k in keys}

@@ -72,8 +72,9 @@ def _rel(got, want):
 def test_the_preset_is_the_published_shape_in_small(params):
     assert STACKS["mellum"] == STACKS["afmoe"] == "periodic"
     assert CFG.period_form == PERIOD_FORMS["mellum"]
-    assert periodic.layer_plan(CFG) == [
-        ("periods", 2, ("window", "window", "window", "global"), True)]
+    assert periodic.layer_plan(CFG) == [("periods", (2, 4), True)]
+    assert periodic.step_kinds(CFG) == [
+        ("window", "window", "window", "global")]
     assert periodic.cache_layers(CFG) == {"window": 6, "global": 2}
     assert routed_layers(CFG) == 8
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))

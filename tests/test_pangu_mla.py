@@ -61,8 +61,8 @@ def _rel(got, want):
 
 def test_the_preset_is_the_published_shape_in_small(params):
     assert STACKS["pangu_ultra_moe"] == "latent"
-    assert latent.layer_plan(CFG) == [("dense_layers", 1, False),
-                                      ("routed_layers", 2, True)]
+    assert latent.layer_plan(CFG) == [("dense_layers", (1,), False),
+                                      ("routed_layers", (2,), True)]
     assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     # One vector a token a layer, latent + rotary key in whole lanes, and

@@ -4,14 +4,16 @@ programs call, with the documented shapes, and nothing above the seam
 asks which architecture it serves."""
 
 import ast
+import hashlib
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.models import configs, generate
+from ray_tpu.models import configs, generate, stackparts
 from ray_tpu.models.transformer import STACKS, init_params, offered, stack
 
 # The tiny preset of each architecture of the table.
@@ -123,6 +125,17 @@ def _tree(path):
         return ast.parse(f.read())
 
 
+def _imported(node):
+    """The names an import statement mentions, dotted ones in parts."""
+    if isinstance(node, ast.Import):
+        names = [a.name for a in node.names]
+    elif isinstance(node, ast.ImportFrom):
+        names = [node.module or ""] + [a.name for a in node.names]
+    else:
+        return set()
+    return {part for name in names for part in name.split(".")}
+
+
 def _touches_arch(node):
     return any(isinstance(n, ast.Attribute) and n.attr == "arch"
                for n in ast.walk(node))
@@ -134,15 +147,23 @@ def test_nothing_above_the_seam_asks_which_architecture_it_serves(path):
     for node in ast.walk(_tree(path)):
         assert not (isinstance(node, ast.Compare) and _touches_arch(node)), \
             f"{path}:{node.lineno} compares cfg.arch"
-        if isinstance(node, ast.Import):
-            imported = [a.name for a in node.names]
-        elif isinstance(node, ast.ImportFrom):
-            imported = [node.module or ""] + [a.name for a in node.names]
-        else:
-            continue
-        assert not names & {part for name in imported
-                            for part in name.split(".")}, \
+        assert not names & _imported(node), \
             f"{path}:{node.lineno} imports a stack module by name"
+
+
+@pytest.mark.parametrize("module", sorted(set(STACKS.values()))
+                         + ["moe", "stackparts"])
+def test_imports_under_the_seam_point_down(module):
+    """The programs reach a stack through `transformer.stack`; a stack
+    reaches neither the programs nor another stack, and what the stacks
+    share (`stackparts.py`, `moe.py`) imports no stack at all."""
+    others = set(STACKS.values()) - {module}
+    for node in ast.walk(_tree(f"models/{module}.py")):
+        names = _imported(node)
+        assert "generate" not in names, \
+            f"models/{module}.py:{node.lineno} imports the programs"
+        assert not names & others, \
+            f"models/{module}.py:{node.lineno} imports a stack"
 
 
 def test_arch_is_compared_and_looked_up_in_transformer_py_alone():
@@ -157,3 +178,105 @@ def test_arch_is_compared_and_looked_up_in_transformer_py_alone():
                         and _touches_arch(node):
                     found.add(path)
     assert found == {os.path.join("models", "transformer.py")}
+
+
+# -- the weights a seed makes -------------------------------------------------
+
+# sha256[:16] over every leaf's path, shape, dtype and bytes of
+# `init_params(preset(), jax.random.key(0))` on commit 13cf627, before the
+# routed stacks' builders became `stackparts.init_params` (this machine,
+# jax 0.9.0, the CPU). The dense stack's builder is its own and splits
+# its key in another order: its two rows are here so that whoever folds
+# it in sees what that changes.
+SEEDED = {
+    "tiny_test": "ff43afc140b2a4be",
+    "tiny_moe_test": "82d30b27bc52ff21",
+    "tiny_afmoe_test": "e233d94d4af14af3",
+    "tiny_mellum_test": "1179e5f4795df8ba",
+    "tiny_sdar_test": "52fb45e14a6e01c2",
+    "tiny_pangu_test": "a01e545ed20b21c1",
+    "tiny_glm_test": "05192f44f3a1ebda",
+}
+
+
+@pytest.mark.parametrize("preset", sorted(SEEDED))
+def test_a_seed_makes_the_weights_it_made(preset):
+    params = init_params(getattr(configs, preset)(), jax.random.key(0))
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(path)} {a.shape} {a.dtype}\n"
+                 .encode())
+        h.update(a.tobytes())
+    assert h.hexdigest()[:16] == SEEDED[preset]
+
+
+# -- the walk over a plan -----------------------------------------------------
+
+def test_the_walk_tells_each_layer_its_place_once_and_in_order():
+    """Two leading layers, a layer a step, then three steps of two routed
+    layers: `layer_at` is asked for each place of a group once (a scan's
+    body is traced once), the layers run in stack order, a routed layer
+    is handed the stack's expert matrices whole with its own first
+    expert, a step's leaves without them, and the stats add up."""
+    cfg = configs.tiny_afmoe_test()
+    E = cfg.moe_experts
+    plan = [stackparts.Group("lead", (2,), False),
+            stackparts.Group("pairs", (3, 2), True)]
+    params = {"lead": {"w": jnp.ones((2, 5))},
+              "pairs": {"w": jnp.ones((3, 2, 5)),
+                        **{leaf: jnp.zeros((3, 2, E, 1, 1))
+                           for leaf in stackparts.EXPERT_LEAVES}}}
+    asked = []
+
+    def layer_at(i, g, j):
+        asked.append((i, j))
+
+        def layer(lp, x, experts_at, state):
+            assert set(lp) == {"w"} and lp["w"].shape == (5,)
+            log, n = state
+            state = log.at[n].set(jnp.stack([i, g, j])), n + 1
+            if experts_at is None:
+                assert not plan[i].routed
+                return x + 1, state, None, None
+            w, first = experts_at
+            assert plan[i].routed and set(w) == set(stackparts.EXPERT_LEAVES)
+            assert all(a.shape == (3 * 2 * E, 1, 1) for a in w.values())
+            return x + 1, state, jnp.ones((4,), jnp.int32), first[None, None]
+
+        return layer
+
+    x, (log, n), stats, chosen = jax.jit(
+        lambda p: stackparts.run(
+            cfg, p, plan, jnp.zeros(()), layer_at,
+            (jnp.zeros((8, 3), jnp.int32), jnp.int32(0))))(params)
+    assert asked == [(0, 0), (1, 0), (1, 1)]
+    assert int(x) == int(n) == 8
+    assert log.tolist() == [[0, 0, 0], [0, 1, 0]] + [
+        [1, g, j] for g in range(3) for j in range(2)]
+    assert stats.tolist() == [6] * 4
+    assert [len(group) for group in chosen] == [0, 2]
+    assert all(a.shape == (3, 1, 1) for a in chosen[1])
+    assert [int(a[0, 0]) for a in stackparts.chosen_by_layer(chosen)] == [
+        l * E for l in range(6)]
+    assert stackparts.routed_layers(plan) == 6
+
+
+@pytest.mark.parametrize("preset", ["tiny_afmoe_test", "tiny_pangu_test"])
+def test_chosen_experts_come_back_a_routed_layer_in_layer_order(preset):
+    """The interface's shapes: for tokens (S,), one (S, K) array a routed
+    layer, numbered as the router numbers its experts. The leading dense
+    layer adds none; a layer's choice depends on the layers before it,
+    so no two layers' arrays are alike."""
+    cfg = getattr(configs, preset)()
+    st = stack(cfg)
+    params = init_params(cfg, jax.random.key(0))
+    tokens = np.arange(3, 15) % cfg.vocab_size
+    chosen = st.chosen_experts(cfg, params, tokens)
+    assert len(chosen) == st.routed_layers(cfg) \
+        == cfg.n_layers - cfg.n_dense_layers
+    for a in chosen:
+        assert a.shape == (len(tokens), cfg.moe_top_k)
+        assert jnp.issubdtype(a.dtype, jnp.integer)
+        assert 0 <= int(a.min()) and int(a.max()) < cfg.router_experts
+    assert len({np.asarray(a).tobytes() for a in chosen}) == len(chosen)
