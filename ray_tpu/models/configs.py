@@ -112,6 +112,25 @@ def tiny_glm_test(vocab: int = 256, router_experts: int = 16,
         index_head_dim=16, index_topk=index_topk)
 
 
+def tiny_solar_test(vocab: int = 256, router_experts: int = 16,
+                    held: int = 4, first: int = 4) -> TransformerConfig:
+    """The period stack with linear-attention layers at a unit-test
+    size: two periods of a global layer with no position and an output
+    gate, then three gated delta-rule layers (2 heads of 16, a
+    convolution over 4 positions); every layer routed, `held` of
+    `router_experts` experts held, sigmoid scores with a selection bias,
+    a shared expert. For the tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=128, dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False, tie_embeddings=False,
+        arch="solar_open2", global_attn_every=4, moe_experts=held,
+        moe_router_experts=router_experts, moe_first_expert=first,
+        moe_top_k=2, moe_d_ff=32, moe_shared_experts=1,
+        score_func="sigmoid", route_norm=True, route_scale=1.0,
+        linear_n_heads=2, linear_head_dim=16, linear_conv_kernel=4)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -168,6 +187,7 @@ NAMED = {
     "tiny_mellum": tiny_mellum_test,
     "tiny_pangu": tiny_pangu_test,
     "tiny_glm": tiny_glm_test,
+    "tiny_solar": tiny_solar_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

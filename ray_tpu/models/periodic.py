@@ -4,29 +4,54 @@ decoder whose layers are not all alike, served through the programs of
 
 `n_dense_layers` leading layers with a dense SwiGLU, then whole periods
 of `global_attn_every` layers with the routed layer of `models/moe.py`
-(plus always-on shared experts where the configuration has them); the
-last layer of a period attends to every earlier position, the others and
-the leading layers to the last `sliding_window`. Every layer: an RMS norm
-before attention and before the FFN and a learned norm over each head of
-q and k. What else a layer has is data, `cfg.period_form`: norms on the
-attention's and the FFN's output, the attention output gated by
+(every expert held, or the share `moe_first_expert` names of the
+`moe_router_experts` scored; plus always-on shared experts where the
+configuration has them). One layer of a period, the global one, attends
+to every earlier position; the others are of one other kind: window
+layers, which attend to the last `sliding_window` (as the leading
+layers do) and which the global layer follows, or linear layers
+(`cfg.period_form.linear`), which keep a state of fixed size and no
+keys and which follow the global layer. Every layer: an RMS norm before
+attention and before the FFN. What else a layer has is data,
+`cfg.period_form`: a learned norm over each head of q and k, norms on
+the attention's and the FFN's output, the attention output gated by
 `sigmoid(h @ wg)` before `wo`, the embedding scaled by sqrt(d_model), a
-selection bias, and which kinds of layer rotate q and k, each kind with
-the table of its own section of `cfg.rope_parameters` (Trinity: window
-layers only; Mellum 2: both, the global ones by YaRN).
+selection bias, where in its period the global layer stands, and which
+kinds of layer rotate q and k, each kind with the table of its own
+section of `cfg.rope_parameters` (Trinity: window layers only; Mellum
+2: both, the global ones by YaRN; Solar Open 2: none, its layers have
+no position but the order the state saw them in).
+
+A linear layer (`_linear_half`, under the scope `attn_linear`): q, k, v
+= silu of a causal depthwise convolution over the last
+`linear_conv_kernel` positions of three projections, q and k
+l2-normalised a head; a log-decay a channel `-exp(A_log) softplus(f_b(
+f_a(h)) + dt_bias)` and a step a head `2 sigmoid(h wb)` (both through
+rank `linear_head_dim`); the gated delta rule of `ops/delta_rule` over
+a (dk, dv) float32 state a head; the heads' outputs through an RMS norm
+over dv, gated by `sigmoid(g_b(g_a(h)))`, into `wo`.
 
 Weights: `dense_layers` (leaves stacked over the leading layers) and
-`periods` (leaves stacked over periods, then over a period's layers).
+`periods` (leaves stacked over periods, then over a period's layers;
+where a period's kinds of layer have different attention leaves, those
+lie a layer under its kind and its place among the period's layers of
+the kind, `global0`, `linear0`, `linear1`, ..., stacked over periods).
 One layer definition (`layer`) and one walk (`stackparts.run`) serve
 prefill, the cache-free first token and decode; they differ in the
 `attend` they hand in, which owns the cache.
 
-The cache holds two kinds of state in one `KVCache`: `k`/`v` for the
-global layers, (Lg, slots, S_max, KVH, Dh), and `kw`/`vw` for the window
+The cache holds three kinds of state in one `KVCache`: `k`/`v` for the
+global layers, (Lg, slots, S_max, KVH, Dh); `kw`/`vw` for the window
 layers, (Lw, slots, min(sliding_window, S_max), KVH, Dh), a ring written
-at `position mod rows`. Softmax does not care in which order the ring
+at `position mod rows` (softmax does not care in which order the ring
 holds its rows, and a key carries its rotary phase from when it was
-written, so decode reads the ring as it lies.
+written, so decode reads the ring as it lies); and for the linear
+layers `s`, (Ll, slots, H, dk, dv) float32, with `tails`, (Ll, slots,
+conv - 1, 3 x H x dk), the projections' last positions. Rows grow with
+the tokens held and are final once written; a state is rewritten whole
+by every step of its slot, so a tile writes it as the prompt's last
+real token left it, padding changes nothing, and a slot nobody owns is
+not written.
 
 A configuration that generates by diffusion over blocks
 (`cfg.block_length`: every layer global) takes the same layer and the
@@ -68,8 +93,8 @@ from .stackparts import (Group, KVCache, _attend_cache,  # noqa: F401
                          rows_held)
 from .transformer import TransformerConfig, rope_tables
 
-WINDOW, GLOBAL = "window", "global"
-KINDS = (WINDOW, GLOBAL)
+WINDOW, GLOBAL, LINEAR = "window", "global", "linear"
+KINDS = (WINDOW, GLOBAL, LINEAR)
 # A kind's section of `TransformerConfig.rope_parameters`, under the key a
 # published config.json gives it.
 ROPE_SECTION = {WINDOW: "sliding_attention", GLOBAL: "full_attention"}
@@ -79,17 +104,21 @@ MISSING = {
     # The prefix programs install one (L, Sp, KVH, Dh) block of keys and
     # values a layer. A window layer's ring holds a slot's last rows at
     # `position mod rows`, not a prefix at [0, Sp): sharing it needs a
-    # layout of its own.
+    # layout of its own. A linear layer's state behind a prefix is a
+    # snapshot a registered prefix would have to carry and a suffix
+    # would have to start from.
     "suffix": "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
-              "compute_prefix_kv) is not written for a windowed cache "
-              "(models/periodic.py)",
+              "compute_prefix_kv) is not written for a windowed cache or "
+              "for a recurrent state, whose value behind the prefix a "
+              "registered prefix would have to carry (models/periodic.py)",
     "param_logical_axes": "the period stack has no sharding rules yet: it "
                           "is served on one chip (models/periodic.py)",
     "forward_train": "the period stack is served only (models/generate.py): "
                      "training lacks a dropless routed layer under "
                      "autodiff (moe_ffn drops tokens over capacity), the "
-                     "backward of windowed flash attention, and the "
-                     "load-balancing update of the selection bias",
+                     "backward of windowed flash attention and of the "
+                     "delta rule's chunked scan, and the load-balancing "
+                     "update of the selection bias",
 }
 # What a configuration with `block_length` lacks of this stack, and one
 # without it of the block walk.
@@ -123,9 +152,12 @@ def layer_plan(cfg: TransformerConfig) -> List[Group]:
 
 def step_kinds(cfg: TransformerConfig) -> List[Tuple[str, ...]]:
     """The kinds of a scan step's layers, a group of `layer_plan`."""
-    win = WINDOW if cfg.sliding_window else GLOBAL
-    period = (win,) * (cfg.global_attn_every - 1) + (GLOBAL,)
-    return [(win,) if group.key == DENSE else period
+    form = cfg.period_form
+    other = LINEAR if form.linear else \
+        WINDOW if cfg.sliding_window else GLOBAL
+    others = (other,) * (cfg.global_attn_every - 1)
+    period = (GLOBAL,) + others if form.global_first else others + (GLOBAL,)
+    return [(other,) if group.key == DENSE else period
             for group in layer_plan(cfg)]
 
 
@@ -135,11 +167,12 @@ def routed_layers(cfg: TransformerConfig) -> int:
 
 
 def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
-    """How many layers keep each kind of state."""
+    """How many layers keep each kind of state (`linear` where the form
+    has such layers)."""
     return {kind: sum(group.lead[0] * kinds.count(kind)
                       for group, kinds in zip(layer_plan(cfg),
                                               step_kinds(cfg)))
-            for kind in KINDS}
+            for kind in (KINDS if cfg.period_form.linear else KINDS[:2])}
 
 
 # ---------------------------------------------------------------------------
@@ -151,15 +184,54 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
     d, hd = cfg.d_model, cfg.head_dim
     q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
     form = cfg.period_form
-    shapes = {
-        "attn_norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
-        "wo": (q, d), "q_norm": (hd,), "k_norm": (hd,), "ffn_norm": (d,),
-    }
+    shapes = {"attn_norm": (d,), "ffn_norm": (d,)}
+    attn = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
+    if form.qk_norm:
+        attn.update(q_norm=(hd,), k_norm=(hd,))
     if form.attn_gate:
-        shapes["wg"] = (d, q)
+        attn["wg"] = (d, q)
     if form.post_norms:
         shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
+    if form.linear:
+        # Two kinds of attention leaves a period: a layer's under its
+        # kind and its place among the period's layers of the kind.
+        shapes[GLOBAL + "0"] = attn
+        for n in range(cfg.global_attn_every - 1):
+            shapes[f"{LINEAR}{n}"] = _linear_shapes(cfg)
+    else:
+        shapes.update(attn)
     return {**shapes, **stackparts.ffn_shapes(cfg, routed, form.router_bias)}
+
+
+def _linear_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
+    """A linear layer's attention leaves: keys and values alike wide."""
+    d, H, D = cfg.d_model, cfg.linear_n_heads, cfg.linear_head_dim
+    return {
+        "wq": (d, H * D), "wk": (d, H * D), "wv": (d, H * D),
+        "wo": (H * D, d), "conv": (cfg.linear_conv_kernel, 3 * H * D),
+        "f_a": (d, D), "f_b": (D, H * D), "A_log": (H,),
+        "dt_bias": (H * D,), "wb": (d, H), "g_a": (d, D),
+        "g_b": (D, H * D), "g_bias": (H * D,), "o_norm": (D,)}
+
+
+def _uniform(lo: float, hi: float, of=lambda u: u):
+    return lambda key, shape: of(jax.random.uniform(
+        key, shape, jnp.float32, lo, hi))
+
+
+# A linear layer's leaves that a scaled normal would make degenerate
+# (a decay of a half a token everywhere): drawn as the published
+# implementation initialises them. exp(A_log) uniform in [1, 16]; the
+# decay's time step log-uniform in [0.001, 0.1], `dt_bias` its inverse
+# softplus; the convolution uniform in +-1/sqrt(its 4 inputs); no bias
+# on the output gate.
+_DRAWS = {
+    "A_log": _uniform(1.0, 16.0, jnp.log),
+    "dt_bias": _uniform(math.log(0.001), math.log(0.1),
+                        lambda u: jnp.log(jnp.expm1(jnp.exp(u)))),
+    "conv": _uniform(-0.5, 0.5),
+    "g_bias": lambda key, shape: jnp.zeros(shape, jnp.float32),
+}
 
 
 def num_params(cfg: TransformerConfig) -> int:
@@ -167,14 +239,15 @@ def num_params(cfg: TransformerConfig) -> int:
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes)
+    return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes,
+                                  _DRAWS if cfg.period_form.linear else None)
 
 
 def cache_terms(cfg: TransformerConfig) -> int:
     """The bf16 terms a cached key or value is kept as: two for float32
     activations on bf16 weights (hi + lo carry 16 bits of mantissa, and
     the products of attention take bf16), else one value of `cfg.dtype`."""
-    return 2 if (cfg.dtype == jnp.float32
+    return 2 if (cfg.dtype == jnp.float32 and cfg.cache_dtype is None
                  and cfg.param_dtype == jnp.bfloat16) else 1
 
 
@@ -186,15 +259,21 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
     def zeros(layers: int, rows: int):
         z = jnp.zeros((terms * layers, num_slots, rows, cfg.n_kv_heads,
                        cfg.head_dim),
-                      jnp.bfloat16 if terms == 2 else cfg.dtype)
+                      jnp.bfloat16 if terms == 2
+                      else jnp.dtype(cfg.cache_dtype or cfg.dtype).type)
         return wsc(z, ("layers", None, None, "act_kv_heads", None))
 
     ring = min(cfg.sliding_window, max_seq_len)
+    H, D = cfg.linear_n_heads, cfg.linear_head_dim
     return KVCache(
         k=zeros(n[GLOBAL], max_seq_len), v=zeros(n[GLOBAL], max_seq_len),
         seq_lens=jnp.zeros((num_slots,), jnp.int32),
         kw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
-        vw=zeros(n[WINDOW], ring) if n[WINDOW] else None)
+        vw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
+        s=jnp.zeros((n[LINEAR], num_slots, H, D, D), jnp.float32)
+        if LINEAR in n else None,
+        tails=jnp.zeros((n[LINEAR], num_slots, cfg.linear_conv_kernel - 1,
+                         3 * H * D), cfg.dtype) if LINEAR in n else None)
 
 
 # ---------------------------------------------------------------------------
@@ -206,9 +285,9 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     """One layer on x (B, S, D) in the activation dtype. `rope`: {kind:
     (sin, cos)} for the kinds that rotate (`rope_by_kind`). `attend(kind,
     q, k, v, state) -> (out (B, S, H, Dh), state)` does the attention and
-    whatever it keeps of k and v. `experts_at`, `rows`: as `ffn_half`
-    takes them. Returns (x, state, routing stats, experts chosen (B*S,
-    K) or None)."""
+    whatever it keeps of k and v (a linear layer's: `_linear_half`).
+    `experts_at`, `rows`: as `ffn_half` takes them. Returns (x, state,
+    routing stats, experts chosen (B*S, K) or None)."""
     B, S, _ = x.shape
     H, KVH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt, eps = cfg.dtype, cfg.norm_eps
@@ -218,20 +297,26 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
     # where it enters the next product or the cache (float32: never).
     h = _norm(x, lp["attn_norm"], eps).astype(dt)
-    q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
-    k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
-    v = _dot(h, lp["wv"]).reshape(B, S, KVH, Dh).astype(dt)
-    gate = _dot(h, lp["wg"]) if form.attn_gate else None
-    q = _norm(q, lp["q_norm"], eps)
-    k = _norm(k, lp["k_norm"], eps)
-    if kind in rope:                   # else the kind has no position
-        q, k = _rope(q, *rope[kind]), _rope(k, *rope[kind])
-    with jax.named_scope("attn_" + kind):
-        out, state = attend(kind, q.astype(dt), k.astype(dt), v, state)
-    out = out.reshape(B, S, H * Dh)
-    if gate is not None:
-        out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(dt)
-    x = joins(x, _dot(out, lp["wo"]),
+    if kind == LINEAR:
+        with jax.named_scope("attn_" + kind):
+            branch, state = _linear_half(cfg, lp, h, attend, state)
+    else:
+        q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
+        k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
+        v = _dot(h, lp["wv"]).reshape(B, S, KVH, Dh).astype(dt)
+        gate = _dot(h, lp["wg"]) if form.attn_gate else None
+        if form.qk_norm:
+            q = _norm(q, lp["q_norm"], eps)
+            k = _norm(k, lp["k_norm"], eps)
+        if kind in rope:                   # else the kind has no position
+            q, k = _rope(q, *rope[kind]), _rope(k, *rope[kind])
+        with jax.named_scope("attn_" + kind):
+            out, state = attend(kind, q.astype(dt), k.astype(dt), v, state)
+        out = out.reshape(B, S, H * Dh)
+        if gate is not None:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(dt)
+        branch = _dot(out, lp["wo"])
+    x = joins(x, branch,
               lp["post_attn_norm"] if form.post_norms else None, eps)
     # A dense layer's stats are zeros, which the walk adds to its sum as
     # it adds a routed layer's: without that add the tile and the decode
@@ -241,6 +326,67 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     x, stats, experts = ffn_half(cfg, lp, x, experts_at, form.post_norms,
                                  rows)
     return x, state, zeros if stats is None else stats, experts
+
+
+def _linear_half(cfg: TransformerConfig, lp, h, attend, state):
+    """The attention half of a linear layer on its normed input h (B, S,
+    D) in the activation dtype -> (the branch (B, S, D) float32, state).
+    `attend(LINEAR, mix, conv, (g, beta), state) -> (o (B, S, H, dv)
+    float32, state)` owns the convolution's tails and the recurrent
+    state: `mix` (B, S, 3 x H x dk) the q, k and v projections before
+    their convolution, `conv` its weights, g (B, S, H, dk) the log-decay
+    and beta (B, S, H) the step (`_linear_core` is what every one of
+    them runs on the positions it has gathered)."""
+    B, S, _ = h.shape
+    H, D = cfg.linear_n_heads, cfg.linear_head_dim
+    dt, f32 = cfg.dtype, jnp.float32
+    mix = jnp.concatenate([_dot(h, lp[w]) for w in ("wq", "wk", "wv")],
+                          axis=-1).astype(dt)
+    decay = _dot(_dot(h, lp["f_a"]).astype(dt), lp["f_b"]) \
+        + lp["dt_bias"].astype(f32)
+    g = -jnp.exp(lp["A_log"].astype(f32))[:, None] \
+        * jax.nn.softplus(decay.reshape(B, S, H, D))
+    beta = 2.0 * jax.nn.sigmoid(_dot(h, lp["wb"]))
+    o, state = attend(LINEAR, mix, lp["conv"], (g, beta), state)
+    gate = _dot(_dot(h, lp["g_a"]).astype(dt), lp["g_b"]) \
+        + lp["g_bias"].astype(f32)
+    o = _norm(o, lp["o_norm"], cfg.norm_eps) \
+        * jax.nn.sigmoid(gate.reshape(B, S, H, D))
+    return _dot(o.reshape(B, S, H * D).astype(dt), lp["wo"]), state
+
+
+def _linear_core(cfg: TransformerConfig, window, conv):
+    """The convolution, the activation and the heads' norms: `window`
+    (B, conv - 1 + S, 3 x H x dk), the projections with the positions
+    before them -> q, k, v (B, S, H, dk) float32, q and k l2-normalised
+    and q scaled by dk^-0.5."""
+    B = window.shape[0]
+    K = conv.shape[0]
+    S = window.shape[1] - (K - 1)
+    H, D = cfg.linear_n_heads, cfg.linear_head_dim
+    f32 = jnp.float32
+    y = sum(conv[i].astype(f32) * window[:, i:i + S].astype(f32)
+            for i in range(K))
+    q, k, v = (a.reshape(B, S, H, D)
+               for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+
+    def unit(a):
+        return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * D ** -0.5, unit(k), v
+
+
+def _linear_tile(cfg: TransformerConfig, mix, conv, gates, lengths=None):
+    """A tile's linear attention from a zero state: (o (B, S, H, dv)
+    float32, the state behind each row's last real position, the
+    projections behind conv - 1 zero positions)."""
+    from ..ops import delta_rule
+
+    window = jnp.pad(mix, ((0, 0), (conv.shape[0] - 1, 0), (0, 0)))
+    q, k, v = (a.astype(cfg.dtype) for a in _linear_core(cfg, window, conv))
+    with jax.named_scope("kda_scan"):
+        o, last = delta_rule.chunk_scan(q, k, v, *gates, lengths)
+    return o, last, window
 
 
 def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
@@ -286,7 +432,17 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
             cfg, lp, x, kind, experts_at, rope, partial(attend, l), state,
             rows)
 
-    return stackparts.run(cfg, params, plan, x, layer_at, state)
+    def leaves_at(i, weights, j):
+        # The step's j-th layer: what every layer has, at j, and the
+        # leaves of its own, under its kind and its place among the
+        # step's layers of the kind.
+        kind = kinds[i][j]
+        return {**{k: a[j] for k, a in weights.items()
+                   if not isinstance(a, dict)},
+                **weights[f"{kind}{kinds[i][:j].count(kind)}"]}
+
+    return stackparts.run(cfg, params, plan, x, layer_at, state,
+                          leaves_at if cfg.period_form.linear else None)
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
@@ -297,6 +453,7 @@ def _embed(cfg: TransformerConfig, params, tokens):
 
 
 _QUERY_BLOCK = 256
+_ROW_ALONE = 512        # positions from which `forward_free` walks rows singly
 
 
 def _attention_f32(q, k, v, window: int, block_len: int = 0):
@@ -376,12 +533,22 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
     layer's last `ring` real positions at `position mod ring` (a row at
     or past `length` holds padding, which decode overwrites before it
     reads it). A row whose slot is out of range is dropped."""
-    kg, vg, kw, vw = state
+    kg, vg, kw, vw, s, tails = state
+    if kind == LINEAR:
+        # q, k, v: the projections, the convolution's weights and (g,
+        # beta) (`_linear_half`). The state and the projections' last
+        # conv - 1 positions as the prompt's last token left them.
+        out, last, window = _linear_tile(cfg, q, k, v, lengths)
+        at = lengths[:, None] + jnp.arange(k.shape[0] - 1)[None, :]
+        tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
+        return out, (kg, vg, kw, vw, s.at[l, slots].set(last, mode="drop"),
+                     tails.at[l, slots].set(tail.astype(tails.dtype),
+                                            mode="drop"))
     S = q.shape[1]
     out = _flash(cfg, kind, q, k, v)
     if kind == GLOBAL:
         return out, (_put(cfg, kg, l, slots, k), _put(cfg, vg, l, slots, v),
-                     kw, vw)
+                     kw, vw, s, tails)
     ring = kw.shape[2]
     if S > ring:
         r = jnp.arange(ring)[None, :]
@@ -392,7 +559,7 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
         k = jnp.take_along_axis(k, src, axis=1)
         v = jnp.take_along_axis(v, src, axis=1)
     return out, (kg, vg, _put(cfg, kw, l, slots, k),
-                 _put(cfg, vw, l, slots, v))
+                 _put(cfg, vw, l, slots, v), s, tails)
 
 
 def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions,
@@ -431,9 +598,32 @@ def _attend_terms(cfg, q, k, v, k_all, v_all, l, write_at, positions,
     return out.reshape(B, 1, KVH * G * Dh), k_all, v_all
 
 
+def _linear_step(cfg, live, l, mix, conv, gates, s, tails):
+    """One token a slot through linear layer `l`: the convolution over
+    the slot's tail and this token, one update of its state
+    (`ops/delta_rule.decode_update`), the tail moved on a position
+    (`move_tails`). A slot that is not `live` keeps both as they were."""
+    from ..ops import delta_rule
+
+    g, beta = gates
+    tail = lax.dynamic_index_in_dim(tails, l, 0, keepdims=False)
+    new = mix.astype(tail.dtype)
+    q, k, v = _linear_core(cfg, jnp.concatenate([tail, new], axis=1), conv)
+    out, s = delta_rule.decode_update(s, l, q[:, 0], k[:, 0], v[:, 0],
+                                      g[:, 0], beta[:, 0], live)
+    return out[:, None], s, delta_rule.move_tails(tails, l, new, live)
+
+
 def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
-    kg, vg, kw, vw = state
+    kg, vg, kw, vw, s, tails = state
+    if kind == LINEAR:
+        out, s, tails = _linear_step(cfg, live, l, q, k, v, s, tails)
+        return out, (kg, vg, kw, vw, s, tails)
     attend = _attend_terms if cache_terms(cfg) == 2 else _attend_cache
+    if kg.dtype != q.dtype and cache_terms(cfg) == 1:
+        # Rows of another dtype than the activations'
+        # (`TransformerConfig.cache_dtype`): the step attends in theirs.
+        q, k, v = (a.astype(kg.dtype) for a in (q, k, v))
     if kind == GLOBAL:
         out, kg, vg = attend(cfg, q, k, v, kg, vg, l, positions, positions,
                              live)
@@ -441,24 +631,38 @@ def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
         out, kw, vw = attend(cfg, q, k, v, kw, vw, l,
                              positions % kw.shape[2], positions, live)
     B = q.shape[0]
-    return out.reshape(B, 1, cfg.n_heads, cfg.head_dim), (kg, vg, kw, vw)
+    return out.reshape(B, 1, cfg.n_heads, cfg.head_dim), \
+        (kg, vg, kw, vw, s, tails)
 
 
 def _block_attend(cfg, p0, live, l, kind, q, k, v, state):
     """A block of positions a slot (`decode_block`): every layer of such
     a configuration is global."""
-    kg, vg, kw, vw = state
+    kg, vg, *rest = state
     out, kg, vg = _attend_cache_block(cfg, q, k, v, kg, vg, l, p0, live)
-    return out.reshape(q.shape), (kg, vg, kw, vw)
+    return out.reshape(q.shape), (kg, vg, *rest)
 
 
 def _free_attend(cfg, l, kind, q, k, v, state):
+    if kind == LINEAR:
+        return _linear_tile(cfg, q, k, v)[0], state
     return _flash(cfg, kind, q, k, v), state
 
 
 # ---------------------------------------------------------------------------
 # What generate.py's programs call
 # ---------------------------------------------------------------------------
+
+def _state(cache: KVCache):
+    """What rides in the walk's carry: the cache without `seq_lens`."""
+    return (cache.k, cache.v, cache.kw, cache.vw, cache.s, cache.tails)
+
+
+def _cache(state, seq_lens) -> KVCache:
+    kg, vg, kw, vw, s, tails = state
+    return KVCache(k=kg, v=vg, seq_lens=seq_lens, kw=kw, vw=vw, s=s,
+                   tails=tails)
+
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
             slots) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
@@ -469,18 +673,29 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     `lengths` are whole blocks (what is left of a prompt opens the
     slot's first block: `generate.prefill_block_batch`)."""
     rope = rope_by_kind(cfg, tokens.shape[1])
-    x, (kg, vg, kw, vw), stats, _ = _run(
+    x, state, stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens), rope,
-        partial(_prefill_attend, cfg, slots, lengths),
-        (cache.k, cache.v, cache.kw, cache.vw))
+        partial(_prefill_attend, cfg, slots, lengths), _state(cache))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
-    return KVCache(k=kg, v=vg, seq_lens=seq_lens, kw=kw, vw=vw), \
-        _final(cfg, params, x), stats if routed_layers(cfg) else None
+    return _cache(state, seq_lens), _final(cfg, params, x), \
+        stats if routed_layers(cfg) else None
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), the experts every routed layer chose: see `stackparts.run`)."""
+    D), the experts every routed layer chose: see `stackparts.run`). A
+    tile of several long rows through linear layers runs a row at a
+    time: a linear layer's projections are 6 x d_model wide in float32
+    between its products, a queue-side tile of 4 x 2,048 held 3.9 GB of
+    them beside the weights and a cache it does not touch, and a row of
+    `_ROW_ALONE` positions fills the chip's multipliers alone."""
+    W, S = tokens.shape
+    if cfg.period_form.linear and W > 1 and S >= _ROW_ALONE:
+        x, chosen = lax.map(
+            lambda row: forward_free(cfg, params, row[None]), tokens)
+        return x[:, 0], jax.tree.map(
+            lambda a: jnp.moveaxis(a, 0, 1).reshape(
+                a.shape[1], W * S, a.shape[-1]), chosen)
     rope = rope_by_kind(cfg, tokens.shape[1])
     x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
                            partial(_free_attend, cfg), None)
@@ -499,11 +714,10 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
         raise NotImplementedError(NOT_ITS_WALK["decode"])
     positions = cache.seq_lens
     rope = rope_by_kind(cfg, cache.max_seq_len, positions)
-    x, (kg, vg, kw, vw), stats, _ = _run(
+    x, state, stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
-        partial(_decode_attend, cfg, positions, live),
-        (cache.k, cache.v, cache.kw, cache.vw), live)
-    cache = KVCache(k=kg, v=vg, seq_lens=positions + 1, kw=kw, vw=vw)
+        partial(_decode_attend, cfg, positions, live), _state(cache), live)
+    cache = _cache(state, positions + 1)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
         stats if routed_layers(cfg) else None
 
@@ -533,11 +747,11 @@ def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
     live = fits if live is None else live & fits
     positions = jnp.minimum(p0[:, None] + jnp.arange(Bd)[None, :], S - 1)
     rope = rope_by_kind(cfg, S, positions)
-    x, (kg, vg, kw, vw), stats, _ = _run(
+    x, state, stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens), rope,
-        partial(_block_attend, cfg, p0, live),
-        (cache.k, cache.v, cache.kw, cache.vw), jnp.repeat(live, Bd))
-    cache = KVCache(k=kg, v=vg, seq_lens=cache.seq_lens, kw=kw, vw=vw)
+        partial(_block_attend, cfg, p0, live), _state(cache),
+        jnp.repeat(live, Bd))
+    cache = _cache(state, cache.seq_lens)
     with jax.named_scope("block_head"):
         logits = head_logits(cfg, params, _final(cfg, params, x))
     return cache, logits, stats if routed_layers(cfg) else None

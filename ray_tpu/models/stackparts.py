@@ -56,7 +56,17 @@ class KVCache(NamedTuple):
     chooses the rows a query attends (`TransformerConfig.index_topk`) it
     keeps a fifth kind of state beside them: `ki`, (L, B, S_max,
     index_head_dim) float32, the one key a token a layer that its indexer
-    scores (after its norm and rotation); None anywhere else."""
+    scores (after its norm and rotation); None anywhere else.
+
+    A period stack with linear-attention layers keeps, beside its global
+    layers' k/v, a sixth kind of state that does not grow with the
+    tokens held and is never final: `s`, (Ll, B, H, dk, dv) float32, a
+    head's recurrent state, which every step of an owned slot rewrites
+    whole, and `tails`, (Ll, B, conv - 1, 3 x H x dk) in the activation
+    dtype, the last inputs of the layer's short convolutions. An
+    admission tile writes both as the prompt's last token left them
+    (`seq_lens` hides a stale row; nothing would hide a stale state);
+    None anywhere else."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -65,6 +75,8 @@ class KVCache(NamedTuple):
     vw: Optional[jax.Array] = None
     c: Optional[jax.Array] = None
     ki: Optional[jax.Array] = None
+    s: Optional[jax.Array] = None
+    tails: Optional[jax.Array] = None
 
     @property
     def _rows(self) -> jax.Array:
@@ -321,21 +333,33 @@ def num_params(cfg: TransformerConfig, plan: Sequence[Group],
     total = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2) \
         + cfg.d_model
     for group in plan:
-        total += group.layers * sum(
-            math.prod(s) for s in layer_shapes(cfg, group.routed).values())
+        for shape in layer_shapes(cfg, group.routed).values():
+            if isinstance(shape, dict):     # one layer of a step's own
+                total += group.lead[0] * sum(
+                    math.prod(s) for s in shape.values())
+            else:
+                total += group.layers * math.prod(shape)
     return total
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array,
-                plan: Sequence[Group], layer_shapes) -> Dict[str, Any]:
+                plan: Sequence[Group], layer_shapes,
+                draws=None) -> Dict[str, Any]:
     """Scaled-normal weights as `transformer.init_params` makes them:
     norm gains one, the selection bias zero, residual-branch outputs
     scaled down by depth; `layer_shapes(cfg, routed)` says a layer's
-    leaves by name, each with its shape. Each leaf is drawn, scaled and
+    leaves by name, each with its shape. Leaves that one layer of a scan
+    step has and the step's others lack are a dict under a name of that
+    layer's, stacked under the group's steps alone (a step hands the
+    layer its slice as a scanned operand, which a product reads where it
+    lies; cut out of a stack over the step's layers it was copied first,
+    0.2 GB a leaf at 3 x 4096 x 8192). `draws`: {leaf: f(key, shape) -> float32}
+    for a leaf drawn another way. Each leaf is drawn, scaled and
     cast in one expression, so under jit no float32 copy of a stacked
     leaf is kept. What a seed makes is pinned (tests/test_stacks.py): a
     key a group in plan order, of it a key a leaf in their names' order."""
     pd = cfg.param_dtype
+    draws = draws or {}
     k_emb, k_head, k_layers = jax.random.split(key, 3)
 
     def normal(key, shape, scale):
@@ -347,31 +371,42 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
               "final_norm": jnp.ones((d,), dtype=pd)}
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
+    def draw(leaf, full, k):
+        if leaf in draws:
+            return draws[leaf](k, full).astype(pd)
+        if leaf.endswith("norm"):
+            return jnp.ones(full, dtype=pd)
+        if leaf == "router_bias":
+            return jnp.zeros(full, dtype=pd)
+        if leaf in ("wo", "w_down", "shared_down"):
+            return normal(k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
+        return normal(k, full, 0.02)
+
     for group, k_group in zip(plan, jax.random.split(k_layers, len(plan))):
         shapes = layer_shapes(cfg, group.routed)
         leaves = {}
         for (leaf, shape), k in zip(
                 sorted(shapes.items()),
                 jax.random.split(k_group, len(shapes))):
-            full = group.lead + shape
-            if leaf.endswith("norm"):
-                leaves[leaf] = jnp.ones(full, dtype=pd)
-            elif leaf == "router_bias":
-                leaves[leaf] = jnp.zeros(full, dtype=pd)
-            elif leaf in ("wo", "w_down", "shared_down"):
-                leaves[leaf] = normal(
-                    k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
+            if isinstance(shape, dict):
+                leaves[leaf] = {
+                    sub: draw(sub, group.lead[:1] + its, ks)
+                    for (sub, its), ks in zip(
+                        sorted(shape.items()),
+                        jax.random.split(k, len(shape)))}
             else:
-                leaves[leaf] = normal(k, full, 0.02)
+                leaves[leaf] = draw(leaf, group.lead + shape, k)
         params[group.key] = leaves
     return params
 
 
 def run(cfg: TransformerConfig, params, plan: Sequence[Group], x, layer_at,
-        state):
+        state, leaves_at=None):
     """x through every layer of `plan`: one `lax.scan` a group, a step's
     layers unrolled in its body (where the group's leaves have that
-    axis), `state` (the caches, or nothing) riding in the carry beside x.
+    axis; `leaves_at(i, weights, j)`: the j-th layer's leaves of a step's
+    of group `i`, where not every leaf has it), `state` (the caches, or
+    nothing) riding in the carry beside x.
     `layer_at(i, g, j)` hands back the layer at step `g` (a number the
     device counts) of group `i`, the `j`-th of its step: `layer(lp, x,
     experts_at, state) -> (x, state, routing stats or None, experts
@@ -397,8 +432,11 @@ def run(cfg: TransformerConfig, params, plan: Sequence[Group], x, layer_at,
             weights, g = scanned
             experts = []
             for j in range(math.prod(unrolled)):
-                lp = jax.tree.map(lambda a: a[j], weights) if unrolled \
-                    else weights
+                if leaves_at is not None:
+                    lp = leaves_at(i, weights, j)
+                else:
+                    lp = jax.tree.map(lambda a: a[j], weights) if unrolled \
+                        else weights
                 layer = layer_at(i, g, j)
                 # The layer's place in its group, so its first expert.
                 at = g * unrolled[0] + j if unrolled else g

@@ -73,7 +73,8 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 # says why in its `MISSING`.
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
                           "mellum": "periodic", "pangu_ultra_moe": "latent",
-                          "sdar_moe": "periodic", "glm_moe_dsa": "latent"}
+                          "sdar_moe": "periodic", "glm_moe_dsa": "latent",
+                          "solar_open2": "periodic"}
 
 # How a block of `TransformerConfig.block_length` positions is unmasked
 # (models/generate.py, `_unmask`): the names a request or a configuration
@@ -88,13 +89,19 @@ class PeriodForm:
     stack reads this and never asks for an architecture by name.
     `rotary`: the kinds of layer ("window", "global") whose q and k get
     the rotary embedding, each with the table of its own section of
-    `TransformerConfig.rope_parameters` (`rope_tables`)."""
+    `TransformerConfig.rope_parameters` (`rope_tables`). A period is one
+    global layer and `global_attn_every` - 1 others: window layers that
+    close with the global one, or, `linear`, gated delta-rule layers
+    (`TransformerConfig.linear_n_heads`) that follow it."""
 
     attn_gate: bool       # attention output x sigmoid(h @ wg) before wo
     post_norms: bool      # RMS norms on the attention and FFN outputs
     embed_scale: bool     # embedding x sqrt(d_model)
     router_bias: bool     # a per-expert bias added for the selection only
     rotary: Tuple[str, ...]
+    qk_norm: bool = True          # a learned norm over each head of q and k
+    linear: bool = False          # a period's other layers: linear attention
+    global_first: bool = False    # the global layer opens its period
 
 
 # `TransformerConfig.arch` -> its layer, for the architectures of STACKS
@@ -114,6 +121,12 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     "sdar_moe": PeriodForm(attn_gate=False, post_norms=False,
                            embed_scale=False, router_bias=False,
                            rotary=("global",)),
+    # Upstage Solar Open 2: a softmax GQA layer with no position and an
+    # output gate opens each period, gated delta-rule layers follow it;
+    # every layer routed, a selection bias.
+    "solar_open2": PeriodForm(attn_gate=True, post_norms=False,
+                              embed_scale=False, router_bias=True, rotary=(),
+                              qk_norm=False, linear=True, global_first=True),
 }
 
 
@@ -216,8 +229,10 @@ class TransformerConfig:
     # "pangu_ultra_moe": the latent-attention stack of
     # models/latent.py (the fields at the end); "glm_moe_dsa": the same
     # stack with another layer (LATENT_FORMS: two norms, a selection
-    # bias) and the sparse-attention indexer (`index_topk`). STACKS
-    # above holds the names.
+    # bias) and the sparse-attention indexer (`index_topk`).
+    # "solar_open2": the period stack again, a global layer then
+    # linear-attention layers (`linear_n_heads`, at the end). STACKS above
+    # holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length; its last layer is global
@@ -271,6 +286,21 @@ class TransformerConfig:
     # `denoise_steps` passes unmask a block by the rule `remask`
     # (`REMASK_RULES`), the dynamic one every position surer than
     # `confidence_threshold`.
+    # Linear attention (models/periodic.py, a form with `linear`): heads
+    # of `linear_head_dim` for keys and values alike, each keeping a
+    # (linear_head_dim, linear_head_dim) float32 state a slot that every
+    # token rewrites whole (the gated delta rule, `ops/delta_rule`), and
+    # a causal depthwise convolution over the last `linear_conv_kernel`
+    # positions of the q, k and v projections.
+    linear_n_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    # The dtype a period stack caches keys and values in (a name or a
+    # type; None = `dtype`, or, float32 activations on bf16 weights, two
+    # bf16 terms a row). bfloat16 under float32 activations: one bf16
+    # value a row, half the bytes; a decode step's attention then takes
+    # bf16 operands, a tile's attention over itself stays float32.
+    cache_dtype: Any = None
     block_length: int = 0
     mask_token_id: int = 0
     denoise_steps: int = 0           # 0 = `block_length`
@@ -296,6 +326,19 @@ class TransformerConfig:
                     f"{self.arch}: n_layers - n_dense_layers ({body}) must "
                     f"be whole periods of global_attn_every "
                     f"({self.global_attn_every})")
+        if self.cache_dtype is not None and STACKS[self.arch] != "periodic":
+            raise ValueError("cache_dtype: only the period stack states "
+                             "its rows' dtype")
+        linear = self.arch in PERIOD_FORMS and PERIOD_FORMS[self.arch].linear
+        if linear != bool(self.linear_n_heads) or (linear and (
+                self.linear_head_dim < 1 or self.linear_conv_kernel < 2
+                or self.sliding_window or self.n_dense_layers
+                or self.block_length)):
+            raise ValueError(
+                f"linear_n_heads {self.linear_n_heads}: a period stack whose "
+                "form has linear layers, and no other, has linear_n_heads "
+                "heads of linear_head_dim, a convolution of 2 positions or "
+                "more, no window, no leading dense layer and no block walk")
         if self.index_topk:
             if STACKS[self.arch] != "latent" or self.index_n_heads < 1 \
                     or self.index_head_dim < self.qk_rope_head_dim \

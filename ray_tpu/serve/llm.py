@@ -401,6 +401,18 @@ class LLMEngine:
             # have.
             self.counts.update(sparse_rows_read=0,
                                prefill_chunks=0, prefill_chunks_of=0)
+        # Layers that keep a recurrent state a slot (`KVCache.s`: a
+        # period stack's linear-attention layers).
+        self._state_layers = 0 if self.cache.s is None \
+            else int(self.cache.s.shape[0])
+        if self._state_layers:
+            # State updates the blocks' steps span (a slot, a step, such
+            # a layer) and those of them a request owns, which alone
+            # read and write a state; and the real (prompt token, such
+            # a layer) pairs the admission tiles ran through the
+            # recurrence.
+            self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
+                               linear_tokens=0)
         # Admission tiles' routing stats, on their way to the host: read
         # where the host next waits for a tile (_deliver_first_tokens).
         self._tile_moe: List[jax.Array] = []
@@ -831,6 +843,10 @@ class LLMEngine:
             c["prefill_chunks"] += run
             c["prefill_chunks_of"] += of
             more = dict(more, chunks=run, chunks_of=of)
+        if self._state_layers:
+            pairs = tokens * self._state_layers
+            c["linear_tokens"] += pairs
+            more = dict(more, linear_tokens=pairs)
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
             tile_rows=W, tokens=tokens, req_ids=_ids(reqs), **more)
@@ -1387,6 +1403,12 @@ class LLMEngine:
             read = sum(self._rows_read(k_block, snap[i]) for i in active)
             c["sparse_rows_read"] += read
             more.update(sparse_rows_read=read)
+        if self._state_layers:
+            steps = k_block * self._state_layers
+            c["linear_slot_steps"] += steps * self.num_slots
+            c["linear_slot_steps_live"] += steps * len(active)
+            more.update(linear_slot_steps=steps * self.num_slots,
+                        linear_slot_steps_live=steps * len(active))
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held, **more):

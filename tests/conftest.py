@@ -70,6 +70,9 @@ def pytest_collection_finish(session):
             tiny.setdefault("sdar-blockgen-closed", "tiny-sdar-closed")
             # tests/benchmark/test_glm_cell.py makes this one's.
             tiny.setdefault("glm5-longctx-closed", "tiny-glm-closed")
+            # tests/benchmark/test_solar_cell.py makes this one's.
+            tiny.setdefault("solar-open2-rollout-closed",
+                            "tiny-solar-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -83,7 +86,7 @@ def _per_layer():
 def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
     test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
-    test_sdar_cell.py, test_glm_cell.py) names
+    test_sdar_cell.py, test_glm_cell.py, test_solar_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(

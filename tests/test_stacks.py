@@ -21,7 +21,8 @@ TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "mellum": configs.tiny_mellum_test,
         "pangu_ultra_moe": configs.tiny_pangu_test,
         "sdar_moe": configs.tiny_sdar_test,
-        "glm_moe_dsa": configs.tiny_glm_test}
+        "glm_moe_dsa": configs.tiny_glm_test,
+        "solar_open2": configs.tiny_solar_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -196,6 +197,9 @@ SEEDED = {
     "tiny_sdar_test": "52fb45e14a6e01c2",
     "tiny_pangu_test": "a01e545ed20b21c1",
     "tiny_glm_test": "05192f44f3a1ebda",
+    # PR 46's preset, on the tree that added it (kinds of layer with
+    # leaves of their own: a key a layer's dict, of it a key a leaf).
+    "tiny_solar_test": "dff022f1f7c5bb71",
 }
 
 

@@ -655,7 +655,8 @@ def serve_trinity(topo):
 @pytest.fixture
 def as_on_the_chip(monkeypatch):
     """The grouped products take megablox's kernel, as on the chip
-    (`models/moe.grouped_dot` asks `on_tpu()`)."""
+    (`models/moe.grouped_dot` asks `on_tpu()`; so does
+    `ops/delta_rule.usable`)."""
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
 
 
@@ -1185,3 +1186,110 @@ def test_glm_admission_tile_walks_the_longest_bucket_in_chunks(
                    "sparse_prefill_attn"):
         assert kernel in text, kernel
     _glm_fits(serve_glm, mem, record_property)
+
+
+# -- solar-open2-rollout-closed: a recurrent state beside keys and values ----
+
+@pytest.fixture(scope="module")
+def serve_solar(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "solar-open2-rollout-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("solar-open2-l8-ep16", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _solar_fits(serve_solar, mem, record_property, cached=True):
+    cfg, slots, one, key, params, cache = serve_solar
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    # 7.80 GB of weights beside 96 slots' states (6 linear layers x 64
+    # heads x 128 x 128 float32: 2.42 GB), their convolutions' tails (6
+    # x 3 x 24,576 in the activations' float32: 0.17) and two GQA
+    # layers' 4,096 rows of 8 KV heads x 128, keys and values, one bf16
+    # value a row (`model.cache_dtype`: 3.22; two terms would be 6.44).
+    assert 7.79e9 < weights < 7.81e9
+    assert cfg.dtype == jnp.float32 and cfg.cache_dtype == "bfloat16"
+    assert (cache.s.shape, cache.s.dtype) == ((6, 96, 64, 128, 128),
+                                              jnp.float32)
+    assert (cache.tails.shape, cache.tails.dtype) == ((6, 96, 3, 24576),
+                                                     jnp.float32)
+    assert cache.k.shape == cache.v.shape == (2, 96, 4096, 8, 128)
+    assert cache.k.dtype == jnp.bfloat16 and cache.kw is None
+    held = sum(x.size * x.dtype.itemsize
+               for x in (cache.s, cache.tails, cache.k, cache.v))
+    assert 5.80e9 < held < 5.81e9
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    if cached:
+        # States, tails, keys and values aliased: no program copies one
+        # in or out.
+        assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + (0 if cached else held) < 15.75e9
+
+
+def test_solar_decode_block_updates_states_and_rows_in_place(
+        serve_solar, as_on_the_chip, record_property):
+    """`decode_multi` (k = 64, the engine's largest block) at the cell's
+    96 slots x 4,096: the linear layers' update under `attn_linear` as
+    the kernels of `ops/delta_rule` (the states and the convolutions'
+    tails aliased in and out), the
+    two GQA layers' rows through the decode kernel, megablox's kernel
+    for the held experts; every kind of state updated in place."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = serve_solar
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 64, 0,
+                                  key, live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    # Float32 rows over bf16 experts: two terms a row through megablox's
+    # kernel, the activation between the products XLA's.
+    assert "moe_experts/while/body/jit(gmm)" in text
+    assert not _fused(text, loop=True)
+    assert "ragged-dot" not in text and "decode_attn" in text
+    assert '"kernel":"kda_update"' in text and '"kernel":"kda_tails"' in text
+    for scope in ("attn_linear", "attn_global", "moe_router", "moe_shared"):
+        assert scope in text, scope
+    assert "attn_window" not in text
+    _solar_fits(serve_solar, mem, record_property)
+    assert mem.temp_size_in_bytes < 0.8e9
+
+
+@pytest.mark.parametrize("program,rows,bucket", [
+    ("prefill_sample_batch", 1, 2048), ("first_token_sample", 4, 2048)])
+def test_solar_tiles_fit_beside_weights_states_and_rows(
+        serve_solar, as_on_the_chip, record_property, program, rows, bucket):
+    """The longest bucket's admission tile (one row of 2,048: the chunked
+    scan under `kda_scan`, the flash kernel for the GQA layers) and the
+    queue-side tile of the longest bucket, which runs beside the cache
+    and not through it, its rows walked singly."""
+    from ray_tpu.models import generate
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_solar
+    cached = program == "prefill_sample_batch"
+    assert rows == (LLMEngine._tile_rows(bucket) if cached
+                    else LLMEngine._queue_tile_rows(bucket))
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    tile, n, temps = (arr((rows, bucket), jnp.int32), arr((rows,), jnp.int32),
+                      arr((rows,), jnp.float32))
+    if cached:
+        lowered = generate.prefill_sample_batch.lower(
+            cfg, params, cache, tile, n, n, 0, temps, key)
+    else:
+        lowered = generate.first_token_sample.lower(
+            cfg, params, tile, n, temps, 0, key)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "kda_scan" in text and "attn_linear" in text
+    assert "moe_experts/while/body/jit(gmm)" in text and "ragged-dot" not in text
+    _solar_fits(serve_solar, mem, record_property, cached)
