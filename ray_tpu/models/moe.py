@@ -6,7 +6,9 @@ also out of reach at serving sizes. Here the token-expert pairs are
 sorted by expert and multiplied by groups (`grouped_dot`: a grouped-matmul
 kernel that visits only the experts that hold rows; `grouped_swiglu`: the
 gate and up products and the activation between them as one such kernel),
-then put back in order, weighted and summed. Router scores and the selection
+then put back in order, weighted and summed (`down_and_combine`: on a TPU
+the down product leaves each row by itself and one kernel fetches a
+token's rows, weights and adds them). Router scores and the selection
 are float32 from a float32 input: routing is discontinuous, and a score
 rounded to bf16 picks another expert where two lie close.
 
@@ -193,6 +195,63 @@ def _grouped_swiglu(w: Dict[str, jax.Array], xs: jax.Array,
     return grouped_dot(h, w["w_down"], groups)
 
 
+def combine(ys: jax.Array, inv: jax.Array, weights: jax.Array,
+            rows=None) -> jax.Array:
+    """ys (P, D) float32, a row a pair; inv (T * K,), where the row of
+    token t's j-th pair lies; weights (T, K) -> float32 (T, D): each
+    token's rows weighted and summed, and zero for a token nobody owns
+    (`rows` (T,) bool), whatever its rows hold."""
+    T, K = weights.shape
+    ys = ys[inv].reshape(T, K, -1) * weights[..., None]
+    if rows is not None:
+        # A row of no group was never written: what lies there must not
+        # reach the sum.
+        ys = jnp.where(rows[:, None, None], ys, 0.0)
+    return jnp.sum(ys, axis=1)
+
+
+def down_and_combine(h: jax.Array, w_down: jax.Array, groups: jax.Array,
+                     inv: jax.Array, weights: jax.Array, rows=None,
+                     kernel=None) -> jax.Array:
+    """`combine` of `grouped_dot(h, w_down, groups)`: h (P, F) sorted by
+    group, the rest as `combine`'s. Where `grouped_dot` would take the
+    kernel for bf16 rows (`kernel` as there) and the product's float32
+    rows are more than XLA's gather keeps on the chip
+    (`ops/moe_combine.MIN_ROW_BYTES`, a measured crossover: an admission
+    tile's rows are, a decode step's are not) the two are
+    `ops/moe_combine`'s pair: the product writes each row by itself, (P,
+    1, D), and one kernel copies a token's K rows from there, weights
+    them and adds them in the order of j. The (P, D) array goes through
+    memory twice, not four times: XLA's gather writes all of it again for
+    the sum to read (6.37 -> 4.05 ms at mellum's 65,536 x 2,304, my chip
+    run, PR 47). Anywhere else the lines of `combine`: off the TPU, a
+    shape that does not tile, fewer rows than that or more pairs than
+    the kernel's scalar memory holds, and float32 rows over bf16 weights,
+    whose two terms a row XLA sums (its reshape into rows apart is a pass
+    more: 1.04 against 0.91 ms at trinity's tile)."""
+    from ..ops import moe_combine
+    P, D = h.shape[0], w_down.shape[2]
+    takes = _kernel_rows(h, w_down, kernel) \
+        if 4 * P * D >= moe_combine.MIN_ROW_BYTES \
+        and P <= moe_combine.MAX_PAIRS else None
+    if not takes:
+        return combine(grouped_dot(h, w_down, groups, kernel), inv, weights,
+                       rows)
+    padded, tiling, interpret = takes
+    ys = moe_combine.gmm_rows_apart(padded, w_down, groups, tiling,
+                                    interpret=interpret)
+    return moe_combine.moe_combine(ys, inv, weights, rows,
+                                   interpret=interpret)
+
+
+def _count(key: jax.Array, n: int) -> jax.Array:
+    """How many of `key` (P,) are each of 0 .. n-1, int32 (n,): a
+    compare and a sum (`jnp.bincount` is a scatter-add, 0.57 ms for
+    65,536 keys on a v5e: PERF.md, PR 44)."""
+    return jnp.sum(key[None, :] == jnp.arange(n, dtype=key.dtype)[:, None],
+                   axis=1, dtype=jnp.int32)
+
+
 def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
                     weights: jax.Array, experts: jax.Array, n_experts: int,
                     first=0, rows=None
@@ -221,21 +280,16 @@ def grouped_experts(w: Dict[str, jax.Array], x: jax.Array,
     key = flat if rows is None else jnp.where(
         jnp.repeat(rows, K), flat, E + flat)
     order = jnp.argsort(key, stable=True)        # pairs, sorted by expert
-    count = jnp.bincount(
-        key, length=E if rows is None else 2 * E).astype(jnp.int32)
+    count = _count(key, E if rows is None else 2 * E)
     sizes = chose = count
     if rows is not None:
         sizes, chose = count[:E], count[:E] + count[E:]
     groups = sizes if G == E else lax.dynamic_update_slice(
         jnp.zeros((G,), jnp.int32), sizes, (first,))
-    ys = _grouped_swiglu(w, x[order // K], groups)    # (T*K, D)
-    ys = ys[jnp.argsort(order)].reshape(T, K, -1)     # back in order
-    ys = ys * weights[..., None]
-    if rows is not None:
-        # A row of no group was never written: what lies there must not
-        # reach the sum.
-        ys = jnp.where(rows[:, None, None], ys, 0.0)
-    return jnp.sum(ys, axis=1), sizes, chose
+    h = grouped_swiglu(x[order // K], w["w_gate"], w["w_up"], groups)
+    out = down_and_combine(h, w["w_down"], groups, jnp.argsort(order),
+                           weights, rows)
+    return out, sizes, chose
 
 
 def held_pass_rows(pairs: int, held: int, routed: int) -> int:
@@ -281,10 +335,10 @@ def held_experts(w: Dict[str, jax.Array], x: jax.Array, weights: jax.Array,
         # An unowned pair is absent too, and counted apart: E + 1 + key.
         key = jnp.where(jnp.repeat(rows, K), key, E + 1 + key)
     order = jnp.argsort(key, stable=True)
-    count = jnp.bincount(key, length=E + 1 if rows is None else 2 * E + 2)
-    sizes = chose = count[:E].astype(jnp.int32)
+    count = _count(key, E + 1 if rows is None else 2 * E + 2)
+    sizes = chose = count[:E]
     if rows is not None:
-        chose = sizes + count[E + 1:2 * E + 1].astype(jnp.int32)
+        chose = sizes + count[E + 1:2 * E + 1]
     ends = jnp.cumsum(sizes)
     starts, kept = ends - sizes, ends[-1]
     room = -(-P // C) * C - P           # the last pass may reach past P

@@ -19,7 +19,7 @@ import pytest
 
 from ray_tpu.models import configs, generate, moe
 from ray_tpu.models.transformer import init_params, loss_fn, stack
-from ray_tpu.ops import grouped_swiglu
+from ray_tpu.ops import grouped_swiglu, moe_combine
 from ray_tpu.ops.grouped_swiglu import gmm_swiglu
 
 T, K, E, D, F = 6, 2, 4, 128, 128
@@ -86,6 +86,22 @@ def products(request, monkeypatch):
         monkeypatch.setattr(moe, "grouped_dot", forced(moe.grouped_dot, 2))
         monkeypatch.setattr(moe, "grouped_swiglu",
                             forced(moe.grouped_swiglu, 3))
+    if path == "interpret":
+        # And a bf16 layer's down product and the rows' return as the
+        # pair of `ops/moe_combine`.
+        down, apart = moe.down_and_combine, moe_combine.gmm_rows_apart
+
+        def rows_apart(a, w, groups, tiling, interpret):
+            y = apart(a, w, groups, tiling, interpret=interpret)
+            if poison != "written_nowhere":
+                return y
+            past = jnp.arange(a.shape[0]) >= jnp.sum(groups)
+            return jnp.where(past[:, None, None], jnp.nan, y)
+
+        monkeypatch.setattr(moe_combine, "gmm_rows_apart", rows_apart)
+        monkeypatch.setattr(moe_combine, "MIN_ROW_BYTES", 0)    # 48 rows
+        monkeypatch.setattr(moe, "down_and_combine",
+                            lambda *operands: down(*operands, force))
     return path, poison
 
 
@@ -124,6 +140,13 @@ def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products,
         return gmm_swiglu(*args, **kw)
 
     monkeypatch.setattr(grouped_swiglu, "gmm_swiglu", counted)
+    combined, combine = [], moe_combine.moe_combine
+
+    def returned(*args, **kw):
+        combined.append(args[0].shape)
+        return combine(*args, block_tokens=8, **kw)   # 6 tokens a call
+
+    monkeypatch.setattr(moe_combine, "moe_combine", returned)
     want, all_sizes, all_chose, keys = run(w, x, weights, key, None)
     if poison == "a_nan_row_of_x":
         x = jnp.where(rows[:, None], x, jnp.nan)
@@ -140,6 +163,10 @@ def test_owned_rows_to_the_bit_and_the_others_zero(layer, dtype, products,
     # bf16 rows in the interpreter: gate, up and the activation between
     # them were the one kernel, in both calls of the layer.
     assert bool(fused) == ((dtype, path) == ("bfloat16", "interpret"))
+    # And `grouped_experts`' rows came back through the combine kernel
+    # (`held_experts` adds a pass's rows by a 0 / 1 product).
+    assert bool(combined) == (
+        (layer, dtype, path) == ("grouped", "bfloat16", "interpret"))
 
 
 @pytest.mark.parametrize("products", [("ragged", "written_nowhere"),
@@ -291,18 +318,22 @@ def _ops(lowered):
 # (d6f4b5d), this machine, jax 0.9.0. The dense stack's programs and the
 # train step, with and without a routed FFN; and of the routed stacks
 # the cache-free forward, which passes no rows and reports no stats.
+# Re-recorded in PR 47, whose count of the experts' sizes is a compare
+# and a sum where `jnp.bincount`'s scatter-add stood: the four that run
+# `grouped_experts` / `held_experts` (tiny_moe's tile, and the routed
+# stacks' forwards); the dense programs and the train step stand.
 BEFORE = {
     ("tiny", "prefill_sample_batch"): "466e821c31a284bd",
     ("tiny", "first_token_sample"): "8e9ea74f930637f4",
     ("tiny", "decode_multi"): "908831b3d941ae47",
     ("tiny", "decode_step"): "f1b991cb2d35fd75",
     ("tiny", "train"): "b45b1592417db270",
-    ("tiny_moe", "prefill_sample_batch"): "eb2f10ad1cd46cb6",
+    ("tiny_moe", "prefill_sample_batch"): "6765e6a7cfa2d324",
     ("tiny_moe", "first_token_sample"): "cbecddc8aacbbe0d",
     ("tiny_moe", "train"): "3c401085a21ea84a",
-    ("tiny_mellum", "first_token_sample"): "94713c265836ac62",
-    ("tiny_afmoe", "first_token_sample"): "12b12154aeedd2a3",
-    ("tiny_pangu", "first_token_sample"): "a380be75b8eba815",
+    ("tiny_mellum", "first_token_sample"): "0cd4e54fe52c79fa",
+    ("tiny_afmoe", "first_token_sample"): "1d087938fd2e6b95",
+    ("tiny_pangu", "first_token_sample"): "8c0556e08c68c7e7",
 }
 
 
@@ -313,28 +344,39 @@ def test_a_program_without_rows_lowers_as_before(name, program):
         == BEFORE[name, program]
 
 
-# A routed stack's admission tile on the parent commit: its text's
-# digest, the digest of its operations counted by name, and the routed
-# layers its text holds (a scan's body once).
+# A routed stack's admission tile on the parent commit (628ebf5, PR 46):
+# its text's digest, the digest of its operations counted by name, the
+# routed layers its text holds (a scan's body once), and what the count's
+# new lines (`moe._count`, PR 47) add to and take from those operations.
 TILE_BEFORE = {
-    "tiny_mellum": ("29b341b5c7acaa74", "20ba963d8e866994", 4),
-    "tiny_afmoe": ("659b17786c19600a", "89cf06bd3da4b1c5", 4),
-    "tiny_pangu": ("3d6183730035d170", "73556728832f3a0c", 1),
+    "tiny_mellum": ("210976664ae7adeb", "4f4d13dd86098e73", 4, {
+        "add": -8, "broadcast_in_dim": -5, "constant": -16, "convert": 3,
+        "iota": 4, "maximum": -1, "reduce": 4, "scatter": -4, "select": -4}),
+    "tiny_afmoe": ("11b93b322c076877", "a7df48e2341025e0", 4, {
+        "add": -8, "broadcast_in_dim": -5, "constant": -16, "convert": 3,
+        "iota": 4, "maximum": -1, "reduce": 4, "scatter": -4, "select": -4}),
+    "tiny_pangu": ("c3dd8ec7937154e2", "78fa5f270005236f", 1, {
+        "add": -2, "broadcast_in_dim": -2, "constant": -4, "iota": 1,
+        "maximum": -1, "reduce": 1, "scatter": -1, "select": -1}),
 }
 
 
 @pytest.mark.parametrize("name", sorted(TILE_BEFORE))
-def test_a_routed_tile_gains_the_stats_entry_and_nothing_else(name):
+def test_a_routed_tile_counts_without_a_scatter_and_nothing_else(name):
     """The tile passes no rows: every position's pairs are taken, padding
-    too. Its program is the parent's but for the fourth entry of a
-    layer's stats: one more sum over the experts' sizes (a reduce, its
-    zero, and the entry's shape for the stack), and no operation of any
-    other name more or fewer."""
+    too. Its program is the parent's but for the experts' sizes: a
+    layer's scatter-add (`jnp.bincount`, with its clamp, its select and
+    their constants) is gone, a compare against an iota and a sum stand
+    in its place, and no operation of any other name is more or fewer:
+    off the TPU the rows come back by the lines that stood."""
     now = _lowered(configs.get(name), "prefill_sample_batch")
-    text, counted, layers = TILE_BEFORE[name]
+    text, counted, layers, moved = TILE_BEFORE[name]
     assert _digest(now) != text
+    assert (moved["scatter"], moved["iota"], moved["reduce"]) \
+        == (-layers, layers, layers)
     ops = _ops(now)
-    for op in ("reduce", "constant", "broadcast_in_dim"):
-        ops["stablehlo." + op] -= layers
-    assert hashlib.sha256(repr(sorted(ops.items())).encode()) \
+    for op, n in moved.items():
+        ops["stablehlo." + op] -= n
+    assert hashlib.sha256(repr(sorted(
+        (op, n) for op, n in ops.items() if n)).encode()) \
         .hexdigest()[:16] == counted

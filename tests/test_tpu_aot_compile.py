@@ -662,11 +662,23 @@ def as_on_the_chip(monkeypatch):
 
 def _fused(text, loop=False):
     """Whether the routed layers' gate and up products are the one
-    kernel of `ops/grouped_swiglu`, beside megablox's for the down
+    kernel of `ops/grouped_swiglu`, beside a grouped kernel for the down
     product (`loop`: inside `held_experts`' loop over passes)."""
     at = "moe_experts/while/body/" if loop else "moe_experts/"
-    assert at + "jit(gmm)" in text and "ragged-dot" not in text
+    assert at + "jit(gmm)" in text or _combined(text)
+    assert "ragged-dot" not in text
     return at + "jit(gmm_swiglu)" in text and '"kernel":"gmm_swiglu"' in text
+
+
+def _combined(text):
+    """Whether `grouped_experts`' down product writes its rows apart and
+    one kernel brings them back to their tokens (`ops/moe_combine`): both
+    or neither, and then megablox's `gmm` is in no routed layer."""
+    pair = [f"moe_experts/jit({name})" in text and f'"kernel":"{name}"' in text
+            for name in ("gmm_rows_apart", "moe_combine")]
+    assert pair[0] == pair[1], pair
+    assert not (pair[0] and "moe_experts/jit(gmm)" in text)
+    return pair[0]
 
 
 def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
@@ -694,8 +706,8 @@ def test_trinity_decode_block_fits_and_updates_both_caches_in_place(
     mem, writes = compiled.memory_analysis(), _device_writes(text)
     assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
     # Float32 rows sum their two bf16 terms before the activation: the
-    # three products stay megablox's.
-    assert not _fused(text)
+    # three products stay megablox's, and XLA brings their rows back.
+    assert not _fused(text) and not _combined(text)
 
     def nbytes(x):
         return x.size * x.dtype.itemsize
@@ -835,11 +847,19 @@ def test_mellum_decode_block_reaches_the_grouped_kernel_under_128_rows(
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
     assert _fused(text) == (activations == "bfloat16")
+    # 32 rows of 2,304 are far under what the pair of `ops/moe_combine`
+    # is faster from: XLA brings a step's rows back.
+    assert not _combined(text)
     assert "decode_attn" in text
     held = sum(x.size * x.dtype.itemsize
                for x in (cache.k, cache.v, cache.kw, cache.vw))
     assert mem.alias_size_in_bytes >= held
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# The bf16 tile's temporaries on the parent commit (628ebf5), bytes: this
+# test's `temp_gb` there, read in PR 47.
+PARENT_TILE_TEMP = 1.443774464e9
 
 
 @pytest.mark.parametrize("activations", ["float32", "bfloat16"])
@@ -871,8 +891,16 @@ def test_mellum_admission_tile_fits_beside_weights_and_cache(
     text, mem = compiled.as_text(), compiled.memory_analysis()
     record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
     record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
-    assert "moe_experts/jit(gmm)" in text and "ragged-dot" not in text
-    assert _fused(text) == (activations == "bfloat16")
+    assert _fused(text) == _combined(text) == (activations == "bfloat16")
+    if activations == "bfloat16":
+        # The pairs' float32 rows are written once, a row apart, by the
+        # down product and read once by the combine: no fusion makes the
+        # (65536, 2304) array again, and the gathered copy is gone from
+        # the temporaries: 1.444 GB on the parent commit
+        # (PARENT_TILE_TEMP), 0.961 here.
+        made = re.findall(r"= f32\[65536,(?:1,)?2304\]\S* (\S+)\(", text)
+        assert made and set(made) == {"custom-call"}, made
+        assert mem.temp_size_in_bytes < PARENT_TILE_TEMP - 0.45e9
     for scope in ("attn_window", "attn_global", "moe_router"):
         assert scope in text, scope
     # The flash kernel a layer of the period (three window layers and
@@ -913,6 +941,40 @@ def test_gate_up_and_activation_compile_as_one_kernel(topo, as_on_the_chip,
     assert f"f32[{rows + -rows % 128},{n}]" not in text
     assert jax.eval_shape(moe.grouped_swiglu, a, w, w, groups).dtype \
         == jnp.bfloat16
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["every_row", "rows"])
+def test_down_product_and_return_compile_as_the_pair(topo, as_on_the_chip,
+                                                     masked):
+    """`moe.down_and_combine` alone at mellum's tile: the down product
+    writes 65,536 float32 rows of 2,304 a row apart, (65536, 1, 2304) in
+    tiles of one row, the combine copies each from there by its index
+    (the 65,536 indices prefetched whole: 256 KB of scalar memory) and
+    writes (8192, 2304) once; no (65536, 2304) float32 array is made, and
+    the program's temporaries are the rows once (604 MB) and the result."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops import moe_combine
+
+    one = SingleDeviceSharding(topo.devices[0])
+    T, K, F, D, E = 8192, 8, 896, 2304, 64
+    assert 4 * T * K * D >= moe_combine.MIN_ROW_BYTES
+    assert T * K <= moe_combine.MAX_PAIRS
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = [arr((T * K, F), jnp.bfloat16), arr((E, F, D), jnp.bfloat16),
+            arr((E,), jnp.int32), arr((T * K,), jnp.int32),
+            arr((T, K), jnp.float32)]
+    if masked:
+        args.append(arr((T,), jnp.bool_))
+    compiled = jax.jit(moe.down_and_combine).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for name in ("gmm_rows_apart", "moe_combine"):
+        assert f'"kernel":"{name}"' in text, name
+    assert "jit(gmm)" not in text and "ragged-dot" not in text
+    assert "f32[65536,1,2304]" in text and "f32[65536,2304]" not in text
+    assert 4 * T * K * D <= mem.temp_size_in_bytes < 4 * T * (K + 2) * D
 
 
 # -- openpangu-longgen-closed: a latent cache and a share of the experts ----
@@ -1058,7 +1120,7 @@ def test_sdar_block_program_runs_four_positions_a_slot_through_the_kernels(
         live).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "moe_experts" in text and "jit(gmm)" in text
-    assert "jit(gmm_swiglu)" in text
+    assert "jit(gmm_swiglu)" in text and not _combined(text)
     assert "ragged-dot" not in text and "decode_attn" in text
     for scope in ("attn_global", "moe_router", "block_head", "block_sample"):
         assert scope in text, scope
@@ -1089,6 +1151,7 @@ def test_sdar_admission_tiles_fit_beside_weights_and_cache(
         arr((W,), jnp.float32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "jit(gmm)" in text and "ragged-dot" not in text
+    assert not _combined(text)          # 4,096 rows of 2,048: 34 MB
     assert "jit(gmm_swiglu)" in text
     _sdar_fits(serve_sdar, mem, record_property)
 
