@@ -28,7 +28,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Sequence
+from typing import (
+    Any, Dict, Iterator, List, Optional, Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -232,6 +233,38 @@ def _routing_sums(stats) -> Dict[str, int]:
                 moe_pairs=pairs[0] if pairs else rows, moe_pairs_held=rows)
 
 
+# Steps past the shortest budget from which a fused block is rounded down
+# and not up. At 8 only what would have been a block of 32 or 64 steps is
+# ever split, and no piece but the last is shorter than 16 steps: 57 ms of
+# device time at the shortest step any cell has (mellum2-repoctx-lone,
+# 3.583 ms: ledger, PR 47) against the 5.4-6.4 ms a tick's own code takes
+# there (`engine.host_self_ms_tick.online`), so the piece queued behind
+# costs the device no gap, only its launch: 0.44-0.59 ms (blocks of 64 /
+# 32 / 16 steps took 229.36 / 114.90 / 57.74 ms there) against the 8 steps
+# or more it saves. That cell's budget of 48 ran as one block of 64 and
+# runs as 32 + 16: `tpot_p90_ms` 4.905 -> 3.694, six pairs (my chip runs,
+# PR 49).
+_ROUND_DOWN_FROM = 8
+
+
+def block_steps(remaining: int, cap: int, headroom: int) -> Tuple[int, int]:
+    """Steps of the next fused block, and the steps rounding up alone
+    would have run, from the smallest budget an active slot has left
+    (`remaining`), `decode_block` (`cap`) and the cache rows the fullest
+    slot has left (`headroom`). Both are powers of two within `cap` and
+    `headroom` (at least 1): each size is one compiled program. The
+    budget is rounded up where that runs fewer than `_ROUND_DOWN_FROM`
+    steps past it, and down where not: the next tick dispatches the rest
+    behind this block, so a budget of b takes at most log2(b) blocks and
+    an exact power of two one."""
+    remaining = max(1, remaining)
+    up = 1 << (remaining - 1).bit_length()
+    k = up // 2 if up - remaining >= _ROUND_DOWN_FROM else up
+    limit = max(1, min(cap, headroom))
+    limit = 1 << (limit.bit_length() - 1)
+    return min(k, limit), min(up, limit)
+
+
 class _Slot:
     __slots__ = ("req", "emitted", "length", "inflight", "blocks_left")
 
@@ -290,11 +323,13 @@ class LLMEngine:
         self.params = params
         # UPPER BOUND on ticks fused per dispatch (decode_multi); the
         # actual block size adapts ONLINE each step to the minimum
-        # remaining generation budget among active slots, so a block
-        # ends exactly when the first slot completes and its
-        # replacement is admitted (no workload-tuned constant — the cap
+        # remaining generation budget among active slots
+        # (`block_steps`): a power of two that ends fewer than
+        # `_ROUND_DOWN_FROM` steps past the first slot to complete, or
+        # before it, a shorter block then following (until PR 49 the
+        # next power of two, up to twice the budget less one). The cap
         # only bounds the number of compiled block sizes and the
-        # worst-case admission latency). A fused block costs one
+        # worst-case admission latency. A fused block costs one
         # dispatch and one host fetch for its tokens.
         self.decode_block = max(1, decode_block)
         # Positions a slot a decode step: 1, or the block a pass of the
@@ -361,7 +396,8 @@ class LLMEngine:
         # happens (stats()["counts"]; docs/METRICS.md): an operator has
         # them without a profiler.
         self.counts: Dict[str, Any] = {
-            "ticks": 0, "blocks": 0, "blocks_by_k": {}, "slot_steps": 0,
+            "ticks": 0, "blocks": 0, "blocks_by_k": {},
+            "blocks_rounded_down": 0, "slot_steps": 0,
             "tokens_discarded": 0, "prefill_tiles": 0, "prefill_rows": 0,
             "prefill_tile_rows": 0, "prefill_tokens": 0,
             "prefill_tile_tokens": 0, "queue_side_first_tokens": 0,
@@ -1295,15 +1331,18 @@ class LLMEngine:
         active = [i for i, s in enumerate(snap) if s is not None]
         block = None
         if active:
-            # Block size (adaptive, per step): sized to the minimum
-            # remaining generation budget among active slots — counting
-            # ticks already in flight — rounded UP to a power of two
-            # (each distinct size is its own XLA compile): rounding
-            # down would split a 63-token budget into ~7 dispatches and
-            # pay the round trip for each; rounding up wastes at most
-            # the finishing slot's share of the overshoot ticks. Capped
-            # by self.decode_block (compile-cache/latency bound) and by
-            # every slot's DEVICE-side cache headroom (length +
+            # Block size (adaptive, per step): `block_steps` of the
+            # minimum remaining generation budget among active slots,
+            # counting ticks already in flight: a power of two (each
+            # distinct size is its own XLA compile), rounded UP where
+            # that overshoots the budget by a few steps and DOWN where
+            # by more, the rest following as a shorter block a tick
+            # later, queued behind this one before it is fetched.
+            # (Always up until PR 49: a budget of 48 ran 64 steps, and
+            # with one caller the finishing slot is the only slot: its
+            # last token left when the block ended, 17 steps late.)
+            # Capped by self.decode_block (compile-cache/latency bound)
+            # and by every slot's DEVICE-side cache headroom (length +
             # inflight) so no in-block write can run past max_seq_len.
             # Where a step is a pass over a block, a slot's budget is
             # the passes its blocks can still take (a commit behind each
@@ -1315,15 +1354,9 @@ class LLMEngine:
                 for i in active)
             budget = max(left)
             if budget > 0 or self._pending is None:
-                remaining = max(1, min(max(1, n) for n in left))
-                k_block = 1
-                while k_block < remaining:
-                    k_block *= 2
-                k_block = min(k_block, self.decode_block,
-                              max(1, headroom))
-                while k_block & (k_block - 1):
-                    k_block &= k_block - 1
-                block = self._dispatch_block(k_block, snap, active)
+                k_block, k_up = block_steps(
+                    min(left), self.decode_block, headroom)
+                block = self._dispatch_block(k_block, snap, active, k_up)
             # else: every active slot's budget is already covered by
             # the in-flight block — dispatching more would only burn
             # wasted ticks; process the pending block instead.
@@ -1375,13 +1408,15 @@ class LLMEngine:
         cap = min(self.cfg.index_topk, self.max_seq_len)
         return sum(min(first + t, cap) for t in range(k_block))
 
-    def _dispatch_block(self, k_block: int, snap: List, active: List[int]):
+    def _dispatch_block(self, k_block: int, snap: List, active: List[int],
+                        k_up: int = 0):
         """One fused block of `k_block` decode steps for every slot (the
         program computes all `num_slots`; `active` of them hold a
         request, and it is told which: the others' cache rows are not
         read). Where the model generates a block of positions a pass, a
-        step is a pass. Returns the pending block `_process_block`
-        takes."""
+        step is a pass. `k_up`: the steps rounding the budget up would
+        have run, where more than `k_block`. Returns the pending block
+        `_process_block` takes."""
         c = self.counts
         number = c["blocks"]
         c["blocks"] = number + 1
@@ -1399,6 +1434,9 @@ class LLMEngine:
         owned[active] = True
         more = dict(passes=k_block, block_length=self.block_length) \
             if self.block_length else {}
+        if k_up > k_block:
+            c["blocks_rounded_down"] += 1
+            more.update(short_of=k_up)
         if self.cfg.index_topk:
             read = sum(self._rows_read(k_block, snap[i]) for i in active)
             c["sparse_rows_read"] += read

@@ -743,3 +743,95 @@ def test_a_lone_request_on_four_slots_meets_its_own_experts_only(arch):
         assert b["moe_experts_hit"] <= b["moe_rows_taken"]
     assert c["moe_experts_hit"] <= c["moe_rows_taken"] < c["moe_pairs"]
     assert c["prefill_moe_rows_taken"] == c["prefill_moe_rows"] > 0
+
+
+# What the issue that brought the rule wrote out: the blocks a lone
+# budget takes, in order.
+_LONE_BLOCKS = {48: [32, 16], 33: [32, 1], 40: [32, 8], 56: [32, 16, 8],
+                31: [32], 5: [8], 17: [16, 1], 24: [16, 8], 100: [64, 32, 4],
+                **{b: [64] for b in range(57, 65)}}
+
+
+@pytest.mark.parametrize("budget", range(1, 131))
+def test_block_steps_of_a_budget(budget):
+    """The sizing rule alone: every size a power of two within the cap
+    and the cache's headroom, fewer than `_ROUND_DOWN_FROM` steps past
+    the budget, an exact power of two one block, and a lone budget
+    covered in at most log2 of it dispatches, none but the last under 16
+    steps."""
+    import math
+
+    from ray_tpu.serve.llm import _ROUND_DOWN_FROM, block_steps
+
+    def pow2(n):
+        return n > 0 and n & (n - 1) == 0
+
+    for cap in (1, 4, 48, 64, 256):
+        for headroom in (0, 1, 5, 40, 64, 10 ** 6):
+            k, up = block_steps(budget, cap, headroom)
+            assert pow2(k) and pow2(up) and k <= up
+            assert k <= min(cap, max(1, headroom))
+            assert k - budget < _ROUND_DOWN_FROM
+            # `up`: what rounding up alone runs (the rule until PR 49).
+            want = 1
+            while want < budget:
+                want *= 2
+            want = min(want, cap, max(1, headroom))
+            while want & (want - 1):
+                want &= want - 1
+            assert up == want
+            # Rounded down only where the cap and the headroom did not
+            # already hold the block under the budget.
+            assert k == up or (k == up // 2 and up - budget
+                               >= _ROUND_DOWN_FROM)
+    if pow2(budget):
+        assert block_steps(budget, 256, 10 ** 6) == (budget, budget)
+    left, ks = budget, []
+    while left > 0:
+        ks.append(block_steps(left, 256, 10 ** 6)[0])
+        left -= ks[-1]
+    assert len(ks) <= max(1, int(math.log2(budget)))
+    assert -left < _ROUND_DOWN_FROM
+    assert all(k >= 16 for k in ks[:-1])
+    assert ks == _LONE_BLOCKS.get(budget, ks)
+    # A slot whose budget the blocks in flight cover counts as 1.
+    assert block_steps(-budget, 64, 64) == (1, 1)
+
+
+def test_a_budget_of_48_runs_as_32_and_16(tiny_model):
+    """One request of 48 tokens under `decode_block` 64: the first token
+    leaves with the tile and the block is sized before it counts, so the
+    budget is 48: a block of 32 and one of 16 behind it, one step past
+    the request's end, where one block of 64 ran 17 past it. The tokens
+    are those of an engine that runs a step a block."""
+    import threading
+
+    from ray_tpu.util import tracing
+
+    cfg, params = tiny_model
+    prompt = list(range(1, 10))
+    eng = LLMEngine(cfg, params, num_slots=4, max_seq_len=128,
+                    decode_block=64)
+    spans, own = [], threading.get_ident()
+    tracing.setup_tracing(lambda e: threading.get_ident() == own
+                          and spans.append(e))
+    try:
+        req = eng.submit(prompt, max_new_tokens=48)
+        while eng.step():
+            pass
+    finally:
+        tracing.clear_tracing()
+    c = eng.stats()["counts"]
+    assert c["blocks_by_k"] == {32: 1, 16: 1}
+    assert c["tokens_discarded"] == 1
+    assert c["blocks_rounded_down"] == 1
+    blocks = [e["args"] for e in spans if e["name"] == "engine.dispatch_block"]
+    assert [(b["k"], b.get("short_of")) for b in blocks] == [(32, 64),
+                                                              (16, None)]
+    one = LLMEngine(cfg, params, num_slots=4, max_seq_len=128, decode_block=1)
+    ref = one.submit(prompt, max_new_tokens=48)
+    while one.step():
+        pass
+    assert one.stats()["counts"]["blocks_rounded_down"] == 0
+    toks = req.result(timeout=5)
+    assert len(toks) == 48 and toks == ref.result(timeout=5)
