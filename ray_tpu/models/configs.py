@@ -131,6 +131,21 @@ def tiny_solar_test(vocab: int = 256, router_experts: int = 16,
         linear_n_heads=2, linear_head_dim=16, linear_conv_kernel=4)
 
 
+def tiny_jamba_test(vocab: int = 256, offset: int = 2) -> TransformerConfig:
+    """The period stack with state-space layers at a unit-test size: two
+    periods of four layers, the one attention layer (4 heads over one KV
+    head of 16, no position) at place `offset`, Mamba layers around it
+    (128 channels of 16 coordinates, the step through rank 8, a
+    convolution over 4 positions), every FFN a dense SwiGLU, the head
+    tied. For the tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=8, n_heads=4, n_kv_heads=1,
+        head_dim=16, d_ff=128, max_seq_len=128, dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False, tie_embeddings=True,
+        arch="jamba", global_attn_every=4, attn_layer_offset=offset,
+        mamba_d_state=16, mamba_expand=2, mamba_dt_rank=8, mamba_d_conv=4)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -188,6 +203,7 @@ NAMED = {
     "tiny_pangu": tiny_pangu_test,
     "tiny_glm": tiny_glm_test,
     "tiny_solar": tiny_solar_test,
+    "tiny_jamba": tiny_jamba_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

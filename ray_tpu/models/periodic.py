@@ -7,20 +7,22 @@ of `global_attn_every` layers with the routed layer of `models/moe.py`
 (every expert held, or the share `moe_first_expert` names of the
 `moe_router_experts` scored; plus always-on shared experts where the
 configuration has them). One layer of a period, the global one, attends
-to every earlier position; the others are of one other kind: window
-layers, which attend to the last `sliding_window` (as the leading
-layers do) and which the global layer follows, or linear layers
-(`cfg.period_form.linear`), which keep a state of fixed size and no
-keys and which follow the global layer. Every layer: an RMS norm before
-attention and before the FFN. What else a layer has is data,
+to every earlier position and stands where the form or the
+configuration says (`global_place`); the others are of one other kind:
+window layers, which attend to the last `sliding_window` (as the leading
+layers do), or layers that keep a state of fixed size and no keys
+(`cfg.period_form.recurrent`): linear layers, the gated delta rule, or
+state-space layers, Mamba's selective scan. A period's FFNs are routed
+or, with no experts, dense. Every layer: an RMS norm before attention
+and before the FFN. What else a layer has is data,
 `cfg.period_form`: a learned norm over each head of q and k, norms on
 the attention's and the FFN's output, the attention output gated by
 `sigmoid(h @ wg)` before `wo`, the embedding scaled by sqrt(d_model), a
 selection bias, where in its period the global layer stands, and which
 kinds of layer rotate q and k, each kind with the table of its own
 section of `cfg.rope_parameters` (Trinity: window layers only; Mellum
-2: both, the global ones by YaRN; Solar Open 2: none, its layers have
-no position but the order the state saw them in).
+2: both, the global ones by YaRN; Solar Open 2 and Jamba: none, their
+layers have no position but the order the state saw them in).
 
 A linear layer (`_linear_half`, under the scope `attn_linear`): q, k, v
 = silu of a causal depthwise convolution over the last
@@ -31,11 +33,21 @@ rank `linear_head_dim`); the gated delta rule of `ops/delta_rule` over
 a (dk, dv) float32 state a head; the heads' outputs through an RMS norm
 over dv, gated by `sigmoid(g_b(g_a(h)))`, into `wo`.
 
+A state-space layer (`_ssm_half`, under the scope `attn_ssm`): `[u, z] =
+h w_in`; u = silu of a causal depthwise convolution over the last
+`mamba_d_conv` positions (with a bias); `[r, B, C] = u w_x`, each
+through an RMS norm of its own; the step a channel `dt = softplus(r
+w_dt + dt_bias)`; the selective scan of `ops/selective_scan` over a
+(mamba_d_state, channels) float32 state with `A = -exp(A_log)`; `(y + D
+u) silu(z)` into `wo`. The scan, the step and the state are float32
+whatever `cfg.dtype` is; the projections take operands of `cfg.dtype`.
+
 Weights: `dense_layers` (leaves stacked over the leading layers) and
 `periods` (leaves stacked over periods, then over a period's layers;
 where a period's kinds of layer have different attention leaves, those
 lie a layer under its kind and its place among the period's layers of
-the kind, `global0`, `linear0`, `linear1`, ..., stacked over periods).
+the kind, `global0`, `linear0`, `linear1`, ..., stacked over periods; a
+dense FFN's matrices lie there with them).
 One layer definition (`layer`) and one walk (`stackparts.run`) serve
 prefill, the cache-free first token and decode; they differ in the
 `attend` they hand in, which owns the cache.
@@ -47,7 +59,10 @@ at `position mod rows` (softmax does not care in which order the ring
 holds its rows, and a key carries its rotary phase from when it was
 written, so decode reads the ring as it lies); and for the linear
 layers `s`, (Ll, slots, H, dk, dv) float32, with `tails`, (Ll, slots,
-conv - 1, 3 x H x dk), the projections' last positions. Rows grow with
+conv - 1, 3 x H x dk), the projections' last positions (state-space
+layers: `s` (Ls, slots, mamba_d_state, channels) float32, the channels
+on the minor axis as the kernels hold them, and `tails` (Ls, slots,
+conv - 1, channels)). Rows grow with
 the tokens held and are final once written; a state is rewritten whole
 by every step of its slot, so a tile writes it as the prompt's last
 real token left it, padding changes nothing, and a slot nobody owns is
@@ -77,7 +92,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -93,8 +108,8 @@ from .stackparts import (Group, KVCache, _attend_cache,  # noqa: F401
                          rows_held)
 from .transformer import TransformerConfig, rope_tables
 
-WINDOW, GLOBAL, LINEAR = "window", "global", "linear"
-KINDS = (WINDOW, GLOBAL, LINEAR)
+WINDOW, GLOBAL, LINEAR, SSM = "window", "global", "linear", "ssm"
+KINDS = (WINDOW, GLOBAL, LINEAR, SSM)
 # A kind's section of `TransformerConfig.rope_parameters`, under the key a
 # published config.json gives it.
 ROPE_SECTION = {WINDOW: "sliding_attention", GLOBAL: "full_attention"}
@@ -104,21 +119,24 @@ MISSING = {
     # The prefix programs install one (L, Sp, KVH, Dh) block of keys and
     # values a layer. A window layer's ring holds a slot's last rows at
     # `position mod rows`, not a prefix at [0, Sp): sharing it needs a
-    # layout of its own. A linear layer's state behind a prefix is a
-    # snapshot a registered prefix would have to carry and a suffix
-    # would have to start from.
+    # layout of its own. A linear or state-space layer's state behind a
+    # prefix is a snapshot a registered prefix would have to carry and a
+    # suffix would have to start from.
     "suffix": "prefix sharing (prefill_suffix_*, first_token_suffix_*, "
               "compute_prefix_kv) is not written for a windowed cache or "
-              "for a recurrent state, whose value behind the prefix a "
-              "registered prefix would have to carry (models/periodic.py)",
+              "for a recurrent state (the delta rule's, the selective "
+              "scan's with its convolution's tail), whose value behind the "
+              "prefix a registered prefix would have to carry "
+              "(models/periodic.py)",
     "param_logical_axes": "the period stack has no sharding rules yet: it "
                           "is served on one chip (models/periodic.py)",
     "forward_train": "the period stack is served only (models/generate.py): "
                      "training lacks a dropless routed layer under "
                      "autodiff (moe_ffn drops tokens over capacity), the "
-                     "backward of windowed flash attention and of the "
-                     "delta rule's chunked scan, and the load-balancing "
-                     "update of the selection bias",
+                     "backward of windowed flash attention, of the delta "
+                     "rule's chunked scan and of the selective scan's "
+                     "kernel, and the load-balancing update of the "
+                     "selection bias",
 }
 # What a configuration with `block_length` lacks of this stack, and one
 # without it of the block walk.
@@ -150,14 +168,31 @@ def layer_plan(cfg: TransformerConfig) -> List[Group]:
     return plan
 
 
+def _other_kind(cfg: TransformerConfig) -> str:
+    """The kind of a period's layers other than its global one, and of
+    the leading layers."""
+    return cfg.period_form.recurrent or (
+        WINDOW if cfg.sliding_window else GLOBAL)
+
+
+def _period_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The kinds of a period's layers, in order."""
+    other, at = _other_kind(cfg), global_place(cfg)
+    return (other,) * at + (GLOBAL,) \
+        + (other,) * (cfg.global_attn_every - 1 - at)
+
+
+def global_place(cfg: TransformerConfig) -> int:
+    """Where in its period the global layer stands: the form's place (0
+    opens the period, -1 closes it) or the configuration's."""
+    at = cfg.period_form.global_at
+    return (cfg.attn_layer_offset if at is None else at) \
+        % cfg.global_attn_every
+
+
 def step_kinds(cfg: TransformerConfig) -> List[Tuple[str, ...]]:
     """The kinds of a scan step's layers, a group of `layer_plan`."""
-    form = cfg.period_form
-    other = LINEAR if form.linear else \
-        WINDOW if cfg.sliding_window else GLOBAL
-    others = (other,) * (cfg.global_attn_every - 1)
-    period = (GLOBAL,) + others if form.global_first else others + (GLOBAL,)
-    return [(other,) if group.key == DENSE else period
+    return [(_other_kind(cfg),) if group.key == DENSE else _period_kinds(cfg)
             for group in layer_plan(cfg)]
 
 
@@ -167,12 +202,14 @@ def routed_layers(cfg: TransformerConfig) -> int:
 
 
 def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
-    """How many layers keep each kind of state (`linear` where the form
-    has such layers)."""
+    """How many layers keep each kind of state (a recurrent kind where
+    the form has such layers)."""
+    recurrent = cfg.period_form.recurrent
     return {kind: sum(group.lead[0] * kinds.count(kind)
                       for group, kinds in zip(layer_plan(cfg),
                                               step_kinds(cfg)))
-            for kind in (KINDS if cfg.period_form.linear else KINDS[:2])}
+            for kind in (WINDOW, GLOBAL) + ((recurrent,) if recurrent
+                                            else ())}
 
 
 # ---------------------------------------------------------------------------
@@ -192,15 +229,20 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
         attn["wg"] = (d, q)
     if form.post_norms:
         shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
-    if form.linear:
+    ffn = stackparts.ffn_shapes(cfg, routed, form.router_bias)
+    if form.recurrent:
         # Two kinds of attention leaves a period: a layer's under its
-        # kind and its place among the period's layers of the kind.
-        shapes[GLOBAL + "0"] = attn
+        # kind and its place among the period's layers of the kind. A
+        # dense FFN's matrices lie with them (a routed one's experts are
+        # never scanned): a step cuts what is stacked over its layers out
+        # of the stack by a copy, 0.13 GB a matrix a layer at 2560 x 8192.
+        own = {} if routed else ffn
+        shapes[GLOBAL + "0"] = {**attn, **own}
         for n in range(cfg.global_attn_every - 1):
-            shapes[f"{LINEAR}{n}"] = _linear_shapes(cfg)
-    else:
-        shapes.update(attn)
-    return {**shapes, **stackparts.ffn_shapes(cfg, routed, form.router_bias)}
+            shapes[f"{form.recurrent}{n}"] = {
+                **_RECURRENT[form.recurrent].shapes(cfg), **own}
+        return {**shapes, **ffn} if routed else shapes
+    return {**shapes, **attn, **ffn}
 
 
 def _linear_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
@@ -214,23 +256,45 @@ def _linear_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
         "g_b": (D, H * D), "g_bias": (H * D,), "o_norm": (D,)}
 
 
+def _ssm_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
+    """A state-space layer's leaves; `A_log` lies as the state does, a
+    coordinate a row."""
+    d, C, N = cfg.d_model, cfg.mamba_d_inner, cfg.mamba_d_state
+    R, K = cfg.mamba_dt_rank, cfg.mamba_d_conv
+    shapes = {"w_in": (d, 2 * C), "conv": (K, C), "w_x": (C, R + 2 * N),
+              "dt_norm": (R,), "b_norm": (N,), "c_norm": (N,),
+              "w_dt": (R, C), "dt_bias": (C,), "A_log": (N, C), "D": (C,),
+              "wo": (C, d)}
+    if cfg.mamba_conv_bias:
+        shapes["conv_bias"] = (C,)
+    return shapes
+
+
 def _uniform(lo: float, hi: float, of=lambda u: u):
     return lambda key, shape: of(jax.random.uniform(
         key, shape, jnp.float32, lo, hi))
 
 
-# A linear layer's leaves that a scaled normal would make degenerate
+# A recurrent layer's leaves that a scaled normal would make degenerate
 # (a decay of a half a token everywhere): drawn as the published
-# implementation initialises them. exp(A_log) uniform in [1, 16]; the
-# decay's time step log-uniform in [0.001, 0.1], `dt_bias` its inverse
-# softplus; the convolution uniform in +-1/sqrt(its 4 inputs); no bias
-# on the output gate.
-_DRAWS = {
-    "A_log": _uniform(1.0, 16.0, jnp.log),
+# implementations initialise them. The decay's time step log-uniform in
+# [0.001, 0.1], `dt_bias` its inverse softplus, and the convolution
+# uniform in +-1/sqrt(its 4 inputs), both kinds. A linear layer:
+# exp(A_log) uniform in [1, 16]; no bias on the output gate. A
+# state-space layer: exp(A_log) = 1 .. d_state down a channel's
+# coordinates, the skip `D` one.
+_STEP_DRAWS = {
     "dt_bias": _uniform(math.log(0.001), math.log(0.1),
                         lambda u: jnp.log(jnp.expm1(jnp.exp(u)))),
     "conv": _uniform(-0.5, 0.5),
-    "g_bias": lambda key, shape: jnp.zeros(shape, jnp.float32),
+}
+_DRAWS = {
+    LINEAR: {**_STEP_DRAWS, "A_log": _uniform(1.0, 16.0, jnp.log),
+             "g_bias": lambda key, shape: jnp.zeros(shape, jnp.float32)},
+    SSM: {**_STEP_DRAWS,
+          "A_log": lambda key, shape: jnp.broadcast_to(jnp.log(jnp.arange(
+              1, shape[-2] + 1, dtype=jnp.float32))[:, None], shape),
+          "D": lambda key, shape: jnp.ones(shape, jnp.float32)},
 }
 
 
@@ -240,7 +304,7 @@ def num_params(cfg: TransformerConfig) -> int:
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes,
-                                  _DRAWS if cfg.period_form.linear else None)
+                                  _DRAWS.get(cfg.period_form.recurrent))
 
 
 def cache_terms(cfg: TransformerConfig) -> int:
@@ -264,16 +328,22 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
         return wsc(z, ("layers", None, None, "act_kv_heads", None))
 
     ring = min(cfg.sliding_window, max_seq_len)
-    H, D = cfg.linear_n_heads, cfg.linear_head_dim
+    s = tails = None
+    if LINEAR in n:
+        H, D = cfg.linear_n_heads, cfg.linear_head_dim
+        s = jnp.zeros((n[LINEAR], num_slots, H, D, D), jnp.float32)
+        tails = jnp.zeros((n[LINEAR], num_slots, cfg.linear_conv_kernel - 1,
+                           3 * H * D), cfg.dtype)
+    if SSM in n:
+        s = jnp.zeros((n[SSM], num_slots, cfg.mamba_d_state,
+                       cfg.mamba_d_inner), jnp.float32)
+        tails = jnp.zeros((n[SSM], num_slots, cfg.mamba_d_conv - 1,
+                           cfg.mamba_d_inner), cfg.dtype)
     return KVCache(
         k=zeros(n[GLOBAL], max_seq_len), v=zeros(n[GLOBAL], max_seq_len),
         seq_lens=jnp.zeros((num_slots,), jnp.int32),
         kw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
-        vw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
-        s=jnp.zeros((n[LINEAR], num_slots, H, D, D), jnp.float32)
-        if LINEAR in n else None,
-        tails=jnp.zeros((n[LINEAR], num_slots, cfg.linear_conv_kernel - 1,
-                         3 * H * D), cfg.dtype) if LINEAR in n else None)
+        vw=zeros(n[WINDOW], ring) if n[WINDOW] else None, s=s, tails=tails)
 
 
 # ---------------------------------------------------------------------------
@@ -285,7 +355,8 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     """One layer on x (B, S, D) in the activation dtype. `rope`: {kind:
     (sin, cos)} for the kinds that rotate (`rope_by_kind`). `attend(kind,
     q, k, v, state) -> (out (B, S, H, Dh), state)` does the attention and
-    whatever it keeps of k and v (a linear layer's: `_linear_half`).
+    whatever it keeps of k and v (a linear layer's: `_linear_half`; a
+    state-space layer's: `_ssm_half`).
     `experts_at`, `rows`: as `ffn_half` takes them. Returns (x, state,
     routing stats, experts chosen (B*S, K) or None)."""
     B, S, _ = x.shape
@@ -297,9 +368,9 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
     # where it enters the next product or the cache (float32: never).
     h = _norm(x, lp["attn_norm"], eps).astype(dt)
-    if kind == LINEAR:
+    if kind in _RECURRENT:
         with jax.named_scope("attn_" + kind):
-            branch, state = _linear_half(cfg, lp, h, attend, state)
+            branch, state = _RECURRENT[kind].half(cfg, lp, h, attend, state)
     else:
         q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
         k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
@@ -355,20 +426,26 @@ def _linear_half(cfg: TransformerConfig, lp, h, attend, state):
     return _dot(o.reshape(B, S, H * D).astype(dt), lp["wo"]), state
 
 
+def _causal_conv(window, conv):
+    """A depthwise convolution over the last K positions: `window` (B,
+    K - 1 + S, C), the inputs with the K - 1 positions before them,
+    `conv` (K, C) -> (B, S, C) float32."""
+    K = conv.shape[0]
+    S = window.shape[1] - (K - 1)
+    return sum(conv[i].astype(jnp.float32)
+               * window[:, i:i + S].astype(jnp.float32) for i in range(K))
+
+
 def _linear_core(cfg: TransformerConfig, window, conv):
     """The convolution, the activation and the heads' norms: `window`
     (B, conv - 1 + S, 3 x H x dk), the projections with the positions
     before them -> q, k, v (B, S, H, dk) float32, q and k l2-normalised
     and q scaled by dk^-0.5."""
     B = window.shape[0]
-    K = conv.shape[0]
-    S = window.shape[1] - (K - 1)
+    S = window.shape[1] - (conv.shape[0] - 1)
     H, D = cfg.linear_n_heads, cfg.linear_head_dim
-    f32 = jnp.float32
-    y = sum(conv[i].astype(f32) * window[:, i:i + S].astype(f32)
-            for i in range(K))
-    q, k, v = (a.reshape(B, S, H, D)
-               for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+    q, k, v = (a.reshape(B, S, H, D) for a in jnp.split(
+        jax.nn.silu(_causal_conv(window, conv)), 3, axis=-1))
 
     def unit(a):
         return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
@@ -387,6 +464,55 @@ def _linear_tile(cfg: TransformerConfig, mix, conv, gates, lengths=None):
     with jax.named_scope("kda_scan"):
         o, last = delta_rule.chunk_scan(q, k, v, *gates, lengths)
     return o, last, window
+
+
+def _ssm_half(cfg: TransformerConfig, lp, h, attend, state):
+    """The mixer of a state-space layer on its normed input h (B, S, D)
+    in the activation dtype -> (the branch (B, S, D) float32, state).
+    `attend(SSM, mix, lp, None, state) -> (y (B, S, channels) float32,
+    state)` owns the convolution's tails and the recurrent state: `mix`
+    the input projection's first half before its convolution (`_ssm_core`
+    is what every one of them runs on the positions it has gathered), `y`
+    the scan's output with the skip `D u`."""
+    C = cfg.mamba_d_inner
+    xz = _dot(h, lp["w_in"])
+    y, state = attend(SSM, xz[..., :C].astype(cfg.dtype), lp, None, state)
+    gated = y * jax.nn.silu(xz[..., C:])
+    return _dot(gated.astype(cfg.dtype), lp["wo"]), state
+
+
+def _ssm_core(cfg: TransformerConfig, lp, window):
+    """The convolution, the activation and what a token makes of itself:
+    `window` (B, conv - 1 + S, channels), the projection with the
+    positions before it -> u (B, S, channels), the step dt (B, S,
+    channels) and B, C (B, S, d_state), float32, with `A` (d_state,
+    channels) and the skip `D u`."""
+    R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
+    dt, f32, eps = cfg.dtype, jnp.float32, cfg.norm_eps
+    y = _causal_conv(window, lp["conv"])
+    if cfg.mamba_conv_bias:
+        y = y + lp["conv_bias"].astype(f32)
+    u = jax.nn.silu(y)
+    x = _dot(u.astype(dt), lp["w_x"])
+    r = _norm(x[..., :R], lp["dt_norm"], eps)
+    step = jax.nn.softplus(_dot(r.astype(dt), lp["w_dt"])
+                           + lp["dt_bias"].astype(f32))
+    return (u, step, _norm(x[..., R:R + N], lp["b_norm"], eps),
+            _norm(x[..., R + N:], lp["c_norm"], eps),
+            -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32) * u)
+
+
+def _ssm_tile(cfg: TransformerConfig, mix, lp, _, lengths=None):
+    """A tile's selective scan from a zero state: (y (B, S, channels)
+    float32, the state behind each row's last real position, the
+    projection behind conv - 1 zero positions)."""
+    from ..ops import selective_scan
+
+    window = jnp.pad(mix, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
+    u, step, b, c, A, skip = _ssm_core(cfg, lp, window)
+    with jax.named_scope("ssm_scan"):
+        y, last = selective_scan.scan(step, u, b, c, A, lengths)
+    return y + skip, last, window
 
 
 def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
@@ -442,7 +568,7 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
                 **weights[f"{kind}{kinds[i][:j].count(kind)}"]}
 
     return stackparts.run(cfg, params, plan, x, layer_at, state,
-                          leaves_at if cfg.period_form.linear else None)
+                          leaves_at if cfg.period_form.recurrent else None)
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
@@ -534,12 +660,13 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
     or past `length` holds padding, which decode overwrites before it
     reads it). A row whose slot is out of range is dropped."""
     kg, vg, kw, vw, s, tails = state
-    if kind == LINEAR:
+    if kind in _RECURRENT:
         # q, k, v: the projections, the convolution's weights and (g,
-        # beta) (`_linear_half`). The state and the projections' last
+        # beta) (`_linear_half`), or the projection and the layer's
+        # leaves (`_ssm_half`). The state and the projections' last
         # conv - 1 positions as the prompt's last token left them.
-        out, last, window = _linear_tile(cfg, q, k, v, lengths)
-        at = lengths[:, None] + jnp.arange(k.shape[0] - 1)[None, :]
+        out, last, window = _RECURRENT[kind].tile(cfg, q, k, v, lengths)
+        at = lengths[:, None] + jnp.arange(tails.shape[2])[None, :]
         tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
         return out, (kg, vg, kw, vw, s.at[l, slots].set(last, mode="drop"),
                      tails.at[l, slots].set(tail.astype(tails.dtype),
@@ -614,10 +741,43 @@ def _linear_step(cfg, live, l, mix, conv, gates, s, tails):
     return out[:, None], s, delta_rule.move_tails(tails, l, new, live)
 
 
+def _ssm_step(cfg, live, l, mix, lp, _, s, tails):
+    """One token a slot through state-space layer `l`, as `_linear_step`
+    does it: the convolution over the slot's tail and this token, one
+    update of its state (`ops/selective_scan.decode_update`), the tail
+    moved on a position."""
+    from ..ops import delta_rule, selective_scan
+
+    tail = lax.dynamic_index_in_dim(tails, l, 0, keepdims=False)
+    new = mix.astype(tail.dtype)
+    u, step, b, c, A, skip = _ssm_core(
+        cfg, lp, jnp.concatenate([tail, new], axis=1))
+    y, s = selective_scan.decode_update(s, l, step[:, 0], u[:, 0], b[:, 0],
+                                        c[:, 0], A, live)
+    return y[:, None] + skip, s, delta_rule.move_tails(tails, l, new, live)
+
+
+class _Recurrent(NamedTuple):
+    """What a kind of layer that keeps a recurrent state brings: its
+    leaves, its half of `layer`, a tile from a zero state, one token a
+    slot."""
+
+    shapes: Callable
+    half: Callable
+    tile: Callable
+    step: Callable
+
+
+_RECURRENT = {
+    LINEAR: _Recurrent(_linear_shapes, _linear_half, _linear_tile,
+                       _linear_step),
+    SSM: _Recurrent(_ssm_shapes, _ssm_half, _ssm_tile, _ssm_step)}
+
+
 def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
     kg, vg, kw, vw, s, tails = state
-    if kind == LINEAR:
-        out, s, tails = _linear_step(cfg, live, l, q, k, v, s, tails)
+    if kind in _RECURRENT:
+        out, s, tails = _RECURRENT[kind].step(cfg, live, l, q, k, v, s, tails)
         return out, (kg, vg, kw, vw, s, tails)
     attend = _attend_terms if cache_terms(cfg) == 2 else _attend_cache
     if kg.dtype != q.dtype and cache_terms(cfg) == 1:
@@ -644,8 +804,8 @@ def _block_attend(cfg, p0, live, l, kind, q, k, v, state):
 
 
 def _free_attend(cfg, l, kind, q, k, v, state):
-    if kind == LINEAR:
-        return _linear_tile(cfg, q, k, v)[0], state
+    if kind in _RECURRENT:
+        return _RECURRENT[kind].tile(cfg, q, k, v)[0], state
     return _flash(cfg, kind, q, k, v), state
 
 
@@ -684,13 +844,13 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
     D), the experts every routed layer chose: see `stackparts.run`). A
-    tile of several long rows through linear layers runs a row at a
+    tile of several long rows through recurrent layers runs a row at a
     time: a linear layer's projections are 6 x d_model wide in float32
     between its products, a queue-side tile of 4 x 2,048 held 3.9 GB of
     them beside the weights and a cache it does not touch, and a row of
     `_ROW_ALONE` positions fills the chip's multipliers alone."""
     W, S = tokens.shape
-    if cfg.period_form.linear and W > 1 and S >= _ROW_ALONE:
+    if cfg.period_form.recurrent and W > 1 and S >= _ROW_ALONE:
         x, chosen = lax.map(
             lambda row: forward_free(cfg, params, row[None]), tokens)
         return x[:, 0], jax.tree.map(

@@ -74,7 +74,7 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
                           "mellum": "periodic", "pangu_ultra_moe": "latent",
                           "sdar_moe": "periodic", "glm_moe_dsa": "latent",
-                          "solar_open2": "periodic"}
+                          "solar_open2": "periodic", "jamba": "periodic"}
 
 # How a block of `TransformerConfig.block_length` positions is unmasked
 # (models/generate.py, `_unmask`): the names a request or a configuration
@@ -100,8 +100,10 @@ class PeriodForm:
     router_bias: bool     # a per-expert bias added for the selection only
     rotary: Tuple[str, ...]
     qk_norm: bool = True          # a learned norm over each head of q and k
-    linear: bool = False          # a period's other layers: linear attention
-    global_first: bool = False    # the global layer opens its period
+    recurrent: str = ""           # a period's other layers: "linear" | "ssm"
+    # The global layer's place in its period: 0 opens it, -1 closes it;
+    # None: the configuration says (`TransformerConfig.attn_layer_offset`).
+    global_at: Optional[int] = -1
 
 
 # `TransformerConfig.arch` -> its layer, for the architectures of STACKS
@@ -126,7 +128,14 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     # every layer routed, a selection bias.
     "solar_open2": PeriodForm(attn_gate=True, post_norms=False,
                               embed_scale=False, router_bias=True, rotary=(),
-                              qk_norm=False, linear=True, global_first=True),
+                              qk_norm=False, recurrent="linear", global_at=0),
+    # AI21 Jamba: a softmax layer with no position, no gate and no q/k
+    # norm at the place of its period the configuration names, Mamba
+    # layers around it; the configuration says whether the FFNs are
+    # routed (Jamba2-3B: none is).
+    "jamba": PeriodForm(attn_gate=False, post_norms=False, embed_scale=False,
+                        router_bias=False, rotary=(), qk_norm=False,
+                        recurrent="ssm", global_at=None),
 }
 
 
@@ -231,11 +240,13 @@ class TransformerConfig:
     # stack with another layer (LATENT_FORMS: two norms, a selection
     # bias) and the sparse-attention indexer (`index_topk`).
     # "solar_open2": the period stack again, a global layer then
-    # linear-attention layers (`linear_n_heads`, at the end). STACKS above
+    # linear-attention layers (`linear_n_heads`, at the end). "jamba":
+    # the period stack with state-space layers (`mamba_d_state`, at the
+    # end) around a global layer at `attn_layer_offset`. STACKS above
     # holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
-    global_attn_every: int = 0       # period length; its last layer is global
+    global_attn_every: int = 0       # period length: one layer of it is global
     sliding_window: int = 0          # keys a window layer sees (0 = all)
     moe_d_ff: int = 0                # width of a routed / shared expert
     moe_shared_experts: int = 0      # always-on experts of width moe_d_ff
@@ -295,6 +306,20 @@ class TransformerConfig:
     linear_n_heads: int = 0
     linear_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # State-space layers (models/periodic.py, a form with `recurrent`
+    # "ssm"), under the published keys: `mamba_expand` x d_model channels,
+    # each keeping `mamba_d_state` float32 coordinates a slot (the
+    # selective scan, `ops/selective_scan`) behind a causal depthwise
+    # convolution over the last `mamba_d_conv` positions; the step a
+    # channel comes through rank `mamba_dt_rank`. `attn_layer_offset`:
+    # the place of such a period's global layer.
+    mamba_d_state: int = 0
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    attn_layer_offset: int = 0
     # The dtype a period stack caches keys and values in (a name or a
     # type; None = `dtype`, or, float32 activations on bf16 weights, two
     # bf16 terms a row). bfloat16 under float32 activations: one bf16
@@ -329,16 +354,36 @@ class TransformerConfig:
         if self.cache_dtype is not None and STACKS[self.arch] != "periodic":
             raise ValueError("cache_dtype: only the period stack states "
                              "its rows' dtype")
-        linear = self.arch in PERIOD_FORMS and PERIOD_FORMS[self.arch].linear
-        if linear != bool(self.linear_n_heads) or (linear and (
-                self.linear_head_dim < 1 or self.linear_conv_kernel < 2
-                or self.sliding_window or self.n_dense_layers
-                or self.block_length)):
+        recurrent = PERIOD_FORMS[self.arch].recurrent \
+            if self.arch in PERIOD_FORMS else ""
+        if recurrent and (self.sliding_window or self.n_dense_layers
+                          or self.block_length):
+            raise ValueError(
+                f"{self.arch}: a period stack whose other layers keep a "
+                "recurrent state has no window, no leading dense layer and "
+                "no block walk")
+        if (recurrent == "linear") != bool(self.linear_n_heads) or (
+                self.linear_n_heads and (self.linear_head_dim < 1
+                                         or self.linear_conv_kernel < 2)):
             raise ValueError(
                 f"linear_n_heads {self.linear_n_heads}: a period stack whose "
                 "form has linear layers, and no other, has linear_n_heads "
-                "heads of linear_head_dim, a convolution of 2 positions or "
-                "more, no window, no leading dense layer and no block walk")
+                "heads of linear_head_dim and a convolution of 2 positions "
+                "or more")
+        if (recurrent == "ssm") != bool(self.mamba_d_state) or (
+                self.mamba_d_state and (
+                    self.mamba_dt_rank < 1 or self.mamba_d_conv < 2
+                    or self.mamba_expand < 1 or self.mamba_proj_bias
+                    or not 0 <= self.attn_layer_offset
+                    < self.global_attn_every)):
+            raise ValueError(
+                f"mamba_d_state {self.mamba_d_state}: a period stack whose "
+                "form has state-space layers, and no other, has "
+                "mamba_d_state coordinates a channel, a step through "
+                "mamba_dt_rank, a convolution of 2 positions or more, "
+                "projections with no bias (mamba_proj_bias is not written) "
+                "and its global layer at attn_layer_offset < "
+                "global_attn_every")
         if self.index_topk:
             if STACKS[self.arch] != "latent" or self.index_n_heads < 1 \
                     or self.index_head_dim < self.qk_rope_head_dim \
@@ -382,6 +427,10 @@ class TransformerConfig:
     @property
     def period_form(self) -> PeriodForm:
         return PERIOD_FORMS[self.arch]
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.d_model
 
     @property
     def latent_form(self) -> LatentForm:

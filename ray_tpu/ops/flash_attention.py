@@ -734,7 +734,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd_rule)
 def _pick_block(s: int, target: int) -> int:
     """Largest block <= target the TPU lowering accepts for a dimension
     of s rows: all of s, or a multiple of 8 rows that divides s (bf16
-    compiles at 8 as well; checked by tests/test_tpu_aot_compile.py).
+    compiles at 8 as well; checked by tests/test_aot_tpu_compile.py).
     0 when there is none."""
     if s <= target:
         return s

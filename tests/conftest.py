@@ -73,6 +73,9 @@ def pytest_collection_finish(session):
             # tests/benchmark/test_solar_cell.py makes this one's.
             tiny.setdefault("solar-open2-rollout-closed",
                             "tiny-solar-closed")
+            # tests/benchmark/test_jamba_cell.py makes this one's.
+            tiny.setdefault("jamba2-reason-wide-closed",
+                            "tiny-jamba-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -86,7 +89,8 @@ def _per_layer():
 def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
     test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
-    test_sdar_cell.py, test_glm_cell.py, test_solar_cell.py) names
+    test_sdar_cell.py, test_glm_cell.py, test_solar_cell.py,
+    test_jamba_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(

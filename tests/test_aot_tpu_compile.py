@@ -6,6 +6,13 @@ block that is not aligned to the tiling, too much fast memory, an eager
 op on a device that is not there. These compiles can, at no chip time.
 Nothing runs, so nothing here says anything about results or speed.
 Skipped as a whole where the topology cannot be described.
+
+One file, so that one process loads the TPU's compiler at a time: split a
+family a file under six `--dist loadfile` workers, a compile aborted inside
+libtpu and took its worker down (my run, PR 50). Named to sort early: the
+file is ten minutes of one worker, and `loadfile` hands files out in
+order, so as `test_tpu_aot_compile.py` it began last and every other
+worker waited for it.
 """
 
 import dataclasses
@@ -1356,3 +1363,161 @@ def test_solar_tiles_fit_beside_weights_states_and_rows(
     assert "kda_scan" in text and "attn_linear" in text
     assert "moe_experts/while/body/jit(gmm)" in text and "ragged-dot" not in text
     _solar_fits(serve_solar, mem, record_property, cached)
+
+
+@pytest.fixture(scope="module")
+def serve_jamba(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "jamba2-reason-wide-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("jamba2-3b", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+def _jamba_fits(serve_jamba, mem, record_property, cached=True):
+    cfg, slots, one, key, params, cache = serve_jamba
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    # 6.06 GB of weights, whole, beside 256 slots' states (26 Mamba
+    # layers x 16 x 5,120 float32: 2.18 GB), their convolutions' tails
+    # (26 x 3 x 5,120 bf16: 0.20) and two MQA layers' 5,120 rows of one
+    # KV head x 128, keys and values (1.34).
+    assert 6.05e9 < weights < 6.07e9 and slots == 256
+    assert cfg.dtype == cfg.param_dtype == jnp.bfloat16
+    assert (cache.s.shape, cache.s.dtype) == ((26, 256, 16, 5120),
+                                              jnp.float32)
+    assert (cache.tails.shape, cache.tails.dtype) == ((26, 256, 3, 5120),
+                                                     jnp.bfloat16)
+    assert cache.k.shape == cache.v.shape == (2, 256, 5120, 1, 128)
+    assert cache.k.dtype == jnp.bfloat16 and cache.kw is None
+    held = sum(x.size * x.dtype.itemsize
+               for x in (cache.s, cache.tails, cache.k, cache.v))
+    assert 3.72e9 < held < 3.74e9
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    if cached:
+        # States, tails, keys and values aliased: no program copies one
+        # in or out.
+        assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + (0 if cached else held) < 15.75e9
+
+
+def test_jamba_decode_block_updates_states_and_rows_in_place(
+        serve_jamba, as_on_the_chip, record_property):
+    """`decode_multi` (k = 64, the engine's largest block) at the cell's
+    256 slots x 5,120: the Mamba layers' update under `attn_ssm` as the
+    kernel of `ops/selective_scan` beside the tails' (the states and the
+    convolutions' tails aliased in and out), the two MQA layers' rows
+    through the decode kernel (20 query heads under one KV head); a
+    period a scan step, so thirteen bodies of each state kernel a
+    program; no stacked leaf is copied out of its stack (a period's
+    SwiGLUs stacked over its layers and cut out by the step were 2.0 GB
+    of temporaries more: they lie a layer under its place)."""
+    from ray_tpu.models.generate import decode_multi
+
+    cfg, slots, one, key, params, cache = serve_jamba
+    toks = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    temps = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one)
+    compiled = decode_multi.lower(cfg, params, cache, toks, temps, 64, 0,
+                                  key, live).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert '"kernel":"ssm_update"' in text and '"kernel":"kda_tails"' in text
+    assert '"kernel":"decode_attn"' in text
+    for scope in ("attn_ssm", "attn_global"):
+        assert scope in text, scope
+    assert "attn_linear" not in text and "moe_experts" not in text
+    assert text.count('"kernel":"kda_tails"') == 13    # a body a place
+    _jamba_fits(serve_jamba, mem, record_property)
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("program", ["prefill_sample_batch",
+                                     "first_token_sample"])
+def test_jamba_tiles_fit_beside_weights_states_and_rows(
+        serve_jamba, as_on_the_chip, record_property, program):
+    """The longest bucket's admission tile (1,024: the scan's kernel
+    under `ssm_scan`; the MQA layers attend through XLA, a tile of 1,024
+    lies under the flash kernel's crossover) and the queue-side tile of
+    that bucket, which runs beside the cache and not through it, its
+    rows walked singly."""
+    from ray_tpu.models import generate
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_jamba
+    cached = program == "prefill_sample_batch"
+    bucket = 1024
+    rows = LLMEngine._tile_rows(bucket) if cached \
+        else LLMEngine._queue_tile_rows(bucket)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    tile, n, temps = (arr((rows, bucket), jnp.int32), arr((rows,), jnp.int32),
+                      arr((rows,), jnp.float32))
+    if cached:
+        lowered = generate.prefill_sample_batch.lower(
+            cfg, params, cache, tile, n, n, 0, temps, key)
+    else:
+        lowered = generate.first_token_sample.lower(
+            cfg, params, tile, n, temps, 0, key)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert '"kernel":"ssm_scan"' in text and "attn_ssm/ssm_scan" in text
+    assert "ssm_update" not in text
+    _jamba_fits(serve_jamba, mem, record_property, cached)
+
+
+def test_the_scans_kernels_compile_alone_at_the_cells_widths(topo):
+    """A row of 2,048 positions x 5,120 channels from a carried state, and
+    one position of 256 slots (the issue's width) against layer `l` of 26,
+    the states aliased."""
+    from ray_tpu.ops import selective_scan as ss
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    B, S, C, N, L, slots = 1, 2048, 5120, 16, 26, 256
+    text = jax.jit(ss._scan_pallas).lower(
+        arr(B, S, C), arr(B, S, C), arr(B, S, N), arr(B, S, N), arr(N, C),
+        arr(B, N, C)).compile().as_text()
+    assert '"kernel":"ssm_scan"' in text
+    compiled = jax.jit(ss._update_pallas, donate_argnums=0).lower(
+        arr(L, slots, N, C), arr(dtype=jnp.int32), arr(slots, C),
+        arr(slots, C), arr(slots, N), arr(slots, N), arr(N, C),
+        arr(slots, dtype=jnp.bool_)).compile()
+    assert '"kernel":"ssm_update"' in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= L * slots * N * C * 4
+
+
+def test_one_kv_head_under_twenty_compiles_through_both_attention_kernels(
+        topo):
+    """MQA, 20 query heads of 128 over one KV head: the decode kernel at
+    (2 layers, 256 slots, 5,120 rows), which `usable()` takes
+    (`block_rows` 256), and the flash forward at a tile of 2,048, the
+    first bucket at its crossover."""
+    from ray_tpu.ops import decode_attention as da
+
+    one = SingleDeviceSharding(topo.devices[0])
+    assert da.block_rows(5120, 128) == 256
+    q = jax.ShapeDtypeStruct((256, 1, 20, 128), jnp.bfloat16, sharding=one)
+    rows = jax.ShapeDtypeStruct((2, 256, 5120, 1, 128), jnp.bfloat16,
+                                sharding=one)
+    text = jax.jit(lambda q, k, v, l, n: da.decode_attention(
+        q, k, v, l, n)).lower(
+            q, rows, rows, jax.ShapeDtypeStruct((), jnp.int32, sharding=one),
+            jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
+    ).compile().as_text()
+    assert '"kernel":"decode_attn"' in text
+    qs = jax.ShapeDtypeStruct((1, 2048, 20, 128), jnp.bfloat16, sharding=one)
+    ks = jax.ShapeDtypeStruct((1, 2048, 1, 128), jnp.bfloat16, sharding=one)
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, interpret=False)).lower(
+            qs, ks, ks).compile().as_text()
+    assert "tpu_custom_call" in text

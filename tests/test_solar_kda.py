@@ -63,8 +63,8 @@ def _prompt(n, seed=1):
 def test_the_preset_is_the_published_shape_in_small(params):
     assert STACKS["solar_open2"] == "periodic"
     form = PERIOD_FORMS["solar_open2"]
-    assert CFG.period_form == form and form.linear and form.global_first \
-        and not form.qk_norm and form.rotary == () and form.attn_gate
+    assert CFG.period_form == form and form.recurrent == "linear" \
+        and form.global_at == 0 and not form.qk_norm and form.rotary == () and form.attn_gate
     assert periodic.layer_plan(CFG) == [("periods", (2, 4), True)]
     assert periodic.step_kinds(CFG) == [
         ("global", "linear", "linear", "linear")]

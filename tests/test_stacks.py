@@ -22,7 +22,8 @@ TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "pangu_ultra_moe": configs.tiny_pangu_test,
         "sdar_moe": configs.tiny_sdar_test,
         "glm_moe_dsa": configs.tiny_glm_test,
-        "solar_open2": configs.tiny_solar_test}
+        "solar_open2": configs.tiny_solar_test,
+        "jamba": configs.tiny_jamba_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -200,6 +201,9 @@ SEEDED = {
     # PR 46's preset, on the tree that added it (kinds of layer with
     # leaves of their own: a key a layer's dict, of it a key a leaf).
     "tiny_solar_test": "dff022f1f7c5bb71",
+    # PR 50's preset, on the tree that added it (a dense FFN's matrices
+    # under the layer's own dict).
+    "tiny_jamba_test": "b5ca64afbe25f7eb",
 }
 
 
