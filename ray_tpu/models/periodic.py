@@ -469,50 +469,59 @@ def _linear_tile(cfg: TransformerConfig, mix, conv, gates, lengths=None):
 def _ssm_half(cfg: TransformerConfig, lp, h, attend, state):
     """The mixer of a state-space layer on its normed input h (B, S, D)
     in the activation dtype -> (the branch (B, S, D) float32, state).
-    `attend(SSM, mix, lp, None, state) -> (y (B, S, channels) float32,
-    state)` owns the convolution's tails and the recurrent state: `mix`
-    the input projection's first half before its convolution (`_ssm_core`
-    is what every one of them runs on the positions it has gathered), `y`
-    the scan's output with the skip `D u`."""
+    `attend(SSM, mix, lp, z, state) -> (out (B, S, channels), state)`
+    owns the convolution's tails and the recurrent state: `mix` the
+    input projection's first half before its convolution (`_ssm_core` is
+    what every one of them runs on the positions it has gathered), `z`
+    its second half, the gate, and `out` what `wo` multiplies,
+    `ops/selective_scan.gated`: the scan's output with the skip `D u`
+    under `silu(z)` (a tile applies it in XLA, the decode step's kernel
+    in the grid step that holds the slot)."""
     C = cfg.mamba_d_inner
-    xz = _dot(h, lp["w_in"])
-    y, state = attend(SSM, xz[..., :C].astype(cfg.dtype), lp, None, state)
-    gated = y * jax.nn.silu(xz[..., C:])
-    return _dot(gated.astype(cfg.dtype), lp["wo"]), state
+    with jax.named_scope("ssm_in"):
+        xz = _dot(h, lp["w_in"])
+    out, state = attend(SSM, xz[..., :C].astype(cfg.dtype), lp, xz[..., C:],
+                        state)
+    with jax.named_scope("ssm_out"):
+        return _dot(out.astype(cfg.dtype), lp["wo"]), state
 
 
 def _ssm_core(cfg: TransformerConfig, lp, window):
     """The convolution, the activation and what a token makes of itself:
     `window` (B, conv - 1 + S, channels), the projection with the
-    positions before it -> u (B, S, channels), the step dt (B, S,
-    channels) and B, C (B, S, d_state), float32, with `A` (d_state,
-    channels) and the skip `D u`."""
+    positions before it -> u (B, S, channels), the step (B, S, channels)
+    before its bias and softplus (`ops/selective_scan.step_size`) and B,
+    C (B, S, d_state), float32, with `A` (d_state, channels)."""
     R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
     dt, f32, eps = cfg.dtype, jnp.float32, cfg.norm_eps
-    y = _causal_conv(window, lp["conv"])
-    if cfg.mamba_conv_bias:
-        y = y + lp["conv_bias"].astype(f32)
-    u = jax.nn.silu(y)
-    x = _dot(u.astype(dt), lp["w_x"])
-    r = _norm(x[..., :R], lp["dt_norm"], eps)
-    step = jax.nn.softplus(_dot(r.astype(dt), lp["w_dt"])
-                           + lp["dt_bias"].astype(f32))
-    return (u, step, _norm(x[..., R:R + N], lp["b_norm"], eps),
-            _norm(x[..., R + N:], lp["c_norm"], eps),
-            -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32) * u)
+    with jax.named_scope("ssm_conv"):
+        y = _causal_conv(window, lp["conv"])
+        if cfg.mamba_conv_bias:
+            y = y + lp["conv_bias"].astype(f32)
+        u = jax.nn.silu(y)
+    with jax.named_scope("ssm_dt"):
+        x = _dot(u.astype(dt), lp["w_x"])
+        r = _norm(x[..., :R], lp["dt_norm"], eps)
+        return (u, _dot(r.astype(dt), lp["w_dt"]),
+                _norm(x[..., R:R + N], lp["b_norm"], eps),
+                _norm(x[..., R + N:], lp["c_norm"], eps),
+                -jnp.exp(lp["A_log"].astype(f32)))
 
 
-def _ssm_tile(cfg: TransformerConfig, mix, lp, _, lengths=None):
-    """A tile's selective scan from a zero state: (y (B, S, channels)
-    float32, the state behind each row's last real position, the
-    projection behind conv - 1 zero positions)."""
+def _ssm_tile(cfg: TransformerConfig, mix, lp, z, lengths=None):
+    """A tile's selective scan from a zero state: (what `wo` multiplies
+    (B, S, channels) float32, the state behind each row's last real
+    position, the projection behind conv - 1 zero positions)."""
     from ..ops import selective_scan
 
+    f32 = jnp.float32
     window = jnp.pad(mix, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
-    u, step, b, c, A, skip = _ssm_core(cfg, lp, window)
+    u, pre, b, c, A = _ssm_core(cfg, lp, window)
+    with jax.named_scope("ssm_dt"):
+        step = selective_scan.step_size(pre, lp["dt_bias"].astype(f32))
     with jax.named_scope("ssm_scan"):
         y, last = selective_scan.scan(step, u, b, c, A, lengths)
-    return y + skip, last, window
+    return selective_scan.gated(y, u, lp["D"].astype(f32), z), last, window
 
 
 def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
@@ -741,20 +750,23 @@ def _linear_step(cfg, live, l, mix, conv, gates, s, tails):
     return out[:, None], s, delta_rule.move_tails(tails, l, new, live)
 
 
-def _ssm_step(cfg, live, l, mix, lp, _, s, tails):
-    """One token a slot through state-space layer `l`, as `_linear_step`
-    does it: the convolution over the slot's tail and this token, one
-    update of its state (`ops/selective_scan.decode_update`), the tail
-    moved on a position."""
-    from ..ops import delta_rule, selective_scan
+def _ssm_step(cfg, live, l, mix, lp, z, s, tails):
+    """One token a slot through state-space layer `l`: XLA makes what the
+    recurrence takes of the token (`_ssm_core` over the slot's tail and
+    this token), and one call does the rest a slot
+    (`ops/selective_scan.decode_update`): the step's bias and softplus,
+    one update of the state, the skip and the gate, the tail a position
+    on. A slot that is not `live` keeps state and tail as they were."""
+    from ..ops import selective_scan
 
     tail = lax.dynamic_index_in_dim(tails, l, 0, keepdims=False)
     new = mix.astype(tail.dtype)
-    u, step, b, c, A, skip = _ssm_core(
-        cfg, lp, jnp.concatenate([tail, new], axis=1))
-    y, s = selective_scan.decode_update(s, l, step[:, 0], u[:, 0], b[:, 0],
-                                        c[:, 0], A, live)
-    return y[:, None] + skip, s, delta_rule.move_tails(tails, l, new, live)
+    u, pre, b, c, A = _ssm_core(cfg, lp,
+                                jnp.concatenate([tail, new], axis=1))
+    out, s, tails = selective_scan.decode_update(
+        s, tails, l, new[:, 0], pre[:, 0], lp["dt_bias"], u[:, 0], b[:, 0],
+        c[:, 0], z[:, 0], A, lp["D"], live)
+    return out[:, None], s, tails
 
 
 class _Recurrent(NamedTuple):

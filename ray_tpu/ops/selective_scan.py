@@ -8,10 +8,12 @@ lanes), with a step `dt_t` (C,) > 0 the input chooses:
 
 `A` (N, C) < 0, `u_t` (C,) the convolved input, `B_t`, `C_t` (N,) what
 the token writes and reads. N x C independent scalar recurrences with no
-matrix form: vector-unit work in a tile, bytes in a decode step. The
-skip `D * u` and the gate `silu(z)` are the caller's (one elementwise
-pass XLA fuses into the output projection's operand). Everything here is
-float32 whatever the activations are.
+matrix form: vector-unit work in a tile, bytes in a decode step. What
+stands around it a channel is here too, one definition each for every
+walk: the step's bias and softplus (`step_size`) and the skip `D * u`
+under the gate `silu(z)` (`gated`); a tile applies them in XLA, a decode
+step in the kernel. Everything here is float32 whatever the activations
+are.
 
 One recurrence (`_advance`), two walks, as every cache has:
 
@@ -24,13 +26,18 @@ One recurrence (`_advance`), two walks, as every cache has:
   position at or past its row's length has `dt = 0`: the state passes
   through it as it came (exp(0) = 1, nothing written). Anywhere else a
   `lax.scan` a position.
-- `decode_update`: one position a slot against layer `l` of the carried
-  states (L, slots, N, C), aliased in and out. On a TPU a kernel
-  (`_update_pallas`, `ssm_update`): a grid step a slot a request owns
-  (the work list `delta_rule._owned` makes on the device), so a state
-  moves once in and once out and a slot nobody owns is neither read nor
-  written. Anywhere else the same update in XLA, such a slot keeping its
-  state bit for bit.
+- `decode_update`: one position a slot through layer `l` of the carried
+  states (L, slots, N, C) and of the convolutions' tails (L, slots, K -
+  1, C), both aliased in and out. On a TPU a kernel (`_update_pallas`,
+  `ssm_update`): a grid step a slot a request owns (the work list
+  `delta_rule._owned` makes on the device), and what the token does a
+  channel once `u` is known happens in that step, beside the 655 KB of
+  state it moves: the step's bias and softplus, the update, the skip
+  and the gate written as the one row the output projection multiplies,
+  the tail a position on. A state moves once in and once out, the rows
+  come as XLA's products left them, and a slot nobody owns is neither
+  read nor written. Anywhere else the same in XLA, such a slot keeping
+  state and tail bit for bit.
 
 XLA's own forms do not serve a tile at a serving width: an associative
 scan materialises (S, C, N) float32, 671 MB an array a layer at 2,048
@@ -169,29 +176,63 @@ def scan(dt, u, Bm, Cm, A, lengths=None, state=None,
 # One position a slot
 # ---------------------------------------------------------------------------
 
-def _update_kernel(l_ref, n_ref, slot_ref, du_ref, bc_ref, a_ref, s_ref,
-                   y_ref, out_ref):
-    """One grid step: the state of one owned slot. `du_ref` (2, C): the
-    slot's dt and u; `bc_ref` (N, 2): its B and C as columns."""
+def step_size(pre, bias):
+    """The step the input chooses, `dt = softplus(pre + bias)` > 0: `pre`
+    the step's projection, `bias` a channel; float32."""
+    return jax.nn.softplus(pre + bias)
+
+
+def gated(y, u, D, z):
+    """What leaves the mixer for its output projection: the scan's `y`
+    with the skip `D u`, under the gate `silu(z)`; float32."""
+    return (y + D * u) * jax.nn.silu(z)
+
+
+def _column(row, n: int):
+    """row (1, n) -> (n, 1): a value a sublane from a value a lane, by a
+    mask and a sum over lanes (a transpose would want a whole tile)."""
+    at = lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+        == lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(at, row, 0.0), axis=1, keepdims=True)
+
+
+def _update_kernel(l_ref, n_ref, slot_ref, new_ref, pre_ref, u_ref, b_ref,
+                   c_ref, z_ref, a_ref, bias_ref, d_ref, s_ref, tail_ref,
+                   o_ref, out_ref, moved_ref):
+    """One grid step: everything an owned slot's token does a channel
+    once `u` is known. `new_ref`, `pre_ref`, `u_ref`, `z_ref` (1, C): the
+    token's input, its step before the bias and the softplus, its
+    convolved input and its gate; `b_ref`, `c_ref` (1, N) as they leave
+    their norms; `tail_ref` (K - 1, C) the slot's last inputs, oldest
+    first. `o_ref` lies where `new_ref`'s array does: a slot's row is
+    read before it is written."""
     t = pl.program_id(0)
+    held = tail_ref.shape[0]
 
     @pl.when(t < n_ref[0])
     def _update():
-        bc = bc_ref[...]
-        out_ref[...], y_ref[...] = _advance(
-            s_ref[...], du_ref[0:1, :], du_ref[1:2, :], a_ref[...],
-            bc[:, 0:1], bc[:, 1:2])
+        N = a_ref.shape[0]
+        new, u = new_ref[...], u_ref[...]
+        out_ref[...], y = _advance(
+            s_ref[...], step_size(pre_ref[...], bias_ref[...]), u,
+            a_ref[...], _column(b_ref[...], N), _column(c_ref[...], N))
+        o_ref[...] = gated(y, u, d_ref[...], z_ref[...]).astype(o_ref.dtype)
+        moved_ref[:held - 1, :] = tail_ref[1:, :]
+        moved_ref[held - 1:, :] = new
 
     @pl.when(t >= n_ref[0])
     def _nobody():
         # The one step a grid has when no slot is owned: as it was.
         out_ref[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+        moved_ref[...] = tail_ref[...]
+        o_ref[...] = new_ref[...]
 
 
-def _update_pallas(states, l, dt, u, Bm, Cm, A, live, interpret=False):
+def _update_pallas(states, tails, l, new, pre, bias, u, Bm, Cm, z, A, D,
+                   live, interpret=False):
     _, B, N, C = states.shape
-    live, slots, n = _owned(live, B)
+    held = tails.shape[2]
+    _, slots, n = _owned(live, B)
 
     def own(t, l_ref, n_ref, slot_ref):
         return (slot_ref[t], 0, 0)
@@ -199,51 +240,72 @@ def _update_pallas(states, l, dt, u, Bm, Cm, A, live, interpret=False):
     def where_it_lies(t, l_ref, n_ref, slot_ref):
         return (l_ref[0], slot_ref[t], 0, 0)
 
-    y, states = pl.pallas_call(
+    row = pl.BlockSpec((None, 1, C), own)
+    column = pl.BlockSpec((None, 1, N), own)
+    channel = pl.BlockSpec((1, C), lambda t, *_: (0, 0))
+    state = pl.BlockSpec((None, None, N, C), where_it_lies)
+    tail = pl.BlockSpec((None, None, held, C), where_it_lies)
+    out, states, tails = pl.pallas_call(
         _update_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(jnp.maximum(n, 1),),
-            in_specs=[
-                pl.BlockSpec((None, 2, C), own),
-                pl.BlockSpec((None, N, 2), own),
-                pl.BlockSpec((N, C), lambda t, *_: (0, 0)),
-                pl.BlockSpec((None, None, N, C), where_it_lies)],
-            out_specs=[
-                pl.BlockSpec((None, 1, C), own),
-                pl.BlockSpec((None, None, N, C), where_it_lies)]),
-        out_shape=[jax.ShapeDtypeStruct((B, 1, C), jnp.float32),
-                   jax.ShapeDtypeStruct(states.shape, jnp.float32)],
-        input_output_aliases={6: 1},
+            in_specs=[row, row, row, column, column, row,
+                      pl.BlockSpec((N, C), lambda t, *_: (0, 0)), channel,
+                      channel, state, tail],
+            out_specs=[row, state, tail]),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, C), new.dtype),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+        # A slot nobody owns is given no step: its state and tail stay
+        # what they were, and its row of the output, which lies where
+        # `new` did, is the row of `new` it came with: finite, nobody's.
+        input_output_aliases={3: 0, 12: 1, 13: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=bool(interpret),
         metadata={"kernel": "ssm_update"},
     )(jnp.reshape(l, (1,)).astype(jnp.int32), jnp.reshape(n, (1,)), slots,
-      jnp.stack([dt, u], axis=1), jnp.stack([Bm, Cm], axis=-1), A, states)
-    # A slot nobody owns was given no step: its row of `y` was never
-    # written.
-    return jnp.where(live[:, None], y[:, 0], 0.0), states
+      new[:, None], pre[:, None], u[:, None], Bm[:, None], Cm[:, None],
+      z[:, None], A, bias[None], D[None], states, tails)
+    return out[:, 0], states, tails
 
 
-def decode_update(states, l, dt, u, Bm, Cm, A,
+def decode_update(states, tails, l, new, pre, bias, u, Bm, Cm, z, A, D,
                   live: Optional[jax.Array] = None,
                   interpret: Optional[bool] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """One position a slot against layer `l` of the carried `states` (L,
-    B, N, C) float32: dt, u (B, C), Bm, Cm (B, N), A (N, C) -> (y (B, C)
-    float32, states'). A slot that is not `live` (B,) keeps the state it
-    had, bit for bit (None: every slot is owned); its `y` is nobody's.
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One position a slot through layer `l` of the carried `states` (L,
+    B, N, C) float32 and `tails` (L, B, K - 1, C), a slot's last K - 1
+    inputs oldest first: `new` (B, C) this token's input in the tails'
+    dtype, `pre` (B, C) and `bias` (C,) its step before `step_size`, u
+    (B, C) its convolved input (over the tail and `new`: the caller has
+    it, the step's narrow products start from it), Bm, Cm (B, N), z (B,
+    C) the gate, A (N, C), `D` (C,) the skip's -> (`gated(y, u, D, z)`
+    (B, C) in `new`'s dtype, states', tails'): one update of the state,
+    the skip and the gate, the tail a position on. A slot that is not
+    `live` (B,) keeps the state and the tail it had, bit for bit (None:
+    every slot is owned); its row of the output is nobody's and finite,
+    the row of `new` it came with (the kernel writes the output where
+    `new` lay and gives such a slot no step). All arithmetic float32.
     `interpret` as `scan` takes it."""
     f32 = jnp.float32
-    dt, u, Bm, Cm, A = (x.astype(f32) for x in (dt, u, Bm, Cm, A))
+    pre, bias, u, Bm, Cm, z, A, D = (
+        x.astype(f32) for x in (pre, bias, u, Bm, Cm, z, A, D))
     _, B, N, C = states.shape
+    new = new.astype(tails.dtype)
     if interpret is not None or usable(N, C):
-        return _update_pallas(states, l, dt, u, Bm, Cm, A, live,
-                              bool(interpret))
+        return _update_pallas(states, tails, l, new, pre, bias, u, Bm, Cm, z,
+                              A, D, live, bool(interpret))
+    tail = lax.dynamic_index_in_dim(tails, l, 0, keepdims=False)
     h0 = lax.dynamic_index_in_dim(states, l, 0, keepdims=False)
-    h, y = _advance(h0, dt[:, None], u[:, None], A, Bm[..., None],
-                    Cm[..., None])
+    h, y = _advance(h0, step_size(pre, bias)[:, None], u[:, None], A,
+                    Bm[..., None], Cm[..., None])
+    out = gated(y[:, 0], u, D, z).astype(new.dtype)
+    moved = jnp.concatenate([tail[:, 1:], new[:, None]], axis=1)
     if live is not None:
         h = jnp.where(live[:, None, None], h, h0)
-    return y[:, 0], lax.dynamic_update_slice(states, h[None], (l, 0, 0, 0))
+        moved = jnp.where(live[:, None, None], moved, tail)
+        out = jnp.where(live[:, None], out, new)
+    return (out, lax.dynamic_update_slice(states, h[None], (l, 0, 0, 0)),
+            lax.dynamic_update_slice(tails, moved[None], (l, 0, 0, 0)))

@@ -1409,10 +1409,11 @@ def test_jamba_decode_block_updates_states_and_rows_in_place(
         serve_jamba, as_on_the_chip, record_property):
     """`decode_multi` (k = 64, the engine's largest block) at the cell's
     256 slots x 5,120: the Mamba layers' update under `attn_ssm` as the
-    kernel of `ops/selective_scan` beside the tails' (the states and the
-    convolutions' tails aliased in and out), the two MQA layers' rows
-    through the decode kernel (20 query heads under one KV head); a
-    period a scan step, so thirteen bodies of each state kernel a
+    one kernel of `ops/selective_scan`, which moves the convolution's
+    tail in the grid step that holds the slot's state (both aliased in
+    and out; the tails have no kernel of their own), the two MQA layers'
+    rows through the decode kernel (20 query heads under one KV head); a
+    period a scan step, so thirteen bodies of the state's kernel a
     program; no stacked leaf is copied out of its stack (a period's
     SwiGLUs stacked over its layers and cut out by the step were 2.0 GB
     of temporaries more: they lie a layer under its place)."""
@@ -1425,12 +1426,16 @@ def test_jamba_decode_block_updates_states_and_rows_in_place(
     compiled = decode_multi.lower(cfg, params, cache, toks, temps, 64, 0,
                                   key, live).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert '"kernel":"ssm_update"' in text and '"kernel":"kda_tails"' in text
     assert '"kernel":"decode_attn"' in text
-    for scope in ("attn_ssm", "attn_global"):
+    for scope in ("attn_ssm", "attn_global", "ssm_in", "ssm_conv", "ssm_dt",
+                  "ssm_out"):
         assert scope in text, scope
     assert "attn_linear" not in text and "moe_experts" not in text
-    assert text.count('"kernel":"kda_tails"') == 13    # a body a place
+    # A body a place (the name stands on the call and on each of the
+    # three results read off it), and one work list for all thirteen.
+    assert len(re.findall(r'custom-call\([^\n]*\n"kernel":"ssm_update"',
+                          text)) == 13
+    assert "kda_tails" not in text and text.count(" sort(") == 1
     _jamba_fits(serve_jamba, mem, record_property)
     assert mem.temp_size_in_bytes < 0.5e9
 
@@ -1473,8 +1478,9 @@ def test_jamba_tiles_fit_beside_weights_states_and_rows(
 
 def test_the_scans_kernels_compile_alone_at_the_cells_widths(topo):
     """A row of 2,048 positions x 5,120 channels from a carried state, and
-    one position of 256 slots (the issue's width) against layer `l` of 26,
-    the states aliased."""
+    one position of 256 slots (the issue's width) through layer `l` of 26,
+    the states, the convolutions' tails and the row that comes back (in
+    the new row's buffer) all aliased."""
     from ray_tpu.ops import selective_scan as ss
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -1487,13 +1493,15 @@ def test_the_scans_kernels_compile_alone_at_the_cells_widths(topo):
         arr(B, S, C), arr(B, S, C), arr(B, S, N), arr(B, S, N), arr(N, C),
         arr(B, N, C)).compile().as_text()
     assert '"kernel":"ssm_scan"' in text
-    compiled = jax.jit(ss._update_pallas, donate_argnums=0).lower(
-        arr(L, slots, N, C), arr(dtype=jnp.int32), arr(slots, C),
-        arr(slots, C), arr(slots, N), arr(slots, N), arr(N, C),
-        arr(slots, dtype=jnp.bool_)).compile()
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(ss._update_pallas, donate_argnums=(0, 1, 3)).lower(
+        arr(L, slots, N, C), arr(L, slots, 3, C, dtype=bf16),
+        arr(dtype=jnp.int32), arr(slots, C, dtype=bf16), arr(slots, C),
+        arr(C), arr(slots, C), arr(slots, N), arr(slots, N), arr(slots, C),
+        arr(N, C), arr(C), arr(slots, dtype=jnp.bool_)).compile()
     assert '"kernel":"ssm_update"' in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
-        >= L * slots * N * C * 4
+        >= L * slots * C * (N * 4 + 3 * 2)
 
 
 def test_one_kv_head_under_twenty_compiles_through_both_attention_kernels(

@@ -186,36 +186,90 @@ def test_the_tiles_scan_is_the_recurrence_a_token_at_a_time(interpret):
         atol=1e-5)
 
 
+def _step_operands(B, C, N, seed, layers=3, dtype=jnp.bfloat16):
+    """One token a slot: `_operands`' steps, inputs, B, C and A, the
+    carried states and tails of `layers` layers, the token's input
+    before its convolution, its step before the bias and the softplus,
+    its gate and the skip."""
+    dt, u, Bm, Cm, A, _ = _operands(B, 1, C, N, seed)
+    ks = jax.random.split(jax.random.key(seed + 100), 5)
+    return dict(
+        states=jax.random.normal(ks[0], (layers, B, N, C)),
+        tails=jax.random.normal(ks[1], (layers, B, 3, C)).astype(dtype),
+        new=jax.random.normal(ks[2], (B, C)).astype(dtype),
+        pre=jnp.log(jnp.expm1(dt[:, 0])) - 0.3, bias=jnp.full((C,), 0.3),
+        u=u[:, 0], Bm=Bm[:, 0], Cm=Cm[:, 0],
+        z=jax.random.normal(ks[3], (B, C)), A=A,
+        D=1.0 + 0.1 * jax.random.normal(ks[4], (C,)))
+
+
+def _update(o, l, live, interpret):
+    return ss.decode_update(
+        o["states"], o["tails"], jnp.int32(l), o["new"], o["pre"], o["bias"],
+        o["u"], o["Bm"], o["Cm"], o["z"], o["A"], o["D"], live,
+        interpret=interpret)
+
+
+def _the_old_path(o, l, live):
+    """What the step made of the same operands before one call did it
+    all: the softplus, the recurrence a slot, the skip, the gate and the
+    cast beside it, `delta_rule.move_tails` for the tail."""
+    from ray_tpu.ops import delta_rule
+
+    dt = jax.nn.softplus(o["pre"] + o["bias"])
+    y, h = zip(*(ref.recurrence(dt[b:b + 1], o["u"][b:b + 1],
+                                o["Bm"][b:b + 1], o["Cm"][b:b + 1], o["A"],
+                                o["states"][l, b])
+                 for b in range(o["u"].shape[0])))
+    out = (jnp.concatenate(y) + o["D"] * o["u"]) * jax.nn.silu(o["z"])
+    return (out.astype(o["new"].dtype), jnp.stack(h), delta_rule.move_tails(
+        o["tails"], jnp.int32(l), o["new"][:, None], live))
+
+
 @pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("live", [None, [True, False, True, False, False]])
-def test_one_update_of_the_carried_states(interpret, live):
-    """Layer 1 of three: the owned slots' states are the recurrence's, a
-    slot nobody owns and the other layers keep theirs bit for bit."""
-    dt, u, Bm, Cm, A, _ = _operands(5, 1, 128, 16, seed=1)
-    states = jax.random.normal(jax.random.key(2), (3, 5, 16, 128))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_one_update_of_the_carried_states(interpret, live, dtype):
+    """Layer 1 of three: the owned slots' states are the recurrence's,
+    their tails `delta_rule.move_tails`', their output rows `(y + D u)
+    silu(z)` of the path that made each apart; a slot nobody owns and
+    the other layers keep state and tail bit for bit, and such a slot's
+    output row is finite."""
+    o = _step_operands(5, 128, 16, seed=1, dtype=dtype)
     mask = None if live is None else jnp.asarray(live)
-    y, out = jax.jit(lambda s, m: ss.decode_update(
-        s, jnp.int32(1), dt[:, 0], u[:, 0], Bm[:, 0], Cm[:, 0], A, m,
-        interpret=interpret))(states, mask)
+    out, states, tails = jax.jit(
+        lambda o, m: _update(o, 1, m, interpret))(o, mask)
+    want, want_h, want_tails = _the_old_path(o, 1, mask)
+    assert out.dtype == tails.dtype == dtype and states.dtype == jnp.float32
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
     for b in range(5):
         if live is None or live[b]:
-            yb, hb = ref.recurrence(dt[b], u[b], Bm[b], Cm[b], A,
-                                    states[1, b])
-            np.testing.assert_allclose(out[1, b], hb, rtol=1e-6, atol=1e-6)
-            np.testing.assert_allclose(y[b], yb[0], rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(states[1, b], want_h[b], rtol=1e-6,
+                                       atol=1e-6)
+            np.testing.assert_allclose(out[b].astype(jnp.float32),
+                                       want[b].astype(jnp.float32),
+                                       rtol=tol, atol=tol)
         else:
-            np.testing.assert_array_equal(out[1, b], states[1, b])
-    np.testing.assert_array_equal(out[0], states[0])
-    np.testing.assert_array_equal(out[2], states[2])
+            np.testing.assert_array_equal(states[1, b], o["states"][1, b])
+            np.testing.assert_array_equal(tails[1, b], o["tails"][1, b])
+            np.testing.assert_array_equal(out[b], o["new"][b])   # finite
+    np.testing.assert_array_equal(tails, want_tails)
+    for other in (0, 2):
+        np.testing.assert_array_equal(states[other], o["states"][other])
+        np.testing.assert_array_equal(tails[other], o["tails"][other])
 
 
-def test_no_owned_slot_leaves_every_state_as_it_was():
-    dt, u, Bm, Cm, A, _ = _operands(2, 1, 128, 16, seed=3)
-    states = jax.random.normal(jax.random.key(4), (2, 2, 16, 128))
-    _, out = ss.decode_update(states, jnp.int32(0), dt[:, 0], u[:, 0],
-                              Bm[:, 0], Cm[:, 0], A,
-                              jnp.asarray([False, False]), interpret=True)
-    np.testing.assert_array_equal(out, states)
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
+def test_no_owned_slot_leaves_every_state_as_it_was(interpret):
+    """Nor any tail; the output's rows are the rows the token's input
+    came in: nobody's, and finite."""
+    o = _step_operands(2, 128, 16, seed=3, layers=2)
+    out, states, tails = jax.jit(lambda o: _update(
+        o, 0, jnp.asarray([False, False]), interpret))(o)
+    np.testing.assert_array_equal(states, o["states"])
+    np.testing.assert_array_equal(tails, o["tails"])
+    np.testing.assert_array_equal(out, o["new"])
 
 
 # -- the stack against the reference -------------------------------------------
