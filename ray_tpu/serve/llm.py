@@ -96,6 +96,16 @@ def _copy_to_host_async(*arrays: Optional[jax.Array]) -> None:
                 pass
 
 
+# What `engine.device_call` spans say they are (`op`; docs/METRICS.md): the
+# key's `split`; host arrays sent (`to_device`, `n` of them in one call);
+# a jitted `program` of `PROGRAM_NAMES`; the eager programs `slice` (an
+# index of a device array), `scatter` (an `.at[].set`), `pad`, `stack`,
+# `concatenate`; the start of a result's copy back (`copy_start`, `n`
+# arrays) and the host read that waits for it (`to_host`, `n` arrays).
+DEVICE_OPS = ("split", "to_device", "program", "slice", "scatter", "pad",
+              "stack", "concatenate", "copy_start", "to_host")
+
+
 @dataclass
 class GenRequest:
     prompt: List[int]
@@ -409,7 +419,13 @@ class LLMEngine:
             # for the device (`engine.fetch`) and for work
             # (`engine.idle_wait`).
             "submitted": 0, "launches": {}, "tick_ns": 0, "tick_cpu_ns": 0,
-            "fetch_wait_ns": 0, "idle_wait_ns": 0}
+            "fetch_wait_ns": 0, "idle_wait_ns": 0,
+            # What the thread asked of the device or of the transfer
+            # engine, one call at a time (`engine.device_call`), and the
+            # time those calls took, both by `op` (`DEVICE_OPS`).
+            "device_calls": {}, "device_call_ns": {}}
+        # The running number of those calls: a span's `call`.
+        self._calls = 0
         if self.block_length:
             # What `engine.process_block` says of its passes, summed.
             self.counts.update(denoise_passes=0, commit_passes=0,
@@ -899,13 +915,52 @@ class LLMEngine:
                             seq=seq)
 
     @contextlib.contextmanager
-    def _wait_span(self, name: str, count: str):
-        """A span in which the engine's thread only waits, for the
-        device (`engine.fetch`) or for work (`engine.idle_wait`), its
-        length added to `counts[count]`."""
+    def _device_call(self, op: str, **attributes):
+        """The span of ONE thing the engine's thread asks of the device
+        or of the transfer engine (`op`, one of `DEVICE_OPS`), numbered:
+        `call` is the engine's running count of them, which is how an
+        `engine.fetch` says whose result it waits for. Wall-clock, so it
+        reads the same where the thread's CPU clock is coarse; a call
+        that stands blocked behind work in flight shows as a long one.
+        Gives the `call`."""
+        call = self._calls
+        self._calls = call + 1
         t0 = time.monotonic_ns()
         try:
-            with tracing.span(name):
+            with tracing.span("engine.device_call", op=op, call=call,
+                              **attributes):
+                yield call
+        finally:
+            took = time.monotonic_ns() - t0
+            calls, ns = (self.counts["device_calls"],
+                         self.counts["device_call_ns"])
+            calls[op] = calls.get(op, 0) + 1
+            ns[op] = ns.get(op, 0) + took
+
+    def _program_call(self, launch: tracing.span, **more):
+        """The `engine.device_call` of a launch's own program: it carries
+        the launch's `program` and `seq` (and `k`, a decode block's)."""
+        return self._device_call(
+            "program", program=launch.attributes["program"],
+            seq=launch.attributes["seq"], **more)
+
+    def _start_host_copy(self, *arrays: Optional[jax.Array]) -> None:
+        """`_copy_to_host_async` of the arrays that are there, as one
+        `engine.device_call`; none at all, no call."""
+        arrays = [a for a in arrays if a is not None]
+        if arrays:
+            with self._device_call("copy_start", n=len(arrays)):
+                _copy_to_host_async(*arrays)
+
+    @contextlib.contextmanager
+    def _wait_span(self, name: str, count: str, **attributes):
+        """A span in which the engine's thread only waits, for the
+        device (`engine.fetch`: `attributes` name the call whose result
+        it reads) or for work (`engine.idle_wait`), its length added to
+        `counts[count]`."""
+        t0 = time.monotonic_ns()
+        try:
+            with tracing.span(name, **attributes):
                 yield
         finally:
             self.counts[count] += time.monotonic_ns() - t0
@@ -953,7 +1008,7 @@ class LLMEngine:
         if not take:
             return []
         with tracing.span("engine.admit", cpu=True, side="slot",
-                          taken=len(take), req_ids=_ids(take)):
+                          req_ids=_ids(take)):
             return self._admit_taken(take, free)
 
     def _admit_taken(self, take: List[GenRequest], free: List[int]) -> List:
@@ -983,7 +1038,8 @@ class LLMEngine:
             slot_idx = np.full((W,), self.num_slots, np.int32)
             for j, (_, idx) in enumerate(chunk):
                 slot_idx[j] = idx
-            self._key, sub = jax.random.split(self._key)
+            with self._device_call("split"):
+                self._key, sub = jax.random.split(self._key)
             reqs = [req for req, _ in chunk]
             try:
                 if pkey is None:
@@ -992,16 +1048,20 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt, req.temperature)
                              for req in reqs])
-                        with self._launch_span(
-                                PROGRAM_NAMES["prefill_sample_batch"]):
-                            self.cache, toks, lps, *moe = \
-                                prefill_sample_batch(
-                                    self.cfg, self.params, self.cache,
-                                    jnp.asarray(buf), jnp.asarray(lens),
-                                    jnp.asarray(slot_idx), self.top_k,
-                                    jnp.asarray(temps), sub)
+                        launch = self._launch_span(
+                            PROGRAM_NAMES["prefill_sample_batch"])
+                        with launch:
+                            with self._device_call("to_device", n=4):
+                                tile = (jnp.asarray(buf), jnp.asarray(lens),
+                                        jnp.asarray(slot_idx),
+                                        jnp.asarray(temps))
+                            with self._program_call(launch):
+                                self.cache, toks, lps, *moe = \
+                                    prefill_sample_batch(
+                                        self.cfg, self.params, self.cache,
+                                        *tile[:3], self.top_k, tile[3], sub)
                     self._tile_moe += moe       # routing stats
-                    _copy_to_host_async(*moe)
+                    self._start_host_copy(*moe)
                 else:
                     sp = len(pkey)
                     with self._tile_span("slot", bucket, W, reqs, skip=sp):
@@ -1009,14 +1069,18 @@ class LLMEngine:
                             bucket, W,
                             [(req.prompt[sp:], req.temperature)
                              for req in reqs])
-                        with self._launch_span(
-                                PROGRAM_NAMES["prefill_suffix_batch"]):
-                            self.cache, toks, lps = prefill_suffix_batch(
-                                self.cfg, self.params, self.cache,
-                                entry["k"], entry["v"],
-                                jnp.asarray(buf), jnp.asarray(lens),
-                                jnp.asarray(slot_idx), self.top_k,
-                                jnp.asarray(temps), sub)
+                        launch = self._launch_span(
+                            PROGRAM_NAMES["prefill_suffix_batch"])
+                        with launch:
+                            with self._device_call("to_device", n=4):
+                                tile = (jnp.asarray(buf), jnp.asarray(lens),
+                                        jnp.asarray(slot_idx),
+                                        jnp.asarray(temps))
+                            with self._program_call(launch):
+                                self.cache, toks, lps = prefill_suffix_batch(
+                                    self.cfg, self.params, self.cache,
+                                    entry["k"], entry["v"], *tile[:3],
+                                    self.top_k, tile[3], sub)
                     self.prefix_hits += len(chunk)
                     self.prefix_tokens_saved += sp * len(chunk)
             except Exception:
@@ -1027,11 +1091,15 @@ class LLMEngine:
                         for req, _ in reversed(later):
                             self.waiting.appendleft(req)
                 raise
-            _copy_to_host_async(lps)
-            self._temps = self._temps.at[slot_idx].set(
-                jnp.asarray(temps), mode="drop")
-            self.cur_tokens = self.cur_tokens.at[slot_idx].set(
-                toks, mode="drop")
+            self._start_host_copy(lps)
+            with self._device_call("to_device", n=1):
+                temps = jnp.asarray(temps)
+            with self._device_call("scatter"):
+                self._temps = self._temps.at[slot_idx].set(
+                    temps, mode="drop")
+            with self._device_call("scatter"):
+                self.cur_tokens = self.cur_tokens.at[slot_idx].set(
+                    toks, mode="drop")
             for j, (req, idx) in enumerate(chunk):
                 slot = _Slot(req, len(req.prompt))
                 self.slots[idx] = slot
@@ -1042,10 +1110,13 @@ class LLMEngine:
                     # batch's sample.
                     slot.emitted = len(req.tokens)
                     slot.length = len(req.prompt) + slot.emitted
-                    self.cur_tokens = self.cur_tokens.at[idx].set(
-                        int(early_tok))
+                    with self._device_call("scatter"):
+                        self.cur_tokens = self.cur_tokens.at[idx].set(
+                            int(early_tok))
                 else:
-                    admitted.append((idx, toks[j], lps, j))
+                    with self._device_call("slice"):
+                        tok = toks[j]
+                    admitted.append((idx, tok, lps, j))
         return admitted
 
     def _admit_blocks(self, take: List[GenRequest], free: List[int]) -> None:
@@ -1089,16 +1160,23 @@ class LLMEngine:
                     buf, lens, temps = self._build_tile(
                         bucket, W, [(req.prompt[:whole], req.temperature)
                                     for req, _, whole in chunk])
-                    with self._launch_span(
-                            PROGRAM_NAMES["prefill_block_batch"]):
-                        self.cache, self._blocks, *moe = prefill_block_batch(
-                            self.cfg, self.params, self.cache, self._blocks,
-                            jnp.asarray(buf), jnp.asarray(lens),
-                            jnp.asarray(slot_idx), jnp.asarray(first_x),
-                            jnp.asarray(first_masked), jnp.asarray(steps),
-                            jnp.asarray(rule), jnp.asarray(threshold))
-                        self._temps = self._temps.at[slot_idx].set(
-                            jnp.asarray(temps), mode="drop")
+                    launch = self._launch_span(
+                        PROGRAM_NAMES["prefill_block_batch"])
+                    with launch:
+                        with self._device_call("to_device", n=8):
+                            tile = [jnp.asarray(a) for a in (
+                                buf, lens, slot_idx, first_x, first_masked,
+                                steps, rule, threshold)]
+                        with self._program_call(launch):
+                            self.cache, self._blocks, *moe = \
+                                prefill_block_batch(
+                                    self.cfg, self.params, self.cache,
+                                    self._blocks, *tile)
+                        with self._device_call("to_device", n=1):
+                            temps = jnp.asarray(temps)
+                        with self._device_call("scatter"):
+                            self._temps = self._temps.at[slot_idx].set(
+                                temps, mode="drop")
             except Exception:
                 with self.lock:
                     for _, later in reversed(chunks[ci:]):
@@ -1106,7 +1184,7 @@ class LLMEngine:
                             self.waiting.appendleft(req)
                 raise
             self._tile_moe += moe
-            _copy_to_host_async(*moe)
+            self._start_host_copy(*moe)
             for req, idx, whole in chunk:
                 slot = _Slot(req, whole)
                 # What the prompt left over stands in the first block.
@@ -1117,7 +1195,10 @@ class LLMEngine:
     def _read_tile_moe(self, span=None) -> None:
         """The admission tiles' routing stats, once the device has them:
         onto the counters and onto `span`."""
-        tile_moe = [np.asarray(m) for m in self._tile_moe]
+        tile_moe = []
+        if self._tile_moe:
+            with self._device_call("to_host", n=len(self._tile_moe)):
+                tile_moe = [np.asarray(m) for m in self._tile_moe]
         self._tile_moe = []
         if tile_moe:
             routed = {"prefill_" + name: n for name, n in
@@ -1144,7 +1225,7 @@ class LLMEngine:
         if not todo:
             return []
         with tracing.span("engine.admit", cpu=True, side="queue",
-                          taken=len(todo), req_ids=_ids(todo)):
+                          req_ids=_ids(todo)):
             return self._first_token_tiles(todo)
 
     def _first_token_tiles(self, todo: List[GenRequest]) -> List:
@@ -1163,16 +1244,23 @@ class LLMEngine:
             with self._tile_span("queue", bucket, W, chunk):
                 buf, lens, temps = self._build_tile(
                     bucket, W, [(r.prompt, r.temperature) for r in chunk])
-                self._key, sub = jax.random.split(self._key)
-                with self._launch_span(PROGRAM_NAMES["first_token_sample"]):
-                    toks, lps = first_token_sample(
-                        self.cfg, self.params, jnp.asarray(buf),
-                        jnp.asarray(lens), jnp.asarray(temps), self.top_k,
-                        sub)
+                with self._device_call("split"):
+                    self._key, sub = jax.random.split(self._key)
+                launch = self._launch_span(
+                    PROGRAM_NAMES["first_token_sample"])
+                with launch:
+                    with self._device_call("to_device", n=3):
+                        tile = (jnp.asarray(buf), jnp.asarray(lens),
+                                jnp.asarray(temps))
+                    with self._program_call(launch):
+                        toks, lps = first_token_sample(
+                            self.cfg, self.params, *tile, self.top_k, sub)
                 if W < self._ADMIT_TILE:
-                    toks, lps = (jnp.pad(x, (0, self._ADMIT_TILE - W))
-                                 for x in (toks, lps))
-            _copy_to_host_async(lps)
+                    with self._device_call("pad"):
+                        toks = jnp.pad(toks, (0, self._ADMIT_TILE - W))
+                    with self._device_call("pad"):
+                        lps = jnp.pad(lps, (0, self._ADMIT_TILE - W))
+            self._start_host_copy(lps)
             outs.append((chunk, toks, lps))
         W = self._ADMIT_TILE      # a suffix tile's: its bucket is short
         # Prefix-matched queued requests: suffix-only forward against
@@ -1183,16 +1271,21 @@ class LLMEngine:
                 buf, lens, temps = self._build_tile(
                     bucket, W, [(r.prompt[sp:], r.temperature)
                                 for r in chunk])
-                self._key, sub = jax.random.split(self._key)
-                with self._launch_span(
-                        PROGRAM_NAMES["first_token_suffix_sample"]):
-                    toks, lps = first_token_suffix_sample(
-                        self.cfg, self.params, entry["k"], entry["v"],
-                        jnp.asarray(buf), jnp.asarray(lens),
-                        jnp.asarray(temps), self.top_k, sub)
+                with self._device_call("split"):
+                    self._key, sub = jax.random.split(self._key)
+                launch = self._launch_span(
+                    PROGRAM_NAMES["first_token_suffix_sample"])
+                with launch:
+                    with self._device_call("to_device", n=3):
+                        tile = (jnp.asarray(buf), jnp.asarray(lens),
+                                jnp.asarray(temps))
+                    with self._program_call(launch):
+                        toks, lps = first_token_suffix_sample(
+                            self.cfg, self.params, entry["k"], entry["v"],
+                            *tile, self.top_k, sub)
             self.prefix_hits += len(chunk)
             self.prefix_tokens_saved += sp * len(chunk)
-            _copy_to_host_async(lps)
+            self._start_host_copy(lps)
             outs.append((chunk, toks, lps))
         return outs
 
@@ -1204,18 +1297,21 @@ class LLMEngine:
         block). Tokens alone: the stack and the concatenation are eager,
         one compiled program per aval, and a benchmark warms them for
         int32. The log-probabilities come over tile by tile, as their
-        programs returned them."""
+        programs returned them. Returns the array with the `call` that
+        made it, or None."""
         if not admitted and not outs:
             return None
-        with tracing.span("engine.fuse_first",
-                          parts=bool(admitted) + len(outs)):
+        with tracing.span("engine.fuse_first"):
             parts = []
             if admitted:
-                parts.append(jnp.stack([t for _, t, _, _ in admitted]))
+                with self._device_call("stack", n=len(admitted)):
+                    parts.append(jnp.stack([t for _, t, _, _ in admitted]))
             parts += [t for _, t, _ in outs]
-            fused = jnp.concatenate(parts)
-            _copy_to_host_async(fused)
-        return fused
+            with self._device_call("concatenate", n=len(parts)) as call:
+                fused = jnp.concatenate(parts)
+            self._start_host_copy(fused)
+        # With the call the first tokens' fetch waits for.
+        return fused, call
 
     def _deliver_first_tokens(self, fused, admitted: List,
                               outs: List) -> None:
@@ -1223,15 +1319,22 @@ class LLMEngine:
         in flight via copy_to_host_async)."""
         if fused is None:
             return
+        fused, call = fused
         span = tracing.span("engine.deliver_first", tokens=len(admitted)
                             + sum(len(reqs) for reqs, _, _ in outs))
         with span:
-            # the host waits here
-            with self._wait_span("engine.fetch", "fetch_wait_ns"):
-                fused = np.asarray(fused)
-                fused_lp = np.concatenate(
-                    [np.asarray(lps)[j:j + 1] for _, _, lps, j in admitted]
-                    + [np.asarray(lps) for _, _, lps in outs])
+            # the host waits here, for the fusion's concatenation (the
+            # eager programs between it and the tiles carry the calls
+            # before it)
+            with self._wait_span("engine.fetch", "fetch_wait_ns", call=call):
+                with self._device_call("to_host", n=1):
+                    fused = np.asarray(fused)
+                with self._device_call(
+                        "to_host", n=len(admitted) + len(outs)):
+                    host_lps = [np.asarray(lps)[j:j + 1]
+                                for _, _, lps, j in admitted] \
+                        + [np.asarray(lps) for _, _, lps in outs]
+                fused_lp = np.concatenate(host_lps)
                 # What the admission tiles behind these tokens (and any
                 # whose tokens the queue side had served) routed: a
                 # tile's span ends at its dispatch, before the device
@@ -1450,44 +1553,64 @@ class LLMEngine:
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held, **more):
-            # Everything the block asks of the device: the key's split,
-            # the transfer, the program, the slice and the copies' start.
-            with self._launch_span(
-                    PROGRAM_NAMES["decode_multi"].format(k=k_block)):
-                self._key, sub = jax.random.split(self._key)
-                live = jnp.asarray(owned)
-                moe = lps = None
+            # Everything the block asks of the device, a call at a time:
+            # the key's split, the transfer, the program, the slice and
+            # the copies' start.
+            launch = self._launch_span(
+                PROGRAM_NAMES["decode_multi"].format(k=k_block))
+            with launch:
+                with self._device_call("split"):
+                    self._key, sub = jax.random.split(self._key)
+                with self._device_call("to_device", n=1):
+                    live = jnp.asarray(owned)
+                moe = lps = sampler = None
                 if self.block_length:
-                    self.cache, self._blocks, toks, *moe = \
-                        decode_block_multi(
-                            self.cfg, self.params, self.cache, self._blocks,
-                            self._temps, k_block, self.top_k, sub, live)
+                    with self._program_call(launch, k=k_block) as call:
+                        self.cache, self._blocks, toks, *moe = \
+                            decode_block_multi(
+                                self.cfg, self.params, self.cache,
+                                self._blocks, self._temps, k_block,
+                                self.top_k, sub, live)
                     moe = moe[0] if moe else None
-                    _copy_to_host_async(*toks)
+                    self._start_host_copy(*toks)
                 elif k_block == 1 and not self._routed_layers:
-                    self.cache, logits = decode_step(
-                        self.cfg, self.params, self.cache,
-                        self.cur_tokens, live)
-                    toks, lps = _sample_batch(logits, self._temps, sub,
-                                              self.top_k)
-                    toks = toks[None]                      # (1, B)
+                    with self._program_call(launch, k=k_block):
+                        self.cache, logits = decode_step(
+                            self.cfg, self.params, self.cache,
+                            self.cur_tokens, live)
+                    # The sampler's tokens are what the host reads: the
+                    # fetch names this call (a program of the table's,
+                    # no `engine.launch` and so no `seq` of its own).
+                    sampler = PROGRAM_NAMES["sample_batch"]
+                    with self._device_call("program",
+                                           program=sampler) as call:
+                        toks, lps = _sample_batch(logits, self._temps, sub,
+                                                  self.top_k)
+                    with self._device_call("slice"):
+                        toks = toks[None]                  # (1, B)
                 else:
-                    self.cache, toks, lps, *moe = decode_multi(
-                        self.cfg, self.params, self.cache,
-                        self.cur_tokens, self._temps, k_block,
-                        self.top_k, sub, live)             # (k, B)
+                    with self._program_call(launch, k=k_block) as call:
+                        self.cache, toks, lps, *moe = decode_multi(
+                            self.cfg, self.params, self.cache,
+                            self.cur_tokens, self._temps, k_block,
+                            self.top_k, sub, live)         # (k, B)
                     moe = moe[0] if moe else None   # routing stats
                 # Start the host copy NOW, before the next tick enqueues
                 # prefills and the next block behind it.
                 if not self.block_length:
-                    self.cur_tokens = toks[-1]
-                    _copy_to_host_async(toks, lps)
-                _copy_to_host_async(moe)
+                    with self._device_call("slice"):
+                        self.cur_tokens = toks[-1]
+                    self._start_host_copy(toks, lps)
+                self._start_host_copy(moe)
+            # Whose result `_process_block`'s fetch waits for.
+            source = dict(call=call, program=sampler) if sampler else dict(
+                call=call, program=launch.attributes["program"],
+                seq=launch.attributes["seq"])
             self.decode_ticks += k_block
             for i in active:
                 snap[i].inflight += k_block
         return (toks, lps, k_block, [(i, snap[i]) for i in active], number,
-                moe)
+                moe, source)
 
     def warm_decode_blocks(self) -> List[int]:
         """Run the fused decode program of every size the adaptive block
@@ -1512,24 +1635,29 @@ class LLMEngine:
         block was in flight now holds a different request, and the
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
-        toks, lps, k_block, slot_snap, number, moe = block
+        toks, lps, k_block, slot_snap, number, moe, source = block
         # `slots`: positions a step computes (a slot's block a pass).
         span = tracing.span("engine.process_block", block=number, k=k_block,
                             slots=self.num_slots * self._step_rows,
                             active=len(slot_snap))
         with span:
             # the host waits here
-            with self._wait_span("engine.fetch", "fetch_wait_ns"):
+            with self._wait_span("engine.fetch", "fetch_wait_ns", **source):
                 if self.block_length:
-                    host = [np.asarray(a) for a in toks]
+                    with self._device_call("to_host", n=len(toks)):
+                        host = [np.asarray(a) for a in toks]
                     # The tiles behind these passes: no first token
                     # waits for them, so their numbers ride this span.
                     self._read_tile_moe(span)
                 else:
-                    host_toks = np.asarray(toks)
-                    # (B,) after a one-step block's own sampler
-                    host_lps = np.asarray(lps).reshape(host_toks.shape)
-                host_moe = np.asarray(moe) if moe is not None else None
+                    with self._device_call("to_host", n=2):
+                        host_toks = np.asarray(toks)
+                        # (B,) after a one-step block's own sampler
+                        host_lps = np.asarray(lps).reshape(host_toks.shape)
+                host_moe = None
+                if moe is not None:
+                    with self._device_call("to_host", n=1):
+                        host_moe = np.asarray(moe)
             self.steps_processed += k_block
             before = self.tokens_out
             with self._emit_span():
@@ -1684,9 +1812,10 @@ class LLMEngine:
         ttfts = sorted(f["ttft_s"] for f in fin)
         out: Dict[str, Any] = {
             "finished": self._n_finished,
-            "counts": dict(self.counts,
-                           blocks_by_k=dict(self.counts["blocks_by_k"]),
-                           launches=dict(self.counts["launches"])),
+            "counts": dict(self.counts, **{
+                k: dict(self.counts[k]) for k in (
+                    "blocks_by_k", "launches", "device_calls",
+                    "device_call_ns")}),
             "decode_ticks": self.decode_ticks,
             "tokens_out": self.tokens_out,
             "waiting": len(self.waiting),

@@ -3,6 +3,7 @@ off state, the engine's spans and counts, `steps_waited`, the serving
 programs' names, the kernels' names and the train step's phase scopes.
 All on the CPU; nothing sleeps or asserts a duration."""
 
+import contextlib
 import importlib
 import re
 import threading
@@ -14,7 +15,8 @@ import pytest
 
 from ray_tpu.models import configs, generate
 from ray_tpu.models.transformer import init_params
-from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.serve import llm
+from ray_tpu.serve.llm import DEVICE_OPS, LLMEngine
 from ray_tpu.util import tracing
 
 
@@ -207,7 +209,7 @@ def test_engine_spans_nest_under_their_tick_and_share_its_trace(drained):
             "engine.tile_build", "engine.launch", "engine.fuse_first",
             "engine.dispatch_block", "engine.deliver_first",
             "engine.process_block", "engine.fetch", "engine.emit",
-            "engine.submit"} == names
+            "engine.submit", "engine.device_call"} == names
     parents = {
         "engine.admit": {"engine.tick"},
         "engine.dispatch_block": {"engine.tick"},
@@ -218,7 +220,10 @@ def test_engine_spans_nest_under_their_tick_and_share_its_trace(drained):
         "engine.tile_build": {"engine.prefill_tile"},
         "engine.launch": {"engine.prefill_tile", "engine.dispatch_block"},
         "engine.fetch": {"engine.process_block", "engine.deliver_first"},
-        "engine.emit": {"engine.process_block", "engine.deliver_first"}}
+        "engine.emit": {"engine.process_block", "engine.deliver_first"},
+        "engine.device_call": {"engine.launch", "engine.admit",
+                               "engine.prefill_tile", "engine.fuse_first",
+                               "engine.fetch"}}
     for e in events:
         if e["name"] in parents:
             parent = by_id[e["args"]["parent"]]
@@ -252,16 +257,49 @@ def test_the_new_spans_carry_their_attributes(drained):
         (r.id, len(r.prompt)) for r in reqs]
     for e in spans("engine.admit"):
         a = e["args"]
-        assert set(a) == keys | {"side", "taken", "req_ids", "cpu_us"}
-        assert a["side"] in ("slot", "queue")
-        assert a["taken"] == len(a["req_ids"].split()) > 0
+        assert set(a) == keys | {"side", "req_ids", "cpu_us"}
+        assert a["side"] in ("slot", "queue") and a["req_ids"].split()
     assert {e["args"]["side"] for e in spans("engine.admit")} == {
         "slot", "queue"}
     assert all(set(e["args"]) == keys for e in spans("engine.tile_build"))
+    # How many parts it joins is the concatenation's `n`.
+    assert all(set(e["args"]) == keys for e in spans("engine.fuse_first"))
+    by_parent = {}
+    for c in spans("engine.device_call"):
+        by_parent.setdefault(c["args"]["parent"], []).append(c["args"])
+    for e in spans("engine.fuse_first"):
+        inside = by_parent[e["tid"].split(":", 1)[1]]
+        assert [c["op"] for c in inside if c["op"] != "stack"] == [
+            "concatenate", "copy_start"]
     programs = set()
     for e in spans("engine.launch"):
         assert set(e["args"]) == keys | {"program", "seq", "cpu_us"}
         programs.add(e["args"]["program"])
+        # Its children are the calls it makes, its own program's among
+        # them under its `program` and `seq`.
+        inside = [c["args"] for c in spans("engine.device_call")
+                  if c["args"]["parent"] == e["tid"].split(":", 1)[1]]
+        own, = [c for c in inside if c.get("seq") is not None]
+        assert (own["op"], own["program"], own["seq"]) == (
+            "program", e["args"]["program"], e["args"]["seq"])
+        block = e["args"]["program"].startswith("decode_k")
+        assert set(own) == keys | {"op", "call", "program", "seq"} | (
+            {"k"} if block else set())
+        assert [c["op"] for c in inside][:2] == (
+            ["split", "to_device"] if block else ["to_device", "program"])
+    # A fetch names the call whose result it reads, and that call's
+    # program and `seq` where it is a launch's.
+    by_call = {c["args"]["call"]: c["args"]
+               for c in spans("engine.device_call")}
+    for e in spans("engine.fetch"):
+        a = e["args"]
+        waited = by_call[a["call"]]
+        assert set(a) == keys | {"call"} | (
+            {"program"} if "program" in waited else set()) | (
+            {"seq"} if "seq" in waited else set())
+        assert waited["op"] == ("program" if "program" in a
+                                else "concatenate")
+        assert all(a[k] == waited[k] for k in ("program", "seq") if k in a)
     assert {"prefill_sample_batch", "first_token_sample"} <= programs
     assert programs - {"prefill_sample_batch", "first_token_sample"} <= {
         f"decode_k{k}" for k in (1, 2, 4, 8)}
@@ -366,6 +404,225 @@ def test_counts_equal_the_sums_of_the_span_attributes(drained):
                            for i in t["req_ids"].split())
     assert in_slot_tiles == sorted(r.id for r in reqs)
     assert all("," not in t["req_ids"] for t in tiles)
+
+
+# -- every device call of the engine's thread is a span -----------------------
+
+# Where each `op` of the vocabulary is made: the spans it may stand
+# directly under. Every one of them lies in an `engine.launch`, an
+# `engine.admit`, an `engine.fuse_first` or an `engine.fetch`.
+OP_PARENTS = {
+    "split": {"engine.launch", "engine.admit", "engine.prefill_tile"},
+    "to_device": {"engine.launch", "engine.admit"},
+    "program": {"engine.launch"},
+    "slice": {"engine.launch", "engine.admit"},
+    "scatter": {"engine.admit", "engine.launch"},
+    "pad": {"engine.prefill_tile"},
+    "stack": {"engine.fuse_first"},
+    "concatenate": {"engine.fuse_first"},
+    "copy_start": {"engine.launch", "engine.admit", "engine.fuse_first"},
+    "to_host": {"engine.fetch"},
+}
+CALL_ROOTS = {"engine.launch", "engine.admit", "engine.fuse_first",
+              "engine.fetch"}
+
+
+@pytest.fixture
+def padded(tiny_model, hook):
+    """A queue-side tile past the 1024 bucket is narrower than
+    `_ADMIT_TILE` and its results are padded: two prompts of 1,100
+    tokens on one slot."""
+    cfg, params = tiny_model
+    before = len(hook)          # another fixture's engine may have run
+    engine = LLMEngine(cfg, params, num_slots=1, max_seq_len=2560,
+                       decode_block=2)
+    reqs = [engine.submit([5] * 1100, max_new_tokens=2) for _ in range(2)]
+    _drain(engine, reqs)
+    return engine, reqs, hook[before:]
+
+
+def test_every_op_of_the_vocabulary_appears_under_its_parent(
+        drained, padded):
+    assert set(OP_PARENTS) == set(DEVICE_OPS)
+    seen = {}
+    for engine, _, events in (drained, padded):
+        by_id = {e["tid"].split(":", 1)[1]: e for e in events}
+        calls = [e for e in events if e["name"] == "engine.device_call"]
+        for e in calls:
+            a = e["args"]
+            parent = by_id[a["parent"]]
+            assert parent["name"] in OP_PARENTS[a["op"]], a
+            seen.setdefault(a["op"], set()).add(parent["name"])
+            up = parent
+            while up["name"] not in CALL_ROOTS:
+                up = by_id[up["args"]["parent"]]
+            assert set(a) >= {"op", "call"}
+            assert ("n" in a) == (a["op"] in (
+                "to_device", "copy_start", "to_host", "stack",
+                "concatenate")), a
+        # `call` runs without a hole, in the order the calls were made.
+        assert [e["args"]["call"] for e in calls] == list(range(len(calls)))
+        # The counters are the spans' sums, by `op`.
+        c = engine.stats()["counts"]
+        assert c["device_calls"] == {
+            op: sum(e["args"]["op"] == op for e in calls)
+            for op in {e["args"]["op"] for e in calls}}
+        assert sum(c["device_calls"].values()) == len(calls)
+        for op, ns in c["device_call_ns"].items():     # read just outside
+            assert ns >= 1000 * sum(e["dur"] for e in calls
+                                    if e["args"]["op"] == op) > 0
+        assert engine.stats()["counts"]["device_calls"] \
+            is not c["device_calls"]
+    assert set(seen) == set(DEVICE_OPS)
+    assert seen["pad"] == {"engine.prefill_tile"}
+    assert seen["split"] == OP_PARENTS["split"]
+
+
+def test_a_fetch_of_first_tokens_leads_back_to_its_tile(drained):
+    """The first tokens' fetch waits for the fusion's concatenation: the
+    calls before it, back to the tile's program, are the eager programs
+    the tokens went through."""
+    _, _, events = drained
+    calls = {e["args"]["call"]: e["args"] for e in events
+             if e["name"] == "engine.device_call"}
+    by_id = {e["tid"].split(":", 1)[1]: e for e in events}
+    firsts = [e for e in events if e["name"] == "engine.fetch"
+              and by_id[e["args"]["parent"]]["name"]
+              == "engine.deliver_first"]
+    assert firsts
+    for e in firsts:
+        back = e["args"]["call"]
+        assert calls[back]["op"] == "concatenate"
+        walked = []
+        while calls[back]["op"] != "program":
+            back -= 1
+            walked.append(calls[back]["op"])
+        assert calls[back]["program"] in ("prefill_sample_batch",
+                                          "first_token_sample")
+        assert set(walked) <= {"stack", "slice", "scatter", "to_device",
+                               "copy_start", "pad", "program"}
+
+
+class _Guard:
+    """The device-facing names `serve/llm.py` reaches the device through,
+    wrapped: each use inside an engine tick is counted, with whether an
+    `engine.device_call` was open around it."""
+
+    def __init__(self):
+        self.ticks = self.open = 0
+        self.uses, self.outside, self.per_call = [], [], []
+
+    def wrap(self, what, fn, only=lambda *a, **k: True):
+        def guarded(*a, **k):
+            if self.ticks and only(*a, **k):
+                self.uses.append(what)
+                if self.open:
+                    self.per_call[-1] += 1
+                else:
+                    self.outside.append(what)
+            return fn(*a, **k)
+        return guarded
+
+    def proxy(self, module, names):
+        guard = self
+
+        class Proxy:
+            def __getattr__(self, name):
+                attr = getattr(module, name)
+                if name in names:
+                    return guard.wrap(f"{module.__name__}.{name}", attr,
+                                      names[name])
+                return attr
+        return Proxy()
+
+
+@pytest.fixture
+def guard(monkeypatch):
+    """Every way `serve/llm.py` asks something of the device, guarded:
+    the `jnp` and `jax.random` functions it uses, the programs, the host
+    copies' start, `np.asarray` of a device array, and a device array's
+    own index and `.at[].set`."""
+    from jax._src import array as jax_array
+    from jax._src.numpy import array_methods
+
+    g = _Guard()
+    always = lambda *a, **k: True                       # noqa: E731
+    on_device = lambda x, *a, **k: isinstance(x, jax.Array)  # noqa: E731
+    monkeypatch.setattr(llm, "jnp", g.proxy(jnp, dict.fromkeys(
+        ("asarray", "pad", "stack", "concatenate", "zeros", "array"),
+        always)))
+    random = g.proxy(jax.random, {"split": always})
+    monkeypatch.setattr(llm, "jax", g.proxy(jax, {}))
+    monkeypatch.setattr(llm.jax, "random", random, raising=False)
+    monkeypatch.setattr(llm, "np", g.proxy(llm.np, {"asarray": on_device}))
+    for name in ("prefill_sample_batch", "prefill_suffix_batch",
+                 "prefill_block_batch", "first_token_sample",
+                 "first_token_suffix_sample", "decode_step", "decode_multi",
+                 "decode_block_multi", "_sample_batch",
+                 "_copy_to_host_async", "compute_prefix_kv"):
+        monkeypatch.setattr(llm, name, g.wrap(name, getattr(llm, name)))
+    monkeypatch.setattr(jax_array.ArrayImpl, "__getitem__", g.wrap(
+        "Array[...]", jax_array.ArrayImpl.__getitem__))
+    monkeypatch.setattr(array_methods._IndexUpdateRef, "set", g.wrap(
+        "Array.at[].set", array_methods._IndexUpdateRef.set))
+
+    step, device_call = LLMEngine.step, LLMEngine._device_call
+
+    def counted_step(self):
+        g.ticks += 1
+        try:
+            return step(self)
+        finally:
+            g.ticks -= 1
+
+    @contextlib.contextmanager
+    def counted_call(self, op, **attributes):
+        with device_call(self, op, **attributes) as call:
+            g.open += 1
+            g.per_call.append(0)
+            try:
+                yield call
+            finally:
+                g.open -= 1
+
+    monkeypatch.setattr(LLMEngine, "step", counted_step)
+    monkeypatch.setattr(LLMEngine, "_device_call", counted_call)
+    return g
+
+
+def _run_guarded(cfg, slots=2):
+    params = init_params(cfg, jax.random.key(0))
+    engine = LLMEngine(cfg, params, num_slots=slots, max_seq_len=64,
+                       decode_block=8)
+    reqs = [engine.submit(list(range(1, 4 + 3 * i)), max_new_tokens=3 + i)
+            for i in range(5)]
+    _drain(engine, reqs)
+    return engine
+
+
+@pytest.mark.parametrize("preset", ["tiny_test", "tiny_afmoe_test",
+                                    "tiny_sdar_test"])
+def test_no_device_call_of_a_tick_lies_outside_a_span(guard, preset):
+    """The list of call sites is closed: a tick's every use of a
+    device-facing name happens inside an `engine.device_call`, each span
+    holds at least one, and the counters count the spans. A call added
+    to the engine's thread without its span fails here."""
+    engine = _run_guarded(getattr(configs, preset)())
+    assert guard.outside == []
+    assert len(guard.uses) >= len(guard.per_call) > 50
+    assert all(n >= 1 for n in guard.per_call)
+    calls = engine.stats()["counts"]["device_calls"]
+    assert sum(calls.values()) == len(guard.per_call) == engine._calls
+    assert set(calls) <= set(DEVICE_OPS)
+
+
+def test_the_guard_sees_a_call_made_outside_a_span(guard, monkeypatch):
+    """The control: the host copies started without their span."""
+    monkeypatch.setattr(
+        LLMEngine, "_start_host_copy",
+        lambda self, *arrays: llm._copy_to_host_async(*arrays))
+    _run_guarded(configs.tiny_test())
+    assert guard.outside and set(guard.outside) == {"_copy_to_host_async"}
 
 
 # What an admission tick builds: (prompt lengths submitted together,
