@@ -73,8 +73,9 @@ latent cache take the activation dtype whatever it says. The steps are
   still fetches `index_topk` rows, which the kernel then skips);
 - a tile writes its rows and keys, scores every row of its bucket each
   query may see (`index_scores_tile`), finds each query's chosen set
-  (`topk_bias`: the k-th largest score a bit at a time, no sort) and
-  attends per head under the chosen sets as a bias (`masked_attention`),
+  (`topk_bias`: the k-th largest score a bit at a time, no sort, over
+  the columns at or below the block's last query alone) and attends per
+  head under the chosen sets as a bias (`masked_attention`),
   the rows of a chunk of keys at a time up-projected from the cache. A
   bucket no longer than `index_topk` chooses every row: the tile over
   itself, as without an indexer.
@@ -108,6 +109,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
@@ -463,6 +465,12 @@ def chunk_rows(cfg: TransformerConfig, bucket: int) -> int:
     return bucket
 
 
+def _choice_rows(T: int) -> int:
+    """Queries of a chunk of T rows that make their choices together:
+    blocks of `_CHOICE_ROWS` where T is whole blocks, else all T."""
+    return _CHOICE_ROWS if T % _CHOICE_ROWS == 0 else T
+
+
 def _write_rows(rows_all, l, slots, start, rows):
     """rows (W, T, width) into rows [start, start + T) of layer `l` of
     each of `slots` (W,) of a cache (L, B, S, width); a slot out of
@@ -538,14 +546,15 @@ def _attend_chunk(cfg: TransformerConfig, slots, start, bucket: int, l, lp,
             q_part, w_part, first = part
             return sa.topk_bias(
                 sa.index_scores_tile(q_part, w_part, keys, first),
-                cfg.index_topk, dtype=dt)
+                cfg.index_topk, first, dtype=dt)
 
-        n = T // _CHOICE_ROWS if T % _CHOICE_ROWS == 0 else 1
+        rows = _choice_rows(T)
+        n = T // rows
         bias = lax.map(choose, (
-            jnp.moveaxis(q_idx.reshape((W, n, T // n) + q_idx.shape[2:]),
+            jnp.moveaxis(q_idx.reshape((W, n, rows) + q_idx.shape[2:]),
                          1, 0),
-            jnp.moveaxis(w_idx.reshape(W, n, T // n, -1), 1, 0),
-            start + jnp.arange(n) * (T // n)))
+            jnp.moveaxis(w_idx.reshape(W, n, rows, -1), 1, 0),
+            start + jnp.arange(n) * rows))
         bias = jnp.moveaxis(bias, 0, 1).reshape(W, T, bucket)
         if picks is not None:
             picks = lax.dynamic_update_slice(
@@ -696,6 +705,24 @@ def prefill_chunks(cfg: TransformerConfig, bucket: int, length: int
     tokens, chunks the bucket has): `_walk`'s count, for the host."""
     T = chunk_rows(cfg, bucket)
     return min(max(-(-length // T), 1), bucket // T), bucket // T
+
+
+def choice_columns(cfg: TransformerConfig, bucket: int, length: int
+                   ) -> Tuple[int, int]:
+    """(columns the choices of such a tile count a layer a row, columns
+    its bucket spans for the same blocks of queries): what `topk_bias`
+    is told of where each block stands (`_attend_chunk`), for the host.
+    A tile that chooses every row it sees counts nothing."""
+    from ..ops import sparse_attention as sa
+
+    T = chunk_rows(cfg, bucket)
+    if bucket <= cfg.index_topk and T == bucket:
+        return 0, 0
+    rows = _choice_rows(T)
+    # The chunks run abut: their blocks of queries are those of one run.
+    firsts = np.arange(0, prefill_chunks(cfg, bucket, length)[0] * T, rows)
+    counted = np.minimum(sa.columns_counted(firsts, rows, bucket), bucket)
+    return int(counted.sum()), len(firsts) * bucket
 
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,

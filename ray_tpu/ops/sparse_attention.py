@@ -16,6 +16,9 @@ tile):
   is found exactly, a bit at a time over the scores' own bit patterns
   (32 counts of a row held in VMEM; no sort, no approximation); the
   rows above it are chosen, and of the rows equal to it the lowest.
+  One kernel counts and writes the bias; told where its block of
+  queries stands, it reads only the columns a query of the block may
+  see.
 - `masked_attention`: per-head attention of a chunk of queries over a
   chunk of keys under such a bias, returning the chunk's part with its
   log-sum-exp so that the parts of several key chunks add up
@@ -36,10 +39,12 @@ the attention of all the rows the chunk may see.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -49,7 +54,8 @@ from .flash_attention import NEG_INF, _LANES
 _NT = (((1,), (1,)), ((), ()))      # a b^T
 _TILE_Q, _TILE_K = 256, 512         # a block of `index_scores_tile`
 _ATTN_Q, _ATTN_K = 512, 512         # a block of `masked_attention`
-_THRESHOLD_ROWS = 8                 # query rows `topk_bias` holds at once
+_THRESHOLD_ROWS = 16                # query rows `topk_bias` holds at once
+_COUNT_COLS = 1024                  # columns it counts or skips together
 
 
 def use_kernels(dtype, interpret: Optional[bool] = None,
@@ -184,6 +190,7 @@ def index_scores_tile(q: jax.Array, w: jax.Array, keys: jax.Array,
 # ---------------------------------------------------------------------------
 
 _SIGN = -2 ** 31
+_LOWEST = _SIGN + 0x7FFFFF            # `_ordered(-inf)`
 
 
 def _ordered(x: jax.Array) -> jax.Array:
@@ -192,12 +199,43 @@ def _ordered(x: jax.Array) -> jax.Array:
     return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
 
 
-def _threshold_kernel(x_ref, thr_ref, cnt_ref, *, k):
-    key = _ordered(x_ref[...])                             # (rows, S)
-    rows = key.shape[0]
+def _count_cols(S: int) -> int:
+    """Columns of a row of S (a multiple of the lanes) that `topk_bias`
+    counts or skips together."""
+    return math.gcd(S, _COUNT_COLS)
 
-    def count(at):
-        return jnp.sum(jnp.where(key >= at, 1.0, 0.0), axis=1, keepdims=True)
+
+def columns_counted(q_offset, T: int, S: int):
+    """Of the S columns a row has, those `topk_bias` counts for T queries
+    at rows [q_offset, q_offset + T): whole blocks of `_count_cols(S)` up
+    to the last query's own column (a number, or one the device counts;
+    past S where the queries are)."""
+    tc = _count_cols(S)
+    return (q_offset + T + tc - 1) // tc * tc
+
+
+def _threshold_kernel(n_ref, x_ref, bias_ref, thr_ref, cnt_ref, key_ref, *,
+                      k, tc, neg):
+    """A block of rows: `n_ref[0]` column blocks of `tc` hold every score
+    that is not `-inf`; no other is read. `neg`: `NEG_INF` as the bias'
+    dtype holds it."""
+    n = n_ref[0]
+    rows, S = key_ref.shape
+
+    def at(j):
+        return pl.ds(pl.multiple_of(j * tc, tc), tc)
+
+    def order(j, _):
+        key_ref[:, at(j)] = _ordered(x_ref[:, at(j)])
+
+    lax.fori_loop(0, n, order, None)
+
+    def count(thr):
+        # Sums of ones, in lanes first: whole numbers, exact in any order.
+        return jnp.sum(lax.fori_loop(
+            0, n, lambda j, acc: acc + jnp.where(key_ref[:, at(j)] >= thr,
+                                                 1.0, 0.0),
+            jnp.zeros((rows, tc), jnp.float32)), axis=1, keepdims=True)
 
     # The k-th largest key, built from its top bit down in the order of
     # unsigned patterns (a signed key with its sign bit turned).
@@ -205,12 +243,26 @@ def _threshold_kernel(x_ref, thr_ref, cnt_ref, *, k):
     for bit in range(31, -1, -1):
         cand = best | jnp.int32(_SIGN if bit == 31 else 1 << bit)
         best = jnp.where(count(cand ^ jnp.int32(_SIGN)) >= k, cand, best)
+    # Fewer than k columns counted: no bit is kept, and the threshold lies
+    # below every key, where `-inf` in the other columns would put it at
+    # theirs: every column seen is chosen either way, and no tie counted.
     thr = best ^ jnp.int32(_SIGN)
     thr_ref[...] = jnp.broadcast_to(thr, thr_ref.shape)
     cnt_ref[...] = jnp.broadcast_to(count(thr), cnt_ref.shape)
 
+    def choose(j, _):
+        chosen = (key_ref[:, at(j)] >= thr) & (x_ref[:, at(j)] > -jnp.inf)
+        bias_ref[:, at(j)] = jnp.where(chosen, 0.0, neg).astype(
+            bias_ref.dtype)
 
-def _exact_choice(scores, key, thr, k):
+    def ahead(j, _):
+        bias_ref[:, at(j)] = jnp.full((rows, tc), neg, bias_ref.dtype)
+
+    lax.fori_loop(0, n, choose, None)
+    lax.fori_loop(n, S // tc, ahead, None)
+
+
+def _exact_choice(key, thr, k):
     """Where rows tie at the threshold: of the rows equal to it, the
     lowest as far as `k` reaches."""
     above = key > thr
@@ -220,49 +272,89 @@ def _exact_choice(scores, key, thr, k):
     return above | (equal & (rank < room))
 
 
-def topk_bias(scores: jax.Array, k: int, *, dtype=jnp.bfloat16,
-              interpret: Optional[bool] = None) -> jax.Array:
-    """scores (W, T, S) float32, `-inf` where a query may not look ->
-    (W, T, S) `dtype`: 0 at the `k` largest scores of each row that are
-    not `-inf` (ties to the lower column; all of them where fewer are
-    not `-inf`), `NEG_INF` elsewhere."""
+def _bias_of(chosen, scores, dtype):
+    return jnp.where(chosen & (scores > -jnp.inf), 0.0,
+                     NEG_INF).astype(dtype)
+
+
+def _topk_bias_xla(scores: jax.Array, k: int, dtype) -> jax.Array:
+    """`topk_bias` as XLA makes it, over whole rows: what a CPU and a
+    shape that does not tile take, and the definition the kernel is held
+    to."""
     W, T, S = scores.shape
-    k = min(int(k), S)
-    seen = scores > -jnp.inf
-    rows = _THRESHOLD_ROWS
-    if not (use_kernels(jnp.bfloat16, interpret) and T % rows == 0
-            and S % _LANES == 0):
-        _, idx = lax.top_k(scores, k)
-        chosen = jnp.zeros((W, T, S), bool)
-        chosen = chosen.at[jnp.arange(W)[:, None, None],
-                           jnp.arange(T)[None, :, None], idx].set(True)
-        return jnp.where(chosen & seen, 0.0, NEG_INF).astype(dtype)
-    thr, cnt = pl.pallas_call(
-        functools.partial(_threshold_kernel, k=float(k)),
-        grid=(W, T // rows),
-        in_specs=[pl.BlockSpec((None, rows, S), lambda b, i: (b, i, 0))],
-        out_specs=[pl.BlockSpec((None, rows, _LANES), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((None, rows, _LANES), lambda b, i: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((W, T, _LANES), jnp.int32),
+    _, idx = lax.top_k(scores, min(int(k), S))
+    chosen = jnp.zeros((W, T, S), bool)
+    return _bias_of(chosen.at[jnp.arange(W)[:, None, None],
+                              jnp.arange(T)[None, :, None], idx].set(True),
+                    scores, dtype)
+
+
+def _threshold_bias(scores, k: int, q_offset, dtype, interpret
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The kernel: (bias (W, T, S) `dtype` with every column at or above
+    a row's threshold chosen, the k-th largest ordered key a row (W, T,
+    1) int32, the columns at or above it (W, T, 1) float32)."""
+    W, T, S = scores.shape
+    rows, tc = _THRESHOLD_ROWS, _count_cols(S)
+    counted = S if q_offset is None else jnp.minimum(
+        columns_counted(q_offset, T, S), S)
+    bias, thr, cnt = pl.pallas_call(
+        functools.partial(
+            _threshold_kernel, k=float(k), tc=tc,
+            # Exact in `dtype`, so that the kernel's cast rounds nothing.
+            neg=float(np.float32(NEG_INF).astype(jnp.dtype(dtype)))),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(W, T // rows),
+            in_specs=[pl.BlockSpec((None, rows, S), lambda b, i, n: (b, i, 0))],
+            out_specs=[
+                pl.BlockSpec((None, rows, S), lambda b, i, n: (b, i, 0)),
+                pl.BlockSpec((None, rows, _LANES), lambda b, i, n: (b, i, 0)),
+                pl.BlockSpec((None, rows, _LANES), lambda b, i, n: (b, i, 0))],
+            scratch_shapes=[pltpu.VMEM((rows, S), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((W, T, S), dtype),
+                   jax.ShapeDtypeStruct((W, T, _LANES), jnp.int32),
                    jax.ShapeDtypeStruct((W, T, _LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=64 * 2 ** 20),
         interpret=bool(interpret),
         metadata={"kernel": "topk_threshold"},
-    )(scores)
-    thr, cnt = thr[..., :1], cnt[..., :1]
-    key = _ordered(scores)
-    # More rows at or above the threshold than k: some tie at it. Rare
-    # (two float32 sums alike), so the ranks are counted only then. A
-    # query that sees fewer than k rows has its threshold at `-inf` and
-    # every row at or above it: no tie to settle, `seen` chooses.
-    lowest = _ordered(jnp.float32(-jnp.inf))
-    chosen = lax.cond(jnp.any((cnt > k) & (thr > lowest)),
-                      lambda: _exact_choice(scores, key, thr, k),
-                      lambda: key >= thr)
-    return jnp.where(chosen & seen, 0.0, NEG_INF).astype(dtype)
+    )((jnp.asarray(counted, jnp.int32) // tc).reshape(1), scores)
+    return bias, thr[..., :1], cnt[..., :1]
 
+
+def _ties(thr, cnt, k: int) -> jax.Array:
+    """Whether any row has more columns at or above its threshold than
+    k: some tie at it. A query that sees fewer than k rows has its
+    threshold below every key: no tie to settle."""
+    return jnp.any((cnt > k) & (thr > _LOWEST))
+
+
+def topk_bias(scores: jax.Array, k: int, q_offset=None, *,
+              dtype=jnp.bfloat16, interpret: Optional[bool] = None
+              ) -> jax.Array:
+    """scores (W, T, S) float32, `-inf` where a query may not look ->
+    (W, T, S) `dtype`: 0 at the `k` largest scores of each row that are
+    not `-inf` (ties to the lower column; all of them where fewer are
+    not `-inf`), `NEG_INF` elsewhere. `q_offset`: the queries stand at
+    rows [q_offset, q_offset + T) of the columns' run and every score
+    from column q_offset + T on is `-inf`, as `index_scores_tile` leaves
+    them: the kernel reads none of those (`columns_counted`)."""
+    W, T, S = scores.shape
+    k = min(int(k), S)
+    if not (use_kernels(jnp.bfloat16, interpret)
+            and T % _THRESHOLD_ROWS == 0 and S % _LANES == 0):
+        return _topk_bias_xla(scores, k, dtype)
+    bias, thr, cnt = _threshold_bias(scores, k, q_offset, dtype, interpret)
+    # Ties are settled in XLA over whole rows, and only where there are
+    # any (two float32 sums alike at a row's k-th place: a tenth of the
+    # blocks of 1,024 queries in glm5-longctx's tiles, PERF.md section 5).
+    return lax.cond(
+        _ties(thr, cnt, k),
+        lambda: _bias_of(_exact_choice(_ordered(scores), thr, k), scores,
+                         dtype),
+        lambda: bias)
 
 # ---------------------------------------------------------------------------
 # Per-head attention under a chosen-set bias

@@ -450,9 +450,11 @@ class LLMEngine:
             # were to read (a slot's min(rows held, index_topk) a step;
             # its indexer scores every row held, `cache_rows_held`), and
             # the chunks its admission tiles ran of those their buckets
-            # have.
+            # have, with the columns their choices counted of those the
+            # buckets span.
             self.counts.update(sparse_rows_read=0,
-                               prefill_chunks=0, prefill_chunks_of=0)
+                               prefill_chunks=0, prefill_chunks_of=0,
+                               choice_columns=0, choice_columns_of=0)
         # Layers that keep a recurrent state a slot (`KVCache.s`: a
         # period stack's linear-attention layers).
         self._state_layers = 0 if self.cache.s is None \
@@ -888,13 +890,20 @@ class LLMEngine:
             # A tile walked a chunk at a time (models/latent.py): the
             # chunks it runs, to the longest prompt's last token (a
             # queue-side tile has no lengths and runs them all), of
-            # those its bucket has.
-            run, of = stack(self.cfg).prefill_chunks(
-                self.cfg, bucket, bucket if side == "queue"
-                else max(len(r.prompt) - skip for r in reqs))
+            # those its bucket has; and the columns its blocks of queries
+            # count to choose their rows, of those the bucket spans.
+            longest = bucket if side == "queue" else max(
+                len(r.prompt) - skip for r in reqs)
+            run, of = stack(self.cfg).prefill_chunks(self.cfg, bucket,
+                                                     longest)
+            cols, cols_of = stack(self.cfg).choice_columns(self.cfg, bucket,
+                                                           longest)
             c["prefill_chunks"] += run
             c["prefill_chunks_of"] += of
-            more = dict(more, chunks=run, chunks_of=of)
+            c["choice_columns"] += cols
+            c["choice_columns_of"] += cols_of
+            more = dict(more, chunks=run, chunks_of=of, choice_columns=cols,
+                        choice_columns_of=cols_of)
         if self._state_layers:
             pairs = tokens * self._state_layers
             c["linear_tokens"] += pairs

@@ -1232,7 +1232,14 @@ def test_glm_admission_tile_walks_the_longest_bucket_in_chunks(
     one program (a loop the device counts), each chunk's scorer, exact
     threshold and masked per-head attention as kernels; it compiles for
     the described chip beside 7.82 GB of weights and 4.70 GB of caches,
-    both rows-major throughout."""
+    both rows-major throughout. A chunk's temporaries, 2.44 GB: a block
+    of 1,024 queries' float32 scores against the bucket (134 MB), the
+    two blocks' biases as the threshold kernel writes them and as the
+    chunk stacks them (bf16, 67 MB a block), the layers' activations and
+    the attention's parts; the scores' ordered keys, the chosen set as a
+    bool and the select over (1,024 x 32,768) exist on the tie path of
+    `topk_bias`'s `cond` alone (2.67 GB while XLA made them for every
+    block)."""
     from ray_tpu.models import latent
     from ray_tpu.models.generate import prefill_sample_batch
     from ray_tpu.serve.llm import LLMEngine
@@ -1255,7 +1262,12 @@ def test_glm_admission_tile_walks_the_longest_bucket_in_chunks(
     for kernel in ("index_scores_tile", "topk_threshold",
                    "sparse_prefill_attn"):
         assert kernel in text, kernel
+    passes = [line for line in text.splitlines()
+              if "[1,1024,32768]" in line
+              and re.search(r" (xor|select|compare)\(", line)]
+    assert passes and all("cond/branch_1_fun" in line for line in passes)
     _glm_fits(serve_glm, mem, record_property)
+    assert mem.temp_size_in_bytes < 2.5e9
 
 
 # -- solar-open2-rollout-closed: a recurrent state beside keys and values ----

@@ -310,6 +310,49 @@ def test_a_chunked_tile_equals_the_same_tile_in_one_chunk(params,
     assert latent.chunk_rows(configs.tiny_pangu_test(), 64) == 64
 
 
+def test_a_tile_counts_the_columns_its_choices_count(params, chunk_of_16):
+    """`engine.prefill_tile`'s `choice_columns` of `choice_columns_of`:
+    what `_attend_chunk` tells `topk_bias` of where each block of queries
+    stands, reckoned on the host."""
+    from types import SimpleNamespace
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    real = dataclasses.replace(CFG, index_topk=2048)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(latent, "PREFILL_CHUNK", 2048)
+        # 20,000 tokens run ten chunks of the 32,768 bucket's sixteen:
+        # twenty blocks of 1,024 queries see 1,024 ... 20,480 columns.
+        assert latent.choice_columns(real, 32768, 20000) == (
+            210 * 1024, 640 * 1024)
+        assert latent.choice_columns(real, 32768, 32768) == (
+            528 * 1024, 1024 * 1024)
+        assert latent.choice_columns(real, 16384, 9000) == (
+            55 * 1024, 160 * 1024)
+        # One chunk no longer than `index_topk`: every row is chosen.
+        assert latent.choice_columns(real, 2048, 1500) == (0, 0)
+        assert latent._choice_rows(2048) == 1024
+        assert sa.columns_counted(np.asarray([4096, 5120]), 1024,
+                                  32768).tolist() == [5120, 6144]
+    # 70 tokens: five chunks of 16 of the 128 bucket's eight, one block of
+    # queries each, one block of 128 columns counted for each.
+    eng = LLMEngine(CFG, params, num_slots=2, max_seq_len=128, seed=0)
+    req = SimpleNamespace(prompt=[1] * 70, id=7)
+    tile = eng._tile_span("slot", 128, 1, [req])
+    assert (tile.attributes["choice_columns"],
+            tile.attributes["choice_columns_of"]) == (5 * 128, 5 * 128)
+    eng._tile_span("queue", 128, 1, [req])
+    assert (eng.counts["choice_columns"],
+            eng.counts["choice_columns_of"]) == (13 * 128, 13 * 128)
+    # A stack without an indexer chooses nothing and counts nothing.
+    pangu = configs.tiny_pangu_test()
+    eng = LLMEngine(pangu, jax.jit(lambda k: init_params(pangu, k))(
+        jax.random.key(2)), num_slots=2, max_seq_len=128, seed=0)
+    tile = eng._tile_span("slot", 128, 1, [req])
+    assert not {"choice_columns", "chunks"} & set(tile.attributes)
+    assert not {"choice_columns", "prefill_chunks"} & set(eng.counts)
+
+
 # -- the tie between the stack's two architectures --------------------------------
 
 def test_with_every_row_chosen_the_attention_is_the_first_architectures(
@@ -475,6 +518,73 @@ def test_the_threshold_kernel_chooses_exactly_k(ties):
     assert np.array_equal((got == 0).sum(-1)[0],
                           np.minimum(seen.sum(-1), 64))
     assert set(np.unique(got)) == {0.0, np.float32(NEG_INF)}
+
+
+# What a block of queries at `q_offset` hands `topk_bias`: (S, T, k,
+# q_offset, what the scores hold). Columns come 1,024 to a block, so 3,072
+# are three; T = 32 is two blocks of the kernel's rows.
+CHOICES = {
+    # Rows see 1 ... 32 columns: fewer than k, k and more than k.
+    "first_block": (2048, 32, 16, 0, "normal"),
+    "k_above_every_row": (2048, 32, 64, 0, "normal"),
+    "mid_bucket": (3072, 32, 64, 1024, "normal"),
+    "last_block": (3072, 32, 64, 3072 - 32, "normal"),
+    # The last query's column is the 1,031st: two column blocks counted.
+    "edge_inside_a_column_block": (3072, 32, 64, 1000, "normal"),
+    "k_of_the_bucket": (2048, 32, 2048, 2048 - 32, "normal"),
+    "no_offset": (2048, 32, 64, None, "normal"),
+    # Whatever stands past the counted columns is not read.
+    "unread_past_the_edge": (3072, 32, 64, 1000, "poison"),
+    # Dozens at the threshold in every second row (the cond's path).
+    "ties_beside_none": (3072, 32, 64, 1024, "ties"),
+    "ties_in_the_first_block": (2048, 32, 16, 0, "ties"),
+    # The k-th place at 1e-40, 0.0, -0.0, -1e-40, -0.5 or -0.75 by the row,
+    # equal negative values below it; then the same values drawn, so that
+    # rows tie at them; then fewer columns than k of them.
+    "threshold_at_zeros_and_denormals": (2048, 32, 48, 1024, "ladder"),
+    "ties_at_zeros_and_denormals": (2048, 32, 48, 1024, "drawn"),
+    "zeros_below_k_columns": (2048, 32, 48, 0, "drawn"),
+}
+_LADDER = [1e-40, 0.0, -0.0, -1e-40, -0.5, -0.75]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("case", sorted(CHOICES))
+def test_the_choice_kernel_is_the_definition_bit_for_bit(case, dtype):
+    """`topk_bias` as one kernel that counts the columns its block may
+    see (the interpreter) against its definition in XLA over whole
+    rows."""
+    S, T, k, q_offset, holds = CHOICES[case]
+    rng = np.random.default_rng(sorted(CHOICES).index(case))
+    scores = rng.normal(size=(1, T, S)).astype(np.float32)
+    if holds == "ties":
+        scores[:, ::2] = np.round(scores[:, ::2] * 2) / 2
+    if holds == "drawn":
+        scores = rng.choice(np.asarray(_LADDER + [-1.5, 0.5], np.float32),
+                            size=(1, T, S))
+    if holds == "ladder":
+        for t in range(T):
+            row = np.full(q_offset + t + 1, -1.5, np.float32)
+            above = k - 1 - t % len(_LADDER)
+            row[:above] = 1 + rng.permutation(above) / above
+            row[above:above + len(_LADDER)] = _LADDER
+            scores[0, t, :len(row)] = rng.permutation(row)
+    first = 0 if q_offset is None else q_offset
+    seen = np.arange(S)[None, :] <= first + np.arange(T)[:, None] \
+        if q_offset is not None else np.ones((T, S), bool)
+    scores = np.where(seen[None], scores, -np.inf).astype(np.float32)
+    want = np.asarray(sa._topk_bias_xla(jnp.asarray(scores), k, dtype))
+    if holds == "poison":
+        counted = sa.columns_counted(q_offset, T, S)
+        assert counted == 2048 and not seen[:, counted:].any()
+        scores[..., counted:] = np.nan
+    got = np.asarray(jax.jit(partial(sa.topk_bias, k=k, dtype=dtype,
+                                     interpret=True))(
+        jnp.asarray(scores), q_offset=q_offset))
+    assert got.dtype == want.dtype == dtype
+    assert np.array_equal(got.view(np.uint8), want.view(np.uint8))
+    assert np.array_equal((got == 0).sum(-1)[0],
+                          np.minimum(seen.sum(-1), k))
 
 
 def test_the_masked_attention_kernel_and_the_merge_of_its_parts():
