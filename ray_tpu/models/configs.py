@@ -146,6 +146,20 @@ def tiny_jamba_test(vocab: int = 256, offset: int = 2) -> TransformerConfig:
         mamba_d_state=16, mamba_expand=2, mamba_dt_rank=8, mamba_d_conv=4)
 
 
+def tiny_ouro_test(vocab: int = 256, ut_steps: int = 3,
+                   threshold: float = 1.0) -> TransformerConfig:
+    """The period stack looped at a unit-test size: three sandwich-normed
+    layers (4 heads over 4 KV heads of 16, rotary, a dense SwiGLU) walked
+    `ut_steps` times a token, nine cache slabs, an exit gate a pass, the
+    head untied. For the tests only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        head_dim=16, d_ff=128, max_seq_len=128, rope_theta=1e6,
+        norm_eps=1e-6, dtype=jnp.float32, param_dtype=jnp.float32,
+        remat=False, tie_embeddings=False, arch="ouro", global_attn_every=1,
+        ut_steps=ut_steps, early_exit_threshold=threshold)
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -204,6 +218,7 @@ NAMED = {
     "tiny_glm": tiny_glm_test,
     "tiny_solar": tiny_solar_test,
     "tiny_jamba": tiny_jamba_test,
+    "tiny_ouro": tiny_ouro_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

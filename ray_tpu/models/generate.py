@@ -135,8 +135,8 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache,
     `length` = real prompt length; `slot` = cache row. Compiles once per
     (S_bucket,) — callers bucket prompt lengths.
     """
-    cache, logits, _ = _prefill_batch_core(cfg, params, cache, tokens,
-                                           length[None], slot[None])
+    cache, logits, _, _ = _prefill_batch_core(cfg, params, cache, tokens,
+                                              length[None], slot[None])
     return cache, logits[0]
 
 
@@ -161,15 +161,25 @@ def sample_logp(logits: jax.Array, temps: jax.Array, key: jax.Array,
     return toks, token_logp(logits, toks)
 
 
+def _last_exits(exits, lengths):
+    """A looped stack's exit passes (W, S) at each row's last real
+    position -> (W,)."""
+    return jnp.take_along_axis(
+        exits, (lengths - 1).astype(jnp.int32)[:, None], axis=1)[:, 0]
+
+
 def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
                         tokens: jax.Array, lengths: jax.Array,
                         slots: jax.Array):
     """Batched-prefill body: write each prompt's KV into its slot,
     return (cache', last-real-token logits (W, V), the tile's routing
-    stats or None: `routed_layers`)."""
+    stats or None: `routed_layers`, the exit pass (W,) behind those
+    logits or None: a looped configuration's, `cfg.ut_steps` > 1)."""
     st = stack(cfg)
-    cache, x, stats = st.prefill(cfg, params, cache, tokens, lengths, slots)
-    return cache, st.last_logits(cfg, params, x, lengths), stats
+    cache, x, stats, *exits = st.prefill(cfg, params, cache, tokens, lengths,
+                                         slots)
+    return cache, st.last_logits(cfg, params, x, lengths), stats, \
+        _last_exits(exits[0], lengths) if exits else None
 
 
 @program("prefill_sample_batch", static_argnums=(0, 6), donate_argnums=(2,))
@@ -180,7 +190,8 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     """Prefill a BATCH of padded prompts (W, S_bucket) into their cache
     slots and sample each one's first token in ONE dispatch. Returns
     (cache', first tokens (W,), their log-probabilities (W,)[, routing
-    stats of the tile's W x S_bucket positions: `routed_layers`]).
+    stats of the tile's W x S_bucket positions: `routed_layers`][, each
+    token's exit pass (W,) int32 from 0: a looped configuration's]).
 
     Every row shares one read of the weights. While that read bounds
     the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
@@ -192,10 +203,10 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     is out of range (the tile's padding) are dropped by the scatter and
     their sampled token is garbage the caller ignores. Compiles once
     per (W, S_bucket)."""
-    cache, logits, stats = _prefill_batch_core(cfg, params, cache, tokens,
-                                               lengths, slots)
+    cache, logits, stats, exits = _prefill_batch_core(
+        cfg, params, cache, tokens, lengths, slots)
     out = (cache,) + sample_logp(logits, temps, key, top_k)
-    return out if stats is None else out + (stats,)
+    return out + tuple(a for a in (stats, exits) if a is not None)
 
 
 @program("prefill_suffix_batch", static_argnums=(0, 8), donate_argnums=(2,))
@@ -290,7 +301,8 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
                        key: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """First token for a BATCH of prompts without touching any KV cache
     (tokens (W, S_bucket), lengths (W,), temps (W,) → (tokens (W,),
-    their log-probabilities (W,))).
+    their log-probabilities (W,)[, their exit passes (W,): a looped
+    configuration's])).
 
     The serving engine uses this to give QUEUED requests their first
     token while every cache slot is busy — TTFT decoupled from slot
@@ -299,9 +311,9 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
     slot's cur_token), so no recomputed sample can diverge from what
     the client already saw."""
     st = stack(cfg)
-    x, _ = st.forward_free(cfg, params, tokens)
+    x, _, *exits = st.forward_free(cfg, params, tokens)
     return sample_logp(st.last_logits(cfg, params, x, lengths), temps, key,
-                       top_k)
+                       top_k) + tuple(_last_exits(e, lengths) for e in exits)
 
 
 @program("decode_step", static_argnums=(0,), donate_argnums=(2,))
@@ -314,7 +326,7 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     simply ignores their output and reuses the slot via prefill. `live`
     (B,) bool: the slots a request owns (None: all of them); the others'
     cache rows are not read (`stackparts._attend_cache`)."""
-    cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens, live)
+    cache, logits, *_ = stack(cfg).decode(cfg, params, cache, tokens, live)
     return cache, logits
 
 
@@ -342,7 +354,9 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
     temperature; live: (B,) bool, the slots a request owns (`decode_step`).
     Returns (cache', toks (num_steps, B), `token_logp` of
-    each (num_steps, B) float32[, routing stats: `routed_layers`]). The
+    each (num_steps, B) float32[, routing stats: `routed_layers`][, each
+    token's exit pass (num_steps, B) int32 from 0: a looped
+    configuration's, `cfg.ut_steps` > 1]). The
     host engine truncates per-slot output at eos/max_new_tokens — slots
     that finish mid-block burn at most num_steps-1 wasted ticks, the
     price of one dispatch and one host fetch per num_steps tokens. The
@@ -364,19 +378,20 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
 
     def body(carry, sub):
         cache, tok, routed = carry
-        cache, logits, stats = st.decode(cfg, params, cache, tok, live)
+        cache, logits, stats, *exits = st.decode(cfg, params, cache, tok,
+                                                 live)
         tok, lp = sample_logp(logits, temps, sub, top_k)
         if stats is not None:
             routed = routed + stats
-        return (cache, tok, routed), (tok, lp)
+        return (cache, tok, routed), (tok, lp, *exits)
 
     subs = jax.random.split(key, num_steps)
     routed = jnp.zeros((st.routing_stats(cfg),), jnp.int32) \
         if routed_layers(cfg) else None
-    (cache, _, routed), (toks, lps) = lax.scan(
+    (cache, _, routed), (toks, lps, *exits) = lax.scan(
         body, (cache, tokens, routed), subs)
-    return (cache, toks, lps) if routed is None \
-        else (cache, toks, lps, routed)
+    return (cache, toks, lps) + (() if routed is None else (routed,)) \
+        + tuple(exits)
 
 
 decode_multi = _BlockPrograms("decode_multi", _decode_multi,

@@ -52,7 +52,9 @@ One layer definition (`layer`) and one walk (`stackparts.run`) serve
 prefill, the cache-free first token and decode; they differ in the
 `attend` they hand in, which owns the cache.
 
-The cache holds three kinds of state in one `KVCache`: `k`/`v` for the
+Cache slabs and weight layers are counted apart (`cache_layers` counts
+the slabs; nothing outside this module sizes a cache from
+`cfg.n_layers`). The cache holds three kinds of state in one `KVCache`: `k`/`v` for the
 global layers, (Lg, slots, S_max, KVH, Dh); `kw`/`vw` for the window
 layers, (Lw, slots, min(sliding_window, S_max), KVH, Dh), a ring written
 at `position mod rows` (softmax does not care in which order the ring
@@ -67,6 +69,15 @@ the tokens held and are final once written; a state is rewritten whole
 by every step of its slot, so a tile writes it as the prompt's last
 real token left it, padding changes nothing, and a slot nobody owns is
 not written.
+
+A looped configuration (`cfg.ut_steps` > 1: `_walk`) runs the same
+leaves that many times a token, the final norm after each pass and its
+output the next pass's input. Pass t of global layer l keeps slab
+t x Lg + l of `k`/`v`, (ut_steps x Lg, slots, S_max, KVH, Dh), written by
+pass t and read by pass t alone; every pass runs and writes its slab
+whatever the exit gate says, and a row's hidden state, logits and exit
+pass are those `stackparts.exit_select` picks from the gate's mass. One
+walk serves prefill, the cache-free first token and decode here too.
 
 A configuration that generates by diffusion over blocks
 (`cfg.block_length`: every layer global) takes the same layer and the
@@ -136,7 +147,16 @@ MISSING = {
                      "backward of windowed flash attention, of the delta "
                      "rule's chunked scan and of the selective scan's "
                      "kernel, and the load-balancing update of the "
-                     "selection bias",
+                     "selection bias; a looped walk (ut_steps) under "
+                     "value_and_grad is not written either",
+    # A looped configuration runs every pass for every token, as the
+    # published implementation does.
+    "early_stop": "a looped walk that stops at a token's exit pass (the "
+                  "passes behind it skipped, their slabs left unwritten) "
+                  "is not written: every pass runs whatever the gate says",
+    "shared_slabs": "one cache slab shared by several passes of a layer "
+                    "(the cache-sharing variants of arXiv:2510.25741) is "
+                    "not written: a slab a (pass, layer)",
 }
 # What a configuration with `block_length` lacks of this stack, and one
 # without it of the block walk.
@@ -201,8 +221,8 @@ def routed_layers(cfg: TransformerConfig) -> int:
     return stackparts.routed_layers(layer_plan(cfg))
 
 
-def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
-    """How many layers keep each kind of state (a recurrent kind where
+def _layers_of(cfg: TransformerConfig) -> Dict[str, int]:
+    """How many weight layers are of each kind (a recurrent kind where
     the form has such layers)."""
     recurrent = cfg.period_form.recurrent
     return {kind: sum(group.lead[0] * kinds.count(kind)
@@ -210,6 +230,13 @@ def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
                                               step_kinds(cfg)))
             for kind in (WINDOW, GLOBAL) + ((recurrent,) if recurrent
                                             else ())}
+
+
+def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
+    """How many cache slabs keep each kind of state: one a layer of the
+    kind, times the passes a token walks (`cfg.ut_steps`), each (pass,
+    layer) its own. Not the count of weight layers."""
+    return {kind: cfg.ut_steps * n for kind, n in _layers_of(cfg).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -580,6 +607,47 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
                           leaves_at if cfg.period_form.recurrent else None)
 
 
+def _walk(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
+    """The whole stack on x, what `prefill`, `forward_free` and `decode`
+    share: `_run`, as it returns. A looped configuration (`cfg.ut_steps`
+    > 1): `_run` that many times over the same leaves, a scan step a
+    pass under the scope `ut_pass`, the final norm after each pass and
+    its output the next pass's input; pass t tells `attend` the cache
+    slab t x (layers of the kind) + l. Every pass runs and writes its
+    slab whatever the exit gate says, and x comes back as every pass's
+    final-normed output, (ut_steps, ..., D). `_leave` makes either x
+    the rows' final-normed state."""
+    if cfg.ut_steps == 1:
+        return _run(cfg, params, x, rope, attend, state, rows)
+    per_pass = _layers_of(cfg)
+
+    def one(carry, t):
+        x, state = carry
+        with jax.named_scope("ut_pass"):
+            x, state, _, _ = _run(
+                cfg, params, x, rope,
+                lambda l, kind, *a: attend(t * per_pass[kind] + l, kind, *a),
+                state, rows)
+            x = _final(cfg, params, x)
+        return (x, state), x
+
+    (_, state), hs = lax.scan(one, (x, state), jnp.arange(cfg.ut_steps))
+    return hs, state, None, ()
+
+
+def _leave(cfg: TransformerConfig, params, x):
+    """`_walk`'s x -> (each row's final-normed state, what a looped
+    configuration's walks return behind their other results
+    (`transformer.STACKS`' interface): each row's exit pass, x's shape
+    without D; nothing where one pass is the walk): the final norm, or,
+    of a looped configuration's passes, the one `stackparts.exit_select`
+    picks a row."""
+    if cfg.ut_steps == 1:
+        return _final(cfg, params, x), ()
+    x, exits, _ = stackparts.exit_select(cfg, params, x)
+    return x, (exits,)
+
+
 def _embed(cfg: TransformerConfig, params, tokens):
     x = params["embed"][tokens].astype(jnp.float32)
     if cfg.period_form.embed_scale:
@@ -653,6 +721,16 @@ def _put(cfg, cache, l, slots, rows):
     """rows (W, R, KVH, Dh) into layer l of `cache`, rows [0, R) of each
     slot (a slot out of range is dropped)."""
     R = rows.shape[1]
+    if cfg.ut_steps > 1 and rows.shape[0] == 1:
+        # A looped tile of one row writes as a tile of two, the twin aimed
+        # past the cache's end and dropped. XLA turns a scatter at one
+        # index into a dynamic-update-slice, and behind a walk of several
+        # passes it then lays the whole carried cache out as the tile's
+        # keys lie for their product (rows minor) instead of copying the
+        # tile: two copies of 3.75 GB in and out at Ouro-2.6B's 192 slabs,
+        # which the compiler refuses for memory (PERF.md section 6, PR 55).
+        rows = jnp.concatenate([rows, rows])
+        slots = jnp.concatenate([slots, jnp.full_like(slots, cache.shape[1])])
     if cache_terms(cfg) == 1:
         return cache.at[l, slots, :R].set(rows.astype(cache.dtype),
                                           mode="drop")
@@ -843,14 +921,17 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     gives a step's, over all W x S positions, padding too; None with no
     routed layer). With `cfg.block_length` the mask is block-causal and
     `lengths` are whole blocks (what is left of a prompt opens the
-    slot's first block: `generate.prefill_block_batch`)."""
+    slot's first block: `generate.prefill_block_batch`). A looped
+    configuration: x is each position's state at its exit pass, and the
+    exit passes (W, S) follow (`_walk`)."""
     rope = rope_by_kind(cfg, tokens.shape[1])
-    x, state, stats, _ = _run(
+    x, state, stats, _ = _walk(
         cfg, params, _embed(cfg, params, tokens), rope,
         partial(_prefill_attend, cfg, slots, lengths), _state(cache))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
-    return _cache(state, seq_lens), _final(cfg, params, x), \
-        stats if routed_layers(cfg) else None
+    x, exits = _leave(cfg, params, x)
+    return (_cache(state, seq_lens), x,
+            stats if routed_layers(cfg) else None) + exits
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):
@@ -869,9 +950,10 @@ def forward_free(cfg: TransformerConfig, params, tokens):
             lambda a: jnp.moveaxis(a, 0, 1).reshape(
                 a.shape[1], W * S, a.shape[-1]), chosen)
     rope = rope_by_kind(cfg, tokens.shape[1])
-    x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
-                           partial(_free_attend, cfg), None)
-    return _final(cfg, params, x), chosen
+    x, _, _, chosen = _walk(cfg, params, _embed(cfg, params, tokens), rope,
+                            partial(_free_attend, cfg), None)
+    x, exits = _leave(cfg, params, x)
+    return (x, chosen) + exits
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
@@ -881,17 +963,21 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     that took a row, pairs routed, the pairs of the expert most chosen,
     rows the experts took; None with no routed layer). `live` (B,) bool:
     the slots a request owns (None: every one): any other slot reads and
-    writes no cache row and its token meets no expert."""
+    writes no cache row and its token meets no expert. A looped
+    configuration: the logits of each slot's exit pass, and the exit
+    passes (B,) follow (`_walk`)."""
     if cfg.block_length:
         raise NotImplementedError(NOT_ITS_WALK["decode"])
     positions = cache.seq_lens
     rope = rope_by_kind(cfg, cache.max_seq_len, positions)
-    x, state, stats, _ = _run(
+    x, state, stats, _ = _walk(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
         partial(_decode_attend, cfg, positions, live), _state(cache), live)
     cache = _cache(state, positions + 1)
-    return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
-        stats if routed_layers(cfg) else None
+    x, exits = _leave(cfg, params, x)
+    return (cache, head_logits(cfg, params, x[:, 0]),
+            stats if routed_layers(cfg) else None) \
+        + tuple(e[:, 0] for e in exits)
 
 
 def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,

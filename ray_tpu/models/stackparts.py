@@ -66,7 +66,13 @@ class KVCache(NamedTuple):
     dtype, the last inputs of the layer's short convolutions. An
     admission tile writes both as the prompt's last token left them
     (`seq_lens` hides a stale row; nothing would hide a stale state);
-    None anywhere else."""
+    None anywhere else.
+
+    The leading axis counts cache slabs, not weight layers: a stack
+    that walks its layers `cfg.ut_steps` times a token
+    (`models/periodic.py`) keeps pass t of layer l at slab t x L + l,
+    (ut_steps x L, B, S_max, KVH, Dh), each written and read by its own
+    pass alone."""
 
     k: Optional[jax.Array]
     v: Optional[jax.Array]
@@ -263,7 +269,8 @@ def ffn_half(cfg: TransformerConfig, lp, x, experts_at, post_norms: bool,
                                 lp["shared_up"], lp["shared_down"])
         f = f.reshape(B, S, -1)
     else:
-        f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
+        with jax.named_scope("ffn"):
+            f = _swiglu(m.astype(dt), lp["w_gate"], lp["w_up"], lp["w_down"])
     gain = lp["post_ffn_norm"] if post_norms else None
     return joins(x, f, gain, eps), stats, experts
 
@@ -283,6 +290,35 @@ def head_logits(cfg: TransformerConfig, params, x) -> jax.Array:
 def last_logits(cfg: TransformerConfig, params, x, lengths) -> jax.Array:
     """Logits (W, V) at the last real position of final-normed x (W, S, D)."""
     return head_logits(cfg, params, _last_rows(x, lengths)[:, 0])
+
+
+def exit_select(cfg: TransformerConfig, params, hs):
+    """Where a looped stack's rows leave it. hs (T, ..., D): the
+    final-normed output of each of the T = `cfg.ut_steps` passes ->
+    (each row's state at its exit pass (..., D), the exit pass (...,)
+    int32 from 0, the exit mass (..., T) float32). The gate of pass t,
+    `lam_t = sigmoid(h_t . w + b)`, gives the mass `p_t = lam_t x
+    prod_{j<t} (1 - lam_j)`, the last pass the rest; a row exits at the
+    first pass whose cumulative mass reaches
+    `cfg.early_exit_threshold`, else at the last. Float32 on the vector
+    unit (a product of two float32 vectors on the matrix unit would
+    take bf16 passes)."""
+    f32 = jnp.float32
+    gate = params["exit_gate"]
+    lam = jax.nn.sigmoid(jnp.moveaxis(
+        jnp.sum(hs.astype(f32) * gate["w"].astype(f32), axis=-1), 0, -1)
+        + gate["b"].astype(f32))                               # (..., T)
+    stay = jnp.cumprod(1.0 - lam, axis=-1)
+    before = jnp.concatenate(
+        [jnp.ones_like(stay[..., :1]), stay[..., :-1]], axis=-1)
+    mass = jnp.concatenate(
+        [(lam * before)[..., :-1], before[..., -1:]], axis=-1)
+    reached = jnp.cumsum(mass, axis=-1) >= cfg.early_exit_threshold
+    last = hs.shape[0] - 1
+    exits = jnp.where(jnp.any(reached, axis=-1),
+                      jnp.argmax(reached, axis=-1), last).astype(jnp.int32)
+    x = jnp.take_along_axis(hs, exits[None, ..., None], axis=0)[0]
+    return x, exits, mass
 
 
 # ---------------------------------------------------------------------------
@@ -332,6 +368,8 @@ def num_params(cfg: TransformerConfig, plan: Sequence[Group],
                layer_shapes) -> int:
     total = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2) \
         + cfg.d_model
+    if cfg.ut_steps > 1:
+        total += cfg.d_model + 1                    # the exit gate
     for group in plan:
         for shape in layer_shapes(cfg, group.routed).values():
             if isinstance(shape, dict):     # one layer of a step's own
@@ -347,7 +385,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
                 draws=None) -> Dict[str, Any]:
     """Scaled-normal weights as `transformer.init_params` makes them:
     norm gains one, the selection bias zero, residual-branch outputs
-    scaled down by depth; `layer_shapes(cfg, routed)` says a layer's
+    scaled down by the depth a token walks (`n_layers` x `ut_steps`); a
+    looped configuration's `exit_gate` (w (D,), b ()) drawn as the rest,
+    from a key folded out of `key` so that no other leaf's draw moves;
+    `layer_shapes(cfg, routed)` says a layer's
     leaves by name, each with its shape. Leaves that one layer of a scan
     step has and the step's others lack are a dict under a name of that
     layer's, stacked under the group's steps alone (a step hands the
@@ -371,6 +412,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
               "final_norm": jnp.ones((d,), dtype=pd)}
     if not cfg.tie_embeddings:
         params["lm_head"] = normal(k_head, (d, cfg.vocab_size), 0.02)
+    if cfg.ut_steps > 1:
+        k_w, k_b = jax.random.split(jax.random.fold_in(key, cfg.ut_steps))
+        params["exit_gate"] = {"w": normal(k_w, (d,), 0.02),
+                               "b": normal(k_b, (), 0.02)}
+
     def draw(leaf, full, k):
         if leaf in draws:
             return draws[leaf](k, full).astype(pd)
@@ -379,7 +425,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
         if leaf == "router_bias":
             return jnp.zeros(full, dtype=pd)
         if leaf in ("wo", "w_down", "shared_down"):
-            return normal(k, full, 0.02 / math.sqrt(2 * cfg.n_layers))
+            return normal(k, full, 0.02 / math.sqrt(
+                2 * cfg.n_layers * cfg.ut_steps))
         return normal(k, full, 0.02)
 
     for group, k_group in zip(plan, jax.random.split(k_layers, len(plan))):

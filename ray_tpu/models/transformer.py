@@ -38,7 +38,13 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 # architecture's stack. The one place an architecture is named: to add
 # one, write its module and add its line. A stack module offers
 #   weights  init_params(cfg, key), num_params(cfg)
-#   cache    init_cache(cfg, num_slots, max_seq_len) -> stackparts.KVCache
+#   cache    init_cache(cfg, num_slots, max_seq_len) -> stackparts.KVCache:
+#              what it holds is the stack's own count. Cache slabs and
+#              weight layers are counted apart: a stack keeps a slab for
+#              each layer that keeps rows (`periodic.cache_layers`), and
+#              one that walks its layers `cfg.ut_steps` times keeps one
+#              for each (pass, layer). Nothing above the stack sizes a
+#              cache from `cfg.n_layers`
 #   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
 #              -> (cache', final-normed hidden states (W, S, D),
 #                  routing stats or None)
@@ -67,6 +73,14 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #            routed_layers(cfg): the layers the stats count over
 #            routing_stats(cfg): how many entries the stats have, for a
 #              stack with routed layers
+#            A stack that serves `cfg.ut_steps` > 1 (the same layers
+#            walked that many times a token, a learned exit gate a pass:
+#            `stackparts.exit_select`) hands back every row's hidden
+#            state and logits at that row's exit pass, and `prefill`,
+#            `forward_free` and `decode` return one value more, last: the
+#            exit pass of each row, int32 from 0, (W, S) or (B,). A stack
+#            that does not walk loops refuses the configuration
+#            (`TransformerConfig.__post_init__`)
 # and, where it has them (`offered`): `suffix` (the walk behind a shared
 # prefix), `param_logical_axes` (sharding rules), `forward_train` (the
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one
@@ -74,7 +88,8 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
                           "mellum": "periodic", "pangu_ultra_moe": "latent",
                           "sdar_moe": "periodic", "glm_moe_dsa": "latent",
-                          "solar_open2": "periodic", "jamba": "periodic"}
+                          "solar_open2": "periodic", "jamba": "periodic",
+                          "ouro": "periodic"}
 
 # How a block of `TransformerConfig.block_length` positions is unmasked
 # (models/generate.py, `_unmask`): the names a request or a configuration
@@ -136,6 +151,16 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     "jamba": PeriodForm(attn_gate=False, post_norms=False, embed_scale=False,
                         router_bias=False, rotary=(), qk_norm=False,
                         recurrent="ssm", global_at=None),
+    # ByteDance Ouro (a looped language model): a sandwich-normed layer
+    # (four norms, the second of each pair on the branch's output), MHA
+    # with rotary, no q/k norm, no gate, a dense SwiGLU; what is its own
+    # is the walk (`ut_steps` passes over the same layers, the final norm
+    # after each, an exit gate a pass). Served as published: every pass
+    # runs and keeps its own cache slab whatever the gate says. Not
+    # served: skipping the passes behind a token's exit, and one slab
+    # shared by several passes (the paper's cache-sharing variants).
+    "ouro": PeriodForm(attn_gate=False, post_norms=True, embed_scale=False,
+                       router_bias=False, rotary=("global",), qk_norm=False),
 }
 
 
@@ -242,8 +267,9 @@ class TransformerConfig:
     # "solar_open2": the period stack again, a global layer then
     # linear-attention layers (`linear_n_heads`, at the end). "jamba":
     # the period stack with state-space layers (`mamba_d_state`, at the
-    # end) around a global layer at `attn_layer_offset`. STACKS above
-    # holds the names.
+    # end) around a global layer at `attn_layer_offset`. "ouro": the
+    # period stack, every layer global, walked `ut_steps` times a token
+    # (at the end). STACKS above holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length: one layer of it is global
@@ -326,6 +352,17 @@ class TransformerConfig:
     # value a row, half the bytes; a decode step's attention then takes
     # bf16 operands, a tile's attention over itself stays float32.
     cache_dtype: Any = None
+    # A looped stack (models/periodic.py), under the published keys'
+    # meaning: a token walks the same `n_layers` weight layers `ut_steps`
+    # times, the final norm after each pass and its output the next
+    # pass's input; pass t of layer l keeps cache slab t x (layers of l's
+    # kind) + l and attends to pass t's rows only. A learned gate reads
+    # each pass's output; a token's logits are those of the first pass
+    # whose cumulative exit mass reaches `early_exit_threshold`, else of
+    # the last (1.0, the published value: the last). 1 = every other
+    # configuration's walk.
+    ut_steps: int = 1
+    early_exit_threshold: float = 1.0
     block_length: int = 0
     mask_token_id: int = 0
     denoise_steps: int = 0           # 0 = `block_length`
@@ -384,6 +421,17 @@ class TransformerConfig:
                 "projections with no bias (mamba_proj_bias is not written) "
                 "and its global layer at attn_layer_offset < "
                 "global_attn_every")
+        if self.ut_steps != 1 and (
+                self.ut_steps < 1 or STACKS[self.arch] != "periodic"
+                or recurrent or self.sliding_window or self.is_moe
+                or self.block_length):
+            raise ValueError(
+                f"ut_steps {self.ut_steps}: only the period stack walks its "
+                "layers more than once a token, and only layers that keep "
+                "every row, with a dense FFN, one token a step (no "
+                "recurrent kind, sliding_window 0, no experts, no "
+                "block_length): a state, a ring, routing stats or an open "
+                "block a pass is not written")
         if self.index_topk:
             if STACKS[self.arch] != "latent" or self.index_n_heads < 1 \
                     or self.index_head_dim < self.qk_rope_head_dim \

@@ -467,6 +467,19 @@ class LLMEngine:
             # recurrence.
             self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
                                linear_tokens=0)
+        # A looped stack (`cfg.ut_steps` > 1 passes over its layers a
+        # token): its programs return each token's exit pass behind
+        # their other results, and the result path counts, of the tokens
+        # delivered, the passes walked for them (every pass runs whatever
+        # the gate says) and how many left at each pass.
+        self._ut_steps = cfg.ut_steps
+        if self._ut_steps > 1:
+            self.counts.update(loop_passes=0,
+                               loop_exit_hist=[0] * self._ut_steps)
+        # First tokens' exit passes on their way to the host, a tile's
+        # array with the rows whose token the tile delivers: read with
+        # the tokens (_deliver_first_tokens).
+        self._tile_exits: List[Tuple[jax.Array, List[int]]] = []
         # Admission tiles' routing stats, on their way to the host: read
         # where the host next waits for a tile (_deliver_first_tokens).
         self._tile_moe: List[jax.Array] = []
@@ -1065,12 +1078,20 @@ class LLMEngine:
                                         jnp.asarray(slot_idx),
                                         jnp.asarray(temps))
                             with self._program_call(launch):
-                                self.cache, toks, lps, *moe = \
+                                self.cache, toks, lps, *more = \
                                     prefill_sample_batch(
                                         self.cfg, self.params, self.cache,
                                         *tile[:3], self.top_k, tile[3], sub)
-                    self._tile_moe += moe       # routing stats
-                    self._start_host_copy(*moe)
+                    if self._ut_steps > 1:
+                        # A request whose first token the queue side
+                        # gave is counted there.
+                        exits = more.pop()
+                        self._tile_exits.append((exits, [
+                            j for j, req in enumerate(reqs)
+                            if getattr(req, "_early_tok", None) is None]))
+                        self._start_host_copy(exits)
+                    self._tile_moe += more      # routing stats
+                    self._start_host_copy(*more)
                 else:
                     sp = len(pkey)
                     with self._tile_span("slot", bucket, W, reqs, skip=sp):
@@ -1262,8 +1283,11 @@ class LLMEngine:
                         tile = (jnp.asarray(buf), jnp.asarray(lens),
                                 jnp.asarray(temps))
                     with self._program_call(launch):
-                        toks, lps = first_token_sample(
+                        toks, lps, *exits = first_token_sample(
                             self.cfg, self.params, *tile, self.top_k, sub)
+                for a in exits:
+                    self._tile_exits.append((a, list(range(len(chunk)))))
+                    self._start_host_copy(a)
                 if W < self._ADMIT_TILE:
                     with self._device_call("pad"):
                         toks = jnp.pad(toks, (0, self._ADMIT_TILE - W))
@@ -1349,6 +1373,12 @@ class LLMEngine:
                 # tile's span ends at its dispatch, before the device
                 # knows, so the numbers ride the span that waits for it.
                 self._read_tile_moe(span)
+                if self._tile_exits:
+                    tiles, self._tile_exits = self._tile_exits, []
+                    with self._device_call("to_host", n=len(tiles)):
+                        exits = np.concatenate(
+                            [np.asarray(a)[rows] for a, rows in tiles])
+                    self._count_exits(span, exits)
             slots = (self.slots[idx] for idx, _, _, _ in admitted)
             first = [s.req for s in slots if s is not None] \
                 + [r for reqs, _, _ in outs for r in reqs]
@@ -1572,7 +1602,7 @@ class LLMEngine:
                     self._key, sub = jax.random.split(self._key)
                 with self._device_call("to_device", n=1):
                     live = jnp.asarray(owned)
-                moe = lps = sampler = None
+                moe = lps = sampler = exits = None
                 if self.block_length:
                     with self._program_call(launch, k=k_block) as call:
                         self.cache, self._blocks, toks, *moe = \
@@ -1582,7 +1612,8 @@ class LLMEngine:
                                 self.top_k, sub, live)
                     moe = moe[0] if moe else None
                     self._start_host_copy(*toks)
-                elif k_block == 1 and not self._routed_layers:
+                elif k_block == 1 and not self._routed_layers \
+                        and self._ut_steps == 1:
                     with self._program_call(launch, k=k_block):
                         self.cache, logits = decode_step(
                             self.cfg, self.params, self.cache,
@@ -1599,17 +1630,19 @@ class LLMEngine:
                         toks = toks[None]                  # (1, B)
                 else:
                     with self._program_call(launch, k=k_block) as call:
-                        self.cache, toks, lps, *moe = decode_multi(
+                        self.cache, toks, lps, *more = decode_multi(
                             self.cfg, self.params, self.cache,
                             self.cur_tokens, self._temps, k_block,
                             self.top_k, sub, live)         # (k, B)
-                    moe = moe[0] if moe else None   # routing stats
+                    # The tokens' exit passes (k, B): a looped stack's.
+                    exits = more.pop() if self._ut_steps > 1 else None
+                    moe = more[0] if more else None   # routing stats
                 # Start the host copy NOW, before the next tick enqueues
                 # prefills and the next block behind it.
                 if not self.block_length:
                     with self._device_call("slice"):
                         self.cur_tokens = toks[-1]
-                    self._start_host_copy(toks, lps)
+                    self._start_host_copy(toks, lps, exits)
                 self._start_host_copy(moe)
             # Whose result `_process_block`'s fetch waits for.
             source = dict(call=call, program=sampler) if sampler else dict(
@@ -1619,7 +1652,7 @@ class LLMEngine:
             for i in active:
                 snap[i].inflight += k_block
         return (toks, lps, k_block, [(i, snap[i]) for i in active], number,
-                moe, source)
+                moe, source, exits)
 
     def warm_decode_blocks(self) -> List[int]:
         """Run the fused decode program of every size the adaptive block
@@ -1644,7 +1677,7 @@ class LLMEngine:
         block was in flight now holds a different request, and the
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
-        toks, lps, k_block, slot_snap, number, moe, source = block
+        toks, lps, k_block, slot_snap, number, moe, source, exits = block
         # `slots`: positions a step computes (a slot's block a pass).
         span = tracing.span("engine.process_block", block=number, k=k_block,
                             slots=self.num_slots * self._step_rows,
@@ -1663,18 +1696,26 @@ class LLMEngine:
                         host_toks = np.asarray(toks)
                         # (B,) after a one-step block's own sampler
                         host_lps = np.asarray(lps).reshape(host_toks.shape)
-                host_moe = None
+                host_moe = host_exits = None
                 if moe is not None:
                     with self._device_call("to_host", n=1):
                         host_moe = np.asarray(moe)
+                if exits is not None:
+                    with self._device_call("to_host", n=1):
+                        host_exits = np.asarray(exits)
             self.steps_processed += k_block
             before = self.tokens_out
             with self._emit_span():
                 if self.block_length:
                     passes = self._emit_passes(host, k_block, slot_snap)
                 else:
-                    self._emit_block(host_toks, host_lps, k_block, slot_snap)
+                    took = self._emit_block(host_toks, host_lps, k_block,
+                                            slot_snap)
             emitted = self.tokens_out - before
+            if host_exits is not None:
+                self._count_exits(span, np.concatenate(
+                    [host_exits[:n, i] for i, n in took.items()]
+                    or [host_exits[:0, 0]]))
             discarded = k_block * len(slot_snap) * self._step_rows - emitted
             if self.block_length:
                 for name, n in passes.items():
@@ -1692,8 +1733,23 @@ class LLMEngine:
                     self.counts[name] += n
                 span.set(**routed)
 
+    def _count_exits(self, span, exits: np.ndarray) -> None:
+        """Delivered tokens' exit passes (from 0) into the counters and
+        onto `span`: the passes walked for them, and how many left at
+        each pass (`loop_exit_p<t>`, t from 1)."""
+        hist = np.bincount(exits, minlength=self._ut_steps).tolist()
+        passes = self._ut_steps * len(exits)
+        self.counts["loop_passes"] += passes
+        for t, n in enumerate(hist):
+            self.counts["loop_exit_hist"][t] += n
+        span.set(loop_passes=passes,
+                 **{f"loop_exit_p{t + 1}": n for t, n in enumerate(hist)})
+
     def _emit_block(self, host_toks, host_lps, k_block: int,
-                    slot_snap: List) -> None:
+                    slot_snap: List) -> Dict[int, int]:
+        """Returns {slot: the steps of the block whose tokens it was
+        given, its first ones} for every slot that took any."""
+        took: Dict[int, int] = {}
         for i, slot0 in slot_snap:
             slot0.inflight -= k_block
             slot = self.slots[i]
@@ -1704,6 +1760,7 @@ class LLMEngine:
                     break  # drained by stop() / finished below
                 tok = int(host_toks[t, i])
                 self._emit(slot, tok, host_lps[t, i])
+                took[i] = t + 1
                 done = (tok == slot.req.eos_token
                         or slot.emitted >= slot.req.max_new_tokens
                         or self._cache_ended(slot))
@@ -1713,6 +1770,7 @@ class LLMEngine:
                     self._finish(i)
                     break
                 slot = self.slots[i]
+        return took
 
     def _emit_passes(self, host, k_block: int, slot_snap: List
                      ) -> Dict[str, int]:
@@ -1822,9 +1880,10 @@ class LLMEngine:
         out: Dict[str, Any] = {
             "finished": self._n_finished,
             "counts": dict(self.counts, **{
-                k: dict(self.counts[k]) for k in (
+                k: type(self.counts[k])(self.counts[k]) for k in (
                     "blocks_by_k", "launches", "device_calls",
-                    "device_call_ns")}),
+                    "device_call_ns", "loop_exit_hist")
+                if k in self.counts}),
             "decode_ticks": self.decode_ticks,
             "tokens_out": self.tokens_out,
             "waiting": len(self.waiting),

@@ -76,6 +76,8 @@ def pytest_collection_finish(session):
             # tests/benchmark/test_jamba_cell.py makes this one's.
             tiny.setdefault("jamba2-reason-wide-closed",
                             "tiny-jamba-closed")
+            # tests/benchmark/test_ouro_cell.py makes this one's.
+            tiny.setdefault("ouro-2b6-mathqa-closed", "tiny-ouro-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -90,7 +92,7 @@ def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
     test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
     test_sdar_cell.py, test_glm_cell.py, test_solar_cell.py,
-    test_jamba_cell.py) names
+    test_jamba_cell.py, test_ouro_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(
@@ -119,7 +121,7 @@ def _tell_of_entries_appended_since(mod):
     mod._own_last = max((order.index(n) for n in names if n in order),
                         default=len(order) - 1)
     # The later files (test_glm_cell.py, test_solar_cell.py,
-    # test_jamba_cell.py) keep the metrics that list their cell and are
+    # test_jamba_cell.py, test_ouro_cell.py) keep the metrics that list their cell and are
     # not their own in `LISTED_IN`, and hold their own names to be their
     # PR's entries exactly (in order, each a share on their made-up
     # profile): an entry appended since that lists their cell is one of
@@ -164,6 +166,13 @@ def pytest_runtest_call(item):
     cell = ((entries.get("workload") or {}).get("name")
             if isinstance(entries, dict) else None) \
         or getattr(mod, "REAL", None)
+    # A file that holds its entries to list the closed cells of its day
+    # (tests/benchmark/test_turn.py, PR 52: `CLOSED_CELLS`) holds the last
+    # of them to be the last name there: a later closed cell goes behind
+    # it (ouro-2b6-mathqa-closed, PR 55, was the first).
+    closed = getattr(mod, "CLOSED_CELLS", None)
+    if not cell and isinstance(closed, list) and closed:
+        cell = closed[-1]
     if cell:
         for kind in ("end_to_end", "per_layer"):
             seen[kind] = [

@@ -1541,3 +1541,81 @@ def test_one_kv_head_under_twenty_compiles_through_both_attention_kernels(
         q, k, v, causal=True, interpret=False)).lower(
             qs, ks, ks).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def serve_ouro(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "ouro-2b6-mathqa-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("ouro-2.6b", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+@pytest.mark.parametrize("program", ["decode_multi", "prefill_sample_batch",
+                                     "prefill"])
+def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
+        serve_ouro, as_on_the_chip, record_property, program):
+    """The looped cell's fused block (k = 64, the engine's largest) and
+    its widest admission tile (the 256 bucket) at 8 slots x 640: 5.34 GB
+    of weights beside K and V of 4 passes x 48 layers = 192 slabs, both
+    aliased in and out; a pass a scan step under `ut_pass` with one layer
+    scan inside it (one body for all 192 layer walks), the rows read
+    through the decode kernel at one query head a KV head. The width
+    rule's evidence (ISSUE 55): arguments + temporaries under the chip's
+    15.75 GB: 13.39 GB of arguments and under 0.03 of temporaries at
+    the cell's float32 activations (under bf16 ones every launch copied
+    wq, wk and wv into another layout, 1.21 GB: PERF.md section 6).
+    `prefill`: the one-row tile the harness's check runs, which written
+    as a one-index scatter copied the whole cache into another layout
+    (20.4 GB: `periodic._put`)."""
+    from ray_tpu.models import generate, periodic
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_ouro
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 5.33e9 < weights < 5.34e9 and cfg.ut_steps == 4
+    assert periodic.cache_layers(cfg)["global"] == 192
+    assert cache.k.shape == cache.v.shape == (192, slots, 640, 16, 128)
+    assert cache.k.dtype == jnp.bfloat16 and cache.kw is None
+    assert cfg.dtype == jnp.float32 and cfg.param_dtype == jnp.bfloat16
+    held = 2 * cache.k.size * 2
+    assert held == 192 * slots * 640 * 4096 * 2
+    if program == "decode_multi":
+        compiled = generate.decode_multi.lower(
+            cfg, params, cache, arr((slots,), jnp.int32),
+            arr((slots,), jnp.float32), 64, 0, key,
+            arr((slots,), jnp.bool_)).compile()
+    elif program == "prefill":
+        one_row = (arr((1, 256), jnp.int32), arr((), jnp.int32),
+                   arr((), jnp.int32))
+        compiled = generate.prefill.lower(cfg, params, cache,
+                                          *one_row).compile()
+        # Under bf16 activations too, where the copy was first met.
+        bf16 = dataclasses.replace(cfg, dtype=jnp.bfloat16, cache_dtype=None)
+        mem = generate.prefill.lower(bf16, params, cache,
+                                     *one_row).compile().memory_analysis()
+        assert mem.temp_size_in_bytes < 1.3e9 \
+            and mem.alias_size_in_bytes >= held
+    else:
+        rows = LLMEngine._tile_rows(256)
+        n = arr((rows,), jnp.int32)
+        compiled = generate.prefill_sample_batch.lower(
+            cfg, params, cache, arr((rows, 256), jnp.int32), n, n, 0,
+            arr((rows,), jnp.float32), key).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for scope in ("ut_pass", "attn_global", "ffn"):
+        assert scope in text, scope
+    assert ('"kernel":"decode_attn"' in text) == (program == "decode_multi")
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"{program}: arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -23,7 +23,8 @@ TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "sdar_moe": configs.tiny_sdar_test,
         "glm_moe_dsa": configs.tiny_glm_test,
         "solar_open2": configs.tiny_solar_test,
-        "jamba": configs.tiny_jamba_test}
+        "jamba": configs.tiny_jamba_test,
+        "ouro": configs.tiny_ouro_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -50,15 +51,18 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
 
     toks = jax.ShapeDtypeStruct((W, S), jnp.int32)
     rows = jax.ShapeDtypeStruct((W,), jnp.int32)
-    filled, x, tile_stats = jax.eval_shape(
+    # A looped configuration's walks return each row's exit pass last.
+    looped = cfg.ut_steps > 1
+    filled, x, tile_stats, *exits = jax.eval_shape(
         lambda p, c, t, n, s: st.prefill(cfg, p, c, t, n, s),
         params, cache, toks, rows, rows)
+    assert [(e.shape, e.dtype) for e in exits] == [((W, S), jnp.int32)] * looped
     assert jax.tree.map(lambda a: (a.shape, a.dtype), filled) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), cache)
     assert x.shape == (W, S, D)
-    free, _chosen = jax.eval_shape(
+    free, _chosen, *exits = jax.eval_shape(
         lambda p, t: st.forward_free(cfg, p, t), params, toks)
-    assert free.shape == (W, S, D)
+    assert free.shape == (W, S, D) and len(exits) == looped
     logits = jax.eval_shape(
         lambda p, x, n: st.last_logits(cfg, p, x, n), params, x, rows)
     assert (logits.shape, logits.dtype) == ((W, V), jnp.float32)
@@ -84,8 +88,9 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
             jax.ShapeDtypeStruct((B,), jnp.int32))
         assert (logits.shape, logits.dtype) == ((B, Bd, V), jnp.float32)
     else:
-        stepped, logits, stats = decode()
+        stepped, logits, stats, *exits = decode()
         assert (logits.shape, logits.dtype) == ((B, V), jnp.float32)
+        assert [(e.shape, e.dtype) for e in exits] == [((B,), jnp.int32)] * looped
         if hasattr(st, "decode_block"):
             with pytest.raises(NotImplementedError) as e:
                 st.decode_block(cfg, params, cache, None, None)
@@ -204,6 +209,10 @@ SEEDED = {
     # PR 50's preset, on the tree that added it (a dense FFN's matrices
     # under the layer's own dict).
     "tiny_jamba_test": "b5ca64afbe25f7eb",
+    # PR 55's preset, on the tree that added it (a looped walk: the exit
+    # gate from a key folded out of the seed, residual outputs scaled by
+    # the depth walked).
+    "tiny_ouro_test": "c715eedf9dc5f33f",
 }
 
 
