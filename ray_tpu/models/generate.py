@@ -195,12 +195,13 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
 
     Every row shares one read of the weights. While that read bounds
     the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
-    819 GB/s, two operations a position for a bf16 weight's two bytes)
-    W serial prefills cost ~W× one batched prefill; past it every row,
-    padding too, costs its own arithmetic. So the engine chooses W by
-    the bucket (serve/llm.py, `LLMEngine._tile_rows`): wide tiles of
-    short buckets, one row from 512 positions up. Rows whose slot index
-    is out of range (the tile's padding) are dropped by the scatter and
+    819 GB/s, two operations a position for a bf16 weight's two bytes;
+    ~120 where a float32 position is two bf16 terms) W serial prefills
+    cost ~W× one batched prefill; past it every row, padding too, costs
+    its own arithmetic. So the engine chooses W by the bucket and the
+    model's terms (serve/llm.py, `LLMEngine._tile_rows`): one row from
+    512 positions up (256 under two terms). Rows whose slot index is
+    out of range (the tile's padding) are dropped by the scatter and
     their sampled token is garbage the caller ignores. Compiles once
     per (W, S_bucket)."""
     cache, logits, stats, exits = _prefill_batch_core(

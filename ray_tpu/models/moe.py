@@ -37,8 +37,18 @@ def bf16_terms(x: jax.Array) -> jax.Array:
     return jnp.stack([hi, x - hi]).astype(jnp.bfloat16)
 
 
+def dot_terms(act_dtype, weight_dtype) -> int:
+    """The bf16 terms `dot` multiplies an activation as: two for float32
+    on a bf16 weight (every position is two rows of the product), else
+    one. The one reading of the two dtypes: `_split` asks it of two
+    arrays, `periodic.cache_terms` of a configuration's cache, and the
+    engine of the positions its admission tile holds (serve/llm.py)."""
+    return 2 if (act_dtype == jnp.float32
+                 and weight_dtype == jnp.bfloat16) else 1
+
+
 def _split(x: jax.Array, w: jax.Array) -> bool:
-    return x.dtype == jnp.float32 and w.dtype == jnp.bfloat16
+    return dot_terms(x.dtype, w.dtype) == 2
 
 
 def _exact(x: jax.Array):

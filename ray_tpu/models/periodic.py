@@ -112,7 +112,8 @@ from jax import lax
 from ..parallel.sharding import with_sharding_constraint as wsc
 from . import stackparts
 # `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
-from .moe import bf16_terms, dot as _dot, routing_stats  # noqa: F401
+from .moe import bf16_terms, dot as _dot, dot_terms, \
+    routing_stats  # noqa: F401
 from .stackparts import (Group, KVCache, _attend_cache,  # noqa: F401
                          _attend_cache_block, _final, _norm, _rope, ffn_half,
                          head_logits, joins, last_logits, masked_softmax,
@@ -338,8 +339,8 @@ def cache_terms(cfg: TransformerConfig) -> int:
     """The bf16 terms a cached key or value is kept as: two for float32
     activations on bf16 weights (hi + lo carry 16 bits of mantissa, and
     the products of attention take bf16), else one value of `cfg.dtype`."""
-    return 2 if (cfg.dtype == jnp.float32 and cfg.cache_dtype is None
-                 and cfg.param_dtype == jnp.bfloat16) else 1
+    return dot_terms(cfg.dtype, cfg.param_dtype) \
+        if cfg.cache_dtype is None else 1
 
 
 def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int

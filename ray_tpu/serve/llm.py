@@ -57,6 +57,7 @@ from ..models.generate import (
     routed_layers,
     sample_logp,
 )
+from ..models.moe import dot_terms
 from ..models.transformer import (
     REMASK_RULES,
     TransformerConfig,
@@ -467,6 +468,9 @@ class LLMEngine:
             # recurrence.
             self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
                                linear_tokens=0)
+        # The bf16 terms the model multiplies an activation as, which a
+        # slot-side tile's positions follow (`_tile_rows`).
+        self._dot_terms = dot_terms(cfg.dtype, cfg.param_dtype)
         # A looped stack (`cfg.ut_steps` > 1 passes over its layers a
         # token): its programs return each token's exit pass behind
         # their other results, and the result path counts, of the tokens
@@ -837,14 +841,20 @@ class LLMEngine:
     # The widest prefill tile, and the width of every queue-side tile
     # (_early_first_tokens). Rows share one read of the weights, which
     # pays while that read bounds the tile: a bf16 weight is 2 bytes and
-    # 2 operations a position, so a tile of P positions does P operations
-    # a byte, and a v5e turns from memory-bound to compute-bound at
-    # 197 TFLOP/s / 819 GB/s = 240. Under ~240 positions a tile more rows
+    # 2 operations a position and a term (`moe.dot_terms`: a float32
+    # activation on a bf16 weight is multiplied as two bf16 terms, every
+    # position two rows of the product), so a tile of P positions does
+    # P x terms operations a byte, and a v5e turns from memory-bound to
+    # compute-bound at 197 TFLOP/s / 819 GB/s = 240: ~240 positions
+    # under one term, ~120 under two. Under the ridge a tile more rows
     # are free; past it each row costs its own arithmetic, real or not.
     _ADMIT_TILE = 8
-    # Positions a slot-side tile is filled up to: the next power of two
-    # past the ridge with room (256 measured against 512: PERF.md,
-    # section 6, PR 29). _tile_rows() makes the width from it.
+    # Positions of ONE term a slot-side tile is filled up to: the next
+    # power of two past the ridge with room (256 measured against 512:
+    # PERF.md, section 6, PR 29). _tile_rows() makes the width from it
+    # and the model's terms: 512 positions under one, 256 under two, the
+    # same distance past either ridge (128 measured against 256:
+    # PERF.md, section 6, PR 56).
     _TILE_POSITIONS = 512
     # Positions a queue-side tile holds at most: _ADMIT_TILE rows up to
     # the 1024 bucket, fewer past it, one from 8192 on (a tile's
@@ -854,14 +864,23 @@ class LLMEngine:
     FINISHED_RING = 1024
 
     @classmethod
-    def _tile_rows(cls, bucket: int) -> int:
+    def _tile_rows(cls, bucket: int, terms: int = 1) -> int:
         """Rows of a slot-side admission tile of `bucket` positions a
-        row: a function of the bucket alone, so there is ONE program a
-        bucket and a lone request runs the program a full tile runs.
-        Buckets up to 64 keep _ADMIT_TILE rows, 128 gets 4, 256 gets 2,
-        512 and longer 1: several requests of a long bucket are several
-        tiles in one tick, at the same arithmetic."""
-        return max(1, min(cls._ADMIT_TILE, cls._TILE_POSITIONS // bucket))
+        row, for a model that multiplies a position as `terms` bf16
+        terms (an engine asks `_slot_tile_rows`, under its own): a
+        function of the bucket alone in one engine, so there is ONE
+        program a bucket and a lone request runs what a full tile runs.
+        Under one term buckets up to 64 keep _ADMIT_TILE rows, 128 gets
+        4, 256 gets 2, 512 and longer 1; under two 32 keeps them, 64
+        gets 4, 128 gets 2, 256 and longer 1. Several requests of a long
+        bucket are several tiles in one tick, at the same arithmetic."""
+        return max(1, min(cls._ADMIT_TILE,
+                          cls._TILE_POSITIONS // terms // bucket))
+
+    def _slot_tile_rows(self, bucket: int) -> int:
+        """`_tile_rows` under the terms this engine's model multiplies a
+        position as."""
+        return self._tile_rows(bucket, self._dot_terms)
 
     @classmethod
     def _queue_tile_rows(cls, bucket: int) -> int:
@@ -1009,18 +1028,20 @@ class LLMEngine:
         """Prefill waiting requests into free slots (arrival order).
 
         Admissions are BATCHED per prompt-length bucket into tiles of
-        _tile_rows(bucket) rows and dispatched through
-        prefill_sample_batch. Rows share one read of the weights, so in
-        a short bucket (a tile under ~240 positions: memory-bound) W
-        serial prefills would cost ~W x one batched call; a long
-        bucket's row is compute-bound alone, and there each request is
-        its own one-row tile, several a tick. All dispatches are async;
-        first tokens are fetched later by _deliver_first_tokens with
-        one fused host sync. Requests whose first token was already
-        served by _early_first_tokens() are prefilled in the same
-        batch (their sampled token is discarded and decode continues
-        from the token the client saw). Returns [(idx, tok_dev,
-        the tile's log-probs, the request's row in them)].
+        _tile_rows(bucket, the model's terms) rows and dispatched
+        through prefill_sample_batch. Rows share one read of the
+        weights, so in a short bucket (a tile under the ridge, ~240
+        positions a bf16 term the model multiplies a position as:
+        memory-bound) W serial prefills would cost ~W x one batched
+        call; a long bucket's row is compute-bound alone, and there each
+        request is its own one-row tile, several a tick. All dispatches
+        are async; first tokens are fetched later by
+        _deliver_first_tokens with one fused host sync. Requests whose
+        first token was already served by _early_first_tokens() are
+        prefilled in the same batch (their sampled token is discarded
+        and decode continues from the token the client saw). Returns
+        [(idx, tok_dev, the tile's log-probs, the request's row in
+        them)].
         """
         with self.lock:
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -1047,14 +1068,14 @@ class LLMEngine:
         # prefilled); the rest through the full path.
         full, suffix = self._group_by_route(
             list(zip(take, free)), lambda it: it[0].prompt,
-            self._tile_rows)
+            self._slot_tile_rows)
         chunks: List = [(bucket, None, None, chunk)
                         for bucket, chunk in full]
         chunks += [(bucket, pkey, entry, chunk)
                    for pkey, entry, bucket, chunk in suffix]
 
         for ci, (bucket, pkey, entry, chunk) in enumerate(chunks):
-            W = self._tile_rows(bucket)
+            W = self._slot_tile_rows(bucket)
             # Padding rows scatter out of bounds (slot==num_slots) and
             # are dropped on device.
             slot_idx = np.full((W,), self.num_slots, np.int32)
@@ -1162,11 +1183,12 @@ class LLMEngine:
             whole = len(req.prompt) // Bd * Bd
             by_bucket.setdefault(self._bucket_for(whole), []).append(
                 (req, idx, whole))
-        chunks = [(bucket, its[off:off + self._tile_rows(bucket)])
+        rows = self._slot_tile_rows
+        chunks = [(bucket, its[off:off + rows(bucket)])
                   for bucket, its in sorted(by_bucket.items())
-                  for off in range(0, len(its), self._tile_rows(bucket))]
+                  for off in range(0, len(its), rows(bucket))]
         for ci, (bucket, chunk) in enumerate(chunks):
-            W = self._tile_rows(bucket)
+            W = rows(bucket)
             slot_idx = np.full((W,), self.num_slots, np.int32)
             first_x = np.full((W, Bd), self.cfg.mask_token_id, np.int32)
             first_masked = np.ones((W, Bd), bool)

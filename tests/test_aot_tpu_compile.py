@@ -1553,24 +1553,31 @@ def serve_ouro(topo):
     return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
 
 
-@pytest.mark.parametrize("program", ["decode_multi", "prefill_sample_batch",
-                                     "prefill"])
+@pytest.mark.parametrize("program,bucket", [
+    ("decode_multi", None), ("prefill_sample_batch", 256),
+    ("prefill_sample_batch", 128), ("prefill_sample_batch", 64),
+    ("prefill", 256)])
 def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
-        serve_ouro, as_on_the_chip, record_property, program):
+        serve_ouro, as_on_the_chip, record_property, program, bucket):
     """The looped cell's fused block (k = 64, the engine's largest) and
-    its widest admission tile (the 256 bucket) at 8 slots x 640: 5.34 GB
+    its admission tiles at 8 slots x 640, each bucket of its prompts at
+    the rows the engine gives under the cell's two terms (256 positions
+    a tile: 256 x 1, written with `periodic._put`'s twin row as the
+    check's `prefill` is, 128 x 2 and 64 x 4): 5.34 GB
     of weights beside K and V of 4 passes x 48 layers = 192 slabs, both
     aliased in and out; a pass a scan step under `ut_pass` with one layer
     scan inside it (one body for all 192 layer walks), the rows read
     through the decode kernel at one query head a KV head. The width
     rule's evidence (ISSUE 55): arguments + temporaries under the chip's
-    15.75 GB: 13.39 GB of arguments and under 0.03 of temporaries at
-    the cell's float32 activations (under bf16 ones every launch copied
-    wq, wk and wv into another layout, 1.21 GB: PERF.md section 6).
+    15.75 GB: 13.39 GB of arguments and 0.002 GB of temporaries for the
+    block, 0.011 for the 256 x 1 and 128 x 2 tiles and for the check's
+    `prefill`, 0.010 for 64 x 4, at the cell's float32 activations
+    (under bf16 ones every launch copied wq, wk and wv into another
+    layout, 1.21 GB: PERF.md section 6).
     `prefill`: the one-row tile the harness's check runs, which written
     as a one-index scatter copied the whole cache into another layout
     (20.4 GB: `periodic._put`)."""
-    from ray_tpu.models import generate, periodic
+    from ray_tpu.models import generate, moe, periodic
     from ray_tpu.serve.llm import LLMEngine
 
     cfg, slots, one, key, params, cache = serve_ouro
@@ -1592,7 +1599,7 @@ def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
             arr((slots,), jnp.float32), 64, 0, key,
             arr((slots,), jnp.bool_)).compile()
     elif program == "prefill":
-        one_row = (arr((1, 256), jnp.int32), arr((), jnp.int32),
+        one_row = (arr((1, bucket), jnp.int32), arr((), jnp.int32),
                    arr((), jnp.int32))
         compiled = generate.prefill.lower(cfg, params, cache,
                                           *one_row).compile()
@@ -1603,10 +1610,12 @@ def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
         assert mem.temp_size_in_bytes < 1.3e9 \
             and mem.alias_size_in_bytes >= held
     else:
-        rows = LLMEngine._tile_rows(256)
+        rows = LLMEngine._tile_rows(
+            bucket, moe.dot_terms(cfg.dtype, cfg.param_dtype))
+        assert rows * bucket == 256
         n = arr((rows,), jnp.int32)
         compiled = generate.prefill_sample_batch.lower(
-            cfg, params, cache, arr((rows, 256), jnp.int32), n, n, 0,
+            cfg, params, cache, arr((rows, bucket), jnp.int32), n, n, 0,
             arr((rows,), jnp.float32), key).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     for scope in ("ut_pass", "attn_global", "ffn"):
@@ -1614,8 +1623,9 @@ def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
     assert ('"kernel":"decode_attn"' in text) == (program == "decode_multi")
     record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
     record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
-    print(f"{program}: arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
-          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    print(f"{program} {bucket}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 0.1e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -4,6 +4,7 @@ programs' names, the kernels' names and the train step's phase scopes.
 All on the CPU; nothing sleeps or asserts a duration."""
 
 import contextlib
+import dataclasses
 import importlib
 import re
 import threading
@@ -628,7 +629,9 @@ def test_the_guard_sees_a_call_made_outside_a_span(guard, monkeypatch):
 # What an admission tick builds: (prompt lengths submitted together,
 # slots, the slot tiles as (bucket, rows, tile_rows)). Buckets of a
 # 640-row engine: 16 ... 512, 640; a tile holds `_TILE_POSITIONS` = 512
-# positions, at most `_ADMIT_TILE` = 8 rows and at least one.
+# positions, at most `_ADMIT_TILE` = 8 rows and at least one. Under the
+# two bf16 terms of float32 activations on bf16 weights (the last cases:
+# the tiny model's weights cast to bf16) it holds 256.
 TILES = {
     "a_lone_long_prompt_is_one_row": ([300], 2, [(512, 1, 1)]),
     "eight_of_the_smallest_bucket_share_a_tile": (
@@ -643,14 +646,28 @@ TILES = {
     "a_mixed_wave_is_cut_bucket_by_bucket": (
         [10, 300, 12, 120, 301], 8,
         [(16, 2, 8), (128, 1, 4), (512, 1, 1), (512, 1, 1)]),
+    "three_short_buckets_of_one_term": (
+        [60, 100, 250], 4, [(64, 1, 8), (128, 1, 4), (256, 1, 2)]),
+    "two_terms_halve_the_three_short_buckets": (
+        [60, 100, 250], 4, [(64, 1, 4), (128, 1, 2), (256, 1, 1)]),
+    "two_terms_make_five_of_the_128_bucket_three_tiles": (
+        [100] * 5, 5, [(128, 2, 2), (128, 2, 2), (128, 1, 2)]),
 }
+
+
+def _two_terms(model):
+    """The tiny model in float32 on bf16 weights: `moe.dot_terms` 2."""
+    cfg, params = model
+    return (dataclasses.replace(cfg, param_dtype=jnp.bfloat16),
+            jax.tree.map(lambda a: a.astype(jnp.bfloat16), params))
 
 
 @pytest.mark.parametrize("case", sorted(TILES))
 def test_a_slot_tile_is_as_wide_as_its_bucket_needs(tiny_model, hook, case):
     """The wave is admitted by one tick, every request streams all its
     tokens, and the counts are the sums of the spans' attributes."""
-    cfg, params = tiny_model
+    terms = 2 if case.startswith("two_terms") else 1
+    cfg, params = _two_terms(tiny_model) if terms == 2 else tiny_model
     lens, slots, want = TILES[case]
     engine = LLMEngine(cfg, params, num_slots=slots, max_seq_len=640,
                        decode_block=4)
@@ -661,7 +678,7 @@ def test_a_slot_tile_is_as_wide_as_its_bucket_needs(tiny_model, hook, case):
     assert [(t["bucket"], t["rows"], t["tile_rows"]) for t in tiles] == want
     assert all(t["side"] == "slot" for t in tiles)
     assert len({t["parent"] for t in tiles}) == 1        # one tick
-    assert all(t["tile_rows"] == LLMEngine._tile_rows(t["bucket"])
+    assert all(t["tile_rows"] == LLMEngine._tile_rows(t["bucket"], terms)
                for t in tiles)
     _drain(engine, reqs)
     assert [len(list(r)) for r in reqs] == [3] * len(lens)
@@ -675,18 +692,23 @@ def test_a_slot_tile_is_as_wide_as_its_bucket_needs(tiny_model, hook, case):
                                            for t in tiles)
 
 
-def test_a_queue_side_tile_keeps_the_widest_width(tiny_model, hook):
-    """A long prompt that finds no slot gets its first token from an
-    `_ADMIT_TILE`-row tile, then a one-row slot tile when a slot frees."""
-    cfg, params = tiny_model
+@pytest.mark.parametrize("terms,n,bucket,slot_rows", [
+    (1, 300, 512, 1), (1, 100, 128, 4), (2, 100, 128, 2)])
+def test_a_queue_side_tile_keeps_the_widest_width(tiny_model, hook, terms, n,
+                                                  bucket, slot_rows):
+    """A prompt that finds no slot gets its first token from an
+    `_ADMIT_TILE`-row tile, whatever the model's terms, then a slot tile
+    of its bucket's rows when a slot frees."""
+    cfg, params = _two_terms(tiny_model) if terms == 2 else tiny_model
     engine = LLMEngine(cfg, params, num_slots=1, max_seq_len=640,
                        decode_block=4)
-    reqs = [engine.submit([7] * 300, max_new_tokens=3) for _ in range(2)]
+    reqs = [engine.submit([7] * n, max_new_tokens=3) for _ in range(2)]
     _drain(engine, reqs)
     tiles = [(t["args"]["side"], t["args"]["bucket"], t["args"]["tile_rows"])
              for t in hook if t["name"] == "engine.prefill_tile"]
-    assert tiles == [("slot", 512, 1), ("queue", 512, LLMEngine._ADMIT_TILE),
-                     ("slot", 512, 1)]
+    assert tiles == [("slot", bucket, slot_rows),
+                     ("queue", bucket, LLMEngine._ADMIT_TILE),
+                     ("slot", bucket, slot_rows)]
     assert reqs[0].tokens == reqs[1].tokens and len(reqs[1].tokens) == 3
 
 

@@ -237,25 +237,59 @@ def test_default_buckets():
     assert default_buckets(16) == [16]
 
 
-# rows(bucket) = clamp(_TILE_POSITIONS // bucket, 1, _ADMIT_TILE) at
-# _TILE_POSITIONS = 512, for every bucket of a 4096-row engine.
-TILE_ROWS = {16: 8, 32: 8, 64: 8, 128: 4, 256: 2, 512: 1, 1024: 1, 2048: 1,
-             4096: 1}
+# rows(bucket, terms) = clamp(_TILE_POSITIONS // terms // bucket, 1,
+# _ADMIT_TILE) at _TILE_POSITIONS = 512, for every bucket of a 4096-row
+# engine and of a 640-row one, under the one bf16 term a bf16 engine
+# multiplies a position as (the rows every PR since 29 has run) and
+# under a float32 engine's two on bf16 weights.
+TILE_ROWS = {
+    1: {16: 8, 32: 8, 64: 8, 128: 4, 256: 2, 512: 1, 640: 1, 1024: 1,
+        2048: 1, 4096: 1},
+    2: {16: 8, 32: 8, 64: 4, 128: 2, 256: 1, 512: 1, 640: 1, 1024: 1,
+        2048: 1, 4096: 1},
+}
 
 
-def test_tile_rows_covers_every_bucket():
-    assert sorted(TILE_ROWS) == default_buckets(4096)
+@pytest.mark.parametrize("terms", sorted(TILE_ROWS))
+def test_tile_rows_covers_every_bucket(terms):
+    assert sorted(TILE_ROWS[terms]) == sorted(
+        set(default_buckets(4096)) | set(default_buckets(640)))
 
 
-@pytest.mark.parametrize("bucket", sorted(TILE_ROWS))
-def test_tile_rows_by_bucket(bucket):
-    rows = LLMEngine._tile_rows(bucket)
-    assert rows == TILE_ROWS[bucket]
+@pytest.mark.parametrize("bucket", sorted(TILE_ROWS[1]))
+@pytest.mark.parametrize("terms", sorted(TILE_ROWS))
+def test_tile_rows_by_bucket(bucket, terms):
+    rows = LLMEngine._tile_rows(bucket, terms)
+    assert rows == TILE_ROWS[terms][bucket]
+    if terms == 1:
+        # What a caller that names no terms gets: the rows of a bf16
+        # engine, bucket by bucket.
+        assert rows == LLMEngine._tile_rows(bucket)
     assert 1 <= rows <= LLMEngine._ADMIT_TILE
     # As many positions as the constant allows, and never an empty tile.
-    assert rows * bucket <= max(LLMEngine._TILE_POSITIONS, bucket)
-    assert rows == LLMEngine._ADMIT_TILE or \
-        (rows + 1) * bucket > LLMEngine._TILE_POSITIONS
+    positions = LLMEngine._TILE_POSITIONS // terms
+    assert rows * bucket <= max(positions, bucket)
+    assert rows == LLMEngine._ADMIT_TILE or (rows + 1) * bucket > positions
+
+
+@pytest.mark.parametrize("dtype,param_dtype,terms", [
+    (jnp.bfloat16, jnp.bfloat16, 1), (jnp.float32, jnp.float32, 1),
+    (jnp.bfloat16, jnp.float32, 1), (jnp.float32, jnp.bfloat16, 2)])
+def test_an_engine_reads_its_terms_from_its_two_dtypes(dtype, param_dtype,
+                                                       terms):
+    """The one reading (`moe.dot_terms`) as the engine, `moe.dot`'s split
+    and the period stack's cache make it of the same configuration."""
+    from ray_tpu.models import moe, periodic
+
+    cfg = dataclasses.replace(configs.tiny_afmoe_test(), dtype=dtype,
+                              param_dtype=param_dtype)
+    eng = LLMEngine(cfg, init_params(cfg, jax.random.key(0)), num_slots=1,
+                    max_seq_len=32)
+    assert eng._dot_terms == terms == periodic.cache_terms(cfg)
+    assert moe._split(jnp.zeros((1,), dtype),
+                      jnp.zeros((1,), param_dtype)) == (terms == 2)
+    assert periodic.cache_terms(
+        dataclasses.replace(cfg, cache_dtype=jnp.bfloat16)) == 1
 
 
 def _single_row(cfg, params, prompt, new):
@@ -280,29 +314,59 @@ def _single_row(cfg, params, prompt, new):
 
 
 # Prompt lengths in the buckets 16, 128, 256, 512 and 640 of a 640-row
-# engine: slot tiles of 8, 4, 2, 1 and 1 rows.
+# engine: slot tiles of 8, 4, 2, 1 and 1 rows under one term.
 TILE_LENS = (5, 100, 200, 300, 600)
+# Under two terms, the buckets whose width they change (16, 64, 128 and
+# 256: tiles of 8, 4, 2 and 1 rows), a lone request each and each full.
+TWO_TERM_LENS = {"lone": (5, 60, 100, 200),
+                 "full": (5,) * 8 + (60,) * 4 + (100,) * 2 + (200,)}
 
 
-@pytest.mark.parametrize("case", ["dense", "period_stack", "lp_twin",
-                                  "registered_prefix"])
-def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
+def _f32_on_bf16(cfg):
+    """float32 activations on bf16 weights: two bf16 terms a product."""
+    return dataclasses.replace(cfg, param_dtype=jnp.bfloat16)
+
+
+TILE_CASES = {
+    # case: (configuration, terms, the engine's decode block)
+    "dense": (configs.tiny_test, 1, 4),
+    "period_stack": (configs.tiny_afmoe_test, 1, 4),
+    "lp_twin": (configs.tiny_test, 1, 1),
+    "registered_prefix": (configs.tiny_test, 1, 4),
+    "period_stack_f32_on_bf16": (
+        lambda: _f32_on_bf16(configs.tiny_afmoe_test()), 2, 4),
+    "looped_f32_on_bf16": (
+        lambda: _f32_on_bf16(configs.tiny_ouro_test(ut_steps=2)), 2, 4),
+}
+
+
+@pytest.mark.parametrize("case,fill", [
+    ("dense", "lone"), ("period_stack", "lone"), ("lp_twin", "lone"),
+    ("registered_prefix", "lone"),
+    ("period_stack_f32_on_bf16", "lone"),
+    ("period_stack_f32_on_bf16", "full"),
+    ("looped_f32_on_bf16", "lone"), ("looped_f32_on_bf16", "full")])
+def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case,
+                                                                  fill):
     """Temperature 0: the first token and every later one, and the
     log-probability of each, are those of the single-row `prefill`
-    program, whatever the width of the tile the bucket gave (`lp_twin`:
-    blocks of one step, whose tokens the engine's own sampler draws;
-    and behind a registered prefix, where the suffix's bucket gives the
-    width)."""
-    cfg = (configs.tiny_afmoe_test() if case == "period_stack"
-           else configs.tiny_test())
+    program, whatever the width of the tile the bucket and the model's
+    terms gave, a lone request in it or as many as it has rows
+    (`lp_twin`: blocks of one step, whose tokens the engine's own sampler
+    draws; and behind a registered prefix, where the suffix's bucket
+    gives the width)."""
+    make, terms, decode_block = TILE_CASES[case]
+    cfg = make()
     params = init_params(cfg, jax.random.key(2))
     rng = np.random.RandomState(3)
     prefix = list(rng.randint(0, cfg.vocab_size, size=13))
     head = prefix if case == "registered_prefix" else []
+    lens = TILE_LENS if terms == 1 else TWO_TERM_LENS[fill]
     prompts = [head + list(rng.randint(0, cfg.vocab_size, size=n))
-               for n in TILE_LENS]
+               for n in lens]
     eng = LLMEngine(cfg, params, num_slots=len(prompts), max_seq_len=640,
-                    decode_block=1 if case == "lp_twin" else 4)
+                    decode_block=decode_block)
+    assert eng._dot_terms == terms
     if head:
         eng.register_prefix(prefix)
     reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
@@ -312,12 +376,19 @@ def test_a_tile_of_any_width_gives_the_single_row_programs_tokens(case):
     # 640 - 13 < 600 + 13: the longest prompt cannot sit behind the
     # prefix and takes the full path's 640 bucket; the others' suffixes
     # fall in the same buckets as the whole prompts do.
-    assert (c["prefill_tiles"], c["prefill_tile_rows"]) == (5, 8 + 4 + 2 + 2)
+    tiles, tile_rows = (5, 8 + 4 + 2 + 2) if terms == 1 else (4, 8 + 4 + 2 + 1)
+    assert (c["prefill_tiles"], c["prefill_tile_rows"]) == (tiles, tile_rows)
+    assert c["prefill_rows"] == (tiles if fill == "lone" else tile_rows)
     assert eng.stats()["prefix_hits"] == (4 if head else 0)
+    # Under two terms an activation is hi + lo to 2^-17 (`bf16_terms`):
+    # where two tile shapes sum in another order, a last digit of x can
+    # move lo by its own last place, 2^-16 of x, which float32 products
+    # never see: ten times their room, on log-probabilities near 5.
+    atol = 2e-5 if terms == 1 else 2e-4
     for p, r in zip(prompts, reqs):
         toks, lps = _single_row(cfg, params, p, 5)
         assert r.result(timeout=1) == toks
-        np.testing.assert_allclose(r.logprobs, lps, atol=2e-5)
+        np.testing.assert_allclose(r.logprobs, lps, atol=atol)
 
 
 def test_llm_serve_deployment(ray_start):
