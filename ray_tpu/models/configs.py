@@ -160,6 +160,37 @@ def tiny_ouro_test(vocab: int = 256, ut_steps: int = 3,
         ut_steps=ut_steps, early_exit_threshold=threshold)
 
 
+def tiny_kimi_test(vocab: int = 256, router_experts: int = 16,
+                   held: int = 4, first: int = 4, periods: int = 2,
+                   **changes) -> TransformerConfig:
+    """The period stack with linear-attention layers around a
+    latent-attention layer at a unit-test size, planned from lists as
+    the published ones run (L L L G ..., a last period cut short): a
+    leading delta-rule layer with a dense SwiGLU, `periods` periods
+    L L G L and a tail L G (eleven layers); 2 delta-rule heads of 16 (the step
+    not doubled), 4 latent heads (16 + 8 key values, 16 value values)
+    over rows of 32 + 8 with no query rank and no rotation; `held` of
+    `router_experts` experts held, sigmoid scores with a selection bias,
+    a shared expert. For the tests only."""
+    n = 1 + 4 * periods + 2
+    full = [4 * (p + 1) for p in range(periods)] + [n]
+    return TransformerConfig(**{**dict(
+        vocab_size=vocab, d_model=64, n_layers=n, n_dense_layers=1,
+        n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128, max_seq_len=128,
+        dtype=jnp.float32, param_dtype=jnp.float32, remat=False,
+        tie_embeddings=False, arch="kimi_linear", global_attn_every=4,
+        moe_experts=held, moe_router_experts=router_experts,
+        moe_first_expert=first, moe_top_k=2, moe_d_ff=32,
+        moe_shared_experts=1, score_func="sigmoid", route_norm=True,
+        route_scale=2.446, linear_n_heads=2, linear_head_dim=16,
+        linear_conv_kernel=4, q_lora_rank=None, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        linear_attn_config={
+            "full_attn_layers": full, "head_dim": 16, "num_heads": 2,
+            "kda_layers": [i for i in range(1, n + 1) if i not in full],
+            "short_conv_kernel_size": 4}), **changes})
+
+
 def gpt2_125m() -> TransformerConfig:
     """BASELINE config 1 (GPT-2 125M equivalent param count; rotary in
     place of learned positions — TPU-first choice, same capability)."""
@@ -219,6 +250,7 @@ NAMED = {
     "tiny_solar": tiny_solar_test,
     "tiny_jamba": tiny_jamba_test,
     "tiny_ouro": tiny_ouro_test,
+    "tiny_kimi": tiny_kimi_test,
     "gpt2-125m": gpt2_125m,
     "llama-654m": llama_654m,
     "llama-1b4": llama_1b4,

@@ -11,6 +11,12 @@ before the FFN; where the architecture has sandwich norms
 joins the residual stream. The router may add a bias an expert for its
 choice alone (`LatentForm.router_bias`).
 
+The attention half (the projections, a tile's attention per head, a
+decode step's in the latent space, the cache rows) is `models/mla.py`'s,
+which a period stack whose global layer is latent calls too
+(`mla.attention_half`); this module adds the indexer, the chunk walk, the
+layer's FFN half and the entry points.
+
 Attention keeps no key and no value a head. A token's keys and values are
 one vector, `c = N(x W_kva)[:kv_lora_rank]`, from which every head's key
 part without position and its value are products (`wk_b` (H, nope, rank),
@@ -24,8 +30,9 @@ C is that width in whole lanes of 128, the lanes behind the rotary key
 zero; a row narrower than its lanes is laid out rows-minor on the chip
 and copied whole into and out of every program that reads it by rows).
 Queries go through a rank of their own (`wq_a`, a norm, then `wq_nope`
-and `wq_rope`, the published `q_b_proj`'s columns by what they make) to
-`[q_nope | q_r]` a head.
+and `wq_rope`, the published `q_b_proj`'s columns by what they make;
+straight from the layer's input where `q_lora_rank` is 0) to `[q_nope |
+q_r]` a head.
 
 The same products in two orders:
 
@@ -103,7 +110,6 @@ the sum and no exchange runs.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -114,12 +120,15 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import stackparts
+# The attention half, which a period stack's latent layer shares.
+from .mla import (_attend_rows, _attend_tile, _chosen_rows,  # noqa: F401
+                  _heads_dot, _prefill_attend, _project, _scale,
+                  attention_half, attention_shapes, cache_lanes, cache_width,
+                  init_rows)
 # `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
-from .moe import _exact, _split, bf16_terms, dot as _dot, \
-    routing_stats  # noqa: F401
+from .moe import dot as _dot, routing_stats  # noqa: F401
 from .stackparts import (Group, KVCache, _final, _norm, _rope,  # noqa: F401
-                         _swiglu, ffn_half, head_logits, joins, last_logits,
-                         masked_softmax, rows_held)
+                         _swiglu, ffn_half, head_logits, joins, last_logits)
 from .transformer import TransformerConfig, rope_tables
 
 # What the dense stack offers and this one does not (`transformer.offered`).
@@ -157,34 +166,16 @@ def routed_layers(cfg: TransformerConfig) -> int:
     return stackparts.routed_layers(layer_plan(cfg))
 
 
-def cache_width(cfg: TransformerConfig) -> int:
-    """Values a token a layer keeps: the latent vector and the rotary key."""
-    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
-
-
-def cache_lanes(cfg: TransformerConfig) -> int:
-    """A cached row's width: `cache_width` in whole lanes of 128."""
-    return -(-cache_width(cfg) // 128) * 128
-
-
 # ---------------------------------------------------------------------------
 # Weights
 # ---------------------------------------------------------------------------
 
+
 def _layer_shapes(cfg: TransformerConfig, routed: bool
                   ) -> Dict[str, Tuple[int, ...]]:
-    d, H = cfg.d_model, cfg.n_heads
-    qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
-    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
+    d, qr = cfg.d_model, cfg.q_lora_rank
     form = cfg.latent_form
-    shapes = {
-        "attn_norm": (d,), "wq_a": (d, qr), "q_a_norm": (qr,),
-        "wq_nope": (qr, H * nope), "wq_rope": (qr, H * rope),
-        "wkv_a": (d, kvr + rope), "kv_a_norm": (kvr,),
-        "wk_b": (H, nope, kvr), "wv_b": (H, kvr, vd),
-        "wo": (H * vd, d), "ffn_norm": (d,),
-    }
+    shapes = {"attn_norm": (d,), **attention_shapes(cfg), "ffn_norm": (d,)}
     if form.post_norms:
         shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
     if cfg.index_topk:
@@ -194,12 +185,16 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
     return {**shapes, **stackparts.ffn_shapes(cfg, routed, form.router_bias)}
 
 
+def _group_shapes(cfg: TransformerConfig, group: Group):
+    return _layer_shapes(cfg, group.routed)
+
+
 def num_params(cfg: TransformerConfig) -> int:
-    return stackparts.num_params(cfg, layer_plan(cfg), _layer_shapes)
+    return stackparts.num_params(cfg, layer_plan(cfg), _group_shapes)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    return stackparts.init_params(cfg, key, layer_plan(cfg), _layer_shapes)
+    return stackparts.init_params(cfg, key, layer_plan(cfg), _group_shapes)
 
 
 def index_dtype(cfg: TransformerConfig):
@@ -212,7 +207,7 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
     rows = (cfg.n_layers, num_slots, max_seq_len)
     return KVCache(
         k=None, v=None, seq_lens=jnp.zeros((num_slots,), jnp.int32),
-        c=jnp.zeros(rows + (cache_lanes(cfg),), cfg.dtype),
+        c=init_rows(cfg, *rows, cfg.dtype),
         ki=jnp.zeros(rows + (cfg.index_head_dim,), index_dtype(cfg))
         if cfg.index_topk else None)
 
@@ -220,25 +215,6 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
 # ---------------------------------------------------------------------------
 # The layer
 # ---------------------------------------------------------------------------
-
-def _heads_dot(eq: str, x: jax.Array, w: jax.Array) -> jax.Array:
-    """`jnp.einsum(eq, x, w)` of an activation against a weight seen a
-    head at a time, float32 out; float32 x against a bf16 w as two bf16
-    terms, as `moe.dot` takes them."""
-    from ..ops.flash_attention import on_tpu
-
-    two = _split(x, w)
-    if two:
-        ins, out = eq.split("->")
-        x, eq = bf16_terms(x), f"t{ins}->t{out}"
-    if x.dtype == jnp.bfloat16 and not on_tpu():
-        # A CPU has no bf16 x bf16 -> float32 product over a batch of
-        # heads. The products of bf16 operands are exact in float32, so
-        # this is the same sum.
-        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
-    y = jnp.einsum(eq, x, w.astype(x.dtype), precision=_exact(x),
-                   preferred_element_type=jnp.float32)
-    return y[0] + y[1] if two else y
 
 
 def _rope_tables(cfg: TransformerConfig, seq_len: int, positions=None):
@@ -248,29 +224,6 @@ def _rope_tables(cfg: TransformerConfig, seq_len: int, positions=None):
     if positions is not None:
         sin, cos = sin[positions][:, None, :], cos[positions][:, None, :]
     return sin, cos
-
-
-def _project(cfg: TransformerConfig, lp, x, rope):
-    """x (B, S, D) -> (q_nope (B, S, H, nope) float32, q_r (B, S, H,
-    rope) float32 and rotated, the row the cache keeps (B, S, C) in the
-    activation dtype: the latent vector after its norm, the rotary key
-    after its rotation, zeros up to whole lanes; and the layer's normed
-    input (B, S, D) float32, before it is rounded for the products)."""
-    B, S, _ = x.shape
-    H, dt, eps = cfg.n_heads, cfg.dtype, cfg.norm_eps
-    kvr = cfg.kv_lora_rank
-    h32 = _norm(x, lp["attn_norm"], eps)
-    h = h32.astype(dt)
-    c_q = _norm(_dot(h, lp["wq_a"]), lp["q_a_norm"], eps).astype(dt)
-    q_nope = _dot(c_q, lp["wq_nope"]).reshape(B, S, H, -1)
-    q_r = _dot(c_q, lp["wq_rope"]).reshape(B, S, H, -1)
-    kv = _dot(h, lp["wkv_a"])                    # (B, S, rank + rope) float32
-    c = _norm(kv[..., :kvr], lp["kv_a_norm"], eps)
-    k_r = _rope(kv[..., None, kvr:], *rope)[:, :, 0]    # one head, shared
-    row = jnp.concatenate([c, k_r], axis=-1).astype(dt)
-    row = jnp.pad(row, ((0, 0), (0, 0),
-                        (0, cache_lanes(cfg) - cache_width(cfg))))
-    return q_nope, _rope(q_r, *rope), row, h32
 
 
 # The indexer key's LayerNorm (guess: the family's published code builds
@@ -309,141 +262,6 @@ def _index_project(cfg: TransformerConfig, lp, h, rope):
         + lp["idx_k_bias"].astype(jnp.float32)
     w = _dot(h, lp["idx_wp"]) * (Hi ** -0.5 * Di ** -0.5)
     return q.astype(it), w, rotated(k[:, :, None, :])[:, :, 0].astype(it)
-
-
-def _scale(cfg: TransformerConfig) -> float:
-    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-
-
-_QUERY_BLOCK = 256
-
-
-def _attention_f32(q, k, v, sm_scale: float):
-    """Causal attention of float32 q, k (B, S, H, Dk) and v (B, S, H, Dv),
-    both products at the highest precision, a block of queries at a time
-    (the scores held are (B, H, block, S))."""
-    B, S, H, _ = q.shape
-    blk = _QUERY_BLOCK if S % _QUERY_BLOCK == 0 else S
-    hi = lax.Precision.HIGHEST
-    j = jnp.arange(S)[None, :]
-
-    def block(args):
-        qs, start = args                                # (B, blk, H, Dk)
-        s = jnp.einsum("bqhd,bshd->bhqs", qs, k, precision=hi) * sm_scale
-        seen = j <= start + jnp.arange(blk)[:, None]
-        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqs,bshd->bqhd", p, v, precision=hi)
-
-    out = lax.map(block, (jnp.moveaxis(
-        q.reshape(B, S // blk, blk, H, -1), 1, 0),
-        jnp.arange(S // blk) * blk))
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, -1)
-
-
-def _attend_tile(cfg: TransformerConfig, lp, q_nope, q_r, row,
-                 scope: str = "attn_latent"):
-    """A tile over itself, per head: the rows' keys and values
-    up-projected from what the cache keeps of them (so a tile and the
-    decode steps behind it see the same rounding), causal attention with
-    keys nope + rope wide and values `v_head_dim` wide. -> (B, S, H*vd)."""
-    B, S, H, _ = q_nope.shape
-    dt, kvr = cfg.dtype, cfg.kv_lora_rank
-    with jax.named_scope("mla_proj"):
-        c = row[..., :kvr]
-        k_nope = _heads_dot("bsc,hdc->bshd", c, lp["wk_b"]).astype(dt)
-        v = _heads_dot("bsc,hcd->bshd", c, lp["wv_b"]).astype(dt)
-    with jax.named_scope(scope):
-        k_r = jnp.broadcast_to(row[:, :, None, kvr:cache_width(cfg)],
-                               (B, S, H, cfg.qk_rope_head_dim))
-        q = jnp.concatenate([q_nope, q_r], axis=-1).astype(dt)
-        k = jnp.concatenate([k_nope, k_r], axis=-1)
-        if dt == jnp.float32:
-            out = _attention_f32(q, k, v, _scale(cfg))
-        else:
-            from ..ops import flash_attention
-            out = flash_attention(q, k, v, causal=True, sm_scale=_scale(cfg))
-    return out.reshape(B, S, -1)
-
-
-def _attend_rows(cfg: TransformerConfig, positions, live, l, lp, q_nope,
-                 q_r, row, idx, state):
-    """One token a slot against layer `l` of the carried cache (L, B, S,
-    C), in the latent space: this step's row is written at `positions`,
-    `W_UK` goes into the query and `W_UV` onto the weighted rows, and
-    every held row is read once, for scores and values together. With an
-    indexer (`idx`: `_index_project`'s three) the step's indexer key is
-    written beside the row, the slot's held keys are scored, and only the
-    `index_topk` rows of largest score are gathered and attended
-    (`_chosen_rows`): no other latent row is read. `state`: (the latent
-    cache, the indexer's or None, the chosen rows a layer (L, B, k) for
-    whoever asks or None). -> (out (B, 1, H*vd), state)."""
-    from ..ops import decode_attention as da
-
-    c_all, ki_all, picks = state
-    B, S, C = c_all.shape[1:]
-    H, dt, kvr = cfg.n_heads, cfg.dtype, cfg.kv_lora_rank
-    with jax.named_scope("mla_proj"):
-        q_lat = _heads_dot("bhd,hdc->bhc", q_nope[:, 0].astype(dt),
-                           lp["wk_b"])
-        q = jnp.concatenate([q_lat, q_r[:, 0]], axis=-1).astype(dt)
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, C - q.shape[-1])))
-    if idx is not None:
-        with jax.named_scope("attn_index"):
-            n_rows = rows_held(positions, S, live)
-            ki_all, chosen = _chosen_rows(cfg, positions, l, idx, ki_all,
-                                          n_rows)
-            if picks is not None:
-                picks = picks.at[l].set(chosen)
-    with jax.named_scope("attn_latent" if idx is None else "attn_sparse"):
-        # A slot the engine no longer owns keeps advancing and can reach
-        # S: its write falls out of bounds and is dropped.
-        c_all = c_all.at[l, jnp.arange(B), positions].set(row[:, 0],
-                                                          mode="drop")
-        if idx is not None:
-            # The chosen rows alone, gathered: the best first, so the
-            # rows that count are the first `n_rows` of them where a
-            # slot holds fewer than it may choose.
-            rows_all = c_all[l, jnp.arange(B)[:, None], chosen][None]
-            n_rows = jnp.minimum(n_rows, chosen.shape[1])
-            at = jnp.int32(0)
-        else:
-            rows_all, at = c_all, l
-            n_rows = rows_held(positions, S, live)
-        if dt == c_all.dtype and da.usable(rows_all, C, kvr):
-            o_lat = da.decode_attention(
-                q[:, None], rows_all, None, at, n_rows, sm_scale=_scale(cfg),
-                v_width=kvr).reshape(B, H, kvr)
-        else:
-            rows = lax.dynamic_index_in_dim(rows_all, at, 0, keepdims=False)
-            hi = _exact(q)
-            scores = jnp.einsum("bhc,bsc->bhs", q, rows, precision=hi,
-                                preferred_element_type=jnp.float32)
-            probs = masked_softmax((scores * _scale(cfg))[:, None], n_rows,
-                                   live)[:, 0].astype(rows.dtype)
-            o_lat = jnp.einsum("bhs,bsc->bhc", probs, rows[..., :kvr],
-                               precision=hi,
-                               preferred_element_type=jnp.float32)
-    with jax.named_scope("mla_proj"):
-        out = _heads_dot("bhc,hcd->bhd", o_lat.astype(dt), lp["wv_b"])
-    return out.reshape(B, 1, -1).astype(dt), (c_all, ki_all, picks)
-
-
-def _chosen_rows(cfg: TransformerConfig, positions, l, idx, ki_all, n_rows):
-    """A decode step's choice: the step's indexer key into layer `l` of
-    the indexer's cache (L, B, S, Di) at `positions`, every key a slot
-    holds scored against the step's indexer query (`n_rows` (B,) of
-    them), and the `index_topk` rows of largest score, exactly
-    (`lax.top_k`: ties to the lower row), best first: a row past
-    `n_rows` scores `-inf` and comes last. -> (ki_all, rows (B, k))."""
-    from ..ops import sparse_attention as sa
-
-    q, w, key = idx
-    B, S = ki_all.shape[1:3]
-    ki_all = ki_all.at[l, jnp.arange(B), positions].set(key[:, 0],
-                                                         mode="drop")
-    scores = sa.index_scores_rows(q[:, 0], w[:, 0], ki_all, l, n_rows)
-    _, chosen = lax.top_k(scores, min(cfg.index_topk, S))
-    return ki_all, chosen
 
 
 # Rows of a tile that go through the layers together where a stack
@@ -597,16 +415,15 @@ def layer(cfg: TransformerConfig, lp, x, experts_at, rope, attend, state,
     experts chosen (B*S, K), both None for a dense FFN)."""
     post_norms = cfg.latent_form.post_norms
 
-    with jax.named_scope("mla_proj"):
-        q_nope, q_r, row, h = _project(cfg, lp, x, rope)
-    idx = None
+    index = None
     if cfg.index_topk:
-        with jax.named_scope("attn_index"):
-            idx = _index_project(cfg, lp, h, rope)
-    out, state = attend(lp, q_nope, q_r, row, idx, state)
+        def index(h):
+            with jax.named_scope("attn_index"):
+                return _index_project(cfg, lp, h, rope)
+    branch, state = attention_half(cfg, lp, x, rope, attend, state, index)
     with jax.named_scope("mla_proj"):
-        x = joins(x, _dot(out, lp["wo"]),
-                  lp["post_attn_norm"] if post_norms else None, cfg.norm_eps)
+        x = joins(x, branch, lp["post_attn_norm"] if post_norms else None,
+                  cfg.norm_eps)
     x, stats, experts = ffn_half(cfg, lp, x, experts_at, post_norms, rows)
     return x, state, stats, experts
 
@@ -638,21 +455,6 @@ def _embed(cfg: TransformerConfig, params, tokens):
 # ---------------------------------------------------------------------------
 # What generate.py's programs call
 # ---------------------------------------------------------------------------
-
-def _prefill_attend(cfg, slots, l, lp, q_nope, q_r, row, idx, state):
-    c_all, ki_all, picks = state
-    out = _attend_tile(cfg, lp, q_nope, q_r, row)
-    with jax.named_scope("attn_latent"):
-        # The tile's rows into each row's slot, [0, S); a slot out of
-        # range (the tile's padding) is dropped.
-        c_all = c_all.at[l, slots, :row.shape[1]].set(row, mode="drop")
-        # Rows-major, as the cache arrives and as the decode kernel reads
-        # it. Left to itself the compiler lays the carried cache out
-        # rows-minor for this write (the tile's rows come off a product
-        # that way) and copies all of it in and out of the program.
-        c_all = with_layout_constraint(
-            c_all, Layout(major_to_minor=tuple(range(c_all.ndim))))
-    return out, (c_all, ki_all, picks)
 
 
 def _walk(cfg: TransformerConfig, params, state, tokens, lengths, slots,

@@ -21,8 +21,19 @@ the attention's and the FFN's output, the attention output gated by
 selection bias, where in its period the global layer stands, and which
 kinds of layer rotate q and k, each kind with the table of its own
 section of `cfg.rope_parameters` (Trinity: window layers only; Mellum
-2: both, the global ones by YaRN; Solar Open 2 and Jamba: none, their
-layers have no position but the order the state saw them in).
+2: both, the global ones by YaRN; Solar Open 2, Jamba and Kimi Linear:
+none, their layers have no position but the order the state saw them
+in). Where the form says so (`PeriodForm.latent`: Kimi Linear) the global
+layer is multi-head latent attention, `models/mla.py`'s attention half:
+its leaves, its rows in the cache (`KVCache.c`, (Lg, slots, S_max, lanes),
+in place of `k`/`v`), a tile per head and a decode step in the latent
+space, under the scopes `mla_proj` and `attn_latent`. Where the
+configuration lists each layer's kind (`cfg.linear_attn_config`:
+`kda_layers`, `full_attn_layers`) the plan is read from the lists
+(`layer_plan`): the leading layers, whole periods all alike, and a last
+period cut short as a step of its own (`tail_layers`); a leading layer is
+then of the kind the lists say (Kimi Linear's: a linear layer with a
+dense SwiGLU).
 
 A linear layer (`_linear_half`, under the scope `attn_linear`): q, k, v
 = silu of a causal depthwise convolution over the last
@@ -110,7 +121,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from . import stackparts
+from . import mla, stackparts
 # `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
 from .moe import bf16_terms, dot as _dot, dot_terms, \
     routing_stats  # noqa: F401
@@ -138,8 +149,16 @@ MISSING = {
               "compute_prefix_kv) is not written for a windowed cache or "
               "for a recurrent state (the delta rule's, the selective "
               "scan's with its convolution's tail), whose value behind the "
-              "prefix a registered prefix would have to carry "
+              "prefix a registered prefix would have to carry: where the "
+              "global layer is latent, a snapshot of every linear layer's "
+              "state, their tails and the prefix's latent rows "
               "(models/periodic.py)",
+    # One tile a prompt: the engine's buckets reach `max_seq_len`.
+    "chunked_prefill": "a prompt past one tile is not walked a chunk at a "
+                       "time: `ops/delta_rule.chunk_scan` takes a carried "
+                       "state and `models/latent.py` attends rows a tile did "
+                       "not write, but only in its indexer's walk, and the "
+                       "two are not joined for a stack that keeps both",
     "param_logical_axes": "the period stack has no sharding rules yet: it "
                           "is served on one chip (models/periodic.py)",
     "forward_train": "the period stack is served only (models/generate.py): "
@@ -173,19 +192,25 @@ NOT_ITS_WALK = {
 }
 
 
-DENSE, PERIODS = "dense_layers", "periods"
+DENSE, PERIODS, TAIL = "dense_layers", "periods", "tail_layers"
 
 
 def layer_plan(cfg: TransformerConfig) -> List[Group]:
     """The leading layers, a layer a scan step, then whole periods, a
-    period a step."""
+    period a step, and, where the configuration lists each layer's kind
+    and the lists end in a period cut short, that one as a last step of
+    its own. Lists that do not group so are refused (`_listed_steps`)."""
     every = cfg.global_attn_every
     plan = []
     if cfg.n_dense_layers:
         plan.append(Group(DENSE, (cfg.n_dense_layers,), False))
-    periods = (cfg.n_layers - cfg.n_dense_layers) // every
+    periods, tail = divmod(cfg.n_layers - cfg.n_dense_layers, every)
     if periods:
         plan.append(Group(PERIODS, (periods, every), cfg.is_moe))
+    if cfg.listed_global is not None:
+        if tail:
+            plan.append(Group(TAIL, (1, tail), cfg.is_moe))
+        _listed_steps(cfg)
     return plan
 
 
@@ -194,6 +219,27 @@ def _other_kind(cfg: TransformerConfig) -> str:
     the leading layers."""
     return cfg.period_form.recurrent or (
         WINDOW if cfg.sliding_window else GLOBAL)
+
+
+def _listed_steps(cfg: TransformerConfig) -> Dict[str, Tuple[str, ...]]:
+    """{group: the kinds of one of its scan steps' layers} from the
+    configuration's lists (`TransformerConfig.listed_global`): the
+    leading layers alike, every whole period as the first, what is left
+    shorter than a period."""
+    other, every = _other_kind(cfg), cfg.global_attn_every
+    kinds = [GLOBAL if g else other for g in cfg.listed_global]
+    lead, body = kinds[:cfg.n_dense_layers], kinds[cfg.n_dense_layers:]
+    whole = len(body) // every * every
+    periods = [tuple(body[i:i + every]) for i in range(0, whole, every)]
+    if len(set(lead)) > 1 or len(set(periods)) > 1:
+        raise ValueError(
+            f"{cfg.arch}: the listed layers {kinds} are not "
+            f"n_dense_layers ({cfg.n_dense_layers}) leading layers of one "
+            f"kind, then periods of global_attn_every ({every}) layers "
+            "all alike (and a last one cut short)")
+    steps = {DENSE: tuple(lead[:1]), PERIODS: tuple(body[:every]),
+             TAIL: tuple(body[whole:])}
+    return {key: ks for key, ks in steps.items() if ks}
 
 
 def _period_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
@@ -213,6 +259,9 @@ def global_place(cfg: TransformerConfig) -> int:
 
 def step_kinds(cfg: TransformerConfig) -> List[Tuple[str, ...]]:
     """The kinds of a scan step's layers, a group of `layer_plan`."""
+    if cfg.listed_global is not None:
+        listed = _listed_steps(cfg)
+        return [listed[group.key] for group in layer_plan(cfg)]
     return [(_other_kind(cfg),) if group.key == DENSE else _period_kinds(cfg)
             for group in layer_plan(cfg)]
 
@@ -244,17 +293,19 @@ def cache_layers(cfg: TransformerConfig) -> Dict[str, int]:
 # Weights
 # ---------------------------------------------------------------------------
 
-def _layer_shapes(cfg: TransformerConfig, routed: bool
+def _layer_shapes(cfg: TransformerConfig, group: Group
                   ) -> Dict[str, Tuple[int, ...]]:
     d, hd = cfg.d_model, cfg.head_dim
     q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
-    form = cfg.period_form
+    form, routed = cfg.period_form, group.routed
     shapes = {"attn_norm": (d,), "ffn_norm": (d,)}
     attn = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
     if form.qk_norm:
         attn.update(q_norm=(hd,), k_norm=(hd,))
     if form.attn_gate:
         attn["wg"] = (d, q)
+    if form.latent:
+        attn = mla.attention_shapes(cfg)
     if form.post_norms:
         shapes.update(post_attn_norm=(d,), post_ffn_norm=(d,))
     ffn = stackparts.ffn_shapes(cfg, routed, form.router_bias)
@@ -265,10 +316,11 @@ def _layer_shapes(cfg: TransformerConfig, routed: bool
         # never scanned): a step cuts what is stacked over its layers out
         # of the stack by a copy, 0.13 GB a matrix a layer at 2560 x 8192.
         own = {} if routed else ffn
-        shapes[GLOBAL + "0"] = {**attn, **own}
-        for n in range(cfg.global_attn_every - 1):
-            shapes[f"{form.recurrent}{n}"] = {
-                **_RECURRENT[form.recurrent].shapes(cfg), **own}
+        kinds = step_kinds(cfg)[layer_plan(cfg).index(group)]
+        for j, kind in enumerate(kinds):
+            shapes[f"{kind}{kinds[:j].count(kind)}"] = {
+                **(attn if kind == GLOBAL
+                   else _RECURRENT[kind].shapes(cfg)), **own}
         return {**shapes, **ffn} if routed else shapes
     return {**shapes, **attn, **ffn}
 
@@ -356,7 +408,7 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
         return wsc(z, ("layers", None, None, "act_kv_heads", None))
 
     ring = min(cfg.sliding_window, max_seq_len)
-    s = tails = None
+    s = tails = c = None
     if LINEAR in n:
         H, D = cfg.linear_n_heads, cfg.linear_head_dim
         s = jnp.zeros((n[LINEAR], num_slots, H, D, D), jnp.float32)
@@ -367,11 +419,36 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
                        cfg.mamba_d_inner), jnp.float32)
         tails = jnp.zeros((n[SSM], num_slots, cfg.mamba_d_conv - 1,
                            cfg.mamba_d_inner), cfg.dtype)
+    if cfg.period_form.latent:
+        c = mla.init_rows(cfg, n[GLOBAL], num_slots, max_seq_len,
+                             jnp.dtype(cfg.cache_dtype or cfg.dtype).type)
     return KVCache(
-        k=zeros(n[GLOBAL], max_seq_len), v=zeros(n[GLOBAL], max_seq_len),
+        k=None if c is not None else zeros(n[GLOBAL], max_seq_len),
+        v=None if c is not None else zeros(n[GLOBAL], max_seq_len), c=c,
         seq_lens=jnp.zeros((num_slots,), jnp.int32),
         kw=zeros(n[WINDOW], ring) if n[WINDOW] else None,
         vw=zeros(n[WINDOW], ring) if n[WINDOW] else None, s=s, tails=tails)
+
+
+def cache_bytes(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(bytes of a slot's recurrent states and convolution tails, all
+    its layers of the kind; bytes a held token's rows come to over the
+    global layers: a latent layer's `kv_lora_rank + qk_rope_head_dim`
+    values, never the lanes the row is padded to, else a key and a value
+    a KV head, every term): what the engine's `cache_state_bytes_live`
+    and `cache_row_bytes_held` multiply (`serve/llm.py`), read off the
+    cache of one slot and one row. A window layer's ring is neither: it
+    does not grow with the tokens held."""
+    one = jax.eval_shape(lambda: init_cache(cfg, 1, 1))
+
+    def nbytes(*arrays):
+        return sum(a.size * a.dtype.itemsize for a in arrays
+                   if a is not None)
+
+    if one.c is not None:
+        return nbytes(one.s, one.tails), one.c.shape[0] \
+            * one.c.dtype.itemsize * mla.cache_width(cfg)
+    return nbytes(one.s, one.tails), nbytes(one.k, one.v)
 
 
 # ---------------------------------------------------------------------------
@@ -395,11 +472,23 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     # Every product hands back float32; what lies between two products
     # (norms, rotary, gates) stays float32 and is rounded to `dt` once,
     # where it enters the next product or the cache (float32: never).
-    h = _norm(x, lp["attn_norm"], eps).astype(dt)
-    if kind in _RECURRENT:
+    def normed():
+        return _norm(x, lp["attn_norm"], eps).astype(dt)
+
+    if _latent(cfg, kind):
+        # `attend(kind, (the layer's leaves, q_nope, q_r), the row the
+        # cache keeps, None, state) -> (out (B, S, H x v_head_dim),
+        # state)`, under the scopes `mla_proj` and `attn_latent`.
+        branch, state = mla.attention_half(
+            cfg, lp, x, rope.get(kind),
+            lambda lp, q_nope, q_r, row, idx, state: attend(
+                kind, (lp, q_nope, q_r), row, None, state), state)
+    elif kind in _RECURRENT:
+        h = normed()
         with jax.named_scope("attn_" + kind):
             branch, state = _RECURRENT[kind].half(cfg, lp, h, attend, state)
     else:
+        h = normed()
         q = _dot(h, lp["wq"]).reshape(B, S, H, Dh)
         k = _dot(h, lp["wk"]).reshape(B, S, KVH, Dh)
         v = _dot(h, lp["wv"]).reshape(B, S, KVH, Dh).astype(dt)
@@ -427,6 +516,12 @@ def layer(cfg: TransformerConfig, lp, x, kind: str, experts_at, rope,
     return x, state, zeros if stats is None else stats, experts
 
 
+def _latent(cfg: TransformerConfig, kind: str) -> bool:
+    """Whether a layer of `kind` is latent attention
+    (`PeriodForm.latent`: the global one)."""
+    return kind == GLOBAL and cfg.period_form.latent
+
+
 def _linear_half(cfg: TransformerConfig, lp, h, attend, state):
     """The attention half of a linear layer on its normed input h (B, S,
     D) in the activation dtype -> (the branch (B, S, D) float32, state).
@@ -445,7 +540,9 @@ def _linear_half(cfg: TransformerConfig, lp, h, attend, state):
         + lp["dt_bias"].astype(f32)
     g = -jnp.exp(lp["A_log"].astype(f32))[:, None] \
         * jax.nn.softplus(decay.reshape(B, S, H, D))
-    beta = 2.0 * jax.nn.sigmoid(_dot(h, lp["wb"]))
+    beta = jax.nn.sigmoid(_dot(h, lp["wb"]))
+    if cfg.period_form.neg_eigval:
+        beta = 2.0 * beta
     o, state = attend(LINEAR, mix, lp["conv"], (g, beta), state)
     gate = _dot(_dot(h, lp["g_a"]).astype(dt), lp["g_b"]) \
         + lp["g_bias"].astype(f32)
@@ -561,7 +658,9 @@ def rope_by_kind(cfg: TransformerConfig, seq_len: int, positions=None):
     end reads its last row: such a slot's pass is dropped)."""
     out = {}
     for kind in cfg.period_form.rotary:
-        sin, cos = rope_tables(cfg, seq_len, ROPE_SECTION[kind])
+        sin, cos = rope_tables(
+            cfg, seq_len, ROPE_SECTION[kind],
+            cfg.qk_rope_head_dim if _latent(cfg, kind) else 0)
         if positions is not None and positions.ndim == 2:
             sin, cos = sin[positions], cos[positions]
         elif positions is not None:
@@ -579,8 +678,11 @@ def _run(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
         # The leading layers walk as periods of one layer, their leaves
         # given that axis here: scanned as they lie, a decode block
         # compiles to another module (ROADMAP D19).
-        params = {**params, DENSE: jax.tree.map(lambda a: a[:, None],
-                                                params[DENSE])}
+        # (a leading layer's own leaves, under its kind, have no such
+        # axis to begin with: `leaves_at`).
+        params = {**params, DENSE: {
+            k: a if isinstance(a, dict) else a[:, None]
+            for k, a in params[DENSE].items()}}
         plan = [plan[0]._replace(lead=plan[0].lead + (1,))] + plan[1:]
     per = [{kind: ks.count(kind) for kind in KINDS} for ks in kinds]
     # Each group's first layer of a kind: those the groups before it hold.
@@ -748,6 +850,12 @@ def _prefill_attend(cfg, slots, lengths, l, kind, q, k, v, state):
     or past `length` holds padding, which decode overwrites before it
     reads it). A row whose slot is out of range is dropped."""
     kg, vg, kw, vw, s, tails = state
+    if _latent(cfg, kind):
+        # q, k: (the layer's leaves, q_nope, q_r) and the tile's rows
+        # (`layer`); `kg` the latent rows.
+        out, (kg, _, _) = mla._prefill_attend(cfg, slots, l, *q, k, None,
+                                                 (kg, None, None))
+        return out, (kg, vg, kw, vw, s, tails)
     if kind in _RECURRENT:
         # q, k, v: the projections, the convolution's weights and (g,
         # beta) (`_linear_half`), or the projection and the layer's
@@ -867,6 +975,10 @@ _RECURRENT = {
 
 def _decode_attend(cfg, positions, live, l, kind, q, k, v, state):
     kg, vg, kw, vw, s, tails = state
+    if _latent(cfg, kind):
+        out, (kg, _, _) = mla._attend_rows(cfg, positions, live, l, *q, k,
+                                              None, (kg, None, None))
+        return out, (kg, vg, kw, vw, s, tails)
     if kind in _RECURRENT:
         out, s, tails = _RECURRENT[kind].step(cfg, live, l, q, k, v, s, tails)
         return out, (kg, vg, kw, vw, s, tails)
@@ -895,6 +1007,8 @@ def _block_attend(cfg, p0, live, l, kind, q, k, v, state):
 
 
 def _free_attend(cfg, l, kind, q, k, v, state):
+    if _latent(cfg, kind):
+        return mla._attend_tile(cfg, *q, k), state
     if kind in _RECURRENT:
         return _RECURRENT[kind].tile(cfg, q, k, v)[0], state
     return _flash(cfg, kind, q, k, v), state
@@ -905,12 +1019,18 @@ def _free_attend(cfg, l, kind, q, k, v, state):
 # ---------------------------------------------------------------------------
 
 def _state(cache: KVCache):
-    """What rides in the walk's carry: the cache without `seq_lens`."""
-    return (cache.k, cache.v, cache.kw, cache.vw, cache.s, cache.tails)
+    """What rides in the walk's carry: the cache without `seq_lens`; the
+    global layers' rows first, keys (and values) a head or, of a latent
+    global layer, `c`."""
+    return (cache.c if cache.k is None else cache.k, cache.v, cache.kw,
+            cache.vw, cache.s, cache.tails)
 
 
-def _cache(state, seq_lens) -> KVCache:
+def _cache(state, seq_lens, latent: bool = False) -> KVCache:
     kg, vg, kw, vw, s, tails = state
+    if latent:
+        return KVCache(k=None, v=None, seq_lens=seq_lens, c=kg, s=s,
+                       tails=tails)
     return KVCache(k=kg, v=vg, seq_lens=seq_lens, kw=kw, vw=vw, s=s,
                    tails=tails)
 
@@ -931,7 +1051,7 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
         partial(_prefill_attend, cfg, slots, lengths), _state(cache))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     x, exits = _leave(cfg, params, x)
-    return (_cache(state, seq_lens), x,
+    return (_cache(state, seq_lens, cfg.period_form.latent), x,
             stats if routed_layers(cfg) else None) + exits
 
 
@@ -974,7 +1094,7 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
     x, state, stats, _ = _walk(
         cfg, params, _embed(cfg, params, tokens)[:, None, :], rope,
         partial(_decode_attend, cfg, positions, live), _state(cache), live)
-    cache = _cache(state, positions + 1)
+    cache = _cache(state, positions + 1, cfg.period_form.latent)
     x, exits = _leave(cfg, params, x)
     return (cache, head_logits(cfg, params, x[:, 0]),
             stats if routed_layers(cfg) else None) \

@@ -66,7 +66,10 @@ class KVCache(NamedTuple):
     dtype, the last inputs of the layer's short convolutions. An
     admission tile writes both as the prompt's last token left them
     (`seq_lens` hides a stale row; nothing would hide a stale state);
-    None anywhere else.
+    None anywhere else. Where such a stack's global layers are latent
+    (`PeriodForm.latent`) their rows are `c`, (Lg, B, S_max, C), beside
+    `s` and `tails`, and k and v are None: two kinds of entry in one
+    cache that are both unlike keys and values.
 
     The leading axis counts cache slabs, not weight layers: a stack
     that walks its layers `cfg.ut_steps` times a token
@@ -371,7 +374,7 @@ def num_params(cfg: TransformerConfig, plan: Sequence[Group],
     if cfg.ut_steps > 1:
         total += cfg.d_model + 1                    # the exit gate
     for group in plan:
-        for shape in layer_shapes(cfg, group.routed).values():
+        for shape in layer_shapes(cfg, group).values():
             if isinstance(shape, dict):     # one layer of a step's own
                 total += group.lead[0] * sum(
                     math.prod(s) for s in shape.values())
@@ -388,8 +391,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
     scaled down by the depth a token walks (`n_layers` x `ut_steps`); a
     looped configuration's `exit_gate` (w (D,), b ()) drawn as the rest,
     from a key folded out of `key` so that no other leaf's draw moves;
-    `layer_shapes(cfg, routed)` says a layer's
-    leaves by name, each with its shape. Leaves that one layer of a scan
+    `layer_shapes(cfg, group)` says the leaves
+    of a layer of that group by name, each with its shape. Leaves that one layer of a scan
     step has and the step's others lack are a dict under a name of that
     layer's, stacked under the group's steps alone (a step hands the
     layer its slice as a scanned operand, which a product reads where it
@@ -430,7 +433,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
         return normal(k, full, 0.02)
 
     for group, k_group in zip(plan, jax.random.split(k_layers, len(plan))):
-        shapes = layer_shapes(cfg, group.routed)
+        shapes = layer_shapes(cfg, group)
         leaves = {}
         for (leaf, shape), k in zip(
                 sorted(shapes.items()),

@@ -45,6 +45,11 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #              one that walks its layers `cfg.ut_steps` times keeps one
 #              for each (pass, layer). Nothing above the stack sizes a
 #              cache from `cfg.n_layers`
+#            cache_bytes(cfg) -> (a slot's state bytes, a held token's row
+#              bytes): a stack whose cache keeps a recurrent state a slot
+#              (`KVCache.s`) says what its two kinds of entry weigh, for
+#              the engine's `cache_state_bytes_live` /
+#              `cache_row_bytes_held`
 #   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
 #              -> (cache', final-normed hidden states (W, S, D),
 #                  routing stats or None)
@@ -89,7 +94,7 @@ STACKS: Dict[str, str] = {"llama": "dense", "afmoe": "periodic",
                           "mellum": "periodic", "pangu_ultra_moe": "latent",
                           "sdar_moe": "periodic", "glm_moe_dsa": "latent",
                           "solar_open2": "periodic", "jamba": "periodic",
-                          "ouro": "periodic"}
+                          "ouro": "periodic", "kimi_linear": "periodic"}
 
 # How a block of `TransformerConfig.block_length` positions is unmasked
 # (models/generate.py, `_unmask`): the names a request or a configuration
@@ -119,6 +124,16 @@ class PeriodForm:
     # The global layer's place in its period: 0 opens it, -1 closes it;
     # None: the configuration says (`TransformerConfig.attn_layer_offset`).
     global_at: Optional[int] = -1
+    # The global layer is multi-head latent attention: its leaves, its
+    # cache rows and its two orders of products are `models/mla.py`'s
+    # attention half (the query's low rank where `q_lora_rank` says so;
+    # rotated where "global" is in `rotary`), and `attn_gate` / `qk_norm`
+    # do not apply to it.
+    latent: bool = False
+    # A linear layer's step is `2 sigmoid(h wb)`, in [0, 2]: the
+    # transition's eigenvalue along k may be negative (the published
+    # `kda_allow_neg_eigval`); else `sigmoid(h wb)`.
+    neg_eigval: bool = False
 
 
 # `TransformerConfig.arch` -> its layer, for the architectures of STACKS
@@ -143,7 +158,8 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     # every layer routed, a selection bias.
     "solar_open2": PeriodForm(attn_gate=True, post_norms=False,
                               embed_scale=False, router_bias=True, rotary=(),
-                              qk_norm=False, recurrent="linear", global_at=0),
+                              qk_norm=False, recurrent="linear", global_at=0,
+                              neg_eigval=True),
     # AI21 Jamba: a softmax layer with no position, no gate and no q/k
     # norm at the place of its period the configuration names, Mamba
     # layers around it; the configuration says whether the FFNs are
@@ -161,6 +177,14 @@ PERIOD_FORMS: Dict[str, PeriodForm] = {
     # shared by several passes (the paper's cache-sharing variants).
     "ouro": PeriodForm(attn_gate=False, post_norms=True, embed_scale=False,
                        router_bias=False, rotary=("global",), qk_norm=False),
+    # Moonshot Kimi Linear: gated delta-rule layers (the step not
+    # doubled) and, where the published lists say, a latent-attention
+    # layer with no position (`mla_use_nope`: nothing is rotated) and no
+    # low rank on its query; a pre-norm layer of two norms, a selection
+    # bias; the leading dense layer is a delta-rule layer too.
+    "kimi_linear": PeriodForm(attn_gate=False, post_norms=False,
+                              embed_scale=False, router_bias=True, rotary=(),
+                              qk_norm=False, recurrent="linear", latent=True),
 }
 
 
@@ -269,7 +293,9 @@ class TransformerConfig:
     # the period stack with state-space layers (`mamba_d_state`, at the
     # end) around a global layer at `attn_layer_offset`. "ouro": the
     # period stack, every layer global, walked `ut_steps` times a token
-    # (at the end). STACKS above holds the names.
+    # (at the end). "kimi_linear": the period stack, linear-attention
+    # layers around a latent-attention layer, each layer's kind read
+    # from `linear_attn_config`. STACKS above holds the names.
     arch: str = "llama"
     n_dense_layers: int = 0          # leading layers with a dense FFN
     global_attn_every: int = 0       # period length: one layer of it is global
@@ -286,9 +312,11 @@ class TransformerConfig:
     # section. Kept as sorted tuples of pairs (hashable): `rope_section`.
     rope_parameters: Any = None
     # Latent attention (models/latent.py), under the published keys: the
-    # ranks the query and the keys-and-values are projected down to, and
-    # a head's three widths (the part of q and k with no position, the
-    # rotary part, which every head's key shares, and the values).
+    # ranks the query and the keys-and-values are projected down to
+    # (`q_lora_rank` 0, or a published `null`: the query is projected
+    # from the layer's input, no rank and no norm between), and a head's
+    # three widths (the part of q and k with no position, the rotary
+    # part, which every head's key shares, and the values).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -332,6 +360,14 @@ class TransformerConfig:
     linear_n_heads: int = 0
     linear_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # The published group of that name, kept as sorted tuples of pairs.
+    # Read for two of its keys alone: where it has `kda_layers` and
+    # `full_attn_layers` (layers counted from 1), they say each layer's
+    # kind, and the period stack plans its scan steps from them
+    # (`periodic.layer_plan`: they cover 1..n_layers once, or the
+    # configuration is refused). Without them: whole periods, as
+    # `global_attn_every` and the form place them.
+    linear_attn_config: Any = None
     # State-space layers (models/periodic.py, a form with `recurrent`
     # "ssm"), under the published keys: `mamba_expand` x d_model channels,
     # each keeping `mamba_d_state` float32 coordinates a slot (the
@@ -375,30 +411,43 @@ class TransformerConfig:
                                self.d_model // self.n_heads)
         object.__setattr__(self, "rope_parameters",
                            _frozen(self.rope_parameters))
+        object.__setattr__(self, "linear_attn_config",
+                           _frozen(self.linear_attn_config))
         if self.sliding_window is None:     # a published `null`: no window
             object.__setattr__(self, "sliding_window", 0)
+        if self.q_lora_rank is None:        # a published `null`: no rank
+            object.__setattr__(self, "q_lora_rank", 0)
         if self.arch not in STACKS:
             raise ValueError(f"arch must be one of {sorted(STACKS)}, got "
                              f"{self.arch!r}")
+        listed = self.listed_global is not None
         if self.arch in PERIOD_FORMS:
             body = self.n_layers - self.n_dense_layers
-            if self.global_attn_every < 1 or body < 0 \
-                    or body % self.global_attn_every:
+            if self.global_attn_every < 1 or body < 0 or (
+                    not listed and body % self.global_attn_every):
                 raise ValueError(
                     f"{self.arch}: n_layers - n_dense_layers ({body}) must "
                     f"be whole periods of global_attn_every "
                     f"({self.global_attn_every})")
+        if listed:
+            if self.arch not in PERIOD_FORMS \
+                    or not PERIOD_FORMS[self.arch].recurrent:
+                raise ValueError(
+                    "linear_attn_config lists each layer's kind: only a "
+                    "period stack with linear layers plans from lists")
+            stack(self).layer_plan(self)    # refuses lists it cannot group
         if self.cache_dtype is not None and STACKS[self.arch] != "periodic":
             raise ValueError("cache_dtype: only the period stack states "
                              "its rows' dtype")
         recurrent = PERIOD_FORMS[self.arch].recurrent \
             if self.arch in PERIOD_FORMS else ""
-        if recurrent and (self.sliding_window or self.n_dense_layers
-                          or self.block_length):
+        if recurrent and (self.sliding_window or self.block_length or (
+                self.n_dense_layers and not listed)):
             raise ValueError(
                 f"{self.arch}: a period stack whose other layers keep a "
-                "recurrent state has no window, no leading dense layer and "
-                "no block walk")
+                "recurrent state has no window, no leading dense layer "
+                "(but where linear_attn_config lists each layer's kind) "
+                "and no block walk")
         if (recurrent == "linear") != bool(self.linear_n_heads) or (
                 self.linear_n_heads and (self.linear_head_dim < 1
                                          or self.linear_conv_kernel < 2)):
@@ -435,11 +484,12 @@ class TransformerConfig:
         if self.index_topk:
             if STACKS[self.arch] != "latent" or self.index_n_heads < 1 \
                     or self.index_head_dim < self.qk_rope_head_dim \
-                    or self.index_topk < 1:
+                    or self.index_topk < 1 or not self.q_lora_rank:
                 raise ValueError(
                     f"index_topk {self.index_topk}: only the latent stack "
                     "has the indexer, with index_n_heads heads of "
-                    "index_head_dim >= qk_rope_head_dim")
+                    "index_head_dim >= qk_rope_head_dim, its queries "
+                    "from the query's rank (q_lora_rank)")
         if self.block_length:
             Bd = self.block_length
             if self.arch not in PERIOD_FORMS or self.sliding_window:
@@ -463,6 +513,24 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_experts > 0
+
+    @property
+    def listed_global(self) -> Optional[Tuple[bool, ...]]:
+        """Whether each layer is a global one, in order, where
+        `linear_attn_config` lists the layers of each kind (`kda_layers`,
+        `full_attn_layers`, counted from 1); None where it does not.
+        Lists that do not cover 1..n_layers once are refused."""
+        group = dict(self.linear_attn_config or ())
+        if "kda_layers" not in group and "full_attn_layers" not in group:
+            return None
+        linear = list(group.get("kda_layers") or ())
+        full = list(group.get("full_attn_layers") or ())
+        if sorted(linear + full) != list(range(1, self.n_layers + 1)):
+            raise ValueError(
+                f"linear_attn_config: kda_layers {linear} and "
+                f"full_attn_layers {full} must cover the layers 1.."
+                f"{self.n_layers} once each")
+        return tuple(n in full for n in range(1, self.n_layers + 1))
 
     @property
     def router_experts(self) -> int:

@@ -468,6 +468,13 @@ class LLMEngine:
             # recurrence.
             self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
                                linear_tokens=0)
+            # Which of the two kinds of cache a block's owned bytes are:
+            # the owned slots' states and tails, which every step
+            # rewrites whole, and the rows their held tokens come to
+            # (the stack's own sizes: `cache_bytes`).
+            self._state_bytes, self._row_bytes = stack(cfg).cache_bytes(cfg)
+            self.counts.update(cache_state_bytes_live=0,
+                               cache_row_bytes_held=0)
         # The bf16 terms the model multiplies an activation as, which a
         # slot-side tile's positions follow (`_tile_rows`).
         self._dot_terms = dot_terms(cfg.dtype, cfg.param_dtype)
@@ -1609,8 +1616,13 @@ class LLMEngine:
             steps = k_block * self._state_layers
             c["linear_slot_steps"] += steps * self.num_slots
             c["linear_slot_steps_live"] += steps * len(active)
+            state_bytes = k_block * len(active) * self._state_bytes
+            c["cache_state_bytes_live"] += state_bytes
+            c["cache_row_bytes_held"] += held * self._row_bytes
             more.update(linear_slot_steps=steps * self.num_slots,
-                        linear_slot_steps_live=steps * len(active))
+                        linear_slot_steps_live=steps * len(active),
+                        cache_state_bytes_live=state_bytes,
+                        cache_row_bytes_held=held * self._row_bytes)
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held, **more):

@@ -78,6 +78,8 @@ def pytest_collection_finish(session):
                             "tiny-jamba-closed")
             # tests/benchmark/test_ouro_cell.py makes this one's.
             tiny.setdefault("ouro-2b6-mathqa-closed", "tiny-ouro-closed")
+            # tests/benchmark/test_kimi_cell.py makes this one's.
+            tiny.setdefault("kimi-linear-docgen-closed", "tiny-kimi-closed")
     for mod in {getattr(item, "module", None) for item in session.items}:
         _tell_of_entries_appended_since(mod)
 
@@ -92,7 +94,7 @@ def _tell_of_entries_appended_since(mod):
     """A test file a PR added with its cell (tests/benchmark/
     test_afmoe_cell.py, test_mellum_cell.py, test_pangu_cell.py,
     test_sdar_cell.py, test_glm_cell.py, test_solar_cell.py,
-    test_jamba_cell.py, test_ouro_cell.py) names
+    test_jamba_cell.py, test_ouro_cell.py, test_kimi_cell.py) names
     its cell (`REAL`) and the per-layer entries it appended
     (`NEW_READERS` or `NEW_NAMES`), and holds every other metric that
     lists its cell to be one it knew (`listed == ...`, `spec.metrics(

@@ -1629,3 +1629,75 @@ def test_ouro_programs_fit_beside_the_weights_and_192_slabs(
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 0.1e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- kimi-linear-docgen-closed: states and tails beside latent rows ----------
+
+@pytest.fixture(scope="module")
+def serve_kimi(topo):
+    with open(os.path.join(ROOT, "benchmarks", "cells",
+                           "kimi-linear-docgen-closed.json")) as f:
+        sizes = json.load(f)
+    cfg = _benchmark_config("kimi-linear-48b-ep16", sizes)
+    slots, max_seq = sizes["slots"], sizes["max_seq_len"]
+    return (cfg, slots) + _serve_structs(topo, cfg, slots, max_seq)
+
+
+@pytest.mark.parametrize("program", ["decode_multi", "prefill_sample_batch"])
+def test_kimi_programs_fit_beside_weights_states_and_latent_rows(
+        serve_kimi, as_on_the_chip, record_property, program):
+    """The fused decode block (k = 64) beside the whole cache: twenty KDA
+    layers through the kernels of `ops/delta_rule` (states and tails
+    aliased in and out), seven MLA layers' rows through the decode kernel
+    with one array, megablox's kernel for the held experts; and the
+    longest bucket's admission tile, one row of 4,096: twenty chunked
+    scans, seven layers of per-head attention, 26 routed layers."""
+    from ray_tpu.models import generate
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, slots, one, key, params, cache = serve_kimi
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if program == "decode_multi":
+        lowered = generate.decode_multi.lower(
+            cfg, params, cache, arr((slots,), jnp.int32),
+            arr((slots,), jnp.float32), 64, 0, key, arr((slots,), jnp.bool_))
+    else:
+        rows = LLMEngine._tile_rows(4096, 2 if cfg.dtype == jnp.float32
+                                    else 1)
+        assert rows == 1
+        lowered = generate.prefill_sample_batch.lower(
+            cfg, params, cache, arr((1, 4096), jnp.int32),
+            arr((1,), jnp.int32), arr((1,), jnp.int32), 0,
+            arr((1,), jnp.float32), key)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    # 8.59 GB of weights beside the slots' states (20 x 32 heads x 128 x
+    # 128 float32 a slot), their tails and seven layers' rows of 640
+    # lanes: 3.15-3.20 GB at 32 slots.
+    assert 8.59e9 < weights < 8.60e9
+    assert cache.k is None and cache.c.shape == (7, slots, 6144, 640)
+    assert cache.s.shape == (20, slots, 32, 128, 128)
+    held = sum(x.size * x.dtype.itemsize
+               for x in (cache.s, cache.tails, cache.c))
+    for scope in ("attn_linear", "attn_latent", "mla_proj", "moe_router",
+                  "moe_shared"):
+        assert scope in text, scope
+    assert "attn_global" not in text and "ragged-dot" not in text
+    assert "jit(gmm)" in text
+    if program == "decode_multi":
+        assert '"kernel":"kda_update"' in text \
+            and '"kernel":"kda_tails"' in text and "decode_attn" in text
+    else:
+        assert "kda_scan" in text
+    record_property("argument_gb", mem.argument_size_in_bytes / 1e9)
+    record_property("temp_gb", mem.temp_size_in_bytes / 1e9)
+    print(f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    # Both kinds of cache aliased: no program copies one in or out.
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+

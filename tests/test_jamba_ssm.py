@@ -114,7 +114,7 @@ def test_the_global_layer_stands_where_the_configuration_says(offset):
     assert [(p, j) for p, j, *_ in table] == [
         (p, j) for p in range(group.lead[0]) for j in range(group.lead[1])]
     assert {f"{kind}{own}" for *_, kind, own in table} == {
-        k for k, v in periodic._layer_shapes(cfg, group.routed).items()
+        k for k, v in periodic._layer_shapes(cfg, group).items()
         if isinstance(v, dict)}
     assert [own for _, j, kind, own in table] == [
         period[:j].count(kind) for _, j, kind, _ in table]

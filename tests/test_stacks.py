@@ -24,7 +24,8 @@ TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "glm_moe_dsa": configs.tiny_glm_test,
         "solar_open2": configs.tiny_solar_test,
         "jamba": configs.tiny_jamba_test,
-        "ouro": configs.tiny_ouro_test}
+        "ouro": configs.tiny_ouro_test,
+        "kimi_linear": configs.tiny_kimi_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
 ROOT = os.path.dirname(ray_tpu.__file__)
 
@@ -159,11 +160,11 @@ def test_nothing_above_the_seam_asks_which_architecture_it_serves(path):
 
 
 @pytest.mark.parametrize("module", sorted(set(STACKS.values()))
-                         + ["moe", "stackparts"])
+                         + ["moe", "stackparts", "mla"])
 def test_imports_under_the_seam_point_down(module):
     """The programs reach a stack through `transformer.stack`; a stack
     reaches neither the programs nor another stack, and what the stacks
-    share (`stackparts.py`, `moe.py`) imports no stack at all."""
+    share (`stackparts.py`, `moe.py`, `mla.py`) imports no stack at all."""
     others = set(STACKS.values()) - {module}
     for node in ast.walk(_tree(f"models/{module}.py")):
         names = _imported(node)
@@ -213,6 +214,10 @@ SEEDED = {
     # gate from a key folded out of the seed, residual outputs scaled by
     # the depth walked).
     "tiny_ouro_test": "c715eedf9dc5f33f",
+    # PR 57's preset, on the tree that added it (a plan of three groups
+    # read from lists: a leading layer's own leaves under its kind, a
+    # tail's beside a period's).
+    "tiny_kimi_test": "fe7ce9e5b5630662",
 }
 
 
