@@ -430,6 +430,27 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
         vw=zeros(n[WINDOW], ring) if n[WINDOW] else None, s=s, tails=tails)
 
 
+def scan_chunks(cfg: TransformerConfig, bucket: int, lengths
+                ) -> Optional[Tuple[int, int]]:
+    """(chunks a linear layer's recurrence runs over a tile of `bucket`
+    positions whose rows hold `lengths` tokens, chunks it is asked for:
+    those the rows span): host arithmetic for the engine's
+    `linear_chunks` of `linear_chunks_of`. Where `ops/delta_rule.
+    chunk_scan` takes its kernel at the tile's shape a row runs to the
+    chunk that holds its last token; on the XLA walk every chunk runs
+    and the two are equal. None for a stack whose recurrence is not cut
+    in chunks (a state-space layer's)."""
+    from ..ops import delta_rule
+
+    if not cfg.linear_n_heads:
+        return None
+    ran, asked = delta_rule.scan_chunks(bucket, lengths)
+    heads = jax.ShapeDtypeStruct(
+        (1, bucket, cfg.linear_n_heads, cfg.linear_head_dim), cfg.dtype)
+    return (ran if delta_rule.scan_usable(heads, heads, heads) else asked,
+            asked)
+
+
 def cache_bytes(cfg: TransformerConfig) -> Tuple[int, int]:
     """(bytes of a slot's recurrent states and convolution tails, all
     its layers of the kind; bytes a held token's rows come to over the

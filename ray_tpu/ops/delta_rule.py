@@ -11,25 +11,50 @@ caller normalises q and k and scales q.
 
 Two walks, as every cache has:
 
-- `chunk_scan`: a tile, C = 64 positions a `lax.scan` step, the state
-  carried between chunks. Inside a chunk the C updates are one
-  triangular system (the WY form): with G the running sum of g inside
-  the chunk, `A_ij = sum_d k_id k_jd exp(G_id - G_jd)` for j < i,
+- `chunk_scan`: a tile, C = 64 positions a step, the state carried
+  between chunks. Inside a chunk the C updates are one triangular system
+  (the WY form): with G the running sum of g inside the chunk,
+  `A_ij = sum_d k_id k_jd exp(G_id - G_jd)` for j < i,
   `(I + diag(beta) A) U = diag(beta) (V - (K exp G) S_0)`, then
   `O = (Q exp G) S_0 + P U` with P as A but of q and with its diagonal,
   and `S_C = diag(exp G_C) S_0 + (K exp(G_C - G))^T U`. No exponent is
-  ever positive: 16 x 16 diagonal blocks of A and P sum `exp(G_i - G_j)`
-  a channel as it stands (j <= i), and a block below the diagonal is a
-  product of two factors taken against the running sum where its rows
-  begin, each at most 1 (a single factorisation `exp(G_i) exp(-G_j)`
-  overflows float32 from a decay of 1.4 a token on). The system is solved
-  by blocks of 16, each diagonal block's inverse a product of four
-  (I - N)(I + N^2)(I + N^4)(I + N^8), always in float32 at the highest
-  precision: an error in U stays in the state.
-  Operands of the other products take q's dtype (bf16: one pass a
-  product, float32 accumulated, the state rounded where it enters a
-  product and nowhere else; float32: the highest precision). XLA, a scan
-  step a chunk.
+  ever positive (a single factorisation `exp(G_i) exp(-G_j)` overflows
+  float32 from a decay of 1.4 a token on): a pair's decay is either
+  summed a channel as it stands or is a product of two factors taken
+  against a running sum between the two positions, each at most 1. The
+  system is solved by blocks of 16, each diagonal block's inverse a
+  product of four (I - N)(I + N^2)(I + N^4)(I + N^8), always in float32
+  at the highest precision: an error in U stays in the state. Operands
+  of the other products take q's dtype (bf16: one pass a product,
+  float32 accumulated, the state rounded where it enters a product and
+  nowhere else; float32: the highest precision).
+  On a TPU a kernel (`_scan_pallas`, `kda_scan`): a grid step a chunk of
+  a row through `_HEADS_A_STEP` heads, whose states stay in VMEM from
+  the row's first chunk to its last; the chunk's (C, 8, d) blocks are cut
+  out of q, k, v, g (B, S, H, d) where they lie and `o` is written
+  there; a chunk wholly past its row's length gets no step's work and no
+  loads. Inside a step `_SCAN_HEADS_ABREAST` heads are one array with the
+  heads in front, so every operation is written once and the compiler
+  has four independent chains to pack. A pair (i, j < i) belongs to the
+  one width w, a power of two, at which i's block of w follows j's, and
+  is factored against the running sum at the last row of j's block: six
+  products of (C, d) by (d, C) a head make A and P, those of widths
+  under 16 in float32 at the highest precision whatever the operands'
+  dtype (they are the 16 x 16 diagonal blocks). The kernel is one jitted
+  callee: every site of a program that calls it with equal avals (a
+  kimi tile's five, a queue-side tile's rows) shares one trace and one
+  lowering to Mosaic, and its body is ~1,100 lines of jaxpr (the four
+  blocks of 16, the three squarings and the six widths are all that is
+  unrolled). Anywhere else (off the TPU, a tile that is no whole number
+  of chunks, heads that are not 128 x 128 in blocks of eight) XLA, a
+  `lax.scan` step a chunk (`_chunk_scan_xla`): 16 x 16 diagonal blocks
+  of A and P sum `exp(G_i - G_j)` a channel as it stands (j <= i), a
+  block below them is factored against the running sum where its rows
+  begin, and the right-hand side is [V, K exp G], the state multiplied
+  in behind the solve. On a TPU v5e (my chip run, PR 59) a layer's walk
+  of kimi's 4,096 positions with 2,900 real, 32 heads: XLA 6.51 ms, the
+  kernel 2.33; solar's 2,048 / 1,300, 64 heads: 7.69 and 2.16; `o`
+  within 3.3e-7 and the state within 4.6e-6 of the XLA walk.
 - `decode_update`: one token a slot, layer `l` of the carried states
   (L, B, H, dk, dv) read and written where they lie, in float32
   throughout. On a TPU a kernel (`_update_pallas`): a grid step a slot a
@@ -38,7 +63,8 @@ Two walks, as every cache has:
   given no step. Anywhere else the same update in XLA, `_SLOTS_A_PASS`
   slots a pass, a slot that is not `live` keeping its state bit for bit.
 
-PERF.md section 6, PR 46, has what the chip measured of both.
+PERF.md section 6 has what the chip measured: PR 46 of both walks, PR 59
+of the tile's kernel and of what it costs a program's set-up.
 """
 
 from __future__ import annotations
@@ -140,7 +166,26 @@ def chunk_scan(q, k, v, g, beta, lengths=None, state=None
     last real position (B, H, dk, dv) float32). `lengths` (B,): a
     position at or past its row's length changes no state (None: every
     position is real); what `o` holds there is padding. `state`: what
-    the rows start from (None: zeros)."""
+    the rows start from (None: zeros). On a TPU, where `scan_usable`,
+    the kernel `kda_scan`; anywhere else `_chunk_scan_xla`."""
+    if scan_usable(q, k, v):
+        B, S, H, dk = k.shape
+        # Every call site of a program hands the one jitted callee equal
+        # avals: the kernel is traced once a process a shape and lowered
+        # once a program.
+        return _scan_pallas(
+            q, k, v, g, beta,
+            jnp.full((B,), S, jnp.int32) if lengths is None else lengths,
+            jnp.zeros((B, H, dk, dk), jnp.float32) if state is None
+            else state)
+    return _chunk_scan_xla(q, k, v, g, beta, lengths, state)
+
+
+def _chunk_scan_xla(q, k, v, g, beta, lengths=None, state=None
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """`chunk_scan` in XLA, a `lax.scan` step a chunk: the path off the
+    TPU and at the shapes the kernel does not take, and what the kernel's
+    tests compare it with."""
     B, S, H, dk = k.shape
     dv = v.shape[-1]
     dtype = q.dtype
@@ -400,3 +445,220 @@ def decode_update(states, l, q, k, v, g, beta,
     states, o = lax.fori_loop(0, B // n, one_pass,
                               (states, jnp.zeros((B, H, dv), f32)))
     return o, states
+
+
+# ---------------------------------------------------------------------------
+# The tile's walk as a kernel
+# ---------------------------------------------------------------------------
+
+def scan_usable(q, k, v) -> bool:
+    """Whether `chunk_scan` runs its kernel here: on a TPU, heads of 128
+    x 128 in whole grid steps, whole chunks, float32 or bf16 operands."""
+    from .flash_attention import on_tpu
+
+    _, S, H, dk = k.shape
+    return (on_tpu() and dk == v.shape[-1] == _LANES and 0 < S
+            and S % CHUNK == 0 and q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.float32, jnp.bfloat16)
+            and H % _HEADS_A_STEP == 0)
+
+
+def scan_chunks(positions: int, lengths) -> Tuple[int, int]:
+    """(chunks the walk of a tile of `positions` runs where a chunk
+    wholly past its row's last token is skipped, each row to the chunk
+    that holds the last of its `lengths` tokens; chunks its rows span):
+    host arithmetic, for a counter."""
+    of = -(-positions // CHUNK)
+    return sum(min(-(-n // CHUNK), of) for n in lengths), of * len(lengths)
+
+
+_SCAN_HEADS_ABREAST = 4     # heads a pass of the kernel's loop works as one
+
+
+def _scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                 o_ref, s_ref, st_scr, x_scr, u_scr, *, hb, dtype):
+    """One grid step: a chunk of `CHUNK` positions of one row through
+    `hb` heads, `_SCAN_HEADS_ABREAST` of them a pass of one `fori_loop`,
+    every array of a pass with those heads in front (hh, ...). `st_scr`
+    (hb, dv, dk): the heads' states, transposed (a decay a channel scales
+    lanes), from the row's first chunk to its last; `x_scr`, `u_scr` (hh,
+    C, dv): the system's right-hand side and its solution, a block of 16
+    rows at a time."""
+    b, h0, n = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    C, hh, f32 = CHUNK, _SCAN_HEADS_ABREAST, jnp.float32
+    length = len_ref[b]
+
+    def turned(src, dst):
+        def one(j, _):
+            dst[j] = src[j].T
+        lax.fori_loop(0, hb, one, None)
+
+    @pl.when(n == 0)
+    def _start():
+        turned(s0_ref, st_scr)
+
+    @pl.when(n * C >= length)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n * C < length)
+    def _chunk():
+        def iota(shape, d):
+            return lax.broadcasted_iota(jnp.int32, shape, d)
+
+        row, col = iota((C, C), 0), iota((C, C), 1)
+        at = iota((C, _LANES), 0)
+        real = n * C + iota((C, 1), 0) < length
+        eye = (row == col).astype(f32)
+        same = row // BLOCK == col // BLOCK
+        mine = iota(beta_ref.shape, 1) - h0 * hb
+
+        def down(x, by):            # row i of a head takes row i - by
+            return pltpu.roll(x, by % C, 1)
+
+        def turn(G, w):
+            """G at the row where a row's pair of blocks of w turns from
+            the first block to the second: row (i // 2w) 2w + w - 1 for
+            row i."""
+            if w >= 4:              # whole tiles of 8 rows: a broadcast
+                pairs = G.reshape(hh, C // (2 * w), 2 * w, _LANES)
+                return jnp.broadcast_to(
+                    pairs[:, :, w - 1:w, :], pairs.shape).reshape(G.shape)
+            if w == 2:
+                return jnp.where(
+                    at & 3 == 0, down(G, -1), jnp.where(
+                        at & 3 == 1, G, jnp.where(
+                            at & 3 == 2, down(G, 1), down(G, 2))))
+            return jnp.where(at & 1 == 1, down(G, 1), G)
+
+        def heads(i, _):
+            j0 = i * hh
+
+            def cut(ref):                 # hh heads' (C, dk) of (C, hb, dk)
+                return jnp.stack([
+                    ref.reshape(C * hb, _LANES)[pl.ds(j0 + u, C, stride=hb), :]
+                    for u in range(hh)])
+
+            q, k, v, g = (cut(r) for r in (q_ref, k_ref, v_ref, g_ref))
+            beta = jnp.where(real, jnp.stack([jnp.sum(
+                jnp.where(mine == j0 + u, beta_ref[...], 0.0), axis=1,
+                keepdims=True) for u in range(hh)]), 0.0)       # (hh, C, 1)
+            G = jnp.where(real, g, 0.0)
+            for by in (1, 2, 4, 8, 16, 32):      # the running sum
+                G = G + jnp.where(at >= by, down(G, by), 0.0)
+
+            # A (k against k, below the diagonal) and P (q against k, the
+            # diagonal too). The pair (i, j < i) belongs to the one width
+            # w, a power of two, at which i's block of w follows j's, and
+            # is decayed through the running sum at the last row of j's
+            # block: rows lose what they decayed since, columns what was
+            # left to decay, so no exponent is ever positive. Widths
+            # under 16 are the 16 x 16 diagonal blocks: float32 whatever
+            # the operands' dtype.
+            A = jnp.zeros((hh, C, C), f32)
+            P = eye * jnp.sum(q * k, axis=-1, keepdims=True)
+            for w in (32, 16, 8, 4, 2, 1):
+                decay = jnp.exp(-jnp.abs(G - turn(G, w)))
+                kd, qd = k * decay, q * decay
+                # The rows of a block that follows one: k's where they
+                # lie, q's in the rows of the block before.
+                both = _mm("hid,hjd->hij",
+                           jnp.where((at // w) % 2 == 1, kd, down(qd, -w)),
+                           kd, dtype if w >= BLOCK else f32)
+                here = ((row // w) % 2 == 1) & (col // w == row // w - 1)
+                A = jnp.where(here, both, A)
+                P = jnp.where(here, down(both, w), P)
+
+            # (I + diag(beta) A) U = diag(beta) (V - (K exp G) S_0): the
+            # diagonal blocks' inverses at once (a product of
+            # block-diagonal matrices is their blocks' products), then
+            # forward by blocks of 16.
+            M = beta * A
+            power = jnp.where(same, M, 0.0)
+            inv = eye - power
+            for _ in range(3):                   # N^16 = 0
+                power = _mm("hij,hjk->hik", power, power, f32)
+                inv = _mm("hij,hjk->hik", inv, eye + power, f32)
+            ST = st_scr[pl.ds(j0, hh)]                        # (hh, dv, dk)
+            eG = jnp.exp(G)
+            seen = _mm("hid,hvd->hiv",
+                       jnp.concatenate([k * eG, q * eG], axis=1), ST,
+                       dtype)                                 # (hh, 2 C, dv)
+            x_scr[...] = beta * (v - seen[:, :C])
+            u_scr[...] = jnp.zeros_like(u_scr)
+            for lo in range(0, C, BLOCK):
+                rows = slice(lo, lo + BLOCK)
+                if lo:
+                    x_scr[:, rows, :] -= _mm("hij,hjv->hiv", M[:, rows],
+                                             u_scr[...], f32)
+                # Off its diagonal blocks `inv` is zeros: of x only these
+                # rows are read.
+                u_scr[:, rows, :] = _mm("hij,hjv->hiv", inv[:, rows],
+                                        x_scr[...], f32)
+            U = u_scr[...]
+            o = seen[:, C:] + _mm("hij,hjv->hiv", P, U, dtype)
+            for u in range(hh):
+                o_ref[:, j0 + u, :] = o[u]
+            last = G[:, C - 1:, :]
+            st_scr[pl.ds(j0, hh)] = jnp.exp(last) * ST + _mm(
+                "hcv,hcd->hvd", U, k * jnp.exp(last - G), dtype)
+
+        lax.fori_loop(0, hb // hh, heads, None)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _end():
+        turned(st_scr, s_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _scan_pallas(q, k, v, g, beta, lengths, state, interpret: bool = False):
+    """`chunk_scan` as a kernel: a grid step a (row, `_HEADS_A_STEP`
+    heads, chunk), the chunk axis innermost and in order. The heads'
+    states stay in VMEM from a row's first chunk to its last; a chunk's
+    (C, heads, d) blocks are cut out of the tile where it lies and `o`
+    is written there; a chunk wholly past its row's length is given no
+    work and no loads (its blocks are the last real chunk's, which are
+    not fetched again), its `o` zeros. The tile enters as float32 (a
+    position's eight heads are then one (8, 128) tile, and a head's rows
+    one strided load): bf16 operands are widened where they are made and
+    rounded again where they enter a product. Jitted, so that the sites
+    of a program that call it with equal avals share one trace and one
+    lowering."""
+    hb, hh = _HEADS_A_STEP, _SCAN_HEADS_ABREAST
+    B, S, H, dk = k.shape
+    f32 = jnp.float32
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, S)
+
+    def chunk(b, h, n, len_ref):
+        # The last chunk that holds a real position, for those behind it.
+        return (b, jnp.minimum(n, jnp.maximum(
+            (len_ref[b] + CHUNK - 1) // CHUNK - 1, 0)), h, 0)
+
+    def heads(b, h, n, len_ref):
+        return (b, h, 0, 0)
+
+    tile = pl.BlockSpec((1, CHUNK, hb, dk), chunk)
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, hb=hb, dtype=q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H // hb, S // CHUNK),
+            in_specs=[tile, tile, tile, tile,
+                      pl.BlockSpec((None, CHUNK, H),
+                                   lambda b, h, n, len_ref:
+                                   chunk(b, 0, n, len_ref)[:3]),
+                      pl.BlockSpec((None, hb, dk, dk), heads)],
+            out_specs=[pl.BlockSpec((None, CHUNK, hb, dk),
+                                    lambda b, h, n, len_ref: (b, n, h, 0)),
+                       pl.BlockSpec((None, hb, dk, dk), heads)],
+            scratch_shapes=[pltpu.VMEM((hb, dk, dk), f32),
+                            pltpu.VMEM((hh, CHUNK, dk), f32),
+                            pltpu.VMEM((hh, CHUNK, dk), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, S, H, dk), f32),
+                   jax.ShapeDtypeStruct((B, H, dk, dk), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        metadata={"kernel": "kda_scan"},
+    )(lengths, q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+      beta.astype(f32), state.astype(f32))

@@ -468,6 +468,11 @@ class LLMEngine:
             # recurrence.
             self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
                                linear_tokens=0)
+            if stack(cfg).scan_chunks(cfg, 1, [1]):
+                # The (chunk, such a layer) pairs the tiles' recurrence
+                # ran, of those it was asked for: where its walk skips a
+                # chunk wholly past its row's last token, fewer.
+                self.counts.update(linear_chunks=0, linear_chunks_of=0)
             # Which of the two kinds of cache a block's owned bytes are:
             # the owned slots' states and tails, which every step
             # rewrites whole, and the rows their held tokens come to
@@ -947,6 +952,16 @@ class LLMEngine:
             pairs = tokens * self._state_layers
             c["linear_tokens"] += pairs
             more = dict(more, linear_tokens=pairs)
+            # A row nobody fills is built one token long; a queue-side
+            # tile's rows have no lengths: every chunk runs.
+            chunks = stack(self.cfg).scan_chunks(
+                self.cfg, bucket, [bucket] * W if side == "queue" else
+                [len(r.prompt) - skip for r in reqs] + [1] * (W - len(reqs)))
+            if chunks:
+                ran, asked = (n * self._state_layers for n in chunks)
+                c["linear_chunks"] += ran
+                c["linear_chunks_of"] += asked
+                more = dict(more, linear_chunks=ran, linear_chunks_of=asked)
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
             tile_rows=W, tokens=tokens, req_ids=_ids(reqs), **more)

@@ -401,6 +401,8 @@ def test_a_slots_second_request_gets_what_it_gets_alone(params):
     np.testing.assert_allclose(got_lp, want_lp, rtol=0, atol=1e-5)
     counts = eng.stats()["counts"]
     assert counts["linear_tokens"] == (13 + 10) * 6
+    # A state-space layer's scan is not cut in chunks: nothing to count.
+    assert "linear_chunks" not in counts
     assert counts["linear_slot_steps"] == counts["linear_slot_steps_live"] \
         == counts["slot_steps"] * 6
     assert "moe_rows" not in counts

@@ -401,6 +401,7 @@ def test_a_slots_second_request_gets_what_it_gets_alone_and_the_counters(
     np.testing.assert_allclose(got_lp, want_lp, rtol=0, atol=1e-5)
     counts = eng.stats()["counts"]
     assert counts["linear_tokens"] == (13 + 10) * 8
+    assert (counts["linear_chunks"], counts["linear_chunks_of"]) == (128, 128)
     assert counts["linear_slot_steps"] == counts["linear_slot_steps_live"] \
         == counts["slot_steps"] * 8
     # A slot's eight states (2 x 16 x 16 float32) and tails (3 x 96

@@ -355,6 +355,43 @@ def test_a_slots_second_request_gets_what_it_gets_alone(params):
     assert counts["linear_tokens"] == (13 + 10) * 6
     assert counts["linear_slot_steps"] == counts["linear_slot_steps_live"] \
         == counts["slot_steps"] * 6
+    # Two tiles of eight rows, one filled, in a bucket shorter than a
+    # chunk of the recurrence, on the XLA walk: every row's one chunk.
+    assert (counts["linear_chunks"], counts["linear_chunks_of"]) == (96, 96)
+
+
+@pytest.mark.parametrize("side,kernel,ran", [
+    # Rows of 130, 64 and 1 tokens and a row nobody fills (one token
+    # long) in a bucket of 256, where the walk is the kernel: each to the
+    # chunk of 64 that holds its last token.
+    ("slot", True, 3 + 1 + 1 + 1),
+    # On the XLA walk nothing is skipped: asked and ran are equal.
+    ("slot", False, 4 * 4),
+    # A queue-side tile's rows have no lengths: every chunk of every row.
+    ("queue", True, 4 * 4)])
+def test_a_tiles_chunks_of_the_recurrence_are_counted(params, monkeypatch,
+                                                      side, kernel, ran):
+    """`linear_chunks` of `linear_chunks_of`: the (chunk, linear layer)
+    pairs a tile's recurrence ran of those it was asked for, in the
+    counts and on `engine.prefill_tile`."""
+    import types
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    monkeypatch.setattr(delta_rule, "scan_usable", lambda q, k, v: kernel)
+    eng = LLMEngine(CFG, params, num_slots=1, max_seq_len=64,
+                    decode_block=4)
+    reqs = [types.SimpleNamespace(prompt=[1] * n, id=i)
+            for i, n in enumerate((130, 64, 1))]
+    assert periodic.scan_chunks(CFG, 256, [256, 65]) \
+        == ((4 + 2) if kernel else 4 * 2, 4 * 2)
+    span = eng._tile_span(side, 256, 4, reqs)
+    assert span.attributes["linear_chunks"] == ran * 6
+    assert span.attributes["linear_chunks_of"] == 4 * 4 * 6
+    assert span.attributes["linear_tokens"] == (130 + 64 + 1) * 6
+    counts = eng.stats()["counts"]
+    assert (counts["linear_chunks"], counts["linear_chunks_of"]) \
+        == (ran * 6, 4 * 4 * 6)
 
 
 # -- an expert layer that holds a share ---------------------------------------
