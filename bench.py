@@ -399,7 +399,7 @@ def _smoke_prefix_equivalence() -> None:
     temps = jnp.zeros((W,), jnp.float32)  # greedy
     key = jax.random.key(0)
 
-    _, toks_full, _ = prefill_sample_batch(
+    _, toks_full, _, _ = prefill_sample_batch(
         cfg, params, init_kv_cache(cfg, slots, max_seq),
         jnp.asarray(fbuf), flens, slot_idx, 0, temps, key)
     _, toks_suffix, _ = prefill_suffix_batch(
@@ -497,8 +497,9 @@ def bench_serve_prefix(quick: bool, model: str = "llama-654m",
     slot_d, temps_d = jnp.asarray(slot_idx), jnp.asarray(temps)
 
     def wave_full(cache):
-        return prefill_sample_batch(
+        cache, toks, lps, _extras = prefill_sample_batch(
             cfg, params, cache, fbuf_d, flens_d, slot_d, 0, temps_d, key)
+        return cache, toks, lps
 
     def wave_suffix(cache):
         return prefill_suffix_batch(

@@ -646,7 +646,8 @@ def _prefill_logits(cfg, params, prompts, mesh=None):
     slot_idx[:R] = np.arange(R)
     with (jax.sharding.set_mesh(mesh) if mesh is not None
           else contextlib.nullcontext()):
-        _, logits, *_ = jax.jit(_prefill_batch_core, static_argnums=(0,))(
+        _, logits, _extras = jax.jit(_prefill_batch_core,
+                                     static_argnums=(0,))(
             cfg, params, _init_kv_cache(cfg, W, bucket), jnp.asarray(buf),
             jnp.asarray(lens), jnp.asarray(slot_idx))
     return np.asarray(logits)[:R]

@@ -24,7 +24,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
-from .stackparts import KVCache, _attend_cache, _last_rows, _rope
+# Nothing of this stack's is counted on the host: the seam's defaults.
+from .stackparts import (Extras, KVCache, _attend_cache,  # noqa: F401
+                         _last_rows, _rope, block_counts, by_products,
+                         counters, result_counts, tile_counts)
 from .transformer import (
     TransformerConfig,
     dense_ffn,
@@ -238,10 +241,11 @@ def _final(cfg: TransformerConfig, params, x):
 
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
-            slots) -> Tuple[KVCache, jax.Array, None]:
+            slots) -> Tuple[KVCache, jax.Array, Extras]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D), None: no routed layer to report on). A row
-    whose slot is out of range (a tile's padding) is dropped."""
+    hidden states (W, S, D), `Extras` with nothing in it: no routed layer
+    to report on). A row whose slot is out of range (a tile's padding)
+    is dropped."""
     S = tokens.shape[1]
     x = _embed(cfg, params, tokens)
     sin, cos = rope_tables(cfg, S)
@@ -257,22 +261,22 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
     v = cache.v.at[:, slots, :S].set(vs.astype(cache.v.dtype), mode="drop")
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     return KVCache(k=k, v=v, seq_lens=seq_lens), _final(cfg, params, x), \
-        None
+        Extras()
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), None: no routed layer reports what it chose)."""
+    D), None: no routed layer reports what it chose, `Extras`)."""
     x, _aux = forward_train(cfg, params, tokens)
-    return x, None
+    return x, None, Extras()
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
-           live=None) -> Tuple[KVCache, jax.Array, None]:
-    """One token a slot -> (cache', logits (B, V), None: see
-    `routed_layers`). The cache rides in the scan's carry, so no layer's
-    slab is sliced out of it or stacked back into it. `live` (B,) bool:
-    the slots a request owns (None: every one)."""
+           live=None) -> Tuple[KVCache, jax.Array, Extras]:
+    """One token a slot -> (cache', logits (B, V), `Extras` with nothing
+    in it: see `routed_layers`). The cache rides in the scan's carry, so
+    no layer's slab is sliced out of it or stacked back into it. `live`
+    (B,) bool: the slots a request owns (None: every one)."""
     positions = cache.seq_lens                              # (B,)
     x = _embed(cfg, params, tokens)[:, None, :]             # (B, 1, D)
     sin_t, cos_t = rope_tables(cfg, cache.max_seq_len)
@@ -292,7 +296,7 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
         body, (x, cache.k, cache.v),
         (params["layers"], jnp.arange(cfg.n_layers)))
     logits = head_logits(cfg, params, _final(cfg, params, x))[:, 0]
-    return KVCache(k=k, v=v, seq_lens=positions + 1), logits, None
+    return KVCache(k=k, v=v, seq_lens=positions + 1), logits, Extras()
 
 
 def suffix(cfg: TransformerConfig, params, prefix_k, prefix_v, tokens):

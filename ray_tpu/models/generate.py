@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .stackparts import KVCache
+from .stackparts import Extras, KVCache
 from .transformer import TransformerConfig, offered, stack
 
 
@@ -135,8 +135,8 @@ def prefill(cfg: TransformerConfig, params, cache: KVCache,
     `length` = real prompt length; `slot` = cache row. Compiles once per
     (S_bucket,) — callers bucket prompt lengths.
     """
-    cache, logits, _, _ = _prefill_batch_core(cfg, params, cache, tokens,
-                                              length[None], slot[None])
+    cache, logits, _ = _prefill_batch_core(cfg, params, cache, tokens,
+                                           length[None], slot[None])
     return cache, logits[0]
 
 
@@ -161,25 +161,25 @@ def sample_logp(logits: jax.Array, temps: jax.Array, key: jax.Array,
     return toks, token_logp(logits, toks)
 
 
-def _last_exits(exits, lengths):
-    """A looped stack's exit passes (W, S) at each row's last real
-    position -> (W,)."""
-    return jnp.take_along_axis(
-        exits, (lengths - 1).astype(jnp.int32)[:, None], axis=1)[:, 0]
+def _last_exits(extras: Extras, lengths) -> Extras:
+    """A tile walk's `extras` with its exit passes (W, S), where it has
+    them, at each row's last real position -> (W,)."""
+    if extras.exits is None:
+        return extras
+    return extras._replace(exits=jnp.take_along_axis(
+        extras.exits, (lengths - 1).astype(jnp.int32)[:, None], axis=1)[:, 0])
 
 
 def _prefill_batch_core(cfg: TransformerConfig, params, cache: KVCache,
                         tokens: jax.Array, lengths: jax.Array,
                         slots: jax.Array):
     """Batched-prefill body: write each prompt's KV into its slot,
-    return (cache', last-real-token logits (W, V), the tile's routing
-    stats or None: `routed_layers`, the exit pass (W,) behind those
-    logits or None: a looped configuration's, `cfg.ut_steps` > 1)."""
+    return (cache', last-real-token logits (W, V), the walk's `Extras`
+    with `exits` the exit pass (W,) behind those logits)."""
     st = stack(cfg)
-    cache, x, stats, *exits = st.prefill(cfg, params, cache, tokens, lengths,
-                                         slots)
-    return cache, st.last_logits(cfg, params, x, lengths), stats, \
-        _last_exits(exits[0], lengths) if exits else None
+    cache, x, extras = st.prefill(cfg, params, cache, tokens, lengths, slots)
+    return cache, st.last_logits(cfg, params, x, lengths), \
+        _last_exits(extras, lengths)
 
 
 @program("prefill_sample_batch", static_argnums=(0, 6), donate_argnums=(2,))
@@ -189,9 +189,9 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
                          temps: jax.Array, key: jax.Array):
     """Prefill a BATCH of padded prompts (W, S_bucket) into their cache
     slots and sample each one's first token in ONE dispatch. Returns
-    (cache', first tokens (W,), their log-probabilities (W,)[, routing
-    stats of the tile's W x S_bucket positions: `routed_layers`][, each
-    token's exit pass (W,) int32 from 0: a looped configuration's]).
+    (cache', first tokens (W,), their log-probabilities (W,), `Extras`:
+    routing stats of the tile's W x S_bucket positions, each token's
+    exit pass (W,)).
 
     Every row shares one read of the weights. While that read bounds
     the tile (under ~240 positions a tile on a v5e: 197 TFLOP/s over
@@ -204,10 +204,9 @@ def prefill_sample_batch(cfg: TransformerConfig, params, cache: KVCache,
     out of range (the tile's padding) are dropped by the scatter and
     their sampled token is garbage the caller ignores. Compiles once
     per (W, S_bucket)."""
-    cache, logits, stats, exits = _prefill_batch_core(
+    cache, logits, extras = _prefill_batch_core(
         cfg, params, cache, tokens, lengths, slots)
-    out = (cache,) + sample_logp(logits, temps, key, top_k)
-    return out + tuple(a for a in (stats, exits) if a is not None)
+    return (cache,) + sample_logp(logits, temps, key, top_k) + (extras,)
 
 
 @program("prefill_suffix_batch", static_argnums=(0, 8), donate_argnums=(2,))
@@ -299,11 +298,11 @@ def compute_prefix_kv(cfg: TransformerConfig, params,
 @program("first_token_sample", static_argnums=(0, 5))
 def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
                        lengths: jax.Array, temps: jax.Array, top_k: int,
-                       key: jax.Array) -> Tuple[jax.Array, jax.Array]:
+                       key: jax.Array
+                       ) -> Tuple[jax.Array, jax.Array, Extras]:
     """First token for a BATCH of prompts without touching any KV cache
     (tokens (W, S_bucket), lengths (W,), temps (W,) → (tokens (W,),
-    their log-probabilities (W,)[, their exit passes (W,): a looped
-    configuration's])).
+    their log-probabilities (W,), `Extras`: their exit passes (W,))).
 
     The serving engine uses this to give QUEUED requests their first
     token while every cache slot is busy — TTFT decoupled from slot
@@ -312,9 +311,9 @@ def first_token_sample(cfg: TransformerConfig, params, tokens: jax.Array,
     slot's cur_token), so no recomputed sample can diverge from what
     the client already saw."""
     st = stack(cfg)
-    x, _, *exits = st.forward_free(cfg, params, tokens)
+    x, _, extras = st.forward_free(cfg, params, tokens)
     return sample_logp(st.last_logits(cfg, params, x, lengths), temps, key,
-                       top_k) + tuple(_last_exits(e, lengths) for e in exits)
+                       top_k) + (_last_exits(extras, lengths),)
 
 
 @program("decode_step", static_argnums=(0,), donate_argnums=(2,))
@@ -327,22 +326,8 @@ def decode_step(cfg: TransformerConfig, params, cache: KVCache,
     simply ignores their output and reuses the slot via prefill. `live`
     (B,) bool: the slots a request owns (None: all of them); the others'
     cache rows are not read (`stackparts._attend_cache`)."""
-    cache, logits, *_ = stack(cfg).decode(cfg, params, cache, tokens, live)
+    cache, logits, _ = stack(cfg).decode(cfg, params, cache, tokens, live)
     return cache, logits
-
-
-def routed_layers(cfg: TransformerConfig) -> int:
-    """The layers whose experts' use the fused decode blocks and the
-    admission tiles (`prefill_sample_batch`) of `cfg` count: with any,
-    either returns, after its other results, int32 (4,) = [experts that
-    took a row, summed over steps (one for a tile) and those layers;
-    pairs routed, every slot's; the pairs of the expert most chosen,
-    summed over steps and layers; rows the experts took: the pairs of
-    the slots a request owns (a tile: every position's)]; a stack whose
-    layers hold a share of their experts counts those four over the
-    experts held and adds the pairs routed over all of them (the stack's
-    `routing_stats(cfg)` says how many entries: `moe.routed_ffn`)."""
-    return stack(cfg).routed_layers(cfg)
 
 
 def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
@@ -355,9 +340,8 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
     tokens: (B,) last emitted token per slot; temps: (B,) per-slot
     temperature; live: (B,) bool, the slots a request owns (`decode_step`).
     Returns (cache', toks (num_steps, B), `token_logp` of
-    each (num_steps, B) float32[, routing stats: `routed_layers`][, each
-    token's exit pass (num_steps, B) int32 from 0: a looped
-    configuration's, `cfg.ut_steps` > 1]). The
+    each (num_steps, B) float32, `Extras`: routing stats summed over the
+    steps, each token's exit pass (num_steps, B)). The
     host engine truncates per-slot output at eos/max_new_tokens — slots
     that finish mid-block burn at most num_steps-1 wasted ticks, the
     price of one dispatch and one host fetch per num_steps tokens. The
@@ -379,20 +363,18 @@ def _decode_multi(cfg: TransformerConfig, params, cache: KVCache,
 
     def body(carry, sub):
         cache, tok, routed = carry
-        cache, logits, stats, *exits = st.decode(cfg, params, cache, tok,
-                                                 live)
+        cache, logits, extras = st.decode(cfg, params, cache, tok, live)
         tok, lp = sample_logp(logits, temps, sub, top_k)
-        if stats is not None:
-            routed = routed + stats
-        return (cache, tok, routed), (tok, lp, *exits)
+        if extras.routing is not None:
+            routed = routed + extras.routing
+        return (cache, tok, routed), (tok, lp, extras.exits)
 
     subs = jax.random.split(key, num_steps)
     routed = jnp.zeros((st.routing_stats(cfg),), jnp.int32) \
-        if routed_layers(cfg) else None
-    (cache, _, routed), (toks, lps, *exits) = lax.scan(
+        if st.routed_layers(cfg) else None
+    (cache, _, routed), (toks, lps, exits) = lax.scan(
         body, (cache, tokens, routed), subs)
-    return (cache, toks, lps) + (() if routed is None else (routed,)) \
-        + tuple(exits)
+    return cache, toks, lps, Extras(routed, exits)
 
 
 decode_multi = _BlockPrograms("decode_multi", _decode_multi,
@@ -459,9 +441,9 @@ def prefill_block_batch(cfg: TransformerConfig, params, cache: KVCache,
     first block opened: `first_x` (W, Bd) holds what the prompt left
     over, fixed, then the mask token where `first_masked`. No token is
     sampled: a prompt's last logits are not how such a model starts.
-    Returns (cache', blocks'[, routing stats of the tile])."""
-    cache, _, stats = stack(cfg).prefill(cfg, params, cache, tokens, lengths,
-                                         slots)
+    Returns (cache', blocks', `Extras`: routing stats of the tile)."""
+    cache, _, extras = stack(cfg).prefill(cfg, params, cache, tokens,
+                                          lengths, slots)
 
     def put(old, new):
         return old.at[slots].set(new.astype(old.dtype), mode="drop")
@@ -474,7 +456,7 @@ def prefill_block_batch(cfg: TransformerConfig, params, cache: KVCache,
         passes=put(blocks.passes, jnp.zeros((W,), jnp.int32)),
         steps=put(blocks.steps, steps), rule=put(blocks.rule, rule),
         threshold=put(blocks.threshold, threshold))
-    return (cache, blocks) if stats is None else (cache, blocks, stats)
+    return cache, blocks, extras
 
 
 @program("decode_block_step", static_argnums=(0,), donate_argnums=(2,))
@@ -531,14 +513,15 @@ def _block_pass(cfg: TransformerConfig, params, cache: KVCache,
     and the next block opens all masked. Returns (cache', blocks',
     (kind, x, at_pass, logp, unmasked): what the pass did a slot
     (`PASS_*`), the block as it came in (final where kind is
-    `PASS_COMMIT`), and how many positions it unmasked; routing stats)."""
+    `PASS_COMMIT`), and how many positions it unmasked; the walk's
+    `Extras`)."""
     st = stack(cfg)
     Bd, S = cfg.block_length, cache.max_seq_len
     p0 = cache.seq_lens
     ok = p0 + Bd <= S
     ok = ok if live is None else ok & live
-    cache, logits, stats = st.decode_block(cfg, params, cache, blocks.x, p0,
-                                           ok)
+    cache, logits, extras = st.decode_block(cfg, params, cache, blocks.x, p0,
+                                            ok)
     open_ = jnp.any(blocks.masked, axis=-1)
     denoise, commit = ok & open_, ok & ~open_
     hot = jnp.broadcast_to(temps[:, None], blocks.x.shape)
@@ -571,7 +554,7 @@ def _block_pass(cfg: TransformerConfig, params, cache: KVCache,
         logp=jnp.where(fresh, 0.0, jnp.where(take, lp, blocks.logp)),
         passes=jnp.where(commit, 0, blocks.passes + denoise))
     cache = cache._replace(seq_lens=jnp.where(commit, p0 + Bd, p0))
-    return cache, blocks, out, stats
+    return cache, blocks, out, extras
 
 
 def _decode_block_multi(cfg: TransformerConfig, params, cache: KVCache,
@@ -584,24 +567,24 @@ def _decode_block_multi(cfg: TransformerConfig, params, cache: KVCache,
     times are at different passes of their blocks and one pass serves
     them all. Returns (cache', blocks', (kind (num_steps, B), the block's
     tokens (num_steps, B, Bd), the pass that unmasked each, `token_logp`
-    of each, positions unmasked (num_steps, B))[, routing stats:
-    `routed_layers`, over B x Bd rows a pass]). The host emits a block
-    where `kind` says its pass committed it."""
+    of each, positions unmasked (num_steps, B)), `Extras`: routing stats
+    over B x Bd rows a pass, summed over the passes). The host emits a
+    block where `kind` says its pass committed it."""
     def body(carry, sub):
         cache, blocks, routed = carry
-        cache, blocks, out, stats = _block_pass(cfg, params, cache, blocks,
-                                                temps, top_k, sub, live)
-        if stats is not None:
-            routed = routed + stats
+        cache, blocks, out, extras = _block_pass(cfg, params, cache, blocks,
+                                                 temps, top_k, sub, live)
+        if extras.routing is not None:
+            routed = routed + extras.routing
         return (cache, blocks, routed), out
 
+    st = stack(cfg)
     subs = jax.random.split(key, num_steps)
-    routed = jnp.zeros((stack(cfg).routing_stats(cfg),), jnp.int32) \
-        if routed_layers(cfg) else None
+    routed = jnp.zeros((st.routing_stats(cfg),), jnp.int32) \
+        if st.routed_layers(cfg) else None
     (cache, blocks, routed), out = lax.scan(
         body, (cache, blocks, routed), subs)
-    return (cache, blocks, out) if routed is None \
-        else (cache, blocks, out, routed)
+    return cache, blocks, out, Extras(routing=routed)
 
 
 decode_block_multi = _BlockPrograms("decode_block_multi",

@@ -111,7 +111,7 @@ the sum and no exchange runs.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -127,8 +127,9 @@ from .mla import (_attend_rows, _attend_tile, _chosen_rows,  # noqa: F401
                   init_rows)
 # `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
 from .moe import dot as _dot, routing_stats  # noqa: F401
-from .stackparts import (Group, KVCache, _final, _norm, _rope,  # noqa: F401
-                         _swiglu, ffn_half, head_logits, joins, last_logits)
+from .stackparts import (Extras, Group, KVCache, _final,  # noqa: F401
+                         _norm, _rope, _swiglu, ffn_half, head_logits, joins,
+                         last_logits)
 from .transformer import TransformerConfig, rope_tables
 
 # What the dense stack offers and this one does not (`transformer.offered`).
@@ -164,6 +165,10 @@ def layer_plan(cfg: TransformerConfig) -> List[Group]:
 def routed_layers(cfg: TransformerConfig) -> int:
     """Layers whose use of their experts `decode` reports."""
     return stackparts.routed_layers(layer_plan(cfg))
+
+
+def by_products(cfg: TransformerConfig) -> bool:
+    return bool(routed_layers(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -527,26 +532,74 @@ def choice_columns(cfg: TransformerConfig, bucket: int, length: int
     return int(counted.sum()), len(firsts) * bucket
 
 
+# What the engine counts of this stack, on the host (`stackparts.counters`;
+# docs/METRICS.md says what each counter means).
+
+def counters(cfg: TransformerConfig) -> Dict[str, Any]:
+    found = stackparts.routing_counters(routed_layers(cfg))
+    if cfg.index_topk:
+        found.update(sparse_rows_read=0, prefill_chunks=0,
+                     prefill_chunks_of=0, choice_columns=0,
+                     choice_columns_of=0)
+    return found
+
+
+def tile_counts(cfg: TransformerConfig, bucket: int, lengths, tokens: int):
+    """A tile walked a chunk at a time: the chunks it runs, to the longest
+    row's last token, of those its bucket has (a span's `chunks` of
+    `chunks_of`; the counters, which hold blocks' numbers beside,
+    `prefill_chunks` of `prefill_chunks_of`), and the columns its blocks
+    of queries count to choose their rows, of those the bucket spans."""
+    if not cfg.index_topk:
+        return {}, {}
+    run, of = prefill_chunks(cfg, bucket, max(lengths))
+    cols, cols_of = choice_columns(cfg, bucket, max(lengths))
+    columns = dict(choice_columns=cols, choice_columns_of=cols_of)
+    return dict(prefill_chunks=run, prefill_chunks_of=of, **columns), \
+        dict(chunks=run, chunks_of=of, **columns)
+
+
+def block_counts(cfg: TransformerConfig, k: int, num_slots: int,
+                 max_seq_len: int, first_rows, held: int):
+    """The latent rows the owned slots' attention is to read over the
+    block: of the rows a slot holds at a step `index_topk`, all of them
+    while it holds no more. What the program was asked for, not what it
+    was seen to do."""
+    if not cfg.index_topk:
+        return {}, {}
+    cap = min(cfg.index_topk, max_seq_len)
+    found = dict(sparse_rows_read=sum(
+        min(first + t, cap) for first in first_rows for t in range(k)))
+    return found, found
+
+
+def result_counts(cfg: TransformerConfig, k: int, extras: Extras, taken):
+    found = stackparts.routing_counts(cfg, routed_layers(cfg), k,
+                                      extras.routing)
+    return found, found
+
+
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
-            slots) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+            slots) -> Tuple[KVCache, jax.Array, Extras]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D), routing stats of the tile as `decode` gives a
-    step's, over all W x S positions, padding too; None with no routed
-    layer). Without an indexer the tile goes through whole and attends
-    itself; with one, `_walk` (a chunk that is not run counts nothing)."""
+    hidden states (W, S, D), `Extras`: routing stats of the tile as
+    `decode` gives a step's, over all W x S positions, padding too; None
+    with no routed layer). Without an indexer the tile goes through whole
+    and attends itself; with one, `_walk` (a chunk that is not run
+    counts nothing)."""
     if cfg.index_topk:
         x, (c_all, ki_all, _), stats, _ = _walk(
             cfg, params, (cache.c, cache.ki, None), tokens, lengths, slots)
         seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
         return cache._replace(c=c_all, ki=ki_all, seq_lens=seq_lens), x, \
-            stats if routed_layers(cfg) else None
+            Extras(stats if routed_layers(cfg) else None)
     rope = _rope_tables(cfg, tokens.shape[1])
     x, (c_all, _, _), stats, _ = _run(
         cfg, params, _embed(cfg, params, tokens), rope,
         partial(_prefill_attend, cfg, slots), (cache.c, None, None))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     return cache._replace(c=c_all, seq_lens=seq_lens), \
-        _final(cfg, params, x), stats if routed_layers(cfg) else None
+        _final(cfg, params, x), Extras(stats if routed_layers(cfg) else None)
 
 
 def _free_attend(cfg, l, lp, q_nope, q_r, row, idx, state):
@@ -562,18 +615,18 @@ def _scratch(cfg: TransformerConfig, W: int, S: int):
 def forward_free(cfg: TransformerConfig, params, tokens, whole: bool = False):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
     D), the experts every routed layer chose: see `stackparts.run`; none
-    where the tile is walked in chunks, unless `whole`). A stack that
-    chooses its rows walks the tile against a cache of its own, one slot
-    a row."""
+    where the tile is walked in chunks, unless `whole`; `Extras` with
+    nothing in it). A stack that chooses its rows walks the tile against
+    a cache of its own, one slot a row."""
     if cfg.index_topk:
         W, S = tokens.shape
         x, _, _, chosen = _walk(cfg, params, _scratch(cfg, W, S) + (None,),
                                 tokens, None, jnp.arange(W), whole)
-        return x, chosen
+        return x, chosen, Extras()
     rope = _rope_tables(cfg, tokens.shape[1])
     x, _, _, chosen = _run(cfg, params, _embed(cfg, params, tokens), rope,
                            partial(_free_attend, cfg), None)
-    return _final(cfg, params, x), chosen
+    return _final(cfg, params, x), chosen, Extras()
 
 
 def _decode(cfg: TransformerConfig, params, cache: KVCache, tokens, live,
@@ -586,13 +639,13 @@ def _decode(cfg: TransformerConfig, params, cache: KVCache, tokens, live,
         (cache.c, cache.ki, picks), live)
     cache = cache._replace(c=c_all, ki=ki_all, seq_lens=positions + 1)
     return cache, head_logits(cfg, params, _final(cfg, params, x)[:, 0]), \
-        stats if routed_layers(cfg) else None, picks
+        Extras(stats if routed_layers(cfg) else None), picks
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
-           live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
-    """One token a slot -> (cache', logits (B, V), routing stats of the
-    step (`moe.routed_ffn`'s, summed over the routed layers): held
+           live=None) -> Tuple[KVCache, jax.Array, Extras]:
+    """One token a slot -> (cache', logits (B, V), `Extras`: routing stats
+    of the step (`moe.routed_ffn`'s, summed over the routed layers): held
     experts that took a row, pairs kept, the pairs of the held expert
     most chosen, rows the experts took and, where the layer holds a
     share, the pairs routed; None with no routed layer). `live` (B,)
@@ -605,7 +658,7 @@ def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
     """For tests and for telling a routing flip from arithmetic: the
     experts each routed layer chose for tokens (S,), in layer order, each
     (S, K), numbered as the router numbers them."""
-    _, chosen = jax.jit(partial(forward_free, cfg, whole=True))(
+    _, chosen, _ = jax.jit(partial(forward_free, cfg, whole=True))(
         params, jnp.asarray(tokens, jnp.int32)[None])
     return stackparts.chosen_by_layer(chosen)
 

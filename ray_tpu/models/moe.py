@@ -396,6 +396,21 @@ def routing_stats(cfg) -> int:
     return 4 if cfg.router_experts == cfg.moe_experts else 5
 
 
+def routing_sums(stats) -> Dict[str, int]:
+    """`routed_ffn`'s stats, summed over layers and steps and once on the
+    host, under the counters' names: experts that took a row; the pairs
+    that chose an expert held here and those of the expert most chosen,
+    every slot's; the pairs the products took (the slots a request
+    owns); and the token-expert pairs routed beside the pairs that chose
+    an expert held here (a layer that holds all its experts keeps every
+    pair; one that holds a share says how many were routed in a fifth
+    entry)."""
+    hit, rows, fullest, taken, *pairs = (int(n) for n in stats)
+    return dict(moe_experts_hit=hit, moe_rows=rows, moe_rows_max=fullest,
+                moe_rows_taken=taken,
+                moe_pairs=pairs[0] if pairs else rows, moe_pairs_held=rows)
+
+
 def routed_ffn(cfg, lp: Dict[str, jax.Array], m: jax.Array, dtype,
                expert_weights=None, first=0, rows=None
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:

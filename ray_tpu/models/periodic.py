@@ -112,12 +112,14 @@ moves that token's logits by a tenth of their size or more (PERF.md).
 
 from __future__ import annotations
 
+import functools
 import math
 from functools import partial
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..parallel.sharding import with_sharding_constraint as wsc
@@ -125,10 +127,10 @@ from . import mla, stackparts
 # `routing_stats` and `last_logits` are the seam's (`transformer.STACKS`).
 from .moe import bf16_terms, dot as _dot, dot_terms, \
     routing_stats  # noqa: F401
-from .stackparts import (Group, KVCache, _attend_cache,  # noqa: F401
-                         _attend_cache_block, _final, _norm, _rope, ffn_half,
-                         head_logits, joins, last_logits, masked_softmax,
-                         rows_held)
+from .stackparts import (Extras, Group, KVCache,  # noqa: F401
+                         _attend_cache, _attend_cache_block, _final, _norm,
+                         _rope, ffn_half, head_logits, joins, last_logits,
+                         masked_softmax, rows_held)
 from .transformer import TransformerConfig, rope_tables
 
 WINDOW, GLOBAL, LINEAR, SSM = "window", "global", "linear", "ssm"
@@ -269,6 +271,10 @@ def step_kinds(cfg: TransformerConfig) -> List[Tuple[str, ...]]:
 def routed_layers(cfg: TransformerConfig) -> int:
     """Layers whose use of their experts `decode` reports."""
     return stackparts.routed_layers(layer_plan(cfg))
+
+
+def by_products(cfg: TransformerConfig) -> bool:
+    return bool(routed_layers(cfg)) or cfg.ut_steps > 1
 
 
 def _layers_of(cfg: TransformerConfig) -> Dict[str, int]:
@@ -430,34 +436,14 @@ def init_cache(cfg: TransformerConfig, num_slots: int, max_seq_len: int
         vw=zeros(n[WINDOW], ring) if n[WINDOW] else None, s=s, tails=tails)
 
 
-def scan_chunks(cfg: TransformerConfig, bucket: int, lengths
-                ) -> Optional[Tuple[int, int]]:
-    """(chunks a linear layer's recurrence runs over a tile of `bucket`
-    positions whose rows hold `lengths` tokens, chunks it is asked for:
-    those the rows span): host arithmetic for the engine's
-    `linear_chunks` of `linear_chunks_of`. Where `ops/delta_rule.
-    chunk_scan` takes its kernel at the tile's shape a row runs to the
-    chunk that holds its last token; on the XLA walk every chunk runs
-    and the two are equal. None for a stack whose recurrence is not cut
-    in chunks (a state-space layer's)."""
-    from ..ops import delta_rule
-
-    if not cfg.linear_n_heads:
-        return None
-    ran, asked = delta_rule.scan_chunks(bucket, lengths)
-    heads = jax.ShapeDtypeStruct(
-        (1, bucket, cfg.linear_n_heads, cfg.linear_head_dim), cfg.dtype)
-    return (ran if delta_rule.scan_usable(heads, heads, heads) else asked,
-            asked)
-
-
+@functools.cache
 def cache_bytes(cfg: TransformerConfig) -> Tuple[int, int]:
     """(bytes of a slot's recurrent states and convolution tails, all
     its layers of the kind; bytes a held token's rows come to over the
     global layers: a latent layer's `kv_lora_rank + qk_rope_head_dim`
     values, never the lanes the row is padded to, else a key and a value
-    a KV head, every term): what the engine's `cache_state_bytes_live`
-    and `cache_row_bytes_held` multiply (`serve/llm.py`), read off the
+    a KV head, every term): what `cache_state_bytes_live` and
+    `cache_row_bytes_held` multiply (`block_counts`), read off the
     cache of one slot and one row. A window layer's ring is neither: it
     does not grow with the tokens held."""
     one = jax.eval_shape(lambda: init_cache(cfg, 1, 1))
@@ -470,6 +456,88 @@ def cache_bytes(cfg: TransformerConfig) -> Tuple[int, int]:
         return nbytes(one.s, one.tails), one.c.shape[0] \
             * one.c.dtype.itemsize * mla.cache_width(cfg)
     return nbytes(one.s, one.tails), nbytes(one.k, one.v)
+
+
+# What the engine counts of this stack, on the host (`stackparts.counters`;
+# docs/METRICS.md says what each counter means).
+
+@functools.cache
+def _state_layers(cfg: TransformerConfig) -> int:
+    """Cache slabs that keep a recurrent state a slot (`KVCache.s`)."""
+    return cache_layers(cfg).get(cfg.period_form.recurrent, 0)
+
+
+def counters(cfg: TransformerConfig) -> Dict[str, Any]:
+    found = stackparts.routing_counters(routed_layers(cfg))
+    if _state_layers(cfg):
+        found.update(linear_slot_steps=0, linear_slot_steps_live=0,
+                     linear_tokens=0, cache_state_bytes_live=0,
+                     cache_row_bytes_held=0)
+        if cfg.linear_n_heads:
+            found.update(linear_chunks=0, linear_chunks_of=0)
+    if cfg.ut_steps > 1:
+        found.update(loop_passes=0, loop_exit_hist=[0] * cfg.ut_steps)
+    return found
+
+
+def tile_counts(cfg: TransformerConfig, bucket: int, lengths, tokens: int):
+    """The (token, layer that keeps a state) pairs a tile runs through the
+    recurrence and, where that is cut in chunks (a linear layer's; a
+    state-space layer's is not), the (chunk, layer) pairs it runs of those
+    it is asked for, the chunks its rows span: where `ops/delta_rule.
+    chunk_scan` takes its kernel at the tile's shape a row runs to the
+    chunk that holds its last token; on the XLA walk every chunk runs."""
+    from ..ops import delta_rule
+
+    layers = _state_layers(cfg)
+    if not layers:
+        return {}, {}
+    found = dict(linear_tokens=tokens * layers)
+    if cfg.linear_n_heads:
+        ran, asked = delta_rule.scan_chunks(bucket, lengths)
+        heads = jax.ShapeDtypeStruct(
+            (1, bucket, cfg.linear_n_heads, cfg.linear_head_dim), cfg.dtype)
+        if not delta_rule.scan_usable(heads, heads, heads):
+            ran = asked
+        found.update(linear_chunks=ran * layers,
+                     linear_chunks_of=asked * layers)
+    return found, found
+
+
+def block_counts(cfg: TransformerConfig, k: int, num_slots: int,
+                 max_seq_len: int, first_rows, held: int):
+    """The state updates a block's steps span and those a request owns,
+    which alone read and write a state; the owned slots' states and tails,
+    which every step rewrites whole, beside the bytes their held rows come
+    to (`cache_bytes`)."""
+    layers = _state_layers(cfg)
+    if not layers:
+        return {}, {}
+    state_bytes, row_bytes = cache_bytes(cfg)
+    steps, live = k * layers, len(first_rows)
+    found = dict(linear_slot_steps=steps * num_slots,
+                 linear_slot_steps_live=steps * live,
+                 cache_state_bytes_live=k * live * state_bytes,
+                 cache_row_bytes_held=held * row_bytes)
+    return found, found
+
+
+def result_counts(cfg: TransformerConfig, k: int, extras: Extras, taken):
+    """The routing sums and, of a looped configuration's delivered tokens,
+    the passes walked for them (every pass runs whatever the gate says)
+    and how many left at each pass (the counter a list, the span
+    `loop_exit_p<t>`, t from 1)."""
+    counted = stackparts.routing_counts(cfg, routed_layers(cfg), k,
+                                        extras.routing)
+    if extras.exits is None:
+        return counted, counted
+    exits = np.asarray(extras.exits).reshape(-1, len(taken))
+    got = exits[np.arange(len(exits))[:, None] < np.asarray(taken)[None, :]]
+    hist = np.bincount(got, minlength=cfg.ut_steps).tolist()
+    passes = cfg.ut_steps * len(got)
+    return dict(counted, loop_passes=passes, loop_exit_hist=hist), \
+        dict(counted, loop_passes=passes,
+             **{f"loop_exit_p{t + 1}": n for t, n in enumerate(hist)})
 
 
 # ---------------------------------------------------------------------------
@@ -760,16 +828,14 @@ def _walk(cfg: TransformerConfig, params, x, rope, attend, state, rows=None):
 
 
 def _leave(cfg: TransformerConfig, params, x):
-    """`_walk`'s x -> (each row's final-normed state, what a looped
-    configuration's walks return behind their other results
-    (`transformer.STACKS`' interface): each row's exit pass, x's shape
-    without D; nothing where one pass is the walk): the final norm, or,
-    of a looped configuration's passes, the one `stackparts.exit_select`
-    picks a row."""
+    """`_walk`'s x -> (each row's final-normed state, each row's exit
+    pass, x's shape without D; None where one pass is the walk): the
+    final norm, or, of a looped configuration's passes, the one
+    `stackparts.exit_select` picks a row."""
     if cfg.ut_steps == 1:
-        return _final(cfg, params, x), ()
+        return _final(cfg, params, x), None
     x, exits, _ = stackparts.exit_select(cfg, params, x)
-    return x, (exits,)
+    return x, exits
 
 
 def _embed(cfg: TransformerConfig, params, tokens):
@@ -1057,28 +1123,29 @@ def _cache(state, seq_lens, latent: bool = False) -> KVCache:
 
 
 def prefill(cfg: TransformerConfig, params, cache: KVCache, tokens, lengths,
-            slots) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+            slots) -> Tuple[KVCache, jax.Array, Extras]:
     """tokens (W, S) into the slots' cache rows -> (cache', final-normed
-    hidden states (W, S, D), routing stats of the tile as `decode`
-    gives a step's, over all W x S positions, padding too; None with no
-    routed layer). With `cfg.block_length` the mask is block-causal and
+    hidden states (W, S, D), `Extras`: routing stats of the tile as
+    `decode` gives a step's, over all W x S positions, padding too; None
+    with no routed layer). With `cfg.block_length` the mask is block-causal and
     `lengths` are whole blocks (what is left of a prompt opens the
     slot's first block: `generate.prefill_block_batch`). A looped
-    configuration: x is each position's state at its exit pass, and the
-    exit passes (W, S) follow (`_walk`)."""
+    configuration: x is each position's state at its exit pass, and
+    `exits` the exit passes (W, S) (`_walk`)."""
     rope = rope_by_kind(cfg, tokens.shape[1])
     x, state, stats, _ = _walk(
         cfg, params, _embed(cfg, params, tokens), rope,
         partial(_prefill_attend, cfg, slots, lengths), _state(cache))
     seq_lens = cache.seq_lens.at[slots].set(lengths, mode="drop")
     x, exits = _leave(cfg, params, x)
-    return (_cache(state, seq_lens, cfg.period_form.latent), x,
-            stats if routed_layers(cfg) else None) + exits
+    return _cache(state, seq_lens, cfg.period_form.latent), x, \
+        Extras(stats if routed_layers(cfg) else None, exits)
 
 
 def forward_free(cfg: TransformerConfig, params, tokens):
     """tokens (W, S) with no cache -> (final-normed hidden states (W, S,
-    D), the experts every routed layer chose: see `stackparts.run`). A
+    D), the experts every routed layer chose: see `stackparts.run`,
+    `Extras`: a looped configuration's exit passes (W, S)). A
     tile of several long rows through recurrent layers runs a row at a
     time: a linear layer's projections are 6 x d_model wide in float32
     between its products, a queue-side tile of 4 x 2,048 held 3.9 GB of
@@ -1086,28 +1153,29 @@ def forward_free(cfg: TransformerConfig, params, tokens):
     `_ROW_ALONE` positions fills the chip's multipliers alone."""
     W, S = tokens.shape
     if cfg.period_form.recurrent and W > 1 and S >= _ROW_ALONE:
-        x, chosen = lax.map(
+        x, chosen, extras = lax.map(
             lambda row: forward_free(cfg, params, row[None]), tokens)
         return x[:, 0], jax.tree.map(
             lambda a: jnp.moveaxis(a, 0, 1).reshape(
-                a.shape[1], W * S, a.shape[-1]), chosen)
+                a.shape[1], W * S, a.shape[-1]), chosen), \
+            jax.tree.map(lambda a: a[:, 0], extras)
     rope = rope_by_kind(cfg, tokens.shape[1])
     x, _, _, chosen = _walk(cfg, params, _embed(cfg, params, tokens), rope,
                             partial(_free_attend, cfg), None)
     x, exits = _leave(cfg, params, x)
-    return (x, chosen) + exits
+    return x, chosen, Extras(exits=exits)
 
 
 def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
-           live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
-    """One token a slot -> (cache', logits (B, V), routing stats of the
-    step (`moe.routed_ffn`'s, summed over the routed layers): experts
+           live=None) -> Tuple[KVCache, jax.Array, Extras]:
+    """One token a slot -> (cache', logits (B, V), `Extras`: routing stats
+    of the step (`moe.routed_ffn`'s, summed over the routed layers): experts
     that took a row, pairs routed, the pairs of the expert most chosen,
     rows the experts took; None with no routed layer). `live` (B,) bool:
     the slots a request owns (None: every one): any other slot reads and
     writes no cache row and its token meets no expert. A looped
-    configuration: the logits of each slot's exit pass, and the exit
-    passes (B,) follow (`_walk`)."""
+    configuration: the logits of each slot's exit pass, and `exits` the
+    exit passes (B,) (`_walk`)."""
     if cfg.block_length:
         raise NotImplementedError(NOT_ITS_WALK["decode"])
     positions = cache.seq_lens
@@ -1117,18 +1185,18 @@ def decode(cfg: TransformerConfig, params, cache: KVCache, tokens,
         partial(_decode_attend, cfg, positions, live), _state(cache), live)
     cache = _cache(state, positions + 1, cfg.period_form.latent)
     x, exits = _leave(cfg, params, x)
-    return (cache, head_logits(cfg, params, x[:, 0]),
-            stats if routed_layers(cfg) else None) \
-        + tuple(e[:, 0] for e in exits)
+    return cache, head_logits(cfg, params, x[:, 0]), Extras(
+        stats if routed_layers(cfg) else None,
+        None if exits is None else exits[:, 0])
 
 
 def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
-                 live=None) -> Tuple[KVCache, jax.Array, Optional[jax.Array]]:
+                 live=None) -> Tuple[KVCache, jax.Array, Extras]:
     """One pass over a block a slot: tokens (B, Bd) (the mask token's id
     where a position is still masked) at positions p0 .. p0 + Bd - 1 (p0
     (B,): the rows the slot has committed, a multiple of Bd) -> (cache',
-    logits (B, Bd, V), routing stats of the pass as `decode` gives a
-    step's, over B x Bd rows; None with no routed layer). Each layer
+    logits (B, Bd, V), `Extras`: routing stats of the pass as `decode`
+    gives a step's, over B x Bd rows; None with no routed layer). Each layer
     writes the block's keys and values at rows [p0, p0 + Bd) and every
     query of the block attends over rows [0, p0 + Bd): the committed
     prefix and the block itself, no mask inside it. `seq_lens` is left
@@ -1154,13 +1222,13 @@ def decode_block(cfg: TransformerConfig, params, cache: KVCache, tokens, p0,
     cache = _cache(state, cache.seq_lens)
     with jax.named_scope("block_head"):
         logits = head_logits(cfg, params, _final(cfg, params, x))
-    return cache, logits, stats if routed_layers(cfg) else None
+    return cache, logits, Extras(stats if routed_layers(cfg) else None)
 
 
 def chosen_experts(cfg: TransformerConfig, params, tokens) -> List[jax.Array]:
     """For tests and for telling a routing flip from arithmetic: the
     experts each routed layer chose for tokens (S,), in layer order, each
     (S, K)."""
-    _, chosen = jax.jit(partial(forward_free, cfg))(
+    _, chosen, _ = jax.jit(partial(forward_free, cfg))(
         params, jnp.asarray(tokens, jnp.int32)[None])
     return stackparts.chosen_by_layer(chosen)

@@ -2,8 +2,10 @@
 them: this module imports no stack and not `generate.py`; every stack
 imports it.
 
-- The cache every stack hands the programs (`KVCache`), and one token or
-  one block a slot against a carried cache of keys and values a head.
+- The cache every stack hands the programs (`KVCache`), what its walks
+  hand back beside their results (`Extras`), what a stack counts on the
+  host for the engine (`counters`: the defaults), and one token or one
+  block a slot against a carried cache of keys and values a head.
 - A layer's pieces: the RMS norm, SwiGLU, a branch's way into the
   residual stream, the FFN half (`models/moe.py`'s routed layer beside
   its shared experts, or a dense SwiGLU); the final norm and the head.
@@ -23,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .moe import EXPERT_LEAVES, dot as _dot, routed_ffn, routing_stats
+from .moe import (EXPERT_LEAVES, dot as _dot, routed_ffn, routing_stats,
+                  routing_sums)
 from .transformer import TransformerConfig, apply_rope
 
 
@@ -98,6 +101,72 @@ class KVCache(NamedTuple):
     @property
     def num_slots(self) -> int:
         return self._rows.shape[1]
+
+
+class Extras(NamedTuple):
+    """What a stack's walks and the programs over them hand back beside
+    their results, last and always: a field nobody fills is None, an empty
+    pytree, so a program has one arity whatever the configuration.
+    routing: int32 (4,) or (5,), `moe.routed_ffn`'s stats summed over the
+    routed layers (and over a block's steps). exits: int32, each row's
+    exit pass from 0 where the stack walks its layers `cfg.ut_steps`
+    times: (W, S) a tile's walk, (W,) its program's, (B,) a step's, (k, B)
+    a block's."""
+
+    routing: Optional[jax.Array] = None
+    exits: Optional[jax.Array] = None
+
+
+# ---------------------------------------------------------------------------
+# What a stack counts on the host for the engine: the defaults
+# ---------------------------------------------------------------------------
+# Plain Python over ints (`transformer.STACKS`). `counters`: the zeroed
+# counters a configuration reports beyond the engine's own. Each `*_counts`
+# returns (what the counters gain, by counter; what the span says, by
+# attribute: one dict twice where the names agree): of an admission tile of
+# `bucket` positions whose rows hold `lengths` tokens as the program sees
+# them (`bucket` each on the queue side, 1 for a row nobody fills), `tokens`
+# of them real; of a decode block of `k` steps at dispatch, whose owned
+# slots hold `first_rows[i]` rows once its first step's row is written and
+# `held` summed over its steps; and of a block's `extras` once on the host
+# (a tile's: `k` 0), where `taken[i]` of slot i's (row i's) results reached
+# a caller, its first ones. `by_products`: whether the programs fill any
+# field of `Extras`. Here, what a stack with nothing to add re-exports.
+
+def counters(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {}
+
+
+def _nothing(cfg: TransformerConfig, *told):
+    return {}, {}
+
+
+tile_counts = block_counts = result_counts = _nothing
+
+
+def by_products(cfg: TransformerConfig) -> bool:
+    return False
+
+
+def routing_counters(layers: int) -> Dict[str, int]:
+    """The zeroed counters of `layers` routed layers (`routing_counts`)."""
+    if not layers:
+        return {}
+    names = list(routing_sums([0] * 4))
+    return dict.fromkeys(
+        ["moe_expert_steps"] + names + ["prefill_" + n for n in names], 0)
+
+
+def routing_counts(cfg, layers: int, k: int, routing) -> Dict[str, int]:
+    """`Extras.routing` under the counters' names: a block's of `k` steps,
+    with the expert-steps it offered (steps x layers x experts held; the
+    program says how many took a row), or, under `prefill_`, a tile's."""
+    if routing is None:
+        return {}
+    sums = routing_sums(routing)
+    if not k:
+        return {"prefill_" + name: n for name, n in sums.items()}
+    return dict(moe_expert_steps=k * layers * cfg.moe_experts, **sums)
 
 
 # ---------------------------------------------------------------------------

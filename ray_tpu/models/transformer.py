@@ -45,24 +45,24 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #              one that walks its layers `cfg.ut_steps` times keeps one
 #              for each (pass, layer). Nothing above the stack sizes a
 #              cache from `cfg.n_layers`
-#            cache_bytes(cfg) -> (a slot's state bytes, a held token's row
-#              bytes): a stack whose cache keeps a recurrent state a slot
-#              (`KVCache.s`) says what its two kinds of entry weigh, for
-#              the engine's `cache_state_bytes_live` /
-#              `cache_row_bytes_held`
-#   walks    prefill(cfg, params, cache, tokens (W, S), lengths, slots)
-#              -> (cache', final-normed hidden states (W, S, D),
-#                  routing stats or None)
+#   walks    each returns `stackparts.Extras` last, whatever the
+#            configuration: `routing`, the routed layers' stats (4,), or
+#            (5,) where a layer holds a share of its experts (the pairs
+#            routed over the router's whole width behind), None with no
+#            routed layer; `exits`, each row's exit pass, None unless the
+#            stack walks its layers `cfg.ut_steps` > 1 times
+#            prefill(cfg, params, cache, tokens (W, S), lengths, slots)
+#              -> (cache', final-normed hidden states (W, S, D), Extras:
+#                  exits (W, S))
 #            forward_free(cfg, params, tokens (W, S))
-#              -> (final-normed hidden states, experts chosen or None)
+#              -> (final-normed hidden states, experts chosen or None,
+#                  Extras: no routing, exits (W, S))
 #            decode(cfg, params, cache, tokens (B,), live (B,) bool or None)
-#              -> (cache', logits (B, V), routing stats (4,) or None; (5,)
-#                  where the layer holds a share of its experts: the
-#                  pairs routed over the router's whole width behind).
+#              -> (cache', logits (B, V), Extras: exits (B,)).
 #                  A slot that is not live reads and writes no cache row
 #                  and its token meets no expert (`moe.routed_ffn`)
 #            decode_block(cfg, params, cache, tokens (B, Bd), p0 (B,), live)
-#              -> (cache', logits (B, Bd, V), routing stats): a stack that
+#              -> (cache', logits (B, Bd, V), Extras): a stack that
 #              serves `cfg.block_length` > 0 (generation by diffusion over
 #              blocks) offers this walk beside `decode`: the block's Bd
 #              keys and values go to rows [p0, p0 + Bd) of each slot and
@@ -81,11 +81,13 @@ from ..parallel.sharding import with_sharding_constraint as wsc
 #            A stack that serves `cfg.ut_steps` > 1 (the same layers
 #            walked that many times a token, a learned exit gate a pass:
 #            `stackparts.exit_select`) hands back every row's hidden
-#            state and logits at that row's exit pass, and `prefill`,
-#            `forward_free` and `decode` return one value more, last: the
-#            exit pass of each row, int32 from 0, (W, S) or (B,). A stack
-#            that does not walk loops refuses the configuration
+#            state and logits at that row's exit pass. A stack that does
+#            not walk loops refuses the configuration
 #            (`TransformerConfig.__post_init__`)
+#   counts   counters(cfg), tile_counts, block_counts, result_counts,
+#            by_products(cfg): what the engine reports of the stack's own
+#            mechanism, reckoned on the host (`stackparts.counters`); the
+#            engine names no mechanism (tests/test_stacks.py)
 # and, where it has them (`offered`): `suffix` (the walk behind a shared
 # prefix), `param_logical_axes` (sharding rules), `forward_train` (the
 # walk `forward` and `loss_fn` differentiate). A stack that lacks one

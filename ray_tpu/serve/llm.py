@@ -41,6 +41,7 @@ from ..models.generate import (
     PASS_DENOISE,
     PROGRAM_NAMES,
     BlockState,
+    Extras,
     KVCache,
     compute_prefix_kv,
     decode_block_multi,
@@ -54,7 +55,6 @@ from ..models.generate import (
     prefill_sample_batch,
     prefill_suffix_batch,
     program,
-    routed_layers,
     sample_logp,
 )
 from ..models.moe import dot_terms
@@ -228,20 +228,6 @@ def _ids(reqs) -> str:
     """Request ids as a span attribute: space-separated (the profiler
     cuts a string value at a comma)."""
     return " ".join(str(r.id) for r in reqs)
-
-
-def _routing_sums(stats) -> Dict[str, int]:
-    """A program's routing stats (`models/generate.routed_layers`) under
-    the counters' names: experts that took a row; the pairs that chose
-    an expert held here and those of the expert most chosen, every
-    slot's; the pairs the products took (the slots a request owns); and
-    the token-expert pairs routed beside the pairs that chose an expert
-    held here (a layer that holds all its experts keeps every pair; one
-    that holds a share says how many were routed in a fifth entry)."""
-    hit, rows, fullest, taken, *pairs = (int(n) for n in stats)
-    return dict(moe_experts_hit=hit, moe_rows=rows, moe_rows_max=fullest,
-                moe_rows_taken=taken,
-                moe_pairs=pairs[0] if pairs else rows, moe_pairs_held=rows)
 
 
 # Steps past the shortest budget from which a fused block is rounded down
@@ -432,72 +418,24 @@ class LLMEngine:
             self.counts.update(denoise_passes=0, commit_passes=0,
                                blocks_committed=0, positions_unmasked=0,
                                tokens_truncated=0)
-        # The decode blocks and the admission tiles of a stack with
-        # routed layers report how their experts were used
-        # (models/generate.routed_layers).
-        self._routed_layers = routed_layers(cfg)
-        if self._routed_layers:
-            self.counts.update(moe_expert_steps=0, moe_experts_hit=0,
-                               moe_rows=0, moe_rows_max=0, moe_rows_taken=0,
-                               moe_pairs=0, moe_pairs_held=0,
-                               prefill_moe_experts_hit=0,
-                               prefill_moe_rows=0, prefill_moe_rows_max=0,
-                               prefill_moe_rows_taken=0,
-                               prefill_moe_pairs=0,
-                               prefill_moe_pairs_held=0)
-        if cfg.index_topk:
-            # A stack that chooses the rows a step attends
-            # (models/latent.py): the latent rows the blocks' steps
-            # were to read (a slot's min(rows held, index_topk) a step;
-            # its indexer scores every row held, `cache_rows_held`), and
-            # the chunks its admission tiles ran of those their buckets
-            # have, with the columns their choices counted of those the
-            # buckets span.
-            self.counts.update(sparse_rows_read=0,
-                               prefill_chunks=0, prefill_chunks_of=0,
-                               choice_columns=0, choice_columns_of=0)
-        # Layers that keep a recurrent state a slot (`KVCache.s`: a
-        # period stack's linear-attention layers).
-        self._state_layers = 0 if self.cache.s is None \
-            else int(self.cache.s.shape[0])
-        if self._state_layers:
-            # State updates the blocks' steps span (a slot, a step, such
-            # a layer) and those of them a request owns, which alone
-            # read and write a state; and the real (prompt token, such
-            # a layer) pairs the admission tiles ran through the
-            # recurrence.
-            self.counts.update(linear_slot_steps=0, linear_slot_steps_live=0,
-                               linear_tokens=0)
-            if stack(cfg).scan_chunks(cfg, 1, [1]):
-                # The (chunk, such a layer) pairs the tiles' recurrence
-                # ran, of those it was asked for: where its walk skips a
-                # chunk wholly past its row's last token, fewer.
-                self.counts.update(linear_chunks=0, linear_chunks_of=0)
-            # Which of the two kinds of cache a block's owned bytes are:
-            # the owned slots' states and tails, which every step
-            # rewrites whole, and the rows their held tokens come to
-            # (the stack's own sizes: `cache_bytes`).
-            self._state_bytes, self._row_bytes = stack(cfg).cache_bytes(cfg)
-            self.counts.update(cache_state_bytes_live=0,
-                               cache_row_bytes_held=0)
+        # What the stack counts of its own mechanism beside those
+        # (`transformer.STACKS`: `counters`), reckoned by the stack where
+        # a tile or a block is dispatched and where its results are read.
+        self._stack = stack(cfg)
+        self.counts.update(self._stack.counters(cfg))
+        # Whether the programs hand back anything beside their tokens
+        # (`Extras`): a one-step block whose program does not may run
+        # `decode_step` and the engine's own sampler.
+        self._by_products = self._stack.by_products(cfg)
         # The bf16 terms the model multiplies an activation as, which a
         # slot-side tile's positions follow (`_tile_rows`).
         self._dot_terms = dot_terms(cfg.dtype, cfg.param_dtype)
-        # A looped stack (`cfg.ut_steps` > 1 passes over its layers a
-        # token): its programs return each token's exit pass behind
-        # their other results, and the result path counts, of the tokens
-        # delivered, the passes walked for them (every pass runs whatever
-        # the gate says) and how many left at each pass.
-        self._ut_steps = cfg.ut_steps
-        if self._ut_steps > 1:
-            self.counts.update(loop_passes=0,
-                               loop_exit_hist=[0] * self._ut_steps)
-        # First tokens' exit passes on their way to the host, a tile's
-        # array with the rows whose token the tile delivers: read with
-        # the tokens (_deliver_first_tokens).
+        # Admission tiles' `Extras` on their way to the host
+        # (`_keep_tile_extras`): the exit passes, a tile's array with
+        # which of its rows' tokens the tile delivers, read with the
+        # first tokens (`_deliver_first_tokens`), and the routing stats,
+        # read where the host next waits for a tile (`_read_tile_moe`).
         self._tile_exits: List[Tuple[jax.Array, List[int]]] = []
-        # Admission tiles' routing stats, on their way to the host: read
-        # where the host next waits for a tile (_deliver_first_tokens).
         self._tile_moe: List[jax.Array] = []
         # The last FINISHED_RING completed requests (ttft percentiles
         # in stats() are over these).
@@ -930,41 +868,37 @@ class LLMEngine:
         c["prefill_tile_tokens"] += W * bucket
         if side == "queue":
             c["queue_side_first_tokens"] += len(reqs)
-        if self.cfg.index_topk:
-            # A tile walked a chunk at a time (models/latent.py): the
-            # chunks it runs, to the longest prompt's last token (a
-            # queue-side tile has no lengths and runs them all), of
-            # those its bucket has; and the columns its blocks of queries
-            # count to choose their rows, of those the bucket spans.
-            longest = bucket if side == "queue" else max(
-                len(r.prompt) - skip for r in reqs)
-            run, of = stack(self.cfg).prefill_chunks(self.cfg, bucket,
-                                                     longest)
-            cols, cols_of = stack(self.cfg).choice_columns(self.cfg, bucket,
-                                                           longest)
-            c["prefill_chunks"] += run
-            c["prefill_chunks_of"] += of
-            c["choice_columns"] += cols
-            c["choice_columns_of"] += cols_of
-            more = dict(more, chunks=run, chunks_of=of, choice_columns=cols,
-                        choice_columns_of=cols_of)
-        if self._state_layers:
-            pairs = tokens * self._state_layers
-            c["linear_tokens"] += pairs
-            more = dict(more, linear_tokens=pairs)
-            # A row nobody fills is built one token long; a queue-side
-            # tile's rows have no lengths: every chunk runs.
-            chunks = stack(self.cfg).scan_chunks(
-                self.cfg, bucket, [bucket] * W if side == "queue" else
-                [len(r.prompt) - skip for r in reqs] + [1] * (W - len(reqs)))
-            if chunks:
-                ran, asked = (n * self._state_layers for n in chunks)
-                c["linear_chunks"] += ran
-                c["linear_chunks_of"] += asked
-                more = dict(more, linear_chunks=ran, linear_chunks_of=asked)
+        # The rows' lengths as the program sees them: a row nobody fills
+        # is built one token long, and a queue-side tile hands over none.
+        lengths = [bucket] * W if side == "queue" else \
+            [len(r.prompt) - skip for r in reqs] + [1] * (W - len(reqs))
+        more.update(self._count(self._stack.tile_counts(
+            self.cfg, bucket, lengths, tokens)))
         return tracing.span(
             "engine.prefill_tile", side=side, bucket=bucket, rows=len(reqs),
             tile_rows=W, tokens=tokens, req_ids=_ids(reqs), **more)
+
+    def _count(self, found) -> Dict[str, Any]:
+        """What the stack reckoned (`stackparts.counters`) onto the
+        counters, entry by entry where a counter is a list. Returns what
+        the span is to say."""
+        counted, said = found
+        for name, n in counted.items():
+            old = self.counts[name]
+            self.counts[name] = [a + b for a, b in zip(old, n)] \
+                if isinstance(old, list) else old + n
+        return said
+
+    def _keep_tile_extras(self, extras: Extras, taken: List[int]) -> None:
+        """An admission tile's `Extras`, their host copies started, until
+        the host next waits for a tile. `taken`: a real row, whether the
+        tile's token is the one its request is given."""
+        if extras.exits is not None:
+            self._tile_exits.append((extras.exits, taken))
+        self._start_host_copy(extras.exits)
+        if extras.routing is not None:
+            self._tile_moe.append(extras.routing)
+        self._start_host_copy(extras.routing)
 
     def _launch_span(self, program: str) -> tracing.span:
         """The span of one device program the engine's thread calls
@@ -1121,20 +1055,14 @@ class LLMEngine:
                                         jnp.asarray(slot_idx),
                                         jnp.asarray(temps))
                             with self._program_call(launch):
-                                self.cache, toks, lps, *more = \
+                                self.cache, toks, lps, extras = \
                                     prefill_sample_batch(
                                         self.cfg, self.params, self.cache,
                                         *tile[:3], self.top_k, tile[3], sub)
-                    if self._ut_steps > 1:
-                        # A request whose first token the queue side
-                        # gave is counted there.
-                        exits = more.pop()
-                        self._tile_exits.append((exits, [
-                            j for j, req in enumerate(reqs)
-                            if getattr(req, "_early_tok", None) is None]))
-                        self._start_host_copy(exits)
-                    self._tile_moe += more      # routing stats
-                    self._start_host_copy(*more)
+                    # A request whose first token the queue side gave is
+                    # counted there.
+                    self._keep_tile_extras(extras, [
+                        int(req._early_tok is None) for req in reqs])
                 else:
                     sp = len(pkey)
                     with self._tile_span("slot", bucket, W, reqs, skip=sp):
@@ -1242,7 +1170,7 @@ class LLMEngine:
                                 buf, lens, slot_idx, first_x, first_masked,
                                 steps, rule, threshold)]
                         with self._program_call(launch):
-                            self.cache, self._blocks, *moe = \
+                            self.cache, self._blocks, extras = \
                                 prefill_block_batch(
                                     self.cfg, self.params, self.cache,
                                     self._blocks, *tile)
@@ -1257,8 +1185,7 @@ class LLMEngine:
                         for req, _, _ in reversed(later):
                             self.waiting.appendleft(req)
                 raise
-            self._tile_moe += moe
-            self._start_host_copy(*moe)
+            self._keep_tile_extras(extras, [])
             for req, idx, whole in chunk:
                 slot = _Slot(req, whole)
                 # What the prompt left over stands in the first block.
@@ -1266,21 +1193,17 @@ class LLMEngine:
                                        + req.max_new_tokens) // Bd)
                 self.slots[idx] = slot
 
-    def _read_tile_moe(self, span=None) -> None:
+    def _read_tile_moe(self, span) -> None:
         """The admission tiles' routing stats, once the device has them:
         onto the counters and onto `span`."""
-        tile_moe = []
-        if self._tile_moe:
-            with self._device_call("to_host", n=len(self._tile_moe)):
-                tile_moe = [np.asarray(m) for m in self._tile_moe]
-        self._tile_moe = []
-        if tile_moe:
-            routed = {"prefill_" + name: n for name, n in
-                      _routing_sums(np.sum(tile_moe, 0)).items()}
-            for name, n in routed.items():
-                self.counts[name] += n
-            if span is not None:
-                span.set(moe_tiles=len(tile_moe), **routed)
+        if not self._tile_moe:
+            return
+        tiles, self._tile_moe = self._tile_moe, []
+        with self._device_call("to_host", n=len(tiles)):
+            routing = np.sum([np.asarray(m) for m in tiles], 0)
+        span.set(moe_tiles=len(tiles), **self._count(
+            self._stack.result_counts(self.cfg, 0, Extras(routing=routing),
+                                      ())))
 
     def _early_first_tokens(self) -> List:
         """TTFT decoupled from slot availability: queued requests that
@@ -1327,11 +1250,9 @@ class LLMEngine:
                         tile = (jnp.asarray(buf), jnp.asarray(lens),
                                 jnp.asarray(temps))
                     with self._program_call(launch):
-                        toks, lps, *exits = first_token_sample(
+                        toks, lps, extras = first_token_sample(
                             self.cfg, self.params, *tile, self.top_k, sub)
-                for a in exits:
-                    self._tile_exits.append((a, list(range(len(chunk)))))
-                    self._start_host_copy(a)
+                self._keep_tile_extras(extras, [1] * len(chunk))
                 if W < self._ADMIT_TILE:
                     with self._device_call("pad"):
                         toks = jnp.pad(toks, (0, self._ADMIT_TILE - W))
@@ -1420,9 +1341,11 @@ class LLMEngine:
                 if self._tile_exits:
                     tiles, self._tile_exits = self._tile_exits, []
                     with self._device_call("to_host", n=len(tiles)):
-                        exits = np.concatenate(
-                            [np.asarray(a)[rows] for a, rows in tiles])
-                    self._count_exits(span, exits)
+                        exits = np.concatenate([np.asarray(a)[:len(taken)]
+                                                for a, taken in tiles])
+                    span.set(**self._count(self._stack.result_counts(
+                        self.cfg, 0, Extras(exits=exits),
+                        [t for _, taken in tiles for t in taken])))
             slots = (self.slots[idx] for idx, _, _, _ in admitted)
             first = [s.req for s in slots if s is not None] \
                 + [r for reqs, _, _ in outs for r in reqs]
@@ -1584,16 +1507,6 @@ class LLMEngine:
         return min(k_block * (slot.length + slot.inflight + 1)
                    + k_block * (k_block - 1) // 2, k_block * S)
 
-    def _rows_read(self, k_block: int, slot: _Slot) -> int:
-        """Latent rows the slot's attention is to read over the next
-        `k_block` steps where an indexer chooses them: of the rows it
-        holds at a step (`_rows_held`) `index_topk`, all of them while
-        it holds no more. The host's arithmetic, as `_rows_held` is: what
-        the program was asked for, not what it was seen to do."""
-        first = slot.length + slot.inflight + 1
-        cap = min(self.cfg.index_topk, self.max_seq_len)
-        return sum(min(first + t, cap) for t in range(k_block))
-
     def _dispatch_block(self, k_block: int, snap: List, active: List[int],
                         k_up: int = 0):
         """One fused block of `k_block` decode steps for every slot (the
@@ -1623,21 +1536,9 @@ class LLMEngine:
         if k_up > k_block:
             c["blocks_rounded_down"] += 1
             more.update(short_of=k_up)
-        if self.cfg.index_topk:
-            read = sum(self._rows_read(k_block, snap[i]) for i in active)
-            c["sparse_rows_read"] += read
-            more.update(sparse_rows_read=read)
-        if self._state_layers:
-            steps = k_block * self._state_layers
-            c["linear_slot_steps"] += steps * self.num_slots
-            c["linear_slot_steps_live"] += steps * len(active)
-            state_bytes = k_block * len(active) * self._state_bytes
-            c["cache_state_bytes_live"] += state_bytes
-            c["cache_row_bytes_held"] += held * self._row_bytes
-            more.update(linear_slot_steps=steps * self.num_slots,
-                        linear_slot_steps_live=steps * len(active),
-                        cache_state_bytes_live=state_bytes,
-                        cache_row_bytes_held=held * self._row_bytes)
+        more.update(self._count(self._stack.block_counts(
+            self.cfg, k_block, self.num_slots, self.max_seq_len,
+            [snap[i].length + snap[i].inflight + 1 for i in active], held)))
         with tracing.span("engine.dispatch_block", block=number, k=k_block,
                           active=len(active), slots=self.num_slots,
                           cache_rows=rows, cache_rows_held=held, **more):
@@ -1651,18 +1552,17 @@ class LLMEngine:
                     self._key, sub = jax.random.split(self._key)
                 with self._device_call("to_device", n=1):
                     live = jnp.asarray(owned)
-                moe = lps = sampler = exits = None
+                lps = sampler = None
+                extras = Extras()
                 if self.block_length:
                     with self._program_call(launch, k=k_block) as call:
-                        self.cache, self._blocks, toks, *moe = \
+                        self.cache, self._blocks, toks, extras = \
                             decode_block_multi(
                                 self.cfg, self.params, self.cache,
                                 self._blocks, self._temps, k_block,
                                 self.top_k, sub, live)
-                    moe = moe[0] if moe else None
                     self._start_host_copy(*toks)
-                elif k_block == 1 and not self._routed_layers \
-                        and self._ut_steps == 1:
+                elif k_block == 1 and not self._by_products:
                     with self._program_call(launch, k=k_block):
                         self.cache, logits = decode_step(
                             self.cfg, self.params, self.cache,
@@ -1679,20 +1579,17 @@ class LLMEngine:
                         toks = toks[None]                  # (1, B)
                 else:
                     with self._program_call(launch, k=k_block) as call:
-                        self.cache, toks, lps, *more = decode_multi(
+                        self.cache, toks, lps, extras = decode_multi(
                             self.cfg, self.params, self.cache,
                             self.cur_tokens, self._temps, k_block,
                             self.top_k, sub, live)         # (k, B)
-                    # The tokens' exit passes (k, B): a looped stack's.
-                    exits = more.pop() if self._ut_steps > 1 else None
-                    moe = more[0] if more else None   # routing stats
                 # Start the host copy NOW, before the next tick enqueues
                 # prefills and the next block behind it.
                 if not self.block_length:
                     with self._device_call("slice"):
                         self.cur_tokens = toks[-1]
-                    self._start_host_copy(toks, lps, exits)
-                self._start_host_copy(moe)
+                    self._start_host_copy(toks, lps, extras.exits)
+                self._start_host_copy(extras.routing)
             # Whose result `_process_block`'s fetch waits for.
             source = dict(call=call, program=sampler) if sampler else dict(
                 call=call, program=launch.attributes["program"],
@@ -1701,7 +1598,7 @@ class LLMEngine:
             for i in active:
                 snap[i].inflight += k_block
         return (toks, lps, k_block, [(i, snap[i]) for i in active], number,
-                moe, source, exits)
+                extras, source)
 
     def warm_decode_blocks(self) -> List[int]:
         """Run the fused decode program of every size the adaptive block
@@ -1726,7 +1623,7 @@ class LLMEngine:
         block was in flight now holds a different request, and the
         identity check keeps the dead request's overshoot tokens out
         of the new request's stream."""
-        toks, lps, k_block, slot_snap, number, moe, source, exits = block
+        toks, lps, k_block, slot_snap, number, extras, source = block
         # `slots`: positions a step computes (a slot's block a pass).
         span = tracing.span("engine.process_block", block=number, k=k_block,
                             slots=self.num_slots * self._step_rows,
@@ -1745,26 +1642,18 @@ class LLMEngine:
                         host_toks = np.asarray(toks)
                         # (B,) after a one-step block's own sampler
                         host_lps = np.asarray(lps).reshape(host_toks.shape)
-                host_moe = host_exits = None
-                if moe is not None:
-                    with self._device_call("to_host", n=1):
-                        host_moe = np.asarray(moe)
-                if exits is not None:
-                    with self._device_call("to_host", n=1):
-                        host_exits = np.asarray(exits)
+                extras = Extras(*(a if a is None else self._to_host(a)
+                                  for a in extras))
             self.steps_processed += k_block
             before = self.tokens_out
             with self._emit_span():
                 if self.block_length:
                     passes = self._emit_passes(host, k_block, slot_snap)
+                    taken = ()      # a block's tokens: nothing read of them
                 else:
-                    took = self._emit_block(host_toks, host_lps, k_block,
-                                            slot_snap)
+                    taken = self._emit_block(host_toks, host_lps, k_block,
+                                             slot_snap)
             emitted = self.tokens_out - before
-            if host_exits is not None:
-                self._count_exits(span, np.concatenate(
-                    [host_exits[:n, i] for i, n in took.items()]
-                    or [host_exits[:0, 0]]))
             discarded = k_block * len(slot_snap) * self._step_rows - emitted
             if self.block_length:
                 for name, n in passes.items():
@@ -1772,33 +1661,19 @@ class LLMEngine:
                 span.set(block_length=self.block_length, **passes)
             self.counts["tokens_discarded"] += discarded
             span.set(emitted=emitted, discarded=discarded)
-            if host_moe is not None:
-                # Expert-steps a block offers: steps x routed layers x
-                # experts; the program says how many held a row.
-                routed = dict(
-                    moe_expert_steps=k_block * self._routed_layers
-                    * self.cfg.moe_experts, **_routing_sums(host_moe))
-                for name, n in routed.items():
-                    self.counts[name] += n
-                span.set(**routed)
+            span.set(**self._count(self._stack.result_counts(
+                self.cfg, k_block, extras, taken)))
 
-    def _count_exits(self, span, exits: np.ndarray) -> None:
-        """Delivered tokens' exit passes (from 0) into the counters and
-        onto `span`: the passes walked for them, and how many left at
-        each pass (`loop_exit_p<t>`, t from 1)."""
-        hist = np.bincount(exits, minlength=self._ut_steps).tolist()
-        passes = self._ut_steps * len(exits)
-        self.counts["loop_passes"] += passes
-        for t, n in enumerate(hist):
-            self.counts["loop_exit_hist"][t] += n
-        span.set(loop_passes=passes,
-                 **{f"loop_exit_p{t + 1}": n for t, n in enumerate(hist)})
+    def _to_host(self, array: jax.Array) -> np.ndarray:
+        """One array's host read, as one `engine.device_call`."""
+        with self._device_call("to_host", n=1):
+            return np.asarray(array)
 
     def _emit_block(self, host_toks, host_lps, k_block: int,
-                    slot_snap: List) -> Dict[int, int]:
-        """Returns {slot: the steps of the block whose tokens it was
-        given, its first ones} for every slot that took any."""
-        took: Dict[int, int] = {}
+                    slot_snap: List) -> List[int]:
+        """Returns, a slot, the steps of the block whose tokens it was
+        given, its first ones."""
+        took = [0] * self.num_slots
         for i, slot0 in slot_snap:
             slot0.inflight -= k_block
             slot = self.slots[i]
@@ -1928,11 +1803,8 @@ class LLMEngine:
         ttfts = sorted(f["ttft_s"] for f in fin)
         out: Dict[str, Any] = {
             "finished": self._n_finished,
-            "counts": dict(self.counts, **{
-                k: type(self.counts[k])(self.counts[k]) for k in (
-                    "blocks_by_k", "launches", "device_calls",
-                    "device_call_ns", "loop_exit_hist")
-                if k in self.counts}),
+            "counts": {k: type(v)(v) if isinstance(v, (dict, list)) else v
+                       for k, v in self.counts.items()},
             "decode_ticks": self.decode_ticks,
             "tokens_out": self.tokens_out,
             "waiting": len(self.waiting),

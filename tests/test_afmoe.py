@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import configs, moe, periodic
+from ray_tpu.models import configs, dense, moe, periodic
 from ray_tpu.models.generate import (
     compute_prefix_kv,
     decode_multi,
@@ -24,7 +24,6 @@ from ray_tpu.models.generate import (
     prefill,
     prefill_sample_batch,
     prefill_suffix_batch,
-    routed_layers,
 )
 from ray_tpu.models.transformer import (
     TransformerConfig,
@@ -73,7 +72,8 @@ def test_the_preset_is_the_published_shape_in_small():
     assert cache.k.shape == (1, 3, 64, 2, 32)
     assert cache.kw.shape == (4, 3, 8, 2, 32)      # a ring of the window
     assert configs.get("tiny_afmoe") == CFG
-    assert routed_layers(CFG) == 4 and not routed_layers(configs.tiny_test())
+    assert periodic.routed_layers(CFG) == 4
+    assert not dense.routed_layers(configs.tiny_test())
 
 
 def test_head_dim_defaults_to_the_quotient():
@@ -142,10 +142,10 @@ def test_tile_first_token_and_block_agree_with_the_reference(params):
     temps = jnp.zeros((4,), jnp.float32)
     key = jax.random.key(0)
     cache = init_kv_cache(CFG, 3, 48)
-    cache, first, *_ = prefill_sample_batch(
+    cache, first, _, _ = prefill_sample_batch(
         CFG, params, cache, jnp.asarray(toks), lengths, slots, 0, temps, key)
-    free, _ = first_token_sample(CFG, params, jnp.asarray(toks), lengths,
-                                 temps, 0, key)
+    free, _, _ = first_token_sample(CFG, params, jnp.asarray(toks), lengths,
+                                    temps, 0, key)
     want = [int(np.argmax(np.asarray(ref.forward_logits(
         ARCH, params, toks[i, :n].tolist()))[-1]))
         for i, n in enumerate(lens)]
@@ -153,8 +153,9 @@ def test_tile_first_token_and_block_agree_with_the_reference(params):
     assert list(np.asarray(cache.seq_lens)) == [3, 16, 11]
 
     cur = jnp.asarray([want[1], want[2], want[0]], jnp.int32)   # by slot
-    cache, out, _, stats = decode_multi(CFG, params, cache, cur, temps[:3],
-                                        4, 0, key)
+    cache, out, _, extras = decode_multi(CFG, params, cache, cur, temps[:3],
+                                         4, 0, key)
+    stats = extras.routing
     out = np.asarray(out)
     for slot, i in ((0, 1), (1, 2), (2, 0)):
         seq = toks[i, :lens[i]].tolist() + [want[i]] + out[:, slot].tolist()
@@ -384,13 +385,13 @@ def _serve_outputs(cfg):
     temps = jnp.zeros((2,), jnp.float32)
     key = jax.random.key(1)
     cache = init_kv_cache(cfg, 2, 32)
-    cache, first, *_ = prefill_sample_batch(
+    cache, first, _, _ = prefill_sample_batch(
         cfg, params, cache, toks, lengths, jnp.asarray([0, 1], jnp.int32),
         0, temps, key)
-    cache, block, _ = decode_multi(cfg, params, cache, first, temps, 4, 0,
-                                   key)
+    cache, block, _, _ = decode_multi(cfg, params, cache, first, temps, 4, 0,
+                                      key)
     cache, logits = decode_step(cfg, params, cache, block[-1])
-    free, _ = first_token_sample(cfg, params, toks, lengths, temps, 0, key)
+    free, _, _ = first_token_sample(cfg, params, toks, lengths, temps, 0, key)
     return first, block, logits, free, cache.k, cache.v
 
 

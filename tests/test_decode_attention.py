@@ -167,11 +167,11 @@ def test_an_ownerless_slot_changes_no_owned_slots_logits(arch):
     np.testing.assert_array_equal(np.asarray(c_two.k)[:, [0, 2]],
                                   np.asarray(c_all.k)[:, [0, 2]])
     # The fused block takes `live` last, and gives the step's tokens.
-    out = generate.decode_multi(cfg, params, copy(cache), tok,
-                                jnp.zeros((3,)), 2, 0, jax.random.PRNGKey(0),
-                                live)
+    _, toks, _, _ = generate.decode_multi(
+        cfg, params, copy(cache), tok, jnp.zeros((3,)), 2, 0,
+        jax.random.PRNGKey(0), live)
     np.testing.assert_array_equal(
-        np.asarray(out[1][0])[[0, 2]],
+        np.asarray(toks[0])[[0, 2]],
         np.asarray(jnp.argmax(four, axis=-1))[[0, 2]])
 
 

@@ -844,8 +844,9 @@ def test_a_block_size_is_one_program_compiled_once(tiny_model):
     assert generate.decode_multi.program_for(2) is not fn
     cache = generate.init_kv_cache(cfg, 2, 32)
     cur, temps = jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.float32)
-    cache, toks, lps = generate.decode_multi(cfg, params, cache, cur, temps,
-                                             4, 0, jax.random.key(1))
+    cache, toks, lps, extras = generate.decode_multi(
+        cfg, params, cache, cur, temps, 4, 0, jax.random.key(1))
+    assert extras == generate.Extras()       # a dense stack fills nothing
     assert toks.shape == lps.shape == (4, 2) and int(cache.seq_lens[0]) == 4
 
 

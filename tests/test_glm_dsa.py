@@ -29,7 +29,6 @@ from ray_tpu.models.generate import (
     init_kv_cache,
     prefill,
     prefill_sample_batch,
-    routed_layers,
 )
 from ray_tpu.models.transformer import (
     LATENT_FORMS,
@@ -85,7 +84,7 @@ def test_the_preset_is_the_published_shape_in_small(params):
     assert LATENT_FORMS["pangu_ultra_moe"].post_norms is True
     assert latent.layer_plan(CFG) == [("dense_layers", (1,), False),
                                       ("routed_layers", (2,), True)]
-    assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
+    assert latent.routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     # Two arrays of rows: the latent vector with its rotary key in whole
     # lanes, and the indexer's one key a token a layer.
@@ -197,20 +196,22 @@ def test_the_fused_block_and_the_admission_tile_serve_the_same(params):
     buf = np.zeros((1, 32), np.int32)
     buf[0, :27] = seq
     cache = init_kv_cache(CFG, 2, 64)
-    cache, toks, lps, stats = prefill_sample_batch(
+    cache, toks, lps, extras = prefill_sample_batch(
         CFG, params, cache, jnp.asarray(buf), jnp.asarray([27]),
         jnp.asarray([1]), 0, jnp.zeros((1,)), jax.random.key(0))
     want = np.asarray(ref.forward_logits(ARCH, params, seq))
-    assert int(toks[0]) == int(np.argmax(want[-1])) and stats.shape == (5,)
-    early, _ = first_token_sample(CFG, params, jnp.asarray(buf),
-                                  jnp.asarray([27]), jnp.zeros((1,)), 0,
-                                  jax.random.key(0))
+    assert int(toks[0]) == int(np.argmax(want[-1]))
+    assert extras.routing.shape == (5,) and extras.exits is None
+    early, _, _ = first_token_sample(CFG, params, jnp.asarray(buf),
+                                     jnp.asarray([27]), jnp.zeros((1,)), 0,
+                                     jax.random.key(0))
     assert int(early[0]) == int(toks[0])        # the queue side's, no cache
     cur = jnp.asarray([0, int(toks[0])], jnp.int32)
     live = jnp.asarray([False, True])
-    cache, out, _, routed = decode_multi(
+    cache, out, _, extras = decode_multi(
         CFG, params, cache, cur, jnp.zeros((2,)), 4, 0, jax.random.key(1),
         live)
+    routed = extras.routing
     full = seq + [int(toks[0])] + [int(t) for t in out[:3, 1]]
     want = np.asarray(ref.forward_logits(ARCH, params, full))
     assert [int(t) for t in out[:, 1]] == [
@@ -292,7 +293,8 @@ def test_a_chunked_tile_equals_the_same_tile_in_one_chunk(params,
         return jax.jit(partial(latent.prefill, CFG))(
             params, cache, tokens, lengths, slots)
 
-    (one, x1, s1), (many, x2, s2) = run(64), run(16)
+    (one, x1, e1), (many, x2, e2) = run(64), run(16)
+    s1, s2 = e1.routing, e2.routing
     # 40 tokens end in the third chunk of 16: the fourth is not run.
     assert latent.prefill_chunks(CFG, 64, 40) == (3, 4)
     assert np.allclose(np.asarray(x1)[:, :48], np.asarray(x2)[:, :48],

@@ -296,7 +296,7 @@ def test_prefill_then_sixteen_steps_are_the_references_forward(params):
     assert not np.asarray(cache.s[:, 0]).any()
     # The tied head: the logits are the final hidden state against the
     # embedding's rows.
-    x, _ = periodic.forward_free(CFG, params, jnp.asarray(toks)[None])
+    x, _, _ = periodic.forward_free(CFG, params, jnp.asarray(toks)[None])
     np.testing.assert_allclose(
         x[0, -1] @ params["embed"].T, want[-1], rtol=0, atol=2e-5)
 
@@ -307,9 +307,10 @@ def test_the_queue_side_first_token_is_the_tiles(params):
         .at[1, :9].set(toks[:9])
     lengths = jnp.asarray([20, 9], jnp.int32)
     temps, key = jnp.zeros((2,)), jax.random.key(0)
-    first, lp = first_token_sample(CFG, params, tile, lengths, temps, 0, key)
+    first, lp, _ = first_token_sample(CFG, params, tile, lengths, temps, 0,
+                                      key)
     cache = init_kv_cache(CFG, 2, 64)
-    _, got, got_lp = prefill_sample_batch(
+    _, got, got_lp, _ = prefill_sample_batch(
         CFG, params, cache, tile, lengths, jnp.asarray([0, 1], jnp.int32), 0,
         temps, key)
     np.testing.assert_array_equal(first, got)
@@ -360,10 +361,11 @@ def test_a_dead_slots_state_is_bit_equal_after_a_block(params):
                            jnp.int32(slot))
     before = jax.tree.map(np.asarray, cache)
     live = jnp.asarray([True, False, True])
-    # No routed layer: the block hands back no routing stats.
-    cache, toks, _ = decode_multi(
+    cache, toks, _, extras = decode_multi(
         CFG, params, cache, jnp.asarray([5, 6, 7], jnp.int32),
         jnp.zeros((3,)), 4, 0, jax.random.key(0), live)
+    # No routed layer and one pass: the block hands back nothing beside.
+    assert extras.routing is None and extras.exits is None
     for name in ("s", "tails"):
         np.testing.assert_array_equal(getattr(cache, name)[:, 1],
                                       getattr(before, name)[:, 1])

@@ -103,9 +103,9 @@ def test_in_place_decode_is_the_plain_reference(kind, group):
     fed.append(np.asarray(tok))
 
     cache_b, tok_b = start()
-    cache_b, toks_b, _ = decode_multi(cfg, params, cache_b, tok_b,
-                                      jnp.zeros((B,), jnp.float32), n, 0,
-                                      jax.random.key(5))
+    cache_b, toks_b, _, _ = decode_multi(cfg, params, cache_b, tok_b,
+                                         jnp.zeros((B,), jnp.float32), n, 0,
+                                         jax.random.key(5))
     toks_b = np.asarray(toks_b)
 
     touched = np.zeros((B, S), bool)
@@ -490,12 +490,12 @@ def test_queue_side_first_token_matches_slot_path():
     padded = jnp.zeros((1, bucket), jnp.int32).at[0, :24].set(prompt)
 
     cache = init_kv_cache(cfg, 2, 64)
-    _, tok_slot, lp_slot = prefill_sample_batch(
+    _, tok_slot, lp_slot, _ = prefill_sample_batch(
         cfg, params, cache, padded, jnp.full((1,), 24, jnp.int32),
         jnp.zeros((1,), jnp.int32), 0, jnp.zeros((1,), jnp.float32),
         jax.random.key(2))
 
-    toks, lps = first_token_sample(
+    toks, lps, _ = first_token_sample(
         cfg, params, jnp.broadcast_to(padded, (4, bucket)),
         jnp.full((4,), 24, jnp.int32), jnp.zeros((4,), jnp.float32), 0,
         jax.random.key(3))
@@ -778,7 +778,7 @@ def test_a_lone_request_on_four_slots_meets_its_own_experts_only(arch):
     (`moe_experts_hit`), on the block's span as in the counts."""
     import threading
 
-    from ray_tpu.models.generate import routed_layers
+    from ray_tpu.models.transformer import stack
     from ray_tpu.util import tracing
 
     cfg = {"mellum": configs.tiny_mellum_test, "pangu": configs.tiny_pangu_test,
@@ -804,7 +804,8 @@ def test_a_lone_request_on_four_slots_meets_its_own_experts_only(arch):
                                         for n in names}
     for b in blocks:
         # Positions a step x top k x routed layers, over the block's steps.
-        scored = b["k"] * b["slots"] * cfg.moe_top_k * routed_layers(cfg)
+        scored = b["k"] * b["slots"] * cfg.moe_top_k \
+            * stack(cfg).routed_layers(cfg)
         assert b["moe_pairs"] == scored
         if arch == "pangu":     # of its pairs, those on an expert held
             assert b["moe_rows_taken"] <= b["moe_rows"] <= scored

@@ -246,18 +246,18 @@ def test_a_lone_slots_step_is_its_step_among_four(name):
     pairs and no other, and the pairs scored stay every slot's."""
     cfg = TINY[name]()
     walk, pairs = _stepped(cfg)
-    cache, logits, every = walk(jnp.ones((B,), bool))
-    every = [int(n) for n in every]
+    cache, logits, extras = walk(jnp.ones((B,), bool))
+    every = [int(n) for n in extras.routing]
     assert every[3] == every[1]                 # all owned: all taken
     assert (every[4] if name == "pangu" else every[1]) == B * pairs
     taken = 0
     for i in range(B):
-        alone, got, stats = walk(jnp.arange(B) == i)
+        alone, got, extras = walk(jnp.arange(B) == i)
         assert np.array_equal(np.asarray(got[i]), np.asarray(logits[i]))
         for a, b in zip(_slot(alone, i), _slot(cache, i)):
             assert np.array_equal(a, b)
         assert np.all(np.isfinite(np.asarray(got)))
-        stats = [int(n) for n in stats]
+        stats = [int(n) for n in extras.routing]
         if name == "pangu":     # of its pairs, those on an expert held
             assert stats[0] <= stats[3] <= stats[1] <= stats[4] == B * pairs
             assert stats[3] <= pairs

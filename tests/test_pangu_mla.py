@@ -26,7 +26,6 @@ from ray_tpu.models.generate import (
     init_kv_cache,
     prefill,
     prefill_sample_batch,
-    routed_layers,
 )
 from ray_tpu.models.transformer import STACKS, forward, init_params
 from ray_tpu.ops import decode_attention as da
@@ -63,7 +62,7 @@ def test_the_preset_is_the_published_shape_in_small(params):
     assert STACKS["pangu_ultra_moe"] == "latent"
     assert latent.layer_plan(CFG) == [("dense_layers", (1,), False),
                                       ("routed_layers", (2,), True)]
-    assert routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
+    assert latent.routed_layers(CFG) == 2 and latent.routing_stats(CFG) == 5
     cache = jax.eval_shape(lambda: init_kv_cache(CFG, 3, 64))
     # One vector a token a layer, latent + rotary key in whole lanes, and
     # nothing a head.
@@ -362,10 +361,11 @@ def test_tile_first_token_and_block_agree_and_count_their_routing(params):
     temps = jnp.zeros((4,), jnp.float32)
     key = jax.random.key(0)
     cache = init_kv_cache(CFG, 3, 48)
-    cache, first, _, tile = prefill_sample_batch(
+    cache, first, _, extras = prefill_sample_batch(
         CFG, params, cache, jnp.asarray(toks), lengths, slots, 0, temps, key)
-    free, _ = first_token_sample(CFG, params, jnp.asarray(toks), lengths,
-                                 temps, 0, key)
+    tile = extras.routing
+    free, _, _ = first_token_sample(CFG, params, jnp.asarray(toks), lengths,
+                                    temps, 0, key)
     want = [int(np.argmax(np.asarray(ref.forward_logits(
         ARCH, params, toks[i, :n].tolist()))[-1]))
         for i, n in enumerate(lens)]
@@ -376,8 +376,9 @@ def test_tile_first_token_and_block_agree_and_count_their_routing(params):
     assert 0 < kept < pairs and 0 < hit <= 2 * 4 and fullest <= kept
 
     cur = jnp.asarray([want[1], want[2], want[0]], jnp.int32)   # by slot
-    cache, out, _, stats = decode_multi(CFG, params, cache, cur, temps[:3],
-                                        4, 0, key)
+    cache, out, _, extras = decode_multi(CFG, params, cache, cur, temps[:3],
+                                         4, 0, key)
+    stats = extras.routing
     out = np.asarray(out)
     for slot, i in ((0, 1), (1, 2), (2, 0)):
         seq = toks[i, :lens[i]].tolist() + [want[i]] + out[:, slot].tolist()

@@ -261,7 +261,8 @@ def test_the_queue_side_first_token_is_the_tiles(params):
         .at[1, :9].set(toks[:9])
     lengths = jnp.asarray([20, 9], jnp.int32)
     temps, key = jnp.zeros((2,)), jax.random.key(0)
-    first, lp = first_token_sample(CFG, params, tile, lengths, temps, 0, key)
+    first, lp, _ = first_token_sample(CFG, params, tile, lengths, temps, 0,
+                                      key)
     cache = init_kv_cache(CFG, 2, 64)
     _, got, got_lp, _ = prefill_sample_batch(
         CFG, params, cache, tile, lengths, jnp.asarray([0, 1], jnp.int32), 0,
@@ -313,9 +314,10 @@ def test_a_dead_slots_state_is_bit_equal_after_a_block(params):
                            jnp.int32(slot))
     before = jax.tree.map(np.asarray, cache)
     live = jnp.asarray([True, False, True])
-    cache, toks, _, stats = decode_multi(
+    cache, toks, _, extras = decode_multi(
         CFG, params, cache, jnp.asarray([5, 6, 7], jnp.int32),
         jnp.zeros((3,)), 4, 0, jax.random.key(0), live)
+    stats = extras.routing
     for name in ("s", "tails"):
         np.testing.assert_array_equal(getattr(cache, name)[:, 1],
                                       getattr(before, name)[:, 1])
@@ -383,8 +385,10 @@ def test_a_tiles_chunks_of_the_recurrence_are_counted(params, monkeypatch,
                     decode_block=4)
     reqs = [types.SimpleNamespace(prompt=[1] * n, id=i)
             for i, n in enumerate((130, 64, 1))]
-    assert periodic.scan_chunks(CFG, 256, [256, 65]) \
-        == ((4 + 2) if kernel else 4 * 2, 4 * 2)
+    # Six linear layers, whose recurrence the stack reckons.
+    assert periodic.tile_counts(CFG, 256, [256, 65], 321)[1] == dict(
+        linear_tokens=321 * 6, linear_chunks_of=4 * 2 * 6,
+        linear_chunks=((4 + 2) if kernel else 4 * 2) * 6)
     span = eng._tile_span(side, 256, 4, reqs)
     assert span.attributes["linear_chunks"] == ran * 6
     assert span.attributes["linear_chunks_of"] == 4 * 4 * 6
@@ -431,9 +435,9 @@ def test_all_sixteen_shares_add_up_to_the_uncut_layer():
 def test_long_queue_side_rows_walk_singly_to_the_same_tile(params,
                                                            monkeypatch):
     toks = jnp.asarray(np.stack([_prompt(32, seed=20), _prompt(32, seed=21)]))
-    want, want_chosen = periodic.forward_free(CFG, params, toks)
+    want, want_chosen, _ = periodic.forward_free(CFG, params, toks)
     monkeypatch.setattr(periodic, "_ROW_ALONE", 32)
-    got, chosen = periodic.forward_free(CFG, params, toks)
+    got, chosen, _ = periodic.forward_free(CFG, params, toks)
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
     assert jax.tree.structure(chosen) == jax.tree.structure(want_chosen)
     for a, b in zip(jax.tree.leaves(chosen), jax.tree.leaves(want_chosen)):

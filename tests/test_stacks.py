@@ -27,6 +27,13 @@ TINY = {"llama": configs.tiny_test, "afmoe": configs.tiny_afmoe_test,
         "ouro": configs.tiny_ouro_test,
         "kimi_linear": configs.tiny_kimi_test}
 OPTIONAL = ("suffix", "param_logical_axes", "forward_train")
+# What `transformer.STACKS` documents of a stack module: nothing above the
+# seam calls anything else of one.
+COUNTS = ("counters", "tile_counts", "block_counts", "result_counts",
+          "by_products")
+INTERFACE = ("init_params", "num_params", "init_cache", "prefill",
+             "forward_free", "decode", "decode_block", "last_logits",
+             "routed_layers", "routing_stats") + COUNTS + OPTIONAL
 ROOT = os.path.dirname(ray_tpu.__file__)
 
 
@@ -52,18 +59,28 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
 
     toks = jax.ShapeDtypeStruct((W, S), jnp.int32)
     rows = jax.ShapeDtypeStruct((W,), jnp.int32)
-    # A looped configuration's walks return each row's exit pass last.
+
+    def typed(tree):
+        return jax.tree.map(lambda a: (a.shape, a.dtype), tree)
+
+    # Every walk returns `Extras` last, whatever the configuration: a
+    # looped one's `exits` has each row's exit pass, any other's is None.
     looped = cfg.ut_steps > 1
-    filled, x, tile_stats, *exits = jax.eval_shape(
+
+    def exits_of(extras, shape):
+        assert isinstance(extras, stackparts.Extras)
+        assert typed(extras.exits) == ((shape, jnp.int32) if looped else None)
+
+    filled, x, tile = jax.eval_shape(
         lambda p, c, t, n, s: st.prefill(cfg, p, c, t, n, s),
         params, cache, toks, rows, rows)
-    assert [(e.shape, e.dtype) for e in exits] == [((W, S), jnp.int32)] * looped
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), filled) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), cache)
+    exits_of(tile, (W, S))
+    assert typed(filled) == typed(cache)
     assert x.shape == (W, S, D)
-    free, _chosen, *exits = jax.eval_shape(
+    free, _chosen, extras = jax.eval_shape(
         lambda p, t: st.forward_free(cfg, p, t), params, toks)
-    assert free.shape == (W, S, D) and len(exits) == looped
+    assert free.shape == (W, S, D) and extras.routing is None
+    exits_of(extras, (W, S))
     logits = jax.eval_shape(
         lambda p, x, n: st.last_logits(cfg, p, x, n), params, x, rows)
     assert (logits.shape, logits.dtype) == ((W, V), jnp.float32)
@@ -83,30 +100,31 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
         with pytest.raises(NotImplementedError):
             generate.decode_step.lower(
                 cfg, params, cache, jax.ShapeDtypeStruct((B,), jnp.int32))
-        stepped, logits, stats = jax.eval_shape(
+        stepped, logits, step = jax.eval_shape(
             lambda p, c, t, p0: st.decode_block(cfg, p, c, t, p0), params,
             cache, jax.ShapeDtypeStruct((B, Bd), jnp.int32),
             jax.ShapeDtypeStruct((B,), jnp.int32))
         assert (logits.shape, logits.dtype) == ((B, Bd, V), jnp.float32)
+        assert isinstance(step, stackparts.Extras) and step.exits is None
     else:
-        stepped, logits, stats, *exits = decode()
+        stepped, logits, step = decode()
         assert (logits.shape, logits.dtype) == ((B, V), jnp.float32)
-        assert [(e.shape, e.dtype) for e in exits] == [((B,), jnp.int32)] * looped
+        exits_of(step, (B,))
         if hasattr(st, "decode_block"):
             with pytest.raises(NotImplementedError) as e:
                 st.decode_block(cfg, params, cache, None, None)
             assert str(e.value) == st.NOT_ITS_WALK["decode_block"]
     assert jax.tree.structure(stepped) == jax.tree.structure(cache)
+    stats = step.routing
     assert (stats is None) == (st.routed_layers(cfg) == 0)
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), tile_stats) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), stats)
-    assert generate.routed_layers(cfg) == st.routed_layers(cfg)
+    assert typed(tile.routing) == typed(stats)
     if stats is not None:
         # Four sums; a stack whose layers hold a share of their experts
         # adds the pairs routed (`routing_stats`).
         n = st.routing_stats(cfg)
         assert n in (4, 5)
         assert (stats.shape, stats.dtype) == ((n,), jnp.int32)
+    assert st.by_products(cfg) == (stats is not None or looped)
 
     # What a stack lacks, it says why; what it has, `offered` hands over.
     for name in OPTIONAL:
@@ -126,6 +144,96 @@ def test_a_stack_offers_the_interface_with_the_documented_shapes(arch):
         assert x.shape == (W, S, D)
         assert ks.shape == vs.shape == (
             cfg.n_layers, W, S, cfg.n_kv_heads, cfg.head_dim)
+
+
+# -- what a stack counts on the host -----------------------------------------
+
+# A tile of three rows in the 128 bucket holding 65, 40 and 1 tokens, 106
+# real; a block of 4 steps over 3 slots of 32 rows, two of them owned,
+# holding 3 and 10 rows at its first step and 50 over all of it. What each
+# stack's span says of them, reckoned by hand (the latent stack's chunk is
+# cut to 32 rows): `tile_counts(...)[1]`, `block_counts(...)[1]`.
+TILE, BLOCK = (128, [65, 40, 1], 106), (4, 3, 32, [3, 10], 50)
+RECKONED = {
+    # 65 tokens end in the third chunk of four; a slot reads min(rows
+    # held, index_topk = 8) a step: 3 + 4 + 5 + 6, and 4 x 8.
+    "glm_moe_dsa": (dict(chunks=3, chunks_of=4),
+                    dict(sparse_rows_read=18 + 32)),
+    # Six linear layers: 106 tokens each; chunks of 64, 2 + 1 + 1 of the
+    # rows' 3 x 2 (all six on the XLA walk, which runs every chunk); a
+    # slot's states and tails 12,288 + 6,912 bytes, a held row 2 layers
+    # x k and v x 2 heads x 16 x two bf16 terms.
+    "solar_open2": (dict(linear_tokens=636, linear_chunks=36,
+                         linear_chunks_of=36),
+                    dict(linear_slot_steps=72, linear_slot_steps_live=48,
+                         cache_state_bytes_live=4 * 2 * 19200,
+                         cache_row_bytes_held=50 * 512)),
+    # Six state-space layers, no chunks: 49,152 + 9,216 bytes a slot.
+    "jamba": (dict(linear_tokens=636),
+              dict(linear_slot_steps=72, linear_slot_steps_live=48,
+                   cache_state_bytes_live=4 * 2 * 58368,
+                   cache_row_bytes_held=50 * 256)),
+    # Eight linear layers and three latent ones of 40 float32 values a row.
+    "kimi_linear": (dict(linear_tokens=848, linear_chunks=48,
+                         linear_chunks_of=48),
+                    dict(linear_slot_steps=96, linear_slot_steps_live=64,
+                         cache_state_bytes_live=4 * 2 * 25600,
+                         cache_row_bytes_held=50 * 480)),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(STACKS))
+def test_a_stack_counts_its_own_mechanism_under_names_it_declared(
+        arch, monkeypatch):
+    cfg = TINY[arch]()
+    st = stack(cfg)
+    if arch == "glm_moe_dsa":
+        monkeypatch.setattr(st, "PREFILL_CHUNK", 32)
+    declared = st.counters(cfg)
+    assert all(n == 0 or n == [0] * cfg.ut_steps for n in declared.values())
+    # A block of two steps' by-products, and a tile's (`k` 0): three
+    # slots, of which the first was given both steps' tokens, the second
+    # one, the third none.
+    n = st.routing_stats(cfg) if st.routed_layers(cfg) else 0
+    routing = np.array([5, 40, 9, 30, 160][:n]) if n else None
+    exits = np.array([[0, 2, 1], [2, 2, 0]]) if cfg.ut_steps > 1 else None
+    extras = stackparts.Extras(routing, exits)
+    tile, block = st.tile_counts(cfg, *TILE), st.block_counts(cfg, *BLOCK)
+    read = st.result_counts(cfg, 2, extras, [2, 1, 0])
+    first = st.result_counts(cfg, 0, stackparts.Extras(
+        routing, None if exits is None else exits[0]), [1, 1, 0])
+    counted = [found[0] for found in (tile, block, read, first)]
+    assert set().union(*counted) == set(declared)
+    assert st.by_products(cfg) == bool(read[0])
+
+    want_tile, want_block = RECKONED.get(arch, ({}, {}))
+    assert {k: tile[1][k] for k in want_tile} == want_tile
+    assert block[1] == want_block
+    if arch == "glm_moe_dsa":
+        # The one pair a span and the counters name apart, paired here.
+        cols = dict(zip(("choice_columns", "choice_columns_of"),
+                        st.choice_columns(cfg, 128, 65)))
+        assert tile == (dict(prefill_chunks=3, prefill_chunks_of=4, **cols),
+                        dict(chunks=3, chunks_of=4, **cols))
+    else:
+        assert tile[0] == tile[1] == want_tile and block[0] == block[1]
+
+    sums = {}
+    if n:
+        sums = dict(moe_experts_hit=5, moe_rows=40, moe_rows_max=9,
+                    moe_rows_taken=30, moe_pairs=160 if n == 5 else 40,
+                    moe_pairs_held=40)
+        assert first[0] == first[1] == {
+            "prefill_" + name: v for name, v in sums.items()}
+        sums["moe_expert_steps"] = 2 * st.routed_layers(cfg) * cfg.moe_experts
+    if exits is None:
+        assert read[0] == read[1] == sums
+    else:
+        # Delivered: passes 0 and 2 of the first slot, 2 of the second.
+        assert read == (dict(loop_passes=9, loop_exit_hist=[1, 0, 2]),
+                        dict(loop_passes=9, loop_exit_p1=1, loop_exit_p2=0,
+                             loop_exit_p3=2))
+        assert first[0] == dict(loop_passes=6, loop_exit_hist=[1, 0, 1])
 
 
 def _tree(path):
@@ -157,6 +265,45 @@ def test_nothing_above_the_seam_asks_which_architecture_it_serves(path):
             f"{path}:{node.lineno} compares cfg.arch"
         assert not names & _imported(node), \
             f"{path}:{node.lineno} imports a stack module by name"
+
+
+# What a stack's mechanism is configured by and kept in: the stack's to
+# read, and nobody's above the seam.
+MECHANISM = ("index_topk", "ut_steps", "early_exit_threshold", "moe_experts")
+MECHANISM_PREFIXES = ("linear_", "mamba_")
+STATE = ("s", "c", "ki", "tails")
+
+
+def _source(node):
+    return ast.unparse(node)
+
+
+@pytest.mark.parametrize("path", ["models/generate.py", "serve/llm.py"])
+def test_nothing_above_the_seam_reads_a_stack_s_mechanism(path):
+    """The programs and the engine read no configuration field that says
+    how a stack attends, loops or routes, no kind of cache entry but keys
+    and values, and call on `stack(cfg)` the documented interface alone:
+    what a mechanism counts, its stack reckons (`stackparts.counters`)."""
+    tree = _tree(path)
+    # Whatever `stack(...)` was assigned to (`st`, `self._stack`).
+    stacks = {"stack(cfg)", "stack(self.cfg)"}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call) \
+                and _source(node.value.func) == "stack":
+            stacks |= {_source(t) for t in node.targets}
+    called = set()
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Attribute):
+            continue
+        where = f"{path}:{node.lineno} reads .{node.attr}"
+        assert node.attr not in MECHANISM, where
+        assert not node.attr.startswith(MECHANISM_PREFIXES), where
+        owner = _source(node.value)
+        assert not (node.attr in STATE and "cache" in owner), where
+        if owner in stacks:
+            called.add(node.attr)
+            assert node.attr in INTERFACE, where + " of a stack"
+    assert called, f"{path} reaches no stack"
 
 
 @pytest.mark.parametrize("module", sorted(set(STACKS.values()))
